@@ -1387,7 +1387,10 @@ class BrokerHttpServer:
                 url = urlparse(self.path)
                 if url.path not in ("/query", "/"):
                     if url.path == "/health":
-                        return self._respond({"status": "ok"})
+                        # "jax": a broker must never hold a device
+                        from pinot_tpu.utils.platform import backend_state
+
+                        return self._respond({"status": "ok", "jax": backend_state()})
                     if url.path == "/metrics":
                         # Prometheus text exposition (scrape target)
                         return self._respond_text(prometheus_text(broker.metrics))

@@ -1177,7 +1177,10 @@ class ControllerHttpServer:
                         trace = (qs.get("trace") or ["false"])[0].lower() == "true"
                         return self._respond(_proxy_pql(ctrl, pql, trace))
                     if parts == ["health"]:
-                        return self._respond({"status": "ok"})
+                        # "jax": a controller must never hold a device
+                        from pinot_tpu.utils.platform import backend_state
+
+                        return self._respond({"status": "ok", "jax": backend_state()})
                     if parts == ["metrics"]:
                         # Prometheus text exposition (scrape target)
                         return self._respond_text(ctrl.metrics_text())

@@ -1,34 +1,30 @@
 """Persistent compile cache: warm restarts for the device lanes.
 
-PR 8 measured the failure mode this module kills: every plan shape pays
-a cold XLA compile (~25s on a real TPU) on its first launch, so a server
-restart, rollout, or rebalance destination is a p99 cliff until the
-whole working set has recompiled.  jax already ships a persistent
-compilation cache (keyed on the serialized HLO + compile options); this
-module wires it under the lanes and adds the two properties jax's cache
-cannot give us by itself:
-
-- **Topology isolation.**  The on-disk XLA cache lives under
-  ``<root>/xla/<fingerprint>`` where the fingerprint digests the jax
-  version, backend platform, device count/kind, and the x64 flag.  A
-  cache written on a different mesh shape or jax version lands in a
-  different directory — it can *miss*, never poison.  (jax's own key
-  covers most of this too; the directory split makes the isolation
-  auditable and survives jax key-scheme changes.)
+Every plan shape pays a cold XLA compile on its first launch, so a
+server restart, rollout, or rebalance destination is a p99 cliff until
+the whole working set has recompiled.  jax already ships a persistent
+compilation cache (keyed on the serialized HLO, compile options, jax
+version, backend and topology); this module places it and adds the one
+property jax's cache cannot give by itself:
 
 - **A plan ledger.**  jax's cache is opaque: a lane cannot ask "is this
   plan-shape digest warm on disk?" before paying the compile.  The
-  ledger records one tiny JSON file per (plan digest, fingerprint) after
-  each successful compile, so the first launch of a shape can be
-  *classified* — ``persistent`` (ledger hit: the XLA cache will serve
-  the binary) vs genuinely ``cold`` — and the ``compile.cold`` meter
-  stays honest across restarts.  Corrupt or alien ledger entries are a
-  miss, never a crash: the ledger is advisory accounting, the XLA cache
-  is the actual store.
+  ledger records one tiny JSON file per (plan digest, topology
+  fingerprint) after each successful compile, so the first launch of a
+  shape can be *classified* — ``persistent`` (ledger hit: the XLA cache
+  will serve the binary) vs genuinely ``cold`` — and the
+  ``compile.cold`` meter stays honest across restarts.  Corrupt or alien
+  ledger entries are a miss, never a crash: the ledger is advisory
+  accounting, the XLA cache is the actual store.
 
-Everything is gated on ``PINOT_TPU_COMPILE_CACHE_DIR``; unset means
-fully disabled (no config writes, no ledger I/O) so default test runs
-and in-process harnesses see the pre-existing cold/warm behavior.
+Where the cache lives.  ``JAX_COMPILATION_CACHE_DIR`` places it from
+outside: jax has already taken that directory at import, so this module
+makes no ``jax_compilation_cache_dir`` update of its own and only keeps
+the ledger in ``<that dir>/plans/``.  Unset, the cache goes to
+``<checkout>/.jax_cache`` — resolved from this package's own path, a
+fixed name, because a cache that moves never hits.  An explicit
+``root=`` to ``configure_jax_cache`` is for tests and harnesses that
+need a cache of their own.
 """
 from __future__ import annotations
 
@@ -42,20 +38,38 @@ from typing import Optional
 
 logger = logging.getLogger(__name__)
 
+_DEFAULT_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
 _lock = threading.Lock()
-# directory most recently handed to jax_compilation_cache_dir (idempotence
-# guard: lanes call configure() per construction, jax.config once)
-_configured_dir: Optional[str] = None
+# root most recently configured (idempotence guard: lanes call
+# configure_jax_cache() per construction, jax.config once)
+_configured_root: Optional[str] = None
+# explicit root= from a test or harness; wins over the environment
+_root_override: Optional[str] = None
 
 
-def cache_root() -> Optional[str]:
-    """The persistent-cache root, or None when the feature is off."""
-    root = os.environ.get("PINOT_TPU_COMPILE_CACHE_DIR", "").strip()
-    return root or None
+def _env_root() -> str:
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
 
 
-def enabled() -> bool:
-    return cache_root() is not None
+def cache_root() -> str:
+    """The directory holding jax's cache files and the ``plans/`` ledger."""
+    return _root_override or _env_root() or _DEFAULT_ROOT
+
+
+def wiped_subroot(name: str) -> str:
+    """``<root>/<name>``, emptied: a cache of its own for a harness whose
+    first phase has to compile cold.  A fixed name under the resolved
+    root, never a temporary one."""
+    import shutil
+
+    path = os.path.join(cache_root(), name)
+    shutil.rmtree(path, ignore_errors=True)
+    os.makedirs(path)
+    return path
 
 
 def topology_fingerprint(
@@ -65,7 +79,8 @@ def topology_fingerprint(
     device_kind: Optional[str] = None,
     x64: Optional[bool] = None,
 ) -> str:
-    """Short stable digest of everything that must invalidate the cache.
+    """Short stable digest of everything that must invalidate a ledger
+    entry.
 
     A compiled executable is only reusable on the same jax version,
     backend platform, device count (mesh shape), device kind, and
@@ -81,11 +96,11 @@ def topology_fingerprint(
     if platform is None or device_count is None or device_kind is None:
         devices = jax.devices()
         if platform is None:
-            platform = devices[0].platform if devices else "none"
+            platform = devices[0].platform
         if device_count is None:
             device_count = len(devices)
         if device_kind is None:
-            device_kind = getattr(devices[0], "device_kind", "") if devices else ""
+            device_kind = devices[0].device_kind
     if x64 is None:
         x64 = bool(jax.config.jax_enable_x64)
     payload = json.dumps(
@@ -102,48 +117,37 @@ def topology_fingerprint(
 
 
 def configure_jax_cache(root: Optional[str] = None) -> Optional[str]:
-    """Point jax's persistent compilation cache under the root.
+    """Make sure jax's persistent compilation cache is on, and say where.
 
-    Returns the per-topology XLA cache directory in use, or None when
-    the feature is disabled or jax refused the config (old jax builds
-    without the knobs must degrade to plain cold compiles, not crash
-    lane construction).  Idempotent: repeat calls with the same root are
-    free; a changed root re-points the cache.
+    Returns the cache root in use, or None when the directory cannot be
+    created (the lanes then compile cold, they do not fail).  With
+    ``root`` given the cache is re-pointed there and the ledger follows;
+    otherwise see the module docstring.  Idempotent: repeat calls with
+    the same root are free.
     """
-    global _configured_dir
-    if root is None:
-        root = cache_root()
-    if root is None:
-        return None
-    xla_dir = os.path.join(root, "xla", topology_fingerprint())
+    global _configured_root, _root_override
     with _lock:
-        if _configured_dir == xla_dir:
-            return xla_dir
+        if root is not None:
+            _root_override = root
+        target = cache_root()
+        if _configured_root == target:
+            return target
         try:
-            os.makedirs(xla_dir, exist_ok=True)
+            os.makedirs(target, exist_ok=True)
         except OSError:
-            logger.warning("compile cache dir unusable: %s", xla_dir, exc_info=True)
+            logger.warning("compile cache dir unusable: %s", target, exc_info=True)
             return None
         import jax
 
-        try:
-            jax.config.update("jax_compilation_cache_dir", xla_dir)
-        except Exception:
-            logger.warning("jax persistent compile cache unavailable", exc_info=True)
-            return None
-        # CPU/test compiles finish in milliseconds; without zeroing the
-        # floor nothing would ever be written and every restart test
-        # would silently exercise the cold path
-        for knob, value in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", 0),
-        ):
-            try:
-                jax.config.update(knob, value)
-            except Exception:
-                pass
-        _configured_dir = xla_dir
-        return xla_dir
+        if _root_override is not None or not _env_root():
+            jax.config.update("jax_compilation_cache_dir", target)
+        # jax only persists compiles that took over a second by default;
+        # the ledger calls a shape "persistent" after ANY compile, so the
+        # floors go to zero to keep the two in step
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        _configured_root = target
+        return target
 
 
 # -- plan ledger ------------------------------------------------------------
@@ -169,14 +173,16 @@ def record_plan(
     """
     if root is None:
         root = cache_root()
-    if root is None or not digest:
+    if not digest:
         return False
     if fingerprint is None:
         fingerprint = topology_fingerprint()
     path = _plan_path(root, digest, fingerprint)
     try:
         os.makedirs(os.path.dirname(path), exist_ok=True)
-        tmp = f"{path}.tmp.{os.getpid()}"
+        # unique per writer: two lanes of one process compile the same
+        # digest at once, and each must rename a file of its own
+        tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
         with open(tmp, "w") as f:
             json.dump(
                 {
@@ -208,7 +214,7 @@ def known_plan(
     """
     if root is None:
         root = cache_root()
-    if root is None or not digest:
+    if not digest:
         return False
     if fingerprint is None:
         fingerprint = topology_fingerprint()
@@ -226,7 +232,8 @@ def known_plan(
 
 
 def _reset_for_tests() -> None:
-    """Forget the idempotence guard so a test can re-point the cache."""
-    global _configured_dir
+    """Forget the configured root and any ``root=`` override."""
+    global _configured_root, _root_override
     with _lock:
-        _configured_dir = None
+        _configured_root = None
+        _root_override = None

@@ -55,6 +55,16 @@ def max_key_space() -> int:
     return 2**62 if x64_enabled() else 2**30
 
 
+def row_count_dtype():
+    """Integer dtype in which row counts cross the segment axis and the
+    mesh.  A float32 sum stops being exact at 2^24: 16 segments of 8.4M
+    rows answered ``count(*)`` one short on the chip.  Within ONE segment
+    a float32 count is exact (fewer than 2^24 rows), so kernels may
+    count in float there (the one-hot matmul does) and cast before the
+    merge.  int32 bounds a server's answer at 2^31 - 1 matched rows."""
+    return jnp.int64 if x64_enabled() else jnp.int32
+
+
 def pad_docs(n: int) -> int:
     """Round doc count up to the padding bucket (pow2 beyond one block)."""
     if n <= DOC_PAD_MULTIPLE:
@@ -105,8 +115,8 @@ def pad_value_card(c: int) -> int:
 # stage a dictionary-decoded float raw array for aggregation reads; at
 # or below it, the kernel gathers dict_vals[fwd].
 #
-# Measured on a real v5e chip (2026-07-30, tools/microbench.py
-# `gather_vs_raw`): XLA lowers the per-row dict gather to a serialized
+# Measured on a v5e chip (2026-07-30, tools/microbench.py
+# `gather_vs_raw`; the record is gone, ROADMAP S5 re-measures): XLA lowers the per-row dict gather to a serialized
 # loop — ~12.5 ns/element, 159x slower than streaming a raw float32
 # array (1257 ms vs 7.9 ms for TPC-H Q1 over 33.5M rows; raw-feed hits
 # 4.25 B rows/s vs the 295 GB/s stream roofline).  So on accelerators

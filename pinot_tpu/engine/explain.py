@@ -325,7 +325,7 @@ def build_explain_node(
 
                 cstate = (
                     "persistent"
-                    if compilecache.enabled() and compilecache.known_plan(pdigest)
+                    if compilecache.known_plan(pdigest)
                     else "cold"
                 )
                 compile_info = {"state": cstate, "costAnalysis": "unavailable"}
@@ -411,8 +411,7 @@ def build_explain_node(
 
                     state = (
                         "persistent"
-                        if compilecache.enabled()
-                        and compilecache.known_plan(pdigest)
+                        if compilecache.known_plan(pdigest)
                         else "cold"
                     )
                     compile_info = {"state": state, "costAnalysis": "unavailable"}

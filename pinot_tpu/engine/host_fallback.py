@@ -77,8 +77,7 @@ _VECTOR_AGGS = {"count", "sum", "min", "max", "avg", "minmaxrange"}
 # distinct aggs vectorize in the GROUP-BY path via (group, gid) pair
 # dedup (np.unique); they only touch global dict ids, so strings are
 # fine.  Without this, a beyond-capacity group-by with distinctcount
-# fell to the per-row Python loop — ~30 min at 134M rows vs ~80 s
-# vectorized (NORTHSTAR_HLL.json aux paths).
+# fell to the per-row Python loop, minutes at 134M rows.
 _DISTINCT_AGGS = {"distinctcount", "distinctcounthll", "fasthll"}
 
 

@@ -21,7 +21,7 @@ With neither set the topology is the **trivial single lane** — exactly
 the pre-mesh serving path (one lane, no mesh, default device), so
 existing deployments and tests see zero behavior change.  Tier-1 runs
 simulate a pod slice with ``XLA_FLAGS=--xla_force_host_platform_device_
-count=N`` (``utils/platform.force_cpu_mesh`` — the conftest already
+count=N`` (``utils/platform.virtual_cpu_mesh`` — the conftest already
 forces 8).
 
 Fallback matrix (README "Mesh execution" has the operator view):

@@ -26,10 +26,11 @@ TPU lowering notes (validated on a real v5e chip):
   scratch across grid steps (the MXU path, mirroring
   ``kernel._segment_add_matmul``).
 
-Status: compiled + validated on TPU v5e; also runs in interpret mode on
-CPU for the unit tests.  Wiring into the executor is gated on the
-microbench (see ``tools/microbench.py``): XLA's own fusion of the same
-pipeline is the default.
+Status: compiles on a TPU v5e under jax 0.9.0 and agrees with the XLA
+kernel there (``tests/test_tpu_platform.py``); the unit tests run it in
+interpret mode by passing ``interpret=True``.  Nothing in the executor
+calls it: XLA's own fusion of the same pipeline is the serving path, and
+``tools/microbench.py pallas_ab`` is the A/B that would change that.
 """
 from __future__ import annotations
 
@@ -42,15 +43,10 @@ import numpy as np
 
 from pinot_tpu.engine import config
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    PALLAS_AVAILABLE = True
-except ImportError:  # pragma: no cover
-    PALLAS_AVAILABLE = False
-
 import os as _os
+
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # sublanes per grid step; the sublane walk is unrolled at trace time, so
 # larger blocks trade Mosaic compile time for fewer grid steps
@@ -67,12 +63,6 @@ def _pad_rows(n: int) -> int:
 
 def _pad_lane(c: int) -> int:
     return max(LANE, -(-c // LANE) * LANE)
-
-
-def use_pallas() -> bool:
-    import os
-
-    return PALLAS_AVAILABLE and os.environ.get("PINOT_TPU_USE_PALLAS") == "1"
 
 
 def _table_gather(tab_row: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:

@@ -22,33 +22,8 @@ from typing import Any, Callable, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.6: top-level shard_map
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-# replication-check kwarg renamed across jax versions (check_rep ->
-# check_vma); detect ONCE instead of guessing, so a trace-time
-# TypeError can't masquerade as a poisoned plan and silently heal
-# every sharded query onto the host path (the bug that kept the
-# serving path single-chip: each mesh launch "failed" at shard_map
-# and failed over)
-import inspect as _inspect
-
-try:
-    _SHARD_MAP_CHECK_KWARG = (
-        "check_vma"
-        if "check_vma" in _inspect.signature(shard_map).parameters
-        else (
-            "check_rep"
-            if "check_rep" in _inspect.signature(shard_map).parameters
-            else None
-        )
-    )
-except (TypeError, ValueError):  # pragma: no cover - exotic wrappers
-    _SHARD_MAP_CHECK_KWARG = None
 
 from pinot_tpu.engine.kernel import (
     apply_reduce,
@@ -163,15 +138,12 @@ def _make_sharded(plan: StaticPlan, mesh: Mesh, single: Callable, n_extra: int) 
             jax.tree_util.tree_map(lambda _: shard_spec, segs),
             jax.tree_util.tree_map(lambda _: shard_spec, q),
         ) + (shard_spec,) * n_extra
-        kwargs = {}
-        if _SHARD_MAP_CHECK_KWARG is not None:
-            kwargs[_SHARD_MAP_CHECK_KWARG] = False
         fn = shard_map(
             local_fn,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=_out_specs(reducers, shard_spec),
-            **kwargs,
+            check_vma=False,
         )
         return fn(segs, q, *extra)
 

@@ -6,7 +6,7 @@ RoaringBitmap of docIds, read host-side by
 selective predicates in O(matches) regardless of doc order.
 
 TPU-first placement: the postings stay HOST-resident, not in HBM.
-On-chip measurement (MICROBENCH_TPU.json) puts XLA per-element gathers
+The 2026-07 chip measurement (ROADMAP S5) put XLA per-element gathers
 at ~12.5 ns — fine for thousands of matched rows, poison at per-row
 scan scale.  The executor therefore uses postings to resolve matched
 row ids on host and aggregates exactly those rows with numpy
@@ -22,7 +22,7 @@ one dictId are one contiguous slice, and a dictId *range* (the sorted
 dictionary makes value ranges dictId ranges) is also one contiguous
 slice, so EQ/RANGE resolve to slices and IN to a few of them.
 
-Compression (VERDICT r3 #6): the raw int32 posting stream costs
+Compression: the raw int32 posting stream costs
 4 B/row/indexed column (~4 GB per column at 1B rows).  The stream is
 chunked into 4096-posting blocks, each stored as whichever of two
 container kinds is smaller — the roaring-container idea

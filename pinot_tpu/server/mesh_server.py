@@ -1,5 +1,5 @@
 """Multi-host serving topology: broker PQL answered by a (hosts, chips)
-mesh (VERDICT r3 #7 — the single-program ICI+DCN path wired into the
+mesh (the single-program ICI+DCN path wired into the
 serving stack, not just the SPMD harness).
 
 The reference scales serving across machines only by scatter-gather
@@ -161,7 +161,7 @@ class MultihostQueryServer:
                 self._fanout.submit(self._transport.request, addr, payload, 600.0)
                 for addr in self._followers
             ]
-            # The hard failure window (r4 VERDICT #7): a follower dying
+            # The hard failure window: a follower dying
             # BETWEEN the preflight ping and collective entry.  Its
             # request future fails fast (connection reset / refused),
             # while a healthy follower's future stays pending until it

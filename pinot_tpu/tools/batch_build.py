@@ -103,7 +103,7 @@ def run_batch_build(spec: BatchBuildSpec, workers: int = 0) -> List[dict]:
 
 
 # ---------------------------------------------------------------------------
-# Cross-machine build fan-out (VERDICT r3 #2 / pinot-hadoop parity)
+# Cross-machine build fan-out (pinot-hadoop parity)
 # ---------------------------------------------------------------------------
 # Reference: SegmentCreationJob.java distributes one segment build per
 # input file across Hadoop mappers; SegmentTarPushJob.java pushes the

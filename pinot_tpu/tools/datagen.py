@@ -226,7 +226,11 @@ def _synthetic_columnar_segment(
         time_column=time_column,
     )
     seg = ImmutableSegment(metadata=smeta, columns=columns)
-    smeta.crc = hash((name, num_rows, seed)) & 0xFFFFFFFF  # cheap identity
+    # a cheap cache-identity token, not a data CRC (no custom["dataCrc"]):
+    # the same in every process, which hash() of a str is not
+    import zlib
+
+    smeta.crc = zlib.crc32(f"{name}:{num_rows}:{seed}".encode())
     return seg
 
 

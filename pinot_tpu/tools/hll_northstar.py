@@ -1,5 +1,5 @@
 """North-star workload: high-cardinality distinctCountHLL group-by on
-synthetic ad-events (BASELINE.json config 4; VERDICT r3 #4).
+synthetic ad-events (BASELINE.json config 4).
 
 Measures, at a requested total row count:
 - kernel-marginal rows/s (bench.py methodology: fixed dispatch RTT

@@ -1,8 +1,7 @@
 """Perf regression gate: fresh ``bench.py`` JSON vs a committed record.
 
-``bench.py`` prints one JSON document per run and the repo commits the
-round captures (``BENCH_r05.json`` & friends).  This gate compares a
-fresh run against a committed baseline with per-metric tolerance bands,
+``bench.py`` prints one JSON document per run.  This gate compares a
+fresh run against a baseline document with per-metric tolerance bands,
 so a perf regression fails CI instead of silently landing:
 
 - higher-is-better metrics (rows/s throughput) must stay above
@@ -36,10 +35,12 @@ per-config rows/s, the sharded-vs-single speedup, and per-lane
 achieved bandwidth against the committed ``MULTICHIP_r06.json``.
 Mixed kinds (default vs serving vs multichip) skip outright.
 
+A default-mode ``bench.py`` document has no committed baseline (the
+benchmark and its records are ROADMAP S0's to build): name one with
+``--baseline``.
+
 Usage:
-  python -m pinot_tpu.tools.perf_gate current.json [--baseline BENCH_r05.json]
-  python bench.py > /tmp/fresh.json && \
-      python -m pinot_tpu.tools.perf_gate /tmp/fresh.json
+  python -m pinot_tpu.tools.perf_gate current.json --baseline earlier.json
 
 Exit codes: 0 pass/skip, 1 regression, 2 input error.
 """
@@ -493,9 +494,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     p.add_argument(
         "--baseline",
         default=None,
-        help="committed capture to gate against (default BENCH_r05.json, "
-        f"{SERVING_DEFAULT_BASELINE} for a serving-mode document, or "
-        f"{MULTICHIP_DEFAULT_BASELINE} for a multichip-mode document)",
+        help="document to gate against (required for a default-mode "
+        f"bench.py document; defaults to {SERVING_DEFAULT_BASELINE} for a "
+        f"serving-mode document, {MULTICHIP_DEFAULT_BASELINE} for a "
+        "multichip-mode document, and so on per kind)",
     )
     p.add_argument(
         "--tolerance-scale",
@@ -524,7 +526,12 @@ def main(argv: Optional[List[str]] = None) -> int:
                 "tiered": TIERED_DEFAULT_BASELINE,
                 "audit": AUDIT_DEFAULT_BASELINE,
                 "dr": DR_DEFAULT_BASELINE,
-            }.get(_doc_kind(current), "BENCH_r05.json")
+            }.get(_doc_kind(current))
+            if baseline_path is None:
+                raise ValueError(
+                    "a default-mode bench.py document has no committed "
+                    "baseline: pass --baseline"
+                )
         baseline = load_bench(baseline_path)
     except (OSError, ValueError, json.JSONDecodeError) as e:
         print(json.dumps({"verdict": "error", "error": str(e)}), file=sys.stderr)

@@ -19,13 +19,14 @@ warmth) sharing one persistent compile cache directory:
 
 The document's headline ``value`` is the prewarmed first-query latency;
 ``cold_free_restart`` is 1.0 only when BOTH restart phases kept
-``compile.cold`` at zero (the gate's exact bar).  On a real TPU the
-cold compile is ~25s and the warm-restart first query is re-trace-only,
-so the first-query-over-steady ratio collapses toward 1; CPU test runs
-keep the same mechanism at millisecond scale.
+``compile.cold`` at zero (the gate's exact bar).  On a TPU the cold
+compile is seconds per shape (PERF.md) and the warm-restart first query
+is re-trace-only; CPU test runs keep the same mechanism at millisecond
+scale.
 
 Usage:
-  PINOT_TPU_COMPILE_CACHE_DIR is managed internally; just run
+  the children share ``<compile cache root>/restart_bench`` (wiped at
+  start) through JAX_COMPILATION_CACHE_DIR; just run
   python -m pinot_tpu.tools.restart_bench > RESTART_r16.json
   python -m pinot_tpu.tools.perf_gate RESTART_r16.json --baseline RESTART_r16.json
 """
@@ -37,7 +38,6 @@ import os
 import statistics
 import subprocess
 import sys
-import tempfile
 import time
 from typing import Any, Dict, List, Optional
 
@@ -125,7 +125,7 @@ def _spawn_phase(
     phase: str, cache_dir: str, workload_path: str, steady_n: int
 ) -> Dict[str, Any]:
     env = dict(os.environ)
-    env["PINOT_TPU_COMPILE_CACHE_DIR"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     proc = subprocess.run(
         [
             sys.executable,
@@ -165,7 +165,10 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     import jax
 
-    cache_dir = args.cache_dir or tempfile.mkdtemp(prefix="pinot_tpu_restart_")
+    from pinot_tpu.engine import compilecache
+
+    # the bench's own cache, so its cold phase is cold
+    cache_dir = args.cache_dir or compilecache.wiped_subroot("restart_bench")
     workload_path = os.path.join(cache_dir, "workload.json")
     cold = _spawn_phase("cold", cache_dir, workload_path, args.steady_n)
     restart = _spawn_phase("restart", cache_dir, workload_path, args.steady_n)

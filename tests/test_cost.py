@@ -629,7 +629,8 @@ def test_debug_capacity_rollup_and_dashboard(tmp_path):
 def _bench_doc():
     from pinot_tpu.tools.perf_gate import load_bench
 
-    return load_bench(os.path.join(REPO, "BENCH_r05.json"))
+    # a synthetic document: these tests hold the gate's logic, not a record
+    return load_bench(os.path.join(REPO, "tests", "bench_doc_synthetic.json"))
 
 
 def test_perf_gate_identical_run_passes():
@@ -680,10 +681,13 @@ def test_perf_gate_skips_on_config_mismatch():
     assert compare(base, other, allow_config_mismatch=True)["verdict"] == "fail"
 
 
-def test_perf_gate_cli_passes_against_committed_capture():
-    """The tier-1 smoke: the gate binary runs clean against the
-    committed capture compared with itself (same run => pass)."""
+def test_perf_gate_cli_passes_against_itself_and_needs_a_baseline(capsys):
+    """The tier-1 smoke: the gate binary runs clean on a document
+    compared with itself (same run => pass); a default-mode document has
+    no committed baseline, so leaving --baseline out is an input error."""
     from pinot_tpu.tools.perf_gate import main
 
-    path = os.path.join(REPO, "BENCH_r05.json")
+    path = os.path.join(REPO, "tests", "bench_doc_synthetic.json")
     assert main([path, "--baseline", path]) == 0
+    assert main([path]) == 2
+    assert "--baseline" in capsys.readouterr().err

@@ -1,4 +1,4 @@
-"""Schema-evolution default columns (VERDICT r3 #5).
+"""Schema-evolution default columns.
 
 Reference behavior: when a schema grows, segments built before the new
 column get a synthesized default-value column at load time

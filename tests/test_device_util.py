@@ -82,7 +82,7 @@ def test_device_query_counts_d2h_transfer_bytes(util_broker):
 
 # --------------------------------------------------- static cost analysis
 def test_normalize_cost_analysis_none_and_partial():
-    """The CPU-backend contract: None / empty / partial / list-shaped
+    """The CPU-backend contract: None / empty / partial / non-dict
     analysis outputs all degrade gracefully, never raise."""
     from pinot_tpu.engine.packing import _normalize_cost_analysis as norm
 
@@ -94,8 +94,7 @@ def test_normalize_cost_analysis_none_and_partial():
     # partial dict: flops without bytes (and vice versa) both survive
     assert norm({"flops": 10.0}) == {"flops": 10.0}
     assert norm({"bytes accessed": 64}) == {"bytesAccessed": 64.0}
-    # older backends wrap the dict in a list
-    assert norm([{"flops": 3, "bytes accessed": 9}]) == {
+    assert norm({"flops": 3, "bytes accessed": 9}) == {
         "flops": 3.0,
         "bytesAccessed": 9.0,
     }
@@ -402,29 +401,36 @@ def test_profiler_endpoints_and_sampler_bracket(util_broker, tmp_path):
 
 
 # ----------------------------------------------------- platform peaks
-def test_platform_peaks_unknown_cpu_and_env_override(monkeypatch):
+def test_platform_peaks_cpu_none_known_tpu_declared_unknown_tpu_raises(monkeypatch):
+    from pinot_tpu.utils import platform
     from pinot_tpu.utils.platform import platform_peaks
 
     out = platform_peaks(refresh=True)
     # CPU test mesh: no declared peak — the roofline must say
     # "unavailable", not invent a number
     assert out["peakFlopsPerSec"] is None and out["peakBytesPerSec"] is None
-    assert out["platform"] == "cpu"
+    assert out["platform"] == "cpu" and out["source"] == "unknown"
 
-    monkeypatch.setenv("PINOT_TPU_PEAK_FLOPS", "2e12")
-    monkeypatch.setenv("PINOT_TPU_PEAK_HBM_BPS", "8e11")
-    env_out = platform_peaks(refresh=True)
-    assert env_out["source"] == "env"
-    assert env_out["peakFlopsPerSec"] == 2e12
-    assert env_out["peakBytesPerSec"] == 8e11
+    class _Dev:
+        platform = "tpu"
 
-    # junk overrides must not break metric scrapes
-    monkeypatch.setenv("PINOT_TPU_PEAK_FLOPS", "banana")
-    junk = platform_peaks(refresh=True)
-    assert junk["peakFlopsPerSec"] != "banana"
-    monkeypatch.delenv("PINOT_TPU_PEAK_FLOPS")
-    monkeypatch.delenv("PINOT_TPU_PEAK_HBM_BPS")
-    platform_peaks(refresh=True)  # restore the cached no-env state
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    import jax
+
+    try:
+        monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("TPU v5 lite")])
+        v5e = platform_peaks(refresh=True)
+        assert v5e["source"] == "declared"
+        assert (v5e["peakFlopsPerSec"], v5e["peakBytesPerSec"]) == (197e12, 819e9)
+        # a TPU the table does not know is an error, not a None peak
+        monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("TPU v99")])
+        with pytest.raises(LookupError, match="TPU v99"):
+            platform_peaks(refresh=True)
+    finally:
+        monkeypatch.undo()
+        platform._peaks_cache = None  # the next caller sees the real CPU device
 
 
 # ------------------------------------------------- roofline consistency
@@ -464,10 +470,21 @@ def test_plan_roofline_consistent_with_phase_timers(util_broker):
 
 
 def test_roofline_fractions_against_declared_peaks(monkeypatch, util_broker):
-    """With peaks declared (env escape hatch), the roofline fraction is
-    the best-utilized resource's achieved/peak ratio."""
-    monkeypatch.setenv("PINOT_TPU_PEAK_FLOPS", "1e15")
-    monkeypatch.setenv("PINOT_TPU_PEAK_HBM_BPS", "1e12")
+    """With peaks declared, the roofline fraction is the best-utilized
+    resource's achieved/peak ratio."""
+    from pinot_tpu.utils import platform
+
+    monkeypatch.setattr(
+        platform,
+        "_peaks_cache",
+        {
+            "platform": "tpu",
+            "deviceKind": "test",
+            "peakFlopsPerSec": 1e15,
+            "peakBytesPerSec": 1e12,
+            "source": "declared",
+        },
+    )
     broker = util_broker
     server = broker.local_servers[0]
     for _ in range(2):
@@ -675,7 +692,7 @@ def test_perf_gate_serving_config_and_kind_mismatch_skip():
 
     # mixed kinds (default bench vs serving mode): nothing to compare
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    default_doc = load_bench(os.path.join(repo, "BENCH_r05.json"))
+    default_doc = load_bench(os.path.join(repo, "tests", "bench_doc_synthetic.json"))
     out2 = compare(default_doc, base)
     assert out2["verdict"] == "skipped"
     assert "kind" in out2["reason"]

@@ -372,3 +372,50 @@ def test_host_fallback_factorization_branches_match_oracle(monkeypatch):
         f"SELECT count(*), sum(m) FROM big WHERE a = {needle} GROUP BY a, b, c TOP 10",
     )
     assert got == want
+
+
+def test_row_counts_cross_the_segment_axis_as_integers():
+    """A float32 sum stops being exact at 2^24, so 16 segments of 8.4M
+    rows answered count(*) one short on the chip.  Every row-count state
+    (num_docs, count, avg's count, percentile histograms) must leave the
+    single-segment kernel in the integer row-count dtype, grouped or not,
+    so that the merges over segments, chunks and chips are integer sums."""
+    import jax
+    import jax.numpy as jnp
+
+    from pinot_tpu.engine import config
+    from pinot_tpu.engine.context import get_table_context
+    from pinot_tpu.engine.device import segment_arrays, stage_segments
+    from pinot_tpu.engine.kernel import make_single_segment_kernel
+    from pinot_tpu.engine.plan import build_query_inputs, build_static_plan
+    from pinot_tpu.tools.datagen import synthetic_lineitem_segment
+
+    cdt = jnp.dtype(config.row_count_dtype())
+    assert jnp.issubdtype(cdt, jnp.integer)
+    seg = synthetic_lineitem_segment(256, seed=3, name="cnt0")
+    for group_by in ("", " GROUP BY l_returnflag TOP 10"):
+        request = optimize_request(
+            parse_pql(
+                "SELECT count(*), avg(l_quantity), percentile50(l_quantity), "
+                "sum(l_quantity) FROM lineitem WHERE l_quantity > 10" + group_by
+            )
+        )
+        ctx = get_table_context([seg])
+        needed = sorted(set(request.referenced_columns()))
+        staged = stage_segments([seg], needed, ctx=ctx)
+        plan = build_static_plan(request, ctx, staged)
+        assert plan.on_device
+        q = build_query_inputs(request, plan, ctx, staged)
+        one = lambda tree: jax.tree_util.tree_map(lambda x: x[0], tree)
+        out = jax.eval_shape(
+            make_single_segment_kernel(plan),
+            one(segment_arrays(staged, needed)),
+            one(q),
+        )
+        prefix = "gb_" if group_by else "agg_"
+        assert out["num_docs"].dtype == cdt
+        assert out[f"{prefix}0"].dtype == cdt  # count(*)
+        avg_sum, avg_count = out[f"{prefix}1"]
+        assert avg_count.dtype == cdt and avg_sum.dtype == config.float_dtype()
+        assert out[f"{prefix}2"].dtype == cdt  # percentile histogram
+        assert out[f"{prefix}3"].dtype == config.float_dtype()  # a sum stays float

@@ -200,7 +200,7 @@ def test_configured_inverted_index_columns_warm_at_load(tmp_path):
         tdm.release_segments(acquired)
 
 
-# -- compressed containers (VERDICT r3 #6) ------------------------------
+# -- compressed containers ----------------------------------------------
 
 
 def test_compressed_blocks_roundtrip_clustered():

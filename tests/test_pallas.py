@@ -5,10 +5,9 @@ import pytest
 
 import jax.numpy as jnp
 
-from pinot_tpu.engine.pallas_kernels import PALLAS_AVAILABLE, fused_filtered_groupby_sums
+from pinot_tpu.engine.pallas_kernels import fused_filtered_groupby_sums
 
 
-@pytest.mark.skipif(not PALLAS_AVAILABLE, reason="pallas not importable")
 def test_fused_groupby_matches_numpy():
     rng = np.random.default_rng(0)
     n = 5000
@@ -42,7 +41,6 @@ def test_fused_groupby_matches_numpy():
     np.testing.assert_allclose(np.asarray(sums), want_sums, rtol=1e-5)
 
 
-@pytest.mark.skipif(not PALLAS_AVAILABLE, reason="pallas not importable")
 def test_fused_groupby_multi_value_columns():
     rng = np.random.default_rng(3)
     n = 1000
@@ -70,7 +68,6 @@ def test_fused_groupby_multi_value_columns():
         np.testing.assert_allclose(np.asarray(sums[i]), want, rtol=1e-5)
 
 
-@pytest.mark.skipif(not PALLAS_AVAILABLE, reason="pallas not importable")
 def test_value_state_counts_pallas_matches_xla():
     """The Pallas occupancy histogram (VMEM-resident accumulator)
     matches the XLA factored contraction bit-for-bit, for K both a
@@ -92,7 +89,7 @@ def test_value_state_counts_pallas_matches_xla():
         idx_np[rng.random(n) < 0.05] = K  # dropped sentinel entries
         idx = jnp.asarray(idx_np)
         a = np.asarray(_value_state_counts(idx, K))
-        b = np.asarray(_value_state_counts_pallas(idx, K))
+        b = np.asarray(_value_state_counts_pallas(idx, K, interpret=True))
         assert a.shape == b.shape == (K,)
         assert np.array_equal(a, b), K
         # ground truth
@@ -102,5 +99,5 @@ def test_value_state_counts_pallas_matches_xla():
     K = 1024
     batch = jnp.asarray(rng.integers(0, K, size=(3, 4096)).astype(np.int32))
     va = np.asarray(jax.vmap(lambda i: _value_state_counts(i, K))(batch))
-    vb = np.asarray(jax.vmap(lambda i: _value_state_counts_pallas(i, K))(batch))
+    vb = np.asarray(jax.vmap(lambda i: _value_state_counts_pallas(i, K, interpret=True))(batch))
     assert np.array_equal(va, vb)

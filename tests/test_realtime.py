@@ -486,7 +486,7 @@ def test_columnar_transport_error_on_known_columnar_reraises():
         def fetch_columns(self, partition, offset):
             self.calls += 1
             if self.calls == 2:
-                raise OSError("tunnel hiccup")
+                raise OSError("connection hiccup")
             return _block(3, start=offset), 3, offset + 3
 
         def fetch(self, partition, offset, max_rows):

@@ -17,7 +17,6 @@ def test_serving_curve_smoke():
     env = dict(os.environ)
     env.update(
         JAX_PLATFORMS="cpu",
-        PINOT_TPU_BENCH_FORCE_CPU="1",
         PINOT_TPU_BENCH_MODE="serving",
         PINOT_TPU_BENCH_SEGMENTS="1",
         PINOT_TPU_BENCH_ROWS_PER_SEGMENT="60000",

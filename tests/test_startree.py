@@ -239,8 +239,7 @@ def test_hll_in_star_tree(tmp_path):
 def test_adevents_hll_cube_groupby_matches_engine():
     """The north-star HLL group-by answered from the star-tree cube
     (campaign split, HLL(user_id) pre-agg): identical to the engine
-    path, independent of row count (NORTHSTAR_HLL.json startree
-    entry)."""
+    path, independent of row count."""
     import json
 
     from pinot_tpu.startree.builder import StarTreeBuilderConfig, build_star_tree

@@ -37,19 +37,12 @@ PQL = "SELECT sum(metInt), count(*) FROM warmT GROUP BY dimStr TOP 5"
 
 @pytest.fixture
 def cache_isolation():
-    """Persistent-cache tests re-point jax's global compilation-cache
-    config; restore it (and the module's idempotence guard) so the rest
-    of the suite keeps its default no-cache behavior."""
-    import jax
-
-    prev = jax.config.jax_compilation_cache_dir
-    compilecache._reset_for_tests()
+    """Persistent-cache tests re-point the compile cache; put the
+    session's own root (tests/conftest.py) back afterwards."""
+    prev = compilecache.cache_root()
     yield
     compilecache._reset_for_tests()
-    try:
-        jax.config.update("jax_compilation_cache_dir", prev)
-    except Exception:
-        pass
+    compilecache.configure_jax_cache(root=prev)
 
 
 def _meter(server, name):
@@ -148,13 +141,44 @@ def test_fingerprint_every_axis_separates_keys():
         assert not compilecache.known_plan("abcd1234", fp_b, root=root)
 
 
-def test_cache_disabled_without_env(monkeypatch):
-    monkeypatch.delenv("PINOT_TPU_COMPILE_CACHE_DIR", raising=False)
-    assert compilecache.cache_root() is None
-    assert not compilecache.enabled()
-    assert compilecache.configure_jax_cache() is None
-    assert not compilecache.record_plan("d1")
-    assert not compilecache.known_plan("d1")
+def test_cache_root_defaults_to_checkout_and_env_places_it(
+    tmp_path, monkeypatch, cache_isolation
+):
+    """Unset, the cache lives at ``<checkout>/.jax_cache`` (a fixed path
+    from the package's own location); ``JAX_COMPILATION_CACHE_DIR``
+    places it from outside, and then jax already holds the directory —
+    this module must not re-point it."""
+    import jax
+
+    import pinot_tpu
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(pinot_tpu.__file__)))
+    compilecache._reset_for_tests()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compilecache.cache_root() == os.path.join(checkout, ".jax_cache")
+
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    assert compilecache.cache_root() == placed
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: (updates.append(k), real_update(k, v))
+    )
+    assert compilecache.configure_jax_cache() == placed
+    assert "jax_compilation_cache_dir" not in updates
+    assert "jax_persistent_cache_min_compile_time_secs" in updates
+    # the ledger follows: <that dir>/plans/, and nowhere else
+    fp = compilecache.topology_fingerprint()
+    assert compilecache.record_plan("d1a2b3c4", fp)
+    assert os.listdir(placed) == ["plans"]
+    assert compilecache.known_plan("d1a2b3c4", fp)
+
+    # an explicit root= (tests, harnesses) wins over the environment
+    own = str(tmp_path / "own")
+    assert compilecache.configure_jax_cache(root=own) == own
+    assert compilecache.cache_root() == own
+    assert "jax_compilation_cache_dir" in updates
 
 
 # ------------------------------------------------------------------
@@ -167,7 +191,7 @@ def test_persistent_hit_classification_across_restart(
     server over the same cache root classifies its first launch
     ``persistentHit`` with ``compile.cold == 0``, and EXPLAIN reports
     the r16 compile states (cold -> persistent -> warm) along the way."""
-    monkeypatch.setenv("PINOT_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+    compilecache.configure_jax_cache(root=str(tmp_path))
 
     broker1 = single_server_broker("warmT", _build_segments(), pipeline=True)
     s1 = broker1.local_servers[0]
@@ -213,7 +237,7 @@ def test_prewarm_compiles_ahead_and_reports_readiness(
     staging BEFORE any query: the first serving query is classified
     ``compile.prewarmed`` (never cold), and the warming flag flips
     synchronously on request and clears when the pass drains."""
-    monkeypatch.setenv("PINOT_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+    compilecache.configure_jax_cache(root=str(tmp_path))
 
     # generation 1 records the workload shape the fleet feed serves
     broker1 = single_server_broker("warmT", _build_segments(), pipeline=True)
