@@ -55,7 +55,7 @@ from pinot_tpu.broker.querylog import SlowQueryLog
 from pinot_tpu.broker.routing import RoutingTableProvider
 from pinot_tpu.broker.time_boundary import TimeBoundaryService
 from pinot_tpu.utils.metrics import BrokerMetrics, prometheus_text
-from pinot_tpu.utils.trace import NULL_TRACE, TraceContext, merge_scope
+from pinot_tpu.utils.trace import NULL_TRACE, TraceContext, boundary, measured, merge_scope
 
 logger = logging.getLogger(__name__)
 
@@ -288,58 +288,73 @@ class BrokerRequestHandler:
         return f"{self._id_prefix}-{n}"
 
     # ------------------------------------------------------------------
+    def open_trace(self) -> Tuple[str, TraceContext]:
+        """A request id and its span tree for a front end that times
+        boundaries outside ``handle_pql`` (the HTTP handler's
+        ``httpTotal``): enabled when the tail sampler is armed, as
+        ``handle_pql`` would decide it."""
+        request_id = self._next_request_id()
+        if self.tail.armed:
+            return request_id, TraceContext(enabled=True, scope=self.name, trace_id=request_id)
+        return request_id, NULL_TRACE
+
     def handle_pql(
         self,
         pql: str,
         trace: bool = False,
         debug_options: Optional[Dict[str, str]] = None,
         timeout_ms: Optional[float] = None,
+        request_id: Optional[str] = None,
+        trace_ctx: Optional[TraceContext] = None,
     ) -> BrokerResponse:
         t0 = time.perf_counter()
         self.metrics.meter("queries").mark()
-        request_id = self._next_request_id()
+        if request_id is None:
+            request_id = self._next_request_id()
         # with the tail sampler armed (default), EVERY query carries the
         # lightweight span tree so the retention decision can happen at
         # completion; with sampling off (PINOT_TPU_TAIL_TRACE=0),
         # untraced queries share the NULL context — no span allocation
-        # anywhere on the handle path (the PR 4 zero-overhead contract)
-        ctx = (
-            TraceContext(enabled=True, scope=self.name, trace_id=request_id)
-            if trace or self.tail.armed
-            else NULL_TRACE
-        )
+        # anywhere on the handle path (the PR 4 zero-overhead contract).
+        # A front end that opened the tree itself (open_trace) owns the
+        # root; the spans outside ``query`` hang under it.
+        rooted = trace_ctx is not None and trace_ctx.enabled
+        if rooted:
+            ctx = trace_ctx
+        elif trace or self.tail.armed:
+            ctx = TraceContext(enabled=True, scope=self.name, trace_id=request_id)
+        else:
+            ctx = NULL_TRACE
         resp: Optional[BrokerResponse] = None
         request = None
         plan_digest = ""
         plan_summary = ""
-        with ctx.span("query", requestId=request_id, pql=pql[:200]):
-            t_parse = time.perf_counter()
-            try:
-                with ctx.span("parse"):
+        with boundary("query", ctx, requestId=request_id, pql=pql[:200]):
+            parse = boundary("parse", ctx, self.metrics.timer("phase.parse"))
+            with parse:
+                try:
                     request = parse_pql(pql)
                     if debug_options:
                         request.debug_options = dict(debug_options)
                     request = optimize_request(request)
-                from pinot_tpu.engine.plandigest import (
-                    plan_shape_digest,
-                    plan_shape_summary,
-                )
+                    from pinot_tpu.engine.plandigest import (
+                        plan_shape_digest,
+                        plan_shape_summary,
+                    )
 
-                # the literal-erased shape digest rides EVERY response
-                # (cross-links /debug/queries -> /debug/plans/workload)
-                plan_digest = plan_shape_digest(request)
-                plan_summary = plan_shape_summary(request)
-                if request.explain:
-                    self.metrics.meter("explain.queries").mark()
-            except PqlParseError as e:
-                # InvalidQueryOptionsError subclasses this; internal
-                # ValueErrors now propagate instead of masquerading as
-                # client parse errors (ADVICE r1)
-                resp = BrokerResponse(
-                    exceptions=[QueryException(ErrorCode.PQL_PARSING, str(e))]
-                )
-            parse_ms = (time.perf_counter() - t_parse) * 1000
-            self.metrics.timer("phase.parse").update(parse_ms)
+                    # the literal-erased shape digest rides EVERY response
+                    # (cross-links /debug/queries -> /debug/plans/workload)
+                    plan_digest = plan_shape_digest(request)
+                    plan_summary = plan_shape_summary(request)
+                    if request.explain:
+                        self.metrics.meter("explain.queries").mark()
+                except PqlParseError as e:
+                    # InvalidQueryOptionsError subclasses this; internal
+                    # ValueErrors now propagate instead of masquerading as
+                    # client parse errors (ADVICE r1)
+                    resp = BrokerResponse(
+                        exceptions=[QueryException(ErrorCode.PQL_PARSING, str(e))]
+                    )
             if resp is None:
                 request.enable_trace = ctx.enabled
                 resp = self.handle_request(
@@ -360,6 +375,32 @@ class BrokerRequestHandler:
         resp.request_id = request_id
         resp.time_used_ms = (time.perf_counter() - t0) * 1000
         self.metrics.timer("queryTotal").update(resp.time_used_ms)
+        # the four planes that record the query run before the reply is
+        # written, so they are a boundary of their own.  Its span hangs
+        # under a front end's root; a direct call has none to hang it
+        # under and keeps the timer and the annotation.
+        with boundary("bookkeeping", ctx if rooted else None,
+                      self.metrics.timer("phase.bookkeeping")):
+            self._record_query(
+                pql, trace, ctx, request, request_id, resp, plan_digest,
+                plan_summary, parse.ms,
+            )
+        return resp
+
+    def _record_query(
+        self,
+        pql: str,
+        trace: bool,
+        ctx: TraceContext,
+        request: Optional[BrokerRequest],
+        request_id: str,
+        resp: BrokerResponse,
+        plan_digest: str,
+        plan_summary: str,
+        parse_ms: float,
+    ) -> None:
+        """``planstats.record``, ``tail.observe`` (and the span-tree
+        merge it may ask for), ``slo.observe``, ``querylog.observe``."""
         shed_q = any(
             e.error_code == ErrorCode.TOO_MANY_REQUESTS
             for e in resp.exceptions
@@ -421,6 +462,9 @@ class BrokerRequestHandler:
                     plan_digest=plan_digest,
                     summary=plan_summary,
                 )
+                # for the front end that owns the root: whether to hand
+                # the finished tree to ``tail.complete``
+                resp._tail_reason = tail_reason
         # per-table SLO counters (utils/slo.py): burn rates evaluate on
         # the history cadence over exactly these cumulative series
         self.slo.observe(
@@ -492,7 +536,6 @@ class BrokerRequestHandler:
                     "codes": [e.error_code for e in resp.exceptions],
                 },
             )
-        return resp
 
     def _history_tick(self, now: float) -> None:
         """Runs on every history sample (the recorder's cadence): SLO
@@ -589,49 +632,43 @@ class BrokerRequestHandler:
             resp.request_id = request_id
             resp._server_traces = getattr(resp, "_server_traces", [])
             return resp
-        t_route = time.perf_counter()
-        try:
-            with ctx.span("route", table=table):
-                physical = self._physical_tables(table, pql)
-                if not physical:
-                    return BrokerResponse(
-                        exceptions=[
-                            QueryException(
-                                ErrorCode.BROKER_RESOURCE_MISSING, f"no routing for table {table}"
-                            )
-                        ],
-                        request_id=request_id,
-                    )
+        # timed even on the no-routing return: a silent phase.route
+        # series during an external-view refill would hide exactly the
+        # period when route behavior changed
+        with boundary("route", ctx, self.metrics.timer("phase.route"), table=table):
+            physical = self._physical_tables(table, pql)
+            if not physical:
+                return BrokerResponse(
+                    exceptions=[
+                        QueryException(
+                            ErrorCode.BROKER_RESOURCE_MISSING, f"no routing for table {table}"
+                        )
+                    ],
+                    request_id=request_id,
+                )
 
-                exceptions: List[QueryException] = []
-                batches: List[_Batch] = []
-                routing_gap = False
-                for phys_table, sub_pql in physical:
-                    routing = self.routing.find_servers(phys_table, health=self.health)
-                    if not routing:
-                        # None (table unknown) or {} (external view refilling
-                        # after a restart): either way this physical table is
-                        # currently unanswerable — surface a retriable error
-                        # rather than silently dropping it from the result
-                        routing_gap = True
-                        exceptions.append(
-                            QueryException(
-                                ErrorCode.BROKER_RESOURCE_MISSING,
-                                f"no servers currently serving table {phys_table}",
-                            )
+            exceptions: List[QueryException] = []
+            batches: List[_Batch] = []
+            routing_gap = False
+            for phys_table, sub_pql in physical:
+                routing = self.routing.find_servers(phys_table, health=self.health)
+                if not routing:
+                    # None (table unknown) or {} (external view refilling
+                    # after a restart): either way this physical table is
+                    # currently unanswerable — surface a retriable error
+                    # rather than silently dropping it from the result
+                    routing_gap = True
+                    exceptions.append(
+                        QueryException(
+                            ErrorCode.BROKER_RESOURCE_MISSING,
+                            f"no servers currently serving table {phys_table}",
                         )
-                        continue
-                    for server, segments in routing.items():
-                        batches.append(
-                            _Batch(phys_table, sub_pql, segments, server, order=len(batches))
-                        )
-        finally:
-            # timed even on the no-routing return: a silent phase.route
-            # series during an external-view refill would hide exactly
-            # the period when route behavior changed
-            self.metrics.timer("phase.route").update(
-                (time.perf_counter() - t_route) * 1000
-            )
+                    )
+                    continue
+                for server, segments in routing.items():
+                    batches.append(
+                        _Batch(phys_table, sub_pql, segments, server, order=len(batches))
+                    )
 
         # AIMD pre-scatter overload check: when EVERY server covering the
         # table is past its congestion window, scattering could only end
@@ -648,16 +685,16 @@ class BrokerRequestHandler:
                     request_id=request_id,
                 )
 
-        t_sg = time.perf_counter()
-        with ctx.span("scatterGather", batches=len(batches)):
+        scatter = boundary("scatterGather", ctx, self.metrics.timer("scatterGather"),
+                           batches=len(batches))
+        with scatter:
             parts, sg = self._scatter_gather(
                 request, batches, timeout_ms, table, request_id, ctx
             )
         exceptions.extend(sg["exceptions"])
-        sg_ms = (time.perf_counter() - t_sg) * 1000
-        self.metrics.timer("scatterGather").update(sg_ms)
 
-        t_red = time.perf_counter()
+        reduce = boundary("reduce", ctx, self.metrics.timer("reduce"), parts=len(parts))
+        reduce.start()
         for p in parts:
             for code, msg in p.exceptions:
                 exceptions.append(QueryException(code, msg))
@@ -673,10 +710,8 @@ class BrokerRequestHandler:
             # reduce (servers executed nothing, partials are empty)
             resp = BrokerResponse(exceptions=exceptions)
         else:
-            with ctx.span("reduce", parts=len(parts)):
-                resp = reduce_to_response(request, parts, exceptions)
-        red_ms = (time.perf_counter() - t_red) * 1000
-        self.metrics.timer("reduce").update(red_ms)
+            resp = reduce_to_response(request, parts, exceptions)
+        reduce.stop()
         resp.request_id = request_id
         # event-time freshness: now − the stalest realtime watermark
         # that contributed to this answer (server stamps min-combine
@@ -732,8 +767,8 @@ class BrokerRequestHandler:
         # slow-query log records (not serialized into the response)
         resp._server_traces = sg["server_traces"]
         resp.phase_ms = {
-            "scatterGather": round(sg_ms, 3),
-            "reduce": round(red_ms, 3),
+            "scatterGather": round(scatter.ms, 3),
+            "reduce": round(reduce.ms, 3),
         }
         # replica-divergence sampling hook (utils/audit.py): a cheap
         # counter for the non-sampled majority, a bounded background
@@ -853,8 +888,12 @@ class BrokerRequestHandler:
             # quota that amplification would starve first-try queries
             hedge_delay_s = None
 
-        # future -> (batch, server, is_hedge, sent_at, wall_sent_ms)
-        pending: Dict[concurrent.futures.Future, Tuple[_Batch, str, bool, float, float]] = {}
+        # future -> (batch, server, is_hedge, sent_at, wall_sent_ms,
+        # the attempt's span id, reserved at send so that the codec
+        # spans of the pool thread can name it as their parent)
+        pending: Dict[
+            concurrent.futures.Future, Tuple[_Batch, str, bool, float, float, Optional[str]]
+        ] = {}
         all_batches: List[_Batch] = list(batches)
         delayed: List[Tuple[float, _Batch]] = []  # (fire_time, batch) backoff queue
         open_lineages = len(batches)  # batches neither completed nor superseded
@@ -864,12 +903,13 @@ class BrokerRequestHandler:
 
         def attempt_span(
             batch: _Batch, server: str, hedge: bool, sent_at: float,
-            wall_sent: float, status: str, **tags
+            wall_sent: float, aid: Optional[str], status: str, **tags
         ) -> Optional[str]:
             return ctx.add(
                 "serverAttempt",
                 (time.monotonic() - sent_at) * 1000.0,
                 start_ms=wall_sent,
+                span_id=aid,
                 server=server,
                 hedge=hedge,
                 reissues=batch.reissues,
@@ -880,6 +920,12 @@ class BrokerRequestHandler:
 
         def submit(batch: _Batch, server: str, hedge: bool = False) -> None:
             now = time.monotonic()
+            wall_sent = time.time() * 1000.0
+            aid = ctx.reserve()
+            # the attempt made ready, up to the hand-over to the pool
+            # (where its poolQueue starts): first child of serverAttempt
+            readying = boundary("attemptSubmit", ctx, self.metrics.timer("phase.attemptSubmit"),
+                                parent=aid).start()
             remaining_ms = max(1.0, (deadline - now) * 1000.0)
             servers_queried.add(server)
             # half-open probe claim: a penalty-boxed server chosen after
@@ -900,6 +946,8 @@ class BrokerRequestHandler:
                 batch.table, batch.segments, batch.excluded
             ):
                 attempt_ms = remaining_ms / 2.0
+            extra = extra_fn(server) if extra_fn is not None else None
+            readying.stop()
             fut = self._pool.submit(
                 self._send_one,
                 server,
@@ -911,7 +959,10 @@ class BrokerRequestHandler:
                 remaining_ms,
                 attempt_ms,
                 request_id,
-                extra_fn(server) if extra_fn is not None else None,
+                extra,
+                ctx=ctx,
+                parent=aid,
+                t_submit=time.perf_counter(),
             )
             # AIMD window accounting: the done-callback observes EVERY
             # attempt outcome exactly once — including attempts that
@@ -924,7 +975,7 @@ class BrokerRequestHandler:
             batch.inflight += 1
             if not hedge:
                 batch.first_sent = now
-            pending[fut] = (batch, server, hedge, now, time.time() * 1000.0)
+            pending[fut] = (batch, server, hedge, now, wall_sent, aid)
 
         def fail_batch(batch: _Batch) -> None:
             nonlocal open_lineages
@@ -1006,7 +1057,7 @@ class BrokerRequestHandler:
             # arm hedges on stragglers
             next_hedge = math.inf
             if hedge_delay_s is not None:
-                for batch, server, hedge, _sent, _wall in list(pending.values()):
+                for batch, server, hedge, _sent, _wall, _aid in list(pending.values()):
                     if hedge or batch.done or batch.hedged:
                         continue
                     fire = batch.first_sent + hedge_delay_s
@@ -1045,7 +1096,7 @@ class BrokerRequestHandler:
                 return_when=concurrent.futures.FIRST_COMPLETED,
             )
             for fut in done:
-                batch, server, hedge, sent_at, wall_sent = pending.pop(fut)
+                batch, server, hedge, sent_at, wall_sent, aid = pending.pop(fut)
                 batch.inflight -= 1
                 try:
                     result = fut.result()
@@ -1057,7 +1108,7 @@ class BrokerRequestHandler:
                     self.health.record_failure(server)
                     logger.warning("server %s failed: %s", server, e)
                     attempt_span(
-                        batch, server, hedge, sent_at, wall_sent,
+                        batch, server, hedge, sent_at, wall_sent, aid,
                         "error", error=f"{type(e).__name__}: {e}"[:200],
                     )
                     batch.errors.append(
@@ -1077,7 +1128,7 @@ class BrokerRequestHandler:
                     # draining): treat as failover-able, not as data
                     self.health.record_failure(server)
                     attempt_span(
-                        batch, server, hedge, sent_at, wall_sent,
+                        batch, server, hedge, sent_at, wall_sent, aid,
                         "refused", errorCode=result.exceptions[0][0],
                     )
                     batch.errors.append(
@@ -1087,6 +1138,9 @@ class BrokerRequestHandler:
                         failover(batch)
                     continue
                 self.health.record_success(server)
+                # the pool thread had the result -> this loop has it
+                measured("gatherWake", (time.perf_counter() - result._t_done) * 1000.0, ctx,
+                         self.metrics.timer("phase.gatherWake"), parent=aid)
                 # per-ATTEMPT latency (a winning hedge measures from its
                 # own send, not the primary's — else the percentile that
                 # arms future hedges inflates itself)
@@ -1098,10 +1152,10 @@ class BrokerRequestHandler:
                     # attempt still shows on the waterfall as the slower
                     # twin, but its data (and trace) is discarded
                     attempt_span(
-                        batch, server, hedge, sent_at, wall_sent, "hedgeLoser"
+                        batch, server, hedge, sent_at, wall_sent, aid, "hedgeLoser"
                     )
                     continue
-                aid = attempt_span(batch, server, hedge, sent_at, wall_sent, "ok")
+                aid = attempt_span(batch, server, hedge, sent_at, wall_sent, aid, "ok")
                 if result.trace:
                     # snapshot: reduce later merges parts IN PLACE, which
                     # would fold every later part's spans into the first
@@ -1150,15 +1204,15 @@ class BrokerRequestHandler:
                         self.metrics.meter("failoverRetries").mark()
                         submit(child, alt_server)
                 # best effort: free the loser's queued twin if it never started
-                for other, (ob, _osrv, _oh, _osent, _owall) in list(pending.items()):
+                for other, (ob, _osrv, _oh, _osent, _owall, _oaid) in list(pending.items()):
                     if ob is batch:
                         other.cancel()
 
         # deadline expired (or queue drained): account every lineage that
         # never completed
-        for fut, (pbatch, pserver, _h, _sent, _wall) in pending.items():
+        for fut, (pbatch, pserver, _h, _sent, _wall, _aid) in pending.items():
             if not pbatch.done and not fut.cancel():
-                attempt_span(pbatch, pserver, _h, _sent, _wall, "timeout")
+                attempt_span(pbatch, pserver, _h, _sent, _wall, _aid, "timeout")
                 # an attempt for a still-open lineage ran past the
                 # deadline: the circuit breaker must learn about hung
                 # servers too, or a blackholed replica would stay CLOSED
@@ -1281,6 +1335,9 @@ class BrokerRequestHandler:
         attempt_timeout_ms: Optional[float],
         request_id: str,
         join: Optional[Dict[str, Any]] = None,
+        ctx: Optional[TraceContext] = None,
+        parent: Optional[str] = None,
+        t_submit: Optional[float] = None,
     ) -> IntermediateResult:
         # timeout_ms is the REMAINING deadline budget at (re-)issue time,
         # already clamped by handle_request — the server's scheduler pins
@@ -1290,20 +1347,32 @@ class BrokerRequestHandler:
         # a hung replica surfaces as a transport timeout early enough to
         # fail over (the server keeps the full budget — wasted work at
         # worst, not an early server-side timeout).
+        # ``ctx``/``parent``: the request's tree and the serverAttempt
+        # span this pool thread's spans hang under.  ``t_submit``: when
+        # the gather loop handed this attempt to the pool.
+        if t_submit is not None:
+            measured("poolQueue", (time.perf_counter() - t_submit) * 1000.0, ctx,
+                     self.metrics.timer("phase.poolQueue"), parent=parent)
         address = self.server_addresses[server]
-        payload = serialize_instance_request(
-            request_id,
-            pql,
-            table,
-            segments,
-            timeout_ms,
-            trace=trace,
-            debug_options=debug_options,
-            join=join,
-        )
+        with boundary("serializeRequest", ctx, self.metrics.timer("phase.serializeRequest"),
+                      parent=parent):
+            payload = serialize_instance_request(
+                request_id,
+                pql,
+                table,
+                segments,
+                timeout_ms,
+                trace=trace,
+                debug_options=debug_options,
+                join=join,
+            )
         wait_ms = timeout_ms if attempt_timeout_ms is None else attempt_timeout_ms
         reply = self.transport.request(address, payload, timeout=wait_ms / 1000.0)
-        return deserialize_result(reply)
+        with boundary("deserializeResult", ctx, self.metrics.timer("phase.deserializeResult"),
+                      parent=parent):
+            result = deserialize_result(reply)
+        result._t_done = time.perf_counter()  # for the gather loop's gatherWake
+        return result
 
 
 # ---------------------------------------------------------------------------
@@ -1472,51 +1541,76 @@ class BrokerHttpServer:
                             }
                         )
                     return self._respond({"error": "not found"}, 404)
-                qs = parse_qs(url.query)
-                pql = (qs.get("pql") or qs.get("bql") or [""])[0]
-                trace = (qs.get("trace") or ["false"])[0].lower() == "true"
-                debug = _parse_debug_options((qs.get("debugOptions") or [""])[0])
-                try:
+
+                def read():
+                    qs = parse_qs(url.query)
+                    pql = (qs.get("pql") or qs.get("bql") or [""])[0]
+                    trace = (qs.get("trace") or ["false"])[0].lower() == "true"
+                    debug = _parse_debug_options((qs.get("debugOptions") or [""])[0])
                     timeout_ms = _parse_timeout((qs.get("timeoutMs") or [""])[0])
-                except InvalidTimeoutError as e:
-                    return self._invalid_timeout(e)
-                resp = broker.handle_pql(
-                    pql,
-                    trace=trace,
-                    debug_options=debug,
-                    timeout_ms=timeout_ms,
-                )
-                self._respond(resp.to_json())
+                    return pql, trace, debug, timeout_ms
+
+                self._query(read)
 
             def do_POST(self):
-                n = int(self.headers.get("Content-Length", "0"))
-                try:
+                def read():
+                    n = int(self.headers.get("Content-Length", "0"))
                     body = json.loads(self.rfile.read(n) or b"{}")
-                except json.JSONDecodeError as e:
-                    return self._respond(
-                        {"exceptions": [{"errorCode": ErrorCode.JSON_PARSING, "message": str(e)}]}
-                    )
-                pql = body.get("pql") or body.get("bql") or ""
-                debug = body.get("debugOptions") or ""
-                if isinstance(debug, dict):
-                    debug = {str(k): str(v) for k, v in debug.items()}
-                else:
-                    # the reference's "k=v;k2=v2" string form; any other
-                    # JSON type is ignored rather than crashing the handler
-                    debug = _parse_debug_options(debug if isinstance(debug, str) else "")
-                try:
-                    timeout_ms = _parse_timeout(body.get("timeoutMs"))
-                except InvalidTimeoutError as e:
-                    return self._invalid_timeout(e)
-                resp = broker.handle_pql(
-                    pql,
-                    trace=bool(body.get("trace")),
-                    debug_options=debug,
-                    timeout_ms=timeout_ms,
-                )
-                self._respond(resp.to_json())
+                    pql = body.get("pql") or body.get("bql") or ""
+                    debug = body.get("debugOptions") or ""
+                    if isinstance(debug, dict):
+                        debug = {str(k): str(v) for k, v in debug.items()}
+                    else:
+                        # the reference's "k=v;k2=v2" string form; any other
+                        # JSON type is ignored rather than crashing the handler
+                        debug = _parse_debug_options(debug if isinstance(debug, str) else "")
+                    return pql, bool(body.get("trace")), debug, _parse_timeout(body.get("timeoutMs"))
 
-        self._httpd = ThreadingHTTPServer((host, port), _Handler)
+                self._query(read)
+
+            def _query(self, read) -> None:
+                """One query over HTTP, handler entry to last byte
+                written (``httpTotal``): ``read`` gives ``handle_pql``'s
+                arguments (``phase.httpRead``), the reply is rendered and
+                written under ``phase.render``.  The tree's root is
+                opened here, so a retained tail gets the finished tree
+                once the reply is out."""
+                rid, ctx = broker.open_trace()
+                resp = None
+                with boundary("httpTotal", ctx, broker.metrics.timer("httpTotal"), requestId=rid):
+                    try:
+                        with boundary("httpRead", ctx, broker.metrics.timer("phase.httpRead")):
+                            pql, trace, debug, timeout_ms = read()
+                    except json.JSONDecodeError as e:
+                        return self._respond(
+                            {"exceptions": [{"errorCode": ErrorCode.JSON_PARSING, "message": str(e)}]}
+                        )
+                    except InvalidTimeoutError as e:
+                        return self._invalid_timeout(e)
+                    resp = broker.handle_pql(
+                        pql,
+                        trace=trace,
+                        debug_options=debug,
+                        timeout_ms=timeout_ms,
+                        request_id=rid,
+                        trace_ctx=ctx,
+                    )
+                    with boundary("render", ctx, broker.metrics.timer("phase.render")):
+                        self._respond(resp.to_json())
+                if getattr(resp, "_tail_reason", None):
+                    broker.tail.complete(rid, ctx.to_dict())
+
+        class _Httpd(ThreadingHTTPServer):
+            # socketserver's default listen backlog of 5 is 170 ms of
+            # arrivals at 30 queries/s: a stall of the accept loop that
+            # long (the v5e's host shows 110 to 170 ms ones a few times
+            # a minute) overflows it, the dropped SYNs come back after
+            # 1, 3, 7... s, and the clients' retries pile onto the next
+            # overflow (PERF.md, PR 24 and PR 25: one window in 23 went
+            # 27 s behind and reset five connections)
+            request_queue_size = 128
+
+        self._httpd = _Httpd((host, port), _Handler)
         self.host = host
         self.port = self._httpd.server_address[1]
         self._thread: Optional[threading.Thread] = None

@@ -83,6 +83,7 @@ from typing import Any, Callable, Deque, Dict, Hashable, List, Optional
 
 from pinot_tpu.engine import compilecache
 from pinot_tpu.server.scheduler import QueryAbandonedError
+from pinot_tpu.utils.trace import boundary, measured
 
 # completed dispatches kept open (still coalescible) at once; beyond
 # this the oldest close early — a bound on pinned output buffers, not
@@ -276,12 +277,15 @@ class LaneTicket:
     ``batch_size`` is the member count of the batched launch this
     ticket's dispatch rode (1 = unbatched)."""
 
-    __slots__ = ("deadline", "coalesced", "batch_size", "_event", "_value", "_error")
+    __slots__ = ("deadline", "coalesced", "batch_size", "delivered_at", "_event", "_value",
+                 "_error", "_dispatch")
 
     def __init__(self, deadline: Optional[float]) -> None:
         self.deadline = deadline
         self.coalesced = False
         self.batch_size = 1
+        self.delivered_at = 0.0  # perf_counter at delivery, for the waiter's laneWake
+        self._dispatch: Optional["_Dispatch"] = None  # for DeviceLane.output_ready
         self._event = threading.Event()
         self._value: Any = None
         self._error: Optional[BaseException] = None
@@ -289,6 +293,7 @@ class LaneTicket:
     def _deliver(self, value: Any = None, error: Optional[BaseException] = None) -> None:
         self._value = value
         self._error = error
+        self.delivered_at = time.perf_counter()
         self._event.set()
 
     def result(self, deadline: Optional[float] = None) -> Any:
@@ -378,6 +383,7 @@ class _Dispatch:
     __slots__ = (
         "key", "launch", "pending", "waiters", "completed", "value",
         "error", "plan_digest", "cost_provider", "batch", "batch_size",
+        "t_submit", "trace", "parent", "program", "launch_id",
     )
 
     def __init__(
@@ -388,7 +394,18 @@ class _Dispatch:
         plan_digest: Optional[str] = None,
         cost_provider: Optional[Callable[[], Optional[dict]]] = None,
         batch: Optional[BatchSpec] = None,
+        trace=None,
+        parent: Optional[str] = None,
+        program: str = "",
+        t_submit: float = 0.0,
     ) -> None:
+        self.t_submit = t_submit  # phase.laneQueue runs from here to the launch call
+        # the submitting query's span tree and the span (its laneWait)
+        # that the lane thread's laneQueue and laneDispatch hang under
+        self.trace = trace
+        self.parent = parent
+        self.program = program  # the jitted program's name, for the launch's tags
+        self.launch_id: Optional[int] = None  # the physical launch this rode (occupancy)
         self.key = key
         self.launch = launch
         self.pending = pending
@@ -488,11 +505,18 @@ class DeviceLane:
         # -- occupancy accounting (utilization plane) ----------------
         # Plain float accumulation at state transitions — NO per-launch
         # allocations (OCCUPANCY_ALLOCATIONS contract above).  busy =
-        # wall seconds inside launch calls; depth-seconds integrates
-        # queue depth over time.  Windowed readers (gauges, status,
-        # sampler) each diff against their own last checkpoint.
+        # wall seconds with a launch's output outstanding: the window
+        # opens before the launch call and closes when the output is
+        # ready (JAX dispatch is asynchronous: the call returns while
+        # the device still runs), held open as the union over
+        # outstanding launches.  Each closed window also lands on the
+        # cumulative timer ``lane.deviceBusy``.  depth-seconds
+        # integrates queue depth over time.  Windowed readers (gauges,
+        # status, sampler) each diff against their own last checkpoint.
         self._busy_s = 0.0
         self._busy_since: Optional[float] = None
+        self._outstanding: set = set()  # launch ids whose output is not known ready
+        self._launch_seq = 0
         self._depth_s = 0.0
         self._depth_mark = time.monotonic()
         self._created_at = self._depth_mark
@@ -514,6 +538,9 @@ class DeviceLane:
                          "batch.windowClosedIdle"):
                 metrics.meter(name)
             metrics.timer("compile.firstCallMs")
+            for name in ("lane.deviceBusy", "phase.laneQueue", "phase.laneDispatch",
+                         "phase.laneDeliver"):
+                metrics.timer(name)
             if self.index is None:
                 metrics.gauge("lane.depth").set(0)
                 metrics.gauge("lane.open").set(0)
@@ -548,11 +575,20 @@ class DeviceLane:
         plan_digest: Optional[str] = None,
         cost_provider: Optional[Callable[[], Optional[dict]]] = None,
         batch: Optional[BatchSpec] = None,
+        trace=None,
+        parent: Optional[str] = None,
+        program: str = "",
     ) -> LaneTicket:
         """Enqueue a kernel launch, or coalesce onto an identical one
         that is queued, launching, or still executing on device.
         Returns immediately; the caller blocks on ``ticket.result`` when
         FINALIZE actually needs the outputs.
+
+        ``trace``/``parent``/``program``: the query's span tree, the
+        span (its ``laneWait``) under which the lane thread's
+        ``laneQueue`` and ``laneDispatch`` hang, and the jitted
+        program's name.  A coalesced ticket gets neither span: its wait
+        is all ``laneWait``.
 
         ``cost_provider`` (optional, utilization plane): a zero-arg
         callable returning the plan's static XLA cost analysis (or
@@ -565,6 +601,7 @@ class DeviceLane:
         vmapped launch.  Identical dispatches still coalesce FIRST (one
         member, many waiters); batching merges *distinct* members."""
         ticket = LaneTicket(deadline)
+        t_submit = time.monotonic()  # before the lane's lock: waiting for it is queueing too
         with self._cv:
             if self._closed:
                 raise LaneClosedError("device lane is closed")
@@ -580,6 +617,7 @@ class DeviceLane:
                     # member slice — the late waiter rode that batch
                     # too, so it must report the same batch size
                     ticket.batch_size = d.batch_size
+                    ticket._dispatch = d
                     ticket._deliver(value=d.value)
                     return ticket
                 self._close_open(d)
@@ -589,7 +627,8 @@ class DeviceLane:
                 ticket.coalesced = True
                 self._hit()
             else:
-                d = _Dispatch(key, launch, pending, plan_digest, cost_provider, batch)
+                d = _Dispatch(key, launch, pending, plan_digest, cost_provider, batch,
+                              trace, parent, program, t_submit)
                 d.waiters.append(ticket)
                 self._by_key[key] = d
                 self._depth_tick_locked()
@@ -605,7 +644,16 @@ class DeviceLane:
                 self._spawn_lane_locked()
                 if self.stall_timeout_s and self.stall_timeout_s > 0:
                     self._spawn_watchdog_locked()
+            ticket._dispatch = d
         return ticket
+
+    def output_ready(self, ticket: LaneTicket) -> None:
+        """A waiter saw its dispatch's output ready (its ``deviceWait``
+        ended): the launch no longer holds the occupancy window open."""
+        d = ticket._dispatch
+        if d is not None and d.launch_id in self._outstanding:
+            with self._cv:
+                self._busy_close_locked(d.launch_id)
 
     @property
     def depth(self) -> int:
@@ -690,6 +738,25 @@ class DeviceLane:
         self._depth_s += len(self._queue) * (now - self._depth_mark)
         self._depth_mark = now
 
+    def _busy_close_locked(self, launch_id: Optional[int]) -> None:
+        """A launch's output is ready, or never will be (error, stall,
+        close).  The window closes when no launch is outstanding."""
+        if launch_id not in self._outstanding:
+            return
+        self._outstanding.discard(launch_id)
+        if not self._outstanding:
+            self._busy_bank_locked()
+
+    def _busy_bank_locked(self, now: Optional[float] = None) -> None:
+        self._outstanding.clear()
+        if self._busy_since is None:
+            return
+        busy = max(0.0, (time.monotonic() if now is None else now) - self._busy_since)
+        self._busy_s += busy
+        self._busy_since = None
+        if self.metrics is not None:
+            self.metrics.timer("lane.deviceBusy").update(busy * 1000.0)
+
     def occupancy_read(
         self, key: str = "default", min_interval_s: float = 0.0
     ) -> Dict[str, float]:
@@ -712,8 +779,9 @@ class DeviceLane:
                 return dict(prev[3])
             busy = self._busy_s
             if self._busy_since is not None:
-                # count the in-flight launch's elapsed time as busy so a
-                # long cold compile doesn't read as an idle device
+                # count the open window's elapsed time as busy so a long
+                # cold compile or a long kernel doesn't read as an idle
+                # device
                 busy += max(0.0, now - self._busy_since)
             self._depth_tick_locked(now)
             depth_s = self._depth_s
@@ -752,6 +820,7 @@ class DeviceLane:
             self._depth_tick_locked()
             self._queue.clear()
             self._open.clear()
+            self._busy_bank_locked()
             self._by_key.clear()
             for d in drained:
                 d.completed = True
@@ -867,12 +936,10 @@ class DeviceLane:
                     # each one over to the host path independently)
                     members = infl[2]
                     self._inflight = None
-                    if self._busy_since is not None:
-                        # bank the wedged launch's window as busy time;
-                        # the abandoned thread sees itself stale later
-                        # and leaves the accounting alone
-                        self._busy_s += max(0.0, now - self._busy_since)
-                        self._busy_since = None
+                    # bank the wedged launch's window as busy time; the
+                    # abandoned thread sees itself stale later and
+                    # leaves the accounting alone
+                    self._busy_bank_locked(now)
                     self._generation += 1
                     self.restart_count += 1
                     self.device_failure_count += 1
@@ -939,7 +1006,11 @@ class DeviceLane:
         for d in list(self._open):
             if d.error is not None or not self._still_pending(d):
                 self._close_open(d)
+                self._busy_close_locked(d.launch_id)
         while len(self._open) > _MAX_OPEN:
+            # leaves the set no sweep will look at again: give up its
+            # hold on the occupancy window rather than risk a stuck one
+            self._busy_close_locked(self._open[0].launch_id)
             self._close_open(self._open[0])
 
     # -- micro-batching formation (lock held) --------------------------
@@ -1073,7 +1144,14 @@ class DeviceLane:
                     # launch is ONE in-flight unit (all members stall
                     # or complete together)
                     self._inflight = (members[0], now, tuple(members))
-                    self._busy_since = now  # occupancy: device busy
+                    # occupancy: the launch's output is outstanding from
+                    # here until it is seen ready
+                    self._launch_seq += 1
+                    for m in members:
+                        m.launch_id = self._launch_seq
+                    self._outstanding.add(self._launch_seq)
+                    if self._busy_since is None:
+                        self._busy_since = now
             if dead:
                 self.shed_count += len(dead)
                 self._lane_mark("shed", len(dead))
@@ -1087,9 +1165,25 @@ class DeviceLane:
                 continue
             d = members[0]
             batched = len(members) > 1
+            queue_timer = launch_timer = deliver_timer = None
+            if self.metrics is not None:
+                queue_timer = self.metrics.timer("phase.laneQueue")
+                launch_timer = self.metrics.timer("phase.laneDispatch")
+                deliver_timer = self.metrics.timer("phase.laneDeliver")
+            now = time.monotonic()
+            for m in members:
+                # queue and batch-formation wait: submit -> the launch call below
+                measured("laneQueue", (now - m.t_submit) * 1000.0, m.trace, queue_timer,
+                         parent=m.parent)
             # launch OUTSIDE the lock: first-call compiles can take
-            # seconds and coalescing submits must not block behind them
-            t0 = time.perf_counter()
+            # seconds and coalescing submits must not block behind them.
+            # ``via`` says before the call whether this plan digest has
+            # launched here ("first": it may compile) and after it how
+            # the first launch got its executable.
+            launching = boundary(
+                "laneDispatch", d.trace, launch_timer, parent=d.parent, program=d.program,
+                via="warm" if d.plan_digest is None or d.plan_digest in self._compile else "first",
+            ).start()
             self._set_inflight(1)
             error: Optional[BaseException] = None
             value: Any = None
@@ -1119,7 +1213,10 @@ class DeviceLane:
                 error = e
             finally:
                 self._set_inflight(0)
-            launch_ms = (time.perf_counter() - t0) * 1000
+                launch_ms = launching.stop()
+            # launch returned -> waiters delivered: the compile timeline,
+            # the coalescing set and the meters, on every query's path
+            delivering = boundary("laneDeliver", d.trace, deliver_timer, parent=d.parent).start()
             cold = False
             via = "cold"
             if (
@@ -1138,13 +1235,10 @@ class DeviceLane:
                     via = "persistent"
             with self._cv:
                 stale = gen != self._generation
-                if not stale and self._busy_since is not None:
-                    # occupancy: launch window closed.  Stale threads
-                    # must not touch this — after a watchdog restart
-                    # _busy_since belongs to the fresh lane thread (the
-                    # watchdog already banked the wedged window).
-                    self._busy_s += max(0.0, time.monotonic() - self._busy_since)
-                    self._busy_since = None
+                # occupancy: the window stays open past the launch call,
+                # until the output is ready (below, output_ready, or the
+                # sweep).  Stale threads must not touch it — the
+                # watchdog already banked the wedged window.
                 if not stale and self._inflight is not None and self._inflight[0] is d:
                     self._inflight = None
                 if stale:
@@ -1152,6 +1246,7 @@ class DeviceLane:
                     # the lane on; delivering now would hand out a result
                     # nobody waits for (or double-deliver an error)
                     self.stale_completions += 1
+                    delivering.stop()
                     return
                 self.dispatch_count += 1
                 if batched:
@@ -1202,6 +1297,7 @@ class DeviceLane:
                 if error is not None:
                     self.device_failure_count += 1
                 deliveries = []
+                outstanding = False
                 for i, m in enumerate(members):
                     m.completed = True
                     m.error = error
@@ -1217,8 +1313,12 @@ class DeviceLane:
                     if error is None and not self._closed and self._still_pending(m):
                         # program still executing: keep coalescible
                         self._open.append(m)
+                        outstanding = True
                     elif self._by_key.get(m.key) is m:
                         self._by_key.pop(m.key)
+                if not outstanding:
+                    # failed, or ready as the call returned
+                    self._busy_close_locked(d.launch_id)
                 self._sweep_open_locked()
             if self.metrics is not None:
                 self._lane_mark("dispatches")
@@ -1251,7 +1351,12 @@ class DeviceLane:
                                 self.metrics.meter("compile.persistentMiss").mark()
                     else:
                         self.metrics.meter("compile.warm").mark()
-                self.metrics.timer("phase.laneDispatch").update(launch_ms)
+            if cold:
+                launching.tag(via=via)
+            for m in members[1:]:
+                # a batched launch is one interval in every member's tree
+                measured("laneDispatch", launch_ms, m.trace, None, parent=m.parent,
+                         program=d.program, batched=len(members))
             if cold and via == "cold" and self.persistent_cache_dir is not None:
                 # the compile just wrote an XLA cache entry; ledger it so
                 # the NEXT process classifies this digest as persistent
@@ -1261,6 +1366,7 @@ class DeviceLane:
                 for w in waiters:
                     w.batch_size = n_members
                     w._deliver(value=mvalue, error=error)
+            delivering.stop()
 
 
 class LaneSelection:
@@ -1323,7 +1429,7 @@ class LaneGroup:
                 lambda: sum(len(l._open) for l in lanes)
             )
             metrics.gauge("lane.inflight").set_fn(
-                lambda: sum(1 for l in lanes if l._busy_since is not None)
+                lambda: sum(1 for l in lanes if l._inflight is not None)
             )
 
     @property

@@ -42,6 +42,7 @@ from pinot_tpu.engine.results import (
     SumPartial,
 )
 from pinot_tpu.segment.immutable import ImmutableSegment
+from pinot_tpu.utils.trace import boundary, current_trace, measured, phases
 
 logger = logging.getLogger(__name__)
 
@@ -241,8 +242,6 @@ class QueryExecutor:
 
     def _heal_mark(self, name: str, **tags) -> None:
         self.metrics.meter(f"heal.{name}").mark()
-        from pinot_tpu.utils.trace import current_trace
-
         tr = current_trace()
         if tr is not None and tr.enabled:
             tr.event(name, **tags)
@@ -439,19 +438,13 @@ class QueryExecutor:
             return None
         return tuple(getattr(d, "id", i) for i, d in enumerate(mesh.devices.flat))
 
-    def _phase(self, name: str, t0: float, **tags) -> float:
-        """Record a ServerQueryPhase-style timer (SURVEY §5: pruning /
-        planBuild / planExec phases) AND, when the request is traced, a
-        span on the current trace tree; returns a fresh t0."""
-        now = time.perf_counter()
-        ms = (now - t0) * 1000
-        self.metrics.timer(f"phase.{name}").update(ms)
-        from pinot_tpu.utils.trace import current_trace
-
-        tr = current_trace()
-        if tr is not None and tr.enabled:
-            tr.add(name, ms, **tags)
-        return now
+    def _phase(self, name: str, **tags) -> boundary:
+        """One executor boundary, unstarted (utils/trace.py): the
+        ServerQueryPhase-style timer ``phase.<name>`` (SURVEY §5:
+        pruning / planBuild / planExec phases), the span on the
+        request's tree when it is traced, and ``pinot:<name>`` on the
+        profiler's host plane."""
+        return boundary(name, current_trace(), self.metrics.timer(f"phase.{name}"), **tags)
 
     def execute(
         self,
@@ -462,20 +455,21 @@ class QueryExecutor:
         """``deadline`` (monotonic seconds) is the broker-propagated
         budget; threaded into the device lane so a query whose budget
         drained while queued there is shed, not executed."""
-        total_docs = sum(s.num_docs for s in segments)
-        live = prune_segments(segments, request)
-        pruned = len(segments) - len(live)
-        if not live:
-            res = self._empty_result(request, total_docs)
-            res.add_cost(segmentsPruned=pruned)
-            return res
-
         # star-tree routing: eligible segments answer from their
         # pre-aggregated cube (startree/operator.py); the rest take the
         # normal device path, partials merge below
         from pinot_tpu.startree.operator import execute_star_tree, is_fit_for_star_tree
 
-        star = [s for s in live if is_fit_for_star_tree(request, s)]
+        with self._phase("prune"):  # segment pruning and star-tree routing
+            total_docs = sum(s.num_docs for s in segments)
+            live = prune_segments(segments, request)
+            pruned = len(segments) - len(live)
+            star = [s for s in live if is_fit_for_star_tree(request, s)]
+        if not live:
+            res = self._empty_result(request, total_docs)
+            res.add_cost(segmentsPruned=pruned)
+            return res
+
         if star:
             normal = [s for s in live if s not in star]
             parts = [execute_star_tree(s, request) for s in star]
@@ -504,7 +498,22 @@ class QueryExecutor:
         request: BrokerRequest,
         deadline: Optional[float] = None,
     ) -> IntermediateResult:
-        t0 = time.perf_counter()
+        # the first stretch is ``staging`` (tier choice + get_staged)
+        # unless a host tier answers and relabels it
+        ph = phases(self._phase)
+        ph.enter("staging")
+        try:
+            return self._execute_tiers(live, request, deadline, ph)
+        finally:
+            ph.stop()
+
+    def _execute_tiers(
+        self,
+        live: List[ImmutableSegment],
+        request: BrokerRequest,
+        deadline: Optional[float],
+        ph: phases,
+    ) -> IntermediateResult:
         total_docs = sum(s.num_docs for s in live)
         needed = set(request.referenced_columns())
         sel_columns: Optional[List[str]] = None
@@ -543,7 +552,7 @@ class QueryExecutor:
         if not self._audit_blocked(audit_digest, "postings"):
             ires = try_index_path(request, live, ctx, total_docs, sel_columns)
         if ires is not None:
-            self._phase("indexPath", t0)
+            ph.relabel("indexPath")
             return self._finish_tier(ires, request, "postings")
 
         # mid-selectivity scalar aggregations the postings tier just
@@ -578,7 +587,7 @@ class QueryExecutor:
                 self._heal_mark("bitslicedFallbacks", error=str(e)[:200])
                 bres = None
             if bres is not None:
-                self._phase("bitslicedPath", t0)
+                ph.relabel("bitslicedPath")
                 return self._finish_tier(bres, request, "bitsliced")
 
         # queries the planner can only send to the host (group space or
@@ -589,7 +598,7 @@ class QueryExecutor:
             from pinot_tpu.engine.host_fallback import execute_host
 
             res = execute_host(live, ctx, request, total_docs, sel_columns)
-            self._phase("hostPath", t0)
+            ph.relabel("hostPath")
             return self._finish_tier(res, request, "host")
 
         # -- device section under the self-healing contract -----------
@@ -613,9 +622,8 @@ class QueryExecutor:
             from pinot_tpu.engine.host_fallback import execute_host
 
             self._heal_mark("hostFailovers", reason="auditQuarantine")
-            t0 = time.perf_counter()
+            ph.enter("hostFailover")
             res = execute_host(live, ctx, request, total_docs, sel_columns)
-            self._phase("hostFailover", t0)
             return self._finish_tier(res, request, "host")
 
         poison_ref: Dict[str, Any] = {}  # device section records the key
@@ -644,11 +652,12 @@ class QueryExecutor:
                 elif attempt > 1:
                     break  # plain transients get exactly ONE device retry
                 self._heal_mark("deviceRetries")
+                ph.enter("staging")
             try:
                 return self._finish_tier(
                     self._device_section(
                         live, request, deadline, ctx, needed, sel_columns,
-                        pad_to, total_docs, t0, poison_ref, sel=sel, mesh=mesh,
+                        pad_to, total_docs, ph, poison_ref, sel=sel, mesh=mesh,
                     ),
                     request,
                     "device",
@@ -689,9 +698,8 @@ class QueryExecutor:
             # slow host path after pressure subsides
             self._poison(poison_ref["key"], str(last))
         self._heal_mark("hostFailovers", reason=str(last)[:200])
-        t0 = time.perf_counter()
+        ph.enter("hostFailover")
         res = execute_host(live, ctx, request, total_docs, sel_columns)
-        self._phase("hostFailover", t0)
         return self._finish_tier(res, request, "host")
 
     def _device_section(
@@ -704,7 +712,7 @@ class QueryExecutor:
         sel_columns: Optional[List[str]],
         pad_to: int,
         total_docs: int,
-        t0: float,
+        ph: phases,
         poison_ref: Dict[str, Any],
         sel=None,
         mesh=None,
@@ -740,7 +748,7 @@ class QueryExecutor:
         try:
             return self._device_section_staged(
                 live, request, deadline, ctx, needed, sel_columns,
-                total_docs, t0, poison_ref, sel, mesh, lane, sharding,
+                total_docs, ph, poison_ref, sel, mesh, lane, sharding,
                 staged,
             )
         finally:
@@ -755,7 +763,7 @@ class QueryExecutor:
         needed: set,
         sel_columns: Optional[List[str]],
         total_docs: int,
-        t0: float,
+        ph: phases,
         poison_ref: Dict[str, Any],
         sel,
         mesh,
@@ -763,7 +771,7 @@ class QueryExecutor:
         sharding,
         staged,
     ) -> IntermediateResult:
-        t0 = self._phase("staging", t0)
+        ph.enter("planBuild")  # staging ends here
         scratch: Dict[Any, Any] = {}  # plan->inputs table cache (regex)
         plan = build_static_plan(request, ctx, staged, scratch=scratch)
 
@@ -771,6 +779,7 @@ class QueryExecutor:
             from pinot_tpu.engine.host_fallback import execute_host
 
             poison_ref["host"] = True  # host path from here: not a device fault
+            ph.stop()
             return execute_host(live, ctx, request, total_docs, sel_columns)
 
         # poison quarantine: this (plan digest, segment set) keeps
@@ -787,11 +796,9 @@ class QueryExecutor:
             from pinot_tpu.engine.host_fallback import execute_host
 
             self._heal_mark("poisonSkips")
-            t0 = self._phase("planBuild", t0)
+            ph.enter("hostFailover")
             poison_ref["host"] = True  # host path from here: not a device fault
-            res = execute_host(live, ctx, request, total_docs, sel_columns)
-            self._phase("hostFailover", t0)
-            return res
+            return execute_host(live, ctx, request, total_docs, sel_columns)
 
         from pinot_tpu.engine.device import segment_arrays
 
@@ -809,7 +816,9 @@ class QueryExecutor:
             # HBM at compile time — fall through to the chunked full
             # kernel instead (correctness over the block-skip win)
             block_ids = None
-        t0 = self._phase("planBuild", t0)
+        # the kernel's lookup, the batch spec and (where the launch does
+        # not carry it) the upload of the query's inputs
+        ph.enter("kernelPrep")
         # kernel outputs fetch via ONE packed D2H transfer
         # (engine/packing.py): per-leaf fetches pay a transfer each
         batch_spec = None
@@ -859,12 +868,13 @@ class QueryExecutor:
             else:
                 args = (seg_arrays, upload_inputs())
         exec_info: Dict[str, Any] = {}
+        ph.stop()  # laneWait/planExec are timed inside _run_kernel
         outs = self._run_kernel(
             kernel, args, plan, staged, digest, block_ids, deadline, pdigest,
             cost=cost, lane=lane, batch_spec=batch_spec, exec_info=exec_info,
             analysis_args=analysis_args,
         )
-        t0 = time.perf_counter()  # laneWait/planExec timed inside _run_kernel
+        ph.enter("finalize")
 
         # sort-dedup distinct overflow: more unique pairs than the
         # device buffer holds — only the host path can finish exactly
@@ -879,6 +889,7 @@ class QueryExecutor:
                     # pair overflow: host finishes exactly — leaving the
                     # device path, so host errors are not device faults
                     poison_ref["host"] = True
+                    ph.stop()
                     return execute_host(live, ctx, request, total_docs, sel_columns)
 
         result = self._finalize(request, plan, ctx, staged, live, outs, total_docs, sel_columns)
@@ -908,7 +919,7 @@ class QueryExecutor:
         # batching actuals for EXPLAIN ANALYZE's device node: how many
         # same-shape queries this member's launch actually carried
         result._batch_size = int(exec_info.get("batchSize", 1) or 1)
-        self._phase("finalize", t0)
+        ph.stop()
         return result
 
     def _docrange_only_columns(
@@ -1016,6 +1027,7 @@ class QueryExecutor:
         return k
 
     def _block_kernel(self, plan: StaticPlan, block: int, mesh=None):
+        from pinot_tpu.engine.kernel import kernel_name
         from pinot_tpu.engine.packing import make_packed_kernel
         from pinot_tpu.parallel.multichip import make_sharded_block_table_kernel
 
@@ -1024,7 +1036,8 @@ class QueryExecutor:
         return self._cached_sharded(
             (plan, "block", block, self._mesh_key(mesh)),
             lambda: make_packed_kernel(
-                make_sharded_block_table_kernel(plan, mesh, block)
+                make_sharded_block_table_kernel(plan, mesh, block),
+                kernel_name("meshzone", plan),
             ),
         )
 
@@ -1279,82 +1292,114 @@ class QueryExecutor:
                 return kernel.fetch, disp(*a)
             return None, kernel(*a)  # raw jit: device arrays out
 
-        t0 = time.perf_counter()
+        # the jitted program's name (engine/kernel.py kernel_name): the
+        # ``program=`` tag of the launch and wait spans, as the device
+        # planes of a capture name it
+        program = getattr(kernel, "__name__", "")
         coalesced = False
-        if lane is None:
-            fetch, handle = launch()
-        else:
-            # coalesce key: identical (plan, staged-table token, inputs
-            # digest, block-id set) => identical device outputs.  The
-            # token is process-unique (device.py), so a table re-staged
-            # after GC can never alias an in-flight dispatch.
-            bkey = (
-                None
-                if block_ids is None
-                else (block_ids.shape, block_ids.tobytes())
-            )
-            from pinot_tpu.engine.packing import kernel_cost_analysis
-
-            ticket = lane.submit(
-                (plan, staged.token, digest, bkey),
-                launch,
-                deadline,
-                plan_digest=pdigest,
-                # static roofline numerator: flops/bytes per launch of
-                # this compiled plan, resolved ONCE per digest on the
-                # lane's async analysis thread (graceful None fallback)
-                cost_provider=lambda: kernel_cost_analysis(kernel, cost_args),
-                batch=batch_spec,
-            )
-            fetch, handle = ticket.result(deadline)
-            # queue + coalesce wait only; the coalesced tag marks a
-            # query that rode an identical in-flight dispatch
-            coalesced = ticket.coalesced
-            t0 = self._phase("laneWait", t0, coalesced=coalesced)
-            if cost is not None and coalesced:
-                cost["coalesceHits"] = cost.get("coalesceHits", 0) + 1
-            bsize = int(getattr(ticket, "batch_size", 1) or 1)
-            if exec_info is not None:
-                exec_info["batchSize"] = bsize
-            if cost is not None and bsize > 1:
-                # this query rode a cross-query batched launch (its
-                # literals stacked with bsize-1 same-plan peers)
-                cost["batchHits"] = cost.get("batchHits", 0) + 1
-        # exactly ONE waiter per dispatch is non-coalesced, so the
-        # physical D2H copy is counted once no matter how many queries
-        # rode the dispatch (coalesced waiters read the cached host copy)
-        outs = fetch(handle, count_transfer=not coalesced) if fetch is not None else handle
-        outs = {
-            k: np.asarray(v)
-            if not isinstance(v, tuple)
-            else tuple(np.asarray(x) for x in v)
-            for k, v in outs.items()
-        }
-        if fetch is None:
-            # raw-jit path (mesh/chunked kernels): the np.asarray calls
-            # above were the D2H transfers — the packed path counts its
-            # own single buffer inside packing.fetch
-            from pinot_tpu.engine.device import TRANSFERS
-
-            if not coalesced:
-                TRANSFERS.record_d2h(
-                    sum(
-                        x.nbytes
-                        for v in outs.values()
-                        for x in (v if isinstance(v, tuple) else (v,))
-                    )
+        ticket = None
+        # planExec excludes lane queueing (timed as laneWait): it covers
+        # launch (serial mode) + the wait for the device + the D2H
+        # fetch, so the per-stage timers on status() sum to wall time
+        # instead of double-counting the wait inside planExec
+        executing = self._phase("planExec")
+        try:
+            if lane is None:
+                executing.start()
+                fetch, handle = launch()
+            else:
+                # coalesce key: identical (plan, staged-table token, inputs
+                # digest, block-id set) => identical device outputs.  The
+                # token is process-unique (device.py), so a table re-staged
+                # after GC can never alias an in-flight dispatch.
+                bkey = (
+                    None
+                    if block_ids is None
+                    else (block_ids.shape, block_ids.tobytes())
                 )
-        # planExec excludes lane queueing (timed above as laneWait): it
-        # covers launch (serial mode) + the blocking packed D2H fetch,
-        # so the per-stage timers on status() sum to wall time instead
-        # of double-counting the wait inside planExec
+                from pinot_tpu.engine.packing import kernel_cost_analysis
+
+                # queue + coalesce wait + the launch call on the lane
+                # thread: laneQueue, laneDispatch and laneDeliver are
+                # recorded there (engine/dispatch.py), laneWake here; the
+                # coalesced tag marks a query that rode an identical
+                # in-flight dispatch
+                with self._phase("laneWait") as waiting:
+                    ticket = lane.submit(
+                        (plan, staged.token, digest, bkey),
+                        launch,
+                        deadline,
+                        plan_digest=pdigest,
+                        # static roofline numerator: flops/bytes per launch of
+                        # this compiled plan, resolved ONCE per digest on the
+                        # lane's async analysis thread (graceful None fallback)
+                        cost_provider=lambda: kernel_cost_analysis(kernel, cost_args),
+                        batch=batch_spec,
+                        trace=current_trace(),
+                        parent=waiting.span_id,
+                        program=program,
+                    )
+                    fetch, handle = ticket.result(deadline)
+                    # the lane thread delivered -> this worker runs again
+                    measured("laneWake", (time.perf_counter() - ticket.delivered_at) * 1000.0,
+                             current_trace(), self.metrics.timer("phase.laneWake"))
+                    coalesced = ticket.coalesced
+                    waiting.tag(coalesced=coalesced)
+                if cost is not None and coalesced:
+                    cost["coalesceHits"] = cost.get("coalesceHits", 0) + 1
+                bsize = int(getattr(ticket, "batch_size", 1) or 1)
+                if exec_info is not None:
+                    exec_info["batchSize"] = bsize
+                if cost is not None and bsize > 1:
+                    # this query rode a cross-query batched launch (its
+                    # literals stacked with bsize-1 same-plan peers)
+                    cost["batchHits"] = cost.get("batchHits", 0) + 1
+                executing.start()
+            with self._phase("deviceWait", program=program):
+                # the packed handle is (layout, device buffer); the raw
+                # jit's is the output pytree itself.  The D2H copy is
+                # queued behind the program first, as ``np.asarray`` on a
+                # pending buffer queues it: waiting alone would put a
+                # host round trip between the kernel and the copy
+                # (0.35 ms a query on the v5e, PERF.md PR 25).
+                buffers = jax.tree_util.tree_leaves(handle[1] if fetch is not None else handle)
+                for buf in buffers:
+                    buf.copy_to_host_async()
+                jax.block_until_ready(buffers)
+            with self._phase("d2hUnpack"):
+                if ticket is not None:
+                    lane.output_ready(ticket)  # occupancy: the device is done with this launch
+                # exactly ONE waiter per dispatch is non-coalesced, so the
+                # physical D2H copy is counted once no matter how many
+                # queries rode the dispatch (coalesced waiters read the
+                # cached host copy)
+                outs = fetch(handle, count_transfer=not coalesced) if fetch is not None else handle
+                outs = {
+                    k: np.asarray(v)
+                    if not isinstance(v, tuple)
+                    else tuple(np.asarray(x) for x in v)
+                    for k, v in outs.items()
+                }
+                if fetch is None:
+                    # raw-jit path (mesh/chunked kernels): the np.asarray
+                    # calls above were the D2H transfers — the packed path
+                    # counts its own single buffer inside packing.fetch
+                    from pinot_tpu.engine.device import TRANSFERS
+
+                    if not coalesced:
+                        TRANSFERS.record_d2h(
+                            sum(
+                                x.nbytes
+                                for v in outs.values()
+                                for x in (v if isinstance(v, tuple) else (v,))
+                            )
+                        )
+        finally:
+            executing.stop()
         if cost is not None:
             # the cost vector's deviceMs is this same window: device
             # execution + the packed D2H fetch, not lane queueing
-            cost["deviceMs"] = cost.get("deviceMs", 0.0) + round(
-                (time.perf_counter() - t0) * 1000, 3
-            )
-        self._phase("planExec", t0)
+            cost["deviceMs"] = cost.get("deviceMs", 0.0) + round(executing.ms, 3)
         return outs
 
     def _inputs_digest(self, inputs: Dict[str, Any]) -> str:
@@ -1692,9 +1737,20 @@ class QueryExecutor:
         probe,
         deadline: Optional[float] = None,
     ) -> IntermediateResult:
+        # the first stretch (packing the sides into a plan) is
+        # ``planBuild`` unless the host join answers and relabels it
+        ph = phases(self._phase)
+        ph.enter("planBuild")
+        try:
+            return self._execute_join(request, build, probe, deadline, ph)
+        finally:
+            ph.stop()
+
+    def _execute_join(
+        self, request: BrokerRequest, build, probe, deadline: Optional[float], ph: phases
+    ) -> IntermediateResult:
         from pinot_tpu.engine import join as join_mod
 
-        t0 = time.perf_counter()
         side_bytes = build.nbytes() + probe.nbytes()
         try:
             planned = join_mod.build_join_plan(request, build, probe)
@@ -1709,7 +1765,7 @@ class QueryExecutor:
         if planned is None:
             res = join_mod.host_join(request, build, probe)
             res.add_cost(buildRows=build.n, probeRows=probe.n)
-            self._phase("hostPath", t0)
+            ph.relabel("hostPath")
             return res
         plan, inputs, meta = planned
         jdigest = join_mod.join_plan_digest(plan)
@@ -1728,7 +1784,7 @@ class QueryExecutor:
             self._heal_mark("poisonSkips")
             res = join_mod.host_join(request, build, probe)
             res.add_cost(buildRows=build.n, probeRows=probe.n)
-            self._phase("hostFailover", t0)
+            ph.relabel("hostFailover")
             return res
 
         last: Optional[DeviceExecutionError] = None
@@ -1740,7 +1796,7 @@ class QueryExecutor:
             try:
                 return self._join_device_section(
                     request, plan, inputs, meta, build, probe, deadline,
-                    jdigest, lane, sel, side_bytes, t0,
+                    jdigest, lane, sel, side_bytes, ph,
                 )
             except (QueryAbandonedError, LaneClosedError, TimeoutError):
                 raise
@@ -1751,15 +1807,14 @@ class QueryExecutor:
                 )
         self._poison(poison_key, str(last))
         self._heal_mark("hostFailovers", reason=str(last)[:200])
-        t0 = time.perf_counter()
+        ph.enter("hostFailover")
         res = join_mod.host_join(request, build, probe)
         res.add_cost(buildRows=build.n, probeRows=probe.n)
-        self._phase("hostFailover", t0)
         return res
 
     def _join_device_section(
         self, request, plan, inputs, meta, build, probe, deadline,
-        jdigest, lane, sel, side_bytes, t0,
+        jdigest, lane, sel, side_bytes, ph,
     ) -> IntermediateResult:
         from pinot_tpu.engine import join as join_mod
         from pinot_tpu.engine.kernel import make_join_kernel
@@ -1781,6 +1836,7 @@ class QueryExecutor:
         # (batch_spec=None): stacking distinct join payloads has no
         # shared-column amortization to win, and the byte-identity
         # proof for batched joins hasn't been done (ISSUE 14 guard)
+        ph.stop()
         outs = self._run_kernel(
             kernel, (inputs,), plan, _JoinToken(), digest, None, deadline,
             pdigest=jdigest, cost=cost, lane=lane, batch_spec=None,
@@ -1790,7 +1846,7 @@ class QueryExecutor:
             # with unique keys and a half-full table, but a wrong
             # answer must never ship): heal to the exact host join
             raise RuntimeError("join hash-table build did not converge")
-        t_fin = time.perf_counter()
+        ph.enter("finalize")
         result = join_mod.finalize_device_join(
             request, plan, meta, build, probe, outs
         )
@@ -1804,7 +1860,6 @@ class QueryExecutor:
         result._device_digest = jdigest
         result._lane_index = sel.index if sel is not None else 0
         result._batch_size = 1
-        self._phase("finalize", t_fin)
         return result
 
     # ------------------------------------------------------------------

@@ -1056,6 +1056,39 @@ def make_single_segment_block_kernel(plan: StaticPlan, block: int) -> Callable:
     return kernel
 
 
+def kernel_name(tier: str, plan, digest_of=None) -> str:
+    """The name a device program is jitted under:
+    ``pinot_<tier>_<agg|gb<K>|sel>_<first 8 of the plan digest>``, so
+    that a capture's ``XLA Modules`` (``jit_<name>(<fingerprint>)``),
+    the launch and wait spans' ``program=`` tag and EXPLAIN's
+    ``device.planDigest`` agree.  ``tier``: scan, zone (block-skipping),
+    bsi, join, their batched twins (``scanb``, ``bsib``) and the mesh
+    programs (``mesh``, ``meshzone``).  ``K`` is the dense group
+    capacity.  The digest is ``engine/dispatch.plan_digest`` of the
+    literal-erased plan (of ``digest_of`` where the plan object is not
+    what the lane digests): stable across processes, seeds and code
+    changes that leave the plan's fields alone, where XLA's fingerprint
+    moves with any change to the lowered program."""
+    from pinot_tpu.engine.dispatch import plan_digest
+
+    group_by = getattr(plan, "group_by", None)
+    if group_by is not None:
+        shape = f"gb{group_by.capacity}"
+    elif getattr(plan, "n_groups", 0):  # a JoinPlan's group space
+        shape = f"gb{plan.n_groups}"
+    elif getattr(plan, "selection", None) is not None:
+        shape = "sel"
+    else:
+        shape = "agg"
+    return f"pinot_{tier}_{shape}_{plan_digest(plan if digest_of is None else digest_of)[:8]}"
+
+
+def named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``, for ``jax.jit`` to name its module after."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 @functools.lru_cache(maxsize=256)
 def make_block_table_kernel(plan: StaticPlan, block: int) -> Callable:
     """vmapped + jitted block-skipping variant of make_table_kernel;
@@ -1067,7 +1100,7 @@ def make_block_table_kernel(plan: StaticPlan, block: int) -> Callable:
         outs = jax.vmap(single)(segs, q, ids)
         return {k: apply_reduce(reducers[k], v) for k, v in outs.items()}
 
-    return jax.jit(table_fn)
+    return jax.jit(named(table_fn, kernel_name("zone", plan)))
 
 
 @functools.lru_cache(maxsize=256)
@@ -1086,7 +1119,7 @@ def make_table_kernel(plan: StaticPlan) -> Callable:
         outs = jax.vmap(single)(segs, q)
         return {k: apply_reduce(reducers[k], v) for k, v in outs.items()}
 
-    return jax.jit(table_fn)
+    return jax.jit(named(table_fn, kernel_name("scan", plan)))
 
 
 # Per-row kernel temporaries scale with S * n_pad: beyond ~2^28 rows the
@@ -1171,8 +1204,9 @@ def _chunked_run(table: Callable, reducers: Dict[str, str], num_segments: int, c
     from pinot_tpu.engine.packing import make_packed_kernel
 
     # the combined outputs still fetch via ONE packed D2H transfer —
-    # per-leaf fetches pay a transfer each (engine/packing.py)
-    pack = make_packed_kernel(lambda o: o)
+    # per-leaf fetches pay a transfer each (engine/packing.py).  The
+    # chunks run as ``table``'s program; the pack is named after it.
+    pack = make_packed_kernel(lambda o: o, table.__name__ + "_pack")
 
     def sliced(tree, s, e):
         return jax.tree_util.tree_map(lambda x: x[s:e], tree)
@@ -1196,6 +1230,7 @@ def _chunked_run(table: Callable, reducers: Dict[str, str], num_segments: int, c
     # sequence without blocking, fetch later from the FINALIZE worker
     run.dispatch = dispatch
     run.fetch = pack.fetch
+    run.__name__ = table.__name__
     return run
 
 
@@ -1226,7 +1261,7 @@ def make_chunked_sharded_kernel(plan: StaticPlan, mesh, num_segments: int, n_pad
         else num_segments
     )
     if not limit or num_segments <= chunk or not plan_chunkable(plan):
-        return make_packed_kernel(make_sharded_table_kernel(plan, mesh))
+        return make_packed_kernel(make_sharded_table_kernel(plan, mesh), kernel_name("mesh", plan))
     return _chunked_run(
         make_sharded_table_kernel(plan, mesh),
         output_reducers(plan),
@@ -1242,14 +1277,14 @@ def make_packed_table_kernel(plan: StaticPlan) -> Callable:
     each; the bench's async dispatch keeps using the raw kernel)."""
     from pinot_tpu.engine.packing import make_packed_kernel
 
-    return make_packed_kernel(make_table_kernel(plan))
+    return make_packed_kernel(make_table_kernel(plan), kernel_name("scan", plan))
 
 
 @functools.lru_cache(maxsize=256)
 def make_packed_block_table_kernel(plan: StaticPlan, block: int) -> Callable:
     from pinot_tpu.engine.packing import make_packed_kernel
 
-    return make_packed_kernel(make_block_table_kernel(plan, block))
+    return make_packed_kernel(make_block_table_kernel(plan, block), kernel_name("zone", plan))
 
 
 @functools.lru_cache(maxsize=128)
@@ -1284,7 +1319,7 @@ def make_packed_batched_table_kernel(plan: StaticPlan) -> Callable:
 
     from pinot_tpu.engine.packing import make_packed_kernel
 
-    return make_packed_kernel(jax.vmap(table_fn, in_axes=(None, 0)))
+    return make_packed_kernel(jax.vmap(table_fn, in_axes=(None, 0)), kernel_name("scanb", plan))
 
 
 # ---------------------------------------------------------------------------
@@ -1432,7 +1467,8 @@ def make_packed_bitsliced_kernel(spec) -> Callable:
 
     from pinot_tpu.engine.packing import make_packed_kernel
 
-    return make_packed_kernel(jax.jit(table_fn))
+    name = kernel_name("bsi", None, ("bsi", spec))
+    return make_packed_kernel(jax.jit(named(table_fn, name)), name)
 
 
 @functools.lru_cache(maxsize=128)
@@ -1457,7 +1493,8 @@ def make_packed_batched_bitsliced_kernel(spec) -> Callable:
 
     from pinot_tpu.engine.packing import make_packed_kernel
 
-    return make_packed_kernel(jax.jit(jax.vmap(table_fn, in_axes=(None, 0))))
+    name = kernel_name("bsib", None, ("bsi", spec))
+    return make_packed_kernel(jax.jit(named(jax.vmap(table_fn, in_axes=(None, 0)), name)), name)
 
 
 # ---------------------------------------------------------------------------
@@ -1657,4 +1694,4 @@ def make_join_kernel(jplan) -> Callable:
 
     from pinot_tpu.engine.packing import make_packed_kernel
 
-    return make_packed_kernel(kern)
+    return make_packed_kernel(kern, kernel_name("join", jplan))

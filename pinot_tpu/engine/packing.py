@@ -50,10 +50,13 @@ def _layout_for(out_shapes) -> Tuple[Any, list]:
     return treedef, layout
 
 
-def make_packed_kernel(fn: Callable) -> Callable:
+def make_packed_kernel(fn: Callable, name: str) -> Callable:
     """Wrap a kernel-like callable (pytree of device arrays out) so a
     call returns the same pytree as HOST numpy arrays via one packed
-    device-to-host transfer.
+    device-to-host transfer.  ``name`` (engine/kernel.py
+    ``kernel_name``) is what the program is jitted under, and the
+    returned callable's ``__name__``: a capture shows
+    ``jit_<name>(<fingerprint>)``, not one ``jit_packed`` for all.
 
     The returned callable also exposes the two pipeline halves as
     attributes: ``.dispatch(*args) -> handle`` launches the packed
@@ -63,7 +66,6 @@ def make_packed_kernel(fn: Callable) -> Callable:
     transfer + unpack (the FINALIZE stage, safe to call from any
     thread and from several waiters of one coalesced dispatch)."""
 
-    @jax.jit
     def packed(*args):
         leaves = jax.tree_util.tree_leaves(fn(*args))
         parts = []
@@ -77,6 +79,8 @@ def make_packed_kernel(fn: Callable) -> Callable:
             return jnp.zeros((0,), jnp.uint8)
         return jnp.concatenate(parts)
 
+    packed.__name__ = packed.__qualname__ = name
+    packed = jax.jit(packed)
     layout_cache: Dict[Tuple, Tuple] = {}
 
     def dispatch(*args):
@@ -125,6 +129,7 @@ def make_packed_kernel(fn: Callable) -> Callable:
     def call(*args):
         return fetch(dispatch(*args))
 
+    call.__name__ = name
     call.dispatch = dispatch
     call.fetch = fetch
     # AOT lowering handle for the static cost analysis (the jitted

@@ -147,7 +147,9 @@ def _make_sharded(plan: StaticPlan, mesh: Mesh, single: Callable, n_extra: int) 
         )
         return fn(segs, q, *extra)
 
-    return jax.jit(sharded)
+    from pinot_tpu.engine.kernel import kernel_name, named
+
+    return jax.jit(named(sharded, kernel_name("meshzone" if n_extra else "mesh", plan)))
 
 
 def make_sharded_table_kernel(plan: StaticPlan, mesh: Mesh) -> Callable:

@@ -36,6 +36,8 @@ from pinot_tpu.utils.metrics import ServerMetrics, prometheus_text
 from pinot_tpu.utils.trace import (
     NULL_TRACE,
     TraceContext,
+    boundary,
+    measured,
     reset_current,
     set_current,
 )
@@ -549,23 +551,54 @@ class ServerInstance:
     def handle_request(self, payload: bytes) -> bytes:
         """Framed request bytes -> framed DataTable bytes."""
         t_start = time.perf_counter()
-        req = deserialize_instance_request(payload)
+        # the two codecs lie outside the server's span tree by
+        # construction (the request says whether there is one; the
+        # reply carries it): timer and annotation only.  In the
+        # broker's tree they are serverAttempt's self time.
+        with boundary("deserializeRequest", None, self.metrics.timer("phase.deserializeRequest")):
+            req = deserialize_instance_request(payload)
+        rid = str(req.get("requestId") or "")
+        # untraced requests share the NULL context: no span allocation
+        # anywhere on this path (the zero-overhead contract)
+        trace = (
+            TraceContext(enabled=True, scope=self.name, trace_id=rid)
+            if req.get("trace")
+            else NULL_TRACE
+        )
+        with boundary("serverQuery", trace, requestId=rid, server=self.name) as root:
+            result = self._answer(req, trace, root.span_id, t_start)
+        if trace.enabled:
+            result.trace.update(trace.to_dict())
+        with boundary("serializeResult", None, self.metrics.timer("phase.serializeResult"),
+                      requestId=rid):
+            return serialize_result(result)
+
+    def _answer(
+        self, req: dict, trace: TraceContext, root_id: Optional[str], t_start: float
+    ) -> IntermediateResult:
+        """Queue, execute and account one request under its
+        ``serverQuery`` root."""
         # ONE deadline for both queueing tiers: the scheduler checks it
         # at worker-dequeue time, the device lane at launch-dequeue time
         timeout_s = req["timeoutMs"] / 1000.0
         deadline = time.monotonic() + timeout_s
-        t_enqueue = time.monotonic()
         outcome = "ok"  # vs "shed" / "failed": the plan-stats verdict
         try:
             # fair-share scheduling: each table queues separately and the
             # DRR dequeue guarantees a flooding tenant cannot starve the
-            # others (server/scheduler.py)
+            # others (server/scheduler.py).  The scheduler times the
+            # wait (phase.schedulerWait, span queueWait under the root).
             result = self.scheduler.run(
-                lambda: self._process(req, deadline, t_enqueue),
+                lambda: self._process(req, deadline, trace, root_id),
                 timeout_s=timeout_s,
                 deadline=deadline,
                 table=req["table"],
+                trace=trace,
+                parent=root_id,
             )
+            # the worker had the result -> this thread has it
+            measured("workerWake", (time.perf_counter() - result._t_done) * 1000.0, trace,
+                     self.metrics.timer("phase.workerWake"))
         except SchedulerSaturatedError as e:
             # overload shed: fast typed rejection, no stack spam — the
             # broker treats 210 as retryable and fails over to a replica
@@ -606,49 +639,50 @@ class ServerInstance:
             result = IntermediateResult(
                 exceptions=[(ErrorCode.QUERY_EXECUTION, f"{type(e).__name__}: {e}")]
             )
-        # per-query cost totals summed into the registry (the server
-        # half of the cost-accounting plane; the broker attributes the
-        # merged vector per table) — error results carry zero cost
-        self.metrics.meter("cost.docsScanned").mark(int(result.num_docs_scanned))
-        self.metrics.meter("cost.bytesScanned").mark(
-            int(result.cost.get("bytesScanned", 0))
-        )
-        for key, timer in (("deviceMs", "cost.deviceMs"), ("hostMs", "cost.hostMs")):
-            ms = result.cost.get(key)
-            if ms:
-                self.metrics.timer(timer).update(float(ms))
-        # serving-tier counters: the cost-vector segment counts mirrored
-        # into per-tier meters so /debug/plans tier mixes reconcile with
-        # a registry-level series (all zero for plain EXPLAIN)
-        for key in self._TIER_KEYS:
-            n = result.cost.get(key)
-            if n:
-                self.metrics.meter(f"cost.tier.{key}").mark(int(n))
-        exec_ms = (time.perf_counter() - t_start) * 1000
-        self._record_plan_stats(req, result, outcome, exec_ms)
-        self.metrics.timer("queryExecution").update(exec_ms)
-        self.metrics.meter("queries").mark()
-        # event-time freshness stamp (broker/freshness.py): realtime
-        # tables carry their stalest consumed partition watermark on the
-        # reply so the broker can derive freshnessMs; offline tables
-        # have no watermark entries and stamp nothing — their payloads
-        # stay byte-identical to the pre-audit-plane wire format
-        from pinot_tpu.broker.freshness import WATERMARKS
+        with boundary("serverBookkeeping", trace, self.metrics.timer("phase.serverBookkeeping")):
+            # per-query cost totals summed into the registry (the server
+            # half of the cost-accounting plane; the broker attributes the
+            # merged vector per table) — error results carry zero cost
+            self.metrics.meter("cost.docsScanned").mark(int(result.num_docs_scanned))
+            self.metrics.meter("cost.bytesScanned").mark(
+                int(result.cost.get("bytesScanned", 0))
+            )
+            for key, timer in (("deviceMs", "cost.deviceMs"), ("hostMs", "cost.hostMs")):
+                ms = result.cost.get(key)
+                if ms:
+                    self.metrics.timer(timer).update(float(ms))
+            # serving-tier counters: the cost-vector segment counts mirrored
+            # into per-tier meters so /debug/plans tier mixes reconcile with
+            # a registry-level series (all zero for plain EXPLAIN)
+            for key in self._TIER_KEYS:
+                n = result.cost.get(key)
+                if n:
+                    self.metrics.meter(f"cost.tier.{key}").mark(int(n))
+            exec_ms = (time.perf_counter() - t_start) * 1000
+            self._record_plan_stats(req, result, outcome, exec_ms)
+            self.metrics.timer("queryExecution").update(exec_ms)
+            self.metrics.meter("queries").mark()
+            # event-time freshness stamp (broker/freshness.py): realtime
+            # tables carry their stalest consumed partition watermark on the
+            # reply so the broker can derive freshnessMs; offline tables
+            # have no watermark entries and stamp nothing — their payloads
+            # stay byte-identical to the pre-audit-plane wire format
+            from pinot_tpu.broker.freshness import WATERMARKS
 
-        wm = WATERMARKS.table_min_ms(req["table"])
-        if wm is not None:
-            result.freshness = {"minEventMs": wm}
-        # backpressure snapshot on EVERY reply (including sheds): the
-        # broker's AIMD admission window reads it to back off before
-        # this server has to shed with 210s
-        result.backpressure = {
-            "pending": self.scheduler.pending,
-            "maxPending": self.scheduler.max_pending,
-            "laneDepth": 0
-            if self.lanes is None
-            else self.lanes.stats().get("depth", 0),
-        }
-        return serialize_result(result)
+            wm = WATERMARKS.table_min_ms(req["table"])
+            if wm is not None:
+                result.freshness = {"minEventMs": wm}
+            # backpressure snapshot on EVERY reply (including sheds): the
+            # broker's AIMD admission window reads it to back off before
+            # this server has to shed with 210s
+            result.backpressure = {
+                "pending": self.scheduler.pending,
+                "maxPending": self.scheduler.max_pending,
+                "laneDepth": 0
+                if self.lanes is None
+                else self.lanes.stats().get("depth", 0),
+            }
+        return result
 
     def _record_plan_stats(
         self, req: dict, result: IntermediateResult, outcome: str, exec_ms: float
@@ -934,32 +968,31 @@ class ServerInstance:
         self,
         req: dict,
         deadline: Optional[float] = None,
-        t_enqueue: Optional[float] = None,
+        trace: TraceContext = NULL_TRACE,
+        parent: Optional[str] = None,
     ) -> IntermediateResult:
-        request = parse_pql(req["pql"])
-        request.debug_options = dict(req.get("debugOptions") or {})
-        request = optimize_request(request)
-        request.enable_trace = bool(req.get("trace"))
-        # untraced requests share the NULL context: no span allocation
-        # anywhere on this path (the zero-overhead contract)
-        if request.enable_trace:
-            trace = TraceContext(
-                enabled=True, scope=self.name, trace_id=str(req.get("requestId") or "")
-            )
-        else:
-            trace = NULL_TRACE
-        token = set_current(trace if trace.enabled else None)
+        """On a scheduler worker: ``trace`` is the request's tree and
+        ``parent`` its ``serverQuery`` root, opened by the thread that
+        waits for this one."""
+        token = set_current(trace if trace.enabled else None, parent)
         try:
-            result = self._process_traced(req, request, trace, deadline, t_enqueue)
+            with boundary("serverParse", trace, self.metrics.timer("phase.serverParse")):
+                request = parse_pql(req["pql"])
+                request.debug_options = dict(req.get("debugOptions") or {})
+                request = optimize_request(request)
+                # plan-stats keying, computed where the parsed request
+                # exists so the recording path needs no second parse
+                from pinot_tpu.engine.plandigest import plan_shape_digest, plan_shape_summary
+
+                digest, summary = plan_shape_digest(request), plan_shape_summary(request)
+            request.enable_trace = trace.enabled
+            result = self._process_traced(req, request, trace, deadline)
         finally:
             reset_current(token)
-        # plan-stats keying, computed where the parsed request exists so
-        # handle_request's recording path needs no second parse
-        from pinot_tpu.engine.plandigest import plan_shape_digest, plan_shape_summary
-
-        result._plan_digest = plan_shape_digest(request)
-        result._plan_summary = plan_shape_summary(request)
+        result._plan_digest = digest
+        result._plan_summary = summary
         result._explain_mode = request.explain
+        result._t_done = time.perf_counter()  # for the waiting thread's workerWake
         return result
 
     def _process_traced(
@@ -968,145 +1001,135 @@ class ServerInstance:
         request,
         trace: TraceContext,
         deadline: Optional[float],
-        t_enqueue: Optional[float],
     ) -> IntermediateResult:
-        with trace.span(
-            "serverQuery", requestId=str(req.get("requestId") or ""), server=self.name
-        ):
-            if t_enqueue is not None:
-                # FCFS queue wait, child of serverQuery: the scheduler
-                # phase of the waterfall (metrics twin lives in
-                # QueryScheduler.run as phase.schedulerWait)
-                trace.add("queueWait", (time.monotonic() - t_enqueue) * 1000.0)
-            tdm = self.data_manager.table(req["table"])
-            if tdm is None:
-                # fall through to the trace attach below: the span tree
-                # for a misrouted query is exactly what an operator
-                # debugging stale routing needs to see
-                result = IntermediateResult(
-                    exceptions=[
-                        (ErrorCode.SERVER_SCHEDULER_DOWN, f"table {req['table']} not on server {self.name}")
-                    ]
-                )
-                trace.event("tableNotHosted", table=req["table"])
-                if trace.enabled:
-                    result.trace.update(trace.to_dict())
-                return result
-            names: Optional[Sequence[str]] = req["segments"] or None
+        tdm = self.data_manager.table(req["table"])
+        if tdm is None:
+            # fall through to the trace attach below: the span tree
+            # for a misrouted query is exactly what an operator
+            # debugging stale routing needs to see
+            result = IntermediateResult(
+                exceptions=[
+                    (ErrorCode.SERVER_SCHEDULER_DOWN, f"table {req['table']} not on server {self.name}")
+                ]
+            )
+            trace.event("tableNotHosted", table=req["table"])
+            return result
+        names: Optional[Sequence[str]] = req["segments"] or None
+        # refcounts taken, missing segments found, query views built
+        acquiring = boundary("segmentAcquire", trace, self.metrics.timer("phase.segmentAcquire")).start()
+        acquired: list = []
+        try:
             acquired = tdm.acquire_segments(names)
-            try:
-                # honest degradation: requested segments this server cannot
-                # serve right now (dropped, quarantined pending re-fetch…)
-                # are REPORTED, not silently skipped — the broker re-covers
-                # them on a replica or flips partialResponse /
-                # numSegmentsUnserved for the client
-                missing: List[str] = []
-                if names:
-                    held = {a.name for a in acquired}
-                    missing = [n for n in names if n not in held]
-                    if missing:
-                        self.metrics.meter("segmentsMissedServing").mark(len(missing))
-                views = [a.query_view() for a in acquired]
-                if req.get("join"):
-                    # distributed-join phase request (broker/joinplan.py):
-                    # extraction or join execution over the local views,
-                    # through the SAME fair-share scheduler slot this
-                    # request already queued in — one tenant's join
-                    # traffic is bounded exactly like its scans
-                    result = self._process_join(
-                        req, request, req["join"], views, deadline, trace
-                    )
-                    result.unserved_segments = missing
-                    if trace.enabled:
-                        result.trace.update(trace.to_dict())
-                    return result
-                if request.explain == "plan":
-                    # EXPLAIN: the physical plan INSTEAD of execution —
-                    # zero lane submissions, zero cost (safe to call in
-                    # production; tier-1 guarded)
-                    from pinot_tpu.engine.explain import build_explain_node
-
-                    with trace.span("explainPlan", segments=len(acquired)):
-                        node = build_explain_node(
-                            self.executor, views, request, req["table"],
-                            self.name, plan_stats=self.plan_stats,
-                            result_cache=self.result_cache,
-                        )
-                    node["mode"] = "plan"
-                    self.metrics.meter("plan.explains").mark()
-                    result = IntermediateResult(
-                        total_docs=int(node.get("totalDocs") or 0),
-                        plan_info=[node],
-                    )
-                else:
-                    # ingest-aware result cache: the key covers the
-                    # exact staged data generation (segment names +
-                    # process-unique staging tokens), so a hit is
-                    # provably as fresh as re-executing — and costs
-                    # zero device work.  Traced/EXPLAIN requests and
-                    # partial covers bypass (key_for + the missing
-                    # guard); results with exceptions are never stored.
-                    ckey = None
-                    cache = self.result_cache
-                    if cache.enabled and not missing:
-                        ckey = cache.key_for(request, views, req["table"])
-                    result = cache.get(ckey) if ckey is not None else None
-                    if result is not None:
-                        # the hit executed nothing: the live span tree
-                        # records the verdict instead of phase spans
-                        trace.event("rescacheHit")
-                    else:
-                        with trace.span("planAndExecute", segments=len(acquired)):
-                            result = self.executor.execute(
-                                views, request, deadline=deadline
-                            )
-                        if ckey is not None and not result.exceptions:
-                            cache.put(ckey, result)
-                    if request.explain == "analyze":
-                        # EXPLAIN ANALYZE: the prediction is built AFTER
-                        # execution (so quarantine/compile state reflects
-                        # what just happened) and annotated with actuals
-                        # straight off this reply's cost vector — the
-                        # per-node actuals sum EXACTLY to the broker's
-                        # merged cost because only merged replies'
-                        # plan nodes survive the gather
-                        from pinot_tpu.engine.explain import (
-                            _json_safe,
-                            build_explain_node,
-                        )
-
-                        node = build_explain_node(
-                            self.executor, views, request, req["table"],
-                            self.name, plan_stats=self.plan_stats,
-                            result_cache=self.result_cache,
-                        )
-                        node["mode"] = "analyze"
-                        node["actualCost"] = _json_safe(dict(result.cost))
-                        node["actualDocsScanned"] = int(result.num_docs_scanned)
-                        dev_node = node.get("device")
-                        if isinstance(dev_node, dict) and "batching" in dev_node:
-                            # batching ACTUAL off this very execution:
-                            # how many same-shape peers the launch
-                            # carried.  (No actualCacheHit field:
-                            # ANALYZE always executes — the cache is
-                            # keyed off for explain modes — so the
-                            # standing-entry probe `cacheHit` is the
-                            # honest cache signal here.)
-                            dev_node["batching"]["actualBatchSize"] = int(
-                                getattr(result, "_batch_size", 1) or 1
-                            )
-                        result.plan_info = [node]
-                if not missing:
-                    # shadow-audit sampling hook (utils/audit.py): the
-                    # held views pin the exact served snapshot; the
-                    # offer itself is one counter increment for the
-                    # non-sampled 1-in-N losers
-                    self.auditor.offer(req, request, views, result)
+            # honest degradation: requested segments this server cannot
+            # serve right now (dropped, quarantined pending re-fetch…)
+            # are REPORTED, not silently skipped — the broker re-covers
+            # them on a replica or flips partialResponse /
+            # numSegmentsUnserved for the client
+            missing: List[str] = []
+            if names:
+                held = {a.name for a in acquired}
+                missing = [n for n in names if n not in held]
+                if missing:
+                    self.metrics.meter("segmentsMissedServing").mark(len(missing))
+            views = [a.query_view() for a in acquired]
+            acquiring.stop()
+            if req.get("join"):
+                # distributed-join phase request (broker/joinplan.py):
+                # extraction or join execution over the local views,
+                # through the SAME fair-share scheduler slot this
+                # request already queued in — one tenant's join
+                # traffic is bounded exactly like its scans
+                result = self._process_join(
+                    req, request, req["join"], views, deadline, trace
+                )
                 result.unserved_segments = missing
-            finally:
-                tdm.release_segments(acquired)
-        if trace.enabled:
-            result.trace.update(trace.to_dict())
+                return result
+            if request.explain == "plan":
+                # EXPLAIN: the physical plan INSTEAD of execution —
+                # zero lane submissions, zero cost (safe to call in
+                # production; tier-1 guarded)
+                from pinot_tpu.engine.explain import build_explain_node
+
+                with trace.span("explainPlan", segments=len(acquired)):
+                    node = build_explain_node(
+                        self.executor, views, request, req["table"],
+                        self.name, plan_stats=self.plan_stats,
+                        result_cache=self.result_cache,
+                    )
+                node["mode"] = "plan"
+                self.metrics.meter("plan.explains").mark()
+                result = IntermediateResult(
+                    total_docs=int(node.get("totalDocs") or 0),
+                    plan_info=[node],
+                )
+            else:
+                # ingest-aware result cache: the key covers the
+                # exact staged data generation (segment names +
+                # process-unique staging tokens), so a hit is
+                # provably as fresh as re-executing — and costs
+                # zero device work.  Traced/EXPLAIN requests and
+                # partial covers bypass (key_for + the missing
+                # guard); results with exceptions are never stored.
+                ckey = None
+                cache = self.result_cache
+                if cache.enabled and not missing:
+                    ckey = cache.key_for(request, views, req["table"])
+                result = cache.get(ckey) if ckey is not None else None
+                if result is not None:
+                    # the hit executed nothing: the live span tree
+                    # records the verdict instead of phase spans
+                    trace.event("rescacheHit")
+                else:
+                    with boundary("planAndExecute", trace, segments=len(acquired)):
+                        result = self.executor.execute(
+                            views, request, deadline=deadline
+                        )
+                    if ckey is not None and not result.exceptions:
+                        cache.put(ckey, result)
+                if request.explain == "analyze":
+                    # EXPLAIN ANALYZE: the prediction is built AFTER
+                    # execution (so quarantine/compile state reflects
+                    # what just happened) and annotated with actuals
+                    # straight off this reply's cost vector — the
+                    # per-node actuals sum EXACTLY to the broker's
+                    # merged cost because only merged replies'
+                    # plan nodes survive the gather
+                    from pinot_tpu.engine.explain import (
+                        _json_safe,
+                        build_explain_node,
+                    )
+
+                    node = build_explain_node(
+                        self.executor, views, request, req["table"],
+                        self.name, plan_stats=self.plan_stats,
+                        result_cache=self.result_cache,
+                    )
+                    node["mode"] = "analyze"
+                    node["actualCost"] = _json_safe(dict(result.cost))
+                    node["actualDocsScanned"] = int(result.num_docs_scanned)
+                    dev_node = node.get("device")
+                    if isinstance(dev_node, dict) and "batching" in dev_node:
+                        # batching ACTUAL off this very execution:
+                        # how many same-shape peers the launch
+                        # carried.  (No actualCacheHit field:
+                        # ANALYZE always executes — the cache is
+                        # keyed off for explain modes — so the
+                        # standing-entry probe `cacheHit` is the
+                        # honest cache signal here.)
+                        dev_node["batching"]["actualBatchSize"] = int(
+                            getattr(result, "_batch_size", 1) or 1
+                        )
+                    result.plan_info = [node]
+            if not missing:
+                # shadow-audit sampling hook (utils/audit.py): the
+                # held views pin the exact served snapshot; the
+                # offer itself is one counter increment for the
+                # non-sampled 1-in-N losers
+                self.auditor.offer(req, request, views, result)
+            result.unserved_segments = missing
+        finally:
+            acquiring.stop()
+            tdm.release_segments(acquired)
         return result
 
     # -- distributed joins (engine/join.py + broker/joinplan.py) ------
@@ -1142,33 +1165,31 @@ class ServerInstance:
             left_cols, right_cols = join_mod.side_columns(request)
             if phase == "extract":
                 side_name = jctx.get("side")
-                if side_name == "build":
-                    stripped = [spec.strip_right(c) for c in right_cols]
-                    name_of = {spec.strip_right(c): c for c in right_cols}
-                    rows, matched = join_mod.extract_side(
-                        views, right_f, spec.right_key, stripped, name_of
+                with self.executor._phase("joinExtract", side=side_name, segments=len(views)):
+                    if side_name == "build":
+                        stripped = [spec.strip_right(c) for c in right_cols]
+                        name_of = {spec.strip_right(c): c for c in right_cols}
+                        rows, matched = join_mod.extract_side(
+                            views, right_f, spec.right_key, stripped, name_of
+                        )
+                        read_cols = [spec.right_key, *stripped]
+                    else:
+                        rows, matched = join_mod.extract_side(
+                            views, left_f, spec.left_key, left_cols
+                        )
+                        read_cols = [spec.left_key, *left_cols]
+                    res = IntermediateResult(
+                        num_docs_scanned=matched,
+                        total_docs=sum(v.num_docs for v in views),
+                        num_segments_queried=len(views),
                     )
-                    read_cols = [spec.right_key, *stripped]
-                else:
-                    rows, matched = join_mod.extract_side(
-                        views, left_f, spec.left_key, left_cols
+                    res.add_cost(
+                        hostMs=round((time.perf_counter() - t0) * 1000, 3),
+                        bytesScanned=self._extract_bytes(views, read_cols),
                     )
-                    read_cols = [spec.left_key, *left_cols]
-                res = IntermediateResult(
-                    num_docs_scanned=matched,
-                    total_docs=sum(v.num_docs for v in views),
-                    num_segments_queried=len(views),
-                )
-                res.add_cost(
-                    hostMs=round((time.perf_counter() - t0) * 1000, 3),
-                    bytesScanned=self._extract_bytes(views, read_cols),
-                )
-                res.join_payload = join_mod.encode_side(rows)
-                self.metrics.meter("join.extracts").mark()
-                self.executor._phase(
-                    "joinExtract", t0, side=side_name, segments=len(views)
-                )
-                return res
+                    res.join_payload = join_mod.encode_side(rows)
+                    self.metrics.meter("join.extracts").mark()
+                    return res
 
             if phase != "exec":
                 raise join_mod.JoinValidationError(

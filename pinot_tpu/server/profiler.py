@@ -29,15 +29,28 @@ Semantics:
 
 The hot path cost while idle is literally zero — nothing is consulted
 per query; the profiler only acts inside start/stop.
+
+**The summary.**  ``stop`` reduces the capture it just closed and
+returns the result under ``summary`` (also written beside the trace as
+``pinot_summary.json``): busy share per device, device seconds per
+program name, and the idle seconds of the first device by the innermost
+``pinot:<name>`` span (utils/trace.py ``boundary``) open on the host at
+each instant of a gap, ``no_query_in_flight`` where none is.
+``load_capture`` reads the ``.xplane.pb`` into plain lists,
+``summarize`` is arithmetic on them.
 """
 from __future__ import annotations
 
+import glob
+import heapq
+import json
 import logging
 import os
+import re
 import shutil
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 logger = logging.getLogger(__name__)
 
@@ -50,9 +63,149 @@ def _default_trace_api():
     try:
         from jax import profiler as jprof
 
-        return jprof.start_trace, jprof.stop_trace
+        def start(capture_dir: str) -> None:
+            # the interpreter's own tracer (on by default) slows the host
+            # it measures and swamps the trace; the host planes keep the
+            # TraceMe events, the pinot: spans among them
+            options = jprof.ProfileOptions()
+            options.python_tracer_level = 0
+            jprof.start_trace(capture_dir, profiler_options=options)
+
+        return start, jprof.stop_trace
     except Exception as e:  # pragma: no cover - import environment
         raise ProfilerUnavailableError(f"jax.profiler unavailable: {e}")
+
+
+SPAN_PREFIX = "pinot:"
+NO_QUERY = "no_query_in_flight"
+SUMMARY_FILE = "pinot_summary.json"
+_FINGERPRINT = re.compile(r"\(\d+\)$")  # jit_<name>(<XLA's fingerprint>)
+
+
+def load_capture(path_or_data) -> Dict[str, Any]:
+    """``{"devices": {plane: {"ops": [(start_ns, end_ns)], "programs":
+    [(name, start_ns, end_ns)]}}, "spans": [(name, start_ns, end_ns)]}``
+    from an ``.xplane.pb`` (or a ``ProfileData``).  Device planes are
+    ``/device:<kind>:<n>``, their ``XLA Ops`` line holds the executed
+    operations and ``XLA Modules`` one event per program launch; the
+    ``pinot:`` spans are on the host planes, on the same clock."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(path_or_data) if isinstance(path_or_data, str) else path_or_data
+    devices: Dict[str, Dict[str, list]] = {}
+    spans: List[Tuple[str, float, float]] = []
+    for plane in data.planes:
+        if plane.name.startswith("/device:"):
+            lines = {line.name: line for line in plane.lines}
+            if "XLA Ops" not in lines:
+                continue
+            devices[plane.name] = {
+                "ops": [(float(e.start_ns), float(e.start_ns + e.duration_ns))
+                        for e in lines["XLA Ops"].events],
+                "programs": [(_FINGERPRINT.sub("", e.name), float(e.start_ns),
+                              float(e.start_ns + e.duration_ns))
+                             for e in lines["XLA Modules"].events] if "XLA Modules" in lines else [],
+            }
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(SPAN_PREFIX):
+                        spans.append((e.name, float(e.start_ns), float(e.start_ns + e.duration_ns)))
+    return {"devices": devices, "spans": spans}
+
+
+def _union(intervals: list) -> list:
+    out: list = []
+    for start, end in sorted(intervals):
+        if out and start <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], end)
+        else:
+            out.append([start, end])
+    return out
+
+
+def innermost_segments(spans: list) -> list:
+    """``[(start, end, name)]``, disjoint and sorted: for every instant
+    at which some span is open, the one that opened last (across
+    threads: a worker's ``planBuild`` inside the HTTP thread's
+    ``scatterGather``)."""
+    cuts = sorted({t for _, s, e in spans for t in (s, e)})
+    by_start = sorted(spans, key=lambda x: x[1])
+    open_heap: list = []  # (-start, end, name)
+    out, i = [], 0
+    for t0, t1 in zip(cuts, cuts[1:]):
+        while i < len(by_start) and by_start[i][1] <= t0:
+            name, start, end = by_start[i]
+            heapq.heappush(open_heap, (-start, end, name))
+            i += 1
+        while open_heap and open_heap[0][1] <= t0:
+            heapq.heappop(open_heap)  # lazily: ended spans leave when they surface
+        if open_heap:
+            name = open_heap[0][2]
+            if out and out[-1][2] == name and out[-1][1] == t0:
+                out[-1][1] = t1
+            else:
+                out.append([t0, t1, name])
+    return out
+
+
+def summarize(loaded: Dict[str, Any]) -> Dict[str, Any]:
+    """The capture in numbers.  The window runs from the first to the
+    last event (operation or span); ``idle`` is the first device's."""
+    planes = sorted(loaded["devices"])
+    spans = loaded["spans"]
+    edges = [t for p in planes for s, e in loaded["devices"][p]["ops"] for t in (s, e)]
+    edges += [t for _, s, e in spans for t in (s, e)]
+    if not planes or not edges:
+        return {"windowS": 0.0, "devices": {}, "programs": {}, "idle": {}, "idleS": 0.0}
+    lo, hi = min(edges), max(edges)
+    devices, programs, busy0 = {}, {}, []
+    for n, plane in enumerate(planes):
+        busy = _union(loaded["devices"][plane]["ops"])
+        if n == 0:
+            busy0 = busy
+        busy_s = sum(e - s for s, e in busy) / 1e9
+        devices[plane] = {"busyS": busy_s, "busyShare": busy_s / ((hi - lo) / 1e9)}
+        for name, start, end in loaded["devices"][plane]["programs"]:
+            programs[name] = programs.get(name, 0.0) + (end - start) / 1e9 / len(planes)
+    bounds = [lo] + [t for iv in busy0 for t in iv] + [hi]
+    gaps = [(a, b) for a, b in zip(bounds[0::2], bounds[1::2]) if b > a]
+    idle: Dict[str, float] = {}
+    segments, k = innermost_segments(spans), 0
+    for g0, g1 in gaps:
+        covered = 0.0
+        while k < len(segments) and segments[k][1] <= g0:
+            k += 1
+        j = k
+        while j < len(segments) and segments[j][0] < g1:
+            cover = min(segments[j][1], g1) - max(segments[j][0], g0)
+            idle[segments[j][2]] = idle.get(segments[j][2], 0.0) + cover / 1e9
+            covered += cover
+            j += 1
+        idle[NO_QUERY] = idle.get(NO_QUERY, 0.0) + (g1 - g0 - covered) / 1e9
+    return {
+        "windowS": (hi - lo) / 1e9,
+        "devices": devices,
+        "programs": dict(sorted(programs.items(), key=lambda x: -x[1])),
+        "idle": dict(sorted(idle.items(), key=lambda x: -x[1])),
+        "idleS": sum(b - a for a, b in gaps) / 1e9,
+    }
+
+
+def summarize_capture(capture_dir: str) -> Dict[str, Any]:
+    """Reduce the newest trace under ``capture_dir`` and leave the
+    result beside it; ``{"error": ...}`` where there is nothing to read."""
+    paths = sorted(glob.glob(os.path.join(capture_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        return {"error": f"no .xplane.pb under {capture_dir}"}
+    try:
+        summary = summarize(load_capture(paths[-1]))
+        with open(os.path.join(capture_dir, SUMMARY_FILE), "w") as f:
+            json.dump(summary, f, indent=1)
+    except Exception as e:  # a capture nobody can reduce is still a capture
+        logger.warning("profile summary failed: %s", e)
+        return {"error": f"{type(e).__name__}: {e}"}
+    return summary
 
 
 class DeviceProfiler:
@@ -139,16 +292,21 @@ class DeviceProfiler:
         Stopping an inactive profiler is a no-op snapshot (idempotent
         — a retried stop after a timeout must not error)."""
         ended = False
+        capture_dir = None
         with self._lock:
             if self._refcount > 0:
                 self._refcount -= 1
                 if self.metrics is not None:
                     self.metrics.meter("profile.stops").mark()
                 if self._refcount == 0:
+                    capture_dir = self._capture_dir
                     ended = self._end_capture_locked()
             snap = self._snapshot_locked()
         if ended:
             self._fire_capture_end()
+            # outside the lock: reading a trace of a few seconds of
+            # traffic takes seconds itself
+            snap["summary"] = summarize_capture(capture_dir)
         return snap
 
     def snapshot(self) -> Dict[str, Any]:

@@ -39,6 +39,8 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
+from pinot_tpu.utils.trace import measured
+
 # fair-share default queue for table-less submits (unit tests, internal
 # work): behaves exactly like any other table queue
 DEFAULT_QUEUE = ""
@@ -61,14 +63,16 @@ class QueryAbandonedError(RuntimeError):
 
 
 class _Entry:
-    __slots__ = ("fn", "future", "deadline", "table", "t_submit")
+    __slots__ = ("fn", "future", "deadline", "table", "t_submit", "trace", "parent")
 
-    def __init__(self, fn, future, deadline, table, t_submit) -> None:
+    def __init__(self, fn, future, deadline, table, t_submit, trace=None, parent=None) -> None:
         self.fn = fn
         self.future = future
         self.deadline = deadline
         self.table = table
         self.t_submit = t_submit
+        self.trace = trace  # the request's span tree, for its queueWait span
+        self.parent = parent  # ... and the span it hangs under
 
 
 # live worker-thread registry for the conftest leak guard (same pattern
@@ -247,6 +251,8 @@ class QueryScheduler:
         fn: Callable[[], Any],
         table: str = DEFAULT_QUEUE,
         deadline: Optional[float] = None,
+        trace=None,
+        parent: Optional[str] = None,
     ) -> concurrent.futures.Future:
         fut: concurrent.futures.Future = concurrent.futures.Future()
         with self._cv:
@@ -273,7 +279,7 @@ class QueryScheduler:
                     f"fair-share cap {cap} "
                     f"({self._pending_total}/{self._max_pending} total)",
                 )
-            entry = _Entry(fn, fut, deadline, table, time.monotonic())
+            entry = _Entry(fn, fut, deadline, table, time.monotonic(), trace, parent)
             q = self._queues.get(table)
             if q is None:
                 q = self._queues[table] = deque()
@@ -361,12 +367,16 @@ class QueryScheduler:
         if not fut.set_running_or_notify_cancel():
             return  # cancelled while queued; done-callback freed the slot
         now = time.monotonic()
-        if self.metrics is not None:
-            # FCFS queue wait — the ServerQueryPhase SCHEDULER_WAIT
-            # analog, measured submit -> worker dequeue
-            self.metrics.timer("phase.schedulerWait").update(
-                (now - entry.t_submit) * 1000.0
-            )
+        # FCFS queue wait — the ServerQueryPhase SCHEDULER_WAIT analog,
+        # measured submit -> worker dequeue: timer phase.schedulerWait
+        # and, on a traced request, the span queueWait
+        measured(
+            "queueWait",
+            (now - entry.t_submit) * 1000.0,
+            entry.trace,
+            self.metrics.timer("phase.schedulerWait") if self.metrics is not None else None,
+            parent=entry.parent,
+        )
         if entry.deadline is not None and now >= entry.deadline:
             with self._cv:
                 self._abandoned += 1
@@ -389,9 +399,12 @@ class QueryScheduler:
         timeout_s: float,
         deadline: Optional[float] = None,
         table: str = DEFAULT_QUEUE,
+        trace=None,
+        parent: Optional[str] = None,
     ) -> Any:
         """Run ``fn`` with at most ``timeout_s`` of wall budget on
-        ``table``'s fair-share queue.
+        ``table``'s fair-share queue.  ``trace``/``parent``: the
+        request's span tree and the span its queue wait hangs under.
 
         ``deadline`` (monotonic seconds) defaults to now+timeout_s; it is
         checked at dequeue time so a query whose budget drained in the
@@ -409,7 +422,7 @@ class QueryScheduler:
             raise QueryAbandonedError(
                 "deadline expired while queued; broker already gave up"
             )
-        fut = self.submit(fn, table=table, deadline=deadline)
+        fut = self.submit(fn, table=table, deadline=deadline, trace=trace, parent=parent)
         try:
             return fut.result(timeout=max(0.0, deadline - time.monotonic()))
         except concurrent.futures.TimeoutError as e:

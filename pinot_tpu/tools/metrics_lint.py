@@ -9,7 +9,9 @@ series that dashboards and alerts never see.
 
 Dynamic names are declared in the catalogs with ``*`` wildcards
 (``phase.*``, ``*.segmentCount``); an f-string call site is normalized
-by replacing each ``{...}`` part with ``*`` before matching.
+by replacing each ``{...}`` part with ``*`` before matching.  A literal
+call site needs a literal entry: a wildcard (``phase.*``) stands for
+the names code composes, not for whatever a new call site spells.
 
 Run standalone (``python -m pinot_tpu.tools.metrics_lint``) or as the
 tier-1 test ``tests/test_observability.py::test_metrics_lint``.
@@ -40,18 +42,16 @@ _CANON_RE = re.compile(r"\*+")
 
 
 def _matches(used: str, entry: str) -> bool:
-    """A literal use matches a literal entry exactly or a wildcard entry
-    as a glob; an f-string use (normalized to ``*``) matches an entry
-    with the same fixed skeleton, or any literal entry the pattern
-    covers (``heal.*`` is satisfied by ``heal.deviceFailures``)."""
+    """A literal use matches a literal entry exactly; an f-string use
+    (normalized to ``*``) matches an entry with the same fixed skeleton,
+    or any literal entry the pattern covers (``heal.*`` is satisfied by
+    ``heal.deviceFailures``)."""
     import fnmatch
 
     if "*" in used:
         if _CANON_RE.sub("*", used) == _CANON_RE.sub("*", entry):
             return True
         return "*" not in entry and fnmatch.fnmatchcase(entry, used)
-    if "*" in entry:
-        return fnmatch.fnmatchcase(used, entry)
     return used == entry
 
 

@@ -200,26 +200,30 @@ class MetricsRegistry:
         self._gauges: Dict[str, Gauge] = {}
         self._lock = threading.Lock()
 
+    # a series that exists is found without the lock (a dict read is
+    # atomic): the boundaries of a query look up some forty timers, from
+    # six threads, and one lock for the whole role is a queue
+
     def meter(self, name: str) -> Meter:
-        with self._lock:
-            m = self._meters.get(name)
-            if m is None:
-                m = self._meters[name] = Meter()
-            return m
+        m = self._meters.get(name)
+        if m is None:
+            with self._lock:
+                m = self._meters.setdefault(name, Meter())
+        return m
 
     def timer(self, name: str) -> Timer:
-        with self._lock:
-            t = self._timers.get(name)
-            if t is None:
-                t = self._timers[name] = Timer()
-            return t
+        t = self._timers.get(name)
+        if t is None:
+            with self._lock:
+                t = self._timers.setdefault(name, Timer())
+        return t
 
     def gauge(self, name: str) -> Gauge:
-        with self._lock:
-            g = self._gauges.get(name)
-            if g is None:
-                g = self._gauges[name] = Gauge()
-            return g
+        g = self._gauges.get(name)
+        if g is None:
+            with self._lock:
+                g = self._gauges.setdefault(name, Gauge())
+        return g
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
@@ -388,6 +392,21 @@ BROKER_METRIC_CATALOG: Dict[str, str] = {
     "phase.route": "routing-table lookup + batch build time",
     "scatterGather": "scatter-gather wall time per query",
     "reduce": "partial-merge + finalize time per query",
+    # the boundaries around handle_pql (utils/trace.py boundary): with
+    # the ones above they cover a query's wall time inside the broker
+    "httpTotal": "HTTP handler entry to last byte of the reply written",
+    "phase.httpRead": "reading and decoding the HTTP request",
+    "phase.render": "resp.to_json() + json.dumps + writing the reply",
+    "phase.bookkeeping": "planstats, tail sampler, SLO and query-log "
+    "records, made before the reply is written",
+    "phase.attemptSubmit": "one scatter attempt made ready: circuit-breaker "
+    "claim, attempt budget, span id",
+    "phase.poolQueue": "scatter attempt handed to the pool -> a pool thread "
+    "runs it",
+    "phase.gatherWake": "pool thread has the server's result -> the gather "
+    "loop has it",
+    "phase.serializeRequest": "InstanceRequest encode, per scatter attempt",
+    "phase.deserializeResult": "DataTable decode, per scatter attempt",
     "serverLatency": "per-attempt server round-trip latency",
     # cost-accounting plane (merged per-query cost vector totals)
     "cost.docsScanned": "documents scanned, summed over merged responses",
@@ -469,13 +488,38 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "queryExecution": "end-to-end server handle_request latency",
     "scheduler.pending": "queries queued-or-running on the scheduler",
     "phase.schedulerWait": "time from submit to worker dequeue",
+    "phase.deserializeRequest": "InstanceRequest decode",
+    "phase.serverParse": "the server's own PQL parse + optimize + plan "
+    "digest",
+    "phase.segmentAcquire": "segment refcounts taken, missing segments "
+    "found, query views built",
+    "phase.workerWake": "scheduler worker has the result -> the thread "
+    "that waits for it runs again",
+    "phase.laneWake": "lane thread delivered -> the waiting worker runs "
+    "again",
+    "phase.serverBookkeeping": "cost meters, plan stats, freshness and "
+    "backpressure stamps, made before the reply is encoded",
+    "phase.serializeResult": "DataTable encode",
+    "phase.laneQueue": "lane submit to the launch call: queue and "
+    "batch-formation wait, per dispatch",
+    "phase.laneDispatch": "the launch call on the lane thread: jit "
+    "dispatch + input H2D (a first launch: trace + compile)",
+    "phase.laneDeliver": "launch call returned to waiters delivered: compile "
+    "timeline, coalescing set and meters under the lane lock",
+    "phase.kernelPrep": "kernel lookup, batch spec and eager input upload "
+    "between plan build and lane submit",
+    "phase.deviceWait": "block_until_ready on the launch's output",
+    "phase.d2hUnpack": "np.asarray of the packed buffer + unpack",
+    "phase.joinExtract": "join side extraction (remote or local)",
+    "lane.deviceBusy": "closed occupancy windows, cumulative: launch call "
+    "entered to output seen ready, the union over outstanding launches",
     # fair-share scheduling plane (per-table DRR queues)
     "fairshare.activeTables": "tables with a non-empty scheduler queue",
     "fairshare.shed": "submits shed by the global or per-table "
     "fair-share pending cap (210 on the wire)",
     "phase.*": "per-stage executor phase timers (staging, planBuild, "
-    "laneWait, planExec, finalize, indexPath, hostPath, hostFailover, "
-    "laneDispatch)",
+    "prune, kernelPrep, laneWait, planExec, deviceWait, d2hUnpack, finalize, "
+    "indexPath, bitslicedPath, hostPath, hostFailover)",
     "heal.deviceFailures": "device launch failures (classified)",
     "heal.deviceRetries": "transient device failures retried on device",
     "heal.hostFailovers": "queries transparently served via the host path",

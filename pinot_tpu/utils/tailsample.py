@@ -230,6 +230,22 @@ class TailSampler:
         )
         return reason
 
+    def complete(
+        self, request_id: str, scopes: Dict[str, List[Dict[str, Any]]]
+    ) -> bool:
+        """Replace a retained entry's scopes of the same names with
+        ``scopes``: the front end's own tree once the reply is written.
+        ``observe`` runs before the reply is rendered, so the tree it
+        kept ends there (``httpTotal`` and ``bookkeeping`` still open,
+        no ``render``)."""
+        with self._lock:
+            for entry in reversed(self._ring):
+                if entry["requestId"] == request_id:
+                    entry["scopes"] = dict(entry["scopes"], **scopes)
+                    entry["phaseSelfMs"] = phase_self_ms(entry["scopes"])
+                    return True
+        return False
+
     # -- read side -----------------------------------------------------
     def get(self, request_id: str) -> Optional[Dict[str, Any]]:
         """Full retained entry (scopes included) by requestId — the

@@ -1,0 +1,355 @@
+"""Layer boundaries (PR 25): the one helper of ``utils/trace.py``, the
+wall-time cover of a query over HTTP, the jitted programs' names, the
+lane's device-busy window and the profiler's capture summary."""
+import glob
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+import urllib.request
+
+import pytest
+
+import pinot_tpu.utils.trace as trace_mod
+from pinot_tpu.utils.metrics import ServerMetrics
+from pinot_tpu.utils.tailsample import phase_self_ms
+from pinot_tpu.utils.trace import TraceContext, boundary, measured, phases
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+
+
+# -- the helper -------------------------------------------------------------
+
+
+def test_boundary_emits_timer_span_and_annotation_from_one_call(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    metrics = ServerMetrics("s0")
+    ctx = TraceContext(enabled=True, scope="s0", trace_id="rid-7")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with boundary("outer", ctx, metrics.timer("phase.outer")):
+            with boundary("launch", ctx, metrics.timer("phase.launch"), program="pinot_scan_agg_0"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    # (a) the timers
+    assert metrics.timer("phase.launch").count == 1 and metrics.timer("phase.outer").count == 1
+    assert 2.0 <= metrics.timer("phase.launch").total_ms <= metrics.timer("phase.outer").total_ms
+    # (b) the spans, nested, with the timer's milliseconds
+    outer, launch = ctx.to_dict()["s0"]
+    assert (outer["span"], launch["span"], launch["parent"]) == ("outer", "launch", outer["id"])
+    assert launch["ms"] == pytest.approx(metrics.timer("phase.launch").total_ms, abs=1e-3)
+    assert launch["tags"] == {"program": "pinot_scan_agg_0"}
+    # (c) the same intervals on the profiler's host plane, with the request id
+    path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))[0]
+    events = {
+        e.name: (e.duration_ns, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events if e.name.startswith("pinot:")
+    }
+    assert set(events) == {"pinot:outer", "pinot:launch"}
+    assert events["pinot:launch"][1] == {"rid": "rid-7", "program": "pinot_scan_agg_0"}
+    assert events["pinot:launch"][0] / 1e6 == pytest.approx(launch["ms"], abs=0.5)
+
+
+@pytest.mark.parametrize("ctx", [None, trace_mod.NULL_TRACE, TraceContext(enabled=False)])
+def test_boundary_allocates_no_span_when_the_tree_is_disabled(ctx):
+    metrics = ServerMetrics("s0")
+    before = trace_mod.SPAN_ALLOCATIONS
+    with boundary("parse", ctx, metrics.timer("phase.parse"), requestId="r") as b:
+        b.tag(coalesced=True)
+        measured("queueWait", 1.5, ctx, metrics.timer("phase.schedulerWait"))
+    cursor = phases(lambda name, **tags: boundary(name, ctx, metrics.timer(f"phase.{name}"), **tags))
+    cursor.enter("staging")
+    cursor.relabel("indexPath")
+    cursor.stop()
+    assert trace_mod.SPAN_ALLOCATIONS == before
+    assert b.span_id is None
+    # the timers are kept all the same
+    assert metrics.timer("phase.parse").count == 1
+    assert metrics.timer("phase.schedulerWait").total_ms == 1.5
+    assert metrics.timer("phase.indexPath").count == 1 and metrics.timer("phase.staging").count == 0
+
+
+def test_open_spans_read_their_time_so_far_and_reserved_ids_parent_other_threads():
+    ctx = TraceContext(enabled=True, scope="b0", trace_id="r")
+    root = boundary("httpTotal", ctx).start()
+    aid = ctx.reserve()
+
+    def pool_thread():
+        with boundary("serializeRequest", ctx, parent=aid):
+            pass
+
+    t = threading.Thread(target=pool_thread)
+    t.start()
+    t.join()
+    ctx.add("serverAttempt", 1.0, span_id=aid)
+    time.sleep(0.002)
+    cut = {s["span"]: s for s in ctx.to_dict()["b0"]}
+    assert cut["httpTotal"]["tags"]["open"] is True and cut["httpTotal"]["ms"] >= 2.0
+    assert cut["serializeRequest"]["parent"] == aid == cut["serverAttempt"]["id"]
+    assert cut["serverAttempt"]["parent"] == cut["httpTotal"]["id"]
+    root.stop()
+    done = {s["span"]: s for s in ctx.to_dict()["b0"]}
+    assert "tags" not in done["httpTotal"] and done["httpTotal"]["ms"] >= cut["httpTotal"]["ms"]
+
+
+# -- a query's wall time, from inside the program ----------------------------
+
+
+@pytest.fixture(scope="module")
+def http_cluster(tmp_path_factory):
+    from pinot_tpu.tools.cluster_harness import InProcessCluster
+    from pinot_tpu.tools.datagen import lineitem_schema, synthetic_lineitem_segment
+
+    cluster = InProcessCluster(num_servers=1, data_dir=str(tmp_path_factory.mktemp("data")), http=True)
+    try:
+        table = cluster.add_offline_table(lineitem_schema())
+        for i in range(2):
+            cluster.upload(table, synthetic_lineitem_segment(5000, seed=i, name=f"seg{i}"))
+        yield cluster
+    finally:
+        cluster.stop()
+        for server in cluster.servers:
+            server.shutdown()
+
+
+def _post(cluster, pql: str, **extra) -> dict:
+    req = urllib.request.Request(
+        f"http://{cluster.http.host}:{cluster.http.port}/query",
+        json.dumps(dict(extra, pql=pql)).encode(), {"Content-Type": "application/json"})
+    return json.loads(urllib.request.urlopen(req, timeout=60).read())
+
+
+K6 = ("SELECT sum(l_extendedprice), count(*) FROM lineitem "
+      "GROUP BY l_returnflag, l_linestatus TOP 10")
+
+BROKER_SPANS = {"httpTotal", "httpRead", "query", "parse", "route", "scatterGather", "serverAttempt",
+                "attemptSubmit", "poolQueue", "serializeRequest", "deserializeResult", "gatherWake", "reduce", "bookkeeping",
+                "render"}
+SERVER_SPANS = {"serverQuery", "queueWait", "serverParse", "segmentAcquire", "planAndExecute", "prune",
+                "staging", "planBuild", "kernelPrep", "laneWait", "laneQueue", "laneDispatch", "laneDeliver",
+                "laneWake", "planExec", "deviceWait", "d2hUnpack", "finalize", "workerWake",
+                "serverBookkeeping"}
+
+
+def test_self_times_under_httpTotal_sum_to_its_duration(http_cluster, monkeypatch):
+    cluster = http_cluster
+    _post(cluster, K6)  # warm: staging and the compile are not this test's
+    monkeypatch.setattr(cluster.broker.tail, "slow_ms", 0.0)  # keep every tree
+    reply = _post(cluster, K6, trace=True)
+    assert not reply["exceptions"]
+    # the reply's own tree is cut before the reply is rendered: its root is still open
+    cut = {s["span"]: s for s in reply["traceInfo"]["scopes"][cluster.broker.name]}
+    assert cut["httpTotal"]["tags"]["open"] is True and "render" not in cut
+    # the finished one is the retained tail's
+    entry = None
+    for _ in range(100):
+        entry = cluster.broker.tail.get(reply["requestId"])
+        if entry and any(s["span"] == "render" for s in entry["scopes"][cluster.broker.name]):
+            break
+        time.sleep(0.01)
+    scopes = entry["scopes"]
+    server = cluster.servers[0].name
+    assert {s["span"] for s in scopes[cluster.broker.name]} == BROKER_SPANS
+    assert {s["span"] for s in scopes[server]} == SERVER_SPANS
+    spans = [s for part in scopes.values() for s in part]
+    assert not any("open" in s.get("tags", {}) for s in spans)
+    # one tree: every span hangs, through its parents, under httpTotal
+    by_id = {s["id"]: s for s in spans}
+    (root,) = [s for s in spans if s["parent"] is None]
+    assert root["span"] == "httpTotal"
+    for s in spans:
+        while s["parent"] is not None:
+            s = by_id[s["parent"]]
+        assert s is root
+    # and the self times add up to it: nothing is counted twice, nothing hangs outside
+    assert sum(phase_self_ms(scopes).values()) == pytest.approx(root["ms"], rel=0.05)
+    assert entry["phaseSelfMs"]["render"] > 0
+    # the launch and the wait say which program
+    program = {s["span"]: s.get("tags", {}).get("program") for s in scopes[server]}
+    assert program["laneDispatch"] == program["deviceWait"]
+    assert program["laneDispatch"].startswith("pinot_scan_gb6_")
+    # the timers an operator or the benchmark reads are the same intervals
+    broker, srv = cluster.broker.metrics, cluster.servers[0].metrics
+    assert broker.timer("httpTotal").count == broker.timer("phase.render").count >= 2
+    for name in ("phase.deserializeRequest", "phase.serializeResult", "phase.laneQueue", "phase.deviceWait"):
+        assert srv.timer(name).count >= 2, name
+
+
+def test_direct_call_keeps_one_root_and_a_disabled_sampler_allocates_nothing(http_cluster, monkeypatch):
+    broker = http_cluster.broker
+    resp = broker.handle_pql(K6, trace=True)
+    spans = [s for part in resp.trace_info["scopes"].values() for s in part]
+    assert [s["span"] for s in spans if s["parent"] is None] == ["query"]
+    assert not any(s["span"] in ("bookkeeping", "httpTotal") for s in spans)
+    monkeypatch.setattr(broker.tail, "enabled", False)
+    _post(http_cluster, K6)
+    before = trace_mod.SPAN_ALLOCATIONS
+    assert not _post(http_cluster, K6)["exceptions"]
+    assert trace_mod.SPAN_ALLOCATIONS == before
+    assert broker.metrics.timer("phase.bookkeeping").count >= 3
+
+
+# -- kernel names -----------------------------------------------------------
+
+_NAME_SNIPPET = """
+from pinot_tpu.engine.kernel import kernel_name, make_table_kernel
+from pinot_tpu.engine.plan import StaticAgg, StaticGroupBy, StaticPlan
+agg = (StaticAgg("sum", "sum", "l_extendedprice", False, "scalar", use_raw=True),)
+flat = StaticPlan(None, (), agg, None, None, True)
+grouped = StaticPlan(None, (), agg, StaticGroupBy(("l_returnflag", "l_linestatus"), (False, False), (3, 2), 6, 10), None, True)
+print(kernel_name("scan", flat), kernel_name("scan", grouped), kernel_name("zone", flat), make_table_kernel(flat).__name__)
+"""
+
+
+def test_program_names_repeat_across_processes_and_differ_by_shape():
+    outs = []
+    for hash_seed in ("1", "2"):
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONHASHSEED=hash_seed)
+        done = subprocess.run([sys.executable, "-c", _NAME_SNIPPET], env=env, cwd=ROOT, capture_output=True,
+                              text=True, timeout=300)
+        assert done.returncode == 0, done.stderr
+        outs.append(done.stdout.split())
+    assert outs[0] == outs[1]
+    flat, grouped, zone, jitted = outs[0]
+    assert flat.startswith("pinot_scan_agg_") and grouped.startswith("pinot_scan_gb6_")
+    assert zone == flat.replace("_scan_", "_zone_") and jitted == flat
+    assert flat[-8:] != grouped[-8:] and len(flat.rsplit("_", 1)[1]) == 8
+
+
+def test_the_packed_program_is_jitted_under_its_name():
+    import jax.numpy as jnp
+
+    from pinot_tpu.engine.packing import make_packed_kernel
+
+    kernel = make_packed_kernel(lambda x: {"a": x * 2}, "pinot_scan_agg_0123abcd")
+    assert kernel.__name__ == "pinot_scan_agg_0123abcd"
+    assert "jit_pinot_scan_agg_0123abcd" in kernel.lower(jnp.ones(4)).as_text()
+    assert "jit_packed" not in kernel.lower(jnp.ones(4)).as_text()
+
+
+# -- the lane's busy window ---------------------------------------------------
+
+
+@pytest.mark.parametrize("waiter_reports_ms", [None, 20.0])
+def test_lane_device_busy_closes_when_the_output_is_ready(waiter_reports_ms):
+    """A launch whose call returns at once and whose output turns ready
+    50 ms later: the window is those 50 ms (20 where a waiter saw the
+    output first), not the launch call's microseconds."""
+    from pinot_tpu.engine.dispatch import DeviceLane
+
+    metrics = ServerMetrics("s0")
+    lane = DeviceLane(metrics=metrics, stall_timeout_s=0)
+    try:
+        ready_at = []
+
+        def launch():
+            ready_at.append(time.monotonic() + 0.050)
+            return "handle"
+
+        ticket = lane.submit("k", launch, pending=lambda value: time.monotonic() < ready_at[0])
+        assert ticket.result(time.monotonic() + 5) == "handle"
+        busy = metrics.timer("lane.deviceBusy")
+        assert busy.count == 0  # the call has returned, the output is outstanding
+        assert lane.occupancy_read("t")["inflight"] == 1
+        if waiter_reports_ms is not None:
+            time.sleep(waiter_reports_ms / 1000.0)
+            lane.output_ready(ticket)
+        deadline = time.monotonic() + 2
+        while busy.count == 0 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        expected = waiter_reports_ms or 50.0
+        assert busy.count == 1 and expected - 2 <= busy.total_ms <= expected + 30
+        launch_call = metrics.timer("phase.laneDispatch")
+        assert launch_call.count == 1 and launch_call.total_ms < 5
+        occ = lane.occupancy_read("t")
+        assert occ["inflight"] == 0 and 0 < occ["busyFraction"] <= 1
+        assert metrics.timer("phase.laneQueue").count == 1
+    finally:
+        lane.close()
+
+
+# -- the capture summary ------------------------------------------------------
+
+
+def test_profile_summary_on_a_recorded_capture():
+    """``data/pinot_capture.txt``: a few events cut from a capture of the
+    open cell on the v5e, in the profiler's own text form."""
+    from jax.profiler import ProfileData
+
+    from pinot_tpu.server import profiler
+
+    with open(os.path.join(HERE, "data", "pinot_capture.txt")) as f:
+        loaded = profiler.load_capture(ProfileData.from_text_proto(f.read()))
+    assert sorted(loaded["devices"]) == ["/device:TPU:0"]
+    summary = profiler.summarize(loaded)
+    device = summary["devices"]["/device:TPU:0"]
+    assert 0 < device["busyShare"] < 1
+    assert device["busyS"] + summary["idleS"] == pytest.approx(summary["windowS"])
+    # programs by name, XLA's fingerprint dropped
+    assert all(name.startswith("jit_pinot_") and "(" not in name for name in summary["programs"])
+    assert sum(summary["programs"].values()) >= device["busyS"]
+    # the gap before the launch is plan build's, the gap between the queries is nobody's
+    assert summary["idle"]["pinot:planBuild"] > 0
+    assert summary["idle"][profiler.NO_QUERY] > 0
+    assert sum(summary["idle"].values()) == pytest.approx(summary["idleS"])
+
+
+def test_summarize_is_arithmetic_on_lists():
+    from pinot_tpu.server import profiler
+
+    ms = 1e6
+    loaded = {
+        "devices": {
+            "/device:TPU:0": {"ops": [(10 * ms, 15 * ms), (15 * ms, 20 * ms), (60 * ms, 70 * ms)],
+                              "programs": [("jit_pinot_scan_agg_aa", 10 * ms, 20 * ms),
+                                           ("jit_pinot_scan_gb6_bb", 60 * ms, 70 * ms)]},
+            "/device:TPU:1": {"ops": [(10 * ms, 20 * ms)],
+                              "programs": [("jit_pinot_scan_agg_aa", 10 * ms, 20 * ms)]},
+        },
+        # a query 5-30 whose planBuild runs 6-9 on another thread, and one 55-80
+        "spans": [("pinot:httpTotal", 5 * ms, 30 * ms), ("pinot:planBuild", 6 * ms, 9 * ms),
+                  ("pinot:deviceWait", 9 * ms, 21 * ms), ("pinot:httpTotal", 55 * ms, 80 * ms)],
+    }
+    s = profiler.summarize(loaded)
+    assert s["windowS"] == pytest.approx(0.075)
+    assert s["devices"]["/device:TPU:0"]["busyS"] == pytest.approx(0.020)
+    assert s["devices"]["/device:TPU:1"]["busyShare"] == pytest.approx(10 / 75)
+    assert s["programs"] == {"jit_pinot_scan_agg_aa": pytest.approx(0.010),
+                             "jit_pinot_scan_gb6_bb": pytest.approx(0.005)}
+    # device 0's gaps: 5-10, 20-60, 70-80
+    assert s["idle"] == {
+        "pinot:httpTotal": pytest.approx(0.001 + 0.009 + 0.005 + 0.010),  # 5-6, 21-30, 55-60, 70-80
+        "pinot:planBuild": pytest.approx(0.003),
+        "pinot:deviceWait": pytest.approx(0.001 + 0.001),  # 9-10 before the kernel, 20-21 after it
+        profiler.NO_QUERY: pytest.approx(0.025),  # 30-55
+    }
+    assert s["idleS"] == pytest.approx(0.055)
+
+
+def test_profiler_stop_returns_the_summary(tmp_path):
+    """``POST /debug/profile/stop`` is ``DeviceProfiler.stop``: a real
+    capture on the CPU has host planes and no device plane, so the
+    summary is empty but there, and written beside the trace."""
+    from pinot_tpu.server.profiler import SUMMARY_FILE, DeviceProfiler
+
+    prof = DeviceProfiler(base_dir=str(tmp_path))
+    started = prof.start(timeout_s=30)
+    with boundary("planBuild", None):
+        time.sleep(0.001)
+    stopped = prof.stop()
+    assert stopped["active"] is False
+    assert stopped["summary"] == {"windowS": 0.0, "devices": {}, "programs": {}, "idle": {}, "idleS": 0.0}
+    assert os.path.exists(os.path.join(started["dir"], SUMMARY_FILE))
+    # a capture with nothing to read says so and stays a capture
+    fake = DeviceProfiler(base_dir=str(tmp_path / "fake"), trace_api=(lambda d: None, lambda: None))
+    fake.start()
+    assert "no .xplane.pb" in fake.stop()["summary"]["error"]

@@ -24,7 +24,7 @@ def test_packed_round_trip_mixed_tree():
 
     a = np.linspace(0, 1, 37, dtype=np.float32)
     b = np.arange(37, dtype=np.int32)
-    packed = make_packed_kernel(fn)
+    packed = make_packed_kernel(fn, "pinot_test_pack")
     got = packed(jnp.asarray(a), jnp.asarray(b))
     want = jax.tree_util.tree_map(np.asarray, fn(jnp.asarray(a), jnp.asarray(b)))
 
@@ -43,7 +43,7 @@ def test_packed_layout_cache_shape_change():
     def fn(x):
         return {"sum": x.sum(axis=0), "sq": x * x}
 
-    packed = make_packed_kernel(fn)
+    packed = make_packed_kernel(fn, "pinot_test_pack")
     for n in (8, 16, 8):  # revisit the first shape: cache hit must hold
         x = np.arange(n * 3, dtype=np.float32).reshape(n, 3)
         got = packed(jnp.asarray(x))
@@ -60,7 +60,7 @@ def test_packed_f64_under_x64():
         return {"d": x.astype(jnp.float64) / 3.0}
 
     x = np.arange(11, dtype=np.float64)
-    got = make_packed_kernel(fn)(jnp.asarray(x))
+    got = make_packed_kernel(fn, "pinot_test_pack")(jnp.asarray(x))
     assert got["d"].dtype == np.float64
     np.testing.assert_allclose(got["d"], x / 3.0)
 
