@@ -383,7 +383,7 @@ class _Dispatch:
     __slots__ = (
         "key", "launch", "pending", "waiters", "completed", "value",
         "error", "plan_digest", "cost_provider", "batch", "batch_size",
-        "t_submit", "trace", "parent", "program", "launch_id",
+        "t_submit", "trace", "parent", "program", "groupby", "launch_id",
     )
 
     def __init__(
@@ -398,6 +398,7 @@ class _Dispatch:
         parent: Optional[str] = None,
         program: str = "",
         t_submit: float = 0.0,
+        groupby: str = "",
     ) -> None:
         self.t_submit = t_submit  # phase.laneQueue runs from here to the launch call
         # the submitting query's span tree and the span (its laneWait)
@@ -405,6 +406,7 @@ class _Dispatch:
         self.trace = trace
         self.parent = parent
         self.program = program  # the jitted program's name, for the launch's tags
+        self.groupby = groupby  # a group-by program's lowering (kernel.groupby_lowering)
         self.launch_id: Optional[int] = None  # the physical launch this rode (occupancy)
         self.key = key
         self.launch = launch
@@ -578,6 +580,7 @@ class DeviceLane:
         trace=None,
         parent: Optional[str] = None,
         program: str = "",
+        groupby: str = "",
     ) -> LaneTicket:
         """Enqueue a kernel launch, or coalesce onto an identical one
         that is queued, launching, or still executing on device.
@@ -588,7 +591,9 @@ class DeviceLane:
         span (its ``laneWait``) under which the lane thread's
         ``laneQueue`` and ``laneDispatch`` hang, and the jitted
         program's name.  A coalesced ticket gets neither span: its wait
-        is all ``laneWait``.
+        is all ``laneWait``.  ``groupby``: a group-by program's lowering
+        (``kernel.groupby_lowering``), the launch's ``groupby=`` tag and
+        its ``groupby.lowering.*`` mark.
 
         ``cost_provider`` (optional, utilization plane): a zero-arg
         callable returning the plan's static XLA cost analysis (or
@@ -628,7 +633,7 @@ class DeviceLane:
                 self._hit()
             else:
                 d = _Dispatch(key, launch, pending, plan_digest, cost_provider, batch,
-                              trace, parent, program, t_submit)
+                              trace, parent, program, t_submit, groupby)
                 d.waiters.append(ticket)
                 self._by_key[key] = d
                 self._depth_tick_locked()
@@ -1180,10 +1185,14 @@ class DeviceLane:
             # ``via`` says before the call whether this plan digest has
             # launched here ("first": it may compile) and after it how
             # the first launch got its executable.
+            tags = {"groupby": d.groupby} if d.groupby else {}
             launching = boundary(
                 "laneDispatch", d.trace, launch_timer, parent=d.parent, program=d.program,
                 via="warm" if d.plan_digest is None or d.plan_digest in self._compile else "first",
+                **tags,
             ).start()
+            if d.groupby and self.metrics is not None:
+                self.metrics.meter(f"groupby.lowering.{d.groupby}").mark()
             self._set_inflight(1)
             error: Optional[BaseException] = None
             value: Any = None

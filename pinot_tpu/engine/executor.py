@@ -1296,6 +1296,12 @@ class QueryExecutor:
         # ``program=`` tag of the launch and wait spans, as the device
         # planes of a capture name it
         program = getattr(kernel, "__name__", "")
+        # a group-by program's lowering, from the function the kernel
+        # builder asks: the launch's ``groupby=`` tag and its
+        # ``groupby.lowering.*`` mark ("" for any other program)
+        from pinot_tpu.engine.kernel import groupby_lowering
+
+        groupby = groupby_lowering(plan) or ""
         coalesced = False
         ticket = None
         # planExec excludes lane queueing (timed as laneWait): it covers
@@ -1306,6 +1312,8 @@ class QueryExecutor:
         try:
             if lane is None:
                 executing.start()
+                if groupby:
+                    self.metrics.meter(f"groupby.lowering.{groupby}").mark()
                 fetch, handle = launch()
             else:
                 # coalesce key: identical (plan, staged-table token, inputs
@@ -1338,6 +1346,7 @@ class QueryExecutor:
                         trace=current_trace(),
                         parent=waiting.span_id,
                         program=program,
+                        groupby=groupby,
                     )
                     fetch, handle = ticket.result(deadline)
                     # the lane thread delivered -> this worker runs again

@@ -88,6 +88,145 @@ def _grouped_hll_path(capacity: int) -> str:
     return "scatter"
 
 
+# Dense group-by capacities above MATMUL_GROUP_CAP ride the two-level
+# (radix-128) contraction up to this bound; beyond it the scatter runs.
+# Contraction work grows with K (2 * cols * K flops a row), the
+# scatter's does not.  Q3's shape (occupancy + one float32 sum) on a
+# v5e, ns a row, scatter against _segment_add_radix (chip run, PR 26,
+# 33.5M shuffled rows; at K=2,000 over the cell's 134M sorted rows
+# 17.58 against 0.14):
+#   K = 2^11: 13.62  0.15      K = 2^16: 13.55   2.87
+#   K = 2^14: 13.56  0.76      K = 2^18: 13.56  11.33
+# At 2^18 a second sum costs the contraction 8.5 ns a row and the
+# scatter 6.8, so the crossover lies just under it; 2^16 is the largest
+# measured K that wins at any number of sums (4.7x at one).
+RADIX_GROUP_CAP = 1 << 16
+_RADIX = 128
+# VMEM the generated one-hots of one grid step may take (the block of
+# rows shrinks as K grows), and the limit Mosaic is given above its
+# 16 MiB default for the accumulator's two buffers beside them; a v5e
+# core has 128 MiB
+_RADIX_STEP_BYTES = 12 << 20
+_RADIX_VMEM_LIMIT = 64 << 20
+_RADIX_BLOCK_MAX = 8192  # rows a step: 18.8, 21.3, 27.6 ms at 8192, 4096, 2048 (Q3, 134M rows)
+
+
+def groupby_lowering(plan: StaticPlan) -> Optional[str]:
+    """Which lowering a dense group-by's occupancy and sum-shaped
+    aggregates (count, sum, avg) take, from what the plan states —
+    consulted by the kernel builder and by the launch's
+    ``groupby.lowering.*`` meter and ``groupby=`` tag, which must agree.
+
+    'onehot':  K <= MATMUL_GROUP_CAP, one chunked [cols, chunk] @
+               [chunk, K] contraction (_segment_add_matmul_multi).
+    'radix':   K <= RADIX_GROUP_CAP, the two-level contraction with
+               float32-faithful weights (_segment_add_radix).
+    'scatter': above the bound, and on the CPU backend unless
+               PINOT_TPU_GROUPBY_MATMUL=1 (the tests' switch).
+    None for a plan without a group-by.  min, max, minmaxrange,
+    presence, hist and HLL aggregates keep _group_state on every
+    lowering."""
+    if getattr(plan, "group_by", None) is None:
+        return None
+    cap = plan.group_by.capacity
+    if not _use_matmul_groupby() or cap > RADIX_GROUP_CAP:
+        return "scatter"
+    return "onehot" if cap <= MATMUL_GROUP_CAP else "radix"
+
+
+def _segment_add_radix(flat_idx, weights, capacity: int):
+    """Occupancy counts and the sums of the float ``weights`` columns
+    over ``capacity`` buckets, with ONE two-level one-hot contraction on
+    the matrix unit (Pallas).  Invalid rows carry ``flat_idx ==
+    capacity`` (and zero weights).
+
+    The flat index splits into radix-128 digits (hi, lo).  Per block of
+    rows, ``acc[c*K1 + h, l] += sum_rows (hi==h) * w_c * (lo==l)``:
+    ``A^T [cols*K1, block] . B^T [128, block]`` contracted over the
+    block, A the thin hi one-hot scaled by the weights and B the lo
+    one-hot.  Both are GENERATED in VMEM from the block's indices and
+    weights and never reach HBM, whose traffic is the index and weight
+    streams alone (the XLA form of the same contraction, a dot_general
+    in a scan, measured 182 ms for this one's 18.8 on Q3's shape).
+    The accumulator stays in VMEM across the sequential grid.
+
+    Precision is float32's.  Column 0 is the validity (0/1); a float
+    weight rides as three bfloat16 columns that sum to it exactly
+    (8 + 8 + 8 significand bits, each the top 16 bits of what is left,
+    taken by mask: a rounding convert is a round trip XLA may elide).
+    The one-hots are exact in bfloat16, so every product is exact and
+    only the order of the float32 additions differs from the
+    scatter's.  Counts are exact: a segment has fewer than 2^24 rows.
+
+    Returns float states [1 + len(weights), capacity]: row 0 the
+    occupancy counts, then one row a weight column, as the scatter
+    gives them.  Runs in the Pallas interpreter on the CPU backend,
+    which only the tests' switch reaches."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = flat_idx.shape[0]
+    K1 = -(-capacity // _RADIX)  # the sentinel lands in the padded tail or past it
+    K1p = -(-K1 // 16) * 16  # a bfloat16 tile is 16 sublanes
+    n_parts = 1 + 3 * len(weights)
+    rows = n_parts * K1p
+    # a row of the block costs A^T's column, the hi digit's iota, mask
+    # and select beside it, and B^T's column with its iota
+    blk = _RADIX_STEP_BYTES // (rows * 2 + K1p * 12 + _RADIX * 6)
+    blk = max(256, min(_RADIX_BLOCK_MAX, 1 << (blk.bit_length() - 1)))
+    flat_idx = flat_idx.astype(jnp.int32)
+    weights = [w.astype(jnp.float32) for w in weights]
+    pad = (-n) % blk
+    if pad:
+        flat_idx = jnp.concatenate([flat_idx, jnp.full(pad, capacity, jnp.int32)])
+        weights = [jnp.concatenate([w, jnp.zeros(pad, w.dtype)]) for w in weights]
+
+    def top(x):
+        bits = pltpu.bitcast(x, jnp.uint32) & jnp.uint32(0xFFFF0000)
+        return pltpu.bitcast(bits, jnp.float32)
+
+    def kernel(idx_ref, *refs):
+        w_refs, acc_ref = refs[:-1], refs[-1]
+
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        idx = idx_ref[...]  # [1, blk]: rows along the lanes
+        hi = jax.lax.broadcasted_iota(jnp.int32, (K1p, blk), 0) == (idx >> 7)
+        lo = jax.lax.broadcasted_iota(jnp.int32, (_RADIX, blk), 0) == (idx & (_RADIX - 1))
+        parts = [(idx < capacity).astype(jnp.float32)]
+        for w_ref in w_refs:
+            w1 = top(w_ref[...])
+            r = w_ref[...] - w1
+            w2 = top(r)
+            parts.extend([w1, w2, r - w2])
+        a_t = jnp.concatenate(
+            [jnp.where(hi, p, 0.0).astype(jnp.bfloat16) for p in parts], axis=0
+        )
+        acc_ref[...] += jax.lax.dot_general(
+            a_t, lo.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    acc = pl.pallas_call(
+        kernel,
+        grid=(flat_idx.shape[0] // blk,),
+        in_specs=[pl.BlockSpec((1, blk), lambda i: (0, i))] * (1 + len(weights)),
+        out_specs=pl.BlockSpec((rows, _RADIX), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, _RADIX), jnp.float32),
+        # the accumulator idiom (init at step 0, then +=) needs the grid
+        # in order: the compiled TPU grid, or the interpreter
+        interpret=jax.default_backend() == "cpu",
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_RADIX_VMEM_LIMIT),
+    )(flat_idx.reshape(1, -1), *[w.reshape(1, -1) for w in weights])
+    acc = acc.reshape(n_parts, K1p * _RADIX)[:, :capacity]
+    states = [acc[0]]
+    for j in range(1, n_parts, 3):
+        states.append((acc[j + 2] + acc[j + 1]) + acc[j])
+    return jnp.stack(states).astype(config.float_dtype())
+
+
 def _segment_add_matmul_multi(flat_idx, W, capacity: int):
     """Sum m weight columns into capacity buckets with ONE chunked
     one-hot contraction: [m, chunk] @ [chunk, K] per scan step.
@@ -711,7 +850,8 @@ def make_single_segment_kernel(plan: StaticPlan) -> Callable:
             flat_idx = jnp.where(kvalid, keys, cap).reshape(-1)
             fvalid = kvalid.reshape(-1)
             fdt = config.float_dtype()
-            if cap <= MATMUL_GROUP_CAP and _use_matmul_groupby():
+            lowering = groupby_lowering(plan)
+            if lowering != "scatter":
                 # ONE fused one-hot contraction (MXU) covers occupancy
                 # AND every sum-shaped agg: a single pass over rows with
                 # one one-hot per chunk, instead of a scan per agg —
@@ -731,7 +871,10 @@ def make_single_segment_kernel(plan: StaticPlan) -> Callable:
                     for vec in w:
                         slots[i].append(len(cols))
                         cols.append(jnp.where(fvalid, vec, 0))
-                states = _segment_add_matmul_multi(flat_idx, jnp.stack(cols), cap)
+                if lowering == "onehot":
+                    states = _segment_add_matmul_multi(flat_idx, jnp.stack(cols), cap)
+                else:
+                    states = _segment_add_radix(flat_idx, cols[1:], cap)
                 out["gb_presence"] = (states[0] > 0).astype(jnp.int32)
                 for i, agg in enumerate(plan.aggs):
                     if i in slots:

@@ -528,5 +528,52 @@ def bench_hll_lowerings(rows: int) -> Dict:
 BENCHES["hll_lowerings"] = bench_hll_lowerings
 
 
+def bench_groupby_lowerings(rows: int) -> Dict:
+    """The crossover that sets ``kernel.RADIX_GROUP_CAP``: Q3's shape
+    (occupancy + one float32 sum over shuffled keys) on the serialised
+    scatter against the two-level contraction, K = 2^11 to 2^18, in ns
+    a row, with the contraction's widest relative gap from a float64
+    sum (v5e, PR 26: 13.6 against 0.15, 0.76, 2.87, 11.33).  On the
+    chip; the CPU runs the contraction in the Pallas interpreter."""
+    import jax
+    import jax.numpy as jnp
+
+    from pinot_tpu.engine.kernel import _segment_add_radix
+
+    rng = np.random.default_rng(26)
+    prices = (rng.integers(0, 16384, size=rows) * 6.37 + 901.13).astype(np.float32)
+    sweep = {}
+    for K in (1 << 11, 1 << 14, 1 << 16, 1 << 18):
+        idx = rng.integers(0, K, size=rows).astype(np.int32)
+        want = np.bincount(idx, weights=prices.astype(np.float64), minlength=K)
+
+        def scatter(i, w, K=K):
+            occupied = jnp.zeros(K, jnp.int32).at[i].max(jnp.ones_like(i), mode="drop")
+            return occupied, jnp.zeros(K, jnp.float32).at[i].add(w, mode="drop")
+
+        f_scatter = jax.jit(scatter)
+        f_radix = jax.jit(lambda i, w, K=K: _segment_add_radix(i, [w], K))
+        args = (jnp.asarray(idx), jnp.asarray(prices))
+        got = np.asarray(f_radix(*args)[1], dtype=np.float64)
+        jax.block_until_ready(f_scatter(*args))
+        sweep[K] = {
+            "scatter_ns_per_row": round(
+                _time_best(lambda: jax.block_until_ready(f_scatter(*args)), 3) * 1e9 / rows, 4),
+            "radix_ns_per_row": round(
+                _time_best(lambda: jax.block_until_ready(f_radix(*args)), 3) * 1e9 / rows, 4),
+            "radix_sum_gap": float(np.max(np.abs(got - want) / np.maximum(want, 1.0))),
+        }
+    at_bound = sweep[1 << 16]
+    return {
+        "bench": "groupby_lowerings",
+        "value": round(at_bound["scatter_ns_per_row"] / max(at_bound["radix_ns_per_row"], 1e-9), 2),
+        "unit": "x radix-vs-scatter at K=2^16",
+        "detail": {"rows": rows, "sweep": sweep, "platform": jax.devices()[0].platform},
+    }
+
+
+BENCHES["groupby_lowerings"] = bench_groupby_lowerings
+
+
 if __name__ == "__main__":
     main()

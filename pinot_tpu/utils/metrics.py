@@ -597,6 +597,16 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "stats registry",
     "plan.explains": "EXPLAIN plan requests answered without execution",
     "plan.digests": "distinct plan-shape digests currently tracked",
+    # which lowering a dense group-by's occupancy and sums took, one
+    # mark a launch (engine/kernel.py groupby_lowering, marked where the
+    # launch's laneDispatch span gets its ``groupby=`` tag)
+    "groupby.lowering.onehot": "group-by launches on the one-level "
+    "one-hot contraction (K <= MATMUL_GROUP_CAP)",
+    "groupby.lowering.radix": "group-by launches on the two-level "
+    "(radix-128) contraction with float32-faithful weights "
+    "(K <= RADIX_GROUP_CAP)",
+    "groupby.lowering.scatter": "group-by launches on the serialised "
+    "scatter (K above the radix bound, or the CPU backend)",
     # compile timeline (engine/dispatch.py lane registry): first-call
     # launch of a device-plan digest pays trace + XLA compile
     "compile.cold": "device-plan digests launched for the first time "

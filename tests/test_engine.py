@@ -299,6 +299,166 @@ def test_matmul_holder_paths_forced(monkeypatch):
         assert _values_close(gj, wj), (pql, gj, wj)
 
 
+# ------------------------------------------- the two-level (radix) group-by
+RADIX_SCHEMA = Schema(
+    "rx",
+    dimensions=[
+        FieldSpec("day", DataType.INT),
+        FieldSpec("a", DataType.INT),
+        FieldSpec("b", DataType.INT),
+        FieldSpec("flag", DataType.INT),
+        FieldSpec("tags", DataType.INT_ARRAY, single_value=False),
+    ],
+    metrics=[
+        FieldSpec("price", DataType.DOUBLE, FieldType.METRIC),
+        FieldSpec("qty", DataType.INT, FieldType.METRIC),
+    ],
+)
+
+
+def _radix_rows(K, n, order, a_card=4, tag_card=8, seed=5):
+    """n rows whose ``day`` takes every value of range(K) (so that the
+    dense capacity is K), from 16,384 distinct prices that bfloat16
+    cannot hold: a sum of values rounded to it misses by 1e-4 and more.
+    Rows with ``flag`` 1 all fall on day 7."""
+    import random
+
+    rng = random.Random(seed)
+    rows = []
+    for i in range(n):
+        flag = 1 if i >= K and rng.random() < 0.1 else 0
+        rows.append({
+            "day": 7 if flag else (i if i < K else rng.randrange(K)),
+            "a": i % a_card if i < a_card else rng.randrange(a_card),
+            "b": i % 256 if i < 256 else rng.randrange(256),
+            "flag": flag,
+            "tags": sorted({(i % tag_card) if i < tag_card else rng.randrange(tag_card)
+                            for _ in range(rng.randint(1, 3))}),
+            "price": 901.13 + 6.37 * rng.randrange(16384),
+            "qty": rng.randint(1, 50),
+        })
+    if order == "sorted":
+        rows.sort(key=lambda r: r["day"])
+    else:
+        rng.shuffle(rows)
+    return rows
+
+
+def _group_table(resp):
+    """{function: {group tuple: value string}} of a group-by response."""
+    return {
+        agg["function"]: {tuple(e["group"]): e["value"] for e in agg["groupByResult"]}
+        for agg in resp.to_json()["aggregationResults"]
+    }
+
+
+# id: (K, rows, order, datagen kwargs, PQL after FROM, expected lowering, tier)
+RADIX_CASES = {
+    "k513_sum_sorted": (513, 1500, "sorted", {}, "SELECT sum(price) FROM rx GROUP BY day", "radix", "scan"),
+    "k2000_sum_sorted": (2000, 5000, "sorted", {}, "SELECT sum(price) FROM rx GROUP BY day", "radix", "scan"),
+    "k2000_two_sums_shuffled": (2000, 5000, "shuffled", {},
+                                "SELECT sum(price), sum(qty) FROM rx GROUP BY day", "radix", "scan"),
+    "k2000_count": (2000, 5000, "shuffled", {}, "SELECT count(*) FROM rx GROUP BY day", "radix", "scan"),
+    "k2000_avg": (2000, 5000, "shuffled", {}, "SELECT avg(price), count(*) FROM rx GROUP BY day", "radix", "scan"),
+    "k2000_sum_beside_min": (2000, 5000, "shuffled", {},
+                             "SELECT sum(price), min(price), max(qty) FROM rx GROUP BY day", "radix", "scan"),
+    "k2000_all_in_one_group": (2000, 5000, "shuffled", {},
+                               "SELECT sum(price), count(*) FROM rx WHERE flag = 1 GROUP BY day", "radix", "scan"),
+    "k2000_filtered_zone_tier": (2000, 6000, "sorted", {},
+                                 "SELECT sum(price), count(*) FROM rx WHERE day BETWEEN 300 AND 420 GROUP BY day",
+                                 "radix", "zone"),
+    "k2000_empty_match": (2000, 5000, "shuffled", {},
+                          "SELECT sum(price), count(*) FROM rx WHERE flag = 1 AND day = 3 GROUP BY day",
+                          "radix", "zone"),
+    "k2049_sum_shuffled": (2049, 5000, "shuffled", {}, "SELECT sum(price) FROM rx GROUP BY day", "radix", "scan"),
+    "k700_multi_value_key": (7, 2500, "shuffled", {"tag_card": 700},
+                             "SELECT sum(price), count(*) FROM rx GROUP BY tags", "radix", "scan"),
+    "k4200_multi_value_key_pair": (7, 2500, "shuffled", {"tag_card": 600},
+                                   "SELECT sum(qty) FROM rx GROUP BY day, tags", "radix", "scan"),
+    "bound_65536_two_keys": (7, 3000, "shuffled", {"a_card": 256},
+                             "SELECT sum(price), count(*) FROM rx GROUP BY a, b", "radix", "scan"),
+    "above_bound_scatter": (7, 3000, "shuffled", {"a_card": 257},
+                            "SELECT sum(price), count(*) FROM rx GROUP BY a, b", "scatter", "scan"),
+    "k256_onehot_untouched": (256, 1500, "shuffled", {}, "SELECT sum(price), count(*) FROM rx GROUP BY day",
+                              "onehot", "scan"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RADIX_CASES))
+def test_radix_groupby_forced(monkeypatch, case):
+    """Dense group-bys above the one-level gate ride the two-level
+    (radix-128) contraction on the chip; forced on here so that CPU CI
+    holds it to the oracle: the lowering the gate names, occupancy
+    equal to the scatter's, counts exact, and float32 sums within 2e-6
+    of a float64 sum over prices that bfloat16 cannot hold."""
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    K, n, order, gen, pql, lowering, tier = RADIX_CASES[case]
+    pql += " TOP 100000"
+    monkeypatch.setenv("PINOT_TPU_ZONE_BLOCK", "256")
+    monkeypatch.setenv("PINOT_TPU_INVINDEX", "0")  # the host's postings tier would answer the selective shapes
+    rows = _radix_rows(K, n, order, **gen)
+    half = n // 2
+    segs = [build_segment(RADIX_SCHEMA, rows[:half], "rx", f"{case}0"),
+            build_segment(RADIX_SCHEMA, rows[half:], "rx", f"{case}1")]
+    want = _group_table(ScanQueryProcessor(RADIX_SCHEMA, rows).execute(optimize_request(parse_pql(pql))))
+
+    seen = {}
+    run_kernel = QueryExecutor._run_kernel
+
+    def spy(self, kernel, args, plan, staged, digest, block_ids, *rest, **kw):
+        outs = run_kernel(self, kernel, args, plan, staged, digest, block_ids, *rest, **kw)
+        seen.update(plan=plan, outs=outs, tier="scan" if block_ids is None else "zone",
+                    lowering=kernel_mod.groupby_lowering(plan))
+        return outs
+
+    monkeypatch.setattr(QueryExecutor, "_run_kernel", spy)
+
+    def forget_programs():
+        for cached in ("make_table_kernel", "make_packed_table_kernel", "make_block_table_kernel",
+                       "make_packed_block_table_kernel"):
+            getattr(kernel_mod, cached).cache_clear()
+
+    def run(force):
+        monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", force)
+        forget_programs()  # the caches key on the plan, which does not state the switch
+        req = optimize_request(parse_pql(pql))
+        resp = reduce_to_response(req, [QueryExecutor().execute(segs, req)])
+        return _group_table(resp), dict(seen)
+
+    try:
+        got, forced = run("1")
+        _, scattered = run("0")
+    finally:
+        forget_programs()
+    assert forced["lowering"] == lowering and scattered["lowering"] == "scatter"
+    assert forced["tier"] == scattered["tier"] == tier
+    cap = forced["plan"].group_by.capacity
+    assert (cap > kernel_mod.RADIX_GROUP_CAP) == (lowering == "scatter")
+    if case.startswith("bound"):
+        assert cap == kernel_mod.RADIX_GROUP_CAP
+    # occupancy and every count state: the scatter's, exactly
+    a, b = forced["outs"], scattered["outs"]
+    assert (a["gb_presence"] == b["gb_presence"]).all()
+    for i, agg in enumerate(forced["plan"].aggs):
+        if agg.base == "count":
+            assert a[f"gb_{i}"].dtype == b[f"gb_{i}"].dtype and (a[f"gb_{i}"] == b[f"gb_{i}"]).all()
+        elif agg.base == "avg":
+            assert (a[f"gb_{i}"][1] == b[f"gb_{i}"][1]).all()
+    # the reply against the float64 oracle
+    assert set(got) == set(want)
+    for fn in want:
+        assert set(got[fn]) == set(want[fn]), fn
+        for group, value in want[fn].items():
+            if fn.startswith("count"):
+                assert got[fn][group] == value, (fn, group)
+            else:
+                g, w = float(got[fn][group]), float(value)
+                assert abs(g - w) <= 2e-6 * max(abs(w), 1.0), (fn, group, g, w)
+    if case == "k2000_empty_match":
+        assert not any(want.values()) and not a["gb_presence"].any()
+
+
 def test_grouped_hll_mxu_contraction(monkeypatch):
     """The grouped-HLL occupancy contraction (small group spaces) vs
     the oracle — the cap is raised and kernel caches cleared so the

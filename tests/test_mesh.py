@@ -283,6 +283,52 @@ def test_sharded_payloads_byte_identical_to_single_lane(
         single.local_servers[0].shutdown()
 
 
+def test_radix_groupby_sharded_equals_single_device(lineitem_segments, monkeypatch):
+    """A group-by over the dates (above the one-level gate) through
+    ``make_sharded_table_kernel`` on the 8-device CPU mesh, with the
+    two-level contraction forced: every state equals the single-device
+    program's, so the psum merges what the scatter's states would."""
+    import numpy as np
+
+    from pinot_tpu.engine import kernel as kernel_mod
+    from pinot_tpu.engine.context import get_table_context
+    from pinot_tpu.engine.device import get_staged, segment_arrays
+    from pinot_tpu.engine.plan import build_query_inputs, build_static_plan
+    from pinot_tpu.parallel import default_mesh
+    from pinot_tpu.parallel.multichip import make_sharded_table_kernel
+    from pinot_tpu.pql import optimize_request, parse_pql
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")
+    kernel_mod.make_table_kernel.cache_clear()
+    req = optimize_request(parse_pql(
+        "SELECT sum(l_extendedprice), count(*), avg(l_quantity) FROM lineitem GROUP BY l_shipdate TOP 10"))
+    ctx = get_table_context(lineitem_segments)
+    needed = sorted(set(req.referenced_columns()))
+
+    def states(kernel_of, **staging):
+        staged = get_staged(lineitem_segments, needed, gfwd_columns=("l_shipdate",), ctx=ctx, **staging)
+        plan = build_static_plan(req, ctx, staged)
+        assert kernel_mod.groupby_lowering(plan) == "radix", plan.group_by.capacity
+        outs = kernel_of(plan)(segment_arrays(staged, needed), build_query_inputs(req, plan, ctx, staged))
+        return jax.tree_util.tree_map(np.asarray, outs)
+
+    try:
+        single = states(kernel_mod.make_table_kernel)
+        mesh = default_mesh()
+        sharded = states(lambda plan: make_sharded_table_kernel(plan, mesh), pad_segments_to=8,
+                         sharding=NamedSharding(mesh, P("segments")))
+    finally:
+        kernel_mod.make_table_kernel.cache_clear()
+    assert single["gb_presence"].sum() > 512
+    for key, value in single.items():
+        for a, b in zip(jax.tree_util.tree_leaves(value), jax.tree_util.tree_leaves(sharded[key])):
+            if a.dtype.kind == "f":
+                np.testing.assert_allclose(b, a, rtol=1e-6, err_msg=key)
+            else:
+                assert (a == b).all(), key
+
+
 def test_mesh_status_reports_topology_and_lanes(mesh_broker):
     server = mesh_broker.local_servers[0]
     status = server.status()
