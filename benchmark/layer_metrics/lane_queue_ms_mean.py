@@ -1,7 +1,7 @@
 """Lane submit to dequeue per dispatch: queue and batch-formation wait
 only, from ``phase.laneQueue`` (``engine/dispatch.py``).  The launch
-call is ``lane_launch_ms_mean``; ``scheduler_wait_ms_mean`` lumps both
-with the scheduler's wait."""
+call is ``lane_launch_ms_mean``; the scheduler's own queue is
+``scheduler_wait_ms_mean``."""
 
 
 def read(run):

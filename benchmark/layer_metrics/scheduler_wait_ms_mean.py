@@ -1,8 +1,8 @@
-"""What a query waited for the server's scheduler and for its lane."""
+"""What a query waited for the server's scheduler per query: submit to a
+worker's dequeue, from ``phase.schedulerWait`` (``server/scheduler.py``).
+The wait for the lane is ``lane_queue_ms_mean`` and ``lane_launch_ms_mean``."""
 
 
 def read(run):
-    n = run.delta("server.timer.queryExecution.n")
-    if not n:
-        return None
-    return (run.delta("server.timer.phase.schedulerWait.ms") + run.delta("server.timer.phase.laneWait.ms")) / n
+    n = run.delta("server.timer.phase.schedulerWait.n")
+    return run.delta("server.timer.phase.schedulerWait.ms") / n if n else None

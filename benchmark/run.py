@@ -17,7 +17,9 @@ reader of its own, ``end_to_end/<metric>.py`` or
 ``layer_metrics/<metric>.py``, with one function ``read(run)`` that
 returns the number, or ``None`` where it finds nothing to read.
 ``--trace 0`` prints the end-to-end metrics, ``--trace 1`` the per-layer
-ones, with a few seconds of device trace taken after the window.
+ones, with a few seconds of device trace taken after the window.  A
+traffic file's ``keep_awake`` is the number of processes that spin
+beside the server while traffic is offered (``kept_awake``; 0 if absent).
 """
 from __future__ import annotations
 
@@ -32,6 +34,7 @@ import importlib
 import importlib.util
 import json
 import os
+import subprocess
 import sys
 import tempfile
 import threading
@@ -40,6 +43,8 @@ import types
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 TRACE_SECONDS = 4.0  # of steady traffic under the profiler, after the window
+# a process that only spins, and ends when the parent whose id it is given does
+SPINNER = "import os, sys\nwhile os.getppid() == int(sys.argv[1]):\n    for _ in range(1000000):\n        pass\n"
 
 
 def load_json(*parts: str) -> dict:
@@ -168,6 +173,18 @@ def judge(samples: list, shapes: dict, reference, compare, limits: dict) -> dict
     return worst
 
 
+def by_shape(samples: list, percentile) -> dict:
+    """What a median of the whole window hides: each shape's own median,
+    how many replies it had, and how many took over three times that
+    median (the host's stalls)."""
+    out = {}
+    for shape in sorted({s["shape"] for s in samples}):
+        ms = [s["latency_ms"] for s in samples if s["shape"] == shape]
+        p50 = percentile(ms, 50)
+        out[shape] = {"n": len(ms), "p50_ms": round(p50, 3), "over_3x_p50": sum(1 for x in ms if x > 3 * p50)}
+    return out
+
+
 class CompileLog:
     """When JAX compiled a program or loaded one from its cache: the
     benchmark's own count, beside the program's ``compile.*`` meters."""
@@ -214,6 +231,26 @@ def serving(config: dict, seed: int, references: list, pql_of: dict, post):
             cluster.stop()
             for server in cluster.servers:
                 server.shutdown()
+
+
+@contextlib.contextmanager
+def kept_awake(n: int):
+    """``n`` processes that spin beside the server while traffic is offered
+    (a traffic file's ``keep_awake``).  One closed-loop client that waits
+    27 ms a query for the device leaves the host's other cores idle, and
+    the machine then serves every crossing into the system and the runtime
+    slower, in some processes and not in others (``PERF.md`` section 2: two
+    levels 9% apart).  A deployment's host is not idle beside its server;
+    one busy core stands for that.  They touch neither JAX nor the chip."""
+    spinners = [subprocess.Popen([sys.executable, "-I", "-S", "-c", SPINNER, str(os.getpid())], stdin=subprocess.DEVNULL,
+                                 stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL) for _ in range(n)]
+    try:
+        yield
+    finally:
+        for p in spinners:
+            p.kill()
+        for p in spinners:
+            p.wait()
 
 
 def take_trace(run_pass, trace_reduce) -> tuple:
@@ -281,21 +318,22 @@ def main(argv=None, allow_cpu: bool = False, manifest_path: str = "") -> int:
             s.address, pql_of, traffic, seconds, span)
         # rehearsal: the schedule's first seconds untimed, so that
         # connections, thread pools, lanes and batched programs are hot
-        rehearsal = offer(traffic["rehearse_s"])
-        after_setup = counters(s.cluster)
-        gc.collect()
-        gc.freeze()
-        before = counters(s.cluster)
-        t_window = time.perf_counter()
-        setup["setup_s"] = t_window - T_START - setup["reference_wait_s"]
-        window = offer(args.seconds)
-        compiled = compiles.between(t_window, time.perf_counter())
-        after = counters(s.cluster)
-        traced = trace = None
-        if args.trace:
-            traced, trace = take_trace(
-                lambda span: offer(min(TRACE_SECONDS, args.seconds), span),
-                load_module(os.path.join(HERE, "trace_reduce.py")))
+        with kept_awake(traffic.get("keep_awake", 0)):
+            rehearsal = offer(traffic["rehearse_s"])
+            after_setup = counters(s.cluster)
+            gc.collect()
+            gc.freeze()
+            before = counters(s.cluster)
+            t_window = time.perf_counter()
+            setup["setup_s"] = t_window - T_START - setup["reference_wait_s"]
+            window = offer(args.seconds)
+            compiled = compiles.between(t_window, time.perf_counter())
+            after = counters(s.cluster)
+            traced = trace = None
+            if args.trace:
+                traced, trace = take_trace(
+                    lambda span: offer(min(TRACE_SECONDS, args.seconds), span),
+                    load_module(os.path.join(HERE, "trace_reduce.py")))
         peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in jax.devices())
 
     # timing has stopped: every reply of the rehearsal, the window and the
@@ -305,11 +343,10 @@ def main(argv=None, allow_cpu: bool = False, manifest_path: str = "") -> int:
     failed = sum(1 for s in window["samples"] if not s["ok"])
     correct = all(compared[k] <= limits[k] for k in limits)
     print("# set-up: " + json.dumps({k: round(v, 3) for k, v in setup.items()}))
-    for k in limits:
-        print(f"# compared {k}: {compared[k]!r} limit {limits[k]!r}")
     print(f"# sum_gap by shape: {json.dumps(compared['sum_gap_by_shape'])}")
     for fault in compared["first_faults"][:5]:
         print(f"# fault: {fault}"[:600])
+    print("# window by shape: " + json.dumps(by_shape(window["samples"], loadgen.percentile)))
     slowest = sorted(window["samples"], key=lambda x: -x["latency_ms"])[:5]
     print("# slowest: " + json.dumps([[x["shape"], round(x["due"], 3), round(x["latency_ms"], 1),
                                        round(x["late_ms"], 1)] for x in slowest]))
@@ -339,8 +376,14 @@ def main(argv=None, allow_cpu: bool = False, manifest_path: str = "") -> int:
               "metrics": metrics, "device": device}
     if trace:
         device["busy_s"], device["window_s"] = trace["busy_s"], trace["window_s"]
+        print("# idle with a query in flight, by span: " + json.dumps(trace["idle_by_span"]))
         result["breakdown"] = {"device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"]}
+    # each number compared beside its limit: the result line's last key, and the last lines of standard error
+    result["compared"] = {k: {"value": compared[k], "limit": limits[k]} for k in limits}
     print(json.dumps(result), flush=True)
+    for k, v in result["compared"].items():
+        print(f"compared {k}: {v['value']!r} limit {v['limit']!r}", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

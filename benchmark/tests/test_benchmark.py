@@ -13,6 +13,7 @@ import os
 import shutil
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -213,27 +214,113 @@ def test_shape_bytes_counts_ids_at_their_narrowest_width():
 # -- the trace reduction -------------------------------------------------
 
 
+MS = 1e6
+HAND_MADE = {
+    "devices": {
+        "/device:TPU:0": [("jit_a/fusion", 10 * MS, 5 * MS), ("jit_a/copy", 15 * MS, 5 * MS),
+                          ("jit_b/fusion", 60 * MS, 10 * MS), ("jit_b/late", 95 * MS, 20 * MS)],
+        "/device:TPU:1": [("jit_a/fusion", 10 * MS, 10 * MS)],
+    },
+    "client": [("q0", 5 * MS, 25 * MS), ("q1", 55 * MS, 20 * MS), ("q2", 90 * MS, 30 * MS)],
+}
+# the program's annotations around q0 and q1, nested as the program nests them, on three threads
+PINOT_SPANS = [("httpTotal", 6 * MS, 23 * MS), ("scatterGather", 7 * MS, 20 * MS),  # the HTTP thread
+               ("planBuild", 7.5 * MS, 1.5 * MS), ("laneWait", 9 * MS, 17 * MS),  # the worker, inside scatterGather
+               ("deviceWait", 9.5 * MS, 11 * MS), ("d2hUnpack", 20.5 * MS, 2 * MS),
+               ("render", 27.5 * MS, 1 * MS),
+               ("httpTotal", 56 * MS, 18 * MS), ("deviceWait", 59 * MS, 12 * MS), ("render", 72 * MS, 1.5 * MS)]
+
+
 def test_reduce_on_a_hand_made_trace():
-    ms = 1e6
-    loaded = {
-        "devices": {
-            "/device:TPU:0": [("jit_a/fusion", 10 * ms, 5 * ms), ("jit_a/copy", 15 * ms, 5 * ms),
-                              ("jit_b/fusion", 60 * ms, 10 * ms), ("jit_b/late", 95 * ms, 20 * ms)],
-            "/device:TPU:1": [("jit_a/fusion", 10 * ms, 10 * ms)],
-        },
-        "client": [("q0", 5 * ms, 25 * ms), ("q1", 55 * ms, 20 * ms), ("q2", 90 * ms, 30 * ms)],
-    }
-    r = trace_reduce.reduce(loaded, (0.0, 100 * ms))
+    ms = MS
+    r = trace_reduce.reduce(HAND_MADE, (0.0, 100 * ms))
     # device 0 is busy 10-20, 60-70, 95-100 (clipped); device 1 10-20
     assert r["busy_s"] == pytest.approx((25 + 10) / 2 / 1e3)
     assert r["window_s"] == pytest.approx(0.1)
     assert r["queries"] == 2 and r["queries_by_shape"] == {"q0": 1, "q1": 1}  # q2 ends outside
     assert r["device_ops"][0] == ["jit_a/fusion", pytest.approx(0.0075)]
     gaps = dict(r["idle_gaps"])
-    # gaps of device 0: 0-10 (half under q0), 20-60 (mostly nobody), 70-95 (q1 5 of 25)
-    assert gaps["no_query_in_flight"] == pytest.approx(0.065)
-    assert gaps["query_in_flight:q0__host_cause_not_attributed"] == pytest.approx(0.010)
+    # device 0 is idle 0-10, 20-60, 70-95: nobody's 0-5, 30-55, 75-90; q0's 5-10, 20-30; q1's 55-60, 70-75; q2's 90-95.
+    # A trace with no annotation of the program in it: the query is known, the host's cause is not
+    assert gaps == {"no_query_in_flight": pytest.approx(0.045),
+                    "query_in_flight:q0:host_cause_not_attributed": pytest.approx(0.015),
+                    "query_in_flight:q1:host_cause_not_attributed": pytest.approx(0.010),
+                    "query_in_flight:q2:host_cause_not_attributed": pytest.approx(0.005)}
+    assert r["idle_by_span"] == {"host_cause_not_attributed": pytest.approx(0.030)}
     assert sum(gaps.values()) + 0.025 == pytest.approx(0.1)
+
+
+def test_idle_goes_to_the_innermost_span_open_on_the_host():
+    ms = MS
+    loaded = dict(HAND_MADE, spans=PINOT_SPANS)
+    # a window that ends with q0's reply: device 0 is idle 0-10 and 20-30, q0 is in flight from 5
+    r = trace_reduce.reduce(loaded, (0.0, 30 * ms))
+    gaps = dict(r["idle_gaps"])
+    # 5-10: no span of the program before the handler's entry at 6, httpTotal 6-7, scatterGather 7-7.5,
+    # planBuild 7.5-9, laneWait 9-9.5, deviceWait 9.5-10
+    want = {"host_cause_not_attributed": 1.0 + 1.0, "planBuild": 1.5,
+            # 20-30: deviceWait to 20.5, d2hUnpack to 22.5, laneWait to 26, scatterGather to 27, httpTotal to 29 but
+            # for render 27.5-28.5, and nothing after it
+            "deviceWait": 0.5 + 0.5, "d2hUnpack": 2.0, "laneWait": 0.5 + 3.5, "scatterGather": 0.5 + 1.0,
+            "httpTotal": 1.0 + 0.5 + 0.5, "render": 1.0}
+    assert gaps == dict({f"query_in_flight:q0:{k}": pytest.approx(v / 1e3) for k, v in want.items()},
+                        no_query_in_flight=pytest.approx(0.005))
+    assert r["idle_by_span"] == {k: pytest.approx(v / 1e3) for k, v in want.items()}
+    assert list(r["idle_by_span"])[0] == "laneWait"  # largest first
+    # the whole trace: q1's 55-60 and 70-75 lie under its httpTotal, deviceWait and render; q2 has no span under it
+    r = trace_reduce.reduce(loaded, (0.0, 100 * ms), top=20)
+    gaps = dict(r["idle_gaps"])
+    assert gaps["no_query_in_flight"] == pytest.approx(0.045)  # a span open where no query is in flight changes nothing
+    assert gaps["query_in_flight:q0:planBuild"] == pytest.approx(0.0015)
+    assert gaps["query_in_flight:q1:deviceWait"] == pytest.approx(0.002)
+    assert gaps["query_in_flight:q1:httpTotal"] == pytest.approx(0.0045)
+    assert gaps["query_in_flight:q1:render"] == pytest.approx(0.0015)
+    assert gaps["query_in_flight:q1:host_cause_not_attributed"] == pytest.approx(0.002)
+    assert gaps["query_in_flight:q2:host_cause_not_attributed"] == pytest.approx(0.005)
+    assert sum(gaps.values()) + 0.025 == pytest.approx(0.1)
+
+
+def test_two_queries_in_flight_share_the_span_that_opened_last():
+    """The limit of the pairing, as the module's text states it: shape and
+    span each come from the span that opened last, so the pair can name
+    one query's shape beside the other's span; the sums by span hold."""
+    ms = MS
+    loaded = {"devices": {"/device:TPU:0": [("jit_a/fusion", 0.0, 1 * ms), ("jit_a/fusion", 21 * ms, 1 * ms)]},
+              "client": [("k6", 1 * ms, 19 * ms), ("q0", 5 * ms, 10 * ms)],  # q0 is sent while k6 waits, and ends first
+              "spans": [("httpTotal", 2 * ms, 17 * ms), ("deviceWait", 3 * ms, 15 * ms),  # k6's
+                        ("httpTotal", 6 * ms, 8 * ms), ("render", 12 * ms, 1 * ms)]}  # q0's
+    r = trace_reduce.reduce(loaded, (0.0, 22 * ms))
+    gaps = dict(r["idle_gaps"])
+    assert gaps == {
+        "no_query_in_flight": pytest.approx(0.001),  # 20-21
+        "query_in_flight:k6:host_cause_not_attributed": pytest.approx(0.002),  # 1-2 and 19-20
+        "query_in_flight:k6:httpTotal": pytest.approx(0.002),  # 2-3 and 18-19
+        "query_in_flight:k6:deviceWait": pytest.approx(0.002 + 0.003),  # 3-5, and 15-18 once q0 has its reply
+        "query_in_flight:q0:deviceWait": pytest.approx(0.001 + 0.001),  # 5-6 and 14-15: q0's shape beside k6's span
+        "query_in_flight:q0:httpTotal": pytest.approx(0.006 + 0.001),  # 6-12, 13-14
+        "query_in_flight:q0:render": pytest.approx(0.001)}
+    assert r["idle_by_span"] == {"httpTotal": pytest.approx(0.009), "deviceWait": pytest.approx(0.007),
+                                 "host_cause_not_attributed": pytest.approx(0.002), "render": pytest.approx(0.001)}
+    # ten operations are listed, where there are more
+    many = {"devices": {"/device:TPU:0": [(f"jit_a/op{i}", i * ms, (i + 1) * ms / 100) for i in range(30)]}, "client": []}
+    r = trace_reduce.reduce(many, (0.0, 40 * ms))
+    assert len(r["device_ops"]) == 10 and r["device_ops"][0][0] == "jit_a/op29"
+
+
+def test_pieces_cover_an_interval_by_segment_and_by_nothing():
+    seg = [[2.0, 4.0, "a"], [4.0, 5.0, "b"], [8.0, 12.0, "c"]]
+    assert trace_reduce.pieces(seg, 3.0, 10.0) == [(3.0, 4.0, "a"), (4.0, 5.0, "b"), (5.0, 8.0, None), (8.0, 10.0, "c")]
+    assert trace_reduce.pieces(seg, 0.0, 1.0) == [(0.0, 1.0, None)]
+    assert trace_reduce.pieces(seg, 13.0, 14.0) == [(13.0, 14.0, None)]
+    assert trace_reduce.pieces([], 0.0, 1.0) == [(0.0, 1.0, None)]
+    assert trace_reduce.pieces(seg, 5.0, 5.0) == []
+
+
+def test_innermost_segments_take_the_span_that_opened_last():
+    seg = trace_reduce.innermost_segments([("a", 0.0, 10.0), ("b", 2.0, 3.0), ("c", 4.0, 8.0), ("d", 20.0, 1.0)])
+    # c opens inside b and outlives it; a shows again when c ends; nothing is open from 12 to 20
+    assert seg == [[0.0, 2.0, "a"], [2.0, 4.0, "b"], [4.0, 12.0, "c"], [20.0, 21.0, "d"]]
+    assert trace_reduce.innermost_segments([]) == []
 
 
 def test_load_reads_a_recorded_trace():
@@ -250,6 +337,12 @@ def test_load_reads_a_recorded_trace():
     r = trace_reduce.reduce(loaded, (min(s[1] for s in spans), max(s[1] + s[2] for s in spans)))
     assert 0 < r["busy_s"] < r["window_s"]
     assert all(name.startswith("jit_") for name, _ in r["device_ops"])
+    assert sorted(name for name, _, _ in loaded["spans"]) == ["deviceWait", "httpTotal"]
+    # before the first kernel the device idles under k6's httpTotal, then 0.444 ms under its deviceWait,
+    # which also covers the 0.081 ms between the kernel's last operation and the wait's end
+    assert r["idle_by_span"]["deviceWait"] == pytest.approx(0.5243e-3, rel=1e-3)
+    assert r["idle_by_span"]["httpTotal"] == pytest.approx(7.0e-3 + 4.9e-3)  # 96-103 and 117.1-122 ms
+    assert r["idle_by_span"]["host_cause_not_attributed"] > 0  # q0's query has no annotation under it
 
 
 # -- a whole run, at a tiny size, without the chip -------------------------
@@ -260,12 +353,19 @@ def test_load_reads_a_recorded_trace():
 def test_rehearsal_prints_counts_and_no_time(capsys, tiny_manifest, workload, trace):
     assert run.main(["--workload", workload, "--seed", str(2**31 + 5), "--seconds", "2", "--trace", str(trace)],
                     allow_cpu=True, manifest_path=tiny_manifest) == 0
-    out = last_line(capsys)
+    printed, err = capsys.readouterr()
+    out = json.loads(printed.strip().splitlines()[-1])
     assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0
     assert out["device"]["platform"] == "cpu"
     assert all(m["unit"] in ("count", "B/row") for m in out["metrics"].values())
+    # each number compared beside its limit: the line's last key, and the last lines of standard error
+    assert list(out)[-1] == "compared" and set(out["compared"]) == {"sum_gap", "count_errors", "key_errors", "reply_errors"}
+    assert all(v["value"] <= v["limit"] for v in out["compared"].values())
+    assert err.strip().splitlines()[-1] == "compared reply_errors: 0 limit 0"
+    assert "# window by shape: " in printed
     if trace:
         assert out["metrics"]["compiles_in_window"]["value"] == 0
+        assert all(len(v) <= 10 for v in out["breakdown"].values())
     else:
         assert out["metrics"]["hbm_bytes_per_row"]["value"] > 0
 
@@ -353,3 +453,40 @@ def test_manifest_names_files_that_exist():
             assert os.path.exists(os.path.join(BENCH, folder, m["name"] + ".py")), m["name"]
     moved = {m["name"] for m in manifest["end_to_end"]}
     assert all(m["moves"] in moved for m in manifest["per_layer"])
+
+
+def _gone(pid: int) -> bool:
+    try:
+        with open(f"/proc/{pid}/stat") as f:
+            return f.read().rsplit(")", 1)[1].split()[0] == "Z"
+    except OSError:
+        return True
+
+
+def test_kept_awake_spins_beside_the_run_and_leaves_no_process(monkeypatch):
+    started = []
+    popen = subprocess.Popen
+    monkeypatch.setattr(run.subprocess, "Popen", lambda *a, **k: started.append(popen(*a, **k)) or started[-1])
+    with run.kept_awake(2):
+        assert len(started) == 2 and all(p.poll() is None for p in started)
+    assert all(p.returncode is not None for p in started)
+    with run.kept_awake(0):
+        assert len(started) == 2
+    # a spinner whose parent dies before it could stop it ends by itself
+    monkeypatch.undo()
+    orphaner = ("import os, subprocess, sys\n"
+                f"print(subprocess.Popen([sys.executable, '-I', '-S', '-c', {run.SPINNER!r}, str(os.getpid())],\n"
+                "                       stdout=subprocess.DEVNULL).pid)\n")
+    pid = int(subprocess.run([sys.executable, "-c", orphaner], capture_output=True, text=True, check=True,
+                             timeout=60).stdout)
+    deadline = time.time() + 10
+    while not _gone(pid) and time.time() < deadline:
+        time.sleep(0.05)
+    assert _gone(pid)
+
+
+def test_by_shape_counts_the_replies_over_three_times_their_shapes_median():
+    samples = [{"shape": "q3", "latency_ms": x} for x in (30.0, 31.0, 32.0, 33.0, 150.0)]
+    samples += [{"shape": "q4", "latency_ms": x} for x in (40.0, 44.0)]
+    assert run.by_shape(samples, loadgen.percentile) == {
+        "q3": {"n": 5, "p50_ms": 32.0, "over_3x_p50": 1}, "q4": {"n": 2, "p50_ms": 42.0, "over_3x_p50": 0}}

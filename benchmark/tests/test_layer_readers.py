@@ -41,14 +41,22 @@ def tiny_manifest(tmp_path_factory) -> str:
 
 
 def test_manifest_lists_the_new_readers_last_and_for_every_cell():
+    """PR 25's ten are there, in their order, each for every cell.  Not
+    last: a PR that adds a reader appends its entry after them (PR 26's
+    stands there).  A PR that adds a cell appends it to their
+    ``workloads`` lists, the one edit such a PR makes to entries that
+    are there."""
     manifest = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-len(NEW):] == NEW
+    entries = {m["name"]: m for m in manifest["per_layer"]}
+    assert [n for n in entries if n in NEW] == NEW
     cells = [w["name"] for w in manifest["workloads"]]
-    for m in manifest["per_layer"][-len(NEW):]:
+    for m in (entries[n] for n in NEW):
         assert m["moves"] == "latency_p50_ms" and m["source"] in ("program_span", "program_counter")
         assert m.get("workloads", cells) == cells
         assert os.path.exists(os.path.join(BENCH, "layer_metrics", m["name"] + ".py"))
+    # retired in PR 28, each the sum of readers listed on their own
+    assert "launch_fetch_ms_mean" not in entries
+    assert not os.path.exists(os.path.join(BENCH, "layer_metrics", "launch_fetch_ms_mean.py"))
 
 
 @pytest.mark.parametrize("workload", ["lineitem_suite_open", "lineitem_groupby_closed"])
@@ -91,3 +99,14 @@ def test_reader_finds_nothing_on_a_program_without_the_span(name):
            "server.timer.queryExecution.n": 10}
     r = types.SimpleNamespace(delta=lambda key: old.get(key, 0), window_s=45.0, samples=[], trace=None)
     assert run.load_module(os.path.join(BENCH, "layer_metrics", name + ".py")).read(r) is None
+
+
+def test_scheduler_wait_reads_the_schedulers_queue_alone():
+    """``phase.laneWait`` (the lane's queue, launch, delivery and wake-up,
+    each listed on its own) is no part of it since PR 28."""
+    d = {"server.timer.phase.schedulerWait.ms": 4.0, "server.timer.phase.schedulerWait.n": 10,
+         "server.timer.phase.laneWait.ms": 300.0, "server.timer.phase.laneWait.n": 10,
+         "server.timer.queryExecution.n": 10}
+    read = run.load_module(os.path.join(BENCH, "layer_metrics", "scheduler_wait_ms_mean.py")).read
+    assert read(types.SimpleNamespace(delta=lambda key: d.get(key, 0))) == 0.4
+    assert read(types.SimpleNamespace(delta=lambda key: 0)) is None
