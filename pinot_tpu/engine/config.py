@@ -31,6 +31,18 @@ MAX_VALUE_STATE = 1 << 22
 # sensible response payload.
 DISTINCT_PAIR_CAP = 1 << 22
 
+# Rows the host path (engine/host_fallback.py) takes in one step: the
+# filter mask, the dictionary gathers and the float64 partial states of
+# one block.  That bounds what one numpy call holds the interpreter lock
+# for beside serving (the shadow auditor's passes), and keeps a block's
+# temporaries (8 bytes a row each) in the cache and in memory the
+# allocator already holds.  On a v5e host the widest shape served (a
+# K=6 group-by with four aggregates, 134M rows) takes 5 to 6 ms a step
+# here; a pass of it cost 3.1 s of the processor at 2^18, 3.4 s at
+# 2^19, 4.3 to 8.0 s at 2^20 by the machine, 20 s with a segment whole
+# (PERF.md section 6, PR 29).
+HOST_BLOCK_ROWS = 1 << 18
+
 HLL_LOG2M = 8  # HllConstants.java DEFAULT_LOG2M
 HLL_M = 1 << HLL_LOG2M
 

@@ -379,12 +379,23 @@ class QueryExecutor:
     def execute_host_oracle(
         self, segments: Sequence[ImmutableSegment], request: BrokerRequest
     ) -> IntermediateResult:
-        """The shadow-audit oracle: re-execute ``request`` over the
-        exact views a production reply served, on the always-correct
-        host path — no device lane, no result cache, no tier ladder.
-        Pruning is correctness-preserving, so the payload (modulo
-        accounting) must match whatever tier served production."""
-        from pinot_tpu.engine.host_fallback import execute_host
+        """The shadow-audit oracle: ``host_oracle_steps`` run to its end."""
+        from pinot_tpu.engine.host_fallback import run_steps
+
+        return run_steps(self.host_oracle_steps(segments, request))
+
+    def host_oracle_steps(
+        self, segments: Sequence[ImmutableSegment], request: BrokerRequest
+    ):
+        """The oracle's pass as a generator: re-execute ``request`` over
+        the exact views a production reply served, on the always-correct
+        host path — no device lane, no result cache, no tier ladder —
+        one ``yield`` after each block of rows
+        (``host_fallback.execute_host_steps``), the result as the
+        generator's return value.  Pruning is correctness-preserving,
+        so the payload (modulo accounting) must match whatever tier
+        served production."""
+        from pinot_tpu.engine.host_fallback import execute_host_steps
 
         segments = list(segments)
         total_docs = sum(s.num_docs for s in segments)
@@ -398,7 +409,9 @@ class QueryExecutor:
                 else None
             )
             ctx = get_table_context(live)
-            res = execute_host(live, ctx, request, total_docs, sel_columns)
+            res = yield from execute_host_steps(
+                live, ctx, request, total_docs, sel_columns
+            )
         res._served_tier = "host"
         return res
 

@@ -14,10 +14,20 @@ segmented reductions -> trim to topN*5 candidates before any Python
 objects are built.  Only queries outside that shape (MV group columns,
 value-state aggregations, radix overflow) drop to the row-wise
 accumulators shared with the scan oracle.
+
+Every path works through a segment in row blocks of
+``config.HOST_BLOCK_ROWS`` and carries its float64 partial states
+(sums, counts, minima, maxima, group keys, distinct pairs) from block
+to block: memory is bounded by a block and the states, and no numpy
+call holds the interpreter lock for longer than a block takes.
+``execute_host_steps`` is the pass as a generator, one ``yield`` a
+block; ``execute_host`` runs it to its end.  The shadow auditor
+(``utils/audit.py``) drives the steps on its own thread, so that a
+134M-row re-derivation runs beside serving instead of in front of it.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -49,8 +59,15 @@ from pinot_tpu.segment.immutable import ImmutableSegment
 from pinot_tpu.tools.scan_engine import _Accumulator
 
 
-def _segment_mask(seg: ImmutableSegment, tree: Optional[FilterQueryTree]) -> np.ndarray:
-    n = seg.num_docs
+def _segment_mask(
+    seg: ImmutableSegment,
+    tree: Optional[FilterQueryTree],
+    lo: int = 0,
+    hi: Optional[int] = None,
+) -> np.ndarray:
+    """The filter over rows ``[lo, hi)`` of ``seg`` (all of it by default)."""
+    hi = seg.num_docs if hi is None else hi
+    n = hi - lo
     if tree is None:
         return np.ones(n, dtype=bool)
     if tree.is_leaf:
@@ -61,16 +78,74 @@ def _segment_mask(seg: ImmutableSegment, tree: Optional[FilterQueryTree]) -> np.
         if col.is_single_value:
             if negative:
                 table = ~table
-            return table[col.fwd]
-        hits = table[col.mv_values]
+            return table[col.fwd[lo:hi]]
+        offsets = col.mv_offsets[lo : hi + 1]
+        hits = table[col.mv_values[offsets[0] : offsets[-1]]]
         any_hit = np.zeros(n, dtype=bool)
-        np.logical_or.at(any_hit, np.repeat(np.arange(n), np.diff(col.mv_offsets)), hits)
+        np.logical_or.at(any_hit, np.repeat(np.arange(n), np.diff(offsets)), hits)
         return ~any_hit if negative else any_hit
-    masks = [_segment_mask(seg, c) for c in tree.children]
+    masks = [_segment_mask(seg, c, lo, hi) for c in tree.children]
     out = masks[0]
     for m in masks[1:]:
         out = (out & m) if tree.operator == FilterOperator.AND else (out | m)
     return out
+
+
+class _Block(NamedTuple):
+    """The matched rows of one step: rows ``[lo, hi)`` of segment ``si``
+    under ``sel`` (None: every row of the range; a bool mask over the
+    range), or, from a postings-backed resolver, the row ids ``sel``."""
+
+    si: int
+    seg: ImmutableSegment
+    lo: int
+    hi: int
+    sel: Optional[np.ndarray]
+    n: int  # matched rows
+
+    def take(self, column: np.ndarray) -> np.ndarray:
+        """``column``'s entries at the matched rows."""
+        if self.sel is None:
+            return column[self.lo : self.hi]
+        if self.sel.dtype == np.bool_:
+            return column[self.lo : self.hi][self.sel]
+        return column[self.sel]
+
+    def rows(self) -> np.ndarray:
+        """The matched rows' ids in the segment."""
+        if self.sel is None:
+            return np.arange(self.lo, self.hi)
+        if self.sel.dtype == np.bool_:
+            return np.flatnonzero(self.sel) + self.lo
+        return self.sel
+
+
+def _matched_blocks(
+    segments: List[ImmutableSegment], request: BrokerRequest, matched_rows=None
+) -> Iterator[_Block]:
+    """Every segment's matched rows, ``config.HOST_BLOCK_ROWS`` rows a
+    block: ``ceil(rows / block)`` blocks a segment.  By default the
+    filter is a vectorized mask over the block's rows (O(n) host scan);
+    ``matched_rows(si, seg)`` substitutes a row-id resolver (the
+    inverted-index path's O(matches) postings, engine/invindex_path.py),
+    whose ids are cut into blocks of the same size."""
+    step = config.HOST_BLOCK_ROWS
+    for si, seg in enumerate(segments):
+        if matched_rows is not None:
+            rows = matched_rows(si, seg)
+            for lo in range(0, rows.size, step):
+                part = rows[lo : lo + step]
+                yield _Block(si, seg, 0, seg.num_docs, part, int(part.size))
+            continue
+        for lo in range(0, seg.num_docs, step):
+            hi = min(lo + step, seg.num_docs)
+            mask, n = None, hi - lo
+            if request.filter is not None:
+                mask = _segment_mask(seg, request.filter, lo, hi)
+                n = int(np.count_nonzero(mask))
+                if n == hi - lo:
+                    mask = None  # every row of the range: slices, no copies
+            yield _Block(si, seg, lo, hi, mask, n)
 
 
 _VECTOR_AGGS = {"count", "sum", "min", "max", "avg", "minmaxrange"}
@@ -84,7 +159,8 @@ _DISTINCT_AGGS = {"distinctcount", "distinctcounthll", "fasthll"}
 def _vectorizable_groupby(request: BrokerRequest, segments, ctx: TableContext) -> bool:
     """True when the fast numpy hash path applies: SV group columns,
     scalar/pair aggregations over SV numeric columns, and a mixed-radix
-    key that fits int64."""
+    key that fits int64, packed with a distinct aggregation's value id
+    too."""
     seg = segments[0]
     for c in request.group_by.columns:
         if c not in seg.columns or not seg.column(c).is_single_value:
@@ -94,18 +170,13 @@ def _vectorizable_groupby(request: BrokerRequest, segments, ctx: TableContext) -
         space *= max(ctx.column(c).global_cardinality, 1)
         if space >= (1 << 62):
             return False
-    return _vectorizable_aggs(request, segments, allow_distinct=True)
-
-
-def _default_matched_rows(request: BrokerRequest):
-    """Row-id resolver: full vectorized mask + nonzero (O(n) host scan).
-    The inverted-index path (engine/invindex_path.py) substitutes an
-    O(matches) postings resolver through the same seam."""
-
-    def resolve(si: int, seg: ImmutableSegment) -> np.ndarray:
-        return np.nonzero(_segment_mask(seg, request.filter))[0]
-
-    return resolve
+    if not _vectorizable_aggs(request, segments, allow_distinct=True):
+        return False
+    return all(
+        space * max(ctx.column(a.column).global_cardinality, 1) < (1 << 62)
+        for a in request.aggregations
+        if a.base_function in _DISTINCT_AGGS
+    )
 
 
 def _vectorizable_aggs(
@@ -135,38 +206,52 @@ def _vectorizable_aggs(
     return True
 
 
+def _decoded(cache: dict, c: str, blk: _Block) -> np.ndarray:
+    """Column ``c``'s float64 values at the block's matched rows.  The
+    dictionary is converted once a (segment, column), into ``cache``."""
+    col = blk.seg.column(c)
+    values = cache.get((blk.si, c))
+    if values is None:
+        values = cache[blk.si, c] = np.asarray(col.dictionary.values, dtype=np.float64)
+    return values[blk.take(col.fwd)]
+
+
 def _aggregation_vectorized(
-    segments: List[ImmutableSegment],
     request: BrokerRequest,
     res: IntermediateResult,
-    matched_rows,
-) -> None:
+    blocks: Iterator[_Block],
+) -> Iterator[None]:
     """Scalar/pair aggregations over matched rows via numpy
-    fancy-indexing — O(matches) when the resolver is postings-backed
-    (engine/invindex_path.py), O(n) under the default mask resolver."""
+    fancy-indexing, a block a step; float64 sums, minima and maxima
+    carried from block to block.  O(matches) when the resolver is
+    postings-backed (engine/invindex_path.py), O(n) under the default
+    mask."""
     needed = {
         a.column
         for a in request.aggregations
         if a.base_function != "count" and a.column != "*"
     }
+    ranged = {
+        a.column
+        for a in request.aggregations
+        if a.base_function in ("min", "max", "minmaxrange")
+    }
+    decoders: dict = {}
     col_sum = {c: 0.0 for c in needed}
-    col_min = {c: float("inf") for c in needed}
-    col_max = {c: float("-inf") for c in needed}
+    col_min = {c: float("inf") for c in ranged}
+    col_max = {c: float("-inf") for c in ranged}
     total = 0
-    for si, seg in enumerate(segments):
-        matched = matched_rows(si, seg)
-        res.num_docs_scanned += int(matched.size)
-        total += int(matched.size)
-        if matched.size == 0:
-            continue
-        for c in needed:
-            col = seg.column(c)
-            vals = np.asarray(col.dictionary.values, dtype=np.float64)[
-                np.asarray(col.fwd)[matched]
-            ]
-            col_sum[c] += float(vals.sum())
-            col_min[c] = min(col_min[c], float(vals.min()))
-            col_max[c] = max(col_max[c], float(vals.max()))
+    for blk in blocks:
+        res.num_docs_scanned += blk.n
+        total += blk.n
+        if blk.n:
+            for c in needed:
+                vals = _decoded(decoders, c, blk)
+                col_sum[c] += float(vals.sum())
+                if c in ranged:
+                    col_min[c] = min(col_min[c], float(vals.min()))
+                    col_max[c] = max(col_max[c], float(vals.max()))
+        yield
     if total == 0:
         res.aggregations = [make_partial(a.base_function) for a in request.aggregations]
         return
@@ -188,21 +273,117 @@ def _aggregation_vectorized(
     res.aggregations = out
 
 
+# a key space up to this many groups keeps its states in arrays over the
+# whole space (a block is one bincount a column); a larger one keeps them
+# by sorted key and merges blocks in
+_DENSE_GROUP_SPACE = 1 << 16
+
+
+class _DenseGroups:
+    """Per-group counts, float64 sums, minima and maxima over blocks of
+    (key, values) rows, in arrays over the whole key space."""
+
+    def __init__(self, space: int, sum_cols, range_cols) -> None:
+        self.counts = np.zeros(space, dtype=np.int64)
+        self.sums = {c: np.zeros(space) for c in sum_cols}
+        self.mins = {c: np.full(space, np.inf) for c in range_cols}
+        self.maxs = {c: np.full(space, -np.inf) for c in range_cols}
+
+    def add(self, keys: np.ndarray, vals: Dict[str, np.ndarray]) -> None:
+        space = self.counts.size
+        self.counts += np.bincount(keys, minlength=space)
+        for c, acc in self.sums.items():
+            acc += np.bincount(keys, weights=vals[c], minlength=space)
+        for c in self.mins:
+            np.minimum.at(self.mins[c], keys, vals[c])
+            np.maximum.at(self.maxs[c], keys, vals[c])
+
+    def finish(self):
+        """(sorted live keys, counts, sums, minima, maxima), each [k]."""
+        uniq = np.flatnonzero(self.counts)
+        pick = lambda arrays: {c: a[uniq] for c, a in arrays.items()}
+        return uniq, self.counts[uniq], pick(self.sums), pick(self.mins), pick(self.maxs)
+
+
+class _SparseGroups:
+    """The same states by sorted unique key, for a key space too large
+    for arrays (the LONG_MAP_BASED analog).  A block is reduced to its
+    own groups and waits; the waiting parts are merged into the running
+    state once they hold as many keys as it does, so that a pass sorts
+    O(n log n) keys in all and holds the states and one block."""
+
+    def __init__(self, sum_cols=(), range_cols=()) -> None:
+        self._sum_cols, self._range_cols = tuple(sum_cols), tuple(range_cols)
+        none, no_value = np.zeros(0, dtype=np.int64), np.zeros(0)
+        self._state = (
+            none,
+            none,
+            {c: no_value for c in self._sum_cols},
+            {c: no_value for c in self._range_cols},
+            {c: no_value for c in self._range_cols},
+        )
+        self._parts: List[tuple] = []
+        self._waiting = 0
+
+    def _combine(self, keys, counts, sums, mins, maxs) -> tuple:
+        """Rows or parts with repeated keys -> one entry a key.  For a
+        block's rows ``counts`` is None (one each) and ``sums``,
+        ``mins`` and ``maxs`` are all the rows' values."""
+        uniq, inv = np.unique(keys, return_inverse=True)
+        k = uniq.size
+        cnt = np.bincount(inv, weights=counts, minlength=k).astype(np.int64)
+        out_sums = {c: np.bincount(inv, weights=sums[c], minlength=k) for c in self._sum_cols}
+        out_mins, out_maxs = {}, {}
+        for c in self._range_cols:
+            out_mins[c] = np.full(k, np.inf)
+            out_maxs[c] = np.full(k, -np.inf)
+            np.minimum.at(out_mins[c], inv, mins[c])
+            np.maximum.at(out_maxs[c], inv, maxs[c])
+        return uniq, cnt, out_sums, out_mins, out_maxs
+
+    def add(self, keys: np.ndarray, vals: Dict[str, np.ndarray]) -> None:
+        part = self._combine(keys, None, vals, vals, vals)
+        self._parts.append(part)
+        self._waiting += part[0].size
+        if self._waiting >= self._state[0].size:
+            self._merge()
+
+    def _merge(self) -> None:
+        parts = [self._state] + self._parts
+        cat = lambda i, c=None: np.concatenate([p[i] if c is None else p[i][c] for p in parts])
+        self._state = self._combine(
+            cat(0),
+            cat(1),
+            {c: cat(2, c) for c in self._sum_cols},
+            {c: cat(3, c) for c in self._range_cols},
+            {c: cat(4, c) for c in self._range_cols},
+        )
+        self._parts, self._waiting = [], 0
+
+    def finish(self):
+        if self._parts:
+            self._merge()
+        return self._state
+
+
 def _groupby_vectorized(
-    segments: List[ImmutableSegment],
     ctx: TableContext,
     request: BrokerRequest,
     res: IntermediateResult,
-    matched_rows=None,
-) -> None:
-    """Vectorized LONG_MAP_BASED analog: one int64 key per matched row,
-    factorized with np.unique; sums/counts via bincount, min/max via
-    sorted reduceat; groups trimmed to topN*5 before materializing
+    blocks: Iterator[_Block],
+) -> Iterator[None]:
+    """Vectorized LONG_MAP_BASED analog, a block a step: one int64 key
+    per matched row; counts and sums via bincount, min/max via
+    ``ufunc.at``, carried per group from block to block (``_DenseGroups``
+    or ``_SparseGroups``); groups trimmed to topN*5 before materializing
     Python keys (MCombineGroupByOperator.java:216 trim semantics)."""
     gb = request.group_by
     gcards = [max(ctx.column(c).global_cardinality, 1) for c in gb.columns]
+    space = 1
+    for g in gcards:
+        space *= g
     # columns whose decoded values the states actually need (count reads
-    # none); gathered once per (segment, column) even when several
+    # none); gathered once per (block, column) even when several
     # aggregations share a column
     val_columns = {
         a.column
@@ -211,101 +392,68 @@ def _groupby_vectorized(
         and a.column != "*"
         and a.base_function not in _DISTINCT_AGGS
     }
+    range_columns = {
+        a.column
+        for a in request.aggregations
+        if a.base_function in ("min", "max", "minmaxrange")
+    }
+    sum_columns = {
+        a.column for a in request.aggregations if a.base_function in ("sum", "avg")
+    }
     gid_columns = {
         a.column
         for a in request.aggregations
         if a.base_function in _DISTINCT_AGGS
     }
+    groups = (
+        _DenseGroups(space, sum_columns, range_columns)
+        if space <= _DENSE_GROUP_SPACE
+        else _SparseGroups(sum_columns, range_columns)
+    )
+    # distinct/HLL: the set of (group key, gid) pairs per column, packed
+    # into one int64 (``_vectorizable_groupby`` holds the product under 2^62)
+    gid_cards = {c: max(ctx.column(c).global_cardinality, 1) for c in gid_columns}
+    pairs = {c: _SparseGroups() for c in gid_columns}
+    decoders: dict = {}
+    # a (segment, group column)'s dictionary ids -> the column's digit of
+    # the mixed-radix key, times the radix of the columns after it, in
+    # int64: a block's keys are one gather a column, summed in place
+    radix = [1] * len(gcards)
+    for j in range(len(gcards) - 2, -1, -1):
+        radix[j] = radix[j + 1] * gcards[j + 1]
+    digits: Dict[Tuple[int, int], np.ndarray] = {}
 
-    if matched_rows is None:
-        matched_rows = _default_matched_rows(request)
-    all_keys: List[np.ndarray] = []
-    col_vals: Dict[str, List[np.ndarray]] = {c: [] for c in val_columns}
-    col_gids: Dict[str, List[np.ndarray]] = {c: [] for c in gid_columns}
-    for si, seg in enumerate(segments):
-        matched = matched_rows(si, seg)
-        res.num_docs_scanned += int(matched.size)
-        if matched.size == 0:
-            continue
-        keys = np.zeros(matched.size, dtype=np.int64)
-        for c, gcard in zip(gb.columns, gcards):
-            col = seg.column(c)
-            remap = ctx.column(c).remaps[si]
-            keys = keys * gcard + remap[col.fwd[matched]].astype(np.int64)
-        all_keys.append(keys)
-        for c in val_columns:
-            col = seg.column(c)
-            col_vals[c].append(
-                np.asarray(col.dictionary.values, dtype=np.float64)[col.fwd[matched]]
-            )
-        for c in gid_columns:
-            col = seg.column(c)
-            col_gids[c].append(ctx.column(c).remaps[si][col.fwd[matched]])
+    def digit(si: int, j: int) -> np.ndarray:
+        if (si, j) not in digits:
+            digits[si, j] = ctx.column(gb.columns[j]).remaps[si].astype(np.int64) * radix[j]
+        return digits[si, j]
 
-    if not all_keys:
+    for blk in blocks:
+        res.num_docs_scanned += blk.n
+        if blk.n:
+            keys = digit(blk.si, 0)[blk.take(blk.seg.column(gb.columns[0]).fwd)]
+            for j in range(1, len(gb.columns)):
+                keys += digit(blk.si, j)[blk.take(blk.seg.column(gb.columns[j]).fwd)]
+            groups.add(keys, {c: _decoded(decoders, c, blk) for c in val_columns})
+            for c in gid_columns:
+                gids = ctx.column(c).remaps[blk.si][blk.take(blk.seg.column(c).fwd)]
+                pairs[c].add(keys * gid_cards[c] + gids.astype(np.int64), {})
+        yield
+
+    uniq, int_counts, sums, mins, maxs = groups.finish()
+    k = uniq.size
+    if k == 0:
         return
-    keys = np.concatenate(all_keys)
-    space = 1
-    for g in gcards:
-        space *= g
-    if space <= (1 << 24) and space <= max(keys.size, 1) * 8:
-        # small DENSE key space (sort-pairs overflow fallbacks group by
-        # a low-card column): factorize with presence + rank gather
-        # instead of np.unique's 134M-row argsort + cumsum (~30s saved
-        # at north-star scale).  The dense-side peak is 5 bytes/slot
-        # (bool presence + int32 cumsum ranks) — the r5 version's two
-        # space-sized int64 arrays cost 16 bytes/slot, a peak-RSS
-        # regression that bit even when only a handful of keys were
-        # live; a space much larger than the matched-row count (sparse)
-        # takes the sort path instead, whose footprint scales with rows.
-        present = np.zeros(space, dtype=bool)
-        present[keys] = True
-        uniq = np.flatnonzero(present).astype(np.int64)
-        rank = np.cumsum(present, dtype=np.int32)  # rank+1 at each live key
-        inv = (rank[keys] - 1).astype(np.int64)
-        del present, rank
-        k = uniq.size
-        counts = np.bincount(inv, minlength=k).astype(np.float64)
-    else:
-        uniq, inv = np.unique(keys, return_inverse=True)
-        k = uniq.size
-        counts = np.bincount(inv, minlength=k).astype(np.float64)
+    counts = int_counts.astype(np.float64)
 
-    # per-agg finalized state arrays, each [k]
-    order = None  # lazily computed stable sort of inv, for reduceat
-    boundaries = None
-
-    def seg_minmax(vals: np.ndarray):
-        nonlocal order, boundaries
-        if order is None:
-            order = np.argsort(inv, kind="stable")
-            boundaries = np.searchsorted(inv[order], np.arange(k))
-        sorted_vals = vals[order]
-        return (
-            np.minimum.reduceat(sorted_vals, boundaries),
-            np.maximum.reduceat(sorted_vals, boundaries),
-        )
-
-    cat_vals = {c: np.concatenate(v) for c, v in col_vals.items()}
-    minmax_cache: Dict[str, tuple] = {}
-
-    # distinct/HLL: one (group, gid) pair dedup per column — sorted, so
-    # each group's distinct gids are one contiguous slice
+    # sorted packed pairs: each group's distinct gids are one contiguous slice
     distinct_cache: Dict[str, tuple] = {}
 
     def distinct_pairs(c: str):
         if c not in distinct_cache:
-            gc = max(ctx.column(c).global_cardinality, 1)
-            gid = np.concatenate(col_gids[c])
-            if k * gc < (1 << 31):
-                # int32 packed pairs sort ~2x faster than int64
-                pair = np.unique(
-                    inv.astype(np.int32) * np.int32(gc) + gid.astype(np.int32)
-                ).astype(np.int64)
-            else:
-                pair = np.unique(inv.astype(np.int64) * gc + gid.astype(np.int64))
-            pg = (pair // gc).astype(np.int64)  # sorted: per-group slices
-            pgid = pair % gc
+            packed = pairs[c].finish()[0]
+            pg = np.searchsorted(uniq, packed // gid_cards[c])
+            pgid = packed % gid_cards[c]
             dcounts = np.bincount(pg, minlength=k).astype(np.float64)
             bounds = np.searchsorted(pg, np.arange(k + 1))
             distinct_cache[c] = (pgid, bounds, dcounts)
@@ -333,19 +481,14 @@ def _groupby_vectorized(
                 states.append(("hll", a.column, pgid, bounds))
                 order_vals.append(dcounts)
             continue
-        vals = cat_vals[a.column]
         if base == "sum":
-            s = np.bincount(inv, weights=vals, minlength=k)
-            states.append(("sum", s))
-            order_vals.append(s)
+            states.append(("sum", sums[a.column]))
+            order_vals.append(sums[a.column])
         elif base == "avg":
-            s = np.bincount(inv, weights=vals, minlength=k)
-            states.append(("avg", s, counts))
-            order_vals.append(s / np.maximum(counts, 1))
+            states.append(("avg", sums[a.column], counts))
+            order_vals.append(sums[a.column] / np.maximum(counts, 1))
         elif base in ("min", "max", "minmaxrange"):
-            if a.column not in minmax_cache:
-                minmax_cache[a.column] = seg_minmax(vals)
-            mn, mx = minmax_cache[a.column]
+            mn, mx = mins[a.column], maxs[a.column]
             if base == "min":
                 states.append(("min", mn))
                 order_vals.append(mn)
@@ -445,13 +588,39 @@ def execute_host(
     sel_columns: Optional[List[str]],
     matched_rows=None,
 ) -> IntermediateResult:
-    """Cost-accounted wrapper: every host-served query reports hostMs,
-    bytesScanned, and the host serving tier on its result's cost vector
-    (engine/results.py COST_KEYS)."""
+    """The host's answer: ``execute_host_steps`` run to its end."""
+    return run_steps(
+        execute_host_steps(segments, ctx, request, total_docs, sel_columns, matched_rows)
+    )
+
+
+def run_steps(steps):
+    """Run a generator of steps to its end; its return value."""
+    while True:
+        try:
+            next(steps)
+        except StopIteration as done:
+            return done.value
+
+
+def execute_host_steps(
+    segments: List[ImmutableSegment],
+    ctx: TableContext,
+    request: BrokerRequest,
+    total_docs: int,
+    sel_columns: Optional[List[str]],
+    matched_rows=None,
+):
+    """The pass as a generator: one ``yield`` after each block of
+    ``config.HOST_BLOCK_ROWS`` rows, the result as its return value.
+    Cost-accounted: every host-served query reports hostMs (wall time,
+    the caller's pauses between steps included), bytesScanned, and the
+    host serving tier on its result's cost vector (engine/results.py
+    COST_KEYS)."""
     import time as _time
 
     t0 = _time.perf_counter()
-    res = _execute_host_impl(
+    res = yield from _execute_host_impl(
         segments, ctx, request, total_docs, sel_columns, matched_rows
     )
     res.add_cost(
@@ -469,21 +638,20 @@ def _execute_host_impl(
     total_docs: int,
     sel_columns: Optional[List[str]],
     matched_rows=None,
-) -> IntermediateResult:
+):
     res = IntermediateResult(
         total_docs=total_docs,
         num_segments_queried=len(segments),
     )
-    if matched_rows is None:
-        matched_rows = _default_matched_rows(request)
+    blocks = _matched_blocks(segments, request, matched_rows)
     if request.is_group_by:
         res.groups = {}
         if _vectorizable_groupby(request, segments, ctx):
-            _groupby_vectorized(segments, ctx, request, res, matched_rows)
+            yield from _groupby_vectorized(ctx, request, res, blocks)
             return res
     elif request.is_aggregation:
         if _vectorizable_aggs(request, segments):
-            _aggregation_vectorized(segments, request, res, matched_rows)
+            yield from _aggregation_vectorized(request, res, blocks)
             return res
         # row-wise accumulators (NOT mergeable partials — those have no
         # .add); _to_partial adapts them below, same as the group-by path
@@ -492,9 +660,11 @@ def _execute_host_impl(
         res.selection_rows = []
         res.selection_columns = sel_columns
 
-    for si, seg in enumerate(segments):
-        matched = matched_rows(si, seg)
-        res.num_docs_scanned += int(matched.size)
+    taken = [0] * len(segments)  # unsorted selection: rows taken a segment
+    for blk in blocks:
+        seg = blk.seg
+        res.num_docs_scanned += blk.n
+        matched = blk.rows() if blk.n else ()
 
         if request.is_group_by:
             gb = request.group_by
@@ -515,7 +685,9 @@ def _execute_host_impl(
         else:
             sel = request.selection
             k = sel.offset + sel.size
-            take = matched[: k] if not sel.sorts else matched
+            # unsorted: the first k matched rows of each segment, as before blocks
+            take = matched if sel.sorts else matched[: max(k - taken[blk.si], 0)]
+            taken[blk.si] += len(take)
             for doc in take:
                 row = seg.row(int(doc))
                 sort_vals = []
@@ -525,8 +697,7 @@ def _execute_host_impl(
                         v = v[0] if v else None
                     sort_vals.append(v)
                 res.selection_rows.append((sort_vals, [row[c] for c in sel_columns]))
-            if sel.sorts and len(res.selection_rows) > 4 * k:
-                pass  # bounded enough for fallback; final trim at reduce
+        yield
 
     # adapt oracle accumulators -> mergeable partials
     if request.is_group_by:

@@ -16,11 +16,29 @@ background samplers that re-derive ground truth and compare:
   differential contract — ``numDocsScanned`` etc. legitimately differ
   per tier) with a bounded numeric tolerance (``payloads_equivalent``):
   a float32 device sum and the float64 host oracle honestly wobble with
-  accumulation order.  A divergence increments ``audit.divergences``,
+  accumulation order, and rank groups whose values tie within that
+  tolerance differently.  A divergence increments ``audit.divergences``,
   dumps a
   flight-recorder bundle carrying both payloads + tier/residency state,
   and quarantines the (plan digest, tier) via the executor's poison map
   so the lying tier stops serving that shape.
+
+  The oracle streams: ``QueryExecutor.host_oracle_steps`` is the pass
+  as a generator, one step a block of ``config.HOST_BLOCK_ROWS`` rows
+  (``engine/host_fallback.py``: float64 partial states carried from
+  block to block, every row of every view still read), and the worker
+  drives the steps on its own thread: no numpy call holds the
+  interpreter lock for longer than a block takes, so a pass over 134M
+  rows hands the interpreter over whenever a serving thread has waited
+  a switch interval, instead of holding it for a segment's whole call.
+  Series: meter ``audit.offered`` (every N-th eligible answer, before
+  budget and queue) beside
+  ``audit.samples`` (passes finished), ``audit.dropped``,
+  ``audit.errors`` and ``audit.divergences``; timer ``audit.stepMs``
+  (one update a step, the worker's processor time) with gauge ``audit.stepMaxMs`` (the longest of
+  the timer's retained steps); timer ``audit.shadowMs``, the wall time
+  of a whole pass, which is also the ``pinot:auditPass`` annotation on
+  the profiler's clock, tagged with the plan digest and shape.
 
 - ``ReplicaAuditor`` (broker-side): occasionally re-issues a sampled
   query's first batch to BOTH the original server and an alternate
@@ -186,6 +204,37 @@ def _as_number(x: Any) -> Optional[float]:
     return None
 
 
+def _ranked_groups_equivalent(a, b, rel_tol: float, abs_tol: float) -> bool:
+    """Two ``groupByResult`` lists (groups ranked by value) that differ
+    only in the order of groups whose values tie within the tolerance.
+
+    The rank of two groups whose values lie closer than the band that
+    forgives each value says nothing more than the values do: at 134M
+    rows two of the six groups of a TPC-H Q1 shape held sums 3e-7 apart
+    on one seed of some fifty, the device's float32 sum and the oracle's
+    float64 one rank them differently, and a byte-exact order
+    quarantined the healthy device tier (PERF.md, PR 29).  So: the same
+    set of group labels, byte-exact; every group's value close to the
+    same group's value on the other side; and the value at every rank
+    close to the value at that rank on the other side, which leaves a
+    group no place but among its ties.  A group missing, a label
+    altered, a value off or a rank taken from a group outside the band
+    still reads as divergence."""
+    entries = [g for side in (a, b) for g in side]
+    if not entries or not all(
+        isinstance(g, dict) and g.keys() == {"group", "value"} for g in entries
+    ):
+        return False
+    theirs = {tuple(g["group"]): g["value"] for g in b}
+    if len(theirs) != len(b) or {tuple(g["group"]) for g in a} != theirs.keys():
+        return False
+    return all(
+        payloads_equivalent(x["value"], theirs[tuple(x["group"])], rel_tol, abs_tol)
+        and payloads_equivalent(x["value"], y["value"], rel_tol, abs_tol)
+        for x, y in zip(a, b)
+    )
+
+
 def payloads_equivalent(
     a: Any, b: Any, rel_tol: float = 5e-4, abs_tol: float = 1e-3
 ) -> bool:
@@ -204,7 +253,9 @@ def payloads_equivalent(
     of magnitude above the band, and the exact-aggregate contract (ints,
     min/max, counts) still compares exactly: identical values are always
     close.  Structure, keys, ordering, group labels, and non-numeric
-    strings remain byte-exact."""
+    strings remain byte-exact.  One order is not: groups of a
+    ``groupByResult`` whose values tie within the same band may stand in
+    either order (``_ranked_groups_equivalent``)."""
     if isinstance(a, dict) and isinstance(b, dict):
         if a.keys() != b.keys():
             return False
@@ -217,7 +268,7 @@ def payloads_equivalent(
         return all(
             payloads_equivalent(x, y, rel_tol, abs_tol)
             for x, y in zip(a, b)
-        )
+        ) or _ranked_groups_equivalent(a, b, rel_tol, abs_tol)
     if a == b:
         return True
     na, nb = _as_number(a), _as_number(b)
@@ -245,7 +296,6 @@ class ShadowAuditor:
 
     _QUEUE_MAX = 16
     _DIVERGENCE_RING = 16
-
     def __init__(
         self,
         instance,
@@ -261,10 +311,12 @@ class ShadowAuditor:
         self.budget = budget if budget is not None else BUDGET
         self.metrics = instance.metrics
         for m in (
-            "audit.samples", "audit.divergences", "audit.dropped",
-            "audit.errors", "audit.quarantines",
+            "audit.offered", "audit.samples", "audit.divergences",
+            "audit.dropped", "audit.errors", "audit.quarantines",
         ):
             self.metrics.meter(m)
+        step_ms = self.metrics.timer("audit.stepMs")
+        self.metrics.gauge("audit.stepMaxMs").set_fn(lambda: step_ms.percentile(100))
         self._count = 0
         self._queue: deque = deque()
         self._divergences: deque = deque(maxlen=self._DIVERGENCE_RING)
@@ -297,6 +349,7 @@ class ShadowAuditor:
             # host-served replies ARE the oracle — re-checking them
             # could only burn budget agreeing with itself
             return False
+        self.metrics.meter("audit.offered").mark()
         if not self.budget.take():
             self.metrics.meter("audit.dropped").mark()
             return False
@@ -353,24 +406,67 @@ class ShadowAuditor:
                     logger.exception("shadow audit failed")
                     self.metrics.meter("audit.errors").mark()
 
-    def _audit_one(self, job: dict) -> None:
+    def _between_steps(self) -> bool:
+        """On the worker, after every step of a pass: True to go on.
+        The steps are what gives the interpreter up: no numpy call of a
+        step holds the lock for longer than a block takes (most release
+        it outright), and where a serving thread has waited a switch
+        interval the interpreter hands it over at the next bytecode,
+        which a block is never far from.  No sleep is taken here: on
+        the chip's host a release of the worker's own between blocks
+        (``time.sleep(0)``) made a pass a fifth longer, a sleep of 1 ms
+        tripled a step, and a thread that sleeps 1 ms at a time woke no
+        later beside a pass without either (PERF.md, PR 29)."""
+        return not self._stop.is_set()
+
+    def _oracle_pass(self, job: dict, digest: str):
+        """One pass of the streamed host oracle over the job's views:
+        step by step on this thread, each step timed on the thread's own
+        processor clock (``audit.stepMs``: what the worker can have kept
+        the interpreter for; a stall of the whole machine, which a wall
+        clock would read into the step it falls in, is no part of it).
+        ``audit.shadowMs`` and the ``pinot:auditPass`` annotation are
+        the pass's wall time.  None if the auditor was stopped before
+        the pass ended."""
+        from pinot_tpu.utils.trace import boundary
+
         request = job["request"]
-        t0 = time.perf_counter()
-        oracle = self.instance.executor.execute_host_oracle(
-            job["views"], request
+        shape = (
+            "groupBy" if request.is_group_by
+            else "aggregation" if request.is_aggregation
+            else "selection"
         )
-        self.metrics.timer("audit.shadowMs").update(
-            (time.perf_counter() - t0) * 1000.0
-        )
+        step_ms = self.metrics.timer("audit.stepMs")
+        steps = self.instance.executor.host_oracle_steps(job["views"], request)
+        with boundary(
+            "auditPass", timer=self.metrics.timer("audit.shadowMs"),
+            requestId=job["requestId"], digest=digest, shape=shape,
+        ):
+            while True:
+                t0 = time.thread_time()
+                try:
+                    next(steps)
+                except StopIteration as done:
+                    return done.value
+                step_ms.update((time.thread_time() - t0) * 1000.0)
+                if not self._between_steps():
+                    steps.close()
+                    return None  # the server is going down: the pass is abandoned
+
+    def _audit_one(self, job: dict) -> None:
+        from pinot_tpu.engine.plandigest import plan_shape_digest
+
+        request = job["request"]
+        digest = plan_shape_digest(request)
+        oracle = self._oracle_pass(job, digest)
+        if oracle is None:
+            return
         self.metrics.meter("audit.samples").mark()
         produced = canonical_payload(request, job["result"])
         expected = canonical_payload(request, oracle)
         if payloads_equivalent(produced, expected):
             return
         # -- divergence: the device (or an optimization tier) lied -----
-        from pinot_tpu.engine.plandigest import plan_shape_digest
-
-        digest = plan_shape_digest(request)
         tier = getattr(job["result"], "_served_tier", "unknown")
         detect_ms = (time.monotonic() - job["enqueuedAt"]) * 1000.0
         self.metrics.meter("audit.divergences").mark()
@@ -407,7 +503,8 @@ class ShadowAuditor:
             "enabled": self.enabled,
             "sampleN": self.sample_n,
             "budgetPerS": self.budget.per_s,
-            "offered": self._count,
+            "completed": self._count,
+            "offered": self.metrics.meter("audit.offered").count,
             "samples": self.metrics.meter("audit.samples").count,
             "divergences": self.metrics.meter("audit.divergences").count,
             "dropped": self.metrics.meter("audit.dropped").count,

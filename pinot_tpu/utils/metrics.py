@@ -699,7 +699,8 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     # differential sampling against the host oracle + event-time
     # watermarks per consuming partition
     "audit.samples": "completed queries re-executed against the host "
-    "oracle by the shadow auditor (1-in-N sampled, off the serving path)",
+    "oracle by the shadow auditor (1-in-N sampled, off the serving path), "
+    "counted when the pass has finished",
     "audit.divergences": "shadow re-executions whose stripped payload "
     "differed from the served answer (wrong answer detected)",
     "audit.quarantines": "(plan digest, tier) quarantines placed by the "
@@ -708,8 +709,19 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "budget exhausted — auditing never blocks serving)",
     "audit.errors": "shadow re-executions that errored before a "
     "comparison (not counted as divergence)",
+    "audit.offered": "every N-th eligible completed answer (the 1-in-N "
+    "winners), counted before the sampler budget and the queue bound: "
+    "samples + dropped + errors + those still queued or in their pass",
     "audit.queueDepth": "shadow-audit jobs currently queued",
-    "audit.shadowMs": "host-oracle re-execution wall ms per audit sample",
+    "audit.shadowMs": "wall ms of one whole pass of the streamed host "
+    "oracle (all its steps, and what the worker waited between them); the "
+    "pinot:auditPass annotation is the same interval",
+    "audit.stepMs": "processor ms of the auditor's thread in one step of "
+    "the oracle's pass (one block of config.HOST_BLOCK_ROWS rows): what "
+    "it can have held the interpreter for; not wall time, which reads a "
+    "stall of the machine into the step it falls in (the thread clock "
+    "ticks in 10 ms on some hosts)",
+    "audit.stepMaxMs": "longest step among audit.stepMs's retained samples",
     "audit.detectMs": "query-completion to divergence-detection wall ms",
     "freshness.lag.*": "per-(table, partition) event-time lag ms "
     "(now - max ingested event time)",

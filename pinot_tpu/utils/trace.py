@@ -215,6 +215,9 @@ def _annotation(name: str, rid: str, tags: Dict[str, Any]):
     program = tags.get("program")
     if program:
         return _annotation_cls("pinot:" + name, rid=rid, program=program)
+    digest = tags.get("digest")
+    if digest:  # an audit pass: the plan's digest and the kind of query
+        return _annotation_cls("pinot:" + name, rid=rid, digest=digest, shape=tags.get("shape", ""))
     return _annotation_cls("pinot:" + name, rid=rid)
 
 
@@ -227,7 +230,8 @@ class boundary:
         enabled (no span dict otherwise: the ``SPAN_ALLOCATIONS``
         contract), parented like ``ctx.span`` or under ``parent``,
     (c) emits the ``jax.profiler.TraceAnnotation`` ``pinot:<name>``
-        with ``rid=<requestId>`` (and ``program=`` where tagged), so a
+        with ``rid=<requestId>`` (and ``program=``, or ``digest=`` and
+        ``shape=``, where tagged), so a
         capture shows the same interval on the host plane, on the clock
         of its device planes.  With no capture running that is TraceMe's
         flag test and nothing else.

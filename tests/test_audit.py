@@ -62,6 +62,40 @@ def test_payloads_equivalent_structure_is_exact():
     )
 
 
+def _ranked(*groups):
+    return {"aggregationResults": [{
+        "function": "sum_m", "groupByColumns": ["flag", "status"],
+        "groupByResult": [{"value": v, "group": list(g)} for g, v in groups],
+    }]}
+
+
+@pytest.mark.parametrize("produced,expected,same", [
+    # the reading of PR 29's chip run: two groups 3e-7 apart, ranked the other way round by a float32 sum
+    (_ranked((("A", "F"), "1118647.75000"), (("N", "O"), "1118612.87500"), (("R", "O"), "1118606.50000"),
+             (("A", "O"), "1118606.37500"), (("R", "F"), "1118500.12500")),
+     _ranked((("A", "F"), "1118548.26000"), (("N", "O"), "1118513.31000"), (("A", "O"), "1118506.87000"),
+             (("R", "O"), "1118506.53000"), (("R", "F"), "1118400.57000")), True),
+    # a rank taken from a group outside the band: the order is wrong, whatever the values
+    (_ranked((("b",), "90.00000"), (("a",), "100.00000")), _ranked((("a",), "100.00000"), (("b",), "90.00000")), False),
+    # tied ranks, but one group's own value is off
+    (_ranked((("b",), "100.00010"), (("a",), "100.00000")), _ranked((("a",), "100.00000"), (("b",), "50.00000")), False),
+    # a label altered among ties, a group missing, a group twice
+    (_ranked((("b",), "100.00010"), (("c",), "100.00000")), _ranked((("a",), "100.00000"), (("b",), "100.00010")), False),
+    (_ranked((("b",), "100.00010")), _ranked((("a",), "100.00000"), (("b",), "100.00010")), False),
+    (_ranked((("b",), "100.00010"), (("b",), "100.00000")), _ranked((("a",), "100.00000"), (("b",), "100.00010")), False),
+    # the same order: the leaves' tolerance alone, as before
+    (_ranked((("a",), "100.00100"), (("b",), "90.00000")), _ranked((("a",), "100.00000"), (("b",), "90.00000")), True),
+    (_ranked((("a",), "101.00000"), (("b",), "90.00000")), _ranked((("a",), "100.00000"), (("b",), "90.00000")), False),
+], ids=["tie_swapped", "rank_outside_band", "tie_value_off", "label_altered", "group_missing", "group_twice",
+        "same_order_close", "same_order_off"])
+def test_payloads_equivalent_group_rank_among_ties(produced, expected, same):
+    """Groups whose values tie within the band may stand in either
+    order (a float32 and a float64 sum rank them differently); nothing
+    else about a ranked group list is forgiven, in either direction."""
+    assert payloads_equivalent(produced, expected) is same
+    assert payloads_equivalent(expected, produced) is same
+
+
 def test_unstripped_field_difference_still_fails():
     """Negative differential guard (satellite 1): stripping accounting
     must not widen the contract — two payloads differing in any
