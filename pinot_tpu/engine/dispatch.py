@@ -383,7 +383,7 @@ class _Dispatch:
     __slots__ = (
         "key", "launch", "pending", "waiters", "completed", "value",
         "error", "plan_digest", "cost_provider", "batch", "batch_size",
-        "t_submit", "trace", "parent", "program", "groupby", "launch_id",
+        "t_submit", "trace", "parent", "program", "groupby", "operands", "launch_id",
     )
 
     def __init__(
@@ -399,6 +399,7 @@ class _Dispatch:
         program: str = "",
         t_submit: float = 0.0,
         groupby: str = "",
+        operands: str = "",
     ) -> None:
         self.t_submit = t_submit  # phase.laneQueue runs from here to the launch call
         # the submitting query's span tree and the span (its laneWait)
@@ -407,6 +408,7 @@ class _Dispatch:
         self.parent = parent
         self.program = program  # the jitted program's name, for the launch's tags
         self.groupby = groupby  # a group-by program's lowering (kernel.groupby_lowering)
+        self.operands = operands  # and where its operands are built (kernel.groupby_operands)
         self.launch_id: Optional[int] = None  # the physical launch this rode (occupancy)
         self.key = key
         self.launch = launch
@@ -581,6 +583,7 @@ class DeviceLane:
         parent: Optional[str] = None,
         program: str = "",
         groupby: str = "",
+        operands: str = "",
     ) -> LaneTicket:
         """Enqueue a kernel launch, or coalesce onto an identical one
         that is queued, launching, or still executing on device.
@@ -593,7 +596,10 @@ class DeviceLane:
         program's name.  A coalesced ticket gets neither span: its wait
         is all ``laneWait``.  ``groupby``: a group-by program's lowering
         (``kernel.groupby_lowering``), the launch's ``groupby=`` tag and
-        its ``groupby.lowering.*`` mark.
+        its ``groupby.lowering.*`` mark; ``operands``: where its operands
+        are built (``kernel.groupby_operands``), the ``operands=`` tag, and
+        one ``groupby.operands.loop`` mark a launch that builds them in
+        the row loop.
 
         ``cost_provider`` (optional, utilization plane): a zero-arg
         callable returning the plan's static XLA cost analysis (or
@@ -633,7 +639,7 @@ class DeviceLane:
                 self._hit()
             else:
                 d = _Dispatch(key, launch, pending, plan_digest, cost_provider, batch,
-                              trace, parent, program, t_submit, groupby)
+                              trace, parent, program, t_submit, groupby, operands)
                 d.waiters.append(ticket)
                 self._by_key[key] = d
                 self._depth_tick_locked()
@@ -1185,7 +1191,7 @@ class DeviceLane:
             # ``via`` says before the call whether this plan digest has
             # launched here ("first": it may compile) and after it how
             # the first launch got its executable.
-            tags = {"groupby": d.groupby} if d.groupby else {}
+            tags = {"groupby": d.groupby, "operands": d.operands} if d.groupby else {}
             launching = boundary(
                 "laneDispatch", d.trace, launch_timer, parent=d.parent, program=d.program,
                 via="warm" if d.plan_digest is None or d.plan_digest in self._compile else "first",
@@ -1193,6 +1199,8 @@ class DeviceLane:
             ).start()
             if d.groupby and self.metrics is not None:
                 self.metrics.meter(f"groupby.lowering.{d.groupby}").mark()
+                if d.operands == "loop":
+                    self.metrics.meter("groupby.operands.loop").mark()
             self._set_inflight(1)
             error: Optional[BaseException] = None
             value: Any = None

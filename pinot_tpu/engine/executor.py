@@ -1309,12 +1309,15 @@ class QueryExecutor:
         # ``program=`` tag of the launch and wait spans, as the device
         # planes of a capture name it
         program = getattr(kernel, "__name__", "")
-        # a group-by program's lowering, from the function the kernel
-        # builder asks: the launch's ``groupby=`` tag and its
-        # ``groupby.lowering.*`` mark ("" for any other program)
-        from pinot_tpu.engine.kernel import groupby_lowering
+        # a group-by program's lowering and where its operands are built,
+        # from the functions the kernel builder asks: the launch's
+        # ``groupby=`` and ``operands=`` tags, its ``groupby.lowering.*``
+        # mark and, built in the row loop, its ``groupby.operands.loop``
+        # mark ("" for any other program)
+        from pinot_tpu.engine.kernel import groupby_lowering, groupby_operands
 
         groupby = groupby_lowering(plan) or ""
+        operands = groupby_operands(plan) or ""
         coalesced = False
         ticket = None
         # planExec excludes lane queueing (timed as laneWait): it covers
@@ -1327,6 +1330,8 @@ class QueryExecutor:
                 executing.start()
                 if groupby:
                     self.metrics.meter(f"groupby.lowering.{groupby}").mark()
+                if operands == "loop":
+                    self.metrics.meter("groupby.operands.loop").mark()
                 fetch, handle = launch()
             else:
                 # coalesce key: identical (plan, staged-table token, inputs
@@ -1360,6 +1365,7 @@ class QueryExecutor:
                         parent=waiting.span_id,
                         program=program,
                         groupby=groupby,
+                        operands=operands,
                     )
                     fetch, handle = ticket.result(deadline)
                     # the lane thread delivered -> this worker runs again

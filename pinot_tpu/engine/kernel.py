@@ -134,6 +134,73 @@ def groupby_lowering(plan: StaticPlan) -> Optional[str]:
     return "onehot" if cap <= MATMUL_GROUP_CAP else "radix"
 
 
+def _sum_shaped(agg: StaticAgg) -> bool:
+    """count, sum and avg over plain values: the aggregates whose group
+    state is a sum of per-row weight columns (_group_add_weights), so
+    that the states of two blocks of rows add."""
+    return agg.base == "count" or (agg.base in ("sum", "avg") and agg.kind in ("scalar", "pair"))
+
+
+def _contraction_slots(plan: StaticPlan) -> Tuple[Dict[int, List[int]], int]:
+    """Which rows of a dense group-by's float states [m, K] each
+    sum-shaped aggregate reads, and m.  Row 0 is the occupancy (the
+    validity column), which is the plain count's weights exactly."""
+    slots: Dict[int, List[int]] = {}
+    m = 1
+    for i, agg in enumerate(plan.aggs):
+        if not _sum_shaped(agg):
+            continue
+        if agg.base == "count" and not agg.is_mv:
+            slots[i] = [0]
+            continue
+        width = 2 if agg.base == "avg" else 1
+        slots[i] = list(range(m, m + width))
+        m += width
+    return slots, m
+
+
+# The row loop answers a group-by of up to this many (group, column)
+# cells with one masked float32 reduction a cell on the vector unit,
+# whose work grows with the cells where the matrix unit's does not.
+# Table kernel over 16 segments of 8,388,608 rows on a v5e, ms a call
+# (chip run, PR 30; the staged one-hot contraction beside it):
+#   K=6 x 4 columns (k6)   5.5  15.0      K=16 x 4   10.5  17.4
+#   K=7 x 2 columns (q6)   3.6  13.0      K=32 x 2    8.7  14.8
+#   K=8 x 4                5.6  15.1      K=32 x 4   18.4  18.2
+#   K=16 x 2               6.1  15.1      K=64 x 2   15.4  17.7
+# 64 cells is the largest measured count that wins clearly (1.7x) at
+# either width; at 128 the two meet.
+_LOOP_CELLS = 64
+
+
+def groupby_operands(plan: StaticPlan) -> Optional[str]:
+    """Where a dense group-by's operands (filter mask, group key, weight
+    columns) are built, from what the plan states — consulted by the
+    kernel builder and by the launch's ``operands=`` tag and
+    ``groupby.operands.loop`` meter, which must agree.
+
+    'loop':   the 'onehot' lowering of a plan whose every output adds
+              over blocks of rows (count, sum, avg; single-value keys;
+              no selection part) and whose K x m cells number at most
+              _LOOP_CELLS: the row loop is the outermost thing in the
+              segment's program, each step filters, keys and reduces
+              one block of the staged columns (_make_loop_groupby_kernel),
+              and no segment-sized intermediate reaches HBM.
+    'staged': every other group-by: the operands are built over the
+              whole segment and handed to the lowering.
+    None for a plan without a group-by."""
+    lowering = groupby_lowering(plan)
+    if lowering is None:
+        return None
+    additive = (
+        plan.selection is None
+        and not any(plan.group_by.col_is_mv)
+        and all(_sum_shaped(agg) for agg in plan.aggs)
+    )
+    cells = plan.group_by.capacity * _contraction_slots(plan)[1]
+    return "loop" if lowering == "onehot" and additive and cells <= _LOOP_CELLS else "staged"
+
+
 def _segment_add_radix(flat_idx, weights, capacity: int):
     """Occupancy counts and the sums of the float ``weights`` columns
     over ``capacity`` buckets, with ONE two-level one-hot contraction on
@@ -602,9 +669,7 @@ def _group_add_weights(agg: StaticAgg, seg, mask, kvalid):
     (count / sum / avg) — the batchable operands of the fused one-hot
     contraction.  None for aggs needing other combining ops (min/max/
     presence/hist/hll), which keep their own scatter paths."""
-    if agg.base not in ("count", "sum", "avg"):
-        return None
-    if agg.base != "count" and agg.kind not in ("scalar", "pair"):
+    if not _sum_shaped(agg):
         return None
     fdt = config.float_dtype()
     shape = kvalid.shape
@@ -833,13 +898,122 @@ def _count_states_to_int(plan: StaticPlan, out: Dict[str, Any]) -> None:
             out[key] = out[key].astype(cdt)
 
 
-def make_single_segment_kernel(plan: StaticPlan) -> Callable:
+def _filter_mask(plan: StaticPlan, seg, q) -> jnp.ndarray:
+    valid = _valid_mask(seg)
+    if plan.filter_tree is None:
+        return valid
+    return _eval_tree(plan, plan.filter_tree, seg, q) & valid
+
+
+def _contraction_operands(plan: StaticPlan, seg, mask, keys, kvalid, zeroed: bool):
+    """(flat_idx, cols) of the rows of ``seg``: the bucket of every
+    (row, key) entry, ``capacity`` where it is filtered out, and the m
+    weight columns of _contraction_slots, the validity first.
+    ``zeroed``: the weights of a filtered entry are zero, as a matrix
+    product needs them; a masked reduction selects on the index and
+    takes them as they are.  ONE pass covers occupancy AND every
+    sum-shaped agg: one index (one one-hot per chunk) for all of them,
+    instead of a scan per agg — the per-agg version re-streamed the
+    one-hot blocks and dominated the kernel's HBM traffic."""
+    flat_idx = jnp.where(kvalid, keys, plan.group_by.capacity).reshape(-1)
+    fvalid = kvalid.reshape(-1)
+    cols = [fvalid.astype(config.float_dtype())]
+    for i, rows in _contraction_slots(plan)[0].items():
+        if rows != [0]:
+            w = _group_add_weights(plan.aggs[i], seg, mask, kvalid)
+            cols.extend(jnp.where(fvalid, vec, 0) if zeroed else vec for vec in w)
+    return flat_idx, cols
+
+
+def _contraction_outputs(plan: StaticPlan, states, out: Dict[str, Any]) -> None:
+    """gb_presence and the sum-shaped aggregates' gb_<i> from a dense
+    group-by's float states [m, K]."""
+    out["gb_presence"] = (states[0] > 0).astype(jnp.int32)
+    for i, slots in _contraction_slots(plan)[0].items():
+        rows = [states[j] for j in slots]
+        out[f"gb_{i}"] = rows[0] if len(rows) == 1 else tuple(rows)
+
+
+def _block_view(seg: Dict[str, Any], start, size: int) -> Dict[str, Any]:
+    """Rows [start, start + size) of one segment's arrays as a segment
+    of their own, the way _gather_blocks hands over a gathered view:
+    row-shaped arrays sliced, ``valid`` and the original doc ids
+    (``rowid``) sliced where the segment carries them (a gathered view)
+    and derived where it does not, so that _valid_mask and docrange
+    leaves read the block as they read a segment."""
+    view: Dict[str, Any] = {}
+    for k, v in seg.items():
+        if k in ("valid", "rowid") or _row_key(k):
+            view[k] = jax.lax.dynamic_slice_in_dim(v, start, size)
+        elif k != "num_docs":
+            view[k] = v
+    if "rowid" not in view:
+        view["rowid"] = start + jax.lax.iota(jnp.int32, size)
+    if "valid" not in view:
+        view["valid"] = view["rowid"] < seg["num_docs"]
+    return view
+
+
+def _make_loop_groupby_kernel(plan: StaticPlan) -> Callable:
+    """The single-segment kernel of a group-by whose operands are built
+    in the row loop (groupby_operands 'loop').  One scan over blocks of
+    _MATMUL_CHUNK rows; a step slices the staged columns, evaluates the
+    filter, the keys and the weight columns of its block, and adds the
+    block's states [m, K] to the carried ones.  What the program reads
+    from HBM is the staged columns, once; mask, index and weights of a
+    block never leave the chip's vector memory.
+
+    A block's states are K masked reductions a column: the one-hot
+    contraction, evaluated on the vector unit without the one-hot.
+    Values and sums are float32 (the matrix unit at default precision
+    rounds the values to bfloat16), and the elementwise work stays on
+    [segments, rows] tiles: fed to the matrix unit, the same block's
+    operands are laid out [segments, m, rows], m of 8 sublanes used,
+    and a K=6 query costs 12.5 ms for this form's 5.5 (chip run, PR 30)."""
+    cap = plan.group_by.capacity
+    m = _contraction_slots(plan)[1]
+
     def kernel(seg: Dict[str, Any], q: Dict[str, Any]) -> Dict[str, Any]:
-        valid = _valid_mask(seg)
-        if plan.filter_tree is not None:
-            mask = _eval_tree(plan, plan.filter_tree, seg, q) & valid
-        else:
-            mask = valid
+        fdt = config.float_dtype()
+        n = next(v.shape[0] for k, v in seg.items() if k == "valid" or _row_shaped(k))
+        chunk = min(_MATMUL_CHUNK, n)
+
+        def add_block(states, start, size):
+            view = _block_view(seg, start, size)
+            mask = _filter_mask(plan, view, q)
+            keys, kvalid = _group_keys(plan, view, q, mask)
+            flat_idx, cols = _contraction_operands(plan, view, mask, keys, kvalid, zeroed=False)
+            rows = [[] for _ in range(m)]
+            for g in range(cap):
+                hit = flat_idx == g  # never a filtered row: its index is K
+                rows[0].append(jnp.sum(hit, dtype=fdt))
+                for row, vec in zip(rows[1:], cols[1:]):
+                    row.append(jnp.sum(jnp.where(hit, vec, 0), dtype=fdt))
+            return states + jnp.stack([jnp.stack(row) for row in rows])
+
+        states, _ = jax.lax.scan(
+            lambda states, b: (add_block(states, b * chunk, chunk), None),
+            jnp.zeros((m, cap), dtype=fdt),
+            jnp.arange(n // chunk, dtype=jnp.int32),
+        )
+        if n % chunk:  # a gathered view's row count: the tail is a block of its own
+            states = add_block(states, n - n % chunk, n % chunk)
+        # single-value keys: every matched doc is one entry of the occupancy
+        # row, whose float counts are exact (a segment has fewer than 2^24 rows)
+        out: Dict[str, Any] = {"num_docs": jnp.sum(states[0]).astype(config.row_count_dtype())}
+        _contraction_outputs(plan, states, out)
+        _count_states_to_int(plan, out)
+        return out
+
+    return kernel
+
+
+def make_single_segment_kernel(plan: StaticPlan) -> Callable:
+    if groupby_operands(plan) == "loop":
+        return _make_loop_groupby_kernel(plan)
+
+    def kernel(seg: Dict[str, Any], q: Dict[str, Any]) -> Dict[str, Any]:
+        mask = _filter_mask(plan, seg, q)
         out: Dict[str, Any] = {
             "num_docs": jnp.sum(mask, dtype=config.row_count_dtype())
         }
@@ -847,48 +1021,25 @@ def make_single_segment_kernel(plan: StaticPlan) -> Callable:
         if plan.group_by is not None:
             keys, kvalid = _group_keys(plan, seg, q, mask)
             cap = plan.group_by.capacity
-            flat_idx = jnp.where(kvalid, keys, cap).reshape(-1)
-            fvalid = kvalid.reshape(-1)
-            fdt = config.float_dtype()
             lowering = groupby_lowering(plan)
             if lowering != "scatter":
-                # ONE fused one-hot contraction (MXU) covers occupancy
-                # AND every sum-shaped agg: a single pass over rows with
-                # one one-hot per chunk, instead of a scan per agg —
-                # the per-agg version re-streamed the one-hot blocks
-                # and dominated the kernel's HBM traffic
-                cols = [fvalid.astype(fdt)]
-                slots: Dict[int, List[int]] = {}
-                for i, agg in enumerate(plan.aggs):
-                    if agg.base == "count" and not agg.is_mv:
-                        # count weights == the occupancy column exactly
-                        slots[i] = [0]
-                        continue
-                    w = _group_add_weights(agg, seg, mask, kvalid)
-                    if w is None:
-                        continue
-                    slots[i] = []
-                    for vec in w:
-                        slots[i].append(len(cols))
-                        cols.append(jnp.where(fvalid, vec, 0))
+                flat_idx, cols = _contraction_operands(plan, seg, mask, keys, kvalid, zeroed=True)
                 if lowering == "onehot":
                     states = _segment_add_matmul_multi(flat_idx, jnp.stack(cols), cap)
                 else:
                     states = _segment_add_radix(flat_idx, cols[1:], cap)
-                out["gb_presence"] = (states[0] > 0).astype(jnp.int32)
+                _contraction_outputs(plan, states, out)
                 for i, agg in enumerate(plan.aggs):
-                    if i in slots:
-                        rows = [states[j] for j in slots[i]]
-                        out[f"gb_{i}"] = rows[0] if len(rows) == 1 else tuple(rows)
-                    else:
+                    if f"gb_{i}" not in out:
                         out[f"gb_{i}"] = _group_state(
                             agg, i, seg, q, mask, keys, kvalid, cap
                         )
             else:
+                flat_idx = jnp.where(kvalid, keys, cap).reshape(-1)
                 out["gb_presence"] = (
                     jnp.zeros(cap, dtype=jnp.int32)
                     .at[flat_idx]
-                    .max(fvalid.astype(jnp.int32), mode="drop")
+                    .max(kvalid.reshape(-1).astype(jnp.int32), mode="drop")
                 )
                 for i, agg in enumerate(plan.aggs):
                     out[f"gb_{i}"] = _group_state(

@@ -607,6 +607,9 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "(K <= RADIX_GROUP_CAP)",
     "groupby.lowering.scatter": "group-by launches on the serialised "
     "scatter (K above the radix bound, or the CPU backend)",
+    "groupby.operands.loop": "group-by launches whose filter mask, key "
+    "and weight columns are built inside the group-by's row loop "
+    "(engine/kernel.py groupby_operands; the launch's ``operands=`` tag)",
     # compile timeline (engine/dispatch.py lane registry): first-call
     # launch of a device-plan digest pays trace + XLA compile
     "compile.cold": "device-plan digests launched for the first time "

@@ -198,6 +198,71 @@ def test_direct_call_keeps_one_root_and_a_disabled_sampler_allocates_nothing(htt
     assert broker.metrics.timer("phase.bookkeeping").count >= 3
 
 
+# -- where a group-by's operands are built ------------------------------------
+
+OPERANDS_SHAPES = {
+    # shape: (PQL, the launch's groupby= tag, its operands= tag)
+    "k6": (K6, "onehot", "loop"),
+    "min_beside_a_sum": ("SELECT min(l_extendedprice), sum(l_quantity) FROM lineitem GROUP BY l_returnflag TOP 10",
+                         "onehot", "staged"),
+    "by_date": ("SELECT sum(l_extendedprice) FROM lineitem GROUP BY l_shipdate TOP 10", "radix", "staged"),
+    "no_group_by": ("SELECT sum(l_extendedprice), sum(l_discount) FROM lineitem", None, None),
+}
+
+
+@pytest.fixture
+def contractions_forced(monkeypatch):
+    """The chip's group-by lowerings on the CPU: the programs the module's
+    cluster compiled without the switch are forgotten, and those of this
+    test after it."""
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    def forget_programs():
+        kernel_mod.make_table_kernel.cache_clear()
+        kernel_mod.make_packed_table_kernel.cache_clear()
+
+    monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")
+    forget_programs()
+    yield kernel_mod
+    forget_programs()
+
+
+def _launch_tags(cluster, pql):
+    reply = _post(cluster, pql, trace=True)
+    assert not reply["exceptions"]
+    (launch,) = [s for s in reply["traceInfo"]["scopes"][cluster.servers[0].name] if s["span"] == "laneDispatch"]
+    return launch["tags"]
+
+
+@pytest.mark.parametrize("shape", sorted(OPERANDS_SHAPES))
+def test_launch_says_where_a_groupbys_operands_are_built(http_cluster, contractions_forced, shape):
+    """``operands=loop|staged`` beside ``groupby=`` on ``laneDispatch``,
+    and one ``groupby.operands.loop`` mark a launch that builds them in
+    the row loop: the answer of ``kernel.groupby_operands``."""
+    pql, groupby, operands = OPERANDS_SHAPES[shape]
+    meter = http_cluster.servers[0].metrics.meter("groupby.operands.loop")
+    before = meter.count
+    tags = _launch_tags(http_cluster, pql)
+    assert (tags.get("groupby"), tags.get("operands")) == (groupby, operands)
+    assert meter.count - before == (operands == "loop")
+
+
+def test_operands_tag_and_kernel_builder_ask_one_function(http_cluster, contractions_forced, monkeypatch):
+    kernel_mod = contractions_forced
+    built = []
+    loop_kernel = kernel_mod._make_loop_groupby_kernel
+    monkeypatch.setattr(kernel_mod, "_make_loop_groupby_kernel", lambda plan: built.append(plan) or loop_kernel(plan))
+    assert _launch_tags(http_cluster, K6)["operands"] == "loop" and len(built) == 1
+    # the function answers otherwise: the tag and the program follow it together
+    monkeypatch.setattr(kernel_mod, "groupby_operands", lambda plan: "staged")
+    kernel_mod.make_table_kernel.cache_clear()
+    kernel_mod.make_packed_table_kernel.cache_clear()
+    meter = http_cluster.servers[0].metrics.meter("groupby.operands.loop")
+    before = meter.count
+    assert _launch_tags(http_cluster, K6)["operands"] == "staged"
+    assert len(built) == 1 and meter.count == before
+
+
 # -- kernel names -----------------------------------------------------------
 
 _NAME_SNIPPET = """

@@ -384,24 +384,18 @@ RADIX_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(RADIX_CASES))
-def test_radix_groupby_forced(monkeypatch, case):
-    """Dense group-bys above the one-level gate ride the two-level
-    (radix-128) contraction on the chip; forced on here so that CPU CI
-    holds it to the oracle: the lowering the gate names, occupancy
-    equal to the scatter's, counts exact, and float32 sums within 2e-6
-    of a float64 sum over prices that bfloat16 cannot hold."""
+def _forced_and_scattered(monkeypatch, name, rows, pql, split=None):
+    """One group-by over two segments of ``rows``, with the contractions
+    forced on (``PINOT_TPU_GROUPBY_MATMUL=1``) and on the scatter: what
+    each launch was (plan, tier, lowering, where the operands are
+    built, the kernel's states) and each reply, beside the scan oracle's."""
     from pinot_tpu.engine import kernel as kernel_mod
 
-    K, n, order, gen, pql, lowering, tier = RADIX_CASES[case]
-    pql += " TOP 100000"
-    monkeypatch.setenv("PINOT_TPU_ZONE_BLOCK", "256")
     monkeypatch.setenv("PINOT_TPU_INVINDEX", "0")  # the host's postings tier would answer the selective shapes
-    rows = _radix_rows(K, n, order, **gen)
-    half = n // 2
-    segs = [build_segment(RADIX_SCHEMA, rows[:half], "rx", f"{case}0"),
-            build_segment(RADIX_SCHEMA, rows[half:], "rx", f"{case}1")]
-    want = _group_table(ScanQueryProcessor(RADIX_SCHEMA, rows).execute(optimize_request(parse_pql(pql))))
+    split = len(rows) // 2 if split is None else split
+    segs = [build_segment(RADIX_SCHEMA, rows[:split], "rx", f"{name}0"),
+            build_segment(RADIX_SCHEMA, rows[split:], "rx", f"{name}1")]
+    oracle = ScanQueryProcessor(RADIX_SCHEMA, rows).execute(optimize_request(parse_pql(pql)))
 
     seen = {}
     run_kernel = QueryExecutor._run_kernel
@@ -409,7 +403,7 @@ def test_radix_groupby_forced(monkeypatch, case):
     def spy(self, kernel, args, plan, staged, digest, block_ids, *rest, **kw):
         outs = run_kernel(self, kernel, args, plan, staged, digest, block_ids, *rest, **kw)
         seen.update(plan=plan, outs=outs, tier="scan" if block_ids is None else "zone",
-                    lowering=kernel_mod.groupby_lowering(plan))
+                    lowering=kernel_mod.groupby_lowering(plan), operands=kernel_mod.groupby_operands(plan))
         return outs
 
     monkeypatch.setattr(QueryExecutor, "_run_kernel", spy)
@@ -424,39 +418,166 @@ def test_radix_groupby_forced(monkeypatch, case):
         forget_programs()  # the caches key on the plan, which does not state the switch
         req = optimize_request(parse_pql(pql))
         resp = reduce_to_response(req, [QueryExecutor().execute(segs, req)])
-        return _group_table(resp), dict(seen)
+        return dict(seen, reply=resp)
 
     try:
-        got, forced = run("1")
-        _, scattered = run("0")
+        return run("1"), run("0"), oracle
     finally:
         forget_programs()
+
+
+def _assert_states_and_reply(forced, scattered, oracle):
+    """Occupancy and every count state are the scatter's, exactly; the
+    reply is the float64 oracle's: groups, counts and ``numDocsScanned``
+    exact, sums within 2e-6."""
+    a, b = forced["outs"], scattered["outs"]
+    assert (a["gb_presence"] == b["gb_presence"]).all()
+    assert int(a["num_docs"]) == int(b["num_docs"])
+    for i, agg in enumerate(forced["plan"].aggs):
+        if agg.base == "count":
+            assert a[f"gb_{i}"].dtype == b[f"gb_{i}"].dtype and (a[f"gb_{i}"] == b[f"gb_{i}"]).all()
+        elif agg.base == "avg":
+            assert (a[f"gb_{i}"][1] == b[f"gb_{i}"][1]).all()
+    assert forced["reply"].to_json()["numDocsScanned"] == oracle.to_json()["numDocsScanned"]
+    got, want = _group_table(forced["reply"]), _group_table(oracle)
+    assert set(got) == set(want)
+    for fn in want:
+        assert set(got[fn]) == set(want[fn]), fn
+        for group, value in want[fn].items():
+            if fn.startswith(("count", "distinctcount")):
+                assert got[fn][group] == value, (fn, group)
+            else:
+                g, w = float(got[fn][group]), float(value)
+                assert abs(g - w) <= 2e-6 * max(abs(w), 1.0), (fn, group, g, w)
+
+
+@pytest.mark.parametrize("case", sorted(RADIX_CASES))
+def test_radix_groupby_forced(monkeypatch, case):
+    """Dense group-bys above the one-level gate ride the two-level
+    (radix-128) contraction on the chip; forced on here so that CPU CI
+    holds it to the oracle: the lowering the gate names, occupancy
+    equal to the scatter's, counts exact, and float32 sums within 2e-6
+    of a float64 sum over prices that bfloat16 cannot hold."""
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    K, n, order, gen, pql, lowering, tier = RADIX_CASES[case]
+    monkeypatch.setenv("PINOT_TPU_ZONE_BLOCK", "256")
+    forced, scattered, oracle = _forced_and_scattered(
+        monkeypatch, case, _radix_rows(K, n, order, **gen), pql + " TOP 100000")
     assert forced["lowering"] == lowering and scattered["lowering"] == "scatter"
     assert forced["tier"] == scattered["tier"] == tier
     cap = forced["plan"].group_by.capacity
     assert (cap > kernel_mod.RADIX_GROUP_CAP) == (lowering == "scatter")
     if case.startswith("bound"):
         assert cap == kernel_mod.RADIX_GROUP_CAP
-    # occupancy and every count state: the scatter's, exactly
-    a, b = forced["outs"], scattered["outs"]
-    assert (a["gb_presence"] == b["gb_presence"]).all()
-    for i, agg in enumerate(forced["plan"].aggs):
-        if agg.base == "count":
-            assert a[f"gb_{i}"].dtype == b[f"gb_{i}"].dtype and (a[f"gb_{i}"] == b[f"gb_{i}"]).all()
-        elif agg.base == "avg":
-            assert (a[f"gb_{i}"][1] == b[f"gb_{i}"][1]).all()
-    # the reply against the float64 oracle
-    assert set(got) == set(want)
-    for fn in want:
-        assert set(got[fn]) == set(want[fn]), fn
-        for group, value in want[fn].items():
-            if fn.startswith("count"):
-                assert got[fn][group] == value, (fn, group)
-            else:
-                g, w = float(got[fn][group]), float(value)
-                assert abs(g - w) <= 2e-6 * max(abs(w), 1.0), (fn, group, g, w)
+    _assert_states_and_reply(forced, scattered, oracle)
     if case == "k2000_empty_match":
-        assert not any(want.values()) and not a["gb_presence"].any()
+        assert not any(_group_table(oracle).values()) and not forced["outs"]["gb_presence"].any()
+
+
+# id: (rows of _radix_rows, PQL, tier, what the case sets: the zone tier's
+# block, the contraction's block, where the two segments split)
+LOOP_CASES = {
+    # the open cell's K=6 shape: a docrange leaf on the sorted column, two key columns, three sums and count(*)
+    "k6_docrange_two_keys": (dict(K=400, n=3000, order="sorted", a_card=3),
+                             "SELECT sum(price), sum(qty), sum(b), count(*) FROM rx WHERE day <= 300 "
+                             "GROUP BY a, flag TOP 100", "scan", {"chunk": 512}),
+    # upstream Q6's: IN + between, K = 7, TOP 10
+    "q6_in_between_top10": (dict(K=400, n=3000, order="shuffled", a_card=7),
+                            "SELECT sum(price) FROM rx WHERE a IN (1, 2) AND day BETWEEN 100 AND 250 "
+                            "GROUP BY a TOP 10", "scan", {"chunk": 512}),
+    "avg_pair_state": (dict(K=400, n=3000, order="shuffled", a_card=5),
+                       "SELECT avg(price), avg(qty), count(*) FROM rx GROUP BY a TOP 100", "scan", {}),
+    "k16_two_columns": (dict(K=400, n=3000, order="shuffled", a_card=16),
+                        "SELECT sum(price), count(*) FROM rx WHERE day < 350 GROUP BY a TOP 1000", "scan", {"chunk": 512}),
+    "cells_at_the_gate": (dict(K=400, n=3000, order="shuffled", a_card=32),
+                          "SELECT sum(qty), count(*) FROM rx GROUP BY a TOP 1000", "scan", {}),
+    "rows_no_multiple_of_the_block": (dict(K=400, n=3000, order="sorted", a_card=3),
+                                      "SELECT sum(price), count(*) FROM rx WHERE day <= 300 GROUP BY a, flag TOP 100",
+                                      "scan", {"chunk": 96}),
+    "zone_tier_gathered_view": (dict(K=2000, n=6000, order="sorted", a_card=6),
+                                "SELECT sum(price), count(*) FROM rx WHERE day BETWEEN 300 AND 420 GROUP BY a TOP 100",
+                                "zone", {"zone_block": "256", "chunk": 640}),
+    "two_segments_of_unequal_length": (dict(K=400, n=3000, order="sorted", a_card=3),
+                                       "SELECT sum(price), count(*) FROM rx WHERE day <= 300 GROUP BY a, flag TOP 100",
+                                       "scan", {"split": 700, "chunk": 256}),
+    "empty_match": (dict(K=400, n=3000, order="shuffled", a_card=6),
+                    "SELECT sum(price), count(*) FROM rx WHERE flag = 1 AND day = 3 GROUP BY a TOP 100", "scan", {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LOOP_CASES))
+def test_onehot_groupby_operands_built_in_the_loop(monkeypatch, case):
+    """Group-bys of up to _LOOP_CELLS (group, column) cells whose every
+    output adds over blocks of rows build mask, key and weight columns
+    inside the row loop (``groupby_operands`` 'loop'): forced on here and
+    held to the scatter's states and the oracle's reply."""
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    gen, pql, tier, sets = LOOP_CASES[case]
+    if "zone_block" in sets:
+        monkeypatch.setenv("PINOT_TPU_ZONE_BLOCK", sets["zone_block"])
+    if "chunk" in sets:
+        monkeypatch.setattr(kernel_mod, "_MATMUL_CHUNK", sets["chunk"])
+    rows = _radix_rows(**gen)
+    forced, scattered, oracle = _forced_and_scattered(monkeypatch, case, rows, pql, split=sets.get("split"))
+    assert (forced["lowering"], forced["operands"]) == ("onehot", "loop")
+    assert (scattered["lowering"], scattered["operands"]) == ("scatter", "staged")
+    assert forced["tier"] == scattered["tier"] == tier
+    plan = forced["plan"]
+    cells = plan.group_by.capacity * kernel_mod._contraction_slots(plan)[1]
+    assert cells <= kernel_mod._LOOP_CELLS
+    if case in ("k6_docrange_two_keys", "rows_no_multiple_of_the_block", "two_segments_of_unequal_length"):
+        assert [leaf.eval_kind for leaf in plan.leaves] == ["docrange"]
+        assert plan.group_by.capacity == 6
+    if case == "cells_at_the_gate":
+        assert cells == kernel_mod._LOOP_CELLS
+    _assert_states_and_reply(forced, scattered, oracle)
+    if case == "empty_match":
+        assert not any(_group_table(oracle).values()) and not forced["outs"]["gb_presence"].any()
+    else:
+        assert any(_group_table(oracle).values())
+
+
+# group-bys at or under the one-level gate with an output that does not add over
+# blocks of rows, or with more cells than the row loop takes
+STAGED_CASES = {
+    "k64_two_columns": (dict(a_card=64), "SELECT sum(price), count(*) FROM rx GROUP BY a TOP 1000"),
+    "k512_the_one_level_gate": (dict(a_card=512), "SELECT sum(price), count(*) FROM rx GROUP BY a TOP 1000"),
+    "min_max_beside_a_sum": (dict(a_card=6), "SELECT sum(price), min(price), max(qty) FROM rx GROUP BY a TOP 100"),
+    "grouped_distinctcount": (dict(a_card=6), "SELECT distinctcount(b), sum(price) FROM rx GROUP BY a TOP 100"),
+    "multi_value_key": (dict(tag_card=8), "SELECT sum(price), count(*) FROM rx GROUP BY tags TOP 100"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STAGED_CASES) + ["selection_part"])
+def test_onehot_groupby_keeps_staged_operands(monkeypatch, case):
+    """min, max, a grouped distinctcount, a multi-value key and a
+    selection part do not add over blocks of rows, and 128 cells are more
+    than the row loop takes: the predicate says 'staged', the one-level
+    contraction is fed whole-segment operands as before, and the
+    answers hold."""
+    import dataclasses
+
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    rows_of = lambda **gen: _radix_rows(400, 4000, "shuffled", **gen)
+    if case == "selection_part":
+        # no PQL states both parts: hand a loop-built plan the selection of a selection query
+        forced, _, _ = _forced_and_scattered(monkeypatch, case, rows_of(a_card=6),
+                                             "SELECT sum(price) FROM rx GROUP BY a TOP 100")
+        sel, _, _ = _forced_and_scattered(monkeypatch, case, rows_of(a_card=6),
+                                          "SELECT a, price FROM rx ORDER BY price LIMIT 5")
+        assert sel["plan"].selection is not None and sel["operands"] is None
+        monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")  # the predicate reads the switch
+        assert kernel_mod.groupby_operands(forced["plan"]) == "loop"
+        both = dataclasses.replace(forced["plan"], selection=sel["plan"].selection)
+        assert kernel_mod.groupby_lowering(both) == "onehot" and kernel_mod.groupby_operands(both) == "staged"
+        return
+    gen, pql = STAGED_CASES[case]
+    forced, scattered, oracle = _forced_and_scattered(monkeypatch, case, rows_of(**gen), pql)
+    assert (forced["lowering"], forced["operands"]) == ("onehot", "staged")
+    _assert_states_and_reply(forced, scattered, oracle)
 
 
 def test_grouped_hll_mxu_contraction(monkeypatch):

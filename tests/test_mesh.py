@@ -329,6 +329,37 @@ def test_radix_groupby_sharded_equals_single_device(lineitem_segments, monkeypat
                 assert (a == b).all(), key
 
 
+def test_loop_groupby_on_the_mesh_marks_operands_loop(lineitem_segments, mesh_broker, monkeypatch):
+    """The open cell's K=6 shape through the sharded kernel with the
+    contractions forced: the launch is tagged ``operands=loop`` and
+    marks ``groupby.operands.loop`` once, and the reply is the one the
+    scatter gives on the module's mesh server."""
+    from pinot_tpu.tools.cluster_harness import single_server_broker
+
+    pql = ("SELECT sum(l_quantity), sum(l_extendedprice), sum(l_discount), count(*) FROM lineitem "
+           "WHERE l_shipdate <= '1998-09-02' GROUP BY l_returnflag, l_linestatus TOP 10")
+    scattered = mesh_broker.handle_pql(pql)
+    monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")
+    broker = single_server_broker("lineitem", lineitem_segments, topology=build_topology(jax.devices(), 2, 4))
+    server = broker.local_servers[0]
+    try:
+        resp = broker.handle_pql(pql, trace=True)
+        assert not resp.exceptions and not scattered.exceptions
+        (launch,) = [s for s in resp.trace_info["scopes"][server.name] if s["span"] == "laneDispatch"]
+        assert launch["tags"]["program"].startswith("pinot_mesh_gb6_")
+        assert (launch["tags"]["groupby"], launch["tags"]["operands"]) == ("onehot", "loop")
+        assert server.metrics.meter("groupby.operands.loop").count == 1
+        assert server.executor.healing_stats()["hostFailovers"] == 0
+        got, want = resp.to_json()["aggregationResults"], scattered.to_json()["aggregationResults"]
+        assert [a["function"] for a in got] == [a["function"] for a in want]
+        for a, b in zip(got, want):
+            assert [e["group"] for e in a["groupByResult"]] == [e["group"] for e in b["groupByResult"]]
+            for x, y in zip(a["groupByResult"], b["groupByResult"]):
+                assert float(x["value"]) == pytest.approx(float(y["value"]), rel=1e-9)
+    finally:
+        server.shutdown()
+
+
 def test_mesh_status_reports_topology_and_lanes(mesh_broker):
     server = mesh_broker.local_servers[0]
     status = server.status()

@@ -1,9 +1,10 @@
-"""Compile the group-by contraction kernel for a v5e that is described,
+"""Compile the group-by contraction kernels for a v5e that is described,
 not attached: the TPU's compiler is installed here, and what it refuses
 (a slice off the tiling, more VMEM than a kernel may use) it would
-refuse on the chip.  Nothing runs, so nothing here is a time or a
-result; ``tests/test_engine.py::test_radix_groupby_forced`` holds the
-answers.  One file, one worker: the topology is described inside a
+refuse on the chip, and what its program keeps in HBM beside its
+arguments it would keep there.  Nothing runs, so nothing here is a time
+or a result; ``tests/test_engine.py::test_radix_groupby_forced`` and
+``test_onehot_groupby_operands_built_in_the_loop`` hold the answers.  One file, one worker: the topology is described inside a
 fixture, never at import."""
 import jax
 import jax.numpy as jnp
@@ -56,3 +57,70 @@ def test_radix_contraction_compiles_for_v5e(one_chip, monkeypatch, shape):
     # the one-hots stay in VMEM: what the program keeps in HBM beside its
     # arguments is the masked weight columns and no [rows, K1 + 128] operand
     assert compiled.memory_analysis().temp_size_in_bytes <= (m + 1) * S * n * 4 + (64 << 20)
+
+
+# the open cell's two group-bys (benchmark/traffic/suite_open.json: k6, q6)
+SUITE_GROUPBYS = {
+    "k6": "SELECT sum(l_quantity), sum(l_extendedprice), sum(l_discount), count(*) FROM lineitem "
+          "WHERE l_shipdate <= '1998-09-02' GROUP BY l_returnflag, l_linestatus TOP 10",
+    "q6": "SELECT sum(l_extendedprice) FROM lineitem WHERE l_shipmode IN ('RAIL','FOB') AND "
+          "l_receiptdate BETWEEN '1997-01-01' AND '1997-12-31' GROUP BY l_shipmode TOP 10",
+}
+
+
+@pytest.fixture(scope="module")
+def suite_launches():
+    """(plan, segment arrays, query inputs) of each launch of
+    SUITE_GROUPBYS as the executor makes it on the chip: float32, value
+    columns staged raw, the contractions on; over a tiny lineitem table."""
+    from pinot_tpu.engine import kernel as kernel_mod
+    from pinot_tpu.engine.executor import QueryExecutor
+    from pinot_tpu.pql import optimize_request, parse_pql
+    from pinot_tpu.tools.datagen import synthetic_lineitem_segment
+
+    launches = {}
+    with pytest.MonkeyPatch.context() as mp, jax.enable_x64(False):
+        mp.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")
+        mp.setenv("PINOT_TPU_RAW_CARD_MIN", "0")
+        run_kernel = QueryExecutor._run_kernel
+
+        def spy(self, kernel, args, plan, *rest, **kw):
+            launches[name] = (plan, args[0], args[1])
+            return run_kernel(self, kernel, args, plan, *rest, **kw)
+
+        mp.setattr(QueryExecutor, "_run_kernel", spy)
+        segs = [synthetic_lineitem_segment(4096, seed=70 + i, name=f"compile{i}") for i in range(2)]
+        try:
+            for name, pql in SUITE_GROUPBYS.items():
+                QueryExecutor().execute(segs, optimize_request(parse_pql(pql)))
+        finally:
+            kernel_mod.make_table_kernel.cache_clear()
+            kernel_mod.make_packed_table_kernel.cache_clear()
+    return launches
+
+
+@pytest.mark.parametrize("shape", sorted(SUITE_GROUPBYS))
+def test_loop_groupby_keeps_its_operands_out_of_hbm_on_v5e(one_chip, suite_launches, monkeypatch, shape):
+    """The single-segment kernel of the open cell's group-bys, vmapped
+    over 16 segments of 8,388,608 rows: with the operands built in the
+    row loop the program keeps next to nothing in HBM beside its
+    arguments.  The staged form kept the [4, S, n] stack and the index
+    (2.0 GiB for k6, 1.1 for q6)."""
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")
+    plan, segs, q = suite_launches[shape]
+    assert kernel_mod.groupby_operands(plan) == "loop"
+    S, n = 16, 1 << 23
+
+    def at_scale(key, v):
+        rows = (n,) + v.shape[2:] if kernel_mod._row_key(key) else v.shape[1:]
+        return jax.ShapeDtypeStruct((S,) + rows, v.dtype, sharding=one_chip)
+
+    segs = {key: at_scale(key, v) for key, v in segs.items()}
+    q = jax.tree_util.tree_map(lambda v: at_scale("", v), q)
+    assert sum(v.shape == (S, n) for v in segs.values()) >= 3
+    table = jax.jit(jax.vmap(kernel_mod.make_single_segment_kernel(plan)))
+    with jax.enable_x64(False):
+        compiled = table.lower(segs, q).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
