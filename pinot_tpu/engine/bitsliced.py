@@ -19,8 +19,8 @@ The decision mirrors ``index_path_decision``'s contract: a JSON-safe
 verdict EXPLAIN can report without serving the query, plus an opaque
 execution state when taken.  Crossover constants live in
 engine/tiercost.py (``PINOT_TPU_TIER_COST_*``); ``PINOT_TPU_BITSLICED``
-is the tier switch: "0" disables, "force" skips the cost model (the
-filter-matrix bench pins tiers this way), unset/auto applies it.
+is the tier switch: "0" disables, "force" skips the cost model (tests
+pin the tier this way), unset/auto applies it.
 
 Fused SUM is offered only where it is bit-exact against the scan
 tier: exactly-integral dictionaries (packing.integral_dictionary_values)

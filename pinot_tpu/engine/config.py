@@ -103,8 +103,8 @@ def pad_value_card(c: int) -> int:
     """Value-state holder padding: QUARTER-pow2 buckets (2048, 2560,
     3072, 3584, 4096, 5120, ...).  The dense presence/hist/HLL
     contraction cost is LINEAR in the padded cardinality, so pow2's
-    up-to-2x overshoot is real MXU work (the r4 bench shape padded
-    2526 -> 4096, a 1.6x tax on the hot HLL group-by); quarter steps
+    up-to-2x overshoot is real MXU work (l_shipdate's 2526 values
+    padded to 4096, a 1.6x tax on the HLL group-by); quarter steps
     cap the overshoot at 25% while keeping the jit cache bucketed."""
     base = MIN_CARD_PAD
     while base * 2 <= c:
@@ -127,11 +127,9 @@ def pad_value_card(c: int) -> int:
 # stage a dictionary-decoded float raw array for aggregation reads; at
 # or below it, the kernel gathers dict_vals[fwd].
 #
-# Measured on a v5e chip (2026-07-30, tools/microbench.py
-# `gather_vs_raw`; the record is gone, ROADMAP S5 re-measures): XLA lowers the per-row dict gather to a serialized
-# loop — ~12.5 ns/element, 159x slower than streaming a raw float32
-# array (1257 ms vs 7.9 ms for TPC-H Q1 over 33.5M rows; raw-feed hits
-# 4.25 B rows/s vs the 295 GB/s stream roofline).  So on accelerators
+# XLA lowers the per-row dict gather to a serialized loop on the TPU
+# (from a measurement before the chip round, record gone; what the raw
+# feeds cost in staged bytes is ROADMAP S9).  So on accelerators
 # the threshold defaults to 0: ALWAYS stage raw feeds — the 4x HBM
 # bytes/row are far cheaper than any gather.  On CPU (tests) vector
 # gathers are cheap and narrow staging halves memory, so the old

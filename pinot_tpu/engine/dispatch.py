@@ -82,7 +82,6 @@ from collections import deque
 from typing import Any, Callable, Deque, Dict, Hashable, List, Optional
 
 from pinot_tpu.engine import compilecache
-from pinot_tpu.server.scheduler import QueryAbandonedError
 from pinot_tpu.utils.trace import boundary, measured
 
 # completed dispatches kept open (still coalescible) at once; beyond
@@ -267,6 +266,12 @@ def outputs_pending(value: Any) -> bool:
 
 class LaneClosedError(RuntimeError):
     """Submit after close(), or queued work drained by close()."""
+
+
+class QueryAbandonedError(RuntimeError):
+    """A query's deadline expired before its work started (in the
+    scheduler's queue, or waiting for the lane): the broker already gave
+    up on this reply.  ``server.scheduler`` re-exports it."""
 
 
 class LaneTicket:
@@ -1464,7 +1469,7 @@ class LaneGroup:
     def lane_index(self, shape_key) -> int:
         """Stable shape -> lane hash (blake2b, not the per-process-
         randomized builtin hash: the routing must be reproducible
-        across runs for committed bench artifacts to be comparable)."""
+        across runs for two runs of a cell to be comparable)."""
         if len(self.lanes) == 1:
             return 0
         import hashlib

@@ -584,8 +584,10 @@ class QueryExecutor:
                     lane_index=sel.index if sel is not None else 0,
                 )
             except Exception as e:
-                from pinot_tpu.engine.dispatch import LaneClosedError
-                from pinot_tpu.server.scheduler import QueryAbandonedError
+                from pinot_tpu.engine.dispatch import (
+                    LaneClosedError,
+                    QueryAbandonedError,
+                )
 
                 if isinstance(
                     e, (QueryAbandonedError, LaneClosedError, TimeoutError)
@@ -624,9 +626,9 @@ class QueryExecutor:
         from pinot_tpu.engine.dispatch import (
             DeviceExecutionError,
             LaneClosedError,
+            QueryAbandonedError,
             classify_device_error,
         )
-        from pinot_tpu.server.scheduler import QueryAbandonedError
 
         if self._audit_blocked(audit_digest, "device"):
             # wrong-answer quarantine: unlike a device FAILURE (which
@@ -1801,9 +1803,9 @@ class QueryExecutor:
         from pinot_tpu.engine.dispatch import (
             DeviceExecutionError,
             LaneClosedError,
+            QueryAbandonedError,
             classify_device_error,
         )
-        from pinot_tpu.server.scheduler import QueryAbandonedError
 
         poison_key = (jdigest, "join")
         sel = self.lane_selection(request)

@@ -598,7 +598,7 @@ def build_join_plan(
     per-dispatch row budget."""
     spec = request.join
     if os.environ.get("PINOT_TPU_JOIN_DEVICE", "1") in ("0", "false"):
-        return None  # host-reference mode (bench differential / tests)
+        return None  # host-reference mode (tests' differential)
     if request.selection is not None or not request.aggregations:
         return None
     if build.n == 0 or probe.n == 0:

@@ -42,23 +42,22 @@ BIG = jnp.inf
 import os as _os
 
 MATMUL_GROUP_CAP = int(_os.environ.get("PINOT_TPU_MATMUL_GROUP_CAP", str(512)))
-# 2^18-row chunks: the on-chip sweep (r4_chunk_sweep) measured 14% off
-# the Q1 kernel vs 2^15 (fewer, fatter scan steps); flat beyond 2^18
+# 2^18-row chunks: PR 30's sweep of the row loop on a v5e (k6, ms a
+# call): 2^15 7.07, 2^16 6.25, 2^17 5.73, 2^18 5.47, 2^19 5.34 (PERF.md)
 _MATMUL_CHUNK = int(_os.environ.get("PINOT_TPU_MATMUL_CHUNK", str(1 << 18)))
 # dense presence/hist holders ride the FACTORED contraction
 # (_value_state_counts) with a combined (group, valueId) key while
-# capacity * gcard_pad stays under this; the r5 on-chip sweep
-# (tools/probe_hll_sweep.py) measured 0.8ns/row at K=2^14 and
-# 3.4ns/row at K=2^18 — still 3.6x ahead of the serialized scatter —
-# so the r4 cap of 2^16 lifts to 2^18
+# capacity * gcard_pad stays under this.  From a sweep before the chip
+# round, record gone; not judged on the chip: ROADMAP D4
 _MATMUL_VALUE_CAP = int(_os.environ.get("PINOT_TPU_MATMUL_VALUE_CAP", str(1 << 18)))
-# grouped HLL: contraction FLOPs grow with capacity*16384, crossing the
-# sort-lowering cost (~4.2ns/row) near capacity ~16 on v5e
+# grouped HLL: contraction FLOPs grow with capacity*16384 and cross the
+# sort lowering's cost near capacity 16 (a sweep before the chip round,
+# record gone; not judged on the chip: ROADMAP D4)
 _MATMUL_HLL_CAP = int(_os.environ.get("PINOT_TPU_MATMUL_HLL_CAP", str(1 << 18)))
 # grouped HLL beyond the matmul gate lowers to ONE packed int32 sort +
-# searchsorted run-max extraction (bit-identical to scatter-max,
-# tools/probe_hll_e2e.py: 565ms vs 1665ms at 134M rows, cap 1024) while
-# (capacity * HLL_M * 64) fits int32; beyond that the flat scatter runs
+# searchsorted run-max extraction (bit-identical to scatter-max) while
+# (capacity * HLL_M * 64) fits int32; beyond that the flat scatter runs.
+# Not judged on the chip: ROADMAP D4
 _HLL_SORT_CAP = int(_os.environ.get("PINOT_TPU_HLL_SORT_CAP", str(1 << 16)))
 
 
@@ -324,9 +323,9 @@ def _segment_add_matmul_multi(flat_idx, W, capacity: int):
     return acc
 
 
-# block size for the factored contraction: the r5 on-chip sweep found
-# batched-dot cost flat from 2^15 to 2^18 blocks; smaller blocks keep
-# the per-block [K1, 128] partials cheap to tree-sum
+# block size for the factored contraction: smaller blocks keep the
+# per-block [K1, 128] partials cheap to tree-sum (from a sweep before
+# the chip round, record gone; not judged on the chip: ROADMAP D4)
 _FACTORED_CHUNK = int(_os.environ.get("PINOT_TPU_FACTORED_CHUNK", str(1 << 15)))
 
 
@@ -339,7 +338,7 @@ def _value_state_counts_pallas(flat_idx, K: int, interpret: bool = False):
     VMEM-resident [K1, 128] accumulator, so HBM traffic is the index
     stream alone (the XLA form streams both generated one-hots through
     HBM, ~512 B/row at K=2^14).  Gated by PINOT_TPU_VALUE_STATE_PALLAS
-    pending the on-chip A/B (microbench hll_lowerings); semantics are
+    and not judged on the chip (ROADMAP D4); semantics are
     identical to _value_state_counts.  Compiled for the TPU unless a
     test passes ``interpret=True``."""
     from jax.experimental import pallas as pl
@@ -408,9 +407,9 @@ def _value_state_counts_xla(flat_idx, K: int):
     with a FACTORED one-hot contraction: split the key into (hi, lo)
     radix-128 digits and contract two THIN one-hots as a real
     [K1, block] @ [block, 128] matmul per block — full MXU tiles instead
-    of the M=1 degenerate matmul of the scan contraction (the r4 shape
-    that measured 31.5ns/row; this form measures 0.8ns/row at K=2^14,
-    tools/probe_hll_sweep.py).
+    of the M=1 degenerate matmul of the scan contraction (measured in a
+    sweep before the chip round, record gone; not judged on the chip:
+    ROADMAP D4).
 
     Weights must be binary and FOLDED into the index: invalid entries
     carry ``flat_idx == K`` and one-hot to a dropped row.  bf16 one-hots
@@ -858,7 +857,7 @@ def _group_state(agg: StaticAgg, i: int, seg, q, mask, keys, kvalid, capacity) -
             # int32 per entry (4 B/row — the leanest HBM footprint of
             # the three paths) and let the cross-segment reduce sort the
             # packed keys and run-max-extract registers (bit-identical
-            # to scatter-max; 3x faster on v5e, tools/probe_hll_e2e.py)
+            # to scatter-max; not judged on the chip: ROADMAP D4)
             packed = jnp.where(
                 pair_v,
                 ((pair_k * config.HLL_M + pair_b.astype(jnp.int32)) << 6)
@@ -1265,8 +1264,8 @@ def _reduce_hll_sort(value, capacity: int):
     plus a searchsorted run-max extraction (bit-identical to the
     scatter-max lowering: rho rides the low 6 bits, so the largest
     packed key within a (group, bucket) cell prefix carries the cell's
-    max rho).  Replaces the serialized scatter for the north-star
-    high-cardinality HLL group-by (3x on v5e, tools/probe_hll_e2e.py).
+    max rho).  Replaces the serialized scatter for the
+    high-cardinality HLL group-by (not judged on the chip: ROADMAP D4).
     """
     s = jax.lax.sort(value.reshape(-1))
     ncells = capacity * config.HLL_M
@@ -1568,7 +1567,7 @@ def make_packed_table_kernel(plan: StaticPlan) -> Callable:
     """make_table_kernel + single-transfer output fetch: returns HOST
     numpy outputs via one packed D2H transfer (engine/packing.py) —
     the serving path's kernel (per-leaf fetches pay one transfer
-    each; the bench's async dispatch keeps using the raw kernel)."""
+    each)."""
     from pinot_tpu.engine.packing import make_packed_kernel
 
     return make_packed_kernel(make_table_kernel(plan), kernel_name("scan", plan))

@@ -3,8 +3,9 @@ pick between the four filter/aggregate tiers, in ONE env-tunable place.
 
 The tiers (engine/invindex_path.py, engine/zonemap.py,
 engine/bitsliced.py, engine/kernel.py) each win a region of the
-(selectivity, layout) plane — FILTER_MATRIX_CPU_r17.json is the
-measured map.  The constants below encode the crossovers; every one is
+(selectivity, layout) plane as measured on a CPU before the chip round
+(record gone; not judged on the chip: ROADMAP S6, D3).  The constants
+below encode the crossovers; every one is
 overridable via ``PINOT_TPU_TIER_COST_*`` so the model can be
 recalibrated per host (a TPU host, a fat CPU dev box) without code
 edits.  Defaults reproduce the pre-knob behavior bit-for-bit: the
@@ -15,7 +16,7 @@ from __future__ import annotations
 
 import os
 
-# name -> default; read fresh per call so tests/benches can flip them
+# name -> default; read fresh per call so tests can flip them
 # without cache invalidation ceremony
 _DEFAULTS = {
     # postings/scan crossover: host fancy-index aggregation costs

@@ -905,7 +905,7 @@ class RealtimeSegmentDataManager:
         """One fetch + index against the stream, preferring the
         columnar block path when the provider and partition support it
         (netstream producec topics: np.frombuffer decode + vectorized
-        dictionary encode — the 5x ingest path, INGEST_r5.json).
+        dictionary encode).
         Returns rows consumed and advances the offset."""
         fetch_cols = getattr(self.stream, "fetch_columns", None)
         if self._columnar is not False and fetch_cols is not None:

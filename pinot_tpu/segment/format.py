@@ -58,7 +58,7 @@ def verify_segment_crc(segment: ImmutableSegment, source: str = "") -> None:
 
     Only producers that actually computed a data CRC mark the claim
     verifiable (``custom["dataCrc"]``: segment/builder.py and the
-    realtime commit conversion).  Synthetic bench segments and consuming
+    realtime commit conversion).  Synthetic (datagen) segments and consuming
     snapshots reuse the crc field as a cheap cache-identity token —
     those (and crc == 0) pass trivially: there is no byte-level claim to
     hold them to."""

@@ -75,23 +75,15 @@ class _RooflineWindow:
             )
             self._cached = None
 
-    def snapshot(self, since: Optional[float] = None) -> dict:
-        """``since`` (a ``time.monotonic()`` stamp) narrows the window to
-        records at/after that instant — bench uses it to exclude warmup
-        (cold-compile) queries from the measured-ladder figures.  The
-        0.5s read cache only serves the default full-window view."""
+    def snapshot(self) -> dict:
         now = time.monotonic()
         with self._lock:
-            if since is None and self._cached is not None and now - self._cached[0] < 0.5:
+            if self._cached is not None and now - self._cached[0] < 0.5:
                 return dict(self._cached[1])
             horizon = now - self.window_s
             while self._dq and self._dq[0][0] < horizon:
                 self._dq.popleft()
-            records = (
-                list(self._dq)
-                if since is None
-                else [r for r in self._dq if r[0] >= since]
-            )
+            records = list(self._dq)
             ms = sum(r[1] for r in records)
             nbytes = sum(r[2] for r in records)
             flops = sum(r[3] for r in records)
@@ -114,9 +106,8 @@ class _RooflineWindow:
         out["rooflineFraction"] = roofline_fractions(
             out["achievedBytesPerSec"], out["achievedFlopsPerSec"], peaks=peaks
         )["rooflineFraction"]
-        if since is None:
-            with self._lock:
-                self._cached = (now, dict(out))
+        with self._lock:
+            self._cached = (now, dict(out))
         return out
 
 
@@ -425,7 +416,7 @@ class ServerInstance:
     # silently miss the reconciliation surfaces
     _TIER_KEYS = SEGMENT_TIER_KEYS
 
-    def _roofline_rollup(self, since: Optional[float] = None) -> dict:
+    def _roofline_rollup(self) -> dict:
         """Recent achieved-rate window across every lane.  Single lane:
         the window's snapshot verbatim (pre-mesh shape).  Lane group:
         per-lane snapshots under ``lanes`` plus a rollup computed FROM
@@ -433,8 +424,8 @@ class ServerInstance:
         concurrent lanes, and the fleet roofline fraction divides by
         the per-chip peak times the server's device count."""
         if len(self._roofline_windows) == 1:
-            return self._roofline_windows[0].snapshot(since=since)
-        lanes = [w.snapshot(since=since) for w in self._roofline_windows]
+            return self._roofline_windows[0].snapshot()
+        lanes = [w.snapshot() for w in self._roofline_windows]
         out = {
             "windowS": lanes[0]["windowS"],
             "queries": sum(l["queries"] for l in lanes),
@@ -909,13 +900,12 @@ class ServerInstance:
         actually ends (refcount zero — the on_capture_end hook)."""
         return self.profiler.stop()
 
-    def device_utilization(self, roofline_since: Optional[float] = None) -> dict:
+    def device_utilization(self) -> dict:
         """Device utilization snapshot (the ``status()["device"]``
         section and the controller ``/debug/utilization`` rollup's
         per-server unit): declared platform peaks, windowed lane
         occupancy, cumulative H2D/D2H transfer totals, the recent
-        achieved-rate window (optionally narrowed to records at/after
-        the ``roofline_since`` monotonic stamp), profiler state, and
+        achieved-rate window, profiler state, and
         (when the opt-in sampler is running) its queue-depth-over-time
         ring."""
         from pinot_tpu.engine.device import TRANSFERS
@@ -930,7 +920,7 @@ class ServerInstance:
             "mesh": self.topology.snapshot(),
             "occupancy": occupancy,
             "transfers": TRANSFERS.snapshot(),
-            "recent": self._roofline_rollup(since=roofline_since),
+            "recent": self._roofline_rollup(),
             "profiler": self.profiler.snapshot(),
         }
         if self.occupancy_sampler is not None and (

@@ -39,6 +39,7 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
+from pinot_tpu.engine.dispatch import QueryAbandonedError
 from pinot_tpu.utils.trace import measured
 
 # fair-share default queue for table-less submits (unit tests, internal
@@ -55,11 +56,6 @@ class SchedulerSaturatedError(RuntimeError):
 class SchedulerShutdownError(RuntimeError):
     """Raised on submit after shutdown.  Broker-side this is RETRYABLE:
     the server is draining for restart, its replicas are not."""
-
-
-class QueryAbandonedError(RuntimeError):
-    """Raised when a queued query's deadline expired before a worker
-    picked it up — the broker already gave up on this reply."""
 
 
 class _Entry:

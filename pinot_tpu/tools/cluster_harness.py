@@ -182,12 +182,11 @@ def single_server_broker(
     **server_kwargs,
 ):
     """One in-process server + broker over LocalTransport — the
-    minimal serving topology every bench uses (bench.py,
-    tools/config_bench.py).  The generous default timeout covers the
-    first query's staging + cold compile.  Extra kwargs
+    minimal serving topology of the tests.  The generous default timeout
+    covers the first query's staging + cold compile.  Extra kwargs
     reach the ServerInstance (e.g. ``pipeline=False`` for the serial
     executor path); the instance is reachable as
-    ``broker.local_servers[0]`` so benches can read lane/scheduler
+    ``broker.local_servers[0]`` so callers can read lane/scheduler
     counters."""
     from pinot_tpu.broker.broker import BrokerRequestHandler
     from pinot_tpu.broker.routing import RoutingTableProvider
@@ -1336,8 +1335,6 @@ def run_hbm_pressure_scenario(
         deltas = {
             n: RESIDENCY.counter(n) - counters0[n] for n in counters0
         }
-        import jax
-
         hot_p99 = hot_summary["p99Ms"]
         base_p99 = baseline["p99Ms"]
         failed = (
@@ -1347,17 +1344,13 @@ def run_hbm_pressure_scenario(
         )
         return {
             "scenario": "hbm-pressure",
-            "metric": "tiered_hbm_pressure",
-            "value": round(addressable / cap, 3),
             "addressable_over_cap": round(addressable / cap, 3),
             "num_tables": num_tables,
-            "platform": jax.default_backend(),
             "tableBytes": table_bytes,
             "addressableBytes": addressable,
             "hbmCapBytes": cap,
             "hot_p99_ms": hot_p99,
             "baseline_p99_ms": base_p99,
-            "hot_p99_over_baseline": round(hot_p99 / max(base_p99, 1e-3), 3),
             "demotions": deltas["demotions"],
             "promotions": deltas["promotions"],
             "cold_demotions": deltas["coldDemotions"],
@@ -1518,8 +1511,6 @@ def run_audit_divergence_scenario(
         )
         return {
             "scenario": "audit-divergence",
-            "metric": "audit_detect_s",
-            "value": round(detected_s, 3) if detected_s is not None else None,
             "detected": detected_s is not None,
             "detectWallS": round(detected_s, 3) if detected_s is not None else None,
             "detectMs": detect_ms,
@@ -2117,16 +2108,18 @@ def run_partition_server_scenario(
             and time.monotonic() >= res.instances[victim].lease_until,
             what="lease window elapsing",
         )
-        for _ in range(4):
+        def victim_dropped() -> bool:
+            # make-before-break takes a round to add a replica and a
+            # later one to drop the victim's, once the new copy serves:
+            # how many rounds that is depends on how fast servers load,
+            # so the wait drives the rounds (a fixed four lost the race
+            # on a busy host and then waited 25 s for nobody)
             st.run_once()
-            time.sleep(0.1)
-        cluster.wait(
-            lambda: not any(
-                victim in r
-                for r in res.get_ideal_state(physical).values()
-            ),
-            what="victim replicas dropped after lease expiry",
-        )
+            return not any(
+                victim in r for r in res.get_ideal_state(physical).values()
+            )
+
+        cluster.wait(victim_dropped, what="victim replicas dropped after lease expiry")
         cluster.wait(
             lambda: res.get_external_view(physical)
             == res.get_ideal_state(physical)
@@ -2596,8 +2589,8 @@ def _zombie_stabilizer_write(ctrl_a, physical: str) -> None:
 # from a live replica, then the controller property store DESTROYED
 # mid-load and the cluster restored from archive + deep store alone —
 # byte-identical answers, zero committed-row loss, drain flags and
-# epoch fencing preserved.  Shared by the CLI, DR_r20.json generation,
-# and tests/test_disaster_recovery.py.
+# epoch fencing preserved.  Shared by the CLI and
+# tests/test_disaster_recovery.py.
 # ---------------------------------------------------------------------------
 
 
@@ -2808,11 +2801,6 @@ def run_disaster_recovery_scenario(
         )
         return {
             "scenario": "disaster-recovery",
-            "metric": "dr_restore_first_query_s",
-            "platform": "cpu",
-            "num_segments": num_segments,
-            "clients": clients,
-            "value": round(first_query_s, 4) if first_query_s else None,
             "backup": backup_stats,
             "restore": {
                 "restoreToFirstQuerySeconds": (

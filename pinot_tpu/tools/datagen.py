@@ -342,54 +342,3 @@ def synthetic_adevents_segment(
         adevents_schema(), ADEVENTS_TABLE, dict_values, num_rows, seed, name,
         clustered_column="event_time", time_column="event_time", rng=rng,
     )
-
-
-def tile_segments(distinct_segments, total: int):
-    """Replicate ``distinct_segments`` round-robin up to ``total``
-    segments under fresh names.  The clones SHARE the originals' numpy
-    arrays (host RAM stays O(distinct)), but stage and execute as
-    independent segments — the standard trick for benchmarking at row
-    counts datagen can't build in reasonable time.  Results are those
-    of the tiled data (e.g. distinct counts don't grow past the
-    distinct set); throughput numbers are unaffected, which is what
-    the tiling is for."""
-    from pinot_tpu.segment.immutable import ImmutableSegment, SegmentMetadata
-
-    out = []
-    for i in range(total):
-        base = distinct_segments[i % len(distinct_segments)]
-        if i < len(distinct_segments):
-            out.append(base)
-            continue
-        m = base.metadata
-        smeta = SegmentMetadata(
-            segment_name=f"{m.segment_name}_t{i}",
-            table_name=m.table_name,
-            num_docs=m.num_docs,
-            columns=dict(m.columns),
-            time_column=m.time_column,
-        )
-        smeta.crc = hash((smeta.segment_name, m.num_docs)) & 0xFFFFFFFF
-        out.append(ImmutableSegment(metadata=smeta, columns=base.columns))
-    return out
-
-
-def synthetic_baseball_segment(num_rows: int, seed: int = 7, name: str = "bb0"):
-    """Fast numpy-path baseballStats segment (quickstart config at bench
-    scale): same schema/cardinalities as ``baseball_rows``, built
-    columnar so 10M+ row segments construct in seconds."""
-    import numpy as np
-
-    dict_values = {
-        "playerName": sorted(f"{f} {l}" for f in _FIRST for l in _LAST),
-        "teamID": sorted(_TEAMS),
-        "league": sorted(_LEAGUES),
-        "yearID": np.arange(1980, 2016, dtype=np.int64),
-        "runs": np.arange(0, 141, dtype=np.int64),
-        "hits": np.arange(0, 326, dtype=np.int64),
-        "homeRuns": np.arange(0, 61, dtype=np.int64),
-        "atBats": np.arange(50, 651, dtype=np.int64),
-    }
-    return _synthetic_columnar_segment(
-        baseball_schema(), "baseballStats", dict_values, num_rows, seed, name
-    )

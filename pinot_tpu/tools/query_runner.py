@@ -116,7 +116,6 @@ class QueryRunner:
             concurrent.futures.wait(futures, timeout=60)
         # wall covers the DRAIN too: a backlogged system finishing its
         # queue after the submission window must not report the backlog
-        # as achieved throughput (the r5 curve briefly showed 256 QPS
-        # "achieved" at 470ms p50 on a ~70 QPS system this way)
+        # as achieved throughput
         wall = max(time.perf_counter() - start, 1e-9)
         return RunnerReport("targetQPS", len(lat), wall, len(lat) / wall, lat)

@@ -63,7 +63,7 @@ def run_offline_quickstart(
     rows = baseball_rows(num_rows)
     # each demo query is a fresh plan shape: on a cold accelerator the
     # first compile takes 20-40s, so the serving default (15s) would
-    # time out every sample query (the bench path does the same)
+    # time out every sample query
     cluster = InProcessCluster(num_servers=2, http=http, timeout_ms=_COLD_TIMEOUT_MS)
     physical = cluster.add_offline_table(schema)
 

@@ -16,7 +16,7 @@ flight recorder dumps that state to disk AT the event:
 - rate-limited (``PINOT_TPU_FLIGHTREC_MIN_INTERVAL_S``, default 30s
   between dumps) so a failure storm costs one bundle, not a disk full;
 - **disabled unless ``PINOT_TPU_FLIGHTREC_DIR`` is set** (or a dir is
-  passed explicitly) — tests and benches opt in.
+  passed explicitly) — tests opt in.
 
 Triggers are role-owned hooks on the HistoryRecorder cadence (broker:
 SLO burn crossing / shed burst / failed query; server: heal events;

@@ -110,26 +110,6 @@ def test_unstripped_field_difference_still_fails():
     assert not payloads_equivalent(sa, sb)
 
 
-def test_bench_strip_timing_excludes_freshness_only():
-    """bench.py's byte-identity differential must ignore freshnessMs
-    (wall-clock-relative) while any other field difference still
-    breaks identity."""
-    import bench
-
-    class _Resp:
-        def __init__(self, d):
-            self._d = d
-
-        def to_json(self):
-            return dict(self._d)
-
-    base = {"totalDocs": 10, "aggregationResults": [], "freshnessMs": 5.0}
-    fresher = dict(base, freshnessMs=900.0)
-    wrong = dict(base, totalDocs=11)
-    assert bench._strip_timing(_Resp(base)) == bench._strip_timing(_Resp(fresher))
-    assert bench._strip_timing(_Resp(base)) != bench._strip_timing(_Resp(wrong))
-
-
 # -------------------------------------------- shadow-audit sampling
 class _StubResult:
     def __init__(self, tier="device"):

@@ -84,14 +84,6 @@ def test_chip_smoke_fails_when_a_phase_fails(tmp_path):
     assert '"ok"' not in out.stdout
 
 
-def test_bench_refuses_a_cpu_nobody_asked_for():
-    # JAX_PLATFORMS unset: jax looks for a chip, finds none, lands on the CPU
-    out, _ = _run(["bench.py"], {"JAX_PLATFORMS": None})
-    assert out.returncode == 2
-    assert "came up on the CPU" in out.stderr
-    assert out.stdout == ""
-
-
 def test_controller_broker_and_quickstart_parent_never_initialize_a_backend(tmp_path):
     """One process for each chip: on a machine with a chip only the server
     process may bring a backend up.  The controller, the broker and the

@@ -18,7 +18,6 @@ from pinot_tpu.segment.builder import build_segment
 from pinot_tpu.tools.cluster_harness import InProcessCluster, single_server_broker
 from pinot_tpu.tools.datagen import make_test_schema, random_rows
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ------------------------------------------------------------------ wire
@@ -625,69 +624,3 @@ def test_debug_capacity_rollup_and_dashboard(tmp_path):
         cluster.stop()
 
 
-# --------------------------------------------------------- perf gate
-def _bench_doc():
-    from pinot_tpu.tools.perf_gate import load_bench
-
-    # a synthetic document: these tests hold the gate's logic, not a record
-    return load_bench(os.path.join(REPO, "tests", "bench_doc_synthetic.json"))
-
-
-def test_perf_gate_identical_run_passes():
-    from pinot_tpu.tools.perf_gate import compare
-
-    base = _bench_doc()
-    out = compare(base, json.loads(json.dumps(base)))
-    assert out["verdict"] == "pass"
-    assert out["compared"] >= 8
-    assert all(m["ok"] for m in out["metrics"])
-
-
-def test_perf_gate_fails_on_latency_and_throughput_regressions():
-    from pinot_tpu.tools.perf_gate import compare
-
-    base = _bench_doc()
-    slow = json.loads(json.dumps(base))
-    slow["detail"]["broker_p50_ms"] = base["detail"]["broker_p50_ms"] * 10
-    out = compare(base, slow)
-    assert out["verdict"] == "fail"
-    bad = [m for m in out["metrics"] if not m["ok"]]
-    assert [m["metric"] for m in bad] == ["detail.broker_p50_ms"]
-
-    dead = json.loads(json.dumps(base))
-    dead["value"] = base["value"] * 0.05
-    out = compare(base, dead)
-    assert out["verdict"] == "fail"
-    assert any(m["metric"] == "value" for m in out["metrics"] if not m["ok"])
-
-    # a wider tolerance scale can absorb a borderline regression
-    mild = json.loads(json.dumps(base))
-    mild["detail"]["broker_p50_ms"] = base["detail"]["broker_p50_ms"] * 2.8
-    assert compare(base, mild)["verdict"] == "fail"
-    assert compare(base, mild, tolerance_scale=2.0)["verdict"] == "pass"
-
-
-def test_perf_gate_skips_on_config_mismatch():
-    from pinot_tpu.tools.perf_gate import compare
-
-    base = _bench_doc()
-    other = json.loads(json.dumps(base))
-    other["detail"]["total_rows"] = base["detail"]["total_rows"] * 8
-    other["detail"]["broker_p50_ms"] = base["detail"]["broker_p50_ms"] * 50
-    out = compare(base, other)
-    assert out["verdict"] == "skipped"
-    assert "detail.total_rows" in out["configMismatch"]
-    # forced comparison still works for exploration
-    assert compare(base, other, allow_config_mismatch=True)["verdict"] == "fail"
-
-
-def test_perf_gate_cli_passes_against_itself_and_needs_a_baseline(capsys):
-    """The tier-1 smoke: the gate binary runs clean on a document
-    compared with itself (same run => pass); a default-mode document has
-    no committed baseline, so leaving --baseline out is an input error."""
-    from pinot_tpu.tools.perf_gate import main
-
-    path = os.path.join(REPO, "tests", "bench_doc_synthetic.json")
-    assert main([path, "--baseline", path]) == 0
-    assert main([path]) == 2
-    assert "--baseline" in capsys.readouterr().err

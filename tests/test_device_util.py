@@ -634,70 +634,6 @@ def test_controller_utilization_rollup_and_dashboard(tmp_path):
         cluster.stop()
 
 
-# ------------------------------------------------------ perf gate
-def _serving_doc():
-    import os
-
-    from pinot_tpu.tools.perf_gate import load_bench
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    return load_bench(os.path.join(repo, "SERVING_UTIL_r10.json"))
-
-
-def test_perf_gate_serving_identical_run_passes():
-    from pinot_tpu.tools.perf_gate import compare
-
-    base = _serving_doc()
-    out = compare(base, json.loads(json.dumps(base)))
-    assert out["verdict"] == "pass"
-    assert out["compared"] >= 6
-    paths = {m["metric"] for m in out["metrics"]}
-    assert "utilization.pipelined.achievedBytesPerSec" in paths
-    assert "utilization.pipelined.busyFraction" in paths
-
-
-def test_perf_gate_serving_direction_aware_fail():
-    from pinot_tpu.tools.perf_gate import compare
-
-    base = _serving_doc()
-    cur = json.loads(json.dumps(base))
-    # bandwidth collapse: an order of magnitude under the band
-    cur["utilization"]["pipelined"]["achievedBytesPerSec"] = (
-        base["utilization"]["pipelined"]["achievedBytesPerSec"] * 0.1
-    )
-    out = compare(base, cur)
-    assert out["verdict"] == "fail"
-    bad = [m for m in out["metrics"] if not m["ok"]]
-    assert [m["metric"] for m in bad] == [
-        "utilization.pipelined.achievedBytesPerSec"
-    ]
-    # higher-is-better: the same magnitude UP is not a regression
-    cur["utilization"]["pipelined"]["achievedBytesPerSec"] = (
-        base["utilization"]["pipelined"]["achievedBytesPerSec"] * 10
-    )
-    assert compare(base, cur)["verdict"] == "pass"
-
-
-def test_perf_gate_serving_config_and_kind_mismatch_skip():
-    import os
-
-    from pinot_tpu.tools.perf_gate import compare, load_bench
-
-    base = _serving_doc()
-    cur = json.loads(json.dumps(base))
-    cur["num_segments"] = base["num_segments"] + 7
-    out = compare(base, cur)
-    assert out["verdict"] == "skipped"
-    assert "num_segments" in out["configMismatch"]
-
-    # mixed kinds (default bench vs serving mode): nothing to compare
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    default_doc = load_bench(os.path.join(repo, "tests", "bench_doc_synthetic.json"))
-    out2 = compare(default_doc, base)
-    assert out2["verdict"] == "skipped"
-    assert "kind" in out2["reason"]
-
-
 # ------------------------------------------------------ explain_dump
 def test_explain_dump_renders_cost_analysis_and_roofline():
     from pinot_tpu.tools.explain_dump import (

@@ -460,54 +460,6 @@ def test_fetch_failing_crc_reports_store_suspect(tmp_path):
     assert not os.path.exists(tmp_path / "dest.pnt")  # bad bytes not installed
 
 
-# ------------------------------------------------- perf gate (dr kind)
-
-
-def _dr_doc():
-    return {
-        "metric": "dr_restore_first_query_s",
-        "platform": "cpu",
-        "num_segments": 6,
-        "clients": 3,
-        "value": 0.3,
-        "backup": {"backupSeconds": 0.05},
-        "restore": {"restoreToFirstQuerySeconds": 0.3, "byteIdentical": True},
-        "scrub": {"okQpsRatio": 1.0, "detected": True, "repaired": True},
-    }
-
-
-def test_perf_gate_dr_kind():
-    from pinot_tpu.tools.perf_gate import _doc_kind, compare
-
-    base = _dr_doc()
-    assert _doc_kind(base) == "dr"
-    assert compare(base, json.loads(json.dumps(base)))["verdict"] == "pass"
-
-    broken = _dr_doc()
-    broken["restore"]["byteIdentical"] = False
-    broken["scrub"]["repaired"] = False
-    out = compare(base, broken)
-    assert out["verdict"] == "fail"
-    failed = {m["metric"] for m in out["metrics"] if not m["ok"]}
-    assert failed == {"restore.byteIdentical", "scrub.repaired"}
-
-    slow = _dr_doc()
-    slow["value"] = slow["restore"]["restoreToFirstQuerySeconds"] = 30.0
-    assert compare(base, slow)["verdict"] == "fail"  # order-of-magnitude rot
-
-    other_kind = dict(_dr_doc(), metric="audit_overhead_ratio")
-    assert compare(base, other_kind)["verdict"] == "skipped"
-
-
-def test_committed_dr_artifact_gates_itself():
-    from pinot_tpu.tools.perf_gate import compare, load_bench
-
-    path = os.path.join(os.path.dirname(__file__), "..", "DR_r20.json")
-    doc = load_bench(path)
-    out = compare(doc, json.loads(json.dumps(doc)))
-    assert out["verdict"] == "pass" and out["compared"] >= 7
-
-
 # --------------------------------------------------- chaos twin (e2e)
 
 

@@ -470,48 +470,6 @@ def test_drain_races_consuming_handoff_zero_loss(tmp_path):
 
 
 # ------------------------------------------------------------------
-# satellite 5: the ingest-ladder perf-gate wiring (direction-aware,
-# config-mismatch SKIP) against the committed INGEST_r15.json
-# ------------------------------------------------------------------
-def test_perf_gate_ingest_ladder_kind():
-    import copy
-
-    from pinot_tpu.tools.perf_gate import compare, load_bench
-
-    doc = load_bench("INGEST_r15.json")
-    out = compare(doc, doc)
-    assert out["verdict"] == "pass", out
-    assert out["compared"] >= 8
-    # the committed capture itself carries the arc's acceptance: the
-    # parallel aggregate beats the INGEST_r5 single-consumer LLC
-    # ceiling by well over 1.5x
-    assert doc["vs_r5_single_consumer_ceiling"] >= 1.5
-
-    # a parallel-scaling collapse (partition-parallel ingest silently
-    # serialized) must FAIL the gate
-    cur = copy.deepcopy(doc)
-    cur["parallel_vs_single"] = doc["parallel_vs_single"] * 0.4
-    cur["vs_r5_single_consumer_ceiling"] = 1.0
-    out = compare(doc, cur)
-    assert out["verdict"] == "fail"
-    failed = {m["metric"] for m in out["metrics"] if not m["ok"]}
-    assert "parallel_vs_single" in failed
-    assert "vs_r5_single_consumer_ceiling" in failed
-
-    # a slower lag drain past the band fails too (direction-aware)
-    cur = copy.deepcopy(doc)
-    cur["ladder"]["c2"]["lag_drain_s"] = doc["ladder"]["c2"]["lag_drain_s"] * 10
-    assert compare(doc, cur)["verdict"] == "fail"
-
-    # ladders from a different-sized host are not comparable: SKIP
-    cur = copy.deepcopy(doc)
-    cur["cpu_cores"] = 96
-    out = compare(doc, cur)
-    assert out["verdict"] == "skipped"
-    assert "cpu_cores" in out["configMismatch"]
-
-
-# ------------------------------------------------------------------
 # control-plane scale: version-keyed cluster-state snapshot cache
 # ------------------------------------------------------------------
 def test_clusterstate_snapshot_cached_per_version():

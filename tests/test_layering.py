@@ -1,0 +1,98 @@
+"""The direction of imports between the packages of ``pinot_tpu``.
+
+Low to high: ``common``, ``utils`` < ``pql``, ``segment``, ``startree``,
+``transport`` < ``engine``, ``parallel`` < ``realtime``, ``server`` <
+``broker``, ``controller`` < ``tools``, ``api``.  A module may import
+its own level and below, at module level or inside a function.  The
+pairs of ``ALLOWED`` are the upward imports the tree still has, each a
+debt ROADMAP names (D14); the list may only shrink: a case fails on an
+upward import that is not on it, and on a listed pair that no longer
+occurs.
+"""
+import ast
+import os
+
+import pytest
+
+PACKAGE = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "pinot_tpu")
+LEVELS = (
+    ("common", "utils"),
+    ("pql", "segment", "startree", "transport"),
+    ("engine", "parallel"),
+    ("realtime", "server"),
+    ("broker", "controller"),
+    ("tools", "api"),
+)
+LEVEL = {package: i for i, level in enumerate(LEVELS) for package in level}
+
+# (importing module, imported module): ROADMAP D14, in its order
+ALLOWED = {
+    # the oracle that audits the device shares its arithmetic with the tests' reference
+    ("engine.host_fallback", "tools.scan_engine"),
+    # the wire codec and the fault injector know the engine's result and error types
+    ("common.datatable", "engine.results"),
+    ("common.faults", "engine.dispatch"),
+    ("common.faults", "transport.tcp"),
+    # the audit plane and the plan statistics re-derive plan digests and reduce answers
+    ("utils.audit", "engine.plandigest"),
+    ("utils.audit", "engine.reduce"),
+    ("utils.audit", "pql"),
+    ("utils.planstats", "engine.plandigest"),
+    # the segment writer builds zone maps; the star-tree builds and answers with the engine's types
+    ("segment.format", "engine.zonemap"),
+    ("startree.builder", "engine.hll"),
+    ("startree.operator", "engine.plan"),
+    ("startree.operator", "engine.results"),
+    # the serving roles reach up for the broker's freshness type and the controller's resource manager
+    ("realtime.llc", "broker.freshness"),
+    ("realtime.llc", "controller.resource_manager"),
+    ("server.instance", "broker.freshness"),
+    ("server.network_starter", "broker.freshness"),
+    ("server.network_starter", "controller.resource_manager"),
+    ("server.starter", "controller.resource_manager"),
+}
+
+
+def imported_modules(tree: ast.AST):
+    """Every ``pinot_tpu`` module a file imports, as ``package.module``."""
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            names = [alias.name for alias in node.names]
+        elif isinstance(node, ast.ImportFrom) and not node.level:
+            names = [node.module or ""]
+            if names[0].count(".") < 2:  # from pinot_tpu[.package] import module
+                names = [f"{names[0]}.{alias.name}" for alias in node.names]
+        else:
+            continue
+        for name in names:
+            parts = name.split(".")
+            if parts[0] != "pinot_tpu" or len(parts) < 2:
+                continue
+            file = os.path.join(PACKAGE, *parts[1:3])
+            is_module = os.path.isfile(file + ".py") or os.path.isdir(file)
+            yield ".".join(parts[1:3]) if is_module else parts[1]  # a name the package itself exports
+
+
+def upward_imports(package: str) -> set:
+    found = set()
+    for folder, _, files in os.walk(os.path.join(PACKAGE, package)):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(folder, name)
+            module = os.path.relpath(path, PACKAGE)[:-3].replace(os.sep, ".")
+            with open(path) as f:
+                tree = ast.parse(f.read(), path)
+            for target in imported_modules(tree):
+                if LEVEL.get(target.split(".")[0], -1) > LEVEL[package]:
+                    found.add((module, target))
+    return found
+
+
+# tools and api stand on top: nothing is above them
+@pytest.mark.parametrize("package", [p for level in LEVELS[:-1] for p in level])
+def test_no_module_imports_a_package_above_it(package):
+    found = upward_imports(package)
+    allowed = {pair for pair in ALLOWED if pair[0].split(".")[0] == package}
+    assert not found - allowed, f"{package} imports upward: {sorted(found - allowed)}"
+    assert not allowed - found, f"turned since: take off ALLOWED and ROADMAP D14: {sorted(allowed - found)}"

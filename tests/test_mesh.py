@@ -2,8 +2,8 @@
 per-chip-group lanes, on the forced 8-device CPU host (conftest).
 
 Covers: topology construction from env, shape-hashed lane routing,
-byte-identical payloads sharded vs single-lane across the bench query
-mix, lane-group coalesce/shed/heal units, chaos (one poisoned plan on
+byte-identical payloads sharded vs single-lane across a mixed query
+set, lane-group coalesce/shed/heal units, chaos (one poisoned plan on
 one lane heals via host fallback while other lanes keep serving),
 sharded staging-ledger accounting + eviction, per-lane utilization
 attribution with sum-consistent rollups, and the EXPLAIN mesh node
@@ -35,10 +35,29 @@ def _segments(n=NUM_SEGMENTS, rows=2500, prefix="msh"):
     ]
 
 
+def _mixed_workload(segments):
+    """Four shapes: the Q1 group-by scan, an IN+range group-by, a
+    selective needle, an HLL distinct."""
+    d_price = segments[0].column("l_extendedprice").dictionary
+    pv = d_price.get(d_price.cardinality // 2)
+    return [
+        "SELECT sum(l_quantity), sum(l_extendedprice), sum(l_discount), count(*) "
+        "FROM lineitem WHERE l_shipdate <= '1998-09-02' "
+        "GROUP BY l_returnflag, l_linestatus TOP 10",
+        "SELECT sum(l_extendedprice) FROM lineitem "
+        "WHERE l_shipmode IN ('RAIL','FOB') AND "
+        "l_receiptdate BETWEEN '1997-01-01' AND '1997-12-31' "
+        "GROUP BY l_shipmode TOP 10",
+        f"SELECT sum(l_quantity), count(*) FROM lineitem "
+        f"WHERE l_extendedprice = {pv!r}",
+        "SELECT distinctcounthll(l_shipdate) FROM lineitem "
+        "GROUP BY l_returnflag TOP 10",
+    ]
+
+
 def _strip(resp) -> str:
-    """Canonical payload for the byte-identity differential (bench.py
-    _strip_timing semantics: timing, request identity, and the
-    path-dependent cost vector excluded)."""
+    """Canonical payload for the byte-identity differential: timing,
+    request identity, and the path-dependent cost vector excluded."""
     return json.dumps(
         {
             k: v
@@ -254,15 +273,13 @@ def test_single_group_lane_is_premesh_shape():
 def test_sharded_payloads_byte_identical_to_single_lane(
     lineitem_segments, mesh_broker
 ):
-    """The bench query mix (plus COUNT(*) and a selection) through a
+    """The mixed query set (plus COUNT(*) and a selection) through a
     2x4 lane-group server serves byte-identical payloads to the
     single-lane server — the mesh is a pure execution-plane change."""
     from pinot_tpu.tools.cluster_harness import single_server_broker
-    from pinot_tpu.tools.serving_curve import mixed_workload
-
     single = single_server_broker("lineitem", lineitem_segments)
     try:
-        queries = mixed_workload(lineitem_segments) + [
+        queries = _mixed_workload(lineitem_segments) + [
             "SELECT count(*) FROM lineitem",
             "SELECT l_returnflag, l_quantity FROM lineitem "
             "ORDER BY l_quantity DESC LIMIT 7",
@@ -512,11 +529,9 @@ def test_ledger_attributes_sharded_staging_per_device():
 
 
 def test_per_lane_utilization_rollup_equals_sum_of_lane_snapshots(mesh_broker):
-    from pinot_tpu.tools.serving_curve import mixed_workload
-
     server = mesh_broker.local_servers[0]
     segs = mesh_broker.local_servers[0].data_manager.table("lineitem_OFFLINE")
-    for pql in mixed_workload(_segments()):  # drive some device work
+    for pql in _mixed_workload(_segments()):  # drive some device work
         mesh_broker.handle_pql(pql)
     du = server.device_utilization()
     assert du["mesh"]["lanes"] == 2
@@ -575,56 +590,9 @@ def test_explain_reports_mesh_decision_and_digest_matches(mesh_broker):
 
 
 # ---------------------------------------------------------------------------
-# perf-gate: multichip-mode documents gate their own namespace
-# ---------------------------------------------------------------------------
-
-
-def test_perf_gate_multichip_kind():
-    from pinot_tpu.tools.perf_gate import compare
-
-    doc = {
-        "metric": "multichip_serving_ladder_rows_per_sec",
-        "platform": "cpu",
-        "n_devices": 8,
-        "num_segments": 8,
-        "total_rows": 1000,
-        "rows_per_sec": {"single_lane": 100.0, "sharded": 320.0, "lane_group": 300.0},
-        "sharded_vs_single": 3.2,
-        "lane_group_vs_single": 3.0,
-        "utilization": {
-            "sharded": {"achievedBytesPerSec": 1000.0},
-            "lane_group": {"achievedBytesPerSec": 900.0},
-        },
-    }
-    # identical docs pass and compare the multichip namespace
-    out = compare(doc, doc)
-    assert out["verdict"] == "pass"
-    assert {r["metric"] for r in out["metrics"]} >= {
-        "rows_per_sec.sharded",
-        "sharded_vs_single",
-        "utilization.lane_group.achievedBytesPerSec",
-    }
-    # a collapsed speedup fails the direction-aware band
-    worse = json.loads(json.dumps(doc))
-    worse["rows_per_sec"]["sharded"] = 110.0
-    worse["sharded_vs_single"] = 1.1
-    out = compare(doc, worse)
-    assert out["verdict"] == "fail"
-    # config mismatch SKIPs (different device count is a different run)
-    other = json.loads(json.dumps(doc))
-    other["n_devices"] = 4
-    assert compare(doc, other)["verdict"] == "skipped"
-    # mixed kinds SKIP outright
-    assert (
-        compare({"metric": "tpch_q1_rows_scanned_per_sec_per_chip"}, doc)["verdict"]
-        == "skipped"
-    )
-
-
-# ---------------------------------------------------------------------------
 # acceptance (slow): sharded execution beats a single lane by >= 3x on
-# the scan-heavy shapes — measured by bench's multichip mode on real
-# hardware; here gated as a slow test so tier-1 stays deterministic
+# the scan-heavy shapes on virtual CPU devices; slow, so tier-1 stays
+# deterministic (the chip's number is the mesh4 cell's, PERF.md)
 # ---------------------------------------------------------------------------
 
 

@@ -83,43 +83,6 @@ def test_query_runner_modes():
     assert rep.to_json()["p99Ms"] >= 0
 
 
-def test_serving_curve_smoke():
-    """The QPS-ladder serving-curve tool runs the mixed workload through
-    a real broker and reports per-step latency + shed counts."""
-    from pinot_tpu.tools.datagen import synthetic_lineitem_segment
-    from pinot_tpu.tools.serving_curve import run_curve
-
-    segs = [synthetic_lineitem_segment(20000, seed=5, name="sc0")]
-    doc = run_curve(segs, [4.0], duration_s=1.5)
-    assert len(doc["steps"]) == 1
-    step = doc["steps"][0]
-    assert step["queries"] > 0
-    assert step["errors"] == 0
-    assert step["p99_ms"] >= step["p50_ms"] > 0
-
-
-def test_serving_curve_two_tenant_smoke():
-    """The two-tenant ladder drives tenant A past its quota while
-    tenant B's closed loop stays clean, and records per-tenant shed /
-    quota counters per step."""
-    from pinot_tpu.tools.datagen import synthetic_lineitem_segment
-    from pinot_tpu.tools.serving_curve import run_two_tenant_ladder
-
-    seg_a = [synthetic_lineitem_segment(15000, seed=6, name="ta0")]
-    seg_b = [synthetic_lineitem_segment(15000, seed=7, name="tb0")]
-    doc = run_two_tenant_ladder(
-        seg_a, seg_b, [40.0], duration_s=1.5, quota_qps=4.0
-    )
-    assert len(doc["steps"]) == 1
-    step = doc["steps"][0]
-    assert step["a_offered_multiple"] == 10.0
-    assert step["a_quota_rejects"] > 0  # A's overflow shed at the quota
-    assert step["a_errors"] == 0  # ...and ONLY with typed errors
-    assert step["b_errors"] == 0  # B untouched by A's flood
-    assert step["b_p99_ms"] >= step["b_p50_ms"] > 0
-    assert step["admission_sheds"]["shedQuota"] == step["a_quota_rejects"]
-
-
 def test_admin_create_and_show_segment(tmp_path, capsys):
     from pinot_tpu.tools.admin import main
 
@@ -267,32 +230,3 @@ def test_hybrid_quickstart():
     assert not resp.exceptions and resp.to_json()["aggregationResults"][0]["groupByResult"]
 
 
-def test_filter_matrix_smoke():
-    """The selectivity x clustering x path matrix runs all four tiers
-    per cell, forces the postings path, and labels zonemap/bitsliced
-    fallthrough so neither tier is credited with a scan's win."""
-    from pinot_tpu.tools.datagen import synthetic_lineitem_segment
-    from pinot_tpu.tools.filter_matrix import PATHS, run_matrix
-
-    segs = [synthetic_lineitem_segment(30000, seed=7, name="fm0")]
-    doc = run_matrix(segs, reps=3)
-    assert len(doc["matrix"]) == 10
-    tiers = tuple(PATHS)
-    assert tiers == ("invindex", "zonemap", "bitsliced", "fullscan")
-    for row in doc["matrix"]:
-        for path in tiers:
-            assert row[f"{path}_p50_ms"] > 0
-        assert isinstance(row["zonemap_engaged"], bool)
-        assert isinstance(row["bitsliced_engaged"], bool)
-        assert row["winner"] in tiers
-        if row["winner"] == "zonemap":
-            assert row["zonemap_engaged"]
-        if row["winner"] == "bitsliced":
-            assert row["bitsliced_engaged"]
-    # the shuffled fusable cells really engage the bit-sliced kernels
-    assert any(
-        r["bitsliced_engaged"] for r in doc["matrix"] if r["shape"] == "shuffled"
-    )
-    assert set(doc["tier_wins"]) == set(tiers)
-    assert sum(doc["tier_wins"].values()) == len(doc["matrix"])
-    assert "bitsliced_midsel_wins" in doc and "num_segments" in doc

@@ -266,9 +266,9 @@ def test_repeated_query_uses_input_cache_on_device(cluster):
 
 
 # ---------------------------------------------------------------------------
-# The two Pallas kernels, compiled by Mosaic for this chip (not the
-# interpreter the CPU suite uses) at the shapes tools/microbench.py runs
-# them at, and held to the XLA lowering they stand beside.
+# The Pallas occupancy histogram, compiled by Mosaic for this chip (not
+# the interpreter the CPU suite uses) and held to the XLA lowering it
+# stands beside.
 # ---------------------------------------------------------------------------
 PALLAS_ROWS = 1 << 20
 
@@ -281,7 +281,7 @@ def test_pallas_value_state_histogram_compiles_and_matches_xla():
         _value_state_counts_xla,
     )
 
-    K = 1 << 14  # microbench hll_lowerings presence shape
+    K = 1 << 14  # the HLL presence shape
     rng = np.random.default_rng(5)
     idx = jnp.asarray(rng.integers(0, K + 1, size=PALLAS_ROWS).astype(np.int32))
     compiled = jax.jit(lambda i: _value_state_counts_pallas(i, K)).lower(idx).compile()
@@ -289,31 +289,3 @@ def test_pallas_value_state_histogram_compiles_and_matches_xla():
     assert np.array_equal(np.asarray(compiled(idx)), want)
 
 
-def test_pallas_fused_q1_compiles_and_matches_numpy():
-    import jax.numpy as jnp
-
-    from pinot_tpu.engine.pallas_kernels import fused_filtered_groupby_sums
-
-    n, capacity = PALLAS_ROWS, 6  # microbench pallas_ab: Q1, raw value feeds
-    rng = np.random.default_rng(6)
-    fwd = rng.integers(0, 2000, size=n).astype(np.int32)
-    keys = rng.integers(0, capacity, size=n).astype(np.int32)
-    raws = [rng.uniform(1.0, 50.0, size=n).astype(np.float32) for _ in range(3)]
-    lo, hi = 100, 1900
-    fused = jax.jit(
-        lambda f, v, k, r0, r1, r2: fused_filtered_groupby_sums(
-            f, None, v, k, [None] * 3, [None] * 3, capacity,
-            filter_bounds=(lo, hi), value_raws=[r0, r1, r2],
-        )
-    )
-    args = (jnp.asarray(fwd), jnp.ones(n, dtype=bool), jnp.asarray(keys),
-            *(jnp.asarray(r) for r in raws))
-    docs, count, sums = fused.lower(*args).compile()(*args)
-    mask = (fwd >= lo) & (fwd < hi)
-    assert float(docs) == float(mask.sum())
-    assert np.array_equal(
-        np.asarray(count), np.bincount(keys[mask], minlength=capacity).astype(np.float32)
-    )
-    for got, raw in zip(sums, raws):
-        want = np.bincount(keys[mask], weights=raw[mask].astype(np.float64), minlength=capacity)
-        assert np.allclose(np.asarray(got), want, rtol=RTOL)
