@@ -332,8 +332,9 @@ def _finalize(
     return partials, matched
 
 
-def try_bitsliced_path(
+def run_bitsliced_path(
     executor,
+    state,
     request: BrokerRequest,
     live: List[ImmutableSegment],
     ctx: TableContext,
@@ -342,15 +343,13 @@ def try_bitsliced_path(
     lane=None,
     lane_index: int = 0,
 ) -> Optional[IntermediateResult]:
-    """Serve an eligible scalar aggregation from the bit-sliced tier,
-    or None to fall through to the zone-map/scan device section.  Rides
+    """Serve the scalar aggregation a taken ``bitsliced_decision`` handed
+    off in ``state`` (which the executor keeps for a repeated query), or
+    None to fall through to the zone-map/scan device section.  Rides
     the same lane dispatch plumbing as the scan kernels (coalescing,
     micro-timers, static cost analysis -> achievedBytesPerSec), with
     the kernel spec standing in for the StaticPlan in every cache key —
     both are process-stable hashables."""
-    decision, state = bitsliced_decision(request, live, ctx, total_docs)
-    if state is None:
-        return None
     spec, leaves, agg_descs, planes_total, filter_planes = state
     leaf_spec, _tree, sums, extremes = spec
 

@@ -13,8 +13,11 @@ from __future__ import annotations
 
 import logging
 import math
+import os
+import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from collections import OrderedDict
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +48,95 @@ from pinot_tpu.segment.immutable import ImmutableSegment
 from pinot_tpu.utils.trace import boundary, current_trace, measured, phases
 
 logger = logging.getLogger(__name__)
+
+# bounds of the prepared-query memo, which is host memory: its entries,
+# and the bytes of the tables they hold (query inputs, block ids, the
+# postings hand-off's match tables).  The least recently asked goes first.
+_PREPARED_ENTRIES = 256
+_PREPARED_BYTES = 64 << 20
+
+
+def _settings_fence() -> Tuple[Tuple[str, str], ...]:
+    """Every ``PINOT_TPU_*`` setting of the moment, part of a prepared
+    query's key: the ladder reads a dozen of them on every query
+    (``INVINDEX``, ``BITSLICED``, ``ZONEMAP``, ``ZONE_BLOCK``,
+    ``CHUNK_ROWS``, the tier cost model's) and a verdict derived under
+    one value must not answer under another."""
+    return tuple(sorted((k, os.environ[k]) for k in os.environ if k.startswith("PINOT_TPU_")))
+
+
+def _table_bytes(tree) -> int:
+    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree) if isinstance(leaf, np.ndarray))
+
+
+class _Derived:
+    """Derivations kept by name: ``once(name, derive)`` derives at the
+    first ask and answers every later one with that value.  Two queries
+    of one key that race derive the same value twice, and either stands:
+    every derivation kept here is a function of the key alone."""
+
+    __slots__ = ("values",)
+
+    def __init__(self) -> None:
+        self.values: Dict[str, Any] = {}
+
+    def once(self, name: str, derive: Callable[[], Any]) -> Any:
+        try:
+            return self.values[name]
+        except KeyError:
+            value = self.values[name] = derive()
+            return value
+
+
+class _Prepared(_Derived):
+    """What the ladder derives on the host from (the query's text, the
+    identity of the segments served, the placement, the settings) alone,
+    kept by the executor under that key so that a repeated query derives
+    none of it again.  By name, in the order ``_execute_tiers`` asks:
+
+    ``scope``     total docs, the columns to stage, the selection's
+                  columns, the segment padding
+    ``postings``  ``index_path_decision``'s hand-off, its postings held
+                  weakly (``invindex_path.hold_state``; (): declined)
+    ``bitsliced`` ``bitsliced_decision``'s hand-off (None: declined)
+    ``forcedHost`` ``plan_forced_host``
+    ``roles``     raw / gfwd / hll role columns and the skip-base set
+
+    and in ``device``, derived against the staged table whose token it
+    names (a table staged anew after a demotion is another table):
+
+    ``plan``      the ``StaticPlan``, its ``plan_digest``, the poison key
+    ``inputs``    ``q_np``, its digest, the block ids and the rows they scan
+    ``batch``     the batch spec's signature and member cap
+
+    It holds no device array and no ``StagedTable``, no segment, table
+    context or postings: an entry must keep alive neither a demoted
+    table's HBM nor an unloaded segment's host memory."""
+
+    __slots__ = ("device", "nbytes")
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.device: Optional[_PreparedDevice] = None
+        self.nbytes = 0  # as the memo last counted it
+
+
+class _PreparedDevice(_Derived):
+    __slots__ = ("token",)
+
+    def __init__(self, token: int) -> None:
+        super().__init__()
+        self.token = token
+
+
+class _Use:
+    """One query's use of the memo: the entry, its key (None: not kept)
+    and what the query found, ``hit``, ``miss`` or ``stale``."""
+
+    __slots__ = ("prepared", "key", "outcome")
+
+    def __init__(self, prepared: _Prepared, key, outcome: str) -> None:
+        self.prepared, self.key, self.outcome = prepared, key, outcome
 
 
 class _PairsState:
@@ -195,16 +287,21 @@ class QueryExecutor:
         # results — the differential suite holds the two together)
         self.lane = lane
         self._sharded_kernels: Dict[Any, Any] = {}
-        self._mesh_shardings: Dict[Any, Any] = {}  # mesh id -> NamedSharding
-        from collections import OrderedDict
-
+        self._mesh_shardings: Dict[Any, Any] = {}  # mesh id -> (NamedSharding, placement key)
         self._qinput_cache: "OrderedDict[Any, Any]" = OrderedDict()
         self._qinput_cache_bytes = 0
         # the QueryScheduler runs queries on a worker pool; byte
         # accounting must not drift under concurrent misses/evictions
-        import threading
-
         self._qinput_cache_lock = threading.Lock()
+        # the prepared-query memo (_Prepared): key -> entry, least
+        # recently asked first; hit + miss + stale = queries that
+        # reached the ladder
+        self._prepared: "OrderedDict[Any, _Prepared]" = OrderedDict()
+        self._prepared_bytes = 0
+        self._prepared_lock = threading.Lock()
+        for outcome in ("hit", "miss", "stale"):
+            metrics.meter(f"plan.prepared.{outcome}")
+        metrics.gauge("plan.prepared.entries").set_fn(lambda: len(self._prepared))
         # self-healing state: device failures fail over to the host
         # path, and a (plan digest, segment set) that keeps failing on
         # device is quarantined so repeat offenders skip the device
@@ -219,9 +316,7 @@ class QueryExecutor:
         # worst case of a permanent verdict is serving a healthy plan
         # from the slow host path forever
         self._poisoned: Dict[Any, Tuple[str, float]] = {}
-        import os as _os
-
-        self._poison_ttl_s = float(_os.environ.get("PINOT_TPU_POISON_TTL_S", "300"))
+        self._poison_ttl_s = float(os.environ.get("PINOT_TPU_POISON_TTL_S", "300"))
         # audit-plane quarantine flag: True once any ("audit", digest,
         # tier) key entered the poison map, so the serving path only
         # pays a plan-digest derivation when a quarantine could apply
@@ -329,16 +424,6 @@ class QueryExecutor:
                     )
         return out
 
-    def _audit_digest(self, request: BrokerRequest) -> Optional[str]:
-        """The shape digest for quarantine checks — derived ONLY when
-        some audit quarantine exists (zero serving-path overhead while
-        the audit plane has never fired)."""
-        if not self._has_audit_poison:
-            return None
-        from pinot_tpu.engine.plandigest import plan_shape_digest
-
-        return plan_shape_digest(request)
-
     def _audit_blocked(self, digest: Optional[str], tier: str) -> bool:
         if digest is None:
             return False
@@ -416,33 +501,38 @@ class QueryExecutor:
         return res
 
     # -- mesh / lane-group routing -------------------------------------
-    def lane_selection(self, request: BrokerRequest):
+    def lane_selection(self, request: BrokerRequest, shape_digest: Optional[str] = None):
         """Shape-hashed chip-group routing (dispatch.LaneGroup.select),
         or None without a lane group.  Shared by the serving path and
         EXPLAIN so the phantom plan stages/pads exactly like the lane
         that would execute it."""
         if self.lanes is None:
             return None
-        from pinot_tpu.engine.plandigest import plan_shape_digest
+        if shape_digest is None:
+            from pinot_tpu.engine.plandigest import plan_shape_digest
 
-        return self.lanes.select(plan_shape_digest(request))
+            shape_digest = plan_shape_digest(request)
+        return self.lanes.select(shape_digest)
 
-    def _mesh_sharding(self, mesh):
-        """NamedSharding splitting the segment axis over ``mesh`` (one
-        cached instance per mesh — it is part of staging-cache keys)."""
+    def _mesh_placement(self, mesh):
+        """(NamedSharding splitting the segment axis over ``mesh``, its
+        ``placement_key``), None and None without a mesh: one cached
+        instance per mesh — it is part of staging-cache keys."""
         if mesh is None:
-            return None
+            return None, None
         key = id(mesh)
-        sh = self._mesh_shardings.get(key)
-        if sh is None:
+        placed = self._mesh_shardings.get(key)
+        if placed is None:
             from jax.sharding import NamedSharding, PartitionSpec as P
+
+            from pinot_tpu.engine.device import placement_key
 
             # axis 0 shards over EVERY mesh axis — the same spec the
             # sharded kernels' in_specs use (multichip._make_sharded),
             # so staged arrays arrive already laid out for shard_map
             sh = NamedSharding(mesh, P(tuple(mesh.axis_names)))
-            self._mesh_shardings[key] = sh
-        return sh
+            placed = self._mesh_shardings[key] = (sh, placement_key(sh))
+        return placed
 
     def _mesh_key(self, mesh) -> Any:
         """Hashable kernel-cache component for a mesh (per-lane meshes
@@ -515,10 +605,88 @@ class QueryExecutor:
         # unless a host tier answers and relabels it
         ph = phases(self._phase)
         ph.enter("staging")
+        use = None
         try:
-            return self._execute_tiers(live, request, deadline, ph)
+            from pinot_tpu.engine.plandigest import plan_shape_digest
+
+            # one digest a query: the lane's choice, the audit plane's
+            # quarantine checks and the memo's key all read it
+            shape = plan_shape_digest(request)
+            # chip-group routing (mesh execution): the lane group picks
+            # the lane/mesh this shape executes on; without one, the
+            # legacy single-mesh (or no-mesh) configuration applies
+            sel = self.lane_selection(request, shape)
+            mesh = sel.group.mesh if sel is not None else self.mesh
+            use = self._prepared_for(request, live, shape, sel, mesh)
+            result = self._execute_tiers(live, request, deadline, ph, shape, sel, mesh, use)
         finally:
             ph.stop()
+            if use is not None:
+                self.metrics.meter(f"plan.prepared.{use.outcome}").mark()
+        if use.outcome == "hit":
+            result.add_cost(preparedHit=1)
+        return result
+
+    # -- the prepared-query memo ---------------------------------------
+    def _prepared_for(self, request: BrokerRequest, live: List[ImmutableSegment], shape: str, sel, mesh) -> _Use:
+        """This query's entry of the memo, found or begun.  The key is
+        ``rescache.ResultCache.key_for``'s identity (shape digest, which
+        holds the raw table; literal digest; each segment's name and
+        staging token) with the segments in the order served, because
+        staging, the query inputs and the postings hand-off are laid
+        out in it; then the placement, the lane group and the settings.
+        A re-loaded segment, a consuming segment whose offset advanced,
+        another literal, another chip group or a flipped setting is
+        another key.  A segment without a token is not kept."""
+        from pinot_tpu.engine.plandigest import plan_literal_digest
+
+        try:
+            fence = tuple((s.segment_name, int(s.staging_token)) for s in live)
+        except (AttributeError, TypeError):
+            return _Use(_Prepared(), None, "miss")
+        key = (
+            shape,
+            plan_literal_digest(request),
+            fence,
+            self._mesh_placement(mesh)[1],
+            sel.index if sel is not None else 0,
+            _settings_fence(),
+        )
+        with self._prepared_lock:
+            found = self._prepared.get(key)
+            if found is not None:
+                self._prepared.move_to_end(key)
+                return _Use(found, key, "hit")
+            begun = self._prepared[key] = _Prepared()
+            self._prepared_make_room()
+        return _Use(begun, key, "miss")
+
+    def _prepared_make_room(self) -> None:
+        """Under the memo's lock: the least recently asked go until
+        entries and bytes fit their bounds."""
+        while self._prepared and (
+            len(self._prepared) > _PREPARED_ENTRIES or self._prepared_bytes > _PREPARED_BYTES
+        ):
+            _, old = self._prepared.popitem(last=False)
+            self._prepared_bytes -= old.nbytes
+
+    def _prepared_count(self, use: _Use) -> None:
+        """Count the entry's tables anew, after a derivation that holds
+        some.  An entry over a quarter of the bound alone is not kept
+        (one query must not turn the whole memo over)."""
+        prep = use.prepared
+        postings = prep.values.get("postings")
+        nbytes = _table_bytes([t for _ref, t in postings[1]]) if postings else 0
+        if prep.device is not None:
+            nbytes += _table_bytes(prep.device.values.get("inputs"))
+        with self._prepared_lock:
+            if self._prepared.get(use.key) is prep:  # else turned out meanwhile, or never kept
+                self._prepared_bytes += nbytes - prep.nbytes
+                prep.nbytes = nbytes
+                if nbytes > _PREPARED_BYTES // 4:
+                    del self._prepared[use.key]
+                    self._prepared_bytes -= nbytes
+                self._prepared_make_room()
 
     def _execute_tiers(
         self,
@@ -526,47 +694,55 @@ class QueryExecutor:
         request: BrokerRequest,
         deadline: Optional[float],
         ph: phases,
+        shape: str,
+        sel,
+        mesh,
+        use: _Use,
     ) -> IntermediateResult:
-        total_docs = sum(s.num_docs for s in live)
-        needed = set(request.referenced_columns())
-        sel_columns: Optional[List[str]] = None
-        if request.is_selection:
-            sel_columns = self._resolve_selection_columns(request, live[0])
-            needed.update(sel_columns)
+        prep = use.prepared
 
-        # chip-group routing (mesh execution): the lane group picks the
-        # lane/mesh this shape executes on; without one, the legacy
-        # single-mesh (or no-mesh) configuration applies
-        sel = self.lane_selection(request)
-        mesh = sel.group.mesh if sel is not None else self.mesh
-        pad_to = 0
-        if mesh is not None:
-            n = int(mesh.devices.size)
-            pad_to = -(-len(live) // n) * n
+        def scope():
+            needed = set(request.referenced_columns())
+            sel_columns: Optional[List[str]] = None
+            if request.is_selection:
+                sel_columns = self._resolve_selection_columns(request, live[0])
+                needed.update(sel_columns)
+            pad_to = 0
+            if mesh is not None:
+                n = int(mesh.devices.size)
+                pad_to = -(-len(live) // n) * n
+            # columns used ONLY by doc-range predicates on sorted columns
+            # never reach the device (the kernel compares row ids against
+            # host-computed doc bounds) — skip staging them entirely
+            needed -= self._docrange_only_columns(request, live, sel_columns)
+            return sum(s.num_docs for s in live), tuple(sorted(needed)), sel_columns, pad_to
 
-        # columns used ONLY by doc-range predicates on sorted columns
-        # never reach the device (the kernel compares row ids against
-        # host-computed doc bounds) — skip staging them entirely
-        needed -= self._docrange_only_columns(request, live, sel_columns)
-
+        total_docs, needed, sel_columns, pad_to = prep.once("scope", scope)
+        # looked up on every query (its own cache, by the segments'
+        # tokens): an entry that held it would hold the segments
         ctx = get_table_context(live)
 
         # audit-plane quarantine (utils/audit.py): a tier caught
-        # serving wrong answers for this shape is skipped — derived
+        # serving wrong answers for this shape is skipped — looked up
         # only while some audit quarantine is live
-        audit_digest = self._audit_digest(request)
+        audit_digest = shape if self._has_audit_poison else None
 
         # selective predicates answer from host postings in O(matches)
         # (engine/invindex_path.py — BitmapBasedFilterOperator analog);
         # unselective ones fall through to the device scan below
-        from pinot_tpu.engine.invindex_path import try_index_path
-
-        ires = None
         if not self._audit_blocked(audit_digest, "postings"):
-            ires = try_index_path(request, live, ctx, total_docs, sel_columns)
-        if ires is not None:
-            ph.relabel("indexPath")
-            return self._finish_tier(ires, request, "postings")
+            from pinot_tpu.engine import invindex_path
+
+            kept = prep.values.get("postings")  # None: not decided yet; (): declined
+            state = invindex_path.held_state(kept) if kept else None
+            if state is None and kept != ():  # not decided, or the postings it took were released since
+                state = invindex_path.index_path_decision(request, live, ctx, total_docs)[1]
+                prep.values["postings"] = invindex_path.hold_state(state) if state is not None else ()
+                self._prepared_count(use)
+            if state is not None:
+                ires = invindex_path.run_index_path(state, request, live, ctx, total_docs, sel_columns)
+                ph.relabel("indexPath")
+                return self._finish_tier(ires, request, "postings")
 
         # mid-selectivity scalar aggregations the postings tier just
         # declined evaluate as O(bit-width) bulk-bitwise passes over
@@ -575,14 +751,19 @@ class QueryExecutor:
         # here falls through to the scan section's healing loop below
         # instead of failing the query on an optimization tier.
         if mesh is None and not self._audit_blocked(audit_digest, "bitsliced"):
-            from pinot_tpu.engine.bitsliced import try_bitsliced_path
+            from pinot_tpu.engine.bitsliced import bitsliced_decision, run_bitsliced_path
 
             try:
-                bres = try_bitsliced_path(
-                    self, request, live, ctx, total_docs, deadline,
-                    lane=sel.lane if sel is not None else None,
-                    lane_index=sel.index if sel is not None else 0,
+                state = prep.once(
+                    "bitsliced", lambda: bitsliced_decision(request, live, ctx, total_docs)[1]
                 )
+                bres = None
+                if state is not None:
+                    bres = run_bitsliced_path(
+                        self, state, request, live, ctx, total_docs, deadline,
+                        lane=sel.lane if sel is not None else None,
+                        lane_index=sel.index if sel is not None else 0,
+                    )
             except Exception as e:
                 from pinot_tpu.engine.dispatch import (
                     LaneClosedError,
@@ -609,7 +790,7 @@ class QueryExecutor:
         # guaranteed pair overflow) skip device staging entirely
         from pinot_tpu.engine.plan import plan_forced_host
 
-        if plan_forced_host(request, ctx):
+        if prep.once("forcedHost", lambda: plan_forced_host(request, ctx)):
             from pinot_tpu.engine.host_fallback import execute_host
 
             res = execute_host(live, ctx, request, total_docs, sel_columns)
@@ -671,8 +852,7 @@ class QueryExecutor:
             try:
                 return self._finish_tier(
                     self._device_section(
-                        live, request, deadline, ctx, needed, sel_columns,
-                        pad_to, total_docs, ph, poison_ref, sel=sel, mesh=mesh,
+                        live, request, deadline, ctx, use, ph, poison_ref, sel=sel, mesh=mesh,
                     ),
                     request,
                     "device",
@@ -723,29 +903,32 @@ class QueryExecutor:
         request: BrokerRequest,
         deadline: Optional[float],
         ctx: TableContext,
-        needed: set,
-        sel_columns: Optional[List[str]],
-        pad_to: int,
-        total_docs: int,
+        use: _Use,
         ph: phases,
         poison_ref: Dict[str, Any],
         sel=None,
         mesh=None,
     ) -> IntermediateResult:
-        if sel is None and mesh is None:
-            mesh = self.mesh  # standalone callers (no lane group)
+        prep = use.prepared
+        _total_docs, needed, _sel_columns, pad_to = prep.values["scope"]
         lane = sel.lane if sel is not None else self.lane
-        sharding = self._mesh_sharding(mesh)
-        raw_cols, gfwd_cols, hll_cols = self._role_columns(request, live, ctx)
-        skip_base = self._skip_base_columns(
-            request, live, raw_cols, gfwd_cols, hll_cols
-        )
-        # pin=True: the staged table's token is refcounted for this
-        # query's whole device section, so tier demotion under memory
-        # pressure (engine/residency.py) can never race the launch
+        sharding, placement = self._mesh_placement(mesh)
+
+        def roles():
+            raw_cols, gfwd_cols, hll_cols = self._role_columns(request, live, ctx)
+            return raw_cols, gfwd_cols, hll_cols, self._skip_base_columns(
+                request, live, raw_cols, gfwd_cols, hll_cols
+            )
+
+        raw_cols, gfwd_cols, hll_cols, skip_base = prep.once("roles", roles)
+        # what a kept entry cannot spare a query, because it is the
+        # table's state and not a function of the key: the staged table
+        # of the moment, pinned.  pin=True: its token is refcounted for
+        # this query's whole device section, so tier demotion under
+        # memory pressure (engine/residency.py) can never race the launch
         staged = get_staged(
             live,
-            sorted(needed),
+            needed,
             pad_segments_to=pad_to,
             raw_columns=raw_cols,
             gfwd_columns=gfwd_cols,
@@ -762,9 +945,8 @@ class QueryExecutor:
 
         try:
             return self._device_section_staged(
-                live, request, deadline, ctx, needed, sel_columns,
-                total_docs, ph, poison_ref, sel, mesh, lane, sharding,
-                staged,
+                live, request, deadline, ctx, use, ph, poison_ref, sel, mesh, lane,
+                sharding, placement, staged,
             )
         finally:
             RESIDENCY.unpin(staged.token)
@@ -775,20 +957,38 @@ class QueryExecutor:
         request: BrokerRequest,
         deadline: Optional[float],
         ctx: TableContext,
-        needed: set,
-        sel_columns: Optional[List[str]],
-        total_docs: int,
+        use: _Use,
         ph: phases,
         poison_ref: Dict[str, Any],
         sel,
         mesh,
         lane,
         sharding,
+        placement,
         staged,
     ) -> IntermediateResult:
         ph.enter("planBuild")  # staging ends here
+        prep = use.prepared
+        total_docs, needed, sel_columns, _pad_to = prep.values["scope"]
+        dev = prep.device
+        if dev is None or dev.token != staged.token:
+            # derived against another staged table (demoted since, and
+            # staged anew): everything below is derived again
+            if dev is not None and use.outcome == "hit":
+                use.outcome = "stale"
+            dev = prep.device = _PreparedDevice(staged.token)
         scratch: Dict[Any, Any] = {}  # plan->inputs table cache (regex)
-        plan = build_static_plan(request, ctx, staged, scratch=scratch)
+
+        def plan_and_digest():
+            # the digest is computed ONCE here and shared with the
+            # lane's injector hook and the failover wrapper's quarantine
+            from pinot_tpu.engine.dispatch import plan_digest
+
+            plan = build_static_plan(request, ctx, staged, scratch=scratch)
+            pdigest = plan_digest(plan) if plan.on_device else None
+            return plan, pdigest, (pdigest, staged.segment_names)
+
+        plan, pdigest, poison_key = dev.once("plan", plan_and_digest)
 
         if not plan.on_device:
             from pinot_tpu.engine.host_fallback import execute_host
@@ -800,14 +1000,9 @@ class QueryExecutor:
         # poison quarantine: this (plan digest, segment set) keeps
         # failing on device — skip the device entirely and serve from
         # the always-correct host path (PIMDAL-style contract: the host
-        # path stays a correct fallback for the accelerator path).  The
-        # digest is computed ONCE here and shared with the lane's
-        # injector hook and the failover wrapper's quarantine.
-        from pinot_tpu.engine.dispatch import plan_digest as _plan_digest
-
-        pdigest = _plan_digest(plan)
-        poison_ref["key"] = (pdigest, staged.segment_names)
-        if self._is_poisoned(poison_ref["key"]):
+        # path stays a correct fallback for the accelerator path)
+        poison_ref["key"] = poison_key
+        if self._is_poisoned(poison_key):
             from pinot_tpu.engine.host_fallback import execute_host
 
             self._heal_mark("poisonSkips")
@@ -817,20 +1012,27 @@ class QueryExecutor:
 
         from pinot_tpu.engine.device import segment_arrays
 
-        cost: Dict[str, float] = {}  # per-query cost vector accumulator
-        q_np = build_query_inputs(request, plan, ctx, staged, scratch=scratch)
-        digest = self._inputs_digest(q_np)
-        seg_arrays = segment_arrays(staged, needed)
-        block_ids, scanned_rows = self._block_skip_ids(plan, q_np, live, staged)
-        from pinot_tpu.engine.kernel import chunk_rows_limit
+        def inputs():
+            q_np = build_query_inputs(request, plan, ctx, staged, scratch=scratch)
+            block_ids, scanned_rows = self._block_skip_ids(plan, q_np, live, staged)
+            from pinot_tpu.engine.kernel import chunk_rows_limit
 
-        _limit = chunk_rows_limit()
-        if block_ids is not None and _limit and staged.num_segments * staged.n_pad > _limit:
-            # the block kernel has no segment-chunked variant: beyond the
-            # per-dispatch row budget its single dispatch would exhaust
-            # HBM at compile time — fall through to the chunked full
-            # kernel instead (correctness over the block-skip win)
-            block_ids = None
+            _limit = chunk_rows_limit()
+            if block_ids is not None and _limit and staged.num_segments * staged.n_pad > _limit:
+                # the block kernel has no segment-chunked variant: beyond the
+                # per-dispatch row budget its single dispatch would exhaust
+                # HBM at compile time — fall through to the chunked full
+                # kernel instead (correctness over the block-skip win)
+                block_ids = None
+            return q_np, self._inputs_digest(q_np), block_ids, scanned_rows
+
+        derived = "inputs" not in dev.values
+        q_np, digest, block_ids, scanned_rows = dev.once("inputs", inputs)
+        if derived:
+            self._prepared_count(use)
+        cost: Dict[str, float] = {}  # per-query cost vector accumulator
+        # the staged table's arrays of the moment: never kept (_Prepared)
+        seg_arrays = segment_arrays(staged, needed)
         # the kernel's lookup, the batch spec and (where the launch does
         # not carry it) the upload of the query's inputs
         ph.enter("kernelPrep")
@@ -841,9 +1043,13 @@ class QueryExecutor:
 
         def upload_inputs():
             return self._to_device_inputs(
-                q_np, plan=plan, digest=digest, cost=cost, sharding=sharding
+                q_np, plan=plan, digest=digest, cost=cost, sharding=sharding,
+                placement=placement,
             )
 
+        # the kernel's handle is looked up on every query, where its
+        # builders keep it (engine/kernel.py, _cached_sharded): a
+        # program forgotten there is built again by the next launch
         if block_ids is not None:
             from pinot_tpu.engine.zonemap import zone_block_rows
 
@@ -869,7 +1075,9 @@ class QueryExecutor:
                 # no per-query block-id gathers, no chunked dispatch
                 # sequence) — exactly the path _kernel chose above when
                 # the table fits the per-dispatch row budget
-                batch_spec = self._batch_spec(plan, staged, q_np, seg_arrays)
+                batch_shape = dev.once("batch", lambda: self._batch_shape(staged, q_np))
+                if batch_shape is not None:
+                    batch_spec = self._batch_spec(plan, staged, q_np, seg_arrays, batch_shape)
             if batch_spec is not None:
                 # defer the solo upload into the launch closure: a
                 # dispatch that rides a batched launch never uses its
@@ -1219,22 +1427,14 @@ class QueryExecutor:
                     hll_cols.add(a.column)
         return tuple(sorted(raw_cols)), tuple(sorted(gfwd_cols)), tuple(sorted(hll_cols))
 
-    def _batch_spec(self, plan: StaticPlan, staged, q_np, seg_arrays):
-        """BatchSpec for the lane micro-batching tier (PIMDAL-style
-        cross-query amortization — engine/dispatch.py module
-        docstring): same-StaticPlan dispatches over the same staged
-        table stack their query inputs along a leading batch axis and
-        execute as ONE vmapped launch reading the resident columns
-        once.
-
-        The key is (StaticPlan, staging token, input signature):
-        literal-bucketed program identity (``a>5`` and ``a>999`` build
-        the SAME StaticPlan — only their match tables/bounds differ) x
-        resident-table identity x structural input identity.
-        ``max_members`` keeps batch x rows under the per-dispatch row
-        budget so batching can never blow the compile-time working set
-        the chunked path exists to bound."""
-        from pinot_tpu.engine.dispatch import BatchSpec
+    def _batch_shape(self, staged, q_np) -> Optional[Tuple[tuple, int]]:
+        """What of a ``BatchSpec`` is a function of the query and the
+        staged table's shape, and so is kept with the prepared query:
+        (the inputs' structural signature, ``max_members``), or None
+        where one member already fills the per-dispatch row budget.
+        ``max_members`` keeps batch x rows under that budget so batching
+        can never blow the compile-time working set the chunked path
+        exists to bound."""
         from pinot_tpu.engine.kernel import chunk_rows_limit
         from pinot_tpu.engine.packing import batch_input_signature
 
@@ -1253,7 +1453,26 @@ class QueryExecutor:
             max_members = 0
         if max_members == 1:
             return None  # one batch member already fills the budget
-        key = (plan, staged.token, batch_input_signature(q_np))
+        return batch_input_signature(q_np), max_members
+
+    def _batch_spec(self, plan: StaticPlan, staged, q_np, seg_arrays, batch_shape):
+        """BatchSpec for the lane micro-batching tier (PIMDAL-style
+        cross-query amortization — engine/dispatch.py module
+        docstring): same-StaticPlan dispatches over the same staged
+        table stack their query inputs along a leading batch axis and
+        execute as ONE vmapped launch reading the resident columns
+        once.
+
+        The key is (StaticPlan, staging token, input signature):
+        literal-bucketed program identity (``a>5`` and ``a>999`` build
+        the SAME StaticPlan — only their match tables/bounds differ) x
+        resident-table identity x structural input identity
+        (``_batch_shape``).  Built on every query: its launch closes
+        over the staged table's arrays of the moment."""
+        from pinot_tpu.engine.dispatch import BatchSpec
+
+        signature, max_members = batch_shape
+        key = (plan, staged.token, signature)
 
         def launch_batched(inputs_list):
             from pinot_tpu.engine.device import to_device_inputs
@@ -1458,20 +1677,24 @@ class QueryExecutor:
         digest: Optional[str] = None,
         cost: Optional[Dict[str, float]] = None,
         sharding=None,
+        placement=None,
     ) -> Dict[str, Any]:
         """Device-resident query-inputs cache: a repeated query (same
         plan, same literal tables) reuses the arrays already in HBM
         instead of re-uploading (each upload is a host->device
         transfer on the query's critical path).  Keyed by (plan, content digest,
         placement), so realtime watermark changes, different literals,
-        or a different chip group miss safely."""
+        or a different chip group miss safely.  ``placement`` is
+        ``placement_key(sharding)`` where the caller already has it."""
         from pinot_tpu.engine.device import placement_key, to_device_inputs
 
         if plan is None:
             return to_device_inputs(inputs, sharding=sharding)
         if digest is None:
             digest = self._inputs_digest(inputs)
-        key = (plan, digest, placement_key(sharding))
+        if placement is None:
+            placement = placement_key(sharding)
+        key = (plan, digest, placement)
         with self._qinput_cache_lock:
             cached = self._qinput_cache.get(key)
             if cached is not None:

@@ -298,7 +298,7 @@ def build_explain_node(
                 )
             # the bit-sliced kernel is a lane-registered device plan
             # like any scan: its digest must match what the real
-            # execution hands the lane (try_bitsliced_path), so the
+            # execution hands the lane (run_bitsliced_path), so the
             # compile timeline and poison lookups stay digest-exact
             pdigest = plan_digest(("bsi", _spec))
             lane = (

@@ -20,6 +20,7 @@ device scan — exactly the reference's operator-choice contract.
 from __future__ import annotations
 
 import os
+import weakref
 from typing import List, Optional
 
 import numpy as np
@@ -219,9 +220,43 @@ def try_index_path(
     sel_columns: Optional[List[str]],
 ) -> Optional[IntermediateResult]:
     """O(matches) host path, or None to take the device scan."""
-    decision, state = index_path_decision(request, live, ctx, total_docs)
+    _decision, state = index_path_decision(request, live, ctx, total_docs)
     if state is None:
         return None
+    return run_index_path(state, request, live, ctx, total_docs, sel_columns)
+
+
+def hold_state(state):
+    """A taken decision's hand-off in the form the executor keeps for a
+    repeated query.  The postings stay their segments' own
+    (``inverted_index`` caches them on the segment, ``release_postings``
+    drops them at unload, a consuming segment's snapshot takes its own
+    with it), so they are held weakly: a kept query must not keep an
+    unloaded segment's index alive."""
+    best, indexes, residuals, est = state
+    return best, [(weakref.ref(idx), t) for idx, t in indexes], residuals, est
+
+
+def held_state(kept):
+    """``hold_state`` undone, or None where a segment's postings have
+    been released since."""
+    best, refs, residuals, est = kept
+    indexes = [(ref(), t) for ref, t in refs]
+    if any(idx is None for idx, _ in indexes):
+        return None
+    return best, indexes, residuals, est
+
+
+def run_index_path(
+    state,
+    request: BrokerRequest,
+    live: List[ImmutableSegment],
+    ctx: TableContext,
+    total_docs: int,
+    sel_columns: Optional[List[str]],
+) -> IntermediateResult:
+    """Answer from the postings a taken ``index_path_decision`` handed
+    off in ``state`` (which the executor keeps for a repeated query)."""
     best, indexes, residuals, est = state
 
     def matched_rows(si: int, seg: ImmutableSegment) -> np.ndarray:

@@ -62,7 +62,7 @@ class ChipGroup:
         return max(1, len(self.devices))
 
     # NOTE: the group's NamedSharding is derived (and cached) by
-    # QueryExecutor._mesh_sharding, and placement identity by
+    # QueryExecutor._mesh_placement, and placement identity by
     # device.placement_key — ONE implementation each, shared by the
     # serving path, EXPLAIN, and the staging cache.
 

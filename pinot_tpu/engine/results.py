@@ -233,6 +233,9 @@ GroupKey = Tuple[str, ...]
 #   coalesceHits       queries served by riding an identical in-flight
 #                      device dispatch (engine/dispatch.py)
 #   qinputCacheHits    device-resident query-input cache hits
+#   preparedHit        queries whose tier verdicts, plan, query inputs
+#                      and block ids came from the executor's
+#                      prepared-query memo (engine/executor.py _Prepared)
 #   batchHits          queries that rode a cross-query batched launch
 #                      (literals stacked with same-plan peers into one
 #                      vmapped kernel — the lane micro-batching tier)
@@ -264,6 +267,7 @@ COST_KEYS = (
     "deviceBytes",
     "coalesceHits",
     "qinputCacheHits",
+    "preparedHit",
     "batchHits",
     "rescacheHits",
     "buildRows",

@@ -597,6 +597,17 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "stats registry",
     "plan.explains": "EXPLAIN plan requests answered without execution",
     "plan.digests": "distinct plan-shape digests currently tracked",
+    # the executor's prepared-query memo (engine/executor.py _Prepared):
+    # one mark a query that reached the tier ladder, so the three add up
+    # to those queries
+    "plan.prepared.hit": "queries whose tier verdicts, static plan, query "
+    "inputs and block ids were kept from an earlier query of the same text "
+    "over the same segments, placement and settings",
+    "plan.prepared.miss": "queries that derived them (and kept them)",
+    "plan.prepared.stale": "queries that found them kept against a staged "
+    "table that had since been demoted and staged anew, and derived the "
+    "device part again",
+    "plan.prepared.entries": "prepared queries currently kept",
     # which lowering a dense group-by's occupancy and sums took, one
     # mark a launch (engine/kernel.py groupby_lowering, marked where the
     # launch's laneDispatch span gets its ``groupby=`` tag)
