@@ -141,15 +141,103 @@ def group_sort_ascending(function: str) -> bool:
     return function in ("min", "minmv")
 
 
+# An aggregate's argument is an arithmetic expression over single-value
+# numeric columns whose simplest case is one column.  The tree is nested
+# tuples, hashable and with a deterministic repr, so that it can ride a
+# StaticPlan: ("col", name) | ("lit", float) | ("neg", x) |
+# ("+" | "-" | "*", left, right).
+EXPR_FUNCTIONS = ("sum", "avg")  # the aggregates that take a compound expression
+
+
+def expr_columns(expr: tuple) -> Tuple[str, ...]:
+    """The expression's leaf columns, each once, in the order written."""
+    if expr[0] == "col":
+        return (expr[1],)
+    out: List[str] = []
+    for child in expr[1:]:
+        if isinstance(child, tuple):
+            out.extend(c for c in expr_columns(child) if c not in out)
+    return tuple(out)
+
+
+def expr_map_columns(expr: tuple, fn) -> tuple:
+    """The same tree with ``fn(name)`` in place of every column name."""
+    if expr[0] == "col":
+        return ("col", fn(expr[1]))
+    return (expr[0],) + tuple(expr_map_columns(c, fn) if isinstance(c, tuple) else c for c in expr[1:])
+
+
+def _number_text(value: float) -> str:
+    return repr(int(value)) if value == int(value) and abs(value) < 1e15 else repr(value)
+
+
+def expr_text(expr: tuple) -> str:
+    """The canonical text: no blanks, the fewest parentheses that give
+    this tree back, numbers as Python writes a float (whole ones as
+    integers).  One tree, one text: it names the result column
+    (``sum_<text>``) and stands for the expression in every digest."""
+    op = expr[0]
+    if op == "col":
+        return expr[1]
+    if op == "lit":
+        return _number_text(expr[1])
+
+    def side(child: tuple, wrap: Tuple[str, ...]) -> str:
+        text = expr_text(child)
+        negative = child[0] == "lit" and child[1] < 0
+        return f"({text})" if child[0] in wrap or negative else text
+
+    if op == "neg":
+        return "-" + side(expr[1], ("+", "-", "*", "neg"))
+    if op == "*":
+        return side(expr[1], ("+", "-", "neg")) + "*" + side(expr[2], ("+", "-", "*", "neg"))
+    return side(expr[1], ()) + op + side(expr[2], ("+", "-", "neg"))
+
+
+def expr_eval(expr: tuple, column, literal=float):
+    """Evaluate the tree: ``column(name)`` gives a leaf's row values,
+    ``literal(value)`` a constant in the caller's precision; the caller's
+    arrays do the arithmetic (numpy float64 on the host, float32 in a
+    kernel's row loop)."""
+    op = expr[0]
+    if op == "col":
+        return column(expr[1])
+    if op == "lit":
+        return literal(expr[1])
+    if op == "neg":
+        return -expr_eval(expr[1], column, literal)
+    a, b = expr_eval(expr[1], column, literal), expr_eval(expr[2], column, literal)
+    return a + b if op == "+" else a - b if op == "-" else a * b
+
+
 @dataclass
 class AggregationInfo:
     """One aggregation call, e.g. sum(runs) (request.thrift AggregationInfo)."""
 
     function: str  # lower-cased, e.g. "sum", "distinctcounthll", "summv"
-    column: str  # "*" for count(*)
+    # "*" for count(*), a column's name, or a compound expression's
+    # canonical text (``expr_text``), which is what names the result
+    # column and tells two plan shapes apart
+    column: str
+    # the tree of a compound expression (``sum(a*(1-b))``); None where the
+    # argument is one column or ``*``
+    expr: Optional[tuple] = None
 
     def __post_init__(self) -> None:
         self.function = self.function.lower()
+
+    @property
+    def argument(self) -> Optional[tuple]:
+        """The argument as an expression tree, a column its simplest
+        case; None for ``*``."""
+        if self.expr is not None:
+            return self.expr
+        return None if self.column == "*" else ("col", self.column)
+
+    @property
+    def columns(self) -> Tuple[str, ...]:
+        """The physical columns the argument reads (none for ``*``)."""
+        return () if self.column == "*" else expr_columns(self.argument)
 
     @property
     def is_mv(self) -> bool:
@@ -271,7 +359,8 @@ class BrokerRequest:
             for node in self.filter.walk():
                 add(node.column)
         for agg in self.aggregations:
-            add(agg.column)
+            for c in agg.columns:
+                add(c)
         if self.group_by:
             for c in self.group_by.columns:
                 add(c)

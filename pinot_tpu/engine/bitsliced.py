@@ -168,6 +168,12 @@ def bitsliced_decision(
             agg_descs.append(("count", None))
             continue
         col = a.column
+        if a.expr is not None:
+            return {
+                "taken": False,
+                "reason": f"{base}({col}): an expression inside an aggregate "
+                "multiplies row values, which sums of bit planes cannot",
+            }, None
         if not all(s.has_column(col) for s in live):
             return {"taken": False, "reason": f"agg column {col!r} missing"}, None
         cols = [s.column(col) for s in live]

@@ -79,7 +79,7 @@ import threading
 import time
 import weakref
 from collections import deque
-from typing import Any, Callable, Deque, Dict, Hashable, List, Optional
+from typing import Any, Callable, Deque, Dict, Hashable, List, Optional, Tuple
 
 from pinot_tpu.engine import compilecache
 from pinot_tpu.utils.trace import boundary, measured
@@ -388,7 +388,7 @@ class _Dispatch:
     __slots__ = (
         "key", "launch", "pending", "waiters", "completed", "value",
         "error", "plan_digest", "cost_provider", "batch", "batch_size",
-        "t_submit", "trace", "parent", "program", "groupby", "operands", "launch_id",
+        "t_submit", "trace", "parent", "program", "groupby", "operands", "expr", "cells", "launch_id",
     )
 
     def __init__(
@@ -405,6 +405,8 @@ class _Dispatch:
         t_submit: float = 0.0,
         groupby: str = "",
         operands: str = "",
+        expr: int = 0,
+        cells: Tuple[int, int] = (0, 0),
     ) -> None:
         self.t_submit = t_submit  # phase.laneQueue runs from here to the launch call
         # the submitting query's span tree and the span (its laneWait)
@@ -414,6 +416,8 @@ class _Dispatch:
         self.program = program  # the jitted program's name, for the launch's tags
         self.groupby = groupby  # a group-by program's lowering (kernel.groupby_lowering)
         self.operands = operands  # and where its operands are built (kernel.groupby_operands)
+        self.expr = expr  # aggregates of the plan whose argument is a compound expression
+        self.cells = cells  # a group-by's K x m cells and the rows sharing saved (kernel.groupby_cells)
         self.launch_id: Optional[int] = None  # the physical launch this rode (occupancy)
         self.key = key
         self.launch = launch
@@ -589,6 +593,8 @@ class DeviceLane:
         program: str = "",
         groupby: str = "",
         operands: str = "",
+        expr: int = 0,
+        cells: Tuple[int, int] = (0, 0),
     ) -> LaneTicket:
         """Enqueue a kernel launch, or coalesce onto an identical one
         that is queued, launching, or still executing on device.
@@ -604,7 +610,10 @@ class DeviceLane:
         its ``groupby.lowering.*`` mark; ``operands``: where its operands
         are built (``kernel.groupby_operands``), the ``operands=`` tag, and
         one ``groupby.operands.loop`` mark a launch that builds them in
-        the row loop.
+        the row loop; ``cells``: its K x m cells (the ``cells=`` tag) and
+        the rows that sharing saved (``kernel.groupby_cells``; one
+        ``groupby.slots.shared`` mark a row); ``expr``: how many of the
+        plan's aggregates take a compound expression (the ``expr=`` tag).
 
         ``cost_provider`` (optional, utilization plane): a zero-arg
         callable returning the plan's static XLA cost analysis (or
@@ -644,7 +653,7 @@ class DeviceLane:
                 self._hit()
             else:
                 d = _Dispatch(key, launch, pending, plan_digest, cost_provider, batch,
-                              trace, parent, program, t_submit, groupby, operands)
+                              trace, parent, program, t_submit, groupby, operands, expr, cells)
                 d.waiters.append(ticket)
                 self._by_key[key] = d
                 self._depth_tick_locked()
@@ -1196,7 +1205,9 @@ class DeviceLane:
             # ``via`` says before the call whether this plan digest has
             # launched here ("first": it may compile) and after it how
             # the first launch got its executable.
-            tags = {"groupby": d.groupby, "operands": d.operands} if d.groupby else {}
+            tags = {"expr": d.expr} if d.expr else {}
+            if d.groupby:
+                tags = {"groupby": d.groupby, "operands": d.operands, "expr": d.expr, "cells": d.cells[0]}
             launching = boundary(
                 "laneDispatch", d.trace, launch_timer, parent=d.parent, program=d.program,
                 via="warm" if d.plan_digest is None or d.plan_digest in self._compile else "first",
@@ -1206,6 +1217,8 @@ class DeviceLane:
                 self.metrics.meter(f"groupby.lowering.{d.groupby}").mark()
                 if d.operands == "loop":
                     self.metrics.meter("groupby.operands.loop").mark()
+                if d.cells[1]:
+                    self.metrics.meter("groupby.slots.shared").mark(d.cells[1])
             self._set_inflight(1)
             error: Optional[BaseException] = None
             value: Any = None

@@ -69,6 +69,31 @@ def _table_bytes(tree) -> int:
     return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree) if isinstance(leaf, np.ndarray))
 
 
+def _check_expression_columns(request: BrokerRequest, seg: ImmutableSegment) -> None:
+    """An aggregate's compound expression reads single-value numeric
+    columns: anything else is refused by name, here where the schema is
+    known (the parser refuses what the text alone shows)."""
+    from pinot_tpu.pql.parser import PqlParseError
+
+    for a in request.aggregations:
+        if a.expr is None:
+            continue
+        for c in a.columns:
+            if c not in seg.columns:
+                continue  # the pruner drops a segment that lacks a column the query names
+            meta = seg.column(c).metadata
+            if not meta.single_value:
+                raise PqlParseError(
+                    f"an expression over a multi-value column is not supported "
+                    f"({c!r} in {a.function}({a.column}))"
+                )
+            if meta.data_type.stored_type == DataType.STRING:
+                raise PqlParseError(
+                    f"an expression over a column that is not numeric is not supported "
+                    f"({c!r} in {a.function}({a.column}))"
+                )
+
+
 class _Derived:
     """Derivations kept by name: ``once(name, derive)`` derives at the
     first ask and answers every later one with that value.  Two queries
@@ -302,6 +327,8 @@ class QueryExecutor:
         for outcome in ("hit", "miss", "stale"):
             metrics.meter(f"plan.prepared.{outcome}")
         metrics.gauge("plan.prepared.entries").set_fn(lambda: len(self._prepared))
+        for place in ("device", "host"):  # both from the start: a share needs the one that stays 0
+            metrics.meter(f"agg.expr.{place}")
         # self-healing state: device failures fail over to the host
         # path, and a (plan digest, segment set) that keeps failing on
         # device is quarantined so repeat offenders skip the device
@@ -625,6 +652,14 @@ class QueryExecutor:
                 self.metrics.meter(f"plan.prepared.{use.outcome}").mark()
         if use.outcome == "hit":
             result.add_cost(preparedHit=1)
+        n_expr = sum(1 for a in request.aggregations if a.expr is not None)
+        if n_expr:
+            # one mark a query whose plan holds an expression, by where it
+            # was answered: a device program, or a host tier (the postings
+            # tier, the forced host path, the failover)
+            on_device = getattr(result, "_served_tier", "") in ("device", "bitsliced")
+            self.metrics.meter("agg.expr.device" if on_device else "agg.expr.host").mark()
+            result.add_cost(exprAggs=n_expr)
         return result
 
     # -- the prepared-query memo ---------------------------------------
@@ -702,6 +737,7 @@ class QueryExecutor:
         prep = use.prepared
 
         def scope():
+            _check_expression_columns(request, live[0])
             needed = set(request.referenced_columns())
             sel_columns: Optional[List[str]] = None
             if request.is_selection:
@@ -1155,7 +1191,7 @@ class QueryExecutor:
         fast path (plan.py StaticLeaf) and which appear nowhere else in
         the query."""
         qualifying = self._docrange_qualifying_cols(request, live)
-        used_elsewhere = {a.column for a in request.aggregations}
+        used_elsewhere = {c for a in request.aggregations for c in a.columns}
         if request.is_group_by:
             used_elsewhere.update(request.group_by.columns)
         if request.is_selection:
@@ -1338,11 +1374,14 @@ class QueryExecutor:
 
         # scalar/pair agg inputs OUTSIDE raw_cols (small dictionaries)
         # read dict[fwd] on device — their base arrays must stay
+        # (an expression streams every leaf or gathers every leaf,
+        # plan.StaticAgg.use_raw)
         gather_agg_cols = {
-            a.column
+            c
             for a in request.aggregations
             if _agg_kind(a.base_function) in ("scalar", "pair")
-            and a.column not in raw_cols
+            and not set(a.columns) <= set(raw_cols)
+            for c in a.columns
         }
         return (
             set(raw_cols) | set(gfwd_cols) | set(hll_cols)
@@ -1389,12 +1428,14 @@ class QueryExecutor:
                 return False
             return seg.column(c).metadata.data_type.stored_type != DataType.STRING
 
+        # (every leaf of a compound expression is one, whatever its
+        # cardinality: the kernel multiplies row values, not dictionaries)
         raw_cols = {
-            a.column
+            c
             for a in request.aggregations
-            if numeric_any(a.column)
-            and big_card(a.column)
-            and _agg_kind(a.base_function) in ("scalar", "pair")
+            if _agg_kind(a.base_function) in ("scalar", "pair")
+            for c in a.columns
+            if numeric_any(c) and (a.expr is not None or big_card(c))
         }
         gfwd_cols = set()
         if request.is_group_by:
@@ -1535,10 +1576,15 @@ class QueryExecutor:
         # ``groupby=`` and ``operands=`` tags, its ``groupby.lowering.*``
         # mark and, built in the row loop, its ``groupby.operands.loop``
         # mark ("" for any other program)
-        from pinot_tpu.engine.kernel import groupby_lowering, groupby_operands
+        from pinot_tpu.engine.kernel import groupby_cells, groupby_lowering, groupby_operands
 
         groupby = groupby_lowering(plan) or ""
         operands = groupby_operands(plan) or ""
+        # its K x m cells and the rows sharing saved (the ``cells=`` tag,
+        # ``groupby.slots.shared``), and how many aggregates take a
+        # compound expression (the ``expr=`` tag)
+        cells = groupby_cells(plan) or (0, 0)
+        n_expr = sum(1 for a in getattr(plan, "aggs", ()) if getattr(a, "expr", None) is not None)
         coalesced = False
         ticket = None
         # planExec excludes lane queueing (timed as laneWait): it covers
@@ -1553,6 +1599,8 @@ class QueryExecutor:
                     self.metrics.meter(f"groupby.lowering.{groupby}").mark()
                 if operands == "loop":
                     self.metrics.meter("groupby.operands.loop").mark()
+                if cells[1]:
+                    self.metrics.meter("groupby.slots.shared").mark(cells[1])
                 fetch, handle = launch()
             else:
                 # coalesce key: identical (plan, staged-table token, inputs
@@ -1587,6 +1635,8 @@ class QueryExecutor:
                         program=program,
                         groupby=groupby,
                         operands=operands,
+                        expr=n_expr,
+                        cells=cells,
                     )
                     fetch, handle = ticket.result(deadline)
                     # the lane thread delivered -> this worker runs again

@@ -558,6 +558,14 @@ def build_explain_node(
         "estimatedCost": estimated,
         "generatedAtMs": round(time.time() * 1000, 3),
     }
+    expressions = [a for a in request.aggregations if a.expr is not None]
+    if expressions:
+        # arithmetic inside an aggregate, as the engine reads it: the
+        # canonical text (also the result column's name) and its leaves
+        node["expressions"] = [
+            {"aggregate": a.function, "expression": a.column, "columns": list(a.columns)}
+            for a in expressions
+        ]
     if device_info is not None:
         node["device"] = device_info
     return _json_safe(node)

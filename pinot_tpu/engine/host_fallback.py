@@ -35,6 +35,7 @@ from pinot_tpu.common.request import (
     BrokerRequest,
     FilterOperator,
     FilterQueryTree,
+    expr_eval,
     group_sort_ascending,
 )
 from pinot_tpu.common.values import render_value
@@ -196,13 +197,14 @@ def _vectorizable_aggs(
             if is_distinct:
                 return False  # distinctcount(*) has no gid column: per-row path
             continue
-        if a.column not in seg.columns:
-            return False
-        col = seg.column(a.column)
-        if not col.is_single_value:
-            return False
-        if not is_distinct and col.dictionary.stored_type.name == "STRING":
-            return False
+        for c in a.columns:  # an expression's leaves, or the one column
+            if c not in seg.columns:
+                return False
+            col = seg.column(c)
+            if not col.is_single_value:
+                return False
+            if not is_distinct and col.dictionary.stored_type.name == "STRING":
+                return False
     return True
 
 
@@ -214,6 +216,20 @@ def _decoded(cache: dict, c: str, blk: _Block) -> np.ndarray:
     if values is None:
         values = cache[blk.si, c] = np.asarray(col.dictionary.values, dtype=np.float64)
     return values[blk.take(col.fwd)]
+
+
+def _arguments(request: BrokerRequest) -> Dict[str, tuple]:
+    """Each aggregate's argument as an expression tree, by the name the
+    states are kept under (``AggregationInfo.column``: a column, or an
+    expression's canonical text)."""
+    return {a.column: a.argument for a in request.aggregations if a.column != "*"}
+
+
+def _argument_values(cache: dict, argument: tuple, blk: _Block) -> np.ndarray:
+    """An aggregate's argument at the block's matched rows, in float64:
+    a column's decoded values, or the expression over its leaves' (the
+    same tree the kernel evaluates in float32, kernel._row_values)."""
+    return expr_eval(argument, lambda c: _decoded(cache, c, blk))
 
 
 def _aggregation_vectorized(
@@ -237,6 +253,7 @@ def _aggregation_vectorized(
         if a.base_function in ("min", "max", "minmaxrange")
     }
     decoders: dict = {}
+    arguments = _arguments(request)
     col_sum = {c: 0.0 for c in needed}
     col_min = {c: float("inf") for c in ranged}
     col_max = {c: float("-inf") for c in ranged}
@@ -246,7 +263,7 @@ def _aggregation_vectorized(
         total += blk.n
         if blk.n:
             for c in needed:
-                vals = _decoded(decoders, c, blk)
+                vals = _argument_values(decoders, arguments[c], blk)
                 col_sum[c] += float(vals.sum())
                 if c in ranged:
                     col_min[c] = min(col_min[c], float(vals.min()))
@@ -415,6 +432,7 @@ def _groupby_vectorized(
     gid_cards = {c: max(ctx.column(c).global_cardinality, 1) for c in gid_columns}
     pairs = {c: _SparseGroups() for c in gid_columns}
     decoders: dict = {}
+    arguments = _arguments(request)
     # a (segment, group column)'s dictionary ids -> the column's digit of
     # the mixed-radix key, times the radix of the columns after it, in
     # int64: a block's keys are one gather a column, summed in place
@@ -434,7 +452,7 @@ def _groupby_vectorized(
             keys = digit(blk.si, 0)[blk.take(blk.seg.column(gb.columns[0]).fwd)]
             for j in range(1, len(gb.columns)):
                 keys += digit(blk.si, j)[blk.take(blk.seg.column(gb.columns[j]).fwd)]
-            groups.add(keys, {c: _decoded(decoders, c, blk) for c in val_columns})
+            groups.add(keys, {c: _argument_values(decoders, arguments[c], blk) for c in val_columns})
             for c in gid_columns:
                 gids = ctx.column(c).remaps[blk.si][blk.take(blk.seg.column(c).fwd)]
                 pairs[c].add(keys * gid_cards[c] + gids.astype(np.int64), {})

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pinot_tpu.common.request import expr_eval
 from pinot_tpu.engine import config
 from pinot_tpu.engine.plan import MV_ANY, MV_NONE, SV, StaticAgg, StaticPlan
 
@@ -143,19 +144,48 @@ def _sum_shaped(agg: StaticAgg) -> bool:
 def _contraction_slots(plan: StaticPlan) -> Tuple[Dict[int, List[int]], int]:
     """Which rows of a dense group-by's float states [m, K] each
     sum-shaped aggregate reads, and m.  Row 0 is the occupancy (the
-    validity column), which is the plain count's weights exactly."""
+    validity column), which is the plain count's weights exactly.
+
+    A row belongs to what it sums, not to the aggregate that asks: the
+    sum of one argument (a column, or an expression's canonical text) is
+    one row for every ``sum`` and ``avg`` over it, a single-value
+    ``avg``'s count is row 0, and a multi-value column's entry count is
+    one row for its ``countmv`` and its ``avgmv``.  Rows are numbered in
+    the order their first reader stands in the plan, an aggregate's sum
+    before its count (_contraction_operands builds them in that order)."""
     slots: Dict[int, List[int]] = {}
-    m = 1
+    row_of: Dict[tuple, int] = {}
+
+    def row(*what) -> int:
+        return row_of.setdefault(what, 1 + len(row_of))
+
     for i, agg in enumerate(plan.aggs):
         if not _sum_shaped(agg):
             continue
-        if agg.base == "count" and not agg.is_mv:
-            slots[i] = [0]
+        source = (agg.column, agg.is_mv, agg.use_raw)
+        if agg.base == "count":
+            slots[i] = [row("entries", *source) if agg.is_mv else 0]
             continue
-        width = 2 if agg.base == "avg" else 1
-        slots[i] = list(range(m, m + width))
-        m += width
-    return slots, m
+        slots[i] = [row("sum", *source)]
+        if agg.base == "avg":
+            slots[i].append(row("entries", *source) if agg.is_mv else 0)
+    return slots, 1 + len(row_of)
+
+
+def groupby_cells(plan: StaticPlan) -> Optional[Tuple[int, int]]:
+    """(K x m cells of a dense group-by's float states, rows that sharing
+    saved: what one row an aggregate and two an ``avg`` would take, less
+    m), the launch's ``cells=`` tag and ``groupby.slots.shared`` marks.
+    None for a plan without a group-by."""
+    if getattr(plan, "group_by", None) is None:
+        return None
+    m = _contraction_slots(plan)[1]
+    unshared = 1 + sum(
+        0 if agg.base == "count" and not agg.is_mv else 2 if agg.base == "avg" else 1
+        for agg in plan.aggs
+        if _sum_shaped(agg)
+    )
+    return plan.group_by.capacity * m, unshared - m
 
 
 # The row loop answers a group-by of up to this many (group, column)
@@ -535,11 +565,17 @@ def _row_values(agg: StaticAgg, seg, mask):
         mv = seg[f"{agg.column}.mv"]
         vals = seg[f"{agg.column}.dict"][mv]
         return vals, mvv
-    if agg.use_raw:
-        return seg[f"{agg.column}.raw"], mask  # streamed, no gather
-    fwd = seg[f"{agg.column}.fwd"]
-    vals = seg[f"{agg.column}.dict"][fwd]
-    return vals, mask
+
+    def column(name: str):
+        if agg.use_raw:
+            return seg[f"{name}.raw"]  # streamed, no gather
+        return seg[f"{name}.dict"][seg[f"{name}.fwd"]]
+
+    # the argument is an expression whose simplest case is one column:
+    # products and sums of the leaves' values in the float dtype, fused
+    # into the reduction that reads them (a python constant is weakly
+    # typed and takes the arrays' precision)
+    return expr_eval(agg.argument, column), mask
 
 
 def _agg_state(agg: StaticAgg, i: int, seg, q, mask) -> Any:
@@ -918,9 +954,12 @@ def _contraction_operands(plan: StaticPlan, seg, mask, keys, kvalid, zeroed: boo
     fvalid = kvalid.reshape(-1)
     cols = [fvalid.astype(config.float_dtype())]
     for i, rows in _contraction_slots(plan)[0].items():
-        if rows != [0]:
-            w = _group_add_weights(plan.aggs[i], seg, mask, kvalid)
-            cols.extend(jnp.where(fvalid, vec, 0) if zeroed else vec for vec in w)
+        if max(rows) < len(cols):
+            continue  # every row it reads is built: the occupancy, or another aggregate's
+        w = _group_add_weights(plan.aggs[i], seg, mask, kvalid)
+        for r, vec in zip(rows, w):
+            if r == len(cols):
+                cols.append(jnp.where(fvalid, vec, 0) if zeroed else vec)
     return flat_idx, cols
 
 

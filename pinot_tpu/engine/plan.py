@@ -25,7 +25,7 @@ Filter leaf modes:
 from __future__ import annotations
 
 import re
-from dataclasses import dataclass, replace
+from dataclasses import dataclass, field, replace
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -76,7 +76,11 @@ class StaticLeaf:
 class StaticAgg:
     func: str  # full function name e.g. "sum", "summv"
     base: str  # base function e.g. "sum"
-    column: str  # "*" for count(*)
+    # "*" for count(*), a column, or a compound expression's canonical
+    # text (common/request.py expr_text): the text, constants and all,
+    # is of the plan's shape, so that two queries that differ in one
+    # operator or one constant share no program and no digest
+    column: str
     is_mv: bool
     # device state kind: scalar | pair | presence | hist | hll
     kind: str
@@ -99,6 +103,15 @@ class StaticAgg:
     # tables — bit-identical registers at a fraction of the FLOPs of
     # the direct (group, bucket, rho) contraction (K = cap * 16384)
     hll_from_presence: bool = False
+    # the tree of a compound expression under sum or avg, evaluated per
+    # row from its leaf columns (kernel._row_values); None for one column.
+    # Not in the repr, which ``column`` already tells apart: a plan
+    # without an expression keeps the digest and the program name it had
+    expr: Optional[tuple] = field(default=None, repr=False)
+
+    @property
+    def argument(self) -> tuple:
+        return self.expr if self.expr is not None else ("col", self.column)
 
 
 @dataclass(frozen=True)
@@ -431,12 +444,14 @@ def build_static_plan(
                 # run-length counts cover exact percentile histograms
                 sort_pairs = True
         is_mv = a.is_mv
-        if a.column != "*" and not staged.column(a.column).single_value:
+        if any(not staged.column(c).single_value for c in a.columns):
             is_mv = True
+        # every leaf of the argument streams its staged raw values, or
+        # every leaf gathers dict[fwd] (executor._role_columns)
         use_raw = (
             a.column != "*"
             and not is_mv
-            and staged.column(a.column).raw is not None
+            and all(staged.column(c).raw is not None for c in a.columns)
         )
         aggs.append(
             StaticAgg(
@@ -449,6 +464,7 @@ def build_static_plan(
                 use_raw=use_raw,
                 sort_pairs=sort_pairs,
                 hll_from_presence=hll_from_presence,
+                expr=a.expr,
             )
         )
 

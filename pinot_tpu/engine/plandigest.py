@@ -47,7 +47,16 @@ def _filter_shape(node: Optional[FilterQueryTree]) -> Optional[tuple]:
 
 
 def plan_shape(request: BrokerRequest) -> tuple:
-    """The hashable literal-erased shape tuple (deterministic repr)."""
+    """The hashable literal-erased shape tuple (deterministic repr).
+
+    An aggregate's argument is of the shape whole: ``a.column`` is a
+    column's name or an expression's canonical text
+    (``common/request.py expr_text``), its constants with it.  A filter's
+    literal is an input of the compiled program and is erased here; a
+    constant inside ``sum(a*(1-b))`` is compiled into the program
+    (``StaticAgg.column``), so two queries that differ in one operator
+    or one constant are two shapes, two programs, two prepared entries
+    and two result-cache keys."""
     aggs = tuple((a.function, a.column) for a in request.aggregations)
     gb = None
     if request.is_group_by:

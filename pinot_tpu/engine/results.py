@@ -236,6 +236,9 @@ GroupKey = Tuple[str, ...]
 #   preparedHit        queries whose tier verdicts, plan, query inputs
 #                      and block ids came from the executor's
 #                      prepared-query memo (engine/executor.py _Prepared)
+#   exprAggs           aggregates of the query whose argument is a
+#                      compound expression (sum(a*(1-b))), on whatever
+#                      tier answered (meters agg.expr.device|host)
 #   batchHits          queries that rode a cross-query batched launch
 #                      (literals stacked with same-plan peers into one
 #                      vmapped kernel — the lane micro-batching tier)
@@ -268,6 +271,7 @@ COST_KEYS = (
     "coalesceHits",
     "qinputCacheHits",
     "preparedHit",
+    "exprAggs",
     "batchHits",
     "rescacheHits",
     "buildRows",

@@ -4,9 +4,18 @@ Implements the language defined by the reference grammar
 (pinot-common ``src/main/antlr4/.../PQL2.g4``) with a hand-written
 tokenizer + recursive-descent parser (no ANTLR dependency):
 
-    SELECT [TOP n] (* | col|agg(col) [, ...]) FROM table
+    SELECT [TOP n] (* | col|agg(expr) [, ...]) FROM table
       [WHERE predicates] [GROUP BY cols] [HAVING pred]
       [ORDER BY col [ASC|DESC], ...] [TOP n] [LIMIT n[, m]]
+
+An aggregate's argument is ``*``, a column, or under ``sum`` and ``avg``
+an arithmetic expression over single-value numeric columns and numeric
+literals: ``+``, ``-``, ``*``, unary minus, parentheses
+(``sum(l_extendedprice*(1-l_discount)*(1+l_tax))``, TPC-H Q1).  Between
+an aggregate's parentheses ``-`` is always a subtraction; a column whose
+name holds one is written in double quotes there.  Everything else is
+refused by name: division, an expression under any other function, in
+WHERE, GROUP BY or HAVING, or in a join query.
 
 Predicates: ``=  <>  !=  <  >  <=  >=``, ``BETWEEN a AND b``,
 ``[NOT] IN (v, ...)``, ``REGEXP_LIKE(col, 'pattern')``, combined with
@@ -22,6 +31,7 @@ from typing import List, Optional, Tuple
 
 from pinot_tpu.common.request import (
     AGGREGATION_FUNCTIONS,
+    EXPR_FUNCTIONS,
     AggregationInfo,
     BrokerRequest,
     FilterOperator,
@@ -32,6 +42,9 @@ from pinot_tpu.common.request import (
     RangeSpec,
     Selection,
     SelectionSort,
+    expr_columns,
+    expr_map_columns,
+    expr_text,
 )
 
 
@@ -54,7 +67,20 @@ _TOKEN_RE = re.compile(
     | (?P<number>[-+]?(\d+\.\d*|\.\d+|\d+)([eE][-+]?\d+)?)
     | (?P<string>'(?:[^']|'')*'|"(?:[^"]|"")*")
     | (?P<ident>[A-Za-z_][A-Za-z0-9_\-]*)
-    | (?P<op><>|<=|>=|!=|[=<>(),.;*])
+    | (?P<op><>|<=|>=|!=|[=<>(),.;*+\-/])
+    """,
+    re.VERBOSE,
+)
+
+# the tokens of an aggregate's argument, where ``-`` is an operator and a
+# name holds none: the text between the parentheses is read again
+_EXPR_TOKEN_RE = re.compile(
+    r"""
+      (?P<ws>\s+)
+    | (?P<number>(\d+\.\d*|\.\d+|\d+)([eE][-+]?\d+)?)
+    | (?P<string>'(?:[^']|'')*'|"(?:[^"]|"")*")
+    | (?P<ident>[A-Za-z_][A-Za-z0-9_]*)
+    | (?P<op>[-+*/().,])
     """,
     re.VERBOSE,
 )
@@ -71,14 +97,16 @@ class Token:
         return self.text.upper()
 
 
-def _tokenize(pql: str) -> List[Token]:
+def _tokenize(pql: str, token_re=_TOKEN_RE, start: int = 0, end: Optional[int] = None, where: str = "") -> List[Token]:
+    """The tokens of ``pql[start:end]`` under ``token_re``, then ``eof``;
+    ``where`` names the place in a refusal (" inside sum(")."""
     tokens: List[Token] = []
-    pos = 0
-    n = len(pql)
-    while pos < n:
-        m = _TOKEN_RE.match(pql, pos)
+    pos = start
+    end = len(pql) if end is None else end
+    while pos < end:
+        m = token_re.match(pql, pos, end)
         if m is None:
-            raise PqlParseError(f"unexpected character {pql[pos]!r} at position {pos}")
+            raise PqlParseError(f"unexpected character {pql[pos]!r}{where} at position {pos}")
         pos = m.end()
         kind = m.lastgroup
         if kind in ("ws", "comment"):
@@ -88,12 +116,99 @@ def _tokenize(pql: str) -> List[Token]:
             quote = text[0]
             text = text[1:-1].replace(quote * 2, quote)
         tokens.append(Token(kind=kind, text=text, pos=m.start()))
-    tokens.append(Token(kind="eof", text="", pos=n))
+    tokens.append(Token(kind="eof", text="", pos=end))
     return tokens
+
+
+class _ExprParser:
+    """An aggregate's argument: ``term (('+'|'-') term)*``, ``term :=
+    unary ('*' unary)*``, ``unary := '-' unary | '+' unary | primary``,
+    ``primary := number | column | "column" | '(' expr ')'``."""
+
+    def __init__(self, tokens: List[Token], func: str) -> None:
+        self.tokens, self.i, self.func = tokens, 0, func
+
+    def peek(self) -> Token:
+        return self.tokens[self.i]
+
+    def take(self) -> Token:
+        t = self.tokens[self.i]
+        self.i += t.kind != "eof"
+        return t
+
+    def accept(self, *ops: str) -> Optional[Token]:
+        t = self.peek()
+        return self.take() if t.kind == "op" and t.text in ops else None
+
+    def parse(self) -> tuple:
+        expr = self.sum_()
+        t = self.peek()
+        if t.kind != "eof":
+            self.unexpected(t)
+        return expr
+
+    def unexpected(self, t: Token):
+        if t.kind == "op" and t.text == "/":
+            raise PqlParseError(
+                f"division inside an aggregate is not supported (position {t.pos}): "
+                "an expression takes +, -, * and parentheses"
+            )
+        raise PqlParseError(f"unexpected {t.text!r} inside {self.func}( at position {t.pos}")
+
+    def sum_(self) -> tuple:
+        left = self.term()
+        while True:
+            op = self.accept("+", "-")
+            if op is None:
+                return left
+            left = (op.text, left, self.term())
+
+    def term(self) -> tuple:
+        left = self.unary()
+        while self.accept("*"):
+            left = ("*", left, self.unary())
+        return left
+
+    def unary(self) -> tuple:
+        if self.accept("+"):
+            return self.unary()
+        if self.accept("-"):
+            child = self.unary()
+            return ("lit", -child[1]) if child[0] == "lit" else ("neg", child)
+        return self.primary()
+
+    def primary(self) -> tuple:
+        t = self.take()
+        if t.kind == "number":
+            return ("lit", float(t.text))
+        if t.kind == "string":
+            return ("col", t.text)
+        if t.kind == "ident":
+            if self.accept("("):
+                raise PqlParseError(
+                    f"a function call inside an aggregate is not supported ({t.text}( at position {t.pos})"
+                )
+            name = t.text
+            if self.accept("."):
+                # ``alias.col``: a join query's column, resolved to its side later
+                nxt = self.take()
+                if nxt.kind != "ident":
+                    self.unexpected(nxt)
+                name += "." + nxt.text
+            return ("col", name)
+        if t.kind == "op" and t.text == "(":
+            inner = self.sum_()
+            if not self.accept(")"):
+                self.unexpected(self.peek())
+            return inner
+        if t.kind == "eof":
+            raise PqlParseError(f"expected a column or a number inside {self.func}( at position {t.pos}")
+        self.unexpected(t)
 
 
 class _Parser:
     def __init__(self, pql: str) -> None:
+        self.pql = pql
         self.tokens = _tokenize(pql)
         self.i = 0
 
@@ -130,6 +245,16 @@ class _Parser:
         if t is None:
             raise PqlParseError(f"expected {op!r} at position {self.peek().pos}, got {self.peek().text!r}")
         return t
+
+    def refuse_arithmetic(self, where: str) -> None:
+        """A named refusal where a clause that takes columns meets an
+        arithmetic operator."""
+        t = self.peek()
+        if t.kind == "op" and t.text in ("+", "-", "*", "/"):
+            raise PqlParseError(
+                f"an expression in {where} is not supported (got {t.text!r} at position "
+                f"{t.pos}): arithmetic is taken inside sum() and avg() only"
+            )
 
     def expect_ident(self) -> Token:
         t = self.peek()
@@ -193,8 +318,10 @@ class _Parser:
                 self.next()
                 self.expect_kw("BY")
                 group_by_cols = [self._column_token()]
+                self.refuse_arithmetic("GROUP BY")
                 while self.accept_op(","):
                     group_by_cols.append(self._column_token())
+                    self.refuse_arithmetic("GROUP BY")
             elif self.accept_kw("HAVING"):
                 having = self._having()
             elif self.peek().upper == "ORDER":
@@ -250,6 +377,12 @@ class _Parser:
                 size=size if size is not None else 10,
             )
         if join is not None:
+            for a in aggregations:
+                if a.expr is not None:
+                    raise PqlParseError(
+                        f"an expression inside an aggregate is not supported in a join query "
+                        f"(got {a.function}({a.column}))"
+                    )
             _resolve_join_columns(req, join, join_aliases)
         else:
             _reject_qualified_columns(req)
@@ -357,21 +490,51 @@ class _Parser:
             func = t.text.lower()
             self.expect_op("(")
             if self.accept_op("*"):
-                col = "*"
+                col, expr = "*", None
+                self.expect_op(")")
             else:
-                col = self._column_token()
-            self.expect_op(")")
+                col, expr = self._aggregate_argument(func)
             if self.accept_kw("AS"):
                 self.next()  # alias ignored (reference keeps function_col naming)
             if func not in AGGREGATION_FUNCTIONS:
                 raise PqlParseError(f"unknown aggregation function {func!r}")
-            return AggregationInfo(function=func, column=col)
+            return AggregationInfo(function=func, column=col, expr=expr)
         name = t.text
         if self.accept_op("."):
             name += "." + self.expect_ident().text
         if self.accept_kw("AS"):
             self.next()
         return name
+
+    def _aggregate_argument(self, func: str) -> Tuple[str, Optional[tuple]]:
+        """The argument of ``func(`` up to and with its ``)``: (the name
+        of the result's column, the tree of a compound expression or
+        None where the argument is one column)."""
+        start, depth = self.peek().pos, 0
+        while True:
+            t = self.next()
+            if t.kind == "eof":
+                raise PqlParseError(f"expected ')' to close {func}( at position {t.pos}")
+            if t.kind == "op" and t.text == ")":
+                if depth == 0:
+                    break
+                depth -= 1
+            elif t.kind == "op" and t.text == "(":
+                depth += 1
+        # the statement's tokenizer reads ``a-b`` as one name and ``-1`` as
+        # one number: here every sign is an operator
+        pieces = _tokenize(self.pql, _EXPR_TOKEN_RE, start, t.pos, where=f" inside {func}(")
+        expr = _ExprParser(pieces, func).parse()
+        if expr[0] == "col":
+            return expr[1], None
+        if not expr_columns(expr):
+            raise PqlParseError(f"{func}({expr_text(expr)}) reads no column: an aggregate's argument names at least one")
+        if func not in EXPR_FUNCTIONS:
+            raise PqlParseError(
+                f"an expression inside {func}() is not supported: only "
+                f"{' and '.join(EXPR_FUNCTIONS)} take one (got {func}({expr_text(expr)}))"
+            )
+        return expr_text(expr), expr
 
     def _table_name(self) -> str:
         t = self.peek()
@@ -431,6 +594,7 @@ class _Parser:
         column = t.text
         if self.accept_op("."):
             column += "." + self.expect_ident().text
+        self.refuse_arithmetic("WHERE")
         if self.accept_kw("BETWEEN"):
             lo = self._literal()
             self.expect_kw("AND")
@@ -479,6 +643,7 @@ class _Parser:
             col = "*"
         else:
             col = self._column_token()
+            self.refuse_arithmetic("HAVING")
         self.expect_op(")")
         op = self.accept_op("=", "<>", "!=", "<", ">", "<=", ">=")
         if op is None:
@@ -511,7 +676,11 @@ def _rewrite_request_columns(req: BrokerRequest, fn) -> None:
             if node.is_leaf:
                 node.column = f(node.column)
     for a in req.aggregations:
-        a.column = f(a.column)
+        if a.expr is not None:
+            a.expr = expr_map_columns(a.expr, f)
+            a.column = expr_text(a.expr)
+        else:
+            a.column = f(a.column)
     if req.group_by is not None:
         req.group_by.columns = [f(c) for c in req.group_by.columns]
     if req.selection is not None:

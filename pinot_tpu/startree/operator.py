@@ -106,7 +106,8 @@ def is_fit_for_star_tree(request: BrokerRequest, segment: ImmutableSegment) -> b
     if tree is None or not request.is_aggregation:
         return False
     for agg in request.aggregations:
-        if agg.is_mv:
+        if agg.is_mv or agg.expr is not None:
+            # (a cube of single-metric sums cannot multiply two metrics)
             return False
         base = agg.base_function
         if base in ("distinctcounthll", "fasthll"):

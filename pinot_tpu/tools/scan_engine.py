@@ -38,6 +38,7 @@ from pinot_tpu.common.request import (
     FilterOperator,
     FilterQueryTree,
     RangeSpec,
+    expr_eval,
     group_sort_ascending,
 )
 from pinot_tpu.common.response import (
@@ -146,6 +147,8 @@ def _build_matcher(tree: Optional[FilterQueryTree], schema: Schema):
 
 
 def _numeric_values(row: Row, agg: AggregationInfo) -> List[float]:
+    if agg.expr is not None:  # sum(a*(1-b)): one value a row, from single-value columns
+        return [float(expr_eval(agg.expr, lambda c: float(row[c])))]
     vals = _values_of(row, agg.column)
     return [float(v) for v in vals]
 

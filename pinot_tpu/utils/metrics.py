@@ -621,6 +621,18 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "groupby.operands.loop": "group-by launches whose filter mask, key "
     "and weight columns are built inside the group-by's row loop "
     "(engine/kernel.py groupby_operands; the launch's ``operands=`` tag)",
+    "groupby.slots.shared": "rows of a dense group-by's float states "
+    "that its aggregates share (a sum read by sum and avg, an avg's count "
+    "on the occupancy row), one mark a row a launch (engine/kernel.py "
+    "groupby_cells; the launch's ``cells=`` tag gives K x m)",
+    # arithmetic inside an aggregate (sum(a*(1-b))): one mark a query
+    # whose plan holds a compound expression, by where it was answered
+    "agg.expr.device": "queries with an expression inside an aggregate "
+    "answered by a device program (the expression is evaluated in the "
+    "kernel's row loop, engine/kernel.py _row_values)",
+    "agg.expr.host": "queries with an expression inside an aggregate "
+    "answered by a host tier (postings, forced host path, failover), in "
+    "float64 (engine/host_fallback.py)",
     # compile timeline (engine/dispatch.py lane registry): first-call
     # launch of a device-plan digest pays trace + XLA compile
     "compile.cold": "device-plan digests launched for the first time "

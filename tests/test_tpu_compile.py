@@ -59,8 +59,12 @@ def test_radix_contraction_compiles_for_v5e(one_chip, monkeypatch, shape):
     assert compiled.memory_analysis().temp_size_in_bytes <= (m + 1) * S * n * 4 + (64 << 20)
 
 
-# the open cell's two group-bys (benchmark/traffic/suite_open.json: k6, q6)
+# the open cell's two group-bys (benchmark/traffic/suite_open.json: k6, q6) and TPC-H Q1 as the
+# specification writes it (benchmark/traffic/tpch_q1q6_closed.json: 36 cells, two products a row)
 SUITE_GROUPBYS = {
+    "q1_spec": "SELECT sum(l_quantity), sum(l_extendedprice), sum(l_extendedprice*(1-l_discount)), "
+               "sum(l_extendedprice*(1-l_discount)*(1+l_tax)), avg(l_quantity), avg(l_extendedprice), avg(l_discount), "
+               "count(*) FROM lineitem WHERE l_shipdate <= '1998-09-02' GROUP BY l_returnflag, l_linestatus TOP 10",
     "k6": "SELECT sum(l_quantity), sum(l_extendedprice), sum(l_discount), count(*) FROM lineitem "
           "WHERE l_shipdate <= '1998-09-02' GROUP BY l_returnflag, l_linestatus TOP 10",
     "q6": "SELECT sum(l_extendedprice) FROM lineitem WHERE l_shipmode IN ('RAIL','FOB') AND "
