@@ -388,7 +388,7 @@ class _Dispatch:
     __slots__ = (
         "key", "launch", "pending", "waiters", "completed", "value",
         "error", "plan_digest", "cost_provider", "batch", "batch_size",
-        "t_submit", "trace", "parent", "program", "groupby", "operands", "expr", "cells", "launch_id",
+        "t_submit", "trace", "parent", "program", "groupby", "operands", "expr", "cells", "blocks", "launch_id",
     )
 
     def __init__(
@@ -407,6 +407,7 @@ class _Dispatch:
         operands: str = "",
         expr: int = 0,
         cells: Tuple[int, int] = (0, 0),
+        blocks: str = "",
     ) -> None:
         self.t_submit = t_submit  # phase.laneQueue runs from here to the launch call
         # the submitting query's span tree and the span (its laneWait)
@@ -418,6 +419,7 @@ class _Dispatch:
         self.operands = operands  # and where its operands are built (kernel.groupby_operands)
         self.expr = expr  # aggregates of the plan whose argument is a compound expression
         self.cells = cells  # a group-by's K x m cells and the rows sharing saved (kernel.groupby_cells)
+        self.blocks = blocks  # how a zone-tier program reads its candidate blocks (kernel.zone_blocks)
         self.launch_id: Optional[int] = None  # the physical launch this rode (occupancy)
         self.key = key
         self.launch = launch
@@ -595,6 +597,7 @@ class DeviceLane:
         operands: str = "",
         expr: int = 0,
         cells: Tuple[int, int] = (0, 0),
+        blocks: str = "",
     ) -> LaneTicket:
         """Enqueue a kernel launch, or coalesce onto an identical one
         that is queued, launching, or still executing on device.
@@ -613,7 +616,11 @@ class DeviceLane:
         the row loop; ``cells``: its K x m cells (the ``cells=`` tag) and
         the rows that sharing saved (``kernel.groupby_cells``; one
         ``groupby.slots.shared`` mark a row); ``expr``: how many of the
-        plan's aggregates take a compound expression (the ``expr=`` tag).
+        plan's aggregates take a compound expression (the ``expr=`` tag);
+        ``blocks``: how a zone-tier program reads its candidate blocks
+        (``kernel.zone_blocks``), the ``blocks=`` tag and one
+        ``zone.blocks.inplace`` or ``zone.blocks.gathered`` mark a launch
+        ("" for any other program).
 
         ``cost_provider`` (optional, utilization plane): a zero-arg
         callable returning the plan's static XLA cost analysis (or
@@ -653,7 +660,7 @@ class DeviceLane:
                 self._hit()
             else:
                 d = _Dispatch(key, launch, pending, plan_digest, cost_provider, batch,
-                              trace, parent, program, t_submit, groupby, operands, expr, cells)
+                              trace, parent, program, t_submit, groupby, operands, expr, cells, blocks)
                 d.waiters.append(ticket)
                 self._by_key[key] = d
                 self._depth_tick_locked()
@@ -1208,6 +1215,8 @@ class DeviceLane:
             tags = {"expr": d.expr} if d.expr else {}
             if d.groupby:
                 tags = {"groupby": d.groupby, "operands": d.operands, "expr": d.expr, "cells": d.cells[0]}
+            if d.blocks:
+                tags["blocks"] = d.blocks
             launching = boundary(
                 "laneDispatch", d.trace, launch_timer, parent=d.parent, program=d.program,
                 via="warm" if d.plan_digest is None or d.plan_digest in self._compile else "first",
@@ -1219,6 +1228,8 @@ class DeviceLane:
                     self.metrics.meter("groupby.operands.loop").mark()
                 if d.cells[1]:
                     self.metrics.meter("groupby.slots.shared").mark(d.cells[1])
+            if d.blocks and self.metrics is not None:
+                self.metrics.meter(f"zone.blocks.{d.blocks}").mark()
             self._set_inflight(1)
             error: Optional[BaseException] = None
             value: Any = None

@@ -1241,10 +1241,15 @@ class QueryExecutor:
         """Zone-map block pruning decision (engine/zonemap.py): returns
         (block_ids [S, nb_pad] or None, candidate_rows or None).
 
-        Engages when the candidate set is under half the table — below
-        that the gather overhead beats the full scan it saves.  On a
-        mesh, the ids array shards over the segment axis like every
-        other per-segment input (nb_pad is a global bucket)."""
+        Engages when the candidate blocks, padded to a power of two,
+        are at most half the table.  The gate dates from the gathered
+        view, whose copy made Q5 (47 blocks of 128) and TPC-H Q6 (22 of
+        128) dearer than the full scan they skip (chip runs, PR 28 and
+        PR 34); an 'inplace' plan (kernel.zone_blocks) no longer pays
+        that, so half is now known to be low for it and waits for a cell
+        with selective traffic to be moved (ROADMAP S6).  On a mesh, the
+        ids array shards over the segment axis like every other
+        per-segment input (nb_pad is a global bucket)."""
         import os
 
         if os.environ.get("PINOT_TPU_ZONEMAP") == "0":
@@ -1575,11 +1580,14 @@ class QueryExecutor:
         # from the functions the kernel builder asks: the launch's
         # ``groupby=`` and ``operands=`` tags, its ``groupby.lowering.*``
         # mark and, built in the row loop, its ``groupby.operands.loop``
-        # mark ("" for any other program)
-        from pinot_tpu.engine.kernel import groupby_cells, groupby_lowering, groupby_operands
+        # mark ("" for any other program); and how a zone-tier program
+        # reads its candidate blocks: the ``blocks=`` tag and the
+        # ``zone.blocks.*`` mark
+        from pinot_tpu.engine.kernel import groupby_cells, groupby_lowering, groupby_operands, zone_blocks
 
         groupby = groupby_lowering(plan) or ""
         operands = groupby_operands(plan) or ""
+        blocks = zone_blocks(plan) if block_ids is not None else ""
         # its K x m cells and the rows sharing saved (the ``cells=`` tag,
         # ``groupby.slots.shared``), and how many aggregates take a
         # compound expression (the ``expr=`` tag)
@@ -1601,6 +1609,8 @@ class QueryExecutor:
                     self.metrics.meter("groupby.operands.loop").mark()
                 if cells[1]:
                     self.metrics.meter("groupby.slots.shared").mark(cells[1])
+                if blocks:
+                    self.metrics.meter(f"zone.blocks.{blocks}").mark()
                 fetch, handle = launch()
             else:
                 # coalesce key: identical (plan, staged-table token, inputs
@@ -1637,6 +1647,7 @@ class QueryExecutor:
                         operands=operands,
                         expr=n_expr,
                         cells=cells,
+                        blocks=blocks,
                     )
                     fetch, handle = ticket.result(deadline)
                     # the lane thread delivered -> this worker runs again

@@ -1373,9 +1373,9 @@ def _gather_blocks(seg: Dict[str, Any], ids: jnp.ndarray, block: int):
 
 
 def make_single_segment_block_kernel(plan: StaticPlan, block: int) -> Callable:
-    """Single-segment kernel over a gathered subset of row blocks —
-    the zone-map skipping path (engine/zonemap.py): work is
-    O(candidate blocks), not O(n)."""
+    """Single-segment kernel over a gathered copy of the candidate row
+    blocks: the zone tier's form for a plan whose outputs need the view
+    whole (zone_blocks 'gathered')."""
     single = make_single_segment_kernel(plan)
 
     def kernel(seg: Dict[str, Any], q: Dict[str, Any], ids: jnp.ndarray):
@@ -1419,20 +1419,6 @@ def named(fn: Callable, name: str) -> Callable:
     """``fn`` under ``name``, for ``jax.jit`` to name its module after."""
     fn.__name__ = fn.__qualname__ = name
     return fn
-
-
-@functools.lru_cache(maxsize=256)
-def make_block_table_kernel(plan: StaticPlan, block: int) -> Callable:
-    """vmapped + jitted block-skipping variant of make_table_kernel;
-    extra input: block ids int32 [S, nb_pad] (-1 padded)."""
-    single = make_single_segment_block_kernel(plan, block)
-    reducers = output_reducers(plan)
-
-    def table_fn(segs, q, ids):
-        outs = jax.vmap(single)(segs, q, ids)
-        return {k: apply_reduce(reducers[k], v) for k, v in outs.items()}
-
-    return jax.jit(named(table_fn, kernel_name("zone", plan)))
 
 
 @functools.lru_cache(maxsize=256)
@@ -1497,6 +1483,156 @@ def combine_reduced(op: str, a, b):
     if op == "minmax_pair":
         return (jnp.minimum(a[0], b[0]), jnp.maximum(a[1], b[1]))
     raise ValueError(op)
+
+
+# The fold of an in-place step reads and writes every segment's carried
+# outputs, so they have to stay small beside the block of rows the step
+# reads (65,536 rows of 10 B in TPC-H Q6).  Measured on the chip at
+# [2, 2000] float states (the closed cell's Q5) and at two scalars (Q6);
+# nothing above that was, so a dense holder of more elements than this
+# keeps the gathered view, whose one pass builds it once.
+_INPLACE_STATE_CELLS = 1 << 18
+
+
+def _state_cells(plan: StaticPlan) -> int:
+    """Elements of one segment's outputs, from what the plan states."""
+
+    def width(agg: StaticAgg) -> int:
+        if agg.kind in ("presence", "hist"):
+            return agg.gcard_pad
+        if agg.kind == "hll":
+            return config.HLL_M
+        return 2 if agg.base in ("avg", "minmaxrange") else 1
+
+    groups = plan.group_by.capacity if plan.group_by is not None else 1
+    return groups * (1 + sum(width(agg) for agg in plan.aggs))
+
+
+def zone_blocks(plan: StaticPlan) -> str:
+    """How the zone tier's block program reads a launch's candidate
+    blocks, from what the plan states: consulted by the kernel builder
+    (make_stacked_block_kernel) and by the launch's ``blocks=`` tag and
+    ``zone.blocks.*`` mark, which must agree.
+
+    'inplace':  every output combines elementwise over blocks of rows
+                (_ELEMENTWISE_REDUCERS: no selection part, no
+                distinct_pairs, no hll_sort) and a segment's outputs are
+                at most _INPLACE_STATE_CELLS elements: a loop over the
+                candidate ids slices the staged columns where they lie
+                and folds each block's outputs into the carried ones.
+    'gathered': every other plan: its outputs need the view whole, so
+                the candidate blocks are copied out first
+                (_gather_blocks)."""
+    elementwise = all(op in _ELEMENTWISE_REDUCERS for op in output_reducers(plan).values())
+    return "inplace" if elementwise and _state_cells(plan) <= _INPLACE_STATE_CELLS else "gathered"
+
+
+def _reducer_identity(op: str, like):
+    """What combine_reduced(op, ., x) leaves x at, shaped like ``like``
+    (a ShapeDtypeStruct, or the pair of them a ``*_pair`` reducer has)."""
+
+    def fill(kind: str, s):
+        if kind == "sum":
+            return jnp.zeros(s.shape, s.dtype)
+        if jnp.issubdtype(s.dtype, jnp.floating):
+            return jnp.full(s.shape, BIG if kind == "min" else -BIG, s.dtype)
+        info = jnp.iinfo(s.dtype)
+        return jnp.full(s.shape, info.max if kind == "min" else info.min, s.dtype)
+
+    if op == "sum_pair":
+        return (fill("sum", like[0]), fill("sum", like[1]))
+    if op == "minmax_pair":
+        return (fill("min", like[0]), fill("max", like[1]))
+    return fill(op, like)
+
+
+def _make_inplace_block_kernel(plan: StaticPlan, block: int) -> Callable:
+    """The zone tier's block program for an 'inplace' plan, over the
+    stacked segment axis: segs, q, ids [S, nb_pad] -> every segment's
+    outputs [S, ...], as ``vmap`` of the single-segment kernel gives
+    them.
+
+    One loop over the UNION of the launch's candidate block ids.  A step
+    takes rows [id * block, (id + 1) * block) of every segment at once
+    (_block_view at a start the segments share), runs the plan's
+    single-segment kernel on that view with a segment's rows valid only
+    where the id is among its own candidates, and folds the block's
+    outputs into the carried ones with the plan's reducers.  What the
+    program reads from HBM is those blocks of the staged columns, once,
+    where they lie.
+
+    Why the union and not each segment's own list: a staged column is
+    [segments, rows] and the chip tiles it (8, 128), a 1-byte column
+    four segments to a 32-bit word, so one segment's block is not
+    contiguous in HBM.  A start that differs by segment is a gather,
+    which the compiler lowers to a slice-and-update copy a segment a
+    column a step, and a loop over (segment, block) pairs works one
+    sublane in eight (chip run, PR 35: PERF.md section 6).  A shared
+    start is the row loop's slice.  Segments of one table filtered on a
+    clustered column keep much the same blocks; where they do not, the
+    loop runs to the union's length and masks, never further than a
+    scan of every block.
+
+    The ids arrive packed to the front, -1 behind them
+    (zonemap.block_ids_input); a launch without a candidate runs one
+    step with nothing valid, which gives the kernel's own empty
+    outputs."""
+    single = make_single_segment_kernel(plan)
+    reducers = output_reducers(plan)
+
+    def kernel(segs: Dict[str, Any], q: Dict[str, Any], ids: jnp.ndarray) -> Dict[str, Any]:
+        n = next(v.shape[1] for k, v in segs.items() if k == "valid" or _row_key(k))
+        # member[s, b]: block b is among segment s's candidates (a padding -1 is no block's id)
+        member = jnp.any(ids[:, :, None] == jnp.arange(n // block, dtype=ids.dtype), axis=1)
+        wanted = jnp.any(member, axis=0)
+        order = jnp.argsort(~wanted, stable=True).astype(jnp.int32)  # the union's ids first, ascending
+
+        def step_outputs(j):
+            b = order[j]
+
+            def of_segment(seg, q_seg, live):
+                view = _block_view(seg, b * block, block)
+                view["valid"] = view["valid"] & live
+                return single(view, q_seg)
+
+            return jax.vmap(of_segment)(segs, q, member[:, b])
+
+        carried = {
+            k: _reducer_identity(reducers[k], like)
+            for k, like in jax.eval_shape(step_outputs, jnp.int32(0)).items()
+        }
+        return jax.lax.fori_loop(
+            0,
+            jnp.maximum(jnp.sum(wanted, dtype=jnp.int32), 1),
+            lambda j, acc: {k: combine_reduced(reducers[k], acc[k], v) for k, v in step_outputs(j).items()},
+            carried,
+        )
+
+    return kernel
+
+
+def make_stacked_block_kernel(plan: StaticPlan, block: int) -> Callable:
+    """The zone tier's block program over the stacked segment axis,
+    before the merge: segs, q, block ids int32 [S, nb_pad] (-1 padded,
+    packed to the front) -> every segment's outputs [S, ...].  The form
+    is zone_blocks(plan)'s."""
+    if zone_blocks(plan) == "inplace":
+        return _make_inplace_block_kernel(plan, block)
+    return jax.vmap(make_single_segment_block_kernel(plan, block))
+
+
+@functools.lru_cache(maxsize=256)
+def make_block_table_kernel(plan: StaticPlan, block: int) -> Callable:
+    """Jitted block-skipping variant of make_table_kernel; extra input:
+    block ids int32 [S, nb_pad] (-1 padded)."""
+    stacked = make_stacked_block_kernel(plan, block)
+    reducers = output_reducers(plan)
+
+    def table_fn(segs, q, ids):
+        outs = stacked(segs, q, ids)
+        return {k: apply_reduce(reducers[k], v) for k, v in outs.items()}
+
+    return jax.jit(named(table_fn, kernel_name("zone", plan)))
 
 
 def make_chunked_table_kernel(plan: StaticPlan, num_segments: int, n_pad: int) -> Callable:

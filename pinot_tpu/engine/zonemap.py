@@ -16,8 +16,10 @@ point lists, match tables) can be tested per block on the host:
                         (prefix-sum lookup)
 
 AND/OR trees combine candidacy bitwise; MV leaves are conservatively
-all-candidate.  The executor gathers only candidate blocks onto the
-device (``kernel.make_block_table_kernel``), so work scales with
+all-candidate.  The device program reads only candidate blocks
+(``kernel.make_block_table_kernel``: a loop over the launch's block ids
+that slices the staged columns where they lie, or for a selection a
+gathered copy of them; ``kernel.zone_blocks``), so work scales with
 selectivity — a point query on a clustered column touches one block per
 segment instead of the whole table.
 """

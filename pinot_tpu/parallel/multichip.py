@@ -112,16 +112,17 @@ def _out_specs(reducers: Dict[str, str], shard_spec) -> Dict[str, Any]:
     return out
 
 
-def _make_sharded(plan: StaticPlan, mesh: Mesh, single: Callable, n_extra: int) -> Callable:
+def _make_sharded(plan: StaticPlan, mesh: Mesh, stacked: Callable, n_extra: int) -> Callable:
     """Shared SPMD wiring for the full-scan and block-skipping kernels:
-    vmap the single-segment kernel per chip, merge with collectives over
-    every mesh axis.  ``n_extra`` extra positional operands (e.g. the
-    block id array) shard over the segment axis like everything else."""
+    ``stacked`` gives every local segment's outputs [S_local, ...] on
+    each chip, merged with collectives over every mesh axis.
+    ``n_extra`` extra positional operands (e.g. the block id array)
+    shard over the segment axis like everything else."""
     reducers = output_reducers(plan)
     axes = tuple(mesh.axis_names)  # 1-D (segments) or 2-D (hosts, segments)
 
     def local_fn(segs: Dict[str, Any], q: Dict[str, Any], *extra) -> Dict[str, Any]:
-        outs = jax.vmap(single)(segs, q, *extra)  # this chip's segments
+        outs = stacked(segs, q, *extra)  # this chip's segments
         merged: Dict[str, Any] = {}
         for k, v in outs.items():
             op = reducers[k]
@@ -164,16 +165,17 @@ def make_sharded_table_kernel(plan: StaticPlan, mesh: Mesh) -> Callable:
     of them, so XLA lowers the reduction hierarchically — ICI inside a
     host, DCN across hosts.
     """
-    return _make_sharded(plan, mesh, make_single_segment_kernel(plan), 0)
+    return _make_sharded(plan, mesh, jax.vmap(make_single_segment_kernel(plan)), 0)
 
 
 def make_sharded_block_table_kernel(plan: StaticPlan, mesh: Mesh, block: int) -> Callable:
     """Zone-map block-skipping variant of the sharded kernel: the block
     id array [S, nb_pad] shards over the segment axis with everything
-    else, so selective queries stay O(candidate blocks) per chip."""
-    from pinot_tpu.engine.kernel import make_single_segment_block_kernel
+    else, so selective queries stay O(candidate blocks) per chip (an
+    'inplace' plan's loop runs over the union of its shard's ids)."""
+    from pinot_tpu.engine.kernel import make_stacked_block_kernel
 
-    return _make_sharded(plan, mesh, make_single_segment_block_kernel(plan, block), 1)
+    return _make_sharded(plan, mesh, make_stacked_block_kernel(plan, block), 1)
 
 
 def run_sharded_query(plan: StaticPlan, mesh: Mesh, seg_arrays, q_inputs):

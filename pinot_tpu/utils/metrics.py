@@ -621,6 +621,13 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "groupby.operands.loop": "group-by launches whose filter mask, key "
     "and weight columns are built inside the group-by's row loop "
     "(engine/kernel.py groupby_operands; the launch's ``operands=`` tag)",
+    # how a zone-tier launch read its candidate blocks, one mark a launch
+    # (engine/kernel.py zone_blocks; the launch's ``blocks=`` tag)
+    "zone.blocks.inplace": "zone-tier launches whose program loops over "
+    "the candidate block ids and slices the staged columns where they lie",
+    "zone.blocks.gathered": "zone-tier launches whose program copies the "
+    "candidate blocks out first (selection, distinct pairs, sorted HLL, or "
+    "a dense holder too large to fold a block at a time)",
     "groupby.slots.shared": "rows of a dense group-by's float states "
     "that its aggregates share (a sum read by sum and avg, an avg's count "
     "on the occupancy row), one mark a row a launch (engine/kernel.py "

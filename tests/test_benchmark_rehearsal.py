@@ -4,7 +4,9 @@ and 1.
 
 Each cell keeps its own configuration's ``env``, ``chips``,
 ``guarantees`` and traffic file; only ``segments`` and
-``rows_per_segment`` are cut.  So the mesh4 cell runs
+``rows_per_segment`` are cut, and the zone tier's block with them, so
+that a cut segment is still 128 blocks and the queries that ride the
+zone tier on the chip (Q5, TPC-H Q6) ride it here.  So the mesh4 cell runs
 ``PINOT_TPU_MESH_SHAPE=1x4`` on conftest's virtual devices and the
 audited cell runs with the shadow auditor at its default.  A span or a
 counter that a listed ``per_layer`` reader needs and no longer finds
@@ -25,6 +27,7 @@ BENCH = os.path.join(ROOT, "benchmark")
 MANIFEST = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
 SEGMENTS, ROWS_PER_SEGMENT = 4, 10_000  # one segment a chip of the 1x4 mesh
+ZONE_BLOCK = 128  # 10,000 rows are staged as 16,384: 128 blocks, as 8,388,608 rows are in blocks of 65,536
 # windows as short as the readers allow, to keep tier-1's wall-clock tests undisturbed.  The
 # auditor takes every 64th device answer: after the warm-up and the rehearsal's 3 s at 30
 # queries/s that is the window's 34th query, 1.1 s in, and its pass has to end inside the window
@@ -71,6 +74,7 @@ def test_cell_rehearses_on_the_cpu(capsys, monkeypatch, run, cut_manifest, cell,
         monkeypatch.delenv(name)
     for name, value in config.get("env", {}).items():
         monkeypatch.setenv(name, value)
+    monkeypatch.setenv("PINOT_TPU_ZONE_BLOCK", str(ZONE_BLOCK))
     read, after = {}, {}  # every reader's answer, before run.py drops the times of a CPU run; the counters it read
     load_module = run.load_module
 
@@ -113,6 +117,8 @@ def test_cell_rehearses_on_the_cpu(capsys, monkeypatch, run, cut_manifest, cell,
     missing = [m["name"] for m in listed if read.get(m["name"]) is None]
     assert not missing, f"per_layer readers of {cell} that found nothing to read: {missing}"
     assert out["metrics"]["compiles_in_window"]["value"] == 0
+    if "zone_inplace_share" in read:  # the closed cells: Q5 and TPC-H Q6 read their blocks in place
+        assert read["zone_inplace_share"] == 100.0
 
 
 def test_every_file_the_manifest_names_exists_and_every_reader_imports(run):

@@ -128,3 +128,80 @@ def test_loop_groupby_keeps_its_operands_out_of_hbm_on_v5e(one_chip, suite_launc
     with jax.enable_x64(False):
         compiled = table.lower(segs, q).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+# the two closed cells' queries that ride the zone tier (benchmark/traffic/groupby_closed.json: q5;
+# tpch_q1q6_closed.json: q6), and the candidate blocks they keep of a segment's 128, padded
+ZONE_SHAPES = {
+    "q5": ("SELECT sum(l_extendedprice) FROM lineitem WHERE l_shipdate BETWEEN '1995-01-01' AND '1996-12-31' "
+           "GROUP BY l_shipdate TOP 10", 64),
+    "q6": ("SELECT sum(l_extendedprice*l_discount) FROM lineitem WHERE l_shipdate >= '1994-01-01' AND "
+           "l_shipdate < '1995-01-01' AND l_discount BETWEEN 0.05 AND 0.07 AND l_quantity < 24", 32),
+}
+
+
+@pytest.fixture(scope="module")
+def zone_launches():
+    """(plan, segment arrays, query inputs) of each launch of ZONE_SHAPES
+    through the zone tier as the executor makes it on the chip, over a
+    tiny lineitem table with a block to match."""
+    from pinot_tpu.engine import kernel as kernel_mod
+    from pinot_tpu.engine.executor import QueryExecutor
+    from pinot_tpu.pql import optimize_request, parse_pql
+    from pinot_tpu.tools.datagen import synthetic_lineitem_segment
+
+    launches = {}
+    with pytest.MonkeyPatch.context() as mp, jax.enable_x64(False):
+        mp.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")
+        mp.setenv("PINOT_TPU_RAW_CARD_MIN", "0")
+        mp.setenv("PINOT_TPU_INVINDEX", "0")
+        mp.setenv("PINOT_TPU_ZONE_BLOCK", "512")
+        run_kernel = QueryExecutor._run_kernel
+
+        def spy(self, kernel, args, plan, staged, digest, block_ids, *rest, **kw):
+            assert block_ids is not None, name
+            launches[name] = (plan, args[0], args[1])
+            return run_kernel(self, kernel, args, plan, staged, digest, block_ids, *rest, **kw)
+
+        mp.setattr(QueryExecutor, "_run_kernel", spy)
+        segs = [synthetic_lineitem_segment(32768, seed=70 + i, name=f"zone{i}") for i in range(2)]
+        try:
+            for name, (pql, _) in ZONE_SHAPES.items():
+                QueryExecutor().execute(segs, optimize_request(parse_pql(pql)))
+        finally:
+            for cached in (kernel_mod.make_table_kernel, kernel_mod.make_block_table_kernel,
+                           kernel_mod.make_packed_block_table_kernel):
+                cached.cache_clear()
+    return launches
+
+
+@pytest.mark.parametrize("shape", sorted(ZONE_SHAPES))
+def test_zone_program_reads_its_blocks_in_place_on_v5e(one_chip, zone_launches, monkeypatch, shape):
+    """The zone tier's block program of Q5 and of TPC-H Q6 over 16
+    segments of 8,388,608 rows in blocks of 65,536: looping over the
+    block ids in place, the program keeps next to nothing in HBM beside
+    its arguments.  The gathered form kept the copies of the candidate
+    blocks there (1.0 GiB for Q5, 1.2 for Q6)."""
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the radix contraction compiled, not interpreted
+    plan, segs, q = zone_launches[shape]
+    assert kernel_mod.zone_blocks(plan) == "inplace"
+    S, n, block, nb_pad = 16, 1 << 23, 1 << 16, ZONE_SHAPES[shape][1]
+
+    def at_scale(key, v):
+        rows = (n,) + v.shape[2:] if kernel_mod._row_key(key) else v.shape[1:]
+        return jax.ShapeDtypeStruct((S,) + rows, v.dtype, sharding=one_chip)
+
+    segs = {key: at_scale(key, v) for key, v in segs.items()}
+    q = jax.tree_util.tree_map(lambda v: at_scale("", v), q)
+    ids = jax.ShapeDtypeStruct((S, nb_pad), jnp.int32, sharding=one_chip)
+    table = kernel_mod.make_block_table_kernel(plan, block)
+    try:
+        with jax.enable_x64(False):
+            compiled = table.lower(segs, q, ids).compile()
+    finally:
+        kernel_mod.make_block_table_kernel.cache_clear()
+    assert ("tpu_custom_call" in compiled.as_text()) == (shape == "q5")
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

@@ -344,3 +344,206 @@ def test_runs_leaf_through_block_path(cluster):
     assert _norm(got) == _norm(want)
     # sanity: the query matches something (the bug returned zero rows)
     assert int(got.to_json()["aggregationResults"][0]["value"]) > 0
+
+
+# -- the block program reads its blocks where they are staged (PR 35) --------
+
+# shape: (PQL, what kernel.zone_blocks answers, needs the chip's group-by lowerings)
+YEAR = "l_shipdate BETWEEN '1994-01-01' AND '1994-12-31'"
+ZONE_SHAPES = {
+    "q6_sum_of_a_product": (
+        "SELECT sum(l_extendedprice*l_discount) FROM lineitem WHERE l_shipdate >= '1994-01-01' AND "
+        "l_shipdate < '1995-01-01' AND l_discount BETWEEN 0.05 AND 0.07 AND l_quantity < 24", "inplace", False),
+    "small_k_in_the_row_loop": (
+        f"SELECT sum(l_quantity), avg(l_discount), count(*) FROM lineitem WHERE {YEAR} GROUP BY l_returnflag TOP 10",
+        "inplace", True),
+    "q5_by_the_sorted_column_radix": (
+        f"SELECT sum(l_extendedprice) FROM lineitem WHERE {YEAR} GROUP BY l_shipdate TOP 10", "inplace", True),
+    "q5_by_the_sorted_column_scatter": (
+        f"SELECT sum(l_extendedprice) FROM lineitem WHERE {YEAR} GROUP BY l_shipdate TOP 10", "inplace", False),
+    "min_max_group_by": (
+        f"SELECT min(l_extendedprice), max(l_discount), minmaxrange(l_quantity) FROM lineitem WHERE {YEAR} "
+        "GROUP BY l_returnflag TOP 10", "inplace", False),
+    "selection": (
+        f"SELECT l_shipdate, l_quantity FROM lineitem WHERE {YEAR} ORDER BY l_quantity DESC LIMIT 5", "gathered", False),
+}
+
+
+def _forget_block_programs():
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    for cached in (kernel_mod.make_table_kernel, kernel_mod.make_packed_table_kernel,
+                   kernel_mod.make_block_table_kernel, kernel_mod.make_packed_block_table_kernel):
+        cached.cache_clear()
+
+
+@pytest.fixture
+def zone_launch(cluster, monkeypatch, request):
+    """(plan, segment arrays, query inputs, block ids) of one shape's
+    launch through the zone tier, as the executor makes it."""
+    pql, _, contractions = ZONE_SHAPES[request.param]
+    if contractions:
+        monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")
+    _forget_block_programs()
+    launch = {}
+    run_kernel = QueryExecutor._run_kernel
+
+    def spy(self, kernel, args, plan, staged, digest, block_ids, *rest, **kw):
+        launch.update(plan=plan, segs=args[0], q=args[1], ids=block_ids)
+        return run_kernel(self, kernel, args, plan, staged, digest, block_ids, *rest, **kw)
+
+    monkeypatch.setattr(QueryExecutor, "_run_kernel", spy)
+    segs, oracle = cluster
+    req = optimize_request(parse_pql(pql))
+    got = reduce_to_response(req, [QueryExecutor().execute(segs, req)])
+    from tests.test_engine import _values_close
+
+    want = oracle.execute(optimize_request(parse_pql(pql))).to_json()
+    got = got.to_json()
+    for k in STRIP:
+        got.pop(k, None), want.pop(k, None)
+    assert _values_close(got, want), request.param
+    assert launch["ids"] is not None, "the zone tier did not take it"
+    yield request.param, launch
+    _forget_block_programs()
+
+
+def _id_variants(ids: np.ndarray, nb_total: int):
+    """The executor's ids (a run a segment) and hand-made ones beside
+    them; every list still holds its segment's candidates (but 'dead',
+    where one segment is left out whole), packed to the front."""
+    run = [sorted(int(b) for b in row if b >= 0) for row in ids]
+    extra = [b for b in (0, 1, nb_total // 2, nb_total - 2, nb_total - 1) if b not in set(sum(run, []))]
+
+    def packed(rows):
+        out = np.full((len(rows), max(8, max(len(r) for r in rows))), -1, dtype=np.int32)
+        for s, r in enumerate(rows):
+            out[s, : len(r)] = r
+        return out
+
+    return {
+        "run": (ids, range(len(run))),
+        "holes": (packed([sorted(r + extra[s % 2 :: 2]) for s, r in enumerate(run)]), range(len(run))),
+        "dead": (packed([[] if s == 1 else r for s, r in enumerate(run)]), [s for s in range(len(run)) if s != 1]),
+        "uneven": (packed([r if s == 0 else sorted(r + extra[: 1 + 2 * s]) for s, r in enumerate(run)]), range(len(run))),
+        "all_dead": (packed([[] for _ in run]), []),
+    }
+
+
+@pytest.mark.parametrize("zone_launch", sorted(ZONE_SHAPES), indirect=True)
+def test_block_program_equals_the_full_scan_on_the_same_segments(zone_launch):
+    """Plan by plan: the zone tier's program over a launch's block ids
+    against the full scan's single-segment kernel over every row of the
+    segments those ids name.  Counts, occupancy and extremes exact, float
+    sums to a sum of block sums; a segment whose ids are all -1 adds
+    nothing, and segments may keep different numbers of blocks."""
+    import jax
+
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    shape, launch = zone_launch
+    plan, segs, q, ids = launch["plan"], launch["segs"], launch["q"], launch["ids"]
+    form = ZONE_SHAPES[shape][1]
+    assert kernel_mod.zone_blocks(plan) == form
+    if shape.endswith("radix"):
+        assert kernel_mod.groupby_lowering(plan) == "radix"
+    elif shape == "small_k_in_the_row_loop":
+        assert kernel_mod.groupby_operands(plan) == "loop"
+    elif plan.group_by is not None:
+        assert kernel_mod.groupby_lowering(plan) == "scatter"
+    reducers = kernel_mod.output_reducers(plan)
+    merged = [k for k, op in reducers.items() if op != "none"]
+    full = jax.vmap(kernel_mod.make_single_segment_kernel(plan))(segs, q)
+    n_pad = next(v.shape[1] for k, v in segs.items() if kernel_mod._row_key(k))
+    program = kernel_mod.make_block_table_kernel(plan, BLOCK)
+    for variant, (variant_ids, kept) in _id_variants(np.asarray(ids), n_pad // BLOCK).items():
+        if form == "gathered" and variant != "run":
+            continue  # a selection's window is the executor's to size
+        got = program(segs, q, jax.numpy.asarray(variant_ids))
+        kept = np.asarray(list(kept), dtype=np.int32)
+        if not kept.size:  # nothing named: what the kernel gives a segment with no valid row
+            assert int(got["num_docs"]) == 0
+            assert "gb_presence" not in got or not np.asarray(got["gb_presence"]).any()
+            continue
+        for key in merged:
+            want = kernel_mod.apply_reduce(reducers[key], jax.tree_util.tree_map(lambda v: v[kept], full[key]))
+            for g, w in zip(jax.tree_util.tree_leaves(got[key]), jax.tree_util.tree_leaves(want)):
+                g, w = np.asarray(g), np.asarray(w)
+                if np.issubdtype(g.dtype, np.integer) or reducers[key] in ("min", "max", "minmax_pair"):
+                    np.testing.assert_array_equal(g, w, err_msg=f"{shape} {variant} {key}")
+                else:  # the radix contraction accumulates in float32 on every backend
+                    np.testing.assert_allclose(g, w, rtol=1e-6, err_msg=f"{shape} {variant} {key}")
+
+
+@pytest.mark.parametrize(
+    "zone_launch", ["q6_sum_of_a_product", "small_k_in_the_row_loop", "q5_by_the_sorted_column_scatter", "selection"],
+    indirect=True)
+def test_inplace_program_holds_no_array_of_the_gathered_length(zone_launch):
+    """The mechanism and not only its answers: the lowered block program
+    of an 'inplace' plan holds no array of nb_pad * block rows a segment
+    (no gathered copy of a staged column, of ``valid`` or of ``rowid``)
+    and no view of a column as [blocks, block]; a 'gathered' plan's
+    holds both."""
+    import re
+
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    shape, launch = zone_launch
+    plan, segs, q, ids = launch["plan"], launch["segs"], launch["q"], np.asarray(launch["ids"])
+    n_seg, nb_pad = ids.shape
+    n_pad = next(v.shape[1] for k, v in segs.items() if kernel_mod._row_key(k))
+    rows = nb_pad * BLOCK
+    assert rows not in (n_pad, BLOCK) and n_pad % BLOCK == 0
+    text = kernel_mod.make_block_table_kernel(plan, BLOCK).lower(segs, q, ids).as_text()
+    gathered_length = re.findall(rf"tensor<(?:{n_seg}x)?{rows}(?:x\d+)*x[a-z]+\d+>", text)
+    by_blocks = re.findall(rf"tensor<(?:{n_seg}x)?(?:{n_pad // BLOCK}|{nb_pad})x{BLOCK}(?:x\d+)*x[a-z]+\d+>", text)
+    if kernel_mod.zone_blocks(plan) == "inplace":
+        assert not gathered_length and not by_blocks, (gathered_length[:3], by_blocks[:3])
+        assert f"tensor<{n_seg}x{BLOCK}x" in text  # a step's slice of every segment
+    else:
+        assert gathered_length and by_blocks
+
+
+@pytest.mark.parametrize("shape", ["q6_sum_of_a_product", "min_max_group_by", "selection"])
+def test_launch_says_how_the_zone_tier_read_its_blocks(cluster, shape, monkeypatch):
+    """``blocks=inplace|gathered`` on the launch's ``laneDispatch`` span
+    and one ``zone.blocks.*`` mark a zone-tier launch: the answer of
+    ``kernel.zone_blocks``, which the kernel builder asks too; a launch
+    of another tier carries neither."""
+    from pinot_tpu.engine import kernel as kernel_mod
+    from pinot_tpu.tools.cluster_harness import single_server_broker
+
+    pql, form, _ = ZONE_SHAPES[shape]
+    segs, _ = cluster
+    _forget_block_programs()
+    built = []
+    inplace_kernel = kernel_mod._make_inplace_block_kernel
+    monkeypatch.setattr(kernel_mod, "_make_inplace_block_kernel",
+                        lambda plan, block: built.append(plan) or inplace_kernel(plan, block))
+    broker = single_server_broker("lineitem", segs)
+    server = broker.local_servers[0]
+    marks = lambda: {k: server.metrics.meter(f"zone.blocks.{k}").count for k in ("inplace", "gathered")}
+
+    def launch_tags(text):
+        resp = broker.handle_pql(text, trace=True)
+        assert not resp.to_json()["exceptions"]
+        (launch,) = [s for s in resp.trace_info["scopes"][server.name] if s["span"] == "laneDispatch"]
+        return launch["tags"]
+
+    try:
+        tags = launch_tags(pql)
+        assert tags["program"].startswith("pinot_zone_") and tags["blocks"] == form
+        assert marks() == {"inplace": int(form == "inplace"), "gathered": int(form == "gathered")}
+        assert len(built) == int(form == "inplace") and all(kernel_mod.zone_blocks(p) == "inplace" for p in built)
+        # the full scan is no zone launch: no tag, no mark
+        scan = launch_tags("SELECT sum(l_quantity) FROM lineitem WHERE l_shipdate <= '1998-09-02'")
+        assert scan["program"].startswith("pinot_scan_") and "blocks" not in scan
+        assert sum(marks().values()) == 1
+        # the function answers otherwise: the tag, the mark and the program follow it together
+        monkeypatch.setattr(kernel_mod, "zone_blocks", lambda plan: "gathered")
+        _forget_block_programs()
+        assert launch_tags(pql)["blocks"] == "gathered"
+        assert marks()["gathered"] == 1 + int(form == "gathered") and len(built) == int(form == "inplace")
+    finally:
+        server.shutdown()
+        _forget_block_programs()
