@@ -1152,6 +1152,8 @@ class QueryExecutor:
                     return execute_host(live, ctx, request, total_docs, sel_columns)
 
         result = self._finalize(request, plan, ctx, staged, live, outs, total_docs, sel_columns)
+        if plan.group_by is not None:
+            ph.current.tag(groups=int(result.cost.get("numGroupsLive", 0)))  # add_cost keeps no zero
         if scanned_rows is not None:
             # zone maps skipped non-candidate blocks: filter scan cost
             # is O(candidate rows), the point of the skipping path
@@ -1825,7 +1827,14 @@ class QueryExecutor:
         )
 
         if plan.group_by is not None:
-            res.groups = self._finalize_groups(request, plan, ctx, outs)
+            res.groups, live_groups, sum_sq = self._finalize_groups(request, plan, ctx, outs)
+            # groups with a row in the fetched state, and those left after
+            # the per-server trim (an empty answer marks neither); the
+            # digest is of every live group, so a reply of TOP n can be
+            # held to the whole state and not to the n it returns
+            self.metrics.meter("groupby.groups.live").mark(live_groups)
+            self.metrics.meter("groupby.groups.kept").mark(len(res.groups))
+            res.add_cost(numGroupsLive=live_groups, numGroupsKept=len(res.groups), groupStateSumSq=sum_sq)
         elif plan.aggs:
             res.aggregations = [
                 self._scalar_partial(agg, outs[f"agg_{i}"], ctx)
@@ -1880,14 +1889,15 @@ class QueryExecutor:
         raise AssertionError(agg)
 
     # ------------------------------------------------------------------
-    def _finalize_groups(
-        self, request: BrokerRequest, plan: StaticPlan, ctx: TableContext, outs
-    ) -> Dict[Tuple[str, ...], List[AggPartial]]:
+    def _kept_group_keys(self, plan: StaticPlan, ctx: TableContext, outs) -> Tuple[int, float, np.ndarray]:
+        """(groups with a row, the sum of squares of their dense values,
+        the dense keys the per-server trim keeps, ascending) of a
+        group-by's fetched state."""
         gb = plan.group_by
-        presence = np.asarray(outs["gb_presence"]).astype(bool)
-        keys = np.nonzero(presence)[0]
-        if keys.size == 0:
-            return {}
+        keys = np.nonzero(np.asarray(outs["gb_presence"]))[0]
+        live_groups = int(keys.size)
+        if live_groups == 0:
+            return 0, 0.0, keys
 
         # sort-dedup distinct states arrive as compacted (slot, gid)
         # pair buffers; index them once per agg for the per-group reads
@@ -1895,23 +1905,46 @@ class QueryExecutor:
             if agg.sort_pairs and not isinstance(outs[f"gb_{i}"], _PairsState):
                 outs[f"gb_{i}"] = _PairsState(outs[f"gb_{i}"], gb.capacity)
 
+        # every live group's order value of each aggregate whose state is
+        # dense floats (count, sum, min, max, avg, minmaxrange: a gather),
+        # and of the others only where the trim needs them
+        trims = live_groups > max(gb.top_n * 5, 100)
+        order, sum_sq = {}, 0.0
+        for i, agg in enumerate(plan.aggs):
+            dense = agg.kind in ("scalar", "pair")
+            if trims or dense:
+                order[i] = self._group_order_values(agg, outs[f"gb_{i}"], keys, ctx)
+            if dense:  # one pass in float64, no BLAS: its threads cost a wake-up a query
+                sum_sq += float(np.square(order[i], dtype=np.float64).sum())
+
         # Trim candidate groups per aggregation (reference trims to
         # topN*5 per server, MCombineGroupByOperator.java:216); the
         # union over aggregations (incl. capped boundary ties) is kept
         # so merges stay consistent.
         from pinot_tpu.engine.results import trim_group_candidates
 
-        if keys.size > max(gb.top_n * 5, 100):
+        if trims:
             keep = trim_group_candidates(
-                [
-                    self._group_order_values(agg, outs[f"gb_{i}"], keys, ctx)
-                    for i, agg in enumerate(plan.aggs)
-                ],
+                [order[i] for i in range(len(plan.aggs))],
                 [group_sort_ascending(agg.func) for agg in plan.aggs],
                 gb.top_n,
-                keys.size,
+                live_groups,
             )
             keys = keys[keep]
+        return live_groups, sum_sq, keys
+
+    def _finalize_groups(
+        self, request: BrokerRequest, plan: StaticPlan, ctx: TableContext, outs
+    ) -> Tuple[Dict[Tuple[str, ...], List[AggPartial]], int, float]:
+        """The kept groups' partials by rendered key, how many groups the
+        fetched state held before the trim, and their values' digest."""
+        gb = plan.group_by
+        # from the fetched state to the kept keys: timer and annotation
+        # only, inside ``finalize`` (which stays the span and the leaf)
+        with boundary("groupTrim", None, self.metrics.timer("phase.groupTrim")):
+            live_groups, sum_sq, keys = self._kept_group_keys(plan, ctx, outs)
+        if keys.size == 0:
+            return {}, live_groups, sum_sq
 
         # decompose mixed-radix keys -> per-column global ids
         gids = []
@@ -1938,7 +1971,7 @@ class QueryExecutor:
             for i, agg in enumerate(plan.aggs):
                 partials.append(self._group_partial(agg, outs[f"gb_{i}"], k, ctx))
             groups[ktup] = partials
-        return groups
+        return groups, live_groups, sum_sq
 
     def _group_order_values(self, agg, state, keys: np.ndarray, ctx: TableContext) -> np.ndarray:
         """Exact finalized per-group values, used for trim ordering."""

@@ -100,6 +100,12 @@ def _grouped_hll_path(capacity: int) -> str:
 # At 2^18 a second sum costs the contraction 8.5 ns a row and the
 # scatter 6.8, so the crossover lies just under it; 2^16 is the largest
 # measured K that wins at any number of sums (4.7x at one).
+# Above the bound, as a cell sends it (chip run, PR 37,
+# lineitem_topsupplier_closed traced on seed 3700001002: TPC-H Q15,
+# K = 220,000, a product under the sum, 16 x 8 blocks of 65,536 rows
+# through the zone tier's gathered view): the two scatters 56.9 and
+# 55.6 ms a query over 8.39M rows, 13.41 ns a row together, and the
+# gather of the blocks 9.6 ms beside them.
 RADIX_GROUP_CAP = 1 << 16
 _RADIX = 128
 # VMEM the generated one-hots of one grid step may take (the block of

@@ -239,6 +239,20 @@ GroupKey = Tuple[str, ...]
 #   exprAggs           aggregates of the query whose argument is a
 #                      compound expression (sum(a*(1-b))), on whatever
 #                      tier answered (meters agg.expr.device|host)
+#   numGroupsLive      groups with a row in a device group-by's
+#                      fetched state, before the per-server trim
+#   numGroupsKept      groups left after it (max(5 x TOP, 100) an
+#                      aggregate, and ties; meters
+#                      groupby.groups.live|kept)
+#   groupStateSumSq    the sum of squares, in float64, of every live
+#                      group's value of each aggregate whose state is
+#                      dense floats (count, sum, min, max, avg,
+#                      minmaxrange): a digest of the whole fetched
+#                      state, where a reply of TOP n shows n groups.
+#                      These three are a server's own: the merge adds
+#                      them, so with one answering server they are the
+#                      query's, and with more a group live on two counts
+#                      twice and the squares are of each server's part
 #   batchHits          queries that rode a cross-query batched launch
 #                      (literals stacked with same-plan peers into one
 #                      vmapped kernel — the lane micro-batching tier)
@@ -272,6 +286,9 @@ COST_KEYS = (
     "qinputCacheHits",
     "preparedHit",
     "exprAggs",
+    "numGroupsLive",
+    "numGroupsKept",
+    "groupStateSumSq",
     "batchHits",
     "rescacheHits",
     "buildRows",

@@ -167,6 +167,7 @@ def _synthetic_columnar_segment(
     clustered_column: Optional[str] = None,
     time_column: Optional[str] = None,
     rng=None,
+    drawn_last: Sequence[str] = (),
 ):
     """Shared fast-path builder behind every synthetic_*_segment:
     ColumnData built directly from per-column value pools (dictIds drawn
@@ -175,7 +176,10 @@ def _synthetic_columnar_segment(
     (arrival-ordered data: zone maps / docrange fast paths have
     something to prune, as a sorted Pinot column does).  Callers whose
     value pools consumed random state pass their ``rng`` so the draw
-    sequence (and thus seeded data) stays reproducible."""
+    sequence (and thus seeded data) stays reproducible.  Rows are drawn
+    column by column in the schema's order, the ``drawn_last`` columns
+    after the others: a schema that adds a column to another's can keep
+    the other's data for a seed."""
     import numpy as np
 
     from pinot_tpu.common.schema import DataType
@@ -189,7 +193,7 @@ def _synthetic_columnar_segment(
 
     rng = rng if rng is not None else np.random.default_rng(seed)
     columns = {}
-    for spec in schema.all_fields():
+    for spec in sorted(schema.all_fields(), key=lambda spec: spec.name in drawn_last):
         vals = dict_values[spec.name]
         if spec.stored_type == DataType.STRING:
             d = Dictionary(DataType.STRING, sorted(set(vals)))
@@ -234,37 +238,67 @@ def _synthetic_columnar_segment(
     return seg
 
 
+def _lineitem_pools(rng) -> Dict[str, Any]:
+    """The value pools of lineitem's nine columns, for both lineitem
+    generators: 2,000 dates of 28-day months from 1992-01-01, and a
+    16,384-value price dictionary drawn from ``rng`` (the pools' one
+    draw, before any row's)."""
+    import numpy as np
+
+    dates = sorted(
+        f"{y:04d}-{m:02d}-{d:02d}" for y in range(1992, 1999) for m in range(1, 13) for d in range(1, 29)
+    )[:2000]
+    return {
+        "l_returnflag": sorted(_RETURN_FLAGS),
+        "l_linestatus": sorted(_LINE_STATUS),
+        "l_shipmode": sorted(_SHIP_MODES),
+        "l_shipdate": dates,
+        "l_receiptdate": dates,
+        "l_quantity": np.arange(1.0, 51.0),
+        "l_extendedprice": np.round(np.sort(rng.uniform(900.0, 105_000.0, 16384)), 2),
+        "l_discount": np.round(np.arange(0.0, 0.11, 0.01), 2),
+        "l_tax": np.round(np.arange(0.0, 0.09, 0.01), 2),
+    }
+
+
 def synthetic_lineitem_segment(num_rows: int, seed: int = 7, name: str = "li0"):
     """Fast numpy-path lineitem segment for benchmarks (see
     ``_synthetic_columnar_segment``)."""
     import numpy as np
 
     rng = np.random.default_rng(seed)
-
-    def dates(n: int) -> List[str]:
-        out = []
-        for y in range(1992, 1999):
-            for m in range(1, 13):
-                for d in range(1, 29):
-                    out.append(f"{y:04d}-{m:02d}-{d:02d}")
-                    if len(out) >= n:
-                        return sorted(out)
-        return sorted(out)
-
-    dict_values = {
-        "l_returnflag": sorted(_RETURN_FLAGS),
-        "l_linestatus": sorted(_LINE_STATUS),
-        "l_shipmode": sorted(_SHIP_MODES),
-        "l_shipdate": dates(2000),
-        "l_receiptdate": dates(2000),
-        "l_quantity": np.arange(1.0, 51.0),
-        "l_extendedprice": np.round(np.sort(rng.uniform(900.0, 105_000.0, 16384)), 2),
-        "l_discount": np.round(np.arange(0.0, 0.11, 0.01), 2),
-        "l_tax": np.round(np.arange(0.0, 0.09, 0.01), 2),
-    }
     return _synthetic_columnar_segment(
-        lineitem_schema(), "lineitem", dict_values, num_rows, seed, name,
+        lineitem_schema(), "lineitem", _lineitem_pools(rng), num_rows, seed, name,
         clustered_column="l_shipdate", rng=rng,
+    )
+
+
+def lineitem_keys_schema() -> Schema:
+    """lineitem's nine columns and its supplier key: the table of TPC-H
+    Q15 (clause 2.4.15), whose view groups lineitem by ``l_suppkey``."""
+    base = lineitem_schema()
+    return Schema(
+        base.schema_name,
+        dimensions=base.dimensions + [FieldSpec("l_suppkey", DataType.INT)],
+        metrics=base.metrics,
+    )
+
+
+def synthetic_lineitem_keys_segment(num_rows: int, seed: int = 7, name: str = "li0", suppliers: int = 220_000):
+    """``synthetic_lineitem_segment`` with ``l_suppkey`` uniform over
+    1..``suppliers`` (as dbgen's is), drawn after the nine older columns
+    from the same generator state: those are bit for bit what
+    ``synthetic_lineitem_segment`` gives for the seed.  Every segment's
+    dictionary holds every supplier.  TPC-H has SF x 10,000 suppliers,
+    and the benchmark's table is SF1 raised 22 times: 220,000."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    pools = _lineitem_pools(rng)
+    pools["l_suppkey"] = np.arange(1, suppliers + 1, dtype=np.int64)
+    return _synthetic_columnar_segment(
+        lineitem_keys_schema(), "lineitem", pools, num_rows, seed, name,
+        clustered_column="l_shipdate", rng=rng, drawn_last=("l_suppkey",),
     )
 
 

@@ -632,6 +632,19 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "that its aggregates share (a sum read by sum and avg, an avg's count "
     "on the occupancy row), one mark a row a launch (engine/kernel.py "
     "groupby_cells; the launch's ``cells=`` tag gives K x m)",
+    # what a device group-by's finalize found and kept, marked by the
+    # count, summed over queries (engine/executor.py _finalize, _kept_group_keys;
+    # ``numGroupsLive``, ``numGroupsKept`` and the digest ``groupStateSumSq``
+    # on the reply's cost vector, ``groups=<live>`` on the group-by's
+    # ``finalize`` span)
+    "groupby.groups.live": "groups with a row in the fetched state of a "
+    "device group-by, before the per-server trim",
+    "groupby.groups.kept": "groups left after the per-server trim "
+    "(max(5 x TOP, 100) an aggregate, and boundary ties)",
+    "phase.groupTrim": "inside phase.finalize of a device group-by: from "
+    "the fetched state to the kept keys (nonzero over the occupancy, the "
+    "order values and their sum of squares, trim_group_candidates); timer "
+    "and annotation, no span",
     # arithmetic inside an aggregate (sum(a*(1-b))): one mark a query
     # whose plan holds a compound expression, by where it was answered
     "agg.expr.device": "queries with an expression inside an aggregate "

@@ -6,7 +6,7 @@ Each cell keeps its own configuration's ``env``, ``chips``,
 ``guarantees`` and traffic file; only ``segments`` and
 ``rows_per_segment`` are cut, and the zone tier's block with them, so
 that a cut segment is still 128 blocks and the queries that ride the
-zone tier on the chip (Q5, TPC-H Q6) ride it here.  So the mesh4 cell runs
+zone tier on the chip (Q5, TPC-H Q6, Q15) ride it here.  So the mesh4 cell runs
 ``PINOT_TPU_MESH_SHAPE=1x4`` on conftest's virtual devices and the
 audited cell runs with the shadow auditor at its default.  A span or a
 counter that a listed ``per_layer`` reader needs and no longer finds
@@ -117,8 +117,14 @@ def test_cell_rehearses_on_the_cpu(capsys, monkeypatch, run, cut_manifest, cell,
     missing = [m["name"] for m in listed if read.get(m["name"]) is None]
     assert not missing, f"per_layer readers of {cell} that found nothing to read: {missing}"
     assert out["metrics"]["compiles_in_window"]["value"] == 0
-    if "zone_inplace_share" in read:  # the closed cells: Q5 and TPC-H Q6 read their blocks in place
-        assert read["zone_inplace_share"] == 100.0
+    # Q15 groups by 220,000 keys: the scatter, and a segment's state (occupancy and one sum) over
+    # kernel._INPLACE_STATE_CELLS, so the zone tier's gathered view; the product under the sum in the kernel
+    top_supplier = cell == "lineitem_topsupplier_closed"
+    if "zone_inplace_share" in read:  # the other closed cells: Q5 and TPC-H Q6 read their blocks in place
+        assert read["zone_inplace_share"] == (0.0 if top_supplier else 100.0)
+    if top_supplier:
+        assert read["groupby_contraction_share"] == 0.0 and read["expr_device_share"] == 100.0
+        assert read["groups_kept_mean"] == 100 and 0 < read["groups_live_mean"] <= SEGMENTS * ROWS_PER_SEGMENT
 
 
 def test_every_file_the_manifest_names_exists_and_every_reader_imports(run):

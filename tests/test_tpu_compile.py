@@ -140,15 +140,22 @@ ZONE_SHAPES = {
 }
 
 
+# TPC-H Q15 (benchmark/traffic/tpch_q15_closed.json): a quarter of the clustered date is 6 or 7 of a
+# segment's 128 blocks, padded to 8; 220,000 suppliers are over RADIX_GROUP_CAP and _INPLACE_STATE_CELLS
+Q15 = ("SELECT sum(l_extendedprice*(1-l_discount)) FROM lineitem WHERE l_shipdate >= '1996-01-01' AND "
+       "l_shipdate < '1996-04-01' GROUP BY l_suppkey TOP 1", 8)
+
+
 @pytest.fixture(scope="module")
 def zone_launches():
     """(plan, segment arrays, query inputs) of each launch of ZONE_SHAPES
-    through the zone tier as the executor makes it on the chip, over a
-    tiny lineitem table with a block to match."""
+    and of Q15 through the zone tier as the executor makes it on the
+    chip, over a tiny lineitem table (with its supplier key: the nine
+    older columns are the plain table's) with a block to match."""
     from pinot_tpu.engine import kernel as kernel_mod
     from pinot_tpu.engine.executor import QueryExecutor
     from pinot_tpu.pql import optimize_request, parse_pql
-    from pinot_tpu.tools.datagen import synthetic_lineitem_segment
+    from pinot_tpu.tools.datagen import synthetic_lineitem_keys_segment
 
     launches = {}
     with pytest.MonkeyPatch.context() as mp, jax.enable_x64(False):
@@ -164,15 +171,39 @@ def zone_launches():
             return run_kernel(self, kernel, args, plan, staged, digest, block_ids, *rest, **kw)
 
         mp.setattr(QueryExecutor, "_run_kernel", spy)
-        segs = [synthetic_lineitem_segment(32768, seed=70 + i, name=f"zone{i}") for i in range(2)]
+        segs = [synthetic_lineitem_keys_segment(32768, seed=70 + i, name=f"zone{i}") for i in range(2)]
         try:
-            for name, (pql, _) in ZONE_SHAPES.items():
+            for name, (pql, _) in dict(ZONE_SHAPES, q15=Q15).items():
                 QueryExecutor().execute(segs, optimize_request(parse_pql(pql)))
         finally:
             for cached in (kernel_mod.make_table_kernel, kernel_mod.make_block_table_kernel,
                            kernel_mod.make_packed_block_table_kernel):
                 cached.cache_clear()
     return launches
+
+
+def compile_zone_program(one_chip, launch, nb_pad: int):
+    """A zone launch's block program compiled for the described chip at
+    16 segments of 8,388,608 rows in blocks of 65,536, ``nb_pad``
+    candidate blocks a segment."""
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    plan, segs, q = launch
+    S, n, block = 16, 1 << 23, 1 << 16
+
+    def at_scale(key, v):
+        rows = (n,) + v.shape[2:] if kernel_mod._row_key(key) else v.shape[1:]
+        return jax.ShapeDtypeStruct((S,) + rows, v.dtype, sharding=one_chip)
+
+    segs = {key: at_scale(key, v) for key, v in segs.items()}
+    q = jax.tree_util.tree_map(lambda v: at_scale("", v), q)
+    ids = jax.ShapeDtypeStruct((S, nb_pad), jnp.int32, sharding=one_chip)
+    table = kernel_mod.make_block_table_kernel(plan, block)
+    try:
+        with jax.enable_x64(False):
+            return table.lower(segs, q, ids).compile()
+    finally:
+        kernel_mod.make_block_table_kernel.cache_clear()
 
 
 @pytest.mark.parametrize("shape", sorted(ZONE_SHAPES))
@@ -186,22 +217,32 @@ def test_zone_program_reads_its_blocks_in_place_on_v5e(one_chip, zone_launches, 
 
     monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the radix contraction compiled, not interpreted
-    plan, segs, q = zone_launches[shape]
-    assert kernel_mod.zone_blocks(plan) == "inplace"
-    S, n, block, nb_pad = 16, 1 << 23, 1 << 16, ZONE_SHAPES[shape][1]
-
-    def at_scale(key, v):
-        rows = (n,) + v.shape[2:] if kernel_mod._row_key(key) else v.shape[1:]
-        return jax.ShapeDtypeStruct((S,) + rows, v.dtype, sharding=one_chip)
-
-    segs = {key: at_scale(key, v) for key, v in segs.items()}
-    q = jax.tree_util.tree_map(lambda v: at_scale("", v), q)
-    ids = jax.ShapeDtypeStruct((S, nb_pad), jnp.int32, sharding=one_chip)
-    table = kernel_mod.make_block_table_kernel(plan, block)
-    try:
-        with jax.enable_x64(False):
-            compiled = table.lower(segs, q, ids).compile()
-    finally:
-        kernel_mod.make_block_table_kernel.cache_clear()
+    assert kernel_mod.zone_blocks(zone_launches[shape][0]) == "inplace"
+    compiled = compile_zone_program(one_chip, zone_launches[shape], ZONE_SHAPES[shape][1])
     assert ("tpu_custom_call" in compiled.as_text()) == (shape == "q5")
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_q15_scatters_220000_groups_over_the_gathered_view_on_v5e(one_chip, zone_launches, monkeypatch):
+    """TPC-H Q15's zone program, 8 candidate blocks a segment, 220,000
+    suppliers: the serialised scatter (with the product under the sum)
+    over the gathered copy of the candidate blocks.  What the program
+    keeps in HBM beside its 1.5 GiB of arguments is stated here: 1.10 GiB
+    (the copies of three columns, ``valid`` and ``rowid`` over 8.4M rows,
+    the scatter's indices and updates, [16, 220000] states), a fourteenth
+    of the chip."""
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")
+    plan, segs, _ = zone_launches["q15"]
+    assert plan.group_by.capacity == 220_000 > kernel_mod.RADIX_GROUP_CAP
+    assert kernel_mod.groupby_lowering(plan) == "scatter" and kernel_mod.zone_blocks(plan) == "gathered"
+    assert kernel_mod._state_cells(plan) == 2 * 220_000 > kernel_mod._INPLACE_STATE_CELLS
+    assert plan.group_by.use_gfwd == (True,) and segs["l_suppkey.gfwd"].dtype == jnp.int32  # ids of 4 bytes
+    compiled = compile_zone_program(one_chip, zone_launches["q15"], Q15[1])
+    memory = compiled.memory_analysis()
+    assert "tpu_custom_call" not in compiled.as_text() and "scatter(" in compiled.as_text()
+    # staged: global ids of 4 bytes and two float32 measures a row; l_shipdate is searched, never staged
+    assert 0 <= memory.argument_size_in_bytes - 3 * 16 * (1 << 23) * 4 < 1 << 20
+    assert memory.output_size_in_bytes <= 2 * 220_000 * 4 + 4096
+    assert memory.temp_size_in_bytes < 5 << 28, f"temporaries {memory.temp_size_in_bytes / (1 << 30):.2f} GiB"
