@@ -610,10 +610,11 @@ class DeviceLane:
         program's name.  A coalesced ticket gets neither span: its wait
         is all ``laneWait``.  ``groupby``: a group-by program's lowering
         (``kernel.groupby_lowering``), the launch's ``groupby=`` tag and
-        its ``groupby.lowering.*`` mark; ``operands``: where its operands
-        are built (``kernel.groupby_operands``), the ``operands=`` tag, and
+        its ``groupby.lowering.*`` mark; ``operands``: how its operands
+        reach it (``kernel.groupby_operands``), the ``operands=`` tag, and
         one ``groupby.operands.loop`` mark a launch that builds them in
-        the row loop; ``cells``: its K x m cells (the ``cells=`` tag) and
+        the row loop, one ``groupby.operands.sorted`` mark a launch that
+        puts them in key order; ``cells``: its K x m cells (the ``cells=`` tag) and
         the rows that sharing saved (``kernel.groupby_cells``; one
         ``groupby.slots.shared`` mark a row); ``expr``: how many of the
         plan's aggregates take a compound expression (the ``expr=`` tag);
@@ -1224,8 +1225,8 @@ class DeviceLane:
             ).start()
             if d.groupby and self.metrics is not None:
                 self.metrics.meter(f"groupby.lowering.{d.groupby}").mark()
-                if d.operands == "loop":
-                    self.metrics.meter("groupby.operands.loop").mark()
+                if d.operands in ("loop", "sorted"):
+                    self.metrics.meter(f"groupby.operands.{d.operands}").mark()
                 if d.cells[1]:
                     self.metrics.meter("groupby.slots.shared").mark(d.cells[1])
             if d.blocks and self.metrics is not None:

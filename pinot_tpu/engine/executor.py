@@ -1581,8 +1581,9 @@ class QueryExecutor:
         # a group-by program's lowering and where its operands are built,
         # from the functions the kernel builder asks: the launch's
         # ``groupby=`` and ``operands=`` tags, its ``groupby.lowering.*``
-        # mark and, built in the row loop, its ``groupby.operands.loop``
-        # mark ("" for any other program); and how a zone-tier program
+        # mark and, built in the row loop or put in key order, its
+        # ``groupby.operands.loop|sorted`` mark ("" for any other
+        # program); and how a zone-tier program
         # reads its candidate blocks: the ``blocks=`` tag and the
         # ``zone.blocks.*`` mark
         from pinot_tpu.engine.kernel import groupby_cells, groupby_lowering, groupby_operands, zone_blocks
@@ -1607,8 +1608,8 @@ class QueryExecutor:
                 executing.start()
                 if groupby:
                     self.metrics.meter(f"groupby.lowering.{groupby}").mark()
-                if operands == "loop":
-                    self.metrics.meter("groupby.operands.loop").mark()
+                if operands in ("loop", "sorted"):
+                    self.metrics.meter(f"groupby.operands.{operands}").mark()
                 if cells[1]:
                     self.metrics.meter("groupby.slots.shared").mark(cells[1])
                 if blocks:

@@ -89,23 +89,36 @@ def _grouped_hll_path(capacity: int) -> str:
 
 
 # Dense group-by capacities above MATMUL_GROUP_CAP ride the two-level
-# (radix-128) contraction up to this bound; beyond it the scatter runs.
-# Contraction work grows with K (2 * cols * K flops a row), the
-# scatter's does not.  Q3's shape (occupancy + one float32 sum) on a
-# v5e, ns a row, scatter against _segment_add_radix (chip run, PR 26,
-# 33.5M shuffled rows; at K=2,000 over the cell's 134M sorted rows
-# 17.58 against 0.14):
-#   K = 2^11: 13.62  0.15      K = 2^16: 13.55   2.87
-#   K = 2^14: 13.56  0.76      K = 2^18: 13.56  11.33
-# At 2^18 a second sum costs the contraction 8.5 ns a row and the
-# scatter 6.8, so the crossover lies just under it; 2^16 is the largest
-# measured K that wins at any number of sums (4.7x at one).
-# Above the bound, as a cell sends it (chip run, PR 37,
-# lineitem_topsupplier_closed traced on seed 3700001002: TPC-H Q15,
-# K = 220,000, a product under the sum, 16 x 8 blocks of 65,536 rows
-# through the zone tier's gathered view): the two scatters 56.9 and
-# 55.6 ms a query over 8.39M rows, 13.41 ns a row together, and the
-# gather of the blocks 9.6 ms beside them.
+# (radix-128) contraction: up to this bound over the rows as they stand,
+# beyond it over the rows sorted by group id (groupby_operands 'sorted').
+# Contraction work grows with K (2 * cols * K flops a row); the sort's
+# and the scatter's do not.  Q3's shape (occupancy + one float32 sum) on
+# a v5e, ns a row: the scatter, _segment_add_radix (chip run, PR 26,
+# 33.5M shuffled rows; at K=2,000 over the cell's 134M sorted rows 17.58
+# against 0.14) and _segment_add_sorted (chip run, PR 38, seed
+# 3800003201, 4 segments of 2^23 shuffled rows; radix re-read there:
+# 1.49 at 2^15, 2.90 at 2^16):
+#   K = 2^11: 13.62   0.15            K = 2^17:     -    5.72   3.75
+#   K = 2^14: 13.56   0.76            K = 2^18: 13.56  11.33   3.75
+#   K = 2^15:     -   1.49   3.75     K = 220,000:  -       -  3.75
+#   K = 2^16: 13.55   2.87   3.75
+# The sorted form costs the same at every K: 3.32 of its 3.75 are the
+# sort (lax.sort of an int32 key carrying one float32; a second sum
+# carried costs 1.9 more, 5.66 against the contraction's 5.09 at 2^16).
+# So on segments of 2^23 rows the crossover lies near K = 85,000 at one
+# sum and 73,000 at two, and 2^16 stays the largest measured K the
+# contraction over the rows as they stand wins.  The sort's cost a row falls with
+# the rows a segment sorts (log^2 n stages): over 16 x 524,288 rows, as
+# the zone tier's gathered view hands TPC-H Q15 its candidate blocks
+# (K = 220,000, a product under the sum, 5.64M of 8.39M rows valid;
+# chip runs, PR 38, seeds 3800001001 and 3800003201), the sorted form
+# takes 11.6 to 11.75 ms, 1.40 ns a row (the sort 8.9, the windowed
+# contraction 2.4 to 2.9; 11.70 with every row on one key, 11.03 with
+# 0.7% of the rows valid), the contraction at K = 220,000 without the
+# sort 82.3 (9.81), the parent's two scatters 113.8 (13.57; in the cell
+# 56.9 and 55.6 ms a query, PR 37) and a sort followed by
+# segment_sum(indices_are_sorted=True) 144.5: the scatter stays serial
+# whatever it is told.
 RADIX_GROUP_CAP = 1 << 16
 _RADIX = 128
 # VMEM the generated one-hots of one grid step may take (the block of
@@ -115,6 +128,16 @@ _RADIX = 128
 _RADIX_STEP_BYTES = 12 << 20
 _RADIX_VMEM_LIMIT = 64 << 20
 _RADIX_BLOCK_MAX = 8192  # rows a step: 18.8, 21.3, 27.6 ms at 8192, 4096, 2048 (Q3, 134M rows)
+# rows in key order (_segment_add_sorted): rows a step and the sublanes
+# (x 128 keys) of the window its hi one-hot spans (chip run, PR 38, Q15's
+# shape, ms: 11.63 at 8192 x 64; 11.70 at 4096 x 64; 13.52 at 16384 x 64;
+# 13.57 at 8192 x 128; 13.73 at 16384 x 128); the VMEM one call's
+# accumulator may take, and the weight columns a call at most (a step's
+# one-hot is [(1 + 3 cols) x window, rows a step] bfloat16 beside it)
+_SORTED_BLOCK = 8192
+_SORTED_WINDOW = 64
+_SORTED_ACC_BYTES = 20 << 20
+_SORTED_COLS_MAX = 3
 
 
 def groupby_lowering(plan: StaticPlan) -> Optional[str]:
@@ -125,19 +148,21 @@ def groupby_lowering(plan: StaticPlan) -> Optional[str]:
 
     'onehot':  K <= MATMUL_GROUP_CAP, one chunked [cols, chunk] @
                [chunk, K] contraction (_segment_add_matmul_multi).
-    'radix':   K <= RADIX_GROUP_CAP, the two-level contraction with
-               float32-faithful weights (_segment_add_radix).
-    'scatter': above the bound, and on the CPU backend unless
-               PINOT_TPU_GROUPBY_MATMUL=1 (the tests' switch).
+    'radix':   every larger K: the two-level contraction with
+               float32-faithful weights, over the rows as they stand up
+               to RADIX_GROUP_CAP (_segment_add_radix) and over the rows
+               in key order above it (_segment_add_sorted; what
+               groupby_operands says).
+    'scatter': the CPU backend, unless PINOT_TPU_GROUPBY_MATMUL=1 (the
+               tests' switch) forces the chip's lowerings.
     None for a plan without a group-by.  min, max, minmaxrange,
     presence, hist and HLL aggregates keep _group_state on every
     lowering."""
     if getattr(plan, "group_by", None) is None:
         return None
-    cap = plan.group_by.capacity
-    if not _use_matmul_groupby() or cap > RADIX_GROUP_CAP:
+    if not _use_matmul_groupby():
         return "scatter"
-    return "onehot" if cap <= MATMUL_GROUP_CAP else "radix"
+    return "onehot" if plan.group_by.capacity <= MATMUL_GROUP_CAP else "radix"
 
 
 def _sum_shaped(agg: StaticAgg) -> bool:
@@ -209,10 +234,10 @@ _LOOP_CELLS = 64
 
 
 def groupby_operands(plan: StaticPlan) -> Optional[str]:
-    """Where a dense group-by's operands (filter mask, group key, weight
-    columns) are built, from what the plan states — consulted by the
-    kernel builder and by the launch's ``operands=`` tag and
-    ``groupby.operands.loop`` meter, which must agree.
+    """How a dense group-by's operands (filter mask, group key, weight
+    columns) reach its lowering, from what the plan states — consulted
+    by the kernel builder and by the launch's ``operands=`` tag and
+    ``groupby.operands.loop|sorted`` meters, which must agree.
 
     'loop':   the 'onehot' lowering of a plan whose every output adds
               over blocks of rows (count, sum, avg; single-value keys;
@@ -221,12 +246,19 @@ def groupby_operands(plan: StaticPlan) -> Optional[str]:
               segment's program, each step filters, keys and reduces
               one block of the staged columns (_make_loop_groupby_kernel),
               and no segment-sized intermediate reaches HBM.
+    'sorted': the 'radix' lowering above RADIX_GROUP_CAP: the operands
+              are built over the whole segment and put in key order
+              (one sort by group id carrying the weight columns), so
+              that a block of rows contracts over a window of keys and
+              not over all K (_segment_add_sorted).
     'staged': every other group-by: the operands are built over the
-              whole segment and handed to the lowering.
+              whole segment and handed to the lowering as they stand.
     None for a plan without a group-by."""
     lowering = groupby_lowering(plan)
     if lowering is None:
         return None
+    if lowering == "radix" and plan.group_by.capacity > RADIX_GROUP_CAP:
+        return "sorted"
     additive = (
         plan.selection is None
         and not any(plan.group_by.col_is_mv)
@@ -234,6 +266,46 @@ def groupby_operands(plan: StaticPlan) -> Optional[str]:
     )
     cells = plan.group_by.capacity * _contraction_slots(plan)[1]
     return "loop" if lowering == "onehot" and additive and cells <= _LOOP_CELLS else "staged"
+
+
+def _exact_parts(idx, w_refs, capacity: int):
+    """The rows [1, block] that a step of the two-level contraction
+    scales its hi one-hot by: the validity, then three bfloat16-exact
+    parts a float32 weight (8 + 8 + 8 significand bits, each the top 16
+    bits of what is left, taken by mask: a rounding convert is a round
+    trip XLA may elide), which sum to the weight exactly."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def top(x):
+        bits = pltpu.bitcast(x, jnp.uint32) & jnp.uint32(0xFFFF0000)
+        return pltpu.bitcast(bits, jnp.float32)
+
+    parts = [(idx < capacity).astype(jnp.float32)]
+    for w_ref in w_refs:
+        w1 = top(w_ref[...])
+        r = w_ref[...] - w1
+        w2 = top(r)
+        parts.extend([w1, w2, r - w2])
+    return parts
+
+
+def _states_of_parts(acc):
+    """[1 + 3 m, K] sums of _exact_parts' rows -> the states' rows: the
+    occupancy, then a weight's three parts added smallest first."""
+    return [acc[0]] + [(acc[j + 2] + acc[j + 1]) + acc[j] for j in range(1, acc.shape[0], 3)]
+
+
+def _whole_blocks(flat_idx, weights, blk: int, capacity: int):
+    """int32 buckets and float32 weight columns padded to whole blocks
+    of ``blk`` rows with rows that count nowhere (the sentinel bucket
+    ``capacity``, zero weights)."""
+    flat_idx = flat_idx.astype(jnp.int32)
+    weights = [w.astype(jnp.float32) for w in weights]
+    pad = (-flat_idx.shape[0]) % blk
+    if pad:
+        flat_idx = jnp.concatenate([flat_idx, jnp.full(pad, capacity, jnp.int32)])
+        weights = [jnp.concatenate([w, jnp.zeros(pad, w.dtype)]) for w in weights]
+    return flat_idx, weights
 
 
 def _segment_add_radix(flat_idx, weights, capacity: int):
@@ -267,7 +339,6 @@ def _segment_add_radix(flat_idx, weights, capacity: int):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n = flat_idx.shape[0]
     K1 = -(-capacity // _RADIX)  # the sentinel lands in the padded tail or past it
     K1p = -(-K1 // 16) * 16  # a bfloat16 tile is 16 sublanes
     n_parts = 1 + 3 * len(weights)
@@ -276,16 +347,7 @@ def _segment_add_radix(flat_idx, weights, capacity: int):
     # and select beside it, and B^T's column with its iota
     blk = _RADIX_STEP_BYTES // (rows * 2 + K1p * 12 + _RADIX * 6)
     blk = max(256, min(_RADIX_BLOCK_MAX, 1 << (blk.bit_length() - 1)))
-    flat_idx = flat_idx.astype(jnp.int32)
-    weights = [w.astype(jnp.float32) for w in weights]
-    pad = (-n) % blk
-    if pad:
-        flat_idx = jnp.concatenate([flat_idx, jnp.full(pad, capacity, jnp.int32)])
-        weights = [jnp.concatenate([w, jnp.zeros(pad, w.dtype)]) for w in weights]
-
-    def top(x):
-        bits = pltpu.bitcast(x, jnp.uint32) & jnp.uint32(0xFFFF0000)
-        return pltpu.bitcast(bits, jnp.float32)
+    flat_idx, weights = _whole_blocks(flat_idx, weights, blk, capacity)
 
     def kernel(idx_ref, *refs):
         w_refs, acc_ref = refs[:-1], refs[-1]
@@ -297,12 +359,7 @@ def _segment_add_radix(flat_idx, weights, capacity: int):
         idx = idx_ref[...]  # [1, blk]: rows along the lanes
         hi = jax.lax.broadcasted_iota(jnp.int32, (K1p, blk), 0) == (idx >> 7)
         lo = jax.lax.broadcasted_iota(jnp.int32, (_RADIX, blk), 0) == (idx & (_RADIX - 1))
-        parts = [(idx < capacity).astype(jnp.float32)]
-        for w_ref in w_refs:
-            w1 = top(w_ref[...])
-            r = w_ref[...] - w1
-            w2 = top(r)
-            parts.extend([w1, w2, r - w2])
+        parts = _exact_parts(idx, w_refs, capacity)
         a_t = jnp.concatenate(
             [jnp.where(hi, p, 0.0).astype(jnp.bfloat16) for p in parts], axis=0
         )
@@ -323,9 +380,89 @@ def _segment_add_radix(flat_idx, weights, capacity: int):
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_RADIX_VMEM_LIMIT),
     )(flat_idx.reshape(1, -1), *[w.reshape(1, -1) for w in weights])
     acc = acc.reshape(n_parts, K1p * _RADIX)[:, :capacity]
-    states = [acc[0]]
-    for j in range(1, n_parts, 3):
-        states.append((acc[j + 2] + acc[j + 1]) + acc[j])
+    return jnp.stack(_states_of_parts(acc)).astype(config.float_dtype())
+
+
+def _segment_add_sorted(flat_idx, weights, capacity: int):
+    """_segment_add_radix's states for a ``capacity`` whose contraction
+    would cost more than putting the rows in key order first
+    (groupby_operands 'sorted'): ONE sort of the rows by bucket carrying
+    the weight columns, then the same two-level contraction with its hi
+    one-hot cut to a window of ``_SORTED_WINDOW`` sublanes (x 128 keys).
+
+    A grid step's block of sorted rows has its first and last key known
+    before the call (scalar prefetch), so the step builds ``hi`` over the
+    window at the first key's sublane (aligned down to 8), adds the
+    product into the accumulator at that dynamic sublane offset, and
+    moves the window on while the block's last key lies past it.  A
+    window either ends a block or moves ``_SORTED_WINDOW`` x 128 keys on,
+    so a segment takes at most ``rows / block + capacity / (window keys)``
+    products whatever its keys are: the work no longer grows with K
+    times the rows.  Filtered rows carry ``flat_idx == capacity`` and
+    zero weights and sort to the end; a block of them alone does nothing.
+
+    Weights, counts and precision are _segment_add_radix's: validity and
+    three exact bfloat16 parts a weight, float32 sums.  The whole
+    accumulator stays in VMEM; past ``_SORTED_ACC_BYTES`` of it the
+    weight columns go in groups, a call a group over the same sorted rows."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    W, blk = _SORTED_WINDOW, _SORTED_BLOCK
+    flat_idx, weights = _whole_blocks(flat_idx, weights, blk, capacity)
+    flat_idx, *weights = jax.lax.sort((flat_idx, *weights), num_keys=1, is_stable=False)
+    blocks = flat_idx.reshape(-1, blk)
+    firsts = blocks[:, 0]
+    lasts = jnp.max(jnp.where(blocks < capacity, blocks, -1), axis=1)  # -1: no row of the block counts
+    K1 = -(-capacity // _RADIX)
+    K1p = -(-(K1 + W) // 16) * 16  # the last window may start at the last key's sublane
+
+    def kernel(first_ref, last_ref, idx_ref, *refs):
+        w_refs, acc_ref = refs[:-1], refs[-1]
+        i = pl.program_id(0)
+
+        @pl.when(i == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        idx = idx_ref[...]  # [1, blk]: rows along the lanes
+        lo = (jax.lax.broadcasted_iota(jnp.int32, (_RADIX, blk), 0) == (idx & (_RADIX - 1))).astype(jnp.bfloat16)
+        parts = _exact_parts(idx, w_refs, capacity)
+        hi_digit = idx >> 7
+        base0 = (first_ref[i] >> 10) << 3
+        windows = jnp.where(last_ref[i] < 0, 0, ((last_ref[i] >> 7) - base0) // W + 1)
+
+        def window(j, carry):
+            base = pl.multiple_of(base0 + j * W, 8)
+            hi = jax.lax.broadcasted_iota(jnp.int32, (W, blk), 0) == (hi_digit - base)
+            a_t = jnp.concatenate([jnp.where(hi, p, 0.0).astype(jnp.bfloat16) for p in parts], axis=0)
+            prod = jax.lax.dot_general(a_t, lo, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            for c in range(len(parts)):
+                acc_ref[c, pl.ds(base, W), :] += prod[c * W:(c + 1) * W]
+            return carry
+
+        jax.lax.fori_loop(0, windows, window, 0)
+
+    def call(ws):
+        n_parts = 1 + 3 * len(ws)
+        acc = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(blocks.shape[0],),
+                in_specs=[pl.BlockSpec((1, blk), lambda i, *_: (0, i))] * (1 + len(ws)),
+                out_specs=pl.BlockSpec((n_parts, K1p, _RADIX), lambda i, *_: (0, 0, 0)),
+            ),
+            out_shape=jax.ShapeDtypeStruct((n_parts, K1p, _RADIX), jnp.float32),
+            interpret=jax.default_backend() == "cpu",
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_RADIX_VMEM_LIMIT),
+        )(firsts, lasts, flat_idx.reshape(1, -1), *[w.reshape(1, -1) for w in ws])
+        return _states_of_parts(acc.reshape(n_parts, K1p * _RADIX)[:, :capacity])
+
+    per = max(1, min(_SORTED_COLS_MAX, (_SORTED_ACC_BYTES // (K1p * _RADIX * 4) - 1) // 3))  # weight columns a call
+    states = call(weights[:per])
+    for at in range(per, len(weights), per):
+        states.extend(call(weights[at:at + per])[1:])
     return jnp.stack(states).astype(config.float_dtype())
 
 
@@ -1070,6 +1207,8 @@ def make_single_segment_kernel(plan: StaticPlan) -> Callable:
                 flat_idx, cols = _contraction_operands(plan, seg, mask, keys, kvalid, zeroed=True)
                 if lowering == "onehot":
                     states = _segment_add_matmul_multi(flat_idx, jnp.stack(cols), cap)
+                elif groupby_operands(plan) == "sorted":
+                    states = _segment_add_sorted(flat_idx, cols[1:], cap)
                 else:
                     states = _segment_add_radix(flat_idx, cols[1:], cap)
                 _contraction_outputs(plan, states, out)

@@ -614,13 +614,18 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "groupby.lowering.onehot": "group-by launches on the one-level "
     "one-hot contraction (K <= MATMUL_GROUP_CAP)",
     "groupby.lowering.radix": "group-by launches on the two-level "
-    "(radix-128) contraction with float32-faithful weights "
-    "(K <= RADIX_GROUP_CAP)",
+    "(radix-128) contraction with float32-faithful weights (every K above "
+    "MATMUL_GROUP_CAP; above RADIX_GROUP_CAP over the rows in key order: "
+    "groupby.operands.sorted)",
     "groupby.lowering.scatter": "group-by launches on the serialised "
-    "scatter (K above the radix bound, or the CPU backend)",
+    "scatter (the CPU backend)",
     "groupby.operands.loop": "group-by launches whose filter mask, key "
     "and weight columns are built inside the group-by's row loop "
     "(engine/kernel.py groupby_operands; the launch's ``operands=`` tag)",
+    "groupby.operands.sorted": "group-by launches over more keys than "
+    "RADIX_GROUP_CAP, whose rows are sorted by group id with their weight "
+    "columns so that a block of them contracts over a window of keys "
+    "(engine/kernel.py groupby_operands; ``operands=sorted``)",
     # how a zone-tier launch read its candidate blocks, one mark a launch
     # (engine/kernel.py zone_blocks; the launch's ``blocks=`` tag)
     "zone.blocks.inplace": "zone-tier launches whose program loops over "

@@ -117,13 +117,15 @@ def test_cell_rehearses_on_the_cpu(capsys, monkeypatch, run, cut_manifest, cell,
     missing = [m["name"] for m in listed if read.get(m["name"]) is None]
     assert not missing, f"per_layer readers of {cell} that found nothing to read: {missing}"
     assert out["metrics"]["compiles_in_window"]["value"] == 0
-    # Q15 groups by 220,000 keys: the scatter, and a segment's state (occupancy and one sum) over
+    # Q15 groups by 220,000 keys: on the CPU the scatter (on the chip the rows in key order for the
+    # contraction, PR 38: both shares read 100 there), and a segment's state (occupancy and one sum) over
     # kernel._INPLACE_STATE_CELLS, so the zone tier's gathered view; the product under the sum in the kernel
     top_supplier = cell == "lineitem_topsupplier_closed"
     if "zone_inplace_share" in read:  # the other closed cells: Q5 and TPC-H Q6 read their blocks in place
         assert read["zone_inplace_share"] == (0.0 if top_supplier else 100.0)
     if top_supplier:
-        assert read["groupby_contraction_share"] == 0.0 and read["expr_device_share"] == 100.0
+        assert read["groupby_contraction_share"] == 0.0 and read["groupby_sorted_share"] == 0.0
+        assert read["expr_device_share"] == 100.0
         assert read["groups_kept_mean"] == 100 and 0 < read["groups_live_mean"] <= SEGMENTS * ROWS_PER_SEGMENT
 
 

@@ -377,8 +377,8 @@ RADIX_CASES = {
                                    "SELECT sum(qty) FROM rx GROUP BY day, tags", "radix", "scan"),
     "bound_65536_two_keys": (7, 3000, "shuffled", {"a_card": 256},
                              "SELECT sum(price), count(*) FROM rx GROUP BY a, b", "radix", "scan"),
-    "above_bound_scatter": (7, 3000, "shuffled", {"a_card": 257},
-                            "SELECT sum(price), count(*) FROM rx GROUP BY a, b", "scatter", "scan"),
+    "above_bound_sorted": (7, 3000, "shuffled", {"a_card": 257},
+                           "SELECT sum(price), count(*) FROM rx GROUP BY a, b", "radix", "scan"),
     "k256_onehot_untouched": (256, 1500, "shuffled", {}, "SELECT sum(price), count(*) FROM rx GROUP BY day",
                               "onehot", "scan"),
 }
@@ -454,10 +454,11 @@ def _assert_states_and_reply(forced, scattered, oracle):
 @pytest.mark.parametrize("case", sorted(RADIX_CASES))
 def test_radix_groupby_forced(monkeypatch, case):
     """Dense group-bys above the one-level gate ride the two-level
-    (radix-128) contraction on the chip; forced on here so that CPU CI
-    holds it to the oracle: the lowering the gate names, occupancy
-    equal to the scatter's, counts exact, and float32 sums within 2e-6
-    of a float64 sum over prices that bfloat16 cannot hold."""
+    (radix-128) contraction on the chip, above ``RADIX_GROUP_CAP`` over
+    the rows in key order; forced on here so that CPU CI holds it to
+    the oracle: the lowering the gate names, occupancy equal to the
+    scatter's, counts exact, and float32 sums within 2e-6 of a float64
+    sum over prices that bfloat16 cannot hold."""
     from pinot_tpu.engine import kernel as kernel_mod
 
     K, n, order, gen, pql, lowering, tier = RADIX_CASES[case]
@@ -467,12 +468,87 @@ def test_radix_groupby_forced(monkeypatch, case):
     assert forced["lowering"] == lowering and scattered["lowering"] == "scatter"
     assert forced["tier"] == scattered["tier"] == tier
     cap = forced["plan"].group_by.capacity
-    assert (cap > kernel_mod.RADIX_GROUP_CAP) == (lowering == "scatter")
+    assert forced["operands"] == ("sorted" if cap > kernel_mod.RADIX_GROUP_CAP else "staged")
+    assert (cap > kernel_mod.RADIX_GROUP_CAP) == case.startswith("above_bound") and scattered["operands"] == "staged"
     if case.startswith("bound"):
         assert cap == kernel_mod.RADIX_GROUP_CAP
     _assert_states_and_reply(forced, scattered, oracle)
     if case == "k2000_empty_match":
         assert not any(_group_table(oracle).values()) and not forced["outs"]["gb_presence"].any()
+
+
+# id: (rows of _radix_rows, PQL after FROM, tier, rows a step of the sorted
+# contraction).  GROUP BY a, b has a_card x 256 cells; rows with ``flag`` 1
+# all fall on (a, b) = (3, 5), about a tenth of them: a run of one key
+SORTED_CASES = {
+    "uniform_keys": (dict(K=7, n=3000, order="shuffled", a_card=300),
+                     "SELECT sum(price), count(*) FROM rx WHERE flag = 0 GROUP BY a, b", "scan", 256),
+    "every_row_one_key": (dict(K=7, n=6000, order="shuffled", a_card=300),
+                          "SELECT sum(price), count(*) FROM rx WHERE flag = 1 GROUP BY a, b", "scan", 128),
+    # 2^20 keys and 5,000 rows in blocks of 512: a block spans some 100,000 keys, a dozen windows of 8,192
+    "few_rows_over_the_whole_range_k_2_20": (dict(K=5000, n=5000, order="shuffled", a_card=4096),
+                                             "SELECT sum(price), count(*) FROM rx GROUP BY a, b", "scan", 512),
+    # the run of (3, 5) is some 300 rows in each segment, a block 128: it fills blocks and crosses their edges
+    "a_run_crosses_block_edges": (dict(K=7, n=6000, order="shuffled", a_card=300),
+                                  "SELECT sum(price), count(*) FROM rx GROUP BY a, b", "scan", 128),
+    "empty_match": (dict(K=7, n=3000, order="shuffled", a_card=300),
+                    "SELECT sum(price), count(*) FROM rx WHERE flag = 1 AND day = 3 GROUP BY a, b", "scan", 256),
+    "multi_value_key": (dict(K=7, n=2500, order="shuffled", a_card=300, tag_card=300),
+                        "SELECT sum(price), count(*) FROM rx GROUP BY a, tags", "scan", 256),
+    # the slots rule: sum(price) and avg(price) read one row, the avg's count the occupancy: m = 3
+    "two_sums_and_an_avg_share_rows": (dict(K=7, n=3000, order="shuffled", a_card=300),
+                                       "SELECT sum(price), sum(qty), avg(price), count(*) FROM rx GROUP BY a, b",
+                                       "scan", 256),
+    "max_beside_a_sum": (dict(K=7, n=3000, order="shuffled", a_card=300),
+                         "SELECT sum(price), max(qty), min(price) FROM rx GROUP BY a, b", "scan", 256),
+    # 65,537 is prime: one key column of that many values
+    "k_one_over_the_bound": (dict(K=65537, n=66000, order="shuffled"),
+                             "SELECT sum(price), count(*) FROM rx GROUP BY day", "scan", 8192),
+    # a filter on the sorted column: the zone tier's loop in place sorts a block of rows a step
+    "zone_tier_in_place": (dict(K=2000, n=6000, order="sorted", a_card=300),
+                           "SELECT sum(price), count(*) FROM rx WHERE day BETWEEN 300 AND 420 GROUP BY a, b",
+                           "zone", 256),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SORTED_CASES))
+def test_sorted_groupby_forced(monkeypatch, case):
+    """A dense group-by over more keys than ``RADIX_GROUP_CAP`` sorts its
+    rows by group id and contracts each block over a window of keys on
+    the chip (``groupby_operands`` 'sorted'); forced on here and held to
+    the scatter's states (occupancy and counts exact) and the float64
+    oracle's reply (sums within 2e-6).  ``min`` and ``max`` keep
+    ``_group_state``; the CPU's own answer stays the scatter."""
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    gen, pql, tier, block = SORTED_CASES[case]
+    rows = _radix_rows(**gen)
+    for row in rows:
+        if row["flag"]:
+            row["a"], row["b"] = 3, 5
+    monkeypatch.setenv("PINOT_TPU_ZONE_BLOCK", "256")
+    monkeypatch.setattr(kernel_mod, "_SORTED_BLOCK", block)
+    forced, scattered, oracle = _forced_and_scattered(monkeypatch, case, rows, pql + " TOP 2000000")
+    cap = forced["plan"].group_by.capacity
+    assert cap > kernel_mod.RADIX_GROUP_CAP and forced["tier"] == scattered["tier"] == tier
+    assert (forced["lowering"], forced["operands"]) == ("radix", "sorted")
+    assert (scattered["lowering"], scattered["operands"]) == ("scatter", "staged")
+    if case.startswith("k_one_over"):
+        assert cap == kernel_mod.RADIX_GROUP_CAP + 1
+    if case.endswith("k_2_20"):
+        assert cap == 1 << 20
+    if case == "zone_tier_in_place":
+        assert kernel_mod.zone_blocks(forced["plan"]) == "inplace"
+    if case == "two_sums_and_an_avg_share_rows":
+        assert kernel_mod._contraction_slots(forced["plan"]) == ({0: [1], 1: [2], 2: [1, 0], 3: [0]}, 3)
+    _assert_states_and_reply(forced, scattered, oracle)
+    live = int(forced["outs"]["gb_presence"].sum())
+    if case == "every_row_one_key":
+        assert live == 1
+    elif case == "empty_match":
+        assert live == 0 and not any(_group_table(oracle).values())
+    else:
+        assert live > 100
 
 
 # id: (rows of _radix_rows, PQL, tier, what the case sets: the zone tier's

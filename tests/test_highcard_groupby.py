@@ -4,9 +4,10 @@ server against the benchmark's plain reference
 (``benchmark/reference_tpch_keys.py``: numpy, float64, nothing of the
 program), at 70,000 suppliers (a segment's states fold a block at a time:
 the zone tier ``inplace``) and at 300,000 (over ``_INPLACE_STATE_CELLS``:
-``gathered``); both above ``RADIX_GROUP_CAP``, so the serialised scatter
-on the chip as here.  What the launch's tags and marks say is what the
-kernel builder asks; the generator's nine older columns are the plain
+``gathered``); both above ``RADIX_GROUP_CAP``: on the chip the rows are put
+in key order for the contraction (PR 38; forced on here in one case), on
+the CPU the scatter adds them up.  What the launch's tags and marks say
+is what the kernel builder asks; the generator's nine older columns are the plain
 lineitem's."""
 import importlib.util
 import json
@@ -229,8 +230,10 @@ def test_the_full_scan_equals_the_zone_tier(served, monkeypatch):
 @pytest.mark.parametrize("lowerings", ["the_cpus", "the_chips_forced"])
 def test_the_lowering_the_zone_form_and_the_marks_agree(table, monkeypatch, lowerings):
     """What the kernel builder asks (``groupby_lowering``, ``zone_blocks``)
-    is what the launch is tagged and marked with; with the chip's
-    lowerings forced, both K stay over ``RADIX_GROUP_CAP``: the scatter."""
+    is what the launch is tagged and marked with.  Both K are over
+    ``RADIX_GROUP_CAP``: with the chip's lowerings forced the rows are
+    put in key order for the contraction (``radix`` / ``sorted``); the
+    CPU's own answer stays the scatter."""
     from pinot_tpu.engine.executor import QueryExecutor
 
     suppliers, segments, ref = table
@@ -252,16 +255,19 @@ def test_the_lowering_the_zone_form_and_the_marks_agree(table, monkeypatch, lowe
         held(resp.to_json(), "q15_1996q1", ref)
         (plan,), (launch,) = plans, launches(resp, server)
         assert plan.group_by.capacity == suppliers > kernel_mod.RADIX_GROUP_CAP
-        assert kernel_mod.groupby_lowering(plan) == launch["tags"]["groupby"] == "scatter"
+        forced = lowerings == "the_chips_forced"
+        assert kernel_mod.groupby_lowering(plan) == launch["tags"]["groupby"] == ("radix" if forced else "scatter")
         assert kernel_mod.zone_blocks(plan) == launch["tags"]["blocks"] == ZONE_FORM[suppliers]
         assert (kernel_mod._state_cells(plan) <= kernel_mod._INPLACE_STATE_CELLS) == (ZONE_FORM[suppliers] == "inplace")
-        assert kernel_mod.groupby_operands(plan) == launch["tags"]["operands"] == "staged"
+        assert kernel_mod.groupby_operands(plan) == launch["tags"]["operands"] == ("sorted" if forced else "staged")
         assert kernel_mod.groupby_cells(plan) == (launch["tags"]["cells"], 0)
         marks = {m: server.metrics.meter(m).count for m in (
             "groupby.lowering.scatter", "groupby.lowering.radix", "groupby.lowering.onehot", "groupby.operands.loop",
+            "groupby.operands.sorted",
             "zone.blocks.inplace", "zone.blocks.gathered", "agg.expr.device", "agg.expr.host")}
-        assert marks == {"groupby.lowering.scatter": 1, "groupby.lowering.radix": 0, "groupby.lowering.onehot": 0,
-                         "groupby.operands.loop": 0, "zone.blocks.inplace": int(ZONE_FORM[suppliers] == "inplace"),
+        assert marks == {"groupby.lowering.scatter": int(not forced), "groupby.lowering.radix": int(forced),
+                         "groupby.lowering.onehot": 0, "groupby.operands.loop": 0,
+                         "groupby.operands.sorted": int(forced), "zone.blocks.inplace": int(ZONE_FORM[suppliers] == "inplace"),
                          "zone.blocks.gathered": int(ZONE_FORM[suppliers] == "gathered"),
                          "agg.expr.device": 1, "agg.expr.host": 0}
         assert server.executor.healing_stats()["hostFailovers"] == 0
@@ -270,10 +276,15 @@ def test_the_lowering_the_zone_form_and_the_marks_agree(table, monkeypatch, lowe
         forget_programs()
 
 
-def test_q15_on_a_mesh_of_four(table):
+@pytest.mark.parametrize("lowerings", ["the_cpus", "the_chips_forced"])
+def test_q15_on_a_mesh_of_four(table, monkeypatch, lowerings):
     """The same plan through ``shard_map``: a segment a chip, the states
-    merged across chips."""
+    merged across chips; a chip sorts its own segment's rows, so the
+    chip's lowering needs no collective of its own."""
     suppliers, segments, ref = table
+    forced = lowerings == "the_chips_forced"
+    if forced:
+        monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")
     forget_programs()
     broker = single_server_broker("lineitem", segments, topology=build_topology(jax.devices()[:4], 1, 4))
     server = broker.local_servers[0]
@@ -282,7 +293,9 @@ def test_q15_on_a_mesh_of_four(table):
             resp = broker.handle_pql(PQL[name], trace=True)
             held(resp.to_json(), name, ref)
             (launch,) = launches(resp, server)
-            assert launch["tags"]["groupby"] == "scatter" and launch["tags"]["blocks"] == ZONE_FORM[suppliers]
+            assert (launch["tags"]["groupby"], launch["tags"]["operands"]) == (
+                ("radix", "sorted") if forced else ("scatter", "staged"))
+            assert launch["tags"]["blocks"] == ZONE_FORM[suppliers]
         assert server.executor.healing_stats()["hostFailovers"] == 0
     finally:
         server.shutdown()

@@ -24,7 +24,9 @@ def one_chip():
 
 
 # (groups, segments, rows a segment, float columns): the closed cell's Q3
-# and Q4, the gate's bound with two sums and with ten, a ragged row count
+# and Q4, the gate's bound with two sums and with ten, a ragged row count;
+# above the bound the rows in key order: Q15's gathered view, the largest
+# capacity a device group-by has (its accumulator in VMEM), weight columns in groups
 SHAPES = {
     "q3_k2000": (2000, 16, 1 << 23, 1),
     "q4_k2000": (2000, 16, 1 << 23, 2),
@@ -32,6 +34,9 @@ SHAPES = {
     "bound_two_sums": (1 << 16, 4, 1 << 23, 2),
     "bound_ten_sums": (1 << 16, 2, 1 << 22, 10),
     "ragged_rows": (2000, 4, 3 * (1 << 20) + 5, 1),
+    "sorted_q15_k220000": (220_000, 16, 1 << 19, 1),
+    "sorted_k1048576_two_sums": (1 << 20, 2, 1 << 19, 2),
+    "sorted_k65537_four_sums_ragged": (65_537, 2, (1 << 19) + 5, 4),
 }
 
 
@@ -40,13 +45,14 @@ def test_radix_contraction_compiles_for_v5e(one_chip, monkeypatch, shape):
     from pinot_tpu.engine import kernel as kernel_mod
 
     K, S, n, m = SHAPES[shape]
-    assert K <= kernel_mod.RADIX_GROUP_CAP
+    assert (K > kernel_mod.RADIX_GROUP_CAP) == shape.startswith("sorted")
+    segment_add = kernel_mod._segment_add_sorted if shape.startswith("sorted") else kernel_mod._segment_add_radix
     # the kernel asks the backend whether to run in the Pallas
     # interpreter; this compile is for the described chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def table(idx, *ws):
-        single = lambda i, *xs: kernel_mod._segment_add_radix(i, [jnp.where(i < K, x, 0) for x in xs], K)
+        single = lambda i, *xs: segment_add(i, [jnp.where(i < K, x, 0) for x in xs], K)
         return jnp.sum(jax.vmap(single)(idx, *ws), axis=0)
 
     idx = jax.ShapeDtypeStruct((S, n), jnp.int32, sharding=one_chip)
@@ -55,8 +61,10 @@ def test_radix_contraction_compiles_for_v5e(one_chip, monkeypatch, shape):
         compiled = jax.jit(table).lower(idx, *([w] * m)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # the one-hots stay in VMEM: what the program keeps in HBM beside its
-    # arguments is the masked weight columns and no [rows, K1 + 128] operand
-    assert compiled.memory_analysis().temp_size_in_bytes <= (m + 1) * S * n * 4 + (64 << 20)
+    # arguments is the masked weight columns (in key order: the sort's
+    # operands, in and out) and no [rows, K1 + 128] operand
+    copies = 2 if shape.startswith("sorted") else 1
+    assert compiled.memory_analysis().temp_size_in_bytes <= copies * (m + 1) * S * n * 4 + (64 << 20)
 
 
 # the open cell's two group-bys (benchmark/traffic/suite_open.json: k6, q6) and TPC-H Q1 as the
@@ -223,26 +231,31 @@ def test_zone_program_reads_its_blocks_in_place_on_v5e(one_chip, zone_launches, 
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-def test_q15_scatters_220000_groups_over_the_gathered_view_on_v5e(one_chip, zone_launches, monkeypatch):
+def test_q15_sorts_220000_groups_over_the_gathered_view_on_v5e(one_chip, zone_launches, monkeypatch):
     """TPC-H Q15's zone program, 8 candidate blocks a segment, 220,000
-    suppliers: the serialised scatter (with the product under the sum)
-    over the gathered copy of the candidate blocks.  What the program
-    keeps in HBM beside its 1.5 GiB of arguments is stated here: 1.10 GiB
-    (the copies of three columns, ``valid`` and ``rowid`` over 8.4M rows,
-    the scatter's indices and updates, [16, 220000] states), a fourteenth
-    of the chip."""
+    suppliers: over the gathered copy of the candidate blocks the rows
+    are sorted by supplier with the product they carry, and a block of
+    them contracts over a window of keys (PR 38).  No count, sum or avg
+    reaches a scatter.  What the program keeps in HBM beside its 1.5 GiB
+    of arguments is stated here: under the 1.10 GiB of the scatter's
+    program (the copies of three columns, ``valid`` and ``rowid`` over
+    8.4M rows, the sort's operands, [16, 4, 1792, 128] accumulators)."""
     from pinot_tpu.engine import kernel as kernel_mod
 
     monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", "1")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the contraction compiled, not interpreted
     plan, segs, _ = zone_launches["q15"]
     assert plan.group_by.capacity == 220_000 > kernel_mod.RADIX_GROUP_CAP
-    assert kernel_mod.groupby_lowering(plan) == "scatter" and kernel_mod.zone_blocks(plan) == "gathered"
+    assert kernel_mod.groupby_lowering(plan) == "radix" and kernel_mod.zone_blocks(plan) == "gathered"
+    assert kernel_mod.groupby_operands(plan) == "sorted"
     assert kernel_mod._state_cells(plan) == 2 * 220_000 > kernel_mod._INPLACE_STATE_CELLS
     assert plan.group_by.use_gfwd == (True,) and segs["l_suppkey.gfwd"].dtype == jnp.int32  # ids of 4 bytes
     compiled = compile_zone_program(one_chip, zone_launches["q15"], Q15[1])
     memory = compiled.memory_analysis()
-    assert "tpu_custom_call" not in compiled.as_text() and "scatter(" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "scatter(" not in text and " sort(" in text
     # staged: global ids of 4 bytes and two float32 measures a row; l_shipdate is searched, never staged
     assert 0 <= memory.argument_size_in_bytes - 3 * 16 * (1 << 23) * 4 < 1 << 20
     assert memory.output_size_in_bytes <= 2 * 220_000 * 4 + 4096
-    assert memory.temp_size_in_bytes < 5 << 28, f"temporaries {memory.temp_size_in_bytes / (1 << 30):.2f} GiB"
+    # the scatter's program of PR 37 kept 1,184,590,848 bytes there
+    assert memory.temp_size_in_bytes < 1_184_590_848, f"temporaries {memory.temp_size_in_bytes / (1 << 30):.3f} GiB"
