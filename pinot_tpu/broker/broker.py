@@ -55,7 +55,7 @@ from pinot_tpu.broker.querylog import SlowQueryLog
 from pinot_tpu.broker.routing import RoutingTableProvider
 from pinot_tpu.broker.time_boundary import TimeBoundaryService
 from pinot_tpu.utils.metrics import BrokerMetrics, prometheus_text
-from pinot_tpu.utils.trace import NULL_TRACE, TraceContext, boundary, measured, merge_scope
+from pinot_tpu.utils.trace import NULL_TRACE, TraceContext, boundary, marked, measured, merge_scope
 
 logger = logging.getLogger(__name__)
 
@@ -290,9 +290,9 @@ class BrokerRequestHandler:
     # ------------------------------------------------------------------
     def open_trace(self) -> Tuple[str, TraceContext]:
         """A request id and its span tree for a front end that times
-        boundaries outside ``handle_pql`` (the HTTP handler's
-        ``httpTotal``): enabled when the tail sampler is armed, as
-        ``handle_pql`` would decide it."""
+        boundaries outside ``handle_pql`` (the HTTP server's
+        ``httpConnection`` and ``httpTotal``): enabled when the tail
+        sampler is armed, as ``handle_pql`` would decide it."""
         request_id = self._next_request_id()
         if self.tail.armed:
             return request_id, TraceContext(enabled=True, scope=self.name, trace_id=request_id)
@@ -1418,6 +1418,97 @@ def _parse_debug_options(s: str) -> Optional[Dict[str, str]]:
     return out or None
 
 
+class _Connection:
+    """One connection's life inside the program, as four intervals that
+    follow one another on one clock (``PERF.md`` section 3):
+
+    ``httpAccept``      ``accept()`` has returned the socket on the accept
+                        loop's thread, until the connection's own thread
+                        runs its first statement: the thread made,
+                        started and woken;
+    ``httpHead``        from there to ``_query``'s entry: the handler
+                        object, the request line, the header parse, the
+                        route;
+    ``httpTotal``       the handler's own, as it was;
+    ``httpClose``       the last byte handed to the socket, until it is
+                        closed: the flush, ``shutdown_request``;
+
+    and around the last three ``httpConnection``, the root of the
+    request's tree: the connection thread's first statement to its last.
+
+    Whether the connection carries a query is known only at ``_query``'s
+    entry: there ``query`` opens the request id and the tree and gives
+    the root, a ``boundary`` open since the first statement, its span
+    and timer (``boundary.attach``).  The three around the handler are
+    ``measured``, each when it is over, from clock reads that a
+    connection makes anyway, and ``marked`` for the profiler while a
+    capture runs; ``httpAccept`` begins on one thread and ends on
+    another, so it lies before its parent and ends where that begins,
+    and its annotation closes when ``Thread.start()`` has returned on
+    the accept loop, that is once the loop has the interpreter back.  A
+    path that is not a query (``/metrics``, ``/health``, ``/debug/*``)
+    never gets to ``query``: no tree, no timer marked."""
+
+    __slots__ = ("_broker", "_timers", "_accepted_ms", "_began_ms", "_t_accepted", "_t_began", "_t_replied",
+                 "_accept_mark", "_mark", "_root", "_ctx", "_retained")
+
+    TIMERS = ("phase.httpAccept", "httpConnection", "phase.httpHead", "phase.httpClose")
+
+    def __init__(self, broker: "BrokerRequestHandler", timers: tuple) -> None:
+        self._broker = broker
+        self._timers = timers  # the broker's, in the order of TIMERS: looked up once a server
+        self._t_replied: Optional[float] = None  # set once a query's reply is out
+        self._retained = False
+        self._accepted_ms = time.time() * 1000.0
+        self._t_accepted = time.perf_counter()
+        self._accept_mark = marked("httpAccept")
+
+    def accepted(self) -> None:
+        """The accept loop is free to accept again."""
+        if self._accept_mark is not None:
+            self._accept_mark.stop()
+
+    def begin(self) -> None:
+        """The connection thread's first statement."""
+        self._t_began = time.perf_counter()
+        self._root = boundary("httpConnection").start()
+        self._mark = marked("httpHead")
+
+    def query(self) -> Tuple[str, TraceContext]:
+        """``_query``'s entry: the request id and its tree, the root
+        ``httpConnection``, under it ``httpAccept`` and ``httpHead``."""
+        accept, connection, head, _ = self._timers
+        rid, ctx = self._broker.open_trace()
+        self._ctx = ctx
+        accept_ms = (self._t_began - self._t_accepted) * 1000.0
+        self._began_ms = began_ms = self._accepted_ms + accept_ms
+        self._root.attach(ctx, connection, began_ms, requestId=rid)
+        measured("httpAccept", accept_ms, ctx, accept, start_ms=self._accepted_ms)
+        if self._mark is not None:
+            self._mark.stop()
+        # the clock is read last, so that what this entry costs is httpHead's and not between two spans
+        measured("httpHead", (time.perf_counter() - self._t_began) * 1000.0, ctx, head, start_ms=began_ms)
+        return rid, ctx
+
+    def replied(self, retained: bool) -> None:
+        """``httpTotal`` has ended: the reply is with the socket."""
+        self._retained = retained
+        self._t_replied = time.perf_counter()
+        self._mark = marked("httpClose")
+
+    def end(self) -> None:
+        """The socket is closed: the connection thread's last statement."""
+        if self._mark is not None:
+            self._mark.stop()  # httpClose's, or httpHead's where no query came
+        replied = self._t_replied
+        if replied is not None:
+            measured("httpClose", (time.perf_counter() - replied) * 1000.0, self._ctx, self._timers[3],
+                     start_ms=self._began_ms + (replied - self._t_began) * 1000.0)
+        self._root.stop()
+        if self._retained:
+            self._broker.tail.complete(self._ctx.trace_id, self._ctx.to_dict())
+
+
 class BrokerHttpServer:
     """HTTP endpoint: GET /query?pql=... and POST /query {"pql": ...}
     (``PinotClientRequestServlet.java:54/:73``)."""
@@ -1572,33 +1663,37 @@ class BrokerHttpServer:
                 """One query over HTTP, handler entry to last byte
                 written (``httpTotal``): ``read`` gives ``handle_pql``'s
                 arguments (``phase.httpRead``), the reply is rendered and
-                written under ``phase.render``.  The tree's root is
-                opened here, so a retained tail gets the finished tree
-                once the reply is out."""
-                rid, ctx = broker.open_trace()
+                written under ``phase.render``.  The request id and the
+                tree are opened here, where the connection turns out to
+                carry a query; its root is the connection's
+                (``_Connection``), which hands a retained tail the
+                finished tree once the socket is closed."""
+                life = self.server.lives[self.request]
+                rid, ctx = life.query()
                 resp = None
-                with boundary("httpTotal", ctx, broker.metrics.timer("httpTotal"), requestId=rid):
-                    try:
-                        with boundary("httpRead", ctx, broker.metrics.timer("phase.httpRead")):
-                            pql, trace, debug, timeout_ms = read()
-                    except json.JSONDecodeError as e:
-                        return self._respond(
-                            {"exceptions": [{"errorCode": ErrorCode.JSON_PARSING, "message": str(e)}]}
+                try:
+                    with boundary("httpTotal", ctx, broker.metrics.timer("httpTotal"), requestId=rid):
+                        try:
+                            with boundary("httpRead", ctx, broker.metrics.timer("phase.httpRead")):
+                                pql, trace, debug, timeout_ms = read()
+                        except json.JSONDecodeError as e:
+                            return self._respond(
+                                {"exceptions": [{"errorCode": ErrorCode.JSON_PARSING, "message": str(e)}]}
+                            )
+                        except InvalidTimeoutError as e:
+                            return self._invalid_timeout(e)
+                        resp = broker.handle_pql(
+                            pql,
+                            trace=trace,
+                            debug_options=debug,
+                            timeout_ms=timeout_ms,
+                            request_id=rid,
+                            trace_ctx=ctx,
                         )
-                    except InvalidTimeoutError as e:
-                        return self._invalid_timeout(e)
-                    resp = broker.handle_pql(
-                        pql,
-                        trace=trace,
-                        debug_options=debug,
-                        timeout_ms=timeout_ms,
-                        request_id=rid,
-                        trace_ctx=ctx,
-                    )
-                    with boundary("render", ctx, broker.metrics.timer("phase.render")):
-                        self._respond(resp.to_json())
-                if getattr(resp, "_tail_reason", None):
-                    broker.tail.complete(rid, ctx.to_dict())
+                        with boundary("render", ctx, broker.metrics.timer("phase.render")):
+                            self._respond(resp.to_json())
+                finally:
+                    life.replied(bool(getattr(resp, "_tail_reason", None)))
 
         class _Httpd(ThreadingHTTPServer):
             # socketserver's default listen backlog of 5 is 170 ms of
@@ -1609,6 +1704,34 @@ class BrokerHttpServer:
             # overflow (PERF.md, PR 24 and PR 25: one window in 23 went
             # 27 s behind and reset five connections)
             request_queue_size = 128
+
+            def __init__(self, *args) -> None:
+                self.lives: Dict[Any, _Connection] = {}  # by socket, from accept to close
+                self.timers = tuple(broker.metrics.timer(name) for name in _Connection.TIMERS)
+                super().__init__(*args)
+
+            def process_request(self, request, client_address):
+                """The accept loop's thread, ``accept()`` just returned:
+                make and start the connection's thread."""
+                life = self.lives[request] = _Connection(broker, self.timers)
+                try:
+                    super().process_request(request, client_address)
+                except BaseException:  # no thread to take it out again
+                    del self.lives[request]
+                    raise
+                finally:
+                    life.accepted()
+
+            def process_request_thread(self, request, client_address):
+                """The connection's thread, first statement to last:
+                the handler (``finish_request``), then the close."""
+                life = self.lives[request]
+                life.begin()
+                try:
+                    super().process_request_thread(request, client_address)
+                finally:
+                    life.end()
+                    del self.lives[request]
 
         self._httpd = _Httpd((host, port), _Handler)
         self.host = host

@@ -392,6 +392,16 @@ BROKER_METRIC_CATALOG: Dict[str, str] = {
     "phase.route": "routing-table lookup + batch build time",
     "scatterGather": "scatter-gather wall time per query",
     "reduce": "partial-merge + finalize time per query",
+    # a connection's life around the handler (broker.py _Connection),
+    # per query: accept, head, httpTotal and close follow one another
+    "phase.httpAccept": "accept() returned the socket -> the connection "
+    "thread's first statement: the thread made, started and woken",
+    "httpConnection": "connection thread's first statement to the socket "
+    "closed",
+    "phase.httpHead": "connection thread's first statement -> the query "
+    "handler's entry: handler object, request line, header parse, route",
+    "phase.httpClose": "last byte of the reply handed to the socket -> the "
+    "socket closed (flush, shutdown, close)",
     # the boundaries around handle_pql (utils/trace.py boundary): with
     # the ones above they cover a query's wall time inside the broker
     "httpTotal": "HTTP handler entry to last byte of the reply written",

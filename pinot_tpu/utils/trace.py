@@ -34,7 +34,8 @@ tree, and a ``jax.profiler.TraceAnnotation`` ``pinot:<name>`` on the
 profiler's host plane, which is on the clock of a capture's device
 planes.  ``ctx.span`` is a boundary without a timer; ``phases`` is the
 cursor for boundaries that follow one another; ``measured`` records an
-interval that is known only when it is over (a wait in a queue).
+interval that is known only when it is over (a wait in a queue), and
+``marked`` gives such an interval its annotation while a capture runs.
 """
 from __future__ import annotations
 
@@ -194,23 +195,29 @@ _annotation_cls = None  # jax.profiler.TraceAnnotation once this process has jax
 _capturing = None  # its is_enabled: TraceMe's own flag test
 
 
-def _annotation(name: str, rid: str, tags: Dict[str, Any]):
-    """``pinot:<name>`` on the profiler's host plane, or None.  Only a
-    process that has imported jax can hold a capture, so one that has
-    not (a broker of its own) never imports it here.  With no capture
-    running TraceMe would record nothing: its flag is tested here (0.05
-    us) before the object is built (0.5 us)."""
+def capturing() -> bool:
+    """Whether a profiler's capture runs in this process.  Only a
+    process that has imported jax can hold one, so one that has not (a
+    broker of its own) never imports it here; after that it is TraceMe's
+    own flag test (0.05 us)."""
     global _annotation_cls, _capturing
     if _annotation_cls is None:
         if "jax" not in sys.modules:
-            return None
+            return False
         try:
             from jax.profiler import TraceAnnotation as _annotation_cls
 
             _capturing = _annotation_cls.is_enabled
         except Exception:  # a jax without its profiler: spans and timers still work
             _annotation_cls = False
-    if not _annotation_cls or not _capturing():
+    return bool(_annotation_cls) and _capturing()
+
+
+def _annotation(name: str, rid: str, tags: Dict[str, Any]):
+    """``pinot:<name>`` on the profiler's host plane, or None.  With no
+    capture running TraceMe would record nothing: its flag is tested
+    before the object is built (0.5 us)."""
+    if not capturing():
         return None
     program = tags.get("program")
     if program:
@@ -238,7 +245,9 @@ class boundary:
 
     ``start()``/``stop()`` are the two halves for code whose interval is
     not a block (``stop`` is idempotent; both on one thread).  ``ms`` is
-    the duration once stopped.  ``relabel`` renames the timer and span of
+    the duration once stopped.  ``attach`` gives an open boundary the
+    tree and the timer that were not known when it began (a connection
+    before its request is read).  ``relabel`` renames the timer and span of
     an open boundary whose outcome names it (the executor's first
     stretch is ``staging`` unless a host tier answers); the annotation
     keeps the name it was opened with and gains ``as=<name>``."""
@@ -260,19 +269,40 @@ class boundary:
         # the clock is read first and (in stop) last, so that what the
         # boundary itself costs is inside its own interval and not in
         # its parent's self time
-        self._t0 = t0 = time.perf_counter()
+        self._t0 = time.perf_counter()
         ctx = self._ctx
         if ctx is not None:
-            stack = _stack.get()
-            parent = (stack[-1] if stack else None) if self._parent == _AUTO else self._parent
-            self._span = ctx._alloc(self.name, 0.0, time.time() * 1000.0, parent, self._tags)
-            self._token = _stack.set(stack + (self._span["id"],))
-            ctx._open[self._span["id"]] = t0
-        rid = ctx.trace_id if ctx is not None else self._tags.get("requestId", "")
-        self._ann = _annotation(self.name, rid, self._tags)
+            self._open_span(time.time() * 1000.0)
+        self._ann = _annotation(self.name, self._rid(), self._tags)
         if self._ann is not None:
             self._ann.__enter__()
         return self
+
+    def _rid(self) -> str:
+        return self._ctx.trace_id if self._ctx is not None else self._tags.get("requestId", "")
+
+    def _open_span(self, start_ms: float) -> None:
+        stack = _stack.get()
+        parent = (stack[-1] if stack else None) if self._parent == _AUTO else self._parent
+        self._span = self._ctx._alloc(self.name, 0.0, start_ms, parent, self._tags)
+        self._token = _stack.set(stack + (self._span["id"],))
+        self._ctx._open[self._span["id"]] = self._t0
+
+    def attach(self, ctx: Optional[TraceContext], timer, start_ms: float, **tags) -> None:
+        """The request's tree and the timer, for an open boundary that
+        began before either was known (a connection's, before its
+        request was read).  The span begins at ``start_ms``, the wall
+        clock as the caller read it when the boundary began; the
+        annotation gets the request id.  On the boundary's own thread."""
+        self._timer = timer
+        self._tags.update(tags)
+        if self._t0 is None:
+            return
+        if ctx is not None and ctx.enabled:
+            self._ctx = ctx
+            self._open_span(start_ms)
+        if self._ann is not None:
+            self._ann.set_metadata(rid=self._rid())
 
     def stop(self) -> float:
         t0 = self._t0
@@ -343,16 +373,25 @@ class phases:
 
 
 def measured(name: str, ms: float, ctx: Optional[TraceContext] = None, timer=None,
-             parent: Optional[str] = _AUTO, **tags) -> None:
+             parent: Optional[str] = _AUTO, start_ms: Optional[float] = None, **tags) -> None:
     """A boundary whose interval has already passed when it is known: a
     wait in a queue, measured at dequeue.  Timer and span as
     ``boundary``; no annotation, because the profiler takes no event
     after the fact (the waiting thread's own open ``pinot:`` span covers
-    the interval on the host plane)."""
+    the interval on the host plane).  ``start_ms`` where the interval
+    did not end just now (another thread's, reported here)."""
     if timer is not None:
         timer.update(ms)
     if ctx is not None and ctx.enabled:
-        ctx.add(name, ms, parent=parent, **tags)
+        ctx.add(name, ms, start_ms=start_ms, parent=parent, **tags)
+
+
+def marked(name: str) -> Optional[boundary]:
+    """The annotation alone, for an interval that is ``measured``: while
+    a capture runs an open boundary with no tree and no timer, which the
+    caller stops on this thread where the interval ends; ``None``
+    otherwise, so that a query outside a capture builds nothing for it."""
+    return boundary(name).start() if capturing() else None
 
 
 def current_trace() -> Optional[TraceContext]:

@@ -8,13 +8,15 @@ import subprocess
 import sys
 import threading
 import time
+import urllib.error
+import urllib.parse
 import urllib.request
 
 import pytest
 
 import pinot_tpu.utils.trace as trace_mod
 from pinot_tpu.utils.metrics import ServerMetrics
-from pinot_tpu.utils.tailsample import phase_self_ms
+from pinot_tpu.utils.tailsample import TailSampler, phase_self_ms
 from pinot_tpu.utils.trace import TraceContext, boundary, measured, phases
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -101,6 +103,50 @@ def test_open_spans_read_their_time_so_far_and_reserved_ids_parent_other_threads
     assert "tags" not in done["httpTotal"] and done["httpTotal"]["ms"] >= cut["httpTotal"]["ms"]
 
 
+def test_attach_gives_an_open_boundary_its_span_with_its_own_start():
+    """A connection's boundaries begin before the request is read: the
+    tree and the timer come later, the start stays the boundary's."""
+    metrics = ServerMetrics("b0")
+    t_open = time.time() * 1000.0  # the wall clock as the caller reads it when the two begin
+    early = boundary("httpConnection").start()
+    inner = boundary("httpHead").start()
+    time.sleep(0.003)
+    ctx = TraceContext(enabled=True, scope="b0", trace_id="r-1")
+    early.attach(ctx, metrics.timer("httpConnection"), t_open, requestId="r-1")
+    measured("httpAccept", 0.25, ctx, metrics.timer("phase.httpAccept"), start_ms=t_open - 0.25)
+    inner.attach(ctx, metrics.timer("phase.httpHead"), t_open)
+    inner.stop()
+    with boundary("httpTotal", ctx):
+        pass
+    cut = {s["span"]: s for s in ctx.to_dict()["b0"]}
+    assert cut["httpConnection"]["tags"] == {"requestId": "r-1", "open": True}
+    early.stop()
+    root, accept, head, total = ctx.to_dict()["b0"]
+    assert [s["span"] for s in (root, accept, head, total)] == ["httpConnection", "httpAccept", "httpHead", "httpTotal"]
+    assert root["parent"] is None and {accept["parent"], head["parent"], total["parent"]} == {root["id"]}
+    # the spans begin where the boundaries began, not where the tree was made
+    assert root["startMs"] == head["startMs"] == int(t_open * 1000.0) / 1000.0 < total["startMs"]
+    assert accept["startMs"] == pytest.approx(t_open - 0.25, abs=0.002) and accept["ms"] == 0.25
+    assert 3.0 <= head["ms"] <= root["ms"] and total["startMs"] >= head["startMs"] + head["ms"] - 1.0
+    assert head["ms"] == pytest.approx(metrics.timer("phase.httpHead").total_ms, abs=1e-3)
+    assert metrics.timer("httpConnection").count == metrics.timer("phase.httpAccept").count == 1
+
+
+@pytest.mark.parametrize("ctx", [None, trace_mod.NULL_TRACE, TraceContext(enabled=False)])
+def test_attach_allocates_no_span_when_the_tree_is_disabled(ctx):
+    metrics = ServerMetrics("b0")
+    before = trace_mod.SPAN_ALLOCATIONS
+    early = boundary("httpHead").start()
+    early.attach(ctx, metrics.timer("phase.httpHead"), 1.0, requestId="r")
+    measured("httpAccept", 0.2, ctx, metrics.timer("phase.httpAccept"), start_ms=1.0)
+    early.stop()
+    assert trace_mod.SPAN_ALLOCATIONS == before and early.span_id is None
+    assert metrics.timer("phase.httpHead").count == 1 and metrics.timer("phase.httpAccept").total_ms == 0.2
+    # a boundary that is over takes the timer and no span
+    early.attach(TraceContext(enabled=True, scope="b0"), metrics.timer("phase.httpHead"), 1.0)
+    assert trace_mod.SPAN_ALLOCATIONS == before and metrics.timer("phase.httpHead").count == 1
+
+
 # -- a query's wall time, from inside the program ----------------------------
 
 
@@ -128,10 +174,30 @@ def _post(cluster, pql: str, **extra) -> dict:
     return json.loads(urllib.request.urlopen(req, timeout=60).read())
 
 
+def _get(cluster, path: str, **params):
+    url = f"http://{cluster.http.host}:{cluster.http.port}{path}"
+    if params:
+        url += "?" + urllib.parse.urlencode(params)
+    body = urllib.request.urlopen(url, timeout=60).read()
+    return body.decode() if path == "/metrics" else json.loads(body)
+
+
+def _finished_tail(cluster, request_id: str) -> dict:
+    """The retained entry once the connection is closed: the client has
+    its reply before ``httpClose`` ends and the finished tree is handed over."""
+    for _ in range(200):
+        entry = cluster.broker.tail.get(request_id)
+        if entry and any(s["span"] == "httpClose" for s in entry["scopes"][cluster.broker.name]):
+            return entry
+        time.sleep(0.01)
+    raise AssertionError(f"no finished tail for {request_id}: {entry}")
+
+
 K6 = ("SELECT sum(l_extendedprice), count(*) FROM lineitem "
       "GROUP BY l_returnflag, l_linestatus TOP 10")
 
-BROKER_SPANS = {"httpTotal", "httpRead", "query", "parse", "route", "scatterGather", "serverAttempt",
+CONNECTION_SPANS = ("httpConnection", "httpAccept", "httpHead", "httpTotal", "httpClose")
+BROKER_SPANS = {*CONNECTION_SPANS, "httpRead", "query", "parse", "route", "scatterGather", "serverAttempt",
                 "attemptSubmit", "poolQueue", "serializeRequest", "deserializeResult", "gatherWake", "reduce", "bookkeeping",
                 "render"}
 SERVER_SPANS = {"serverQuery", "queueWait", "serverParse", "segmentAcquire", "planAndExecute", "prune",
@@ -150,28 +216,32 @@ def test_self_times_under_httpTotal_sum_to_its_duration(http_cluster, monkeypatc
     cut = {s["span"]: s for s in reply["traceInfo"]["scopes"][cluster.broker.name]}
     assert cut["httpTotal"]["tags"]["open"] is True and "render" not in cut
     # the finished one is the retained tail's
-    entry = None
-    for _ in range(100):
-        entry = cluster.broker.tail.get(reply["requestId"])
-        if entry and any(s["span"] == "render" for s in entry["scopes"][cluster.broker.name]):
-            break
-        time.sleep(0.01)
+    entry = _finished_tail(cluster, reply["requestId"])
     scopes = entry["scopes"]
     server = cluster.servers[0].name
     assert {s["span"] for s in scopes[cluster.broker.name]} == BROKER_SPANS
     assert {s["span"] for s in scopes[server]} == SERVER_SPANS
     spans = [s for part in scopes.values() for s in part]
     assert not any("open" in s.get("tags", {}) for s in spans)
-    # one tree: every span hangs, through its parents, under httpTotal
+    # one tree: every span hangs, through its parents, under the connection's root
     by_id = {s["id"]: s for s in spans}
     (root,) = [s for s in spans if s["parent"] is None]
-    assert root["span"] == "httpTotal"
+    assert root["span"] == "httpConnection"
     for s in spans:
         while s["parent"] is not None:
             s = by_id[s["parent"]]
         assert s is root
-    # and the self times add up to it: nothing is counted twice, nothing hangs outside
-    assert sum(phase_self_ms(scopes).values()) == pytest.approx(root["ms"], rel=0.05)
+    # and under httpTotal the self times add up to it: nothing is counted twice, nothing hangs outside
+    (total,) = [s for s in spans if s["span"] == "httpTotal"]
+
+    def under_total(s) -> bool:
+        while s is not total and s["parent"] is not None:
+            s = by_id[s["parent"]]
+        return s is total
+
+    subtree = {scope: [dict(s, parent=None) if s is total else s for s in part if under_total(s)]
+               for scope, part in scopes.items()}
+    assert sum(phase_self_ms(subtree).values()) == pytest.approx(total["ms"], rel=0.05)
     assert entry["phaseSelfMs"]["render"] > 0
     # the launch and the wait say which program
     program = {s["span"]: s.get("tags", {}).get("program") for s in scopes[server]}
@@ -196,6 +266,166 @@ def test_direct_call_keeps_one_root_and_a_disabled_sampler_allocates_nothing(htt
     assert not _post(http_cluster, K6)["exceptions"]
     assert trace_mod.SPAN_ALLOCATIONS == before
     assert broker.metrics.timer("phase.bookkeeping").count >= 3
+
+
+# -- a connection's life, accept to close (PR 39) -------------------------------
+
+
+@pytest.mark.parametrize("method", ["POST", "GET"])
+def test_a_query_leaves_one_tree_under_its_connection(http_cluster, monkeypatch, method):
+    cluster = http_cluster
+    monkeypatch.setattr(cluster.broker.tail, "slow_ms", 0.0)  # keep every tree
+    reply = _post(cluster, K6) if method == "POST" else _get(cluster, "/query", pql=K6)
+    assert not reply["exceptions"]
+    scopes = _finished_tail(cluster, reply["requestId"])["scopes"]
+    spans = [s for part in scopes.values() for s in part]
+    by_id = {s["id"]: s for s in spans}
+    (root,) = [s for s in spans if s["parent"] is None]
+    assert root["span"] == "httpConnection" and root["tags"] == {"requestId": reply["requestId"]}
+    end = lambda s: s["startMs"] + s["ms"]
+    # startMs is cut and ms rounded to the microsecond; a span's start is the wall clock's and its length the
+    # monotonic clock's, read one after the other, so an end set beside another span's start gets a millisecond
+    slack, loose = 0.002, 1.0
+    # httpHead, httpTotal, httpClose follow one another under the root and fill it
+    accept, head, total, close = (next(s for s in spans if s["span"] == n) for n in CONNECTION_SPANS[1:])
+    assert {s["parent"] for s in (accept, head, total, close)} == {root["id"]}
+    assert root["startMs"] <= head["startMs"] < total["startMs"] < close["startMs"]
+    assert end(head) <= total["startMs"] + loose and end(total) <= close["startMs"] + loose
+    assert end(close) <= end(root) + loose
+    assert 0.8 * root["ms"] <= head["ms"] + total["ms"] + close["ms"] <= root["ms"] + 3 * slack
+    # httpAccept is the accept loop's: it lies before the connection's thread ran and ends where that begins
+    assert accept["ms"] > 0 and accept["startMs"] < root["startMs"]
+    assert end(accept) <= head["startMs"] + loose and end(accept) == pytest.approx(root["startMs"], abs=loose)
+    # no other span is older than its parent
+    for s in spans:
+        if s["parent"] is not None and s is not accept:
+            assert s["startMs"] >= by_id[s["parent"]]["startMs"] - slack, (s, by_id[s["parent"]])
+    # the self times add up to the life from accept to close, the root's and before it httpAccept's: the three
+    # around the handler are leaves, and the root keeps what its children leave of it (the few statements between
+    # them, less httpAccept, which is counted under it and lies before it); httpTotal's own subtree is the
+    # test's above
+    self_ms = phase_self_ms(scopes)
+    assert [self_ms[n] for n in ("httpAccept", "httpHead", "httpClose")] == [accept["ms"], head["ms"], close["ms"]]
+    left = root["ms"] - accept["ms"] - head["ms"] - total["ms"] - close["ms"]
+    assert self_ms.get("httpConnection", 0.0) == pytest.approx(max(0.0, left), abs=slack)
+    under_total = sum(self_ms.values()) - sum(self_ms.get(n, 0.0) for n in CONNECTION_SPANS if n != "httpTotal")
+    assert under_total >= total["ms"] - 0.01  # floored, so never under it; over it only where threads overlapped
+
+
+CONNECTION_TIMERS = ("phase.httpAccept", "phase.httpHead", "phase.httpClose", "httpConnection", "httpTotal")
+
+
+def _connection_counts(cluster) -> list:
+    for _ in range(2000):  # the last connection's close ends after its client has the reply
+        if not cluster.http._httpd.lives:
+            break
+        time.sleep(0.005)
+    return [cluster.broker.metrics.timer(name).count for name in CONNECTION_TIMERS]
+
+
+@pytest.mark.parametrize("path", ["/metrics", "/health", "/debug/queries", "/debug/tails", "/nowhere"])
+def test_connection_timers_count_queries_alone(http_cluster, path):
+    """One update a query of each timer a reader divides by ``httpTotal``'s
+    count; a path that is not a query marks none and builds no tree."""
+    before = _connection_counts(http_cluster)
+    assert not _post(http_cluster, K6)["exceptions"]
+    assert not _get(http_cluster, "/query", pql=K6)["exceptions"]
+    after = _connection_counts(http_cluster)
+    assert [b - a for a, b in zip(before, after)] == [2] * len(CONNECTION_TIMERS)
+    allocated, ids = trace_mod.SPAN_ALLOCATIONS, http_cluster.broker._request_id
+    try:
+        _get(http_cluster, path)
+    except urllib.error.HTTPError as e:
+        assert (path, e.code) == ("/nowhere", 404)
+    assert _connection_counts(http_cluster) == after
+    assert (trace_mod.SPAN_ALLOCATIONS, http_cluster.broker._request_id) == (allocated, ids)
+    assert not http_cluster.http._httpd.lives  # every connection took itself out
+
+
+def test_a_disabled_sampler_builds_no_span_for_the_connection(http_cluster, monkeypatch):
+    """``PINOT_TPU_TAIL_TRACE=0``: the timers are kept, no span dict is built."""
+    assert TailSampler(enabled=None).enabled is True
+    monkeypatch.setenv("PINOT_TPU_TAIL_TRACE", "0")
+    assert TailSampler(enabled=None).enabled is False
+    monkeypatch.setattr(http_cluster.broker.tail, "enabled", False)
+    _post(http_cluster, K6)
+    counts, before = _connection_counts(http_cluster), trace_mod.SPAN_ALLOCATIONS
+    assert not _post(http_cluster, K6)["exceptions"]
+    assert not _get(http_cluster, "/query", pql=K6)["exceptions"]
+    assert [b - a for a, b in zip(counts, _connection_counts(http_cluster))] == [2] * len(CONNECTION_TIMERS)
+    assert trace_mod.SPAN_ALLOCATIONS == before
+
+
+def test_connection_spans_are_on_the_profilers_host_plane(http_cluster, monkeypatch, tmp_path):
+    """Under a capture the three intervals around the handler are
+    ``pinot:`` annotations as long as their spans, so that
+    ``trace_reduce`` gives them the idle they cover."""
+    import jax
+    from jax.profiler import ProfileData
+
+    cluster = http_cluster
+    _post(cluster, K6)
+    _connection_counts(cluster)  # the warm-up's connection is closed before the capture begins
+    monkeypatch.setattr(cluster.broker.tail, "slow_ms", 0.0)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        reply = _post(cluster, K6)
+        entry = _finished_tail(cluster, reply["requestId"])
+        # the accept loop closes its annotation when it has the interpreter back: before it takes the next connection
+        _get(cluster, "/health")
+    finally:
+        jax.profiler.stop_trace()
+    rid = reply["requestId"]
+    spans = {s["span"]: s for s in entry["scopes"][cluster.broker.name]}
+    path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))[0]
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("pinot:http"):
+                        events.setdefault(e.name[len("pinot:"):], []).append(
+                            (e.start_ns, e.duration_ns / 1e6, dict(e.stats)))
+    assert set(events) == {"httpAccept", "httpConnection", "httpHead", "httpTotal", "httpRead", "httpClose"}
+    # the request id is known at the handler's entry: the root, open then, gets it (/health's has none);
+    # the three around the handler are measured, and marked for the profiler without one
+    mine = {name: [e for e in found if e[2].get("rid") == rid] for name, found in events.items()}
+    assert {name: len(found) for name, found in mine.items()} == {
+        "httpAccept": 0, "httpConnection": 1, "httpHead": 0, "httpTotal": 1, "httpRead": 1, "httpClose": 0}
+    (began, lasted, _), = mine["httpConnection"]
+    for name in ("httpHead", "httpClose"):  # the query's own: the one that began inside its connection's
+        mine[name] = [e for e in events[name] if began <= e[0] <= began + lasted * 1e6]
+        assert len(mine[name]) == 1, (name, events[name])
+    for name in ("httpHead", "httpClose", "httpConnection", "httpTotal"):
+        assert mine[name][0][1] == pytest.approx(spans[name]["ms"], abs=0.5), name
+    # httpAccept's is the accept loop's own: the one that began last before the connection's.  Never shorter
+    # than the span, which ends at the connection thread's first statement; longer by the loop's wait for
+    # the interpreter
+    accept_ms = max(e for e in events["httpAccept"] if e[0] <= began)[1]
+    assert spans["httpAccept"]["ms"] - 0.5 <= accept_ms <= spans["httpAccept"]["ms"] + 1000
+    # outside a capture none of the three is made
+    assert trace_mod.marked("httpHead") is None and not trace_mod.capturing()
+
+
+def test_a_retained_tail_over_http_holds_the_close_finished(http_cluster, monkeypatch):
+    """``/debug/tails?requestId=``: ``TailSampler.complete`` gets the tree
+    after ``httpClose``, not before it."""
+    cluster = http_cluster
+    monkeypatch.setattr(cluster.broker.tail, "slow_ms", 0.0)
+    reply = _post(cluster, K6)
+    _finished_tail(cluster, reply["requestId"])
+    entry = _get(cluster, "/debug/tails", requestId=reply["requestId"])
+    spans = {s["span"]: s for s in entry["scopes"][cluster.broker.name]}
+    for name in CONNECTION_SPANS:
+        assert "open" not in spans[name].get("tags", {}) and spans[name]["ms"] > 0, name
+    assert entry["phaseSelfMs"]["httpClose"] == spans["httpClose"]["ms"]
+    assert {"httpAccept", "httpHead", "httpClose"} <= set(entry["phaseSelfMs"])
+    # the reply's own tree was cut before the reply was rendered: the connection and the handler still open
+    cut = {s["span"]: s for s in _post(cluster, K6, trace=True)["traceInfo"]["scopes"][cluster.broker.name]}
+    assert cut["httpConnection"]["tags"]["open"] is True and cut["httpTotal"]["tags"]["open"] is True
+    assert "open" not in cut["httpHead"].get("tags", {}) and "httpClose" not in cut and cut["httpAccept"]["ms"] > 0
 
 
 # -- where a group-by's operands are built ------------------------------------
