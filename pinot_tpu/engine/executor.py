@@ -1919,9 +1919,10 @@ class QueryExecutor:
                 sum_sq += float(np.square(order[i], dtype=np.float64).sum())
 
         # Trim candidate groups per aggregation (reference trims to
-        # topN*5 per server, MCombineGroupByOperator.java:216); the
-        # union over aggregations (incl. capped boundary ties) is kept
-        # so merges stay consistent.
+        # topN*5 per server, MCombineGroupByOperator.java:216): a
+        # selection around each aggregation's cut, one pass over the
+        # live groups and no sort of them; the union over aggregations
+        # (incl. capped boundary ties) is kept so merges stay consistent.
         from pinot_tpu.engine.results import trim_group_candidates
 
         if trims:

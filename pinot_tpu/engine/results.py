@@ -452,9 +452,11 @@ class IntermediateResult:
 # tied-at-the-boundary groups are kept — but at huge key spaces a
 # degenerate workload (e.g. COUNT(*) over near-unique keys, every group
 # tied at 1) would otherwise re-admit millions of groups and defeat the
-# trim entirely.  Beyond the cap a deterministic subset is kept; the
-# reference's per-server topN*5 trim makes the same non-guarantee for
-# deep ties (MCombineGroupByOperator.java:216).
+# trim entirely.  Beyond the cap a deterministic subset is kept: every
+# group strictly beyond the boundary, then the tied groups in ascending
+# index until both the cap and the trim are met, in either direction;
+# the reference's per-server topN*5 trim makes the same non-guarantee
+# for deep ties (MCombineGroupByOperator.java:216).
 MAX_TRIM_TIES = 10_000
 
 
@@ -469,19 +471,32 @@ def trim_group_candidates(
     ``order_vals_list`` holds one finalized-value array of shape [k] per
     aggregation; a group survives if it is within topN*5 (min 100) of
     any aggregation's ordering, or tied (capped) with that boundary.
-    Returns sorted indices into [0, k).
+    A selection, not a sort: one ``np.argpartition`` around the cut an
+    aggregation, O(k), in ``np.sort``'s order (NaN last).  Returns sorted
+    indices into [0, k).
     """
     trim = max(top_n * 5, 100)
     if k <= trim:
         return np.arange(k)
-    candidates: set = set()
+    keep = np.zeros(k, dtype=bool)
     for ov, asc in zip(order_vals_list, ascending_list):
-        order = np.argsort(ov, kind="stable")
-        chosen = order[:trim] if asc else order[-trim:]
-        candidates.update(chosen.tolist())
-        boundary = ov[order[trim - 1 if asc else -trim]]
-        ties = np.nonzero(ov == boundary)[0]
-        if ties.size > MAX_TRIM_TIES:
-            ties = ties[:MAX_TRIM_TIES]
-        candidates.update(ties.tolist())
-    return np.asarray(sorted(candidates), dtype=np.int64)
+        ov = np.asarray(ov)
+        cut = trim - 1 if asc else k - trim
+        part = np.argpartition(ov, cut)
+        boundary = ov[part[cut]]
+        nan_cut = boundary != boundary
+        tied = ov != ov if nan_cut else ov == boundary
+        # the cut's side holds every group strictly beyond the boundary
+        # and whichever tied ones the selection left there: drop those,
+        # and take the tied in ascending index
+        inside = part[:trim] if asc else part[cut:]
+        beyond = inside[~tied[inside]]
+        ties = np.nonzero(tied)[0]
+        room = trim - beyond.size
+        if nan_cut:  # NaN equals nothing, so no tie is added: the NaN a stable sort leaves inside the cut
+            ties = ties[:room] if asc else ties[-room:]
+        else:
+            ties = ties[: max(MAX_TRIM_TIES, room)]
+        keep[beyond] = True
+        keep[ties] = True
+    return np.nonzero(keep)[0]

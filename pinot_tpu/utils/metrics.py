@@ -658,8 +658,9 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "(max(5 x TOP, 100) an aggregate, and boundary ties)",
     "phase.groupTrim": "inside phase.finalize of a device group-by: from "
     "the fetched state to the kept keys (nonzero over the occupancy, the "
-    "order values and their sum of squares, trim_group_candidates); timer "
-    "and annotation, no span",
+    "order values and their sum of squares, and trim_group_candidates' "
+    "selection around the cut: no sort of the state); timer and "
+    "annotation, no span",
     # arithmetic inside an aggregate (sum(a*(1-b))): one mark a query
     # whose plan holds a compound expression, by where it was answered
     "agg.expr.device": "queries with an expression inside an aggregate "

@@ -206,6 +206,44 @@ def test_every_row_through_the_full_scan_and_the_trim(served):
     assert launch["tags"]["groupby"] == "scatter" and "blocks" not in launch["tags"]
 
 
+def test_two_aggregates_of_opposite_order_through_the_trim(table, served, monkeypatch):
+    """The sum's largest and the minimum's smallest, each trimmed to 100
+    with its boundary's ties (some 4,000 groups' least quantity is 1),
+    against numpy over the segments: the kept keys as the trim returns
+    them, the counts and the digest of the whole state."""
+    from pinot_tpu.engine import results
+
+    suppliers, segments, _ = table
+    _, broker, _ = served
+    fwd = np.concatenate([seg.column("l_suppkey").fwd for seg in segments])  # every dictionary holds every supplier
+    price, quantity = (np.concatenate([seg.column(c).dictionary.values[seg.column(c).fwd] for seg in segments])
+                       .astype(np.float64) for c in ("l_extendedprice", "l_quantity"))
+    live = np.nonzero(np.bincount(fwd, minlength=suppliers))[0]
+    sums = np.bincount(fwd, weights=price, minlength=suppliers)[live]
+    mins = np.full(suppliers, np.inf)
+    np.minimum.at(mins, fwd, quantity)
+    mins = mins[live]
+    kept = np.nonzero((sums >= np.sort(sums)[-100]) | (mins <= np.sort(mins)[99]))[0]
+    assert 1000 < np.count_nonzero(mins == mins.min()) < results.MAX_TRIM_TIES and kept.size > 1100
+
+    seen = []
+    real = results.trim_group_candidates
+    monkeypatch.setattr(results, "trim_group_candidates", lambda *a: seen.append(real(*a)) or seen[-1])
+    reply = broker.handle_pql("SELECT sum(l_extendedprice), min(l_quantity) FROM lineitem GROUP BY l_suppkey TOP 5").to_json()
+    assert not reply["exceptions"] and not reply["cost"].get("segmentsHost")
+    (keep,) = seen
+    assert np.array_equal(keep, kept)
+    cost = reply["cost"]
+    assert cost["numGroupsLive"] == live.size and cost["numGroupsKept"] == kept.size
+    assert cost["groupStateSumSq"] == pytest.approx(float(np.dot(sums, sums) + np.dot(mins, mins)), rel=1e-9)
+    by_sum, by_min = (r["groupByResult"] for r in reply["aggregationResults"])
+    top = np.argsort(-sums, kind="stable")[:5]
+    assert [int(g["group"][0]) for g in by_sum] == [int(live[i]) + 1 for i in top]
+    np.testing.assert_allclose([float(g["value"]) for g in by_sum], sums[top], rtol=SUM_RTOL)
+    assert [float(g["value"]) for g in by_min] == [mins.min()] * 5
+    assert {int(g["group"][0]) - 1 for g in by_min} <= set(live[mins == mins.min()].tolist())
+
+
 def test_the_full_scan_equals_the_zone_tier(served, monkeypatch):
     suppliers, broker, ref = served
     zone = {name: broker.handle_pql(PQL[name]).to_json() for name in ("q15_1996q1", "every", "none")}
