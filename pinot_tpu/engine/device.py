@@ -160,6 +160,7 @@ def stage_segments(
     sharding=None,
     bsi_columns: Sequence[str] = (),
     bsiv_columns: Sequence[str] = (),
+    hll_timer=None,
 ) -> StagedTable:
     """Stack + pad + transfer the given columns of the segments.
 
@@ -248,7 +249,7 @@ def stage_segments(
                     gf[i, : c.fwd.size] = remaps[i][c.fwd]
                 sc.gfwd = put(gf)
             if name in hll_columns:
-                hb, hr = _hll_streams(cols, S, n_pad)
+                hb, hr = _hll_streams(cols, S, n_pad, hll_timer)
                 sc.hll_rho = put(hr)  # rho first (see _augment_staged)
                 sc.hll_bucket = put(hb)
             if name in bsi_columns:
@@ -656,6 +657,7 @@ def get_staged(
     bsi_columns: Sequence[str] = (),
     bsiv_columns: Sequence[str] = (),
     pin: bool = False,
+    hll_timer=None,
 ) -> StagedTable:
     """Cached staging. The cache key covers only the base arrays; role
     arrays (raw/gfwd/hll streams) are attached to the cached
@@ -673,7 +675,9 @@ def get_staged(
     by demoting the coldest unpinned tables instead of the old
     clear-everything size cap.  ``pin=True`` refcounts the staged
     table's token so tier demotion can never race this query's launch;
-    the caller MUST ``RESIDENCY.unpin(st.token)`` when done."""
+    the caller MUST ``RESIDENCY.unpin(st.token)`` when done.
+    ``hll_timer``: the caller's ``phase.hllDerive``, which times what
+    deriving a column's per-row HLL streams costs here (_hll_streams)."""
     from pinot_tpu.engine.residency import RESIDENCY
     # identity component: (name, claimed crc, instance token).  The
     # token (segment/immutable.py) is what makes a re-loaded copy of the
@@ -718,6 +722,7 @@ def get_staged(
                     ],
                     bsi_columns=bsi_columns,
                     bsiv_columns=bsiv_columns,
+                    hll_timer=hll_timer,
                 )
             else:
                 st = stage_segments(
@@ -732,6 +737,7 @@ def get_staged(
                     sharding=sharding,
                     bsi_columns=bsi_columns,
                     bsiv_columns=bsiv_columns,
+                    hll_timer=hll_timer,
                 )
             table = _table_of(segments)
             with _cache_guard:
@@ -770,6 +776,7 @@ def get_staged(
                 ],
                 bsi_columns=bsi_columns,
                 bsiv_columns=bsiv_columns,
+                hll_timer=hll_timer,
             )
             RESIDENCY.touch(key)
             if attached:
@@ -804,6 +811,7 @@ def _augment_staged(
     base_columns: Sequence[str] = (),
     bsi_columns: Sequence[str] = (),
     bsiv_columns: Sequence[str] = (),
+    hll_timer=None,
 ) -> int:
     """Attach missing role arrays to an already-staged table.  Returns
     the bytes newly uploaded (0 on a plain hit) so the caller can record
@@ -878,7 +886,7 @@ def _augment_staged(
         sc = st.columns.get(name)
         if sc is None or sc.hll_bucket is not None or not sc.single_value:
             continue
-        hb, hr = _hll_streams([seg.column(name) for seg in segments], S, n_pad)
+        hb, hr = _hll_streams([seg.column(name) for seg in segments], S, n_pad, hll_timer)
         # rho FIRST: readers holding this cached table guard on
         # hll_bucket, so both must be visible once bucket is
         sc.hll_rho = put(hr)
@@ -915,19 +923,25 @@ def _augment_staged(
     return attached
 
 
-def _hll_streams(cols, S: int, n_pad: int):
+def _hll_streams(cols, S: int, n_pad: int, timer=None):
     """Per-row HLL (register index, rank) uint8 streams, computed
     host-side per dictionary entry then fanned out through the forward
-    index — the kernel scatter-maxes the streams instead of gathering
-    per-dictId tables on device."""
+    index — the kernel reads the streams instead of gathering
+    per-dictId tables on device.  ``timer`` (``phase.hllDerive``) and
+    the ``pinot:hllDerive`` annotation time the hashing of the
+    dictionaries and the fan-out; no span: ``staging`` stays the leaf."""
     from pinot_tpu.engine.hll import dictionary_tables
+    from pinot_tpu.utils.trace import boundary
 
-    hb = np.zeros((S, n_pad), dtype=np.uint8)
-    hr = np.zeros((S, n_pad), dtype=np.uint8)
-    for i, c in enumerate(cols):
-        bt, rt = dictionary_tables(c.dictionary)
-        hb[i, : c.fwd.size] = bt[c.fwd]
-        hr[i, : c.fwd.size] = rt[c.fwd]
+    with boundary("hllDerive", None, timer):
+        hb = np.zeros((S, n_pad), dtype=np.uint8)
+        hr = np.zeros((S, n_pad), dtype=np.uint8)
+        for i, c in enumerate(cols):
+            bt, rt = dictionary_tables(c.dictionary)
+            # one gather through the forward index for the two tables
+            both = (bt.astype(np.uint16) << 8 | rt)[c.fwd]
+            hb[i, : c.fwd.size] = both >> 8
+            hr[i, : c.fwd.size] = both & 0xFF
     return hb, hr
 
 

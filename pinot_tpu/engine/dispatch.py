@@ -388,7 +388,7 @@ class _Dispatch:
     __slots__ = (
         "key", "launch", "pending", "waiters", "completed", "value",
         "error", "plan_digest", "cost_provider", "batch", "batch_size",
-        "t_submit", "trace", "parent", "program", "groupby", "operands", "expr", "cells", "blocks", "launch_id",
+        "t_submit", "trace", "parent", "program", "groupby", "operands", "expr", "cells", "blocks", "hll", "launch_id",
     )
 
     def __init__(
@@ -408,6 +408,7 @@ class _Dispatch:
         expr: int = 0,
         cells: Tuple[int, int] = (0, 0),
         blocks: str = "",
+        hll: str = "",
     ) -> None:
         self.t_submit = t_submit  # phase.laneQueue runs from here to the launch call
         # the submitting query's span tree and the span (its laneWait)
@@ -420,6 +421,7 @@ class _Dispatch:
         self.expr = expr  # aggregates of the plan whose argument is a compound expression
         self.cells = cells  # a group-by's K x m cells and the rows sharing saved (kernel.groupby_cells)
         self.blocks = blocks  # how a zone-tier program reads its candidate blocks (kernel.zone_blocks)
+        self.hll = hll  # the lowering of the program's HLL aggregates (kernel.hll_lowering)
         self.launch_id: Optional[int] = None  # the physical launch this rode (occupancy)
         self.key = key
         self.launch = launch
@@ -598,6 +600,7 @@ class DeviceLane:
         expr: int = 0,
         cells: Tuple[int, int] = (0, 0),
         blocks: str = "",
+        hll: str = "",
     ) -> LaneTicket:
         """Enqueue a kernel launch, or coalesce onto an identical one
         that is queued, launching, or still executing on device.
@@ -621,7 +624,10 @@ class DeviceLane:
         ``blocks``: how a zone-tier program reads its candidate blocks
         (``kernel.zone_blocks``), the ``blocks=`` tag and one
         ``zone.blocks.inplace`` or ``zone.blocks.gathered`` mark a launch
-        ("" for any other program).
+        ("" for any other program); ``hll``: the lowering of the
+        program's HLL aggregates (``kernel.hll_lowering``), the ``hll=``
+        tag and one ``hll.lowering.matmul|sort|scatter|pairs`` mark a
+        launch ("" for a program without one).
 
         ``cost_provider`` (optional, utilization plane): a zero-arg
         callable returning the plan's static XLA cost analysis (or
@@ -661,7 +667,7 @@ class DeviceLane:
                 self._hit()
             else:
                 d = _Dispatch(key, launch, pending, plan_digest, cost_provider, batch,
-                              trace, parent, program, t_submit, groupby, operands, expr, cells, blocks)
+                              trace, parent, program, t_submit, groupby, operands, expr, cells, blocks, hll)
                 d.waiters.append(ticket)
                 self._by_key[key] = d
                 self._depth_tick_locked()
@@ -1218,6 +1224,8 @@ class DeviceLane:
                 tags = {"groupby": d.groupby, "operands": d.operands, "expr": d.expr, "cells": d.cells[0]}
             if d.blocks:
                 tags["blocks"] = d.blocks
+            if d.hll:
+                tags["hll"] = d.hll
             launching = boundary(
                 "laneDispatch", d.trace, launch_timer, parent=d.parent, program=d.program,
                 via="warm" if d.plan_digest is None or d.plan_digest in self._compile else "first",
@@ -1231,6 +1239,8 @@ class DeviceLane:
                     self.metrics.meter("groupby.slots.shared").mark(d.cells[1])
             if d.blocks and self.metrics is not None:
                 self.metrics.meter(f"zone.blocks.{d.blocks}").mark()
+            if d.hll and self.metrics is not None:
+                self.metrics.meter(f"hll.lowering.{d.hll}").mark()
             self._set_inflight(1)
             error: Optional[BaseException] = None
             value: Any = None

@@ -973,6 +973,7 @@ class QueryExecutor:
             skip_base_columns=skip_base,
             sharding=sharding,
             pin=True,
+            hll_timer=self.metrics.timer("phase.hllDerive") if hll_cols else None,
         )
         # the OOM heal's demotion pass must not evict the very table
         # this query is about to retry against
@@ -1586,11 +1587,14 @@ class QueryExecutor:
         # program); and how a zone-tier program
         # reads its candidate blocks: the ``blocks=`` tag and the
         # ``zone.blocks.*`` mark
-        from pinot_tpu.engine.kernel import groupby_cells, groupby_lowering, groupby_operands, zone_blocks
+        from pinot_tpu.engine.kernel import groupby_cells, groupby_lowering, groupby_operands, hll_lowering, zone_blocks
 
         groupby = groupby_lowering(plan) or ""
         operands = groupby_operands(plan) or ""
         blocks = zone_blocks(plan) if block_ids is not None else ""
+        # which lowering its HLL aggregates take, grouped or not: the
+        # ``hll=`` tag and the ``hll.lowering.*`` mark ("" without one)
+        hll = hll_lowering(plan) or ""
         # its K x m cells and the rows sharing saved (the ``cells=`` tag,
         # ``groupby.slots.shared``), and how many aggregates take a
         # compound expression (the ``expr=`` tag)
@@ -1614,6 +1618,8 @@ class QueryExecutor:
                     self.metrics.meter("groupby.slots.shared").mark(cells[1])
                 if blocks:
                     self.metrics.meter(f"zone.blocks.{blocks}").mark()
+                if hll:
+                    self.metrics.meter(f"hll.lowering.{hll}").mark()
                 fetch, handle = launch()
             else:
                 # coalesce key: identical (plan, staged-table token, inputs
@@ -1651,6 +1657,7 @@ class QueryExecutor:
                         expr=n_expr,
                         cells=cells,
                         blocks=blocks,
+                        hll=hll,
                     )
                     fetch, handle = ticket.result(deadline)
                     # the lane thread delivered -> this worker runs again
@@ -1828,14 +1835,14 @@ class QueryExecutor:
         )
 
         if plan.group_by is not None:
-            res.groups, live_groups, sum_sq = self._finalize_groups(request, plan, ctx, outs)
+            res.groups, live_groups, digest = self._finalize_groups(request, plan, ctx, outs)
             # groups with a row in the fetched state, and those left after
             # the per-server trim (an empty answer marks neither); the
             # digest is of every live group, so a reply of TOP n can be
             # held to the whole state and not to the n it returns
             self.metrics.meter("groupby.groups.live").mark(live_groups)
             self.metrics.meter("groupby.groups.kept").mark(len(res.groups))
-            res.add_cost(numGroupsLive=live_groups, numGroupsKept=len(res.groups), groupStateSumSq=sum_sq)
+            res.add_cost(numGroupsLive=live_groups, numGroupsKept=len(res.groups), **digest)
         elif plan.aggs:
             res.aggregations = [
                 self._scalar_partial(agg, outs[f"agg_{i}"], ctx)
@@ -1890,15 +1897,20 @@ class QueryExecutor:
         raise AssertionError(agg)
 
     # ------------------------------------------------------------------
-    def _kept_group_keys(self, plan: StaticPlan, ctx: TableContext, outs) -> Tuple[int, float, np.ndarray]:
-        """(groups with a row, the sum of squares of their dense values,
-        the dense keys the per-server trim keeps, ascending) of a
-        group-by's fetched state."""
+    def _kept_group_keys(self, plan: StaticPlan, ctx: TableContext, outs) -> Tuple[int, Dict[str, float], np.ndarray]:
+        """(groups with a row, the digest of their values, the dense keys
+        the per-server trim keeps, ascending) of a group-by's fetched
+        state.  The digest: ``groupStateSumSq``, the sum of squares of
+        every live group's value of each aggregate whose state is dense
+        floats, and for the aggregates whose state is HLL registers
+        ``groupStateHllSum``, the sum of every live group's estimate (an
+        integer, exact in float64), and ``groupStateHllSumSq``."""
         gb = plan.group_by
         keys = np.nonzero(np.asarray(outs["gb_presence"]))[0]
         live_groups = int(keys.size)
+        digest = {"groupStateSumSq": 0.0}
         if live_groups == 0:
-            return 0, 0.0, keys
+            return 0, digest, keys
 
         # sort-dedup distinct states arrive as compacted (slot, gid)
         # pair buffers; index them once per agg for the per-group reads
@@ -1907,16 +1919,21 @@ class QueryExecutor:
                 outs[f"gb_{i}"] = _PairsState(outs[f"gb_{i}"], gb.capacity)
 
         # every live group's order value of each aggregate whose state is
-        # dense floats (count, sum, min, max, avg, minmaxrange: a gather),
-        # and of the others only where the trim needs them
+        # dense floats (count, sum, min, max, avg, minmaxrange: a gather)
+        # or dense HLL registers (one estimate a group, in one numpy
+        # pass), and of the others only where the trim needs them
         trims = live_groups > max(gb.top_n * 5, 100)
-        order, sum_sq = {}, 0.0
+        order = {}
         for i, agg in enumerate(plan.aggs):
             dense = agg.kind in ("scalar", "pair")
-            if trims or dense:
+            registers = agg.kind == "hll" and not agg.sort_pairs
+            if trims or dense or registers:
                 order[i] = self._group_order_values(agg, outs[f"gb_{i}"], keys, ctx)
             if dense:  # one pass in float64, no BLAS: its threads cost a wake-up a query
-                sum_sq += float(np.square(order[i], dtype=np.float64).sum())
+                digest["groupStateSumSq"] += float(np.square(order[i], dtype=np.float64).sum())
+            elif registers:
+                digest["groupStateHllSum"] = digest.get("groupStateHllSum", 0.0) + float(order[i].sum())
+                digest["groupStateHllSumSq"] = digest.get("groupStateHllSumSq", 0.0) + float(np.square(order[i]).sum())
 
         # Trim candidate groups per aggregation (reference trims to
         # topN*5 per server, MCombineGroupByOperator.java:216): a
@@ -1933,20 +1950,20 @@ class QueryExecutor:
                 live_groups,
             )
             keys = keys[keep]
-        return live_groups, sum_sq, keys
+        return live_groups, digest, keys
 
     def _finalize_groups(
         self, request: BrokerRequest, plan: StaticPlan, ctx: TableContext, outs
-    ) -> Tuple[Dict[Tuple[str, ...], List[AggPartial]], int, float]:
+    ) -> Tuple[Dict[Tuple[str, ...], List[AggPartial]], int, Dict[str, float]]:
         """The kept groups' partials by rendered key, how many groups the
         fetched state held before the trim, and their values' digest."""
         gb = plan.group_by
         # from the fetched state to the kept keys: timer and annotation
         # only, inside ``finalize`` (which stays the span and the leaf)
         with boundary("groupTrim", None, self.metrics.timer("phase.groupTrim")):
-            live_groups, sum_sq, keys = self._kept_group_keys(plan, ctx, outs)
+            live_groups, digest, keys = self._kept_group_keys(plan, ctx, outs)
         if keys.size == 0:
-            return {}, live_groups, sum_sq
+            return {}, live_groups, digest
 
         # decompose mixed-radix keys -> per-column global ids
         gids = []
@@ -1973,7 +1990,7 @@ class QueryExecutor:
             for i, agg in enumerate(plan.aggs):
                 partials.append(self._group_partial(agg, outs[f"gb_{i}"], k, ctx))
             groups[ktup] = partials
-        return groups, live_groups, sum_sq
+        return groups, live_groups, digest
 
     def _group_order_values(self, agg, state, keys: np.ndarray, ctx: TableContext) -> np.ndarray:
         """Exact finalized per-group values, used for trim ordering."""
@@ -2020,15 +2037,17 @@ class QueryExecutor:
         if agg.kind == "hll":
             from pinot_tpu.engine import hll as hll_mod
 
-            if agg.sort_pairs:
-                # vectorized over ALL requested keys: one batched decode
-                # over the concatenated per-slot gid slices
-                gids, rows = state.gids_rows_for(keys)
-                regs = _regs_from_gids(gids, rows, keys.size)
-                ests = hll_mod.estimate_from_registers(regs)
-            else:
-                ests = hll_mod.estimate_from_registers(np.asarray(state)[keys])
-            return np.asarray(ests, dtype=np.float64)
+            # registers to estimates: timer and annotation only, inside
+            # ``finalize`` and its ``groupTrim`` (no span of its own)
+            with boundary("hllEstimate", None, self.metrics.timer("phase.hllEstimate")):
+                if agg.sort_pairs:
+                    # vectorized over ALL requested keys: one batched decode
+                    # over the concatenated per-slot gid slices
+                    gids, rows = state.gids_rows_for(keys)
+                    regs = _regs_from_gids(gids, rows, keys.size)
+                else:
+                    regs = np.asarray(state)[keys]
+                return np.asarray(hll_mod.estimate_from_registers(regs), dtype=np.float64).reshape(-1)
         raise AssertionError(agg)
 
     def _group_partial(self, agg, state, key: int, ctx: TableContext) -> AggPartial:

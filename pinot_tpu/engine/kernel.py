@@ -48,17 +48,34 @@ MATMUL_GROUP_CAP = int(_os.environ.get("PINOT_TPU_MATMUL_GROUP_CAP", str(512)))
 _MATMUL_CHUNK = int(_os.environ.get("PINOT_TPU_MATMUL_CHUNK", str(1 << 18)))
 # dense presence/hist holders ride the FACTORED contraction
 # (_value_state_counts) with a combined (group, valueId) key while
-# capacity * gcard_pad stays under this.  From a sweep before the chip
-# round, record gone; not judged on the chip: ROADMAP D4
+# capacity * gcard_pad stays under this, and so does an ungrouped
+# distinctcounthll (hll_lowering 'matmul': 16,384 (register, rank)
+# cells).  That one is judged (chip runs, PR 41, ClickBench hits,
+# 12 segments x 8,388,608 rows, distinctcounthll(UserID) with no filter):
+# a reply 33.7 to 34.3 ms, the program's largest operation 20.1 ms, 0.2
+# ns a row (PR 26 read 0.76 at K = 2^14 for the Pallas form of the same
+# contraction); the scatter it replaces costs 13.4 ns a row at any K
+# (PR 37).  The presence and hist holders have no cell yet: ROADMAP D4
 _MATMUL_VALUE_CAP = int(_os.environ.get("PINOT_TPU_MATMUL_VALUE_CAP", str(1 << 18)))
-# grouped HLL: contraction FLOPs grow with capacity*16384 and cross the
-# sort lowering's cost near capacity 16 (a sweep before the chip round,
-# record gone; not judged on the chip: ROADMAP D4)
+# grouped HLL: the contraction's work grows with capacity x 16,384
+# cells, so this admits 16 groups and no cell has so few (the sweep that
+# put the crossover with the sort near capacity 16 is from before the
+# chip round, record gone: ROADMAP D4)
 _MATMUL_HLL_CAP = int(_os.environ.get("PINOT_TPU_MATMUL_HLL_CAP", str(1 << 18)))
 # grouped HLL beyond the matmul gate lowers to ONE packed int32 sort +
 # searchsorted run-max extraction (bit-identical to scatter-max) while
 # (capacity * HLL_M * 64) fits int32; beyond that the flat scatter runs.
-# Not judged on the chip: ROADMAP D4
+# Judged at 9,040 groups (chip runs, PR 41, ClickBench hits by RegionID,
+# 100.7M rows): 1,590 ms a query, of it the flat lax.sort of the packed
+# keys 327 (3.25 ns a row: a key alone over 2^26.6 rows costs what PR 38
+# read for a key and a float32 over 2^23) and the searchsorted of the
+# 2.31M (group, register) bounds 1,217: 27 steps of a gather the chip
+# serialises, 19 ns an element a step, which no sweep priced; packing
+# the keys 44.  The scatter it stands in for would cost 13.4 ns a row,
+# 1,350 ms (PR 37's reading; not run here).  What the trace asks for
+# next is the sorted rows' run ends summed on the matrix unit
+# (_segment_add_sorted's windowed contraction over rank x is-last) and
+# a sort a segment: ROADMAP S11.  Above 65,536 groups: no cell
 _HLL_SORT_CAP = int(_os.environ.get("PINOT_TPU_HLL_SORT_CAP", str(1 << 16)))
 
 
@@ -71,21 +88,44 @@ def _use_matmul_groupby() -> bool:
     return jax.default_backend() != "cpu"
 
 
-def _grouped_hll_path(capacity: int) -> str:
-    """Which lowering a dense grouped-HLL agg takes — consulted by BOTH
-    the kernel builder (_group_state) and the reduce-spec builder
-    (_state_reduce); they must agree or the reduce misreads the state.
+def hll_lowering(plan: StaticPlan) -> Optional[str]:
+    """Which lowering the plan's HLL aggregates take (kind 'hll': a
+    distinctcounthll whose registers are built from the per-row (register,
+    rank) streams, grouped or not), from what the plan states — consulted
+    by the kernel builder (_agg_state, _group_state), the reduce-spec
+    builder (_state_reduce: they must agree or the reduce misreads the
+    state) and by the launch's ``hll=`` tag and ``hll.lowering.*`` mark.
 
-    'matmul': (group, bucket, rho) occupancy contraction on the MXU.
-    'sort':   packed int32 keys, sort + run-max extraction in the reduce.
-    'scatter': flat serialized scatter-max (packed key would overflow).
-    """
-    K = capacity * config.HLL_M * 64
-    if _use_matmul_groupby() and K <= _MATMUL_HLL_CAP:
+    'matmul':  the (group, register, rank) occupancy contraction on the
+               matrix unit (_value_state_counts) and an argmax by iota:
+               an ungrouped aggregate on the chip (16,384 cells, under
+               _MATMUL_VALUE_CAP) and a group-by of up to
+               _MATMUL_HLL_CAP / 16,384 groups (16).
+    'sort':    a group-by beyond that, up to _HLL_SORT_CAP groups: one
+               packed int32 key a row, sorted in the reduce, which reads
+               each (group, register) cell's largest key
+               (_reduce_hll_sort).
+    'scatter': the serialised scatter-max: a group-by over more groups
+               than the packed key holds, and every ungrouped aggregate
+               on the CPU backend.
+    'pairs':   a group space whose dense registers would pass the value
+               state's budget (plan.value_state_sort_pairs): (slot,
+               register x 64 + rank) pairs through the sort-dedup reduce.
+    None for a plan without such an aggregate (also where the planner
+    lowered distinctcounthll to a presence contraction: kind 'presence').
+    The aggregates of one plan share its group space, so one answer."""
+    aggs = [a for a in getattr(plan, "aggs", ()) if getattr(a, "kind", None) == "hll"]  # a JoinPlan's are not StaticAggs
+    if not aggs:
+        return None
+    if aggs[0].sort_pairs:
+        return "pairs"
+    if plan.group_by is None:
+        cells = config.HLL_M * 64  # rho < 64 always (64-bit hash)
+        return "matmul" if _use_matmul_groupby() and cells <= _MATMUL_VALUE_CAP else "scatter"
+    capacity = plan.group_by.capacity
+    if _use_matmul_groupby() and capacity * config.HLL_M * 64 <= _MATMUL_HLL_CAP:
         return "matmul"
-    if capacity <= _HLL_SORT_CAP:
-        return "sort"
-    return "scatter"
+    return "sort" if capacity <= _HLL_SORT_CAP else "scatter"
 
 
 # Dense group-by capacities above MATMUL_GROUP_CAP ride the two-level
@@ -580,9 +620,10 @@ def _value_state_counts_xla(flat_idx, K: int):
     with a FACTORED one-hot contraction: split the key into (hi, lo)
     radix-128 digits and contract two THIN one-hots as a real
     [K1, block] @ [block, 128] matmul per block — full MXU tiles instead
-    of the M=1 degenerate matmul of the scan contraction (measured in a
-    sweep before the chip round, record gone; not judged on the chip:
-    ROADMAP D4).
+    of the M=1 degenerate matmul of the scan contraction.  Judged on the
+    chip for an ungrouped distinctcounthll (K = 16,384; PR 41: 0.2 ns a
+    row over 100.7M rows, the comment at _MATMUL_VALUE_CAP); the
+    presence and hist holders that ride it have no cell: ROADMAP D4.
 
     Weights must be binary and FOLDED into the index: invalid entries
     carry ``flat_idx == K`` and one-hot to a dropped row.  bf16 one-hots
@@ -721,7 +762,7 @@ def _row_values(agg: StaticAgg, seg, mask):
     return expr_eval(agg.argument, column), mask
 
 
-def _agg_state(agg: StaticAgg, i: int, seg, q, mask) -> Any:
+def _agg_state(agg: StaticAgg, i: int, seg, q, mask, hll: Optional[str] = None) -> Any:
     """Per-segment partial state for one aggregation (no group-by)."""
     fdt = config.float_dtype()
     base = agg.base
@@ -794,7 +835,7 @@ def _agg_state(agg: StaticAgg, i: int, seg, q, mask) -> Any:
             m = mask
             b_rows, r_rows = _hll_rows(agg, seg, bucket, rho)
         K = config.HLL_M * 64  # rho < 64 always (64-bit hash)
-        if _use_matmul_groupby() and K <= _MATMUL_VALUE_CAP:
+        if hll == "matmul":
             # register max via a (bucket, rho) occupancy contraction on
             # the MXU + argmax-by-iota — replaces the serialized
             # scatter-max
@@ -872,7 +913,7 @@ def _group_add_weights(agg: StaticAgg, seg, mask, kvalid):
     return (per_entry(row_sum), per_entry(row_cnt))
 
 
-def _group_state(agg: StaticAgg, i: int, seg, q, mask, keys, kvalid, capacity) -> Any:
+def _group_state(agg: StaticAgg, i: int, seg, q, mask, keys, kvalid, capacity, hll: Optional[str] = None) -> Any:
     fdt = config.float_dtype()
     base = agg.base
     idx = jnp.where(kvalid, keys, capacity)  # invalid -> dropped
@@ -1009,9 +1050,8 @@ def _group_state(agg: StaticAgg, i: int, seg, q, mask, keys, kvalid, capacity) -
                 jnp.where(pair_v, pair_k.astype(jnp.int32), sent),
                 jnp.where(pair_v, gid, sent),
             )
-        path = _grouped_hll_path(capacity)
         K = capacity * config.HLL_M * 64
-        if path == "matmul":
+        if hll == "matmul":
             # small group spaces: (group, bucket, rho) occupancy on the
             # MXU + argmax-by-iota, like the scalar HLL path
             combined = jnp.where(
@@ -1031,12 +1071,12 @@ def _group_state(agg: StaticAgg, i: int, seg, q, mask, keys, kvalid, capacity) -
                 jnp.int32, (capacity, config.HLL_M, 64), 2
             )
             return jnp.max(jnp.where(counts > 0, rho_iota, 0), axis=2)
-        if path == "sort":
+        if hll == "sort":
             # mid/large group spaces: pack (group, bucket, rho) into ONE
             # int32 per entry (4 B/row — the leanest HBM footprint of
             # the three paths) and let the cross-segment reduce sort the
             # packed keys and run-max-extract registers (bit-identical
-            # to scatter-max; not judged on the chip: ROADMAP D4)
+            # to scatter-max)
             packed = jnp.where(
                 pair_v,
                 ((pair_k * config.HLL_M + pair_b.astype(jnp.int32)) << 6)
@@ -1192,6 +1232,7 @@ def _make_loop_groupby_kernel(plan: StaticPlan) -> Callable:
 def make_single_segment_kernel(plan: StaticPlan) -> Callable:
     if groupby_operands(plan) == "loop":
         return _make_loop_groupby_kernel(plan)
+    hll = hll_lowering(plan)
 
     def kernel(seg: Dict[str, Any], q: Dict[str, Any]) -> Dict[str, Any]:
         mask = _filter_mask(plan, seg, q)
@@ -1215,7 +1256,7 @@ def make_single_segment_kernel(plan: StaticPlan) -> Callable:
                 for i, agg in enumerate(plan.aggs):
                     if f"gb_{i}" not in out:
                         out[f"gb_{i}"] = _group_state(
-                            agg, i, seg, q, mask, keys, kvalid, cap
+                            agg, i, seg, q, mask, keys, kvalid, cap, hll
                         )
             else:
                 flat_idx = jnp.where(kvalid, keys, cap).reshape(-1)
@@ -1226,11 +1267,11 @@ def make_single_segment_kernel(plan: StaticPlan) -> Callable:
                 )
                 for i, agg in enumerate(plan.aggs):
                     out[f"gb_{i}"] = _group_state(
-                        agg, i, seg, q, mask, keys, kvalid, cap
+                        agg, i, seg, q, mask, keys, kvalid, cap, hll
                     )
         else:
             for i, agg in enumerate(plan.aggs):
-                out[f"agg_{i}"] = _agg_state(agg, i, seg, q, mask)
+                out[f"agg_{i}"] = _agg_state(agg, i, seg, q, mask, hll)
         _count_states_to_int(plan, out)
 
         if plan.selection is not None:
@@ -1306,8 +1347,9 @@ def output_reducers(plan: StaticPlan) -> Dict[str, str]:
     red: Dict[str, str] = {"num_docs": "sum"}
     if plan.group_by is not None:
         red["gb_presence"] = "max"
+        hll = hll_lowering(plan)
         for i, agg in enumerate(plan.aggs):
-            red[f"gb_{i}"] = _state_reduce(agg, plan.group_by.capacity)
+            red[f"gb_{i}"] = _state_reduce(agg, plan.group_by.capacity, hll)
     else:
         for i, agg in enumerate(plan.aggs):
             red[f"agg_{i}"] = _state_reduce(agg)
@@ -1317,7 +1359,7 @@ def output_reducers(plan: StaticPlan) -> Dict[str, str]:
     return red
 
 
-def _state_reduce(agg: StaticAgg, capacity: int = 0) -> str:
+def _state_reduce(agg: StaticAgg, capacity: int = 0, hll: Optional[str] = None) -> str:
     base = agg.base
     if base in ("count", "sum"):
         return "sum"
@@ -1336,7 +1378,7 @@ def _state_reduce(agg: StaticAgg, capacity: int = 0) -> str:
     if agg.kind == "hll":
         if agg.sort_pairs:
             return "distinct_pairs"
-        if capacity and _grouped_hll_path(capacity) == "sort":
+        if hll == "sort":
             # packed-key states: the reduce itself sorts and extracts
             # registers — the capacity rides in the op tag
             return f"hll_sort:{capacity}"
@@ -1449,7 +1491,9 @@ def _reduce_hll_sort(value, capacity: int):
     scatter-max lowering: rho rides the low 6 bits, so the largest
     packed key within a (group, bucket) cell prefix carries the cell's
     max rho).  Replaces the serialized scatter for the
-    high-cardinality HLL group-by (not judged on the chip: ROADMAP D4).
+    high-cardinality HLL group-by.  On the chip (PR 41, 100.7M keys, 2.31M
+    cells): the sort 327 ms, the searchsorted 1,217 (the comment at
+    _HLL_SORT_CAP has the account).
     """
     s = jax.lax.sort(value.reshape(-1))
     ncells = capacity * config.HLL_M

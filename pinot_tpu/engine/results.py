@@ -249,7 +249,13 @@ GroupKey = Tuple[str, ...]
 #                      dense floats (count, sum, min, max, avg,
 #                      minmaxrange): a digest of the whole fetched
 #                      state, where a reply of TOP n shows n groups.
-#                      These three are a server's own: the merge adds
+#   groupStateHllSum   the sum of every live group's estimate of each
+#                      aggregate whose state is dense HLL registers
+#                      (distinctcounthll by group), as the trim computes
+#                      them: an integer far under 2^53, so exact
+#   groupStateHllSumSq the sum of their squares: with the one above the
+#                      digest of a fetched register state
+#                      These five are a server's own: the merge adds
 #                      them, so with one answering server they are the
 #                      query's, and with more a group live on two counts
 #                      twice and the squares are of each server's part
@@ -289,6 +295,8 @@ COST_KEYS = (
     "numGroupsLive",
     "numGroupsKept",
     "groupStateSumSq",
+    "groupStateHllSum",
+    "groupStateHllSumSq",
     "batchHits",
     "rescacheHits",
     "buildRows",

@@ -376,3 +376,189 @@ def synthetic_adevents_segment(
         adevents_schema(), ADEVENTS_TABLE, dict_values, num_rows, seed, name,
         clustered_column="event_time", time_column="event_time", rng=rng,
     )
+
+
+# ---------------------------------------------------------------------------
+# ClickBench ``hits``, the columns its distinct-user queries read
+# (github.com/ClickHouse/ClickBench, queries.sql lines 5, 9 and 10:
+# COUNT(DISTINCT UserID) alone and by RegionID).  The data file is not
+# shipped, so every distribution is synthetic and skewed as a web log is.
+# ---------------------------------------------------------------------------
+
+HITS_TABLE = "hits"
+HITS_USERS = 19_250_000  # ids drawn from; a table of 100.7M rows then holds about 17.6M distinct
+HITS_USER_EXPONENT = 0.7
+HITS_REGIONS = 9_040
+HITS_ADV_ENGINES = 18
+HITS_ADV_SHARE = 0.0063  # AdvEngineID <> 0 in 630,500 of the source's 99,997,497 rows
+# (width, weight in 1,000): a dozen common screens, mean 1,540
+HITS_WIDTHS = ((1024, 40), (1280, 110), (1366, 290), (1440, 90), (1536, 80), (1600, 80), (1680, 60),
+               (1920, 210), (2048, 10), (2560, 20), (360, 5), (768, 5))
+_HITS_DAYS_A_SEGMENT = 3  # a segment is a contiguous run of dates
+_HITS_FIRST_DAY = 15887  # 2013-07-01, days since the epoch
+_REGION_PRIME = 9_041  # rank -> RegionID: rank * 5 mod 9,041, a bijection on 1..9,040
+_ID_SALT = 0x9E3779B97F4A7C15
+
+
+def hits_users_schema() -> Schema:
+    """The five columns ClickBench's three distinct-user queries read,
+    and the date a deployment partitions by.  PQL has no SMALLINT:
+    ``AdvEngineID`` and ``ResolutionWidth`` are INT."""
+    return Schema(
+        HITS_TABLE,
+        dimensions=[
+            FieldSpec("UserID", DataType.LONG),
+            FieldSpec("RegionID", DataType.INT),
+        ],
+        metrics=[
+            FieldSpec("AdvEngineID", DataType.INT, FieldType.METRIC),
+            FieldSpec("ResolutionWidth", DataType.INT, FieldType.METRIC),
+        ],
+        time_field=TimeFieldSpec("EventDate", DataType.INT, time_unit="DAYS"),
+    )
+
+
+def zipf_cdf(n: int, exponent: float):
+    """Cumulative probabilities of ranks 1..n under p(k) ~ k^-exponent."""
+    import numpy as np
+
+    cdf = np.cumsum(np.arange(1, n + 1, dtype=np.float64) ** -exponent)
+    return cdf / cdf[-1]
+
+
+_zipf_tables: Dict[tuple, Any] = {}
+
+
+def _zipf_ranks_sorted(rng, size: int, n: int, exponent: float):
+    """``size`` draws of a rank 0..n-1 by Zipf's law, ascending: sorted
+    uniforms searched in the cumulative table (a sorted needle walks the
+    table once)."""
+    import numpy as np
+
+    key = (n, exponent)
+    if key not in _zipf_tables:
+        _zipf_tables[key] = zipf_cdf(n, exponent)
+    ranks = np.searchsorted(_zipf_tables[key], np.sort(rng.random(size)), side="left").astype(np.int64)
+    return np.minimum(ranks, n - 1, out=ranks)
+
+
+def _mix64(x):
+    """splitmix64's finalizer over a uint64 array: a fixed function of an
+    id, for the id's value and its home region."""
+    import numpy as np
+
+    x = x + np.uint64(_ID_SALT)
+    x = (x ^ (x >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
+    x = (x ^ (x >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
+    return x ^ (x >> np.uint64(31))
+
+
+def hits_expected_distinct(rows: int, n: int, exponent: float) -> float:
+    """How many distinct ranks ``rows`` Zipf draws over 1..n hold, in
+    expectation: sum of 1 - (1 - p_k)^rows."""
+    import numpy as np
+
+    p = np.diff(zipf_cdf(n, exponent), prepend=0.0)
+    return float(np.sum(-np.expm1(rows * np.log1p(-p))))
+
+
+def synthetic_hits_users_segment(
+    num_rows: int,
+    seed: int = 7,
+    name: str = "hits0",
+    users: int = HITS_USERS,
+    regions: int = HITS_REGIONS,
+):
+    """One segment of ``hits``, skewed as a web log is: ``UserID`` by
+    Zipf's law (exponent 0.7) over ``users`` ids, each id's value a fixed
+    63-bit function of its rank (as the source's are opaque); ``RegionID``
+    by Zipf's law (exponent 1) over ``regions``: a user's home region, a
+    fixed function of the id, for nine hits in ten and a fresh draw for
+    the tenth; ``AdvEngineID`` 0 in 99.37% of the rows and uniform over
+    1..18 otherwise; ``ResolutionWidth`` from a dozen common widths;
+    ``EventDate`` three consecutive days a segment, later by the
+    segment's number (the digits that end ``name``), sorted.  The rows of
+    ``seed`` are the same in every process."""
+    import numpy as np
+
+    from pinot_tpu.segment.dictionary import Dictionary
+    from pinot_tpu.segment.immutable import ColumnData, ColumnMetadata, ImmutableSegment, SegmentMetadata
+
+    rng = np.random.default_rng(seed)
+    # UserID: the ranks drawn in ascending order, so that the distinct
+    # ones are the runs' heads; their values sorted into the dictionary;
+    # the rows shuffled at the end (no table by rank, no sort of the rows)
+    user_rank = _zipf_ranks_sorted(rng, num_rows, users, HITS_USER_EXPONENT)
+    head = np.ones(num_rows, dtype=bool)
+    head[1:] = user_rank[1:] != user_rank[:-1]
+    run = np.cumsum(head, dtype=np.int32) - 1  # which distinct user a row is, rows by rank
+    hashes = _mix64(user_rank[head].astype(np.uint64))
+    values = (hashes >> np.uint64(1)).astype(np.int64)  # opaque, positive, one an id (a collision is a 2^-63 event)
+    order = np.argsort(values)
+    position = np.empty(order.size, dtype=np.int32)
+    position[order] = np.arange(order.size, dtype=np.int32)
+    shuffle = rng.permutation(num_rows)
+    user_fwd = position[run][shuffle]
+    # RegionID: the home region of each distinct user, then a fresh draw
+    # for a tenth of the rows.  Rank r has the value r * 5 mod 9,041 (a
+    # bijection on 1..9,040, so that size does not follow the key's order)
+    home_u = (_mix64(hashes) >> np.uint64(11)).astype(np.float64) / float(1 << 53)
+    home = np.minimum(np.searchsorted(zipf_cdf(regions, 1.0), home_u, side="left"), regions - 1).astype(np.int32)
+    region_rank = home[run][shuffle]
+    away = np.nonzero(rng.random(num_rows) >= 0.9)[0]
+    region_rank[away] = _zipf_ranks_sorted(rng, away.size, regions, 1.0)[rng.permutation(away.size)]
+    region_values = np.arange(1, regions + 1, dtype=np.int64)
+    if regions == HITS_REGIONS:
+        region_fwd = (((region_rank.astype(np.int64) + 1) * 5) % _REGION_PRIME - 1).astype(np.int32)
+    else:
+        region_fwd = region_rank.astype(np.int32)
+    adv_fwd = np.zeros(num_rows, dtype=np.int32)
+    clicked = np.nonzero(rng.random(num_rows) < HITS_ADV_SHARE)[0]
+    adv_fwd[clicked] = rng.integers(1, HITS_ADV_ENGINES + 1, clicked.size)
+    by_width = sorted(HITS_WIDTHS)
+    width_cdf = np.cumsum([k for _, k in by_width]) / float(sum(k for _, k in by_width))
+    width_fwd = np.minimum(np.searchsorted(width_cdf, rng.random(num_rows), side="right"), len(by_width) - 1).astype(np.int32)
+    digits = "".join(ch for ch in name if ch.isdigit())
+    first_day = _HITS_FIRST_DAY + _HITS_DAYS_A_SEGMENT * (int(digits[-6:]) if digits else 0)
+    day_fwd = np.sort(rng.integers(0, _HITS_DAYS_A_SEGMENT, num_rows, dtype=np.int32))
+
+    # every dictionary holds its whole pool (UserID's: the ids drawn)
+    pools = {
+        "UserID": (values[order], user_fwd),
+        "RegionID": (region_values, region_fwd),
+        "AdvEngineID": (np.arange(HITS_ADV_ENGINES + 1, dtype=np.int64), adv_fwd),
+        "ResolutionWidth": (np.array([w for w, _ in by_width], dtype=np.int64), width_fwd),
+        "EventDate": (first_day + np.arange(_HITS_DAYS_A_SEGMENT, dtype=np.int64), day_fwd),
+    }
+    columns = {}
+    for spec in hits_users_schema().all_fields():
+        pool, fwd = pools[spec.name]
+        d = Dictionary(spec.stored_type, pool)
+        columns[spec.name] = ColumnData(
+            metadata=ColumnMetadata(
+                name=spec.name,
+                data_type=spec.data_type,
+                field_type=spec.field_type,
+                single_value=True,
+                cardinality=d.cardinality,
+                total_docs=num_rows,
+                is_sorted=spec.name == "EventDate",
+                total_number_of_entries=num_rows,
+                min_value=d.min_value,
+                max_value=d.max_value,
+            ),
+            dictionary=d,
+            fwd=fwd,
+        )
+    smeta = SegmentMetadata(
+        segment_name=name,
+        table_name=HITS_TABLE,
+        num_docs=num_rows,
+        columns={c.metadata.name: c.metadata for c in columns.values()},
+        time_column="EventDate",
+    )
+    seg = ImmutableSegment(metadata=smeta, columns=columns)
+    import zlib
+
+    smeta.crc = zlib.crc32(f"{name}:{num_rows}:{seed}".encode())
+    return seg

@@ -661,6 +661,31 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "order values and their sum of squares, and trim_group_candidates' "
     "selection around the cut: no sort of the state); timer and "
     "annotation, no span",
+    # which lowering a launch's HLL aggregates took, grouped or not, one
+    # mark a launch that carries one (engine/kernel.py hll_lowering,
+    # which the kernel builder and the reduce spec ask too; the launch's
+    # ``hll=`` tag)
+    "hll.lowering.matmul": "launches whose distinctcounthll registers come "
+    "from the (group, register, rank) occupancy contraction on the matrix "
+    "unit (ungrouped on the chip; up to 16 groups)",
+    "hll.lowering.sort": "launches whose grouped distinctcounthll packs "
+    "(group, register, rank) into one int32 key a row, sorted in the "
+    "reduce (up to 65,536 groups)",
+    "hll.lowering.scatter": "launches whose distinctcounthll registers "
+    "come from the serialised scatter-max (more groups than the packed "
+    "key holds; the CPU backend's ungrouped form)",
+    "hll.lowering.pairs": "launches whose grouped distinctcounthll emits "
+    "(slot, register x 64 + rank) pairs for the sort-dedup reduce (a "
+    "group space whose dense registers pass the value state's budget)",
+    "phase.hllEstimate": "inside phase.finalize (and phase.groupTrim) of a "
+    "device group-by with distinctcounthll: every live group's registers "
+    "to its estimate, one numpy pass (engine/hll.py "
+    "estimate_from_registers); timer and annotation, no span",
+    "phase.hllDerive": "inside phase.staging: a column's per-row HLL "
+    "(register, rank) streams derived on the host, the dictionaries "
+    "hashed (engine/hll.py dictionary_tables) and fanned out through the "
+    "forward indexes (engine/device.py _hll_streams); timer and "
+    "annotation, no span",
     # arithmetic inside an aggregate (sum(a*(1-b))): one mark a query
     # whose plan holds a compound expression, by where it was answered
     "agg.expr.device": "queries with an expression inside an aggregate "
