@@ -62,20 +62,25 @@ _MATMUL_VALUE_CAP = int(_os.environ.get("PINOT_TPU_MATMUL_VALUE_CAP", str(1 << 1
 # put the crossover with the sort near capacity 16 is from before the
 # chip round, record gone: ROADMAP D4)
 _MATMUL_HLL_CAP = int(_os.environ.get("PINOT_TPU_MATMUL_HLL_CAP", str(1 << 18)))
-# grouped HLL beyond the matmul gate lowers to ONE packed int32 sort +
-# searchsorted run-max extraction (bit-identical to scatter-max) while
-# (capacity * HLL_M * 64) fits int32; beyond that the flat scatter runs.
-# Judged at 9,040 groups (chip runs, PR 41, ClickBench hits by RegionID,
-# 100.7M rows): 1,590 ms a query, of it the flat lax.sort of the packed
-# keys 327 (3.25 ns a row: a key alone over 2^26.6 rows costs what PR 38
-# read for a key and a float32 over 2^23) and the searchsorted of the
-# 2.31M (group, register) bounds 1,217: 27 steps of a gather the chip
-# serialises, 19 ns an element a step, which no sweep priced; packing
-# the keys 44.  The scatter it stands in for would cost 13.4 ns a row,
-# 1,350 ms (PR 37's reading; not run here).  What the trace asks for
-# next is the sorted rows' run ends summed on the matrix unit
-# (_segment_add_sorted's windowed contraction over rank x is-last) and
-# a sort a segment: ROADMAP S11.  Above 65,536 groups: no cell
+# grouped HLL beyond the matmul gate lowers to one packed int32 key a
+# row, sorted a segment, and the sum of each (group, register) run's last
+# rank on the matrix unit (_hll_sorted_registers; bit-identical to
+# scatter-max) while (capacity * HLL_M * 64) fits int32; beyond that the
+# flat scatter runs.  Judged at 9,040 groups (chip runs, PR 42, ClickBench
+# hits by RegionID, 12 segments of 2^23 rows, 2.31M cells): 386 ms of
+# device time a query, of it the twelve sorts 329 (lax.sort over
+# [12, 2^23] keys: 3.27 ns a row, what ONE sort of all 2^26.6 keys costs,
+# 3.25 to 3.33: a row of 2^23 is no cheaper to sort than the whole), the twelve
+# windowed calls 20.7 (0.2 ns a row, one or two windows a block of 8,192
+# rows), the keys' pass into cells and ranks 7, the occupancy's
+# contraction beside it 14.4.  Until PR 42 the reduce sorted every
+# segment's keys at once and a searchsorted of one bound a cell read the
+# largest key: 1,217 ms of a 1,590 ms query (27 steps of a gather the
+# chip serialises, 19.5 ns an element a step; PR 41), and packing the
+# keys 44, now under 4.3.  The scatter it stands in for would cost 13.4
+# ns a row, 1,350 ms (PR 37's reading; not run here).  What is left is
+# the sort: ROADMAP S13.  Above 12,256 groups the cells go in ranges
+# (compiled for a v5e at 65,536, not run on one); above 65,536: no cell
 _HLL_SORT_CAP = int(_os.environ.get("PINOT_TPU_HLL_SORT_CAP", str(1 << 16)))
 
 
@@ -92,9 +97,10 @@ def hll_lowering(plan: StaticPlan) -> Optional[str]:
     """Which lowering the plan's HLL aggregates take (kind 'hll': a
     distinctcounthll whose registers are built from the per-row (register,
     rank) streams, grouped or not), from what the plan states — consulted
-    by the kernel builder (_agg_state, _group_state), the reduce-spec
-    builder (_state_reduce: they must agree or the reduce misreads the
-    state) and by the launch's ``hll=`` tag and ``hll.lowering.*`` mark.
+    by the kernel builder (_agg_state, _group_state), by zone_blocks and
+    by the launch's ``hll=`` tag and ``hll.lowering.*`` mark.  Whatever
+    the answer, a segment's state is dense registers [capacity, HLL_M]
+    that fold by ``max`` ('pairs' apart).
 
     'matmul':  the (group, register, rank) occupancy contraction on the
                matrix unit (_value_state_counts) and an argmax by iota:
@@ -102,12 +108,17 @@ def hll_lowering(plan: StaticPlan) -> Optional[str]:
                _MATMUL_VALUE_CAP) and a group-by of up to
                _MATMUL_HLL_CAP / 16,384 groups (16).
     'sort':    a group-by beyond that, up to _HLL_SORT_CAP groups: one
-               packed int32 key a row, sorted in the reduce, which reads
-               each (group, register) cell's largest key
-               (_reduce_hll_sort).
+               packed int32 key a row, sorted a segment where they are
+               built; a register is the sum of its (group, register)
+               run's last rank, added on the matrix unit by the windowed
+               contraction over rows in key order
+               (_hll_sorted_registers).
     'scatter': the serialised scatter-max: a group-by over more groups
-               than the packed key holds, and every ungrouped aggregate
-               on the CPU backend.
+               than the packed key holds, and every aggregate on the CPU
+               backend, which has no matrix unit and would run the sorted
+               form's Pallas call in the interpreter (unless
+               PINOT_TPU_GROUPBY_MATMUL=1, the tests' switch, forces the
+               chip's lowerings, as for groupby_lowering).
     'pairs':   a group space whose dense registers would pass the value
                state's budget (plan.value_state_sort_pairs): (slot,
                register x 64 + rank) pairs through the sort-dedup reduce.
@@ -119,11 +130,13 @@ def hll_lowering(plan: StaticPlan) -> Optional[str]:
         return None
     if aggs[0].sort_pairs:
         return "pairs"
+    if not _use_matmul_groupby():
+        return "scatter"
+    cells = config.HLL_M * 64  # rho < 64 always (64-bit hash)
     if plan.group_by is None:
-        cells = config.HLL_M * 64  # rho < 64 always (64-bit hash)
-        return "matmul" if _use_matmul_groupby() and cells <= _MATMUL_VALUE_CAP else "scatter"
+        return "matmul" if cells <= _MATMUL_VALUE_CAP else "scatter"
     capacity = plan.group_by.capacity
-    if _use_matmul_groupby() and capacity * config.HLL_M * 64 <= _MATMUL_HLL_CAP:
+    if capacity * cells <= _MATMUL_HLL_CAP:
         return "matmul"
     return "sort" if capacity <= _HLL_SORT_CAP else "scatter"
 
@@ -173,10 +186,18 @@ _RADIX_BLOCK_MAX = 8192  # rows a step: 18.8, 21.3, 27.6 ms at 8192, 4096, 2048 
 # shape, ms: 11.63 at 8192 x 64; 11.70 at 4096 x 64; 13.52 at 16384 x 64;
 # 13.57 at 8192 x 128; 13.73 at 16384 x 128); the VMEM one call's
 # accumulator may take, and the weight columns a call at most (a step's
-# one-hot is [(1 + 3 cols) x window, rows a step] bfloat16 beside it)
+# one-hot is [(1 + 3 cols) x window, rows a step] bfloat16 beside it).
+# The accumulator's bound was 20 MB until PR 42, whose one-part call
+# (_hll_sorted_registers) the chip's compiler refuses from 15.9 MB of
+# accumulator on ("ran out of memory in memory space vmem", 16 MiB of
+# scoped allocation for the call fused with the update of its vmapped
+# output; 15.0 compiles: a v5e described, not attached); the sums'
+# calls of ten parts compile at 20.4.  One bound for both, under that
+# with room; no cell's or test's sums change their calls by it (a
+# capacity of 307,000 to 520,000 with two or more sums would)
 _SORTED_BLOCK = 8192
 _SORTED_WINDOW = 64
-_SORTED_ACC_BYTES = 20 << 20
+_SORTED_ACC_BYTES = 12 << 20
 _SORTED_COLS_MAX = 3
 
 
@@ -423,39 +444,43 @@ def _segment_add_radix(flat_idx, weights, capacity: int):
     return jnp.stack(_states_of_parts(acc)).astype(config.float_dtype())
 
 
-def _segment_add_sorted(flat_idx, weights, capacity: int):
-    """_segment_add_radix's states for a ``capacity`` whose contraction
-    would cost more than putting the rows in key order first
-    (groupby_operands 'sorted'): ONE sort of the rows by bucket carrying
-    the weight columns, then the same two-level contraction with its hi
-    one-hot cut to a window of ``_SORTED_WINDOW`` sublanes (x 128 keys).
+def _sorted_sublanes(capacity: int) -> int:
+    """Sublanes (x 128 buckets) of the windowed contraction's accumulator
+    over ``capacity`` buckets: the last window may start at the last
+    bucket's sublane, and a bfloat16 tile is 16 sublanes."""
+    return -(-(-(-capacity // _RADIX) + _SORTED_WINDOW) // 16) * 16
 
-    A grid step's block of sorted rows has its first and last key known
+
+def _sorted_window_sums(idx, cols, capacity: int, n_parts: int, parts):
+    """The two-level contraction over rows ALREADY in bucket order, its
+    hi one-hot cut to a window of ``_SORTED_WINDOW`` sublanes (x 128
+    buckets): float32 sums [n_parts, capacity] of the ``n_parts`` rows
+    [1, block] that ``parts(idx, col_refs)`` makes of a block's buckets
+    and float32 columns inside the kernel, each exact in bfloat16.  The
+    caller says what a row's parts are (_segment_add_sorted: the
+    validity and three a float32 weight; _hll_sorted_registers: the rank
+    as it stands); the grid, the windows and the accumulator are one.
+
+    ``idx``: int32, ascending, whole blocks of ``_SORTED_BLOCK``; rows
+    that count nowhere carry ``capacity`` or more (and parts of zero) and
+    stand last.  A grid step's block has its first and last bucket known
     before the call (scalar prefetch), so the step builds ``hi`` over the
-    window at the first key's sublane (aligned down to 8), adds the
+    window at the first bucket's sublane (aligned down to 8), adds the
     product into the accumulator at that dynamic sublane offset, and
-    moves the window on while the block's last key lies past it.  A
-    window either ends a block or moves ``_SORTED_WINDOW`` x 128 keys on,
-    so a segment takes at most ``rows / block + capacity / (window keys)``
-    products whatever its keys are: the work no longer grows with K
-    times the rows.  Filtered rows carry ``flat_idx == capacity`` and
-    zero weights and sort to the end; a block of them alone does nothing.
-
-    Weights, counts and precision are _segment_add_radix's: validity and
-    three exact bfloat16 parts a weight, float32 sums.  The whole
-    accumulator stays in VMEM; past ``_SORTED_ACC_BYTES`` of it the
-    weight columns go in groups, a call a group over the same sorted rows."""
+    moves the window on while the block's last bucket lies past it.  A
+    window either ends a block or moves ``_SORTED_WINDOW`` x 128 buckets
+    on, so the call takes at most ``rows / block + capacity / (window
+    buckets)`` products whatever the buckets are: the work no longer
+    grows with K times the rows.  A block of rows that count nowhere
+    does nothing.  The whole accumulator stays in VMEM."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     W, blk = _SORTED_WINDOW, _SORTED_BLOCK
-    flat_idx, weights = _whole_blocks(flat_idx, weights, blk, capacity)
-    flat_idx, *weights = jax.lax.sort((flat_idx, *weights), num_keys=1, is_stable=False)
-    blocks = flat_idx.reshape(-1, blk)
+    blocks = idx.reshape(-1, blk)
     firsts = blocks[:, 0]
     lasts = jnp.max(jnp.where(blocks < capacity, blocks, -1), axis=1)  # -1: no row of the block counts
-    K1 = -(-capacity // _RADIX)
-    K1p = -(-(K1 + W) // 16) * 16  # the last window may start at the last key's sublane
+    K1p = _sorted_sublanes(capacity)
 
     def kernel(first_ref, last_ref, idx_ref, *refs):
         w_refs, acc_ref = refs[:-1], refs[-1]
@@ -467,7 +492,7 @@ def _segment_add_sorted(flat_idx, weights, capacity: int):
 
         idx = idx_ref[...]  # [1, blk]: rows along the lanes
         lo = (jax.lax.broadcasted_iota(jnp.int32, (_RADIX, blk), 0) == (idx & (_RADIX - 1))).astype(jnp.bfloat16)
-        parts = _exact_parts(idx, w_refs, capacity)
+        rows = parts(idx, w_refs)
         hi_digit = idx >> 7
         base0 = (first_ref[i] >> 10) << 3
         windows = jnp.where(last_ref[i] < 0, 0, ((last_ref[i] >> 7) - base0) // W + 1)
@@ -475,35 +500,91 @@ def _segment_add_sorted(flat_idx, weights, capacity: int):
         def window(j, carry):
             base = pl.multiple_of(base0 + j * W, 8)
             hi = jax.lax.broadcasted_iota(jnp.int32, (W, blk), 0) == (hi_digit - base)
-            a_t = jnp.concatenate([jnp.where(hi, p, 0.0).astype(jnp.bfloat16) for p in parts], axis=0)
+            a_t = jnp.concatenate([jnp.where(hi, p, 0.0).astype(jnp.bfloat16) for p in rows], axis=0)
             prod = jax.lax.dot_general(a_t, lo, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-            for c in range(len(parts)):
+            for c in range(n_parts):
                 acc_ref[c, pl.ds(base, W), :] += prod[c * W:(c + 1) * W]
             return carry
 
         jax.lax.fori_loop(0, windows, window, 0)
 
-    def call(ws):
-        n_parts = 1 + 3 * len(ws)
-        acc = pl.pallas_call(
-            kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(blocks.shape[0],),
-                in_specs=[pl.BlockSpec((1, blk), lambda i, *_: (0, i))] * (1 + len(ws)),
-                out_specs=pl.BlockSpec((n_parts, K1p, _RADIX), lambda i, *_: (0, 0, 0)),
-            ),
-            out_shape=jax.ShapeDtypeStruct((n_parts, K1p, _RADIX), jnp.float32),
-            interpret=jax.default_backend() == "cpu",
-            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_RADIX_VMEM_LIMIT),
-        )(firsts, lasts, flat_idx.reshape(1, -1), *[w.reshape(1, -1) for w in ws])
-        return _states_of_parts(acc.reshape(n_parts, K1p * _RADIX)[:, :capacity])
+    acc = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(blocks.shape[0],),
+            in_specs=[pl.BlockSpec((1, blk), lambda i, *_: (0, i))] * (1 + len(cols)),
+            out_specs=pl.BlockSpec((n_parts, K1p, _RADIX), lambda i, *_: (0, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_parts, K1p, _RADIX), jnp.float32),
+        interpret=jax.default_backend() == "cpu",
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_RADIX_VMEM_LIMIT),
+    )(firsts, lasts, idx.reshape(1, -1), *[w.reshape(1, -1) for w in cols])
+    return acc.reshape(n_parts, K1p * _RADIX)[:, :capacity]
 
-    per = max(1, min(_SORTED_COLS_MAX, (_SORTED_ACC_BYTES // (K1p * _RADIX * 4) - 1) // 3))  # weight columns a call
+
+def _segment_add_sorted(flat_idx, weights, capacity: int):
+    """_segment_add_radix's states for a ``capacity`` whose contraction
+    would cost more than putting the rows in key order first
+    (groupby_operands 'sorted'): ONE sort of the rows by bucket carrying
+    the weight columns, then the windowed contraction over the rows in
+    that order (_sorted_window_sums).  Filtered rows carry ``flat_idx ==
+    capacity`` and zero weights and sort to the end.
+
+    Weights, counts and precision are _segment_add_radix's: validity and
+    three exact bfloat16 parts a weight, float32 sums.  Past
+    ``_SORTED_ACC_BYTES`` of accumulator the weight columns go in groups,
+    a call a group over the same sorted rows."""
+    flat_idx, weights = _whole_blocks(flat_idx, weights, _SORTED_BLOCK, capacity)
+    flat_idx, *weights = jax.lax.sort((flat_idx, *weights), num_keys=1, is_stable=False)
+
+    def call(ws):
+        parts = lambda idx, w_refs: _exact_parts(idx, w_refs, capacity)
+        return _states_of_parts(_sorted_window_sums(flat_idx, ws, capacity, 1 + 3 * len(ws), parts))
+
+    acc_bytes = _sorted_sublanes(capacity) * _RADIX * 4  # one part's
+    per = max(1, min(_SORTED_COLS_MAX, (_SORTED_ACC_BYTES // acc_bytes - 1) // 3))  # weight columns a call
     states = call(weights[:per])
     for at in range(per, len(weights), per):
         states.extend(call(weights[at:at + per])[1:])
     return jnp.stack(states).astype(config.float_dtype())
+
+
+def _hll_sorted_registers(packed, capacity: int):
+    """One segment's dense HLL registers [capacity, HLL_M] uint8 from its
+    packed int32 keys, ``((group * HLL_M + register) << 6) | rank`` a
+    row and ``_PAIR_SENTINEL`` where the row is filtered out: the 'sort'
+    lowering (hll_lowering), bit for bit the scatter-max's.
+
+    The keys are sorted where they were built.  A row is the last of its
+    (group, register) run where ``key >> 6`` differs from the next row's;
+    there its weight is the rank ``key & 63``, which is the run's largest
+    (the rank rides the key's low bits), and everywhere else 0.  So
+    exactly one row a live cell carries a weight, and a register is the
+    SUM of its cell's weights: the windowed contraction over the rows in
+    cell order (_sorted_window_sums), one part a row, since a rank is at
+    most 63 and exact in bfloat16.  The sentinel's cell lies past the last
+    one and counts nowhere.
+
+    The accumulator is 4 B a cell in VMEM.  Cells past ``_SORTED_ACC_BYTES``
+    of it (over 12,256 groups at 256 registers) go in ranges, a call a
+    range over the same sorted rows: the rows under a range are clamped
+    to its first cell with a weight of 0 and those over it to its
+    sentinel, which keeps them in order."""
+    cells = capacity * config.HLL_M
+    keys = jax.lax.sort(_whole_blocks(packed, [], _SORTED_BLOCK, _PAIR_SENTINEL)[0])
+    cell = keys >> 6
+    ends = jnp.concatenate([cell[1:] != cell[:-1], jnp.ones(1, bool)])
+    # cells a call: the sublanes _SORTED_ACC_BYTES holds, less the last window's, in whole tiles
+    per = ((_SORTED_ACC_BYTES // (_RADIX * 4)) // 16 * 16 - _SORTED_WINDOW) * _RADIX
+    rank_as_it_stands = lambda idx, w_refs: [w_refs[0][...]]
+    regs = []
+    for at in range(0, cells, per):
+        size = min(per, cells - at)
+        inside = ends & (cell >= at) & (cell < at + size)
+        rank = jnp.where(inside, keys & 63, 0).astype(jnp.float32)
+        regs.append(_sorted_window_sums(jnp.clip(cell - at, 0, size), [rank], size, 1, rank_as_it_stands)[0])
+    return jnp.concatenate(regs).astype(jnp.uint8).reshape(capacity, config.HLL_M)
 
 
 def _segment_add_matmul_multi(flat_idx, W, capacity: int):
@@ -1074,8 +1155,8 @@ def _group_state(agg: StaticAgg, i: int, seg, q, mask, keys, kvalid, capacity, h
         if hll == "sort":
             # mid/large group spaces: pack (group, bucket, rho) into ONE
             # int32 per entry (4 B/row — the leanest HBM footprint of
-            # the three paths) and let the cross-segment reduce sort the
-            # packed keys and run-max-extract registers (bit-identical
+            # the three paths), sort them here and add each (group,
+            # bucket) run's last rho on the matrix unit (bit-identical
             # to scatter-max)
             packed = jnp.where(
                 pair_v,
@@ -1083,7 +1164,7 @@ def _group_state(agg: StaticAgg, i: int, seg, q, mask, keys, kvalid, capacity, h
                 | pair_r.astype(jnp.int32),
                 _PAIR_SENTINEL,
             )
-            return packed
+            return _hll_sorted_registers(packed, capacity)
         # huge capacities (> _HLL_SORT_CAP: packed key overflows int32):
         # one FLAT scatter index instead of (k, b) pairs — a single fused
         # index plus uint8 values keeps per-row temporaries at 5 B/row
@@ -1347,9 +1428,8 @@ def output_reducers(plan: StaticPlan) -> Dict[str, str]:
     red: Dict[str, str] = {"num_docs": "sum"}
     if plan.group_by is not None:
         red["gb_presence"] = "max"
-        hll = hll_lowering(plan)
         for i, agg in enumerate(plan.aggs):
-            red[f"gb_{i}"] = _state_reduce(agg, plan.group_by.capacity, hll)
+            red[f"gb_{i}"] = _state_reduce(agg)
     else:
         for i, agg in enumerate(plan.aggs):
             red[f"agg_{i}"] = _state_reduce(agg)
@@ -1359,7 +1439,7 @@ def output_reducers(plan: StaticPlan) -> Dict[str, str]:
     return red
 
 
-def _state_reduce(agg: StaticAgg, capacity: int = 0, hll: Optional[str] = None) -> str:
+def _state_reduce(agg: StaticAgg) -> str:
     base = agg.base
     if base in ("count", "sum"):
         return "sum"
@@ -1376,13 +1456,7 @@ def _state_reduce(agg: StaticAgg, capacity: int = 0, hll: Optional[str] = None) 
     if agg.kind == "hist":
         return "distinct_pairs" if agg.sort_pairs else "sum"
     if agg.kind == "hll":
-        if agg.sort_pairs:
-            return "distinct_pairs"
-        if hll == "sort":
-            # packed-key states: the reduce itself sorts and extracts
-            # registers — the capacity rides in the op tag
-            return f"hll_sort:{capacity}"
-        return "max"
+        return "distinct_pairs" if agg.sort_pairs else "max"  # dense registers, whatever built them
     raise AssertionError(agg)
 
 
@@ -1484,31 +1558,7 @@ def merge_pair_buffers(slots, gids, counts):
     return (s2[:k], g2[:k], e2[:k], n_unique, total_valid)
 
 
-def _reduce_hll_sort(value, capacity: int):
-    """Dense grouped-HLL registers from packed (group, bucket, rho)
-    int32 keys across all segments — ONE single-operand device sort
-    plus a searchsorted run-max extraction (bit-identical to the
-    scatter-max lowering: rho rides the low 6 bits, so the largest
-    packed key within a (group, bucket) cell prefix carries the cell's
-    max rho).  Replaces the serialized scatter for the
-    high-cardinality HLL group-by.  On the chip (PR 41, 100.7M keys, 2.31M
-    cells): the sort 327 ms, the searchsorted 1,217 (the comment at
-    _HLL_SORT_CAP has the account).
-    """
-    s = jax.lax.sort(value.reshape(-1))
-    ncells = capacity * config.HLL_M
-    # the last packed key below (cell+1)<<6 is the cell's max-rho entry
-    bounds = (jnp.arange(ncells, dtype=jnp.int32) + 1) << 6
-    pos = jnp.searchsorted(s, bounds) - 1
-    v = s[jnp.maximum(pos, 0)]
-    cell_ids = jnp.arange(ncells, dtype=jnp.int32)
-    regs = jnp.where((pos >= 0) & ((v >> 6) == cell_ids), v & 63, 0)
-    return regs.reshape(capacity, config.HLL_M).astype(jnp.uint8)
-
-
 def apply_reduce(op: str, value: Any):
-    if op.startswith("hll_sort:"):
-        return _reduce_hll_sort(value, int(op.split(":", 1)[1]))
     if op == "sum":
         return jnp.sum(value, axis=0)
     if op == "min":
@@ -1648,19 +1698,13 @@ def chunk_rows_limit() -> int:
 
 
 def plan_chunkable(plan: StaticPlan) -> bool:
-    """Chunk-combinable: every output reduces elementwise, or (hll_sort)
-    reduces to dense registers that merge elementwise across chunks.
-    The distinct_pairs sort-dedup buffers and per-segment selection
-    outputs need their full segment axis in one program."""
-    return all(
-        op in _ELEMENTWISE_REDUCERS or op.startswith("hll_sort:")
-        for op in output_reducers(plan).values()
-    )
+    """Chunk-combinable: every output reduces elementwise.  The
+    distinct_pairs sort-dedup buffers and per-segment selection outputs
+    need their full segment axis in one program."""
+    return all(op in _ELEMENTWISE_REDUCERS for op in output_reducers(plan).values())
 
 
 def combine_reduced(op: str, a, b):
-    if op.startswith("hll_sort:"):
-        return jnp.maximum(a, b)  # chunk-reduced register states
     if op == "sum":
         return a + b
     if op == "max":
@@ -1705,15 +1749,20 @@ def zone_blocks(plan: StaticPlan) -> str:
 
     'inplace':  every output combines elementwise over blocks of rows
                 (_ELEMENTWISE_REDUCERS: no selection part, no
-                distinct_pairs, no hll_sort) and a segment's outputs are
-                at most _INPLACE_STATE_CELLS elements: a loop over the
-                candidate ids slices the staged columns where they lie
-                and folds each block's outputs into the carried ones.
+                distinct_pairs), no HLL aggregate takes the 'sort'
+                lowering and a segment's outputs are at most
+                _INPLACE_STATE_CELLS elements: a loop over the candidate
+                ids slices the staged columns where they lie and folds
+                each block's outputs into the carried ones.
     'gathered': every other plan: its outputs need the view whole, so
                 the candidate blocks are copied out first
-                (_gather_blocks)."""
+                (_gather_blocks).  A sorted HLL's registers would fold,
+                but a sort and a Pallas call a block of rows is not what
+                the loop was measured for, and no cell asks (ROADMAP
+                R6a)."""
     elementwise = all(op in _ELEMENTWISE_REDUCERS for op in output_reducers(plan).values())
-    return "inplace" if elementwise and _state_cells(plan) <= _INPLACE_STATE_CELLS else "gathered"
+    inplace = elementwise and hll_lowering(plan) != "sort" and _state_cells(plan) <= _INPLACE_STATE_CELLS
+    return "inplace" if inplace else "gathered"
 
 
 def _reducer_identity(op: str, like):

@@ -228,7 +228,7 @@ def collective_names(plan) -> List[str]:
             ops.add("psum")
         elif op == "min":
             ops.add("pmin")
-        elif op == "max" or op.startswith("hll_sort:"):
+        elif op == "max":
             ops.add("pmax")
         elif op == "minmax_pair":
             ops.update(("pmin", "pmax"))

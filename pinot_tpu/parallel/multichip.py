@@ -44,10 +44,6 @@ def _collective(op: str, value: Any, axis):
     # ``axis`` may be one name or a tuple of mesh axis names: on a 2-D
     # (hosts, chips) mesh the same psum reduces over ICI within a host
     # and DCN across hosts (multihost.py layering)
-    if op.startswith("hll_sort:"):
-        # each chip's packed-sort reduce already produced dense
-        # registers; the cross-chip merge is an elementwise max
-        return jax.lax.pmax(value, axis)
     if op == "sum":
         return jax.lax.psum(value, axis)
     if op == "min":

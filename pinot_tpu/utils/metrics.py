@@ -663,17 +663,18 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "annotation, no span",
     # which lowering a launch's HLL aggregates took, grouped or not, one
     # mark a launch that carries one (engine/kernel.py hll_lowering,
-    # which the kernel builder and the reduce spec ask too; the launch's
+    # which the kernel builder and zone_blocks ask too; the launch's
     # ``hll=`` tag)
     "hll.lowering.matmul": "launches whose distinctcounthll registers come "
     "from the (group, register, rank) occupancy contraction on the matrix "
     "unit (ungrouped on the chip; up to 16 groups)",
     "hll.lowering.sort": "launches whose grouped distinctcounthll packs "
-    "(group, register, rank) into one int32 key a row, sorted in the "
-    "reduce (up to 65,536 groups)",
+    "(group, register, rank) into one int32 key a row, sorts them a "
+    "segment and sums each run's last rank on the matrix unit (17 to "
+    "65,536 groups, on the chip)",
     "hll.lowering.scatter": "launches whose distinctcounthll registers "
     "come from the serialised scatter-max (more groups than the packed "
-    "key holds; the CPU backend's ungrouped form)",
+    "key holds; every form on the CPU backend)",
     "hll.lowering.pairs": "launches whose grouped distinctcounthll emits "
     "(slot, register x 64 + rank) pairs for the sort-dedup reduce (a "
     "group space whose dense registers pass the value state's budget)",

@@ -749,9 +749,10 @@ def test_having_engine_sentinel():
 def test_grouped_hll_three_lowerings_bit_identical(monkeypatch):
     """The grouped-HLL matmul / packed-sort / scatter lowerings must be
     interchangeable: same registers, same estimates, byte-identical
-    responses (the sort path's searchsorted run-max extraction is the
-    round-5 replacement for scatter-max; the matmul occupancy is the
-    small-capacity fast path)."""
+    responses (the sort path's sum of each sorted run's last rank is
+    the replacement for scatter-max, under the tests' switch as the
+    matmul is: the CPU's own answer is the scatter; the matmul
+    occupancy is the small-capacity fast path)."""
     from pinot_tpu.engine import kernel as kernel_mod
     from pinot_tpu.engine.device import clear_staging_cache
 
@@ -772,10 +773,13 @@ def test_grouped_hll_three_lowerings_bit_identical(monkeypatch):
         # group space is 40*39=1560 -> K ~= 25.6M) so the matmul
         # variant genuinely takes the matmul lowering for each
         "matmul": ("1", 1 << 25, 1 << 16),
-        "sort": ("0", 1 << 18, 1 << 16),
+        "sort": ("1", 1 << 18, 1 << 16),
         "scatter": ("0", 1 << 18, 0),
     }
     results = {}
+    monkeypatch.setenv("PINOT_TPU_HLL_PRESENCE", "0")  # the register streams: 40 values would ride a presence holder
+    summed, run_ends = [], kernel_mod._hll_sorted_registers
+    monkeypatch.setattr(kernel_mod, "_hll_sorted_registers", lambda *a: summed.append(name) or run_ends(*a))
     try:
         for name, (mm, hll_cap, sort_cap) in variants.items():
             monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", mm)
@@ -795,4 +799,5 @@ def test_grouped_hll_three_lowerings_bit_identical(monkeypatch):
         kernel_mod.make_table_kernel.cache_clear()
         kernel_mod.make_packed_table_kernel.cache_clear()
         clear_staging_cache()
+    assert set(summed) == {"sort"}  # the variant that is named for it, and no other, took the sorted form
     assert results["matmul"] == results["sort"] == results["scatter"]

@@ -1,0 +1,242 @@
+"""The 'sort' lowering of a grouped ``distinctcounthll`` (PR 42): a
+segment's packed keys are sorted where they are built and a register is
+the SUM of its (group, register) run's last rank, added on the matrix
+unit by the windowed contraction that ``_segment_add_sorted`` shares
+(``kernel._hll_sorted_registers``, ``kernel._sorted_window_sums``).  Held
+here on the CPU, where the Pallas call runs in the interpreter (only the
+tests' switch reaches it): the registers are the scatter-max's bit for
+bit, a segment alone and two folded by ``max``; the shared inner function
+still gives ``_segment_add_sorted`` the radix contraction's states.
+``tests/test_tpu_compile.py`` compiles the same calls for a described
+v5e at the cell's size; the cell's answers against the reference are in
+``tests/test_hits_users.py``."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pinot_tpu.engine import config
+from pinot_tpu.engine import kernel as kernel_mod
+from pinot_tpu.engine.executor import QueryExecutor
+from pinot_tpu.pql import optimize_request, parse_pql
+from pinot_tpu.segment.builder import build_segment
+from pinot_tpu.tools.datagen import make_test_schema, random_rows
+
+M = config.HLL_M
+SENTINEL = kernel_mod._PAIR_SENTINEL
+BLOCK = kernel_mod._SORTED_BLOCK
+
+
+def scatter_max(packed: np.ndarray, capacity: int) -> np.ndarray:
+    """What the 'scatter' lowering builds of the same rows, in numpy."""
+    regs = np.zeros(capacity * M, np.uint8)
+    live = packed != SENTINEL
+    np.maximum.at(regs, packed[live] >> 6, (packed[live] & 63).astype(np.uint8))
+    return regs.reshape(capacity, M)
+
+
+def keys(rng, capacity: int, rows: int, filtered: float = 0.3) -> np.ndarray:
+    group, register, rank = rng.integers(0, capacity, rows), rng.integers(0, M, rows), rng.integers(0, 64, rows)
+    packed = (((group * M + register) << 6) | rank).astype(np.int32)
+    packed[rng.random(rows) < filtered] = SENTINEL
+    return packed
+
+
+def every_row_filtered(rng):
+    return 40, np.full(3 * BLOCK, SENTINEL, np.int32)
+
+
+def every_row_in_one_cell(rng):
+    return 40, (((23 * M + 200) << 6) | rng.integers(1, 50, 2 * BLOCK + 7)).astype(np.int32)
+
+
+def the_largest_rank_repeated_in_its_run(rng):
+    packed = keys(rng, 17, 5_000, filtered=0.0) & ~np.int32(63)
+    return 17, packed | np.where(rng.random(5_000) < 0.5, 37, rng.integers(0, 37, 5_000)).astype(np.int32)
+
+
+def no_whole_number_of_blocks(rng):
+    return 300, keys(rng, 300, 2 * BLOCK + 4_097)
+
+
+def the_last_cell_and_the_first(rng):
+    packed = np.array([63, (9_040 * M - 1) << 6 | 1, SENTINEL, 5, (9_040 * M - 1) << 6 | 62], np.int32)
+    return 9_040, packed
+
+
+CASES = {
+    "capacity_17": lambda rng: (17, keys(rng, 17, 20_000)),
+    "capacity_9040_the_cells": lambda rng: (9_040, keys(rng, 9_040, 30_000)),
+    "every_row_filtered": every_row_filtered,
+    "every_row_in_one_cell": every_row_in_one_cell,
+    "the_largest_rank_repeated_in_its_run": the_largest_rank_repeated_in_its_run,
+    "no_whole_number_of_blocks": no_whole_number_of_blocks,
+    "the_last_cell_and_the_first": the_last_cell_and_the_first,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_a_register_is_the_sum_of_its_runs_last_rank(case):
+    capacity, packed = CASES[case](np.random.default_rng(42))
+    got = np.asarray(jax.jit(lambda p: kernel_mod._hll_sorted_registers(p, capacity))(packed))
+    assert got.dtype == np.uint8 and got.shape == (capacity, M)
+    assert np.array_equal(got, scatter_max(packed, capacity))
+
+
+# sublanes (x 128 cells) of accumulator a call may take: 48 after the last window's 64, 24 groups of 256 registers
+RANGE_GROUPS = 24
+
+
+@pytest.mark.parametrize("capacity,rows", [
+    (RANGE_GROUPS, "spread"),  # the most one call holds
+    (RANGE_GROUPS + 1, "spread"),  # one past it: two ranges, the second of one group
+    (5 * RANGE_GROUPS + 3, "spread"),  # six ranges
+    (3 * RANGE_GROUPS, "last_range_only"),  # the ranges before it see rows over them alone
+    (3 * RANGE_GROUPS, "filtered"),
+])
+def test_cells_past_one_calls_accumulator_go_in_ranges(monkeypatch, capacity, rows):
+    monkeypatch.setattr(kernel_mod, "_SORTED_ACC_BYTES", (kernel_mod._SORTED_WINDOW + 48) * 128 * 4)
+    rng = np.random.default_rng(capacity)
+    packed = keys(rng, capacity, BLOCK + 1_000)
+    if rows == "last_range_only":
+        packed = np.where(packed >> 6 >= 2 * RANGE_GROUPS * M, packed, SENTINEL).astype(np.int32)
+        assert (packed != SENTINEL).sum() > 1_000
+    elif rows == "filtered":
+        packed[:] = SENTINEL
+    calls, inner = [], kernel_mod._sorted_window_sums
+    monkeypatch.setattr(kernel_mod, "_sorted_window_sums", lambda idx, cols, cells, *a: calls.append(cells) or inner(idx, cols, cells, *a))
+    got = np.asarray(jax.jit(lambda p: kernel_mod._hll_sorted_registers(p, capacity))(packed))
+    assert sum(calls) == capacity * M and len(calls) == -(-capacity // RANGE_GROUPS) and max(calls) == min(capacity, RANGE_GROUPS) * M
+    assert np.array_equal(got, scatter_max(packed, capacity))
+
+
+# ---------------------------------------------------------------------------
+# through the kernel builder: a segment's state and the fold
+# ---------------------------------------------------------------------------
+QUERIES = {
+    "two_segments_folded_by_max": "SELECT distinctcounthll(dimLong) FROM testTable GROUP BY dimStr TOP 10",
+    "a_multi_value_group_key": "SELECT distinctcounthll(dimLong), count(*) FROM testTable GROUP BY dimStrMV TOP 10",
+    "a_multi_value_argument_and_two_keys": "SELECT distinctcounthll(dimIntMV) FROM testTable GROUP BY dimStr, dimInt TOP 10",
+    "under_a_filter": "SELECT fasthll(dimLong) FROM testTable WHERE metInt > 4000 GROUP BY dimInt TOP 10",
+}
+
+
+@pytest.fixture(scope="module")
+def launches():
+    """(plan, segment arrays, query inputs) of each of QUERIES as the
+    executor hands them to the table kernel, over two segments."""
+    schema = make_test_schema(with_mv=True)
+    rows = random_rows(schema, 2_400, seed=4242, cardinality=40)
+    segs = [build_segment(schema, rows[:1_100], "testTable", "re0"), build_segment(schema, rows[1_100:], "testTable", "re1")]
+    got = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PINOT_TPU_HLL_PRESENCE", "0")  # the register streams: 40 values a column would ride a presence holder
+        run_kernel = QueryExecutor._run_kernel
+
+        def spy(self, kernel, args, plan, *rest, **kw):
+            got[name] = (plan, args[0], args[1])
+            return run_kernel(self, kernel, args, plan, *rest, **kw)
+
+        mp.setattr(QueryExecutor, "_run_kernel", spy)
+        for name, pql in QUERIES.items():
+            QueryExecutor().execute(segs, optimize_request(parse_pql(pql)))
+    return got
+
+
+@pytest.mark.parametrize("query", sorted(QUERIES))
+def test_the_sorted_form_gives_the_scatters_registers_a_segment_and_folded(monkeypatch, launches, query):
+    plan, segs, q = launches[query]
+    at = next(i for i, a in enumerate(plan.aggs) if a.kind == "hll" and not a.sort_pairs)
+    assert kernel_mod.hll_lowering(plan) == "scatter"  # the CPU's own answer, without the switch
+    states = {}
+    for answer in ("scatter", "sort"):
+        monkeypatch.setattr(kernel_mod, "hll_lowering", lambda plan, answer=answer: answer)
+        assert kernel_mod.output_reducers(plan)[f"gb_{at}"] == "max"
+        small = kernel_mod._state_cells(plan) <= kernel_mod._INPLACE_STATE_CELLS  # two of the four: 40 groups
+        assert kernel_mod.zone_blocks(plan) == ("inplace" if small and answer == "scatter" else "gathered")
+        assert kernel_mod.plan_chunkable(plan)
+        states[answer] = np.asarray(jax.jit(jax.vmap(kernel_mod.make_single_segment_kernel(plan)))(segs, q)[f"gb_{at}"])
+    assert states["sort"].dtype == np.uint8 and states["sort"].shape == (2, plan.group_by.capacity, M)
+    assert states["sort"].any(axis=(1, 2)).all()  # both segments have rows
+    assert np.array_equal(states["sort"], states["scatter"])
+    folded = kernel_mod.apply_reduce("max", jnp.asarray(states["sort"]))
+    assert np.array_equal(np.asarray(folded), states["scatter"].max(axis=0))
+    assert np.array_equal(np.asarray(kernel_mod.combine_reduced("max", states["sort"][0], states["sort"][1])), np.asarray(folded))
+
+
+def test_the_zone_tier_hands_a_sorted_hll_its_gathered_view(monkeypatch):
+    """A filter that prunes to a few blocks rides the zone tier: under
+    the scatter the blocks are read in place, under the sorted form they
+    are copied out first (``zone_blocks`` asks ``hll_lowering``), and the
+    replies are the same text."""
+    import json
+
+    from pinot_tpu.engine.reduce import reduce_to_response
+    from pinot_tpu.tools.datagen import synthetic_lineitem_keys_segment
+
+    for name, value in (("PINOT_TPU_ZONE_BLOCK", "512"), ("PINOT_TPU_INVINDEX", "0"), ("PINOT_TPU_HLL_PRESENCE", "0")):
+        monkeypatch.setenv(name, value)
+    segs = [synthetic_lineitem_keys_segment(32_768, seed=70 + i, name=f"zone{i}") for i in range(2)]
+    request = optimize_request(parse_pql(
+        "SELECT distinctcounthll(l_suppkey), count(*) FROM lineitem WHERE l_shipdate >= '1996-01-01' AND "
+        "l_shipdate < '1996-04-01' GROUP BY l_shipmode, l_returnflag TOP 30"))
+    launched, run_kernel = [], QueryExecutor._run_kernel
+
+    def spy(self, kernel, args, plan, staged, digest, block_ids, *rest, **kw):
+        launched.append((kernel_mod.hll_lowering(plan), kernel_mod.zone_blocks(plan), block_ids is not None))
+        return run_kernel(self, kernel, args, plan, staged, digest, block_ids, *rest, **kw)
+
+    monkeypatch.setattr(QueryExecutor, "_run_kernel", spy)
+    cached = (kernel_mod.make_table_kernel, kernel_mod.make_packed_table_kernel, kernel_mod.make_block_table_kernel,
+              kernel_mod.make_packed_block_table_kernel)
+    replies = []
+    try:
+        for forced in ("0", "1"):
+            monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", forced)
+            for c in cached:
+                c.cache_clear()
+            reply = reduce_to_response(request, [QueryExecutor().execute(segs, request)])
+            assert not reply.exceptions, reply.exceptions
+            replies.append(json.dumps(reply.to_json()["aggregationResults"], sort_keys=True))
+    finally:
+        for c in cached:
+            c.cache_clear()
+    assert launched == [("scatter", "inplace", True), ("sort", "gathered", True)]
+    assert replies[0] == replies[1] and '"value": "0"' not in replies[0]
+
+
+def test_no_reducer_names_the_lowering():
+    """The op tag ``hll_sort:<capacity>`` and the reduce behind it are gone:
+    the state is dense registers, and what folds them is ``max``."""
+    import inspect
+
+    from pinot_tpu.engine import mesh
+    from pinot_tpu.parallel import multichip
+
+    assert not hasattr(kernel_mod, "_reduce_hll_sort")
+    for module in (kernel_mod, mesh, multichip):
+        assert "hll_sort:" not in inspect.getsource(module) and "searchsorted(" not in inspect.getsource(module), module.__name__
+    with pytest.raises(ValueError):
+        kernel_mod.apply_reduce("hll_sort:40", jnp.zeros((2, 40 * M), jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# the inner function the two share
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("capacity,rows,sums,valid", [
+    (2_200, 16 * 1_024, 1, 0.67),  # TPC-H Q15's shape, a hundredth of its keys: a product under one sum, two rows in three valid
+    (2_200, BLOCK + 5, 4, 1.0),  # weight columns in two groups, a ragged row count
+    (70_000, 3 * BLOCK, 1, 0.01),  # keys that are blocks apart
+])
+def test_the_shared_windows_give_the_sorted_sums_the_radix_contractions_states(capacity, rows, sums, valid):
+    rng = np.random.default_rng(rows)
+    idx = np.where(rng.random(rows) < valid, rng.integers(0, capacity, rows), capacity).astype(np.int32)
+    weights = [np.where(idx < capacity, rng.uniform(900.0, 105_000.0, rows), 0.0).astype(np.float32) for _ in range(sums)]
+    with jax.enable_x64(False):  # the chip's precision: the states are float32 on both sides
+        got = np.asarray(jax.jit(lambda i, *w: kernel_mod._segment_add_sorted(i, list(w), capacity))(idx, *weights))
+        want = np.asarray(jax.jit(lambda i, *w: kernel_mod._segment_add_radix(i, list(w), capacity))(idx, *weights))
+    assert got.shape == (1 + sums, capacity) and got.dtype == np.float32
+    assert np.array_equal(got[0], np.bincount(idx, minlength=capacity + 1)[:capacity])  # the occupancy, exact
+    exact = np.stack([np.bincount(idx, weights=w.astype(np.float64), minlength=capacity + 1)[:capacity] for w in weights])
+    assert np.allclose(got[1:], exact, rtol=2e-6, atol=0.0) and np.allclose(got[1:], want[1:], rtol=2e-6, atol=0.0)
+    assert np.array_equal(got[0], want[0])

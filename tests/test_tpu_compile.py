@@ -67,6 +67,35 @@ def test_radix_contraction_compiles_for_v5e(one_chip, monkeypatch, shape):
     assert compiled.memory_analysis().temp_size_in_bytes <= copies * (m + 1) * S * n * 4 + (64 << 20)
 
 
+# (groups, segments, packed keys a segment): ClickBench's regions as hits_distinct_users_closed holds them
+# (one call: 9.3 MB of accumulator), the lowering's cap (67 MB of cells: four ranges), a ragged row count
+HLL_SHAPES = {
+    "hits_regions_9040": (9_040, 12, 1 << 23),
+    "cap_65536_in_ranges": (1 << 16, 2, 1 << 23),
+    "k17_ragged_rows": (17, 2, (1 << 20) + 5),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(HLL_SHAPES))
+def test_hll_run_ends_compile_for_v5e(one_chip, monkeypatch, shape):
+    """The 'sort' lowering of a grouped distinctcounthll: a sort a
+    segment and the windowed contraction over each (group, register)
+    run's last rank, folded by maximum."""
+    from pinot_tpu.engine import config, kernel as kernel_mod
+
+    K, S, n = HLL_SHAPES[shape]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    table = lambda packed: jnp.max(jax.vmap(lambda p: kernel_mod._hll_sorted_registers(p, K))(packed), axis=0)
+    with jax.enable_x64(False):
+        compiled = jax.jit(table).lower(jax.ShapeDtypeStruct((S, n), jnp.int32, sharding=one_chip)).compile()
+    calls = -(-K * config.HLL_M * 4 // kernel_mod._SORTED_ACC_BYTES)
+    assert (calls > 1) == shape.endswith("in_ranges")
+    # sorted keys, cells and ranks (a range its own) and every segment's accumulator before the fold
+    rows = S * -(-n // kernel_mod._SORTED_BLOCK) * kernel_mod._SORTED_BLOCK * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= (2 + 2 * calls) * rows + 2 * S * K * config.HLL_M * 4 + (64 << 20)
+    assert compiled.as_text().count("tpu_custom_call") >= calls and "while" in compiled.as_text()
+
+
 # the open cell's two group-bys (benchmark/traffic/suite_open.json: k6, q6) and TPC-H Q1 as the
 # specification writes it (benchmark/traffic/tpch_q1q6_closed.json: 36 cells, two products a row)
 SUITE_GROUPBYS = {
