@@ -18,7 +18,11 @@ DOC_PAD_MULTIPLE = 1024
 MIN_CARD_PAD = 8
 
 # Group-by dense-holder cap (reference caps ARRAY_BASED key space at 1M,
-# DefaultGroupKeyGenerator.java): beyond this the host hash path runs.
+# DefaultGroupKeyGenerator.java): beyond this no dense holder is built.
+# count, sum and avg under a TOP n then take the runs lowering on the
+# device (kernel.groupby_lowering 'runs': the rows sorted by key, no
+# holder at all); what it does not take runs the host hash path, by name
+# (plan.group_runs_host_reason).
 MAX_GROUP_CAPACITY = 1 << 20
 
 # distinctcount / percentile dense state cap (global dictionary size).

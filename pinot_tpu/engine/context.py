@@ -49,11 +49,21 @@ class TableContext:
     def __init__(self, segments: Sequence[ImmutableSegment]):
         self.segments = list(segments)
         self._columns: Dict[str, GlobalColumn] = {}
+        # the serving role's ``phase.globalDictBuild`` timer (the executor
+        # hands it to get_table_context), None for a context nobody times
+        self.build_timer = None
 
     def column(self, name: str) -> GlobalColumn:
         gc = self._columns.get(name)
         if gc is None:
-            gc = self._build(name)
+            # a column's union and remaps are built once a segment set,
+            # by whichever query first asks for its global ids: timer and
+            # ``pinot:globalDictBuild`` annotation, no span (it falls in
+            # the executor's ``staging`` stretch, which stays the leaf)
+            from pinot_tpu.utils.trace import boundary
+
+            with boundary("globalDictBuild", None, self.build_timer):
+                gc = self._build(name)
             self._columns[name] = gc
         return gc
 
@@ -80,7 +90,7 @@ class TableContext:
 _context_cache: Dict[Tuple[str, ...], TableContext] = {}
 
 
-def get_table_context(segments: Sequence[ImmutableSegment]) -> TableContext:
+def get_table_context(segments: Sequence[ImmutableSegment], build_timer=None) -> TableContext:
     # (name, crc, instance token): the token makes a re-loaded segment
     # (quarantine re-fetch) miss — a context built from a corrupt load's
     # dictionaries must never serve the clean copy (see engine/device.py)
@@ -91,4 +101,6 @@ def get_table_context(segments: Sequence[ImmutableSegment]) -> TableContext:
         if len(_context_cache) > 64:
             _context_cache.clear()
         _context_cache[key] = ctx
+    if build_timer is not None and ctx.build_timer is None:
+        ctx.build_timer = build_timer
     return ctx

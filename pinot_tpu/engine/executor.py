@@ -756,7 +756,7 @@ class QueryExecutor:
         total_docs, needed, sel_columns, pad_to = prep.once("scope", scope)
         # looked up on every query (its own cache, by the segments'
         # tokens): an entry that held it would hold the segments
-        ctx = get_table_context(live)
+        ctx = get_table_context(live, build_timer=self.metrics.timer("phase.globalDictBuild"))
 
         # audit-plane quarantine (utils/audit.py): a tier caught
         # serving wrong answers for this shape is skipped — looked up
@@ -824,11 +824,16 @@ class QueryExecutor:
 
         # queries the planner can only send to the host (group space or
         # guaranteed pair overflow) skip device staging entirely
-        from pinot_tpu.engine.plan import plan_forced_host
+        from pinot_tpu.engine.plan import group_by_host_reason, plan_forced_host
 
-        if prep.once("forcedHost", lambda: plan_forced_host(request, ctx)):
+        if prep.once("forcedHost", lambda: plan_forced_host(request, ctx, mesh=mesh is not None)):
             from pinot_tpu.engine.host_fallback import execute_host
 
+            # a group-by the device declines says why, by name (the
+            # reason EXPLAIN gives the segment's host record)
+            reason = group_by_host_reason(request, ctx, mesh=mesh is not None) if request.is_group_by else None
+            if reason is not None:
+                self.metrics.meter(f"groupby.forcedHost.{reason.split(':')[0]}").mark()
             res = execute_host(live, ctx, request, total_docs, sel_columns)
             ph.relabel("hostPath")
             return self._finish_tier(res, request, "host")
@@ -1106,7 +1111,11 @@ class QueryExecutor:
             args = (seg_arrays, upload_inputs(), ids_dev)
         else:
             kernel = self._kernel(plan, staged, mesh)
-            if lane is not None and mesh is None and sharding is None:
+            from pinot_tpu.engine.kernel import groupby_lowering
+
+            if lane is not None and mesh is None and sharding is None and groupby_lowering(plan) != "runs":
+                # (a 'runs' group-by sorts the table's rows in its merge:
+                # a member more is a sort more, nothing shared)
                 # cross-query micro-batching eligibility: the plain
                 # packed single-device kernel only (no mesh collectives,
                 # no per-query block-id gathers, no chunked dispatch
@@ -1835,6 +1844,14 @@ class QueryExecutor:
         )
 
         if plan.group_by is not None:
+            # what of the group state came back from the device, whatever
+            # lowering made it: a dense holder's K cells an aggregate, or
+            # the runs lowering's candidates
+            self.metrics.meter("groupby.stateFetchBytes").mark(sum(
+                x.nbytes
+                for k, v in outs.items() if k.startswith("gb_")
+                for x in (v if isinstance(v, tuple) else (v,))
+            ))
             res.groups, live_groups, digest = self._finalize_groups(request, plan, ctx, outs)
             # groups with a row in the fetched state, and those left after
             # the per-server trim (an empty answer marks neither); the
@@ -1906,6 +1923,8 @@ class QueryExecutor:
         ``groupStateHllSum``, the sum of every live group's estimate (an
         integer, exact in float64), and ``groupStateHllSumSq``."""
         gb = plan.group_by
+        if "gb_runs_keys" in outs:
+            return self._kept_run_keys(plan, outs)
         keys = np.nonzero(np.asarray(outs["gb_presence"]))[0]
         live_groups = int(keys.size)
         digest = {"groupStateSumSq": 0.0}
@@ -1952,6 +1971,25 @@ class QueryExecutor:
             keys = keys[keep]
         return live_groups, digest, keys
 
+    def _kept_run_keys(self, plan: StaticPlan, outs) -> Tuple[int, Dict[str, float], np.ndarray]:
+        """_kept_group_keys of a 'runs' group-by (kernel.groupby_lowering),
+        whose program counted the live groups, took the digest and
+        trimmed: the candidates an aggregate are put together here, each
+        key once and ascending, and the aggregates' states set down as a
+        dense lowering's would stand at those keys, a place a key
+        (``gb_<i>`` by place, which _finalize_groups reads by place)."""
+        from pinot_tpu.engine.kernel import _contraction_slots
+
+        listed = np.asarray(outs["gb_runs_keys"])
+        filled = np.nonzero(listed >= 0)[0]
+        keys, first = np.unique(listed[filled], return_index=True)
+        state = [np.asarray(row)[filled[first]] for row in outs["gb_runs_state"]]
+        for i, slots in _contraction_slots(plan)[0].items():
+            rows = [state[j] for j in slots]
+            outs[f"gb_{i}"] = rows[0] if len(rows) == 1 else tuple(rows)
+        digest = {"groupStateSumSq": float(np.sum(outs["gb_runs_sumsq"], dtype=np.float64))}
+        return int(outs["gb_runs_live"]), digest, keys.astype(np.int64)
+
     def _finalize_groups(
         self, request: BrokerRequest, plan: StaticPlan, ctx: TableContext, outs
     ) -> Tuple[Dict[Tuple[str, ...], List[AggPartial]], int, Dict[str, float]]:
@@ -1983,9 +2021,10 @@ class QueryExecutor:
                 )
             )
 
+        by_place = "gb_runs_keys" in outs  # the states hold the kept keys alone (_kept_run_keys)
         groups: Dict[Tuple[str, ...], List[AggPartial]] = {}
         for row, ktup in enumerate(key_tuples):
-            k = int(keys[row])
+            k = row if by_place else int(keys[row])
             partials: List[AggPartial] = []
             for i, agg in enumerate(plan.aggs):
                 partials.append(self._group_partial(agg, outs[f"gb_{i}"], k, ctx))

@@ -36,6 +36,7 @@ from pinot_tpu.engine.invindex_path import index_path_decision
 from pinot_tpu.engine.plan import (
     build_query_inputs,
     build_static_plan,
+    group_by_host_reason,
     plan_forced_host,
 )
 from pinot_tpu.engine.plandigest import plan_shape_digest, plan_shape_summary
@@ -343,14 +344,20 @@ def build_explain_node(
                     "collective": None,
                 },
             }
-        elif plan_forced_host(request, ctx):
+        elif plan_forced_host(request, ctx, mesh=exec_mesh is not None):
             est_bytes = _estimate_scan_bytes(normal, sorted(needed), 1.0)
+            # a group-by the device declines is named: the key space, or
+            # what the runs lowering above MAX_GROUP_CAPACITY keys does
+            # not take (plan.group_runs_host_reason)
+            why = group_by_host_reason(request, ctx, mesh=exec_mesh is not None) if request.is_group_by else None
             for seg in normal:
                 record(
                     seg,
                     "host",
-                    "planner forces host before staging (group capacity "
-                    "or guaranteed sort-pair overflow)",
+                    "planner forces host before staging ("
+                    + (f"group-by: {why}" if why is not None else "guaranteed sort-pair overflow")
+                    + ")",
+                    **({"groupByHostReason": why} if why is not None else {}),
                 )
         else:
             raw_cols, gfwd_cols, hll_cols = executor._role_columns(

@@ -1,5 +1,6 @@
 """Host fallback path for queries whose dense device state would not fit
-(group-by key spaces beyond ``MAX_GROUP_CAPACITY``, huge value-state
+(group-by key spaces beyond ``MAX_GROUP_CAPACITY`` where the runs lowering
+does not take the query: ``plan.group_runs_host_reason``; huge value-state
 aggregations, composite sort keys beyond the key dtype).
 
 The reference's analog is the hash-map group-by storage types

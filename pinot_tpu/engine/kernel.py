@@ -216,11 +216,22 @@ def groupby_lowering(plan: StaticPlan) -> Optional[str]:
                groupby_operands says).
     'scatter': the CPU backend, unless PINOT_TPU_GROUPBY_MATMUL=1 (the
                tests' switch) forces the chip's lowerings.
+    'runs':    more keys than a dense holder takes (over
+               config.MAX_GROUP_CAPACITY, on every backend): no state of
+               K cells at all.  The table's rows are sorted by group id
+               once, a run of equal ids is a group, its count is its
+               length and its sums a scan over it, and the program hands
+               back the per-server trim's candidates, the count of runs
+               and the digest of their values (_reduce_group_runs).  The
+               planner lets through only what it takes
+               (plan.group_runs_host_reason).
     None for a plan without a group-by.  min, max, minmaxrange,
     presence, hist and HLL aggregates keep _group_state on every
-    lowering."""
+    dense lowering."""
     if getattr(plan, "group_by", None) is None:
         return None
+    if plan.group_by.capacity > config.MAX_GROUP_CAPACITY:
+        return "runs"
     if not _use_matmul_groupby():
         return "scatter"
     return "onehot" if plan.group_by.capacity <= MATMUL_GROUP_CAP else "radix"
@@ -277,7 +288,8 @@ def groupby_cells(plan: StaticPlan) -> Optional[Tuple[int, int]]:
         for agg in plan.aggs
         if _sum_shaped(agg)
     )
-    return plan.group_by.capacity * m, unshared - m
+    dense = groupby_lowering(plan) != "runs"  # the runs lowering holds no cell
+    return plan.group_by.capacity * m * dense, unshared - m
 
 
 # The row loop answers a group-by of up to this many (group, column)
@@ -318,6 +330,8 @@ def groupby_operands(plan: StaticPlan) -> Optional[str]:
     lowering = groupby_lowering(plan)
     if lowering is None:
         return None
+    if lowering == "runs":
+        return "staged"  # the lowering sorts the table's rows itself, after the segments' operands are built
     if lowering == "radix" and plan.group_by.capacity > RADIX_GROUP_CAP:
         return "sorted"
     additive = (
@@ -1310,7 +1324,26 @@ def _make_loop_groupby_kernel(plan: StaticPlan) -> Callable:
     return kernel
 
 
+def _make_runs_groupby_kernel(plan: StaticPlan) -> Callable:
+    """The single-segment part of the 'runs' lowering (groupby_lowering):
+    the segment's filter, keys and weight columns, as the dense lowerings
+    build them (_contraction_operands: a filtered row carries the key
+    ``capacity`` and weights of zero), handed on row by row as
+    ``gb_rows``.  Nothing is summed here: the table's rows meet in
+    _reduce_group_runs."""
+
+    def kernel(seg: Dict[str, Any], q: Dict[str, Any]) -> Dict[str, Any]:
+        mask = _filter_mask(plan, seg, q)
+        keys, kvalid = _group_keys(plan, seg, q, mask)
+        flat_idx, cols = _contraction_operands(plan, seg, mask, keys, kvalid, zeroed=True)
+        return {"num_docs": jnp.sum(mask, dtype=config.row_count_dtype()), "gb_rows": (flat_idx, *cols[1:])}
+
+    return kernel
+
+
 def make_single_segment_kernel(plan: StaticPlan) -> Callable:
+    if groupby_lowering(plan) == "runs":
+        return _make_runs_groupby_kernel(plan)
     if groupby_operands(plan) == "loop":
         return _make_loop_groupby_kernel(plan)
     hll = hll_lowering(plan)
@@ -1426,6 +1459,9 @@ def output_reducers(plan: StaticPlan) -> Dict[str, str]:
     These same ops become `psum`/`pmax`-style collectives across chips.
     """
     red: Dict[str, str] = {"num_docs": "sum"}
+    if groupby_lowering(plan) == "runs":
+        red["gb_rows"] = "runs"  # no state a segment: reduce_outputs hands every segment's rows to _reduce_group_runs
+        return red
     if plan.group_by is not None:
         red["gb_presence"] = "max"
         for i, agg in enumerate(plan.aggs):
@@ -1576,6 +1612,196 @@ def apply_reduce(op: str, value: Any):
     raise ValueError(op)
 
 
+def reduce_outputs(plan: StaticPlan, outs: Dict[str, Any]) -> Dict[str, Any]:
+    """The stacked segments' outputs [S, ...] merged over the segment
+    axis, each by its reducer (output_reducers); the rows of a 'runs'
+    group-by (``gb_rows``) by _reduce_group_runs, which needs the plan."""
+    reducers = output_reducers(plan)
+    merged = {k: apply_reduce(reducers[k], v) for k, v in outs.items() if reducers[k] != "runs"}
+    if "gb_rows" in outs:
+        merged.update(_reduce_group_runs(plan, outs["gb_rows"]))
+    return merged
+
+
+# rows a level of a long cumulative pass: the vector as [n / block, block],
+# the pass along the minor axis, the rows' totals in a pass of their own
+_CUMULATIVE_BLOCK = 1024
+# partial sums the digest of a 'runs' group-by comes back in (the host
+# adds them in float64)
+_RUNS_DIGEST_PARTS = 1024
+
+
+def _shifted(x, step: int, fill):
+    """x[i - step] at i, ``fill`` in the first ``step`` places."""
+    return jnp.concatenate([jnp.full((step,), fill, x.dtype), x[:-step]])
+
+
+def _long_cumulative(x, op, join, identity):
+    """An inclusive cumulative pass (``op``: jax.lax.cumsum or cummax)
+    over a long vector in two levels; ``join`` folds the rows before into
+    a row's own pass, ``identity`` stands before the first."""
+    n = x.shape[0]
+    if n <= _CUMULATIVE_BLOCK or n % _CUMULATIVE_BLOCK:
+        return op(x, axis=0)
+    inner = op(x.reshape(-1, _CUMULATIVE_BLOCK), axis=1)
+    before = _shifted(op(inner[:, -1], axis=0), 1, identity)
+    return join(inner, before[:, None]).reshape(-1)
+
+
+def _run_sums(values, dist, longest):
+    """At a run's last row, the sum of the run's ``values`` (rows in run
+    order; ``dist`` a row's distance from its run's first): log2(longest
+    run) shifted adds, each row adding the partial sum that ends a power
+    of two before it where that row is of its run, so a run's values add
+    up pairwise, in the values' own precision, whatever the other runs
+    hold.  A step no run is long enough for is skipped."""
+    step = 1
+    while step < values.shape[0]:
+        values = jax.lax.cond(
+            longest >= step,
+            lambda s, k=step: s + jnp.where(dist >= k, _shifted(s, k, 0), 0),
+            lambda s: s,
+            values,
+        )
+        step *= 2
+    return values
+
+
+def _order_keys(values):
+    """Integers that order as ``values`` do: an integer is its own; a
+    float's bits, the magnitude's flipped where the sign is set (a NaN
+    stands past +inf, where np.sort leaves it)."""
+    if jnp.issubdtype(values.dtype, jnp.integer):
+        return values
+    idt = jnp.int32 if values.dtype == jnp.float32 else jnp.int64
+    bits = jax.lax.bitcast_convert_type(values + 0, idt)  # -0.0 + 0 is +0.0
+    return jnp.where(bits < 0, bits ^ jnp.iinfo(idt).max, bits)
+
+
+def _kth_largest(keys, k: int):
+    """The ``k``-th largest of integer ``keys`` (the dtype's minimum where
+    they are fewer), by bisection on the value: one counting pass over the
+    rows a bit of the key, no sort and no scatter."""
+    info = jnp.iinfo(keys.dtype)
+
+    def halve(bounds):
+        lo, hi = bounds
+        mid = (lo >> 1) + (hi >> 1) + (((lo & 1) + (hi & 1) + 1) >> 1)  # ceil((lo + hi) / 2), no overflow
+        enough = jnp.sum(keys >= mid, dtype=jnp.int32) >= k
+        return jnp.where(enough, mid, lo), jnp.where(enough, hi, mid - 1)
+
+    lo, _ = jax.lax.while_loop(lambda b: b[0] < b[1], halve, (jnp.array(info.min, keys.dtype), jnp.max(keys)))
+    return lo
+
+
+def _first_set(flags, size: int):
+    """(positions of the first ``size`` set flags, ascending, the vector's
+    length where they run out; how many are set): a cumulative count and
+    one binary search a position, ``size`` gathers a step."""
+    counted = _long_cumulative(flags.astype(jnp.int32), jax.lax.cumsum, jnp.add, 0)
+    wanted = jnp.arange(1, size + 1, dtype=jnp.int32)
+    return jnp.searchsorted(counted, wanted, side="left").astype(jnp.int32), counted[-1]
+
+
+def _reduce_group_runs(plan: StaticPlan, rows):
+    """The 'runs' lowering's merge (groupby_lowering): every segment's
+    rows ``(key [S, n], weight columns [S, n] ...)`` to what the server's
+    finalize needs of a group-by, with no array of ``capacity`` cells.
+
+    ONE sort of the table's keys carrying the weight columns (a filtered
+    row's key is ``capacity`` and sorts last): a run of equal keys is a
+    group, whichever segments its rows came from, so the sort is the
+    merge across segments as well.  A run's count is its length, taken
+    from row positions in int32 (exact at any size: no count crosses the
+    segment axis as a float); its sums are _run_sums over the carried
+    columns.  Every aggregate's value stands at its run's last row.
+
+    Out of those, per aggregate in its own (descending) order, the
+    per-server trim's candidates as results.trim_group_candidates keeps
+    them: with more than ``max(5 x TOP, 100)`` live groups, the groups
+    strictly beyond the value at that cut and the groups tied with it in
+    ascending key, ``max(MAX_TRIM_TIES, what is left of the trim)`` of
+    them; else every live group.  The cut is _kth_largest; the positions
+    come from _first_set.  Returned: ``gb_runs_keys`` (the candidates'
+    keys an aggregate, -1 where a place is empty; an aggregate's list may
+    repeat another's), ``gb_runs_state`` (the m state rows of
+    _contraction_slots at those places, the occupancy's as a row count),
+    ``gb_runs_live`` (live groups, exact) and ``gb_runs_sumsq`` (each
+    aggregate's sum of squared values over every live group, in
+    _RUNS_DIGEST_PARTS partial sums: the cost vector's
+    ``groupStateSumSq``).  A few hundred kilobytes at the most, whatever
+    ``capacity`` is."""
+    from pinot_tpu.engine.results import MAX_TRIM_TIES
+
+    gb = plan.group_by
+    fdt = config.float_dtype()
+    ids = rows[0].reshape(-1)
+    cols = [c.reshape(-1) for c in rows[1:]]
+    n = ids.shape[0]
+    trim = max(gb.top_n * 5, 100)
+    most_ties = max(MAX_TRIM_TIES, trim)
+
+    with jax.named_scope("groupby_runs_sort"):
+        ids, *cols = jax.lax.sort((ids, *cols), num_keys=1, is_stable=False)
+
+    with jax.named_scope("groupby_runs_pass"):
+        live_row = ids < gb.capacity
+        end = live_row & (ids != jnp.concatenate([ids[1:], jnp.full((1,), gb.capacity, ids.dtype)]))
+        start = ids != _shifted(ids, 1, -1)
+        pos = jax.lax.iota(jnp.int32, n)
+        first = _long_cumulative(jnp.where(start, pos, 0), jax.lax.cummax, jnp.maximum, 0)
+        dist = pos - first
+        state = [dist + 1]  # the occupancy: a run's length at its last row
+        if cols:
+            longest = jnp.max(jnp.where(live_row, dist, 0))
+            state.extend(_run_sums(c, dist, longest) for c in cols)
+        live = jnp.sum(end, dtype=jnp.int32)
+
+    def value_of(agg: StaticAgg, slots):
+        if agg.base == "avg":
+            return state[slots[0]] / jnp.maximum(state[slots[1]], 1).astype(fdt)
+        return state[slots[0]]
+
+    keys_out, at_out, sumsq = [], [], []
+    chosen: Dict[tuple, tuple] = {}  # aggregates of one order share one selection
+    parts = np.gcd(n, _RUNS_DIGEST_PARTS)
+    for i, slots in _contraction_slots(plan)[0].items():
+        agg = plan.aggs[i]
+        which = (agg.base == "avg", tuple(slots))
+        if which not in chosen:
+            value = value_of(agg, slots)
+            with jax.named_scope("groupby_runs_digest"):
+                squares = jnp.where(end, jnp.square(value.astype(fdt)), 0)
+                digest = jnp.sum(squares.reshape(parts, -1), axis=1)
+            with jax.named_scope("groupby_runs_select"):
+                order = _order_keys(value)
+                order = jnp.where(end, order, jnp.iinfo(order.dtype).min)
+                cut = _kth_largest(order, trim)
+                few = live <= trim
+                beyond_at, beyond_n = _first_set(end & (few | (order > cut)), min(trim, n))
+                tied_at, tied_n = _first_set(end & ~few & (order == cut), min(most_ties, n))
+                tied_n = jnp.minimum(tied_n, jnp.maximum(MAX_TRIM_TIES, trim - beyond_n))
+                at = jnp.concatenate([beyond_at, tied_at])
+                kept = jnp.concatenate([
+                    jnp.arange(beyond_at.shape[0], dtype=jnp.int32) < beyond_n,
+                    jnp.arange(tied_at.shape[0], dtype=jnp.int32) < tied_n,
+                ])
+            chosen[which] = (jnp.minimum(at, n - 1), kept, digest)
+        at, kept, digest = chosen[which]
+        keys_out.append(jnp.where(kept, ids[at], -1))
+        at_out.append(at)
+        sumsq.append(digest)
+    at = jnp.concatenate(at_out)
+    return {
+        "gb_runs_keys": jnp.concatenate(keys_out),
+        "gb_runs_state": tuple(
+            row[at].astype(config.row_count_dtype()) if r == 0 else row[at] for r, row in enumerate(state)
+        ),
+        "gb_runs_live": live,
+        "gb_runs_sumsq": jnp.stack(sumsq),
+    }
+
+
 def _row_key(key: str) -> bool:
     return key.endswith((".fwd", ".raw", ".gfwd", ".mv", ".mvc", ".hllb", ".hllr", ".mvraw"))
 
@@ -1670,11 +1896,10 @@ def make_table_kernel(plan: StaticPlan) -> Callable:
     the same plan on every call.
     """
     single = make_single_segment_kernel(plan)
-    reducers = output_reducers(plan)
 
     def table_fn(segs: Dict[str, Any], q: Dict[str, Any]) -> Dict[str, Any]:
         outs = jax.vmap(single)(segs, q)
-        return {k: apply_reduce(reducers[k], v) for k, v in outs.items()}
+        return reduce_outputs(plan, outs)
 
     return jax.jit(named(table_fn, kernel_name("scan", plan)))
 
@@ -1864,11 +2089,10 @@ def make_block_table_kernel(plan: StaticPlan, block: int) -> Callable:
     """Jitted block-skipping variant of make_table_kernel; extra input:
     block ids int32 [S, nb_pad] (-1 padded)."""
     stacked = make_stacked_block_kernel(plan, block)
-    reducers = output_reducers(plan)
 
     def table_fn(segs, q, ids):
         outs = stacked(segs, q, ids)
-        return {k: apply_reduce(reducers[k], v) for k, v in outs.items()}
+        return reduce_outputs(plan, outs)
 
     return jax.jit(named(table_fn, kernel_name("zone", plan)))
 
@@ -2017,11 +2241,10 @@ def make_packed_batched_table_kernel(plan: StaticPlan) -> Callable:
     together.  Outputs fetch via the standard single packed D2H
     transfer, counted once per batched launch."""
     single = make_single_segment_kernel(plan)
-    reducers = output_reducers(plan)
 
     def table_fn(segs: Dict[str, Any], q: Dict[str, Any]) -> Dict[str, Any]:
         outs = jax.vmap(single)(segs, q)
-        return {k: apply_reduce(reducers[k], v) for k, v in outs.items()}
+        return reduce_outputs(plan, outs)
 
     from pinot_tpu.engine.packing import make_packed_kernel
 

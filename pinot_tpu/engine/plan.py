@@ -160,8 +160,43 @@ def group_capacity(request, ctx) -> int:
     return cap
 
 
-def group_capacity_forces_host(cap: int) -> bool:
-    return cap > config.MAX_GROUP_CAPACITY or cap > config.max_key_space()
+def group_runs_host_reason(request, capacity: int, mv_key: bool = False, mesh: bool = False) -> Optional[str]:
+    """Why a group-by over ``capacity`` keys is answered by the host, by
+    name, or None where the device answers it: through a dense holder up
+    to ``MAX_GROUP_CAPACITY`` keys, through the runs lowering above
+    (``kernel.groupby_lowering`` 'runs': the rows' global ids sorted, a
+    run a group, no state of ``capacity`` cells anywhere), which takes
+    ``count``, ``sum`` and ``avg`` under a TOP n, single-value keys, one
+    chip.  Decided from the request alone, before anything is staged;
+    ``plan_forced_host``, ``build_static_plan``, EXPLAIN, the
+    ``groupby.forcedHost.<name>`` meter and the benchmark's generator
+    (``benchmark/hits_topusers_table.py``, by this name) all ask here."""
+    if capacity > config.max_key_space():
+        return "keySpace"  # no key dtype holds the mixed-radix key (2^30 without x64)
+    if capacity <= config.MAX_GROUP_CAPACITY:
+        return None
+    for a in request.aggregations:
+        if a.base_function not in ("count", "sum", "avg"):
+            return f"aggregate:{a.base_function}"  # min, max, minmaxrange, distinctcount*, percentile*: no run form yet
+    if mv_key:
+        return "multiValueKey"  # a row of several keys is several rows of the sort
+    if request.group_by.top_n <= 0:
+        return "noTopN"  # nothing to trim by: every group would come back
+    if mesh:
+        return "mesh"  # PINOT_TPU_MESH_SHAPE: the sort is one chip's; the sharded path keeps the host's answer
+    from pinot_tpu.engine.kernel import _SORTED_COLS_MAX
+
+    summed = {a.column for a in request.aggregations if a.base_function in ("sum", "avg")}
+    entries = {a.column for a in request.aggregations if a.is_mv and a.base_function in ("count", "avg")}
+    if len(summed) + len(entries) > _SORTED_COLS_MAX:
+        return "measures"  # the sort carries at most that many float columns beside the key
+    return None
+
+
+def group_by_host_reason(request, ctx, mesh: bool = False) -> Optional[str]:
+    """``group_runs_host_reason`` of a request over ``ctx``'s segments."""
+    mv_key = any(not ctx.segments[0].column(c).metadata.single_value for c in request.group_by.columns)
+    return group_runs_host_reason(request, group_capacity(request, ctx), mv_key, mesh)
 
 
 def value_state_sort_pairs(kind: str, gcard_pad: int, cap: Optional[int]) -> bool:
@@ -177,15 +212,16 @@ def value_state_sort_pairs(kind: str, gcard_pad: int, cap: Optional[int]) -> boo
     return False
 
 
-def plan_forced_host(request, ctx) -> bool:
+def plan_forced_host(request, ctx, mesh: bool = False) -> bool:
     """Host-path decisions decidable BEFORE staging — a strict subset of
     the ``on_device = False`` conditions ``build_static_plan`` applies
     (via the same shared predicates above).  The executor consults this
     first so a query that can only run on the host never pays device
-    staging (at north-star scale that's a 1GB+ transfer for nothing)."""
+    staging (at north-star scale that's a 1GB+ transfer for nothing).
+    ``mesh``: the query would run sharded over a mesh."""
     try:
         cap = group_capacity(request, ctx) if request.is_group_by else None
-        if cap is not None and group_capacity_forces_host(cap):
+        if cap is not None and group_by_host_reason(request, ctx, mesh) is not None:
             return True
         if request.filter is None:
             for a in request.aggregations:
@@ -481,7 +517,7 @@ def build_static_plan(
         col_is_mv = tuple(not staged.column(c).single_value for c in cols)
         gcards = tuple(ctx.column(c).global_cardinality for c in cols)
         cap = group_capacity(request, ctx)
-        if group_capacity_forces_host(cap):
+        if group_by_host_reason(request, ctx, mesh=staged.sharding is not None) is not None:
             on_device = False
         # value-state aggs need [capacity, gcard] holders — cap the
         # product; presence escapes to the sort-dedup path instead of

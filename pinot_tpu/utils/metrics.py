@@ -629,6 +629,11 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "groupby.operands.sorted)",
     "groupby.lowering.scatter": "group-by launches on the serialised "
     "scatter (the CPU backend)",
+    "groupby.lowering.runs": "group-by launches over more keys than a "
+    "dense holder takes (above MAX_GROUP_CAPACITY): the table's rows sorted "
+    "by group id once, a run a group, and the trim's candidates, the live "
+    "count and the digest made on the device (engine/kernel.py "
+    "_reduce_group_runs; ``groupby=runs``)",
     "groupby.operands.loop": "group-by launches whose filter mask, key "
     "and weight columns are built inside the group-by's row loop "
     "(engine/kernel.py groupby_operands; the launch's ``operands=`` tag)",
@@ -656,6 +661,29 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "device group-by, before the per-server trim",
     "groupby.groups.kept": "groups left after the per-server trim "
     "(max(5 x TOP, 100) an aggregate, and boundary ties)",
+    "groupby.stateFetchBytes": "bytes of group state a device group-by's "
+    "finalize was handed from the chip, marked by the count a reply, every "
+    "lowering: a dense holder's K cells an aggregate and the occupancy, or "
+    "the runs lowering's candidates (kilobytes whatever K is)",
+    "groupby.forcedHost.keySpace": "group-bys sent to the host before "
+    "staging because the key space passes the key dtype (2^30 without x64)",
+    "groupby.forcedHost.aggregate": "group-bys over more than "
+    "MAX_GROUP_CAPACITY keys sent to the host because an aggregate has no "
+    "run form (min, max, minmaxrange, distinctcount*, percentile*; "
+    "engine/plan.py group_runs_host_reason names it in EXPLAIN)",
+    "groupby.forcedHost.multiValueKey": "the same, because a group column "
+    "is multi-valued",
+    "groupby.forcedHost.noTopN": "the same, because the query has no TOP n "
+    "to trim by",
+    "groupby.forcedHost.mesh": "the same, because the query would run "
+    "sharded over a mesh (PINOT_TPU_MESH_SHAPE): the sort is one chip's",
+    "groupby.forcedHost.measures": "the same, because its sums and averages "
+    "read more columns than the sort carries (kernel._SORTED_COLS_MAX)",
+    "phase.globalDictBuild": "a column's table-level dictionary (the "
+    "sorted union of the segments' dictionaries) and the remap of each "
+    "segment's ids into it, built once a segment set by the first query "
+    "that needs the column's global ids (engine/context.py), inside "
+    "phase.staging; timer and annotation, no span",
     "phase.groupTrim": "inside phase.finalize of a device group-by: from "
     "the fetched state to the kept keys (nonzero over the occupancy, the "
     "order values and their sum of squares, and trim_group_candidates' "

@@ -308,6 +308,8 @@ def test_forced_host_is_subset_of_plan_decision(cluster):
     queries = [
         "SELECT count(*) FROM lineitem GROUP BY l_returnflag TOP 10",
         "SELECT count(*) FROM lineitem GROUP BY l_extendedprice TOP 10",
+        # over MAX_GROUP_CAPACITY a count rides the runs lowering (PR 43); a max has no run form: the host's
+        "SELECT max(l_quantity) FROM lineitem GROUP BY l_extendedprice TOP 10",
         "SELECT distinctcount(l_extendedprice) FROM lineitem GROUP BY l_returnflag TOP 10",
         "SELECT distinctcount(l_extendedprice) FROM lineitem "
         "WHERE l_shipdate > '1993-01-01' GROUP BY l_returnflag TOP 10",

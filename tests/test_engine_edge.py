@@ -25,9 +25,12 @@ def run_both(schema, rows, segments, pql):
     return got, want
 
 
-def test_host_fallback_huge_keyspace():
-    """Group-by key space above MAX_GROUP_CAPACITY routes to the host
-    hash path (the LONG_MAP_BASED analog) and stays correct."""
+@pytest.mark.parametrize("aggregate,on_device", [("max(m)", False), ("sum(m)", True)])
+def test_host_fallback_huge_keyspace(aggregate, on_device):
+    """Group-by key space above MAX_GROUP_CAPACITY: an aggregate with no
+    run form (``max``) routes to the host hash path (the LONG_MAP_BASED
+    analog) and stays correct; a sum stays on the device through the runs
+    lowering (PR 43), with the same answer."""
     schema = Schema(
         "big",
         dimensions=[
@@ -45,13 +48,14 @@ def test_host_fallback_huge_keyspace():
     from pinot_tpu.engine.device import get_staged
     from pinot_tpu.engine.plan import build_static_plan
 
-    req = parse_pql("SELECT sum(m) FROM big GROUP BY a, b, c TOP 10")
+    pql = f"SELECT {aggregate} FROM big GROUP BY a, b, c TOP 10"
+    req = parse_pql(pql)
     ctx = get_table_context([seg])
     staged = get_staged([seg], ["a", "b", "c", "m"])
     plan = build_static_plan(req, ctx, staged)
-    assert not plan.on_device  # confirms the fallback triggers
+    assert plan.on_device == on_device  # confirms the fallback triggers, and for what
 
-    got, want = run_both(schema, rows, [seg], "SELECT sum(m) FROM big GROUP BY a, b, c TOP 10")
+    got, want = run_both(schema, rows, [seg], pql)
     assert got == want
 
 

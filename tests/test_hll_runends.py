@@ -214,8 +214,12 @@ def test_no_reducer_names_the_lowering():
     from pinot_tpu.parallel import multichip
 
     assert not hasattr(kernel_mod, "_reduce_hll_sort")
+    # (the one searchsorted of the kernels is the runs group-by's, PR 43: a few thousand places, not a bound a cell)
+    for part in (mesh, multichip, kernel_mod._hll_sorted_registers, kernel_mod._group_state, kernel_mod._agg_state,
+                 kernel_mod.apply_reduce, kernel_mod._sorted_window_sums):
+        assert "searchsorted(" not in inspect.getsource(part), part.__name__
     for module in (kernel_mod, mesh, multichip):
-        assert "hll_sort:" not in inspect.getsource(module) and "searchsorted(" not in inspect.getsource(module), module.__name__
+        assert "hll_sort:" not in inspect.getsource(module), module.__name__
     with pytest.raises(ValueError):
         kernel_mod.apply_reduce("hll_sort:40", jnp.zeros((2, 40 * M), jnp.int32))
 

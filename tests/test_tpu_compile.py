@@ -288,3 +288,83 @@ def test_q15_sorts_220000_groups_over_the_gathered_view_on_v5e(one_chip, zone_la
     assert memory.output_size_in_bytes <= 2 * 220_000 * 4 + 4096
     # the scatter's program of PR 37 kept 1,184,590,848 bytes there
     assert memory.temp_size_in_bytes < 1_184_590_848, f"temporaries {memory.temp_size_in_bytes / (1 << 30):.3f} GiB"
+
+
+# ClickBench line 16 (benchmark/traffic/hits_topusers_closed.json): COUNT(*) by 17.6M UserID, TOP 10, the
+# 'runs' lowering (PR 43); beside it the same with a sum and an average, which the sort carries
+RUNS_SHAPES = {
+    "line_16": "SELECT COUNT(*) FROM hits GROUP BY UserID TOP 10",
+    "a_sum_and_an_average": "SELECT COUNT(*), sum(AdvEngineID), avg(ResolutionWidth) FROM hits GROUP BY UserID TOP 10",
+}
+HITS_USERS_KEYS = 17_630_976
+
+
+@pytest.fixture(scope="module")
+def runs_launches():
+    """(plan, segment arrays, query inputs) of each launch of RUNS_SHAPES
+    as the executor makes it on the chip (float32, int32), over a tiny
+    hits table whose users are over a patched MAX_GROUP_CAPACITY."""
+    from pinot_tpu.engine import config, kernel as kernel_mod
+    from pinot_tpu.engine.executor import QueryExecutor
+    from pinot_tpu.pql import optimize_request, parse_pql
+    from pinot_tpu.tools.datagen import synthetic_hits_users_segment
+
+    launches = {}
+    with pytest.MonkeyPatch.context() as mp, jax.enable_x64(False):
+        mp.setenv("PINOT_TPU_RAW_CARD_MIN", "0")
+        mp.setattr(config, "MAX_GROUP_CAPACITY", 1 << 10)
+        run_kernel = QueryExecutor._run_kernel
+
+        def spy(self, kernel, args, plan, *rest, **kw):
+            launches[name] = (plan, args[0], args[1])
+            return run_kernel(self, kernel, args, plan, *rest, **kw)
+
+        mp.setattr(QueryExecutor, "_run_kernel", spy)
+        segs = [synthetic_hits_users_segment(4096, seed=430 + i, name=f"runs{i}") for i in range(2)]
+        try:
+            for name, pql in RUNS_SHAPES.items():
+                QueryExecutor().execute(segs, optimize_request(parse_pql(pql)))
+        finally:
+            kernel_mod.make_table_kernel.cache_clear()
+            kernel_mod.make_packed_table_kernel.cache_clear()
+    return launches
+
+
+@pytest.mark.parametrize("shape", sorted(RUNS_SHAPES))
+def test_runs_groupby_compiles_for_v5e_at_the_cells_size(one_chip, runs_launches, monkeypatch, shape):
+    """The whole table program of ClickBench's line 16 at the cell's own
+    size, 12 segments of 8,388,608 rows and 17.6M keys (and, smaller, of
+    the same with two carried columns): one sort of the
+    table's ids, the run pass, the cut, the candidates.  What comes back
+    is kilobytes, and what the program keeps in HBM beside the staged
+    columns is rows, never keys: a few vectors of 100.7M elements."""
+    import dataclasses
+
+    from pinot_tpu.engine import config, kernel as kernel_mod
+
+    monkeypatch.setattr(config, "MAX_GROUP_CAPACITY", 1 << 20)  # the program's own bound: 17.6M keys are over it
+    plan, segs, q = runs_launches[shape]
+    plan = dataclasses.replace(plan, group_by=dataclasses.replace(
+        plan.group_by, gcards=(HITS_USERS_KEYS,), capacity=HITS_USERS_KEYS))
+    assert kernel_mod.groupby_lowering(plan) == "runs"
+    # the cell's query at the cell's size; the carried columns' scans over few rows (a step of the scan a doubling of the rows: the compile grows with them)
+    S, n = (12, 1 << 23) if shape == "line_16" else (2, 1 << 16)
+
+    def at_scale(key, v):
+        rows = (n,) + v.shape[2:] if kernel_mod._row_key(key) else v.shape[1:]
+        dtype = jnp.int32 if key.endswith(".gfwd") else v.dtype  # ids of 17.6M values take four bytes
+        return jax.ShapeDtypeStruct((S,) + rows, dtype, sharding=one_chip)
+
+    segs = {key: at_scale(key, v) for key, v in segs.items()}
+    q = jax.tree_util.tree_map(lambda v: at_scale("", v), q)
+    assert segs["UserID.gfwd"].shape == (S, n)
+    try:
+        with jax.enable_x64(False):
+            compiled = kernel_mod.make_table_kernel(plan).lower(segs, q).compile()
+    finally:
+        kernel_mod.make_table_kernel.cache_clear()
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes < 1 << 20  # candidates, a count and a digest: never 17.6M of anything
+    columns = 1 + 2 * (shape != "line_16")  # the key, and the two measures the sort carries
+    assert memory.temp_size_in_bytes <= (8 + 6 * columns) * S * n * 4
+    assert compiled.as_text().count(" sort(") == 1  # one sort of the table's rows: it is the merge across segments too
