@@ -66,21 +66,32 @@ _MATMUL_HLL_CAP = int(_os.environ.get("PINOT_TPU_MATMUL_HLL_CAP", str(1 << 18)))
 # row, sorted a segment, and the sum of each (group, register) run's last
 # rank on the matrix unit (_hll_sorted_registers; bit-identical to
 # scatter-max) while (capacity * HLL_M * 64) fits int32; beyond that the
-# flat scatter runs.  Judged at 9,040 groups (chip runs, PR 42, ClickBench
-# hits by RegionID, 12 segments of 2^23 rows, 2.31M cells): 386 ms of
-# device time a query, of it the twelve sorts 329 (lax.sort over
-# [12, 2^23] keys: 3.27 ns a row, what ONE sort of all 2^26.6 keys costs,
-# 3.25 to 3.33: a row of 2^23 is no cheaper to sort than the whole), the twelve
-# windowed calls 20.7 (0.2 ns a row, one or two windows a block of 8,192
-# rows), the keys' pass into cells and ranks 7, the occupancy's
-# contraction beside it 14.4.  Until PR 42 the reduce sorted every
-# segment's keys at once and a searchsorted of one bound a cell read the
-# largest key: 1,217 ms of a 1,590 ms query (27 steps of a gather the
-# chip serialises, 19.5 ns an element a step; PR 41), and packing the
-# keys 44, now under 4.3.  The scatter it stands in for would cost 13.4
-# ns a row, 1,350 ms (PR 37's reading; not run here).  What is left is
-# the sort: ROADMAP S13.  Above 12,256 groups the cells go in ranges
-# (compiled for a v5e at 65,536, not run on one); above 65,536: no cell
+# flat scatter runs.  Judged at 9,040 groups (chip runs, PR 42 and PR 44,
+# ClickBench hits by RegionID, 12 segments of 2^23 rows, 2.31M cells):
+# 225 ms of device time a query, of it the twelve sorts 168 (lax.sort of
+# the keys ALONE, unstable, over [12, 2^23]: 1.67 ns a row in the cell,
+# 1.70 alone in a program), the twelve windowed calls 20.7 (0.2 ns a row, one or two
+# windows a block of 8,192 rows), the keys' pass into cells and ranks 7,
+# the occupancy's contraction beside it 14.4.  PR 42 read 329 for the
+# sorts, 3.27 ns a row, and took it for the price of the keys: it was
+# the price of TWO operands.  lax.sort's default is stable, and a stable
+# sort on this chip is the unstable one with an operand of row numbers
+# carried as the last key (`sort(%keys, %iota)`, 537 MB of temporaries),
+# which orders nothing where the keys are the only operand.  ns a row by
+# the rows one sort spans (PR 44's probe, seed 4400001001, lax.sort alone
+# over the 100.7M packed keys), keys alone and unstable: 2^19 0.71, 2^20
+# 0.83, 2^21 0.97, 2^22 1.12, 2^23 1.70, all 2^26.6 flat 1.64; with the
+# row numbers: 2^19 1.40, 2^23 3.30, flat 3.25.  The keys' content costs
+# nothing (every row one key 1.69; plain ids of 17.6M users 1.70 and
+# 1.64).  So a row of 2^23 is no cheaper than the whole, but a row of
+# 2^22 is a third cheaper: ROADMAP S13.  Until PR 42 the reduce sorted
+# every segment's keys at once and a searchsorted of one bound a cell
+# read the largest key: 1,217 ms of a 1,590 ms query (27 steps of a
+# gather the chip serialises, 19.5 ns an element a step; PR 41), and
+# packing the keys 44, now under 4.3.  The scatter it stands in for would
+# cost 13.4 ns a row, 1,350 ms (PR 37's reading; not run here).  Above
+# 12,256 groups the cells go in ranges (compiled for a v5e at 65,536, not
+# run on one); above 65,536: no cell
 _HLL_SORT_CAP = int(_os.environ.get("PINOT_TPU_HLL_SORT_CAP", str(1 << 16)))
 
 
@@ -156,8 +167,13 @@ def hll_lowering(plan: StaticPlan) -> Optional[str]:
 #   K = 2^15:     -   1.49   3.75     K = 220,000:  -       -  3.75
 #   K = 2^16: 13.55   2.87   3.75
 # The sorted form costs the same at every K: 3.32 of its 3.75 are the
-# sort (lax.sort of an int32 key carrying one float32; a second sum
-# carried costs 1.9 more, 5.66 against the contraction's 5.09 at 2^16).
+# sort (lax.sort of an int32 key carrying one float32, unstable: two
+# operands; a second sum carried, three operands, costs 1.9 more, 5.66
+# against the contraction's 5.09 at 2^16).  A sort is priced by its
+# operands as much as by its rows: the key alone over the same [*, 2^23]
+# costs 1.70 (PR 44's probe; 1.61 flat in PR 43's cell), and a stable
+# sort adds an operand of row numbers to whatever it is given (the key
+# alone, stable: 3.30).
 # So on segments of 2^23 rows the crossover lies near K = 85,000 at one
 # sum and 73,000 at two, and 2^16 stays the largest measured K the
 # contraction over the rows as they stand wins.  The sort's cost a row falls with
@@ -165,13 +181,15 @@ def hll_lowering(plan: StaticPlan) -> Optional[str]:
 # the zone tier's gathered view hands TPC-H Q15 its candidate blocks
 # (K = 220,000, a product under the sum, 5.64M of 8.39M rows valid;
 # chip runs, PR 38, seeds 3800001001 and 3800003201), the sorted form
-# takes 11.6 to 11.75 ms, 1.40 ns a row (the sort 8.9, the windowed
-# contraction 2.4 to 2.9; 11.70 with every row on one key, 11.03 with
-# 0.7% of the rows valid), the contraction at K = 220,000 without the
-# sort 82.3 (9.81), the parent's two scatters 113.8 (13.57; in the cell
-# 56.9 and 55.6 ms a query, PR 37) and a sort followed by
-# segment_sum(indices_are_sorted=True) 144.5: the scatter stays serial
-# whatever it is told.
+# takes 11.6 to 11.75 ms, 1.40 ns a row (the sort 8.9, key and payload,
+# unstable: 1.06 a row, where the key alone reads 0.71 over rows of 2^19
+# and a third operand, the payload's or a stable sort's, 1.7 to 2.0: PR
+# 38, PR 40, PR 44; the windowed contraction 2.4 to 2.9; 11.70 with
+# every row on one key, 11.03 with 0.7% of the rows valid), the
+# contraction at K = 220,000 without the sort 82.3 (9.81), the parent's
+# two scatters 113.8 (13.57; in the cell 56.9 and 55.6 ms a query, PR 37)
+# and a sort followed by segment_sum(indices_are_sorted=True) 144.5: the
+# scatter stays serial whatever it is told.
 RADIX_GROUP_CAP = 1 << 16
 _RADIX = 128
 # VMEM the generated one-hots of one grid step may take (the block of
@@ -570,7 +588,10 @@ def _hll_sorted_registers(packed, capacity: int):
     row and ``_PAIR_SENTINEL`` where the row is filtered out: the 'sort'
     lowering (hll_lowering), bit for bit the scatter-max's.
 
-    The keys are sorted where they were built.  A row is the last of its
+    The keys are sorted where they were built, unstably: they are the
+    sort's one operand, so rows that compare equal are equal in every bit
+    and a stable sort's row-number operand would order nothing (on the
+    chip it doubles the sort: PR 44).  A row is the last of its
     (group, register) run where ``key >> 6`` differs from the next row's;
     there its weight is the rank ``key & 63``, which is the run's largest
     (the rank rides the key's low bits), and everywhere else 0.  So
@@ -586,7 +607,7 @@ def _hll_sorted_registers(packed, capacity: int):
     to its first cell with a weight of 0 and those over it to its
     sentinel, which keeps them in order."""
     cells = capacity * config.HLL_M
-    keys = jax.lax.sort(_whole_blocks(packed, [], _SORTED_BLOCK, _PAIR_SENTINEL)[0])
+    keys = jax.lax.sort(_whole_blocks(packed, [], _SORTED_BLOCK, _PAIR_SENTINEL)[0], is_stable=False)
     cell = keys >> 6
     ends = jnp.concatenate([cell[1:] != cell[:-1], jnp.ones(1, bool)])
     # cells a call: the sublanes _SORTED_ACC_BYTES holds, less the last window's, in whole tiles
@@ -1433,7 +1454,7 @@ def _selection_outputs(plan: StaticPlan, seg, q, mask) -> Dict[str, Any]:
         keys = [jnp.logical_not(mask).astype(jnp.int32)]  # matches first
         keys.extend(g for g, _ in _sort_ordinals(sel, seg, q, jnp.int32))
         keys.append(jnp.arange(n, dtype=jnp.int32))  # doc-order tie-break
-        sorted_ops = jax.lax.sort(tuple(keys), num_keys=len(keys))
+        sorted_ops = jax.lax.sort(tuple(keys), num_keys=len(keys), is_stable=True)
         idx = sorted_ops[-1][: sel.k]
         return {"sel_docids": idx, "sel_valid": mask[idx]}
     else:
@@ -1544,7 +1565,7 @@ def _reduce_distinct_pairs(value):
     """
     s = value[0].reshape(-1)
     g = value[1].reshape(-1)
-    s, g = jax.lax.sort((s, g), num_keys=2)
+    s, g = jax.lax.sort((s, g), num_keys=2, is_stable=True)
     first = jnp.concatenate(
         [jnp.ones((1,), bool), (s[1:] != s[:-1]) | (g[1:] != g[:-1])]
     )
@@ -1580,7 +1601,7 @@ def merge_pair_buffers(slots, gids, counts):
     s = slots.reshape(-1).astype(jnp.int32)
     g = gids.reshape(-1).astype(jnp.int32)
     c = counts.reshape(-1).astype(jnp.int32)
-    s, g, c = jax.lax.sort((s, g, c), num_keys=2)
+    s, g, c = jax.lax.sort((s, g, c), num_keys=2, is_stable=True)
     first = jnp.concatenate(
         [jnp.ones((1,), bool), (s[1:] != s[:-1]) | (g[1:] != g[:-1])]
     )

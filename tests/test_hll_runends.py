@@ -10,6 +10,8 @@ still gives ``_segment_add_sorted`` the radix contraction's states.
 ``tests/test_tpu_compile.py`` compiles the same calls for a described
 v5e at the cell's size; the cell's answers against the reference are in
 ``tests/test_hits_users.py``."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,6 +83,55 @@ def test_a_register_is_the_sum_of_its_runs_last_rank(case):
     got = np.asarray(jax.jit(lambda p: kernel_mod._hll_sorted_registers(p, capacity))(packed))
     assert got.dtype == np.uint8 and got.shape == (capacity, M)
     assert np.array_equal(got, scatter_max(packed, capacity))
+
+
+def test_the_sort_has_one_operand_and_is_unstable():
+    """The keys are the sort's only operand, so a stable sort orders
+    nothing an unstable one does not, and on the chip it carries a second
+    operand of row numbers through every stage (PR 44: 3.3 ns a row
+    against 1.6).  Held on the lowered text, which is the same for every
+    backend."""
+    text = jax.jit(lambda p: kernel_mod._hll_sorted_registers(p, 9_040)).lower(jax.ShapeDtypeStruct((3 * BLOCK + 5,), jnp.int32)).as_text()
+    sorts = re.findall(r'stablehlo\.sort"?\(([^)]*)\)', text)
+    assert len(sorts) == 1 and "," not in sorts[0], sorts
+    assert text.count("is_stable = false") == 1 and "is_stable = true" not in text
+
+
+def every_key_64_times_over(rng, capacity):
+    return np.repeat(keys(rng, capacity, 400, filtered=0.1), 64)  # runs of rows equal in every bit, the sentinel's too
+
+
+def two_cells_only(rng, capacity):
+    cells = np.array([0, capacity * M - 1])  # the first and the last
+    return ((cells[rng.integers(0, 2, BLOCK + 300)] << 6) | rng.integers(0, 4, BLOCK + 300)).astype(np.int32)
+
+
+def all_rows_one_key_but_one(rng, capacity):
+    packed = np.full(2 * BLOCK, (int(rng.integers(0, capacity * M)) << 6) | 21, np.int32)
+    packed[rng.integers(0, packed.size)] = (int(rng.integers(0, capacity * M)) << 6) | 44
+    return packed
+
+
+def a_ragged_count_with_sentinels_between(rng, capacity):
+    packed = np.repeat(keys(rng, capacity, 900, filtered=0.0), 10)[:BLOCK + 777]
+    packed[rng.integers(0, packed.size, packed.size // 3)] = SENTINEL
+    return packed
+
+
+DUPLICATES = {f.__name__: f for f in (every_key_64_times_over, two_cells_only, all_rows_one_key_but_one, a_ragged_count_with_sentinels_between)}
+
+
+@pytest.mark.parametrize("capacity", [17, 9_040])
+@pytest.mark.parametrize("seed", [11, 4_400_001_001, 4_400_001_002, 2**31 + 7])
+@pytest.mark.parametrize("case", sorted(DUPLICATES))
+def test_rows_equal_in_every_bit_need_no_order_among_themselves(case, seed, capacity):
+    """What an unstable sort may do differently from a stable one is
+    permute equal keys: the registers are the scatter-max's whatever it
+    does with them."""
+    packed = DUPLICATES[case](np.random.default_rng(seed), capacity)
+    got = np.asarray(jax.jit(lambda p: kernel_mod._hll_sorted_registers(p, capacity))(packed))
+    assert np.array_equal(got, scatter_max(packed, capacity))
+    assert got.any()
 
 
 # sublanes (x 128 cells) of accumulator a call may take: 48 after the last window's 64, 24 groups of 256 registers

@@ -8,6 +8,9 @@ pairs of ``ALLOWED`` are the upward imports the tree still has, each a
 debt ROADMAP names (D14); the list may only shrink: a case fails on an
 upward import that is not on it, and on a listed pair that no longer
 occurs.
+
+The last walk is over calls, not imports: every ``lax.sort`` of the
+package says whether it is stable.
 """
 import ast
 import os
@@ -73,19 +76,23 @@ def imported_modules(tree: ast.AST):
             yield ".".join(parts[1:3]) if is_module else parts[1]  # a name the package itself exports
 
 
+def parsed_modules(folder: str):
+    """(path, syntax tree) of every module under ``folder``."""
+    for sub, _, files in os.walk(folder):
+        for name in files:
+            if name.endswith(".py"):
+                path = os.path.join(sub, name)
+                with open(path) as f:
+                    yield path, ast.parse(f.read(), path)
+
+
 def upward_imports(package: str) -> set:
     found = set()
-    for folder, _, files in os.walk(os.path.join(PACKAGE, package)):
-        for name in files:
-            if not name.endswith(".py"):
-                continue
-            path = os.path.join(folder, name)
-            module = os.path.relpath(path, PACKAGE)[:-3].replace(os.sep, ".")
-            with open(path) as f:
-                tree = ast.parse(f.read(), path)
-            for target in imported_modules(tree):
-                if LEVEL.get(target.split(".")[0], -1) > LEVEL[package]:
-                    found.add((module, target))
+    for path, tree in parsed_modules(os.path.join(PACKAGE, package)):
+        module = os.path.relpath(path, PACKAGE)[:-3].replace(os.sep, ".")
+        for target in imported_modules(tree):
+            if LEVEL.get(target.split(".")[0], -1) > LEVEL[package]:
+                found.add((module, target))
     return found
 
 
@@ -96,3 +103,24 @@ def test_no_module_imports_a_package_above_it(package):
     allowed = {pair for pair in ALLOWED if pair[0].split(".")[0] == package}
     assert not found - allowed, f"{package} imports upward: {sorted(found - allowed)}"
     assert not allowed - found, f"turned since: take off ALLOWED and ROADMAP D14: {sorted(allowed - found)}"
+
+
+def sort_calls_without_is_stable() -> list:
+    found = []
+    for path, tree in parsed_modules(PACKAGE):
+        for node in ast.walk(tree):
+            if not isinstance(node, ast.Call):
+                continue
+            callee = ast.unparse(node.func)
+            if (callee == "sort" or callee.endswith("lax.sort")) and "is_stable" not in {k.arg for k in node.keywords}:
+                found.append(f"{os.path.relpath(path, PACKAGE)}:{node.lineno}")
+    return found
+
+
+def test_every_device_sort_says_whether_it_is_stable():
+    """``lax.sort``'s default is ``is_stable=True``, and on the chip a
+    stable sort is the unstable one with an operand of row numbers
+    carried as the last key: twice the work where the operands are all
+    keys (PR 44: 3.3 ns a row against 1.6 over 100.7M packed keys).  A
+    call that leaves the choice to the default has not made it."""
+    assert not sort_calls_without_is_stable()
