@@ -388,7 +388,7 @@ class _Dispatch:
     __slots__ = (
         "key", "launch", "pending", "waiters", "completed", "value",
         "error", "plan_digest", "cost_provider", "batch", "batch_size",
-        "t_submit", "trace", "parent", "program", "groupby", "operands", "expr", "cells", "blocks", "hll", "launch_id",
+        "t_submit", "trace", "parent", "program", "groupby", "operands", "expr", "cells", "blocks", "hll", "hll_parts", "launch_id",
     )
 
     def __init__(
@@ -409,6 +409,7 @@ class _Dispatch:
         cells: Tuple[int, int] = (0, 0),
         blocks: str = "",
         hll: str = "",
+        hll_parts: int = 0,
     ) -> None:
         self.t_submit = t_submit  # phase.laneQueue runs from here to the launch call
         # the submitting query's span tree and the span (its laneWait)
@@ -422,6 +423,7 @@ class _Dispatch:
         self.cells = cells  # a group-by's K x m cells and the rows sharing saved (kernel.groupby_cells)
         self.blocks = blocks  # how a zone-tier program reads its candidate blocks (kernel.zone_blocks)
         self.hll = hll  # the lowering of the program's HLL aggregates (kernel.hll_lowering)
+        self.hll_parts = hll_parts  # under 'sort', the parts a segment's keys are sorted in (kernel.hll_sort_parts)
         self.launch_id: Optional[int] = None  # the physical launch this rode (occupancy)
         self.key = key
         self.launch = launch
@@ -601,6 +603,7 @@ class DeviceLane:
         cells: Tuple[int, int] = (0, 0),
         blocks: str = "",
         hll: str = "",
+        hll_parts: int = 0,
     ) -> LaneTicket:
         """Enqueue a kernel launch, or coalesce onto an identical one
         that is queued, launching, or still executing on device.
@@ -627,7 +630,9 @@ class DeviceLane:
         ("" for any other program); ``hll``: the lowering of the
         program's HLL aggregates (``kernel.hll_lowering``), the ``hll=``
         tag and one ``hll.lowering.matmul|sort|scatter|pairs`` mark a
-        launch ("" for a program without one).
+        launch ("" for a program without one); ``hll_parts``: under
+        'sort', in how many parts a segment's keys are sorted
+        (``kernel.hll_sort_parts``), marked on ``hll.sort.parts`` a launch.
 
         ``cost_provider`` (optional, utilization plane): a zero-arg
         callable returning the plan's static XLA cost analysis (or
@@ -667,7 +672,7 @@ class DeviceLane:
                 self._hit()
             else:
                 d = _Dispatch(key, launch, pending, plan_digest, cost_provider, batch,
-                              trace, parent, program, t_submit, groupby, operands, expr, cells, blocks, hll)
+                              trace, parent, program, t_submit, groupby, operands, expr, cells, blocks, hll, hll_parts)
                 d.waiters.append(ticket)
                 self._by_key[key] = d
                 self._depth_tick_locked()
@@ -1241,6 +1246,8 @@ class DeviceLane:
                 self.metrics.meter(f"zone.blocks.{d.blocks}").mark()
             if d.hll and self.metrics is not None:
                 self.metrics.meter(f"hll.lowering.{d.hll}").mark()
+                if d.hll_parts:
+                    self.metrics.meter("hll.sort.parts").mark(d.hll_parts)
             self._set_inflight(1)
             error: Optional[BaseException] = None
             value: Any = None

@@ -1557,6 +1557,20 @@ class QueryExecutor:
 
         return BatchSpec(key, q_np, launch_batched, max_members=max_members)
 
+    @staticmethod
+    def _hll_keys_a_segment(plan: StaticPlan, staged, block_ids) -> int:
+        """The packed keys a segment hands a grouped distinctcounthll's
+        'sort' lowering (``kernel._group_state``): one a row of the view
+        the kernel runs over (the staged rows, or the zone tier's gathered
+        blocks), times the width of every multi-value group key, times
+        the widest multi-value argument's."""
+        from pinot_tpu.engine.zonemap import zone_block_rows
+
+        keys = staged.n_pad if block_ids is None else block_ids.shape[-1] * zone_block_rows()
+        for column, is_mv in zip(plan.group_by.columns, plan.group_by.col_is_mv):
+            keys *= staged.column(column).mv_pad if is_mv else 1
+        return keys * max(staged.column(a.column).mv_pad if a.is_mv else 1 for a in plan.aggs if a.kind == "hll")
+
     def _run_kernel(
         self, kernel, args, plan, staged, digest, block_ids, deadline,
         pdigest=None, cost: Optional[Dict[str, float]] = None, lane=None,
@@ -1596,7 +1610,7 @@ class QueryExecutor:
         # program); and how a zone-tier program
         # reads its candidate blocks: the ``blocks=`` tag and the
         # ``zone.blocks.*`` mark
-        from pinot_tpu.engine.kernel import groupby_cells, groupby_lowering, groupby_operands, hll_lowering, zone_blocks
+        from pinot_tpu.engine.kernel import groupby_cells, groupby_lowering, groupby_operands, hll_lowering, hll_sort_parts, zone_blocks
 
         groupby = groupby_lowering(plan) or ""
         operands = groupby_operands(plan) or ""
@@ -1604,6 +1618,9 @@ class QueryExecutor:
         # which lowering its HLL aggregates take, grouped or not: the
         # ``hll=`` tag and the ``hll.lowering.*`` mark ("" without one)
         hll = hll_lowering(plan) or ""
+        # under 'sort', in how many parts a segment's packed keys are
+        # sorted: the ``hll.sort.parts`` mark (0 under any other lowering)
+        hll_parts = hll_sort_parts(self._hll_keys_a_segment(plan, staged, block_ids)) if hll == "sort" else 0
         # its K x m cells and the rows sharing saved (the ``cells=`` tag,
         # ``groupby.slots.shared``), and how many aggregates take a
         # compound expression (the ``expr=`` tag)
@@ -1629,6 +1646,8 @@ class QueryExecutor:
                     self.metrics.meter(f"zone.blocks.{blocks}").mark()
                 if hll:
                     self.metrics.meter(f"hll.lowering.{hll}").mark()
+                if hll_parts:
+                    self.metrics.meter("hll.sort.parts").mark(hll_parts)
                 fetch, handle = launch()
             else:
                 # coalesce key: identical (plan, staged-table token, inputs
@@ -1667,6 +1686,7 @@ class QueryExecutor:
                         cells=cells,
                         blocks=blocks,
                         hll=hll,
+                        hll_parts=hll_parts,
                     )
                     fetch, handle = ticket.result(deadline)
                     # the lane thread delivered -> this worker runs again

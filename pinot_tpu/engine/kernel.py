@@ -63,35 +63,43 @@ _MATMUL_VALUE_CAP = int(_os.environ.get("PINOT_TPU_MATMUL_VALUE_CAP", str(1 << 1
 # chip round, record gone: ROADMAP D4)
 _MATMUL_HLL_CAP = int(_os.environ.get("PINOT_TPU_MATMUL_HLL_CAP", str(1 << 18)))
 # grouped HLL beyond the matmul gate lowers to one packed int32 key a
-# row, sorted a segment, and the sum of each (group, register) run's last
-# rank on the matrix unit (_hll_sorted_registers; bit-identical to
-# scatter-max) while (capacity * HLL_M * 64) fits int32; beyond that the
-# flat scatter runs.  Judged at 9,040 groups (chip runs, PR 42 and PR 44,
-# ClickBench hits by RegionID, 12 segments of 2^23 rows, 2.31M cells):
-# 225 ms of device time a query, of it the twelve sorts 168 (lax.sort of
-# the keys ALONE, unstable, over [12, 2^23]: 1.67 ns a row in the cell,
-# 1.70 alone in a program), the twelve windowed calls 20.7 (0.2 ns a row, one or two
-# windows a block of 8,192 rows), the keys' pass into cells and ranks 7,
-# the occupancy's contraction beside it 14.4.  PR 42 read 329 for the
-# sorts, 3.27 ns a row, and took it for the price of the keys: it was
-# the price of TWO operands.  lax.sort's default is stable, and a stable
-# sort on this chip is the unstable one with an operand of row numbers
-# carried as the last key (`sort(%keys, %iota)`, 537 MB of temporaries),
-# which orders nothing where the keys are the only operand.  ns a row by
-# the rows one sort spans (PR 44's probe, seed 4400001001, lax.sort alone
-# over the 100.7M packed keys), keys alone and unstable: 2^19 0.71, 2^20
-# 0.83, 2^21 0.97, 2^22 1.12, 2^23 1.70, all 2^26.6 flat 1.64; with the
-# row numbers: 2^19 1.40, 2^23 3.30, flat 3.25.  The keys' content costs
-# nothing (every row one key 1.69; plain ids of 17.6M users 1.70 and
-# 1.64).  So a row of 2^23 is no cheaper than the whole, but a row of
-# 2^22 is a third cheaper: ROADMAP S13.  Until PR 42 the reduce sorted
+# row, sorted a segment (in parts of _HLL_SORT_PART rows or fewer), and
+# the sum of each (group, register) run's last rank on the matrix unit
+# (_hll_sorted_registers; bit-identical to scatter-max) while
+# (capacity * HLL_M * 64) fits int32; beyond that the flat scatter runs.
+# Judged at 9,040 groups (chip runs, PR 42, PR 44 and PR 45, ClickBench
+# hits by RegionID, 12 segments of 2^23 rows, 2.31M cells): 225 ms of
+# device time a query while a segment was sorted as one row, of it the
+# twelve sorts 168 (lax.sort of the keys ALONE, unstable, over
+# [12, 2^23]: 1.67 ns a row in the cell, 1.70 alone in a program), the
+# twelve windowed calls 20.7 (0.2 ns a row, one or two windows a block of
+# 8,192 rows), the keys' pass into cells and ranks 7, the occupancy's
+# contraction beside it 14.4; since PR 45 the sort is of [24, 2^22] and
+# 110 (1.09 ns a row), the twenty-four calls 19.7, the query 172: the
+# sorted constants' comment below has the sweep.  PR 42 read 329 for the sorts, 3.27 ns a row, and
+# took it for the price of the keys: it was the price of TWO operands.
+# lax.sort's default is stable, and a stable sort on this chip is the
+# unstable one with an operand of row numbers carried as the last key
+# (`sort(%keys, %iota)`, 537 MB of temporaries), which orders nothing
+# where the keys are the only operand.  ns a row by the rows one sort
+# spans (PR 44's probe, seed 4400001001, lax.sort alone over the 100.7M
+# packed keys as [rows / span, span]), keys alone and unstable: 2^19
+# 0.71, 2^20 0.83, 2^21 0.97, 2^22 1.12, 2^23 1.70, all 2^26.6 flat
+# 1.64; with the row numbers: 2^19 1.40, 2^23 3.30, flat 3.25.  The
+# keys' content costs nothing (every row one key 1.69; plain ids of 17.6M
+# users 1.70 and 1.64).  What looks like a step between 2^23 and 2^22 is
+# the sublanes: the chip sorts eight rows at a time, so [12, 2^23] pays
+# for sixteen rows and [24, 2^22] for its 24 (PR 45: the same parts as
+# [12, 2, 2^22] cost 146.7 ms, 4 tiles of 8 rows, for 110.0 as
+# [24, 2^22], 3 tiles); a tile of eight rows falls 13 to 14% a halving of
+# the row, which is log^2 of the rows.  Until PR 42 the reduce sorted
 # every segment's keys at once and a searchsorted of one bound a cell
 # read the largest key: 1,217 ms of a 1,590 ms query (27 steps of a
 # gather the chip serialises, 19.5 ns an element a step; PR 41), and
 # packing the keys 44, now under 4.3.  The scatter it stands in for would
 # cost 13.4 ns a row, 1,350 ms (PR 37's reading; not run here).  Above
-# 12,256 groups the cells go in ranges (compiled for a v5e at 65,536, not
-# run on one); above 65,536: no cell
+# 12,256 groups the cells go in ranges, every part running every range
+# (compiled for a v5e at 65,536, not run on one); above 65,536: no cell
 _HLL_SORT_CAP = int(_os.environ.get("PINOT_TPU_HLL_SORT_CAP", str(1 << 16)))
 
 
@@ -120,9 +128,10 @@ def hll_lowering(plan: StaticPlan) -> Optional[str]:
                _MATMUL_HLL_CAP / 16,384 groups (16).
     'sort':    a group-by beyond that, up to _HLL_SORT_CAP groups: one
                packed int32 key a row, sorted a segment where they are
-               built; a register is the sum of its (group, register)
-               run's last rank, added on the matrix unit by the windowed
-               contraction over rows in key order
+               built, in hll_sort_parts parts; a register is the sum of
+               its (group, register) run's last rank, added on the
+               matrix unit by the windowed contraction over rows in key
+               order, and the largest of its parts' sums
                (_hll_sorted_registers).
     'scatter': the serialised scatter-max: a group-by over more groups
                than the packed key holds, and every aggregate on the CPU
@@ -150,6 +159,15 @@ def hll_lowering(plan: StaticPlan) -> Optional[str]:
     if capacity * cells <= _MATMUL_HLL_CAP:
         return "matmul"
     return "sort" if capacity <= _HLL_SORT_CAP else "scatter"
+
+
+def hll_sort_parts(rows: int) -> int:
+    """In how many parts of equal length the 'sort' lowering sorts a
+    segment of ``rows`` packed keys (_hll_sorted_registers): as few as
+    leave a part at most ``_HLL_SORT_PART`` rows.  Consulted by the
+    kernel builder and by the launch's ``hll.sort.parts`` mark, which
+    must agree."""
+    return max(1, -(-rows // _HLL_SORT_PART))
 
 
 # Dense group-by capacities above MATMUL_GROUP_CAP ride the two-level
@@ -215,6 +233,21 @@ _RADIX_BLOCK_MAX = 8192  # rows a step: 18.8, 21.3, 27.6 ms at 8192, 4096, 2048 
 # capacity of 307,000 to 520,000 with two or more sums would)
 _SORTED_BLOCK = 8192
 _SORTED_WINDOW = 64
+# rows one lax.sort of a grouped distinct count's packed keys spans at
+# the most (hll_sort_parts; whole blocks of _SORTED_BLOCK): a longer
+# segment is sorted in equal parts, whose registers fold by max.  Chip
+# run, PR 45, seed 4400001001, _hll_sorted_registers whole under vmap
+# over [12, 2^23] keys at 9,040 groups, ms (no row filtered; three in
+# ten), and of it the sort and the windowed calls: one part of 2^23
+# 195.6 (192.6): 167.9, 12.5; parts of 2^22 142.6 (139.4): 110.0, 16.5;
+# 2^21 141.0 (134.9): 95.2, 29.0; 2^20 139.1 (137.5): 81.7, 40.7; the
+# passes about them 15 to 17 at every length.  What a shorter part gives
+# the sort its calls take back (a block of sorted rows spans 35, 71, 141
+# sublanes of cells under a window of 64, for 18 as one part), so the
+# three lie within 3.5 ms and the longest has the fewest call sites (a
+# part its own: 2, 4, 8; times the ranges above 12,256 groups) and the
+# least beside the keys
+_HLL_SORT_PART = 1 << 22
 _SORTED_ACC_BYTES = 12 << 20
 _SORTED_COLS_MAX = 3
 
@@ -591,15 +624,67 @@ def _hll_sorted_registers(packed, capacity: int):
     The keys are sorted where they were built, unstably: they are the
     sort's one operand, so rows that compare equal are equal in every bit
     and a stable sort's row-number operand would order nothing (on the
-    chip it doubles the sort: PR 44).  A row is the last of its
-    (group, register) run where ``key >> 6`` differs from the next row's;
-    there its weight is the rank ``key & 63``, which is the run's largest
-    (the rank rides the key's low bits), and everywhere else 0.  So
-    exactly one row a live cell carries a weight, and a register is the
-    SUM of its cell's weights: the windowed contraction over the rows in
-    cell order (_sorted_window_sums), one part a row, since a rank is at
-    most 63 and exact in bfloat16.  The sentinel's cell lies past the last
-    one and counts nowhere.
+    chip it doubles the sort: PR 44).  A segment of more rows than
+    ``_HLL_SORT_PART`` is cut into ``hll_sort_parts`` parts of equal
+    length (whole blocks each, the padding sentinels, so no part is
+    padding alone) and the ONE sort runs along a part's rows
+    (_sort_in_parts): shorter rows sort cheaper, and two parts of twelve
+    segments fill the chip's sublanes where one leaves a quarter empty
+    (PR 45).  A register is the largest rank any row of its cell carries,
+    so each part gives registers of its own by what follows
+    (_hll_run_end_sums) and the parts fold by ``max`` as the segments'
+    do: no merge, bit for bit the scatter-max's in any cut.  A part's
+    float32 sums are cast to uint8 before the fold.  A segment of one
+    part is the program it was before there were parts."""
+    parts = hll_sort_parts(packed.shape[0])
+    keys = _whole_blocks(packed, [], parts * _SORTED_BLOCK, _PAIR_SENTINEL)[0]
+    if parts == 1:
+        return _hll_run_end_sums(jax.lax.sort(keys, is_stable=False), capacity)
+    return functools.reduce(jnp.maximum, [_hll_run_end_sums(part, capacity) for part in _sort_in_parts(parts)(keys)])
+
+
+@functools.lru_cache(maxsize=None)
+def _sort_in_parts(parts: int):
+    """keys [rows] -> ``parts`` arrays [rows / parts], each a slice of the
+    keys in ascending order, by ONE unstable ``lax.sort`` of one operand.
+
+    Under the segments' ``vmap`` the operand is [parts x segments, rows a
+    part], a row a (part, segment), and not [segments, parts, rows a
+    part]: the chip sorts eight rows at a time, one to a sublane, so
+    twelve segments cost sixteen rows' time in every part, and 24 rows cost
+    24 (PR 45: over 12 x 2^23 keys in parts of 2^22, 146.7 ms as
+    [12, 2, 2^22] and 110.0 as [24, 2^22]).  ``vmap`` alone cannot fold its
+    own axis into the rows, so the batched form is written out here
+    (``custom_vmap``); what it returns is what ``vmap`` of the plain form
+    would.  The parts are slices put side by side, never a reshape: a
+    reshape of [segments, rows] to [segments, parts, rows a part] moves
+    the parts onto the sublanes and costs the chip's compiler a minute."""
+    @jax.custom_batching.custom_vmap
+    def sort_in_parts(keys):
+        return tuple(jax.lax.sort(jnp.stack(jnp.split(keys, parts)), is_stable=False))
+
+    @sort_in_parts.def_vmap
+    def of_segments(segments, in_batched, keys):  # keys [segments, rows]
+        rows = jax.lax.sort(jnp.concatenate(jnp.split(keys, parts, axis=1)), is_stable=False)  # [parts x segments, rows a part]
+        return tuple(jnp.split(rows, parts)), (True,) * parts
+
+    return sort_in_parts
+
+
+def _hll_run_end_sums(keys, capacity: int):
+    """Registers [capacity, HLL_M] uint8 of packed keys in ascending
+    order, whole blocks of ``_SORTED_BLOCK`` (_hll_sorted_registers: a
+    segment's, or one part's of it).
+
+    A row is the last of its (group, register) run where ``key >> 6``
+    differs from the next row's; there its weight is the rank
+    ``key & 63``, which is the run's largest (the rank rides the key's
+    low bits), and everywhere else 0.  So exactly one row a live cell
+    carries a weight, and a register is the SUM of its cell's weights:
+    the windowed contraction over the rows in cell order
+    (_sorted_window_sums), one part a row, since a rank is at most 63 and
+    exact in bfloat16.  The sentinel's cell lies past the last one and
+    counts nowhere.
 
     The accumulator is 4 B a cell in VMEM.  Cells past ``_SORTED_ACC_BYTES``
     of it (over 12,256 groups at 256 registers) go in ranges, a call a
@@ -607,7 +692,6 @@ def _hll_sorted_registers(packed, capacity: int):
     to its first cell with a weight of 0 and those over it to its
     sentinel, which keeps them in order."""
     cells = capacity * config.HLL_M
-    keys = jax.lax.sort(_whole_blocks(packed, [], _SORTED_BLOCK, _PAIR_SENTINEL)[0], is_stable=False)
     cell = keys >> 6
     ends = jnp.concatenate([cell[1:] != cell[:-1], jnp.ones(1, bool)])
     # cells a call: the sublanes _SORTED_ACC_BYTES holds, less the last window's, in whole tiles

@@ -698,8 +698,13 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "unit (ungrouped on the chip; up to 16 groups)",
     "hll.lowering.sort": "launches whose grouped distinctcounthll packs "
     "(group, register, rank) into one int32 key a row, sorts them a "
-    "segment and sums each run's last rank on the matrix unit (17 to "
-    "65,536 groups, on the chip)",
+    "segment (in parts past _HLL_SORT_PART rows: hll.sort.parts) and "
+    "sums each run's last rank on the matrix unit (17 to 65,536 groups, "
+    "on the chip)",
+    "hll.sort.parts": "on a launch that takes the sort lowering, the "
+    "parts a segment's packed keys are sorted in, marked by the count "
+    "(engine/kernel.py hll_sort_parts: 1 up to _HLL_SORT_PART keys a "
+    "segment, so this meter over hll.lowering.sort is the parts a launch)",
     "hll.lowering.scatter": "launches whose distinctcounthll registers "
     "come from the serialised scatter-max (more groups than the packed "
     "key holds; every form on the CPU backend)",

@@ -162,6 +162,140 @@ def test_cells_past_one_calls_accumulator_go_in_ranges(monkeypatch, capacity, ro
 
 
 # ---------------------------------------------------------------------------
+# a segment of more rows than one part holds (PR 45): the parts fold by max
+# ---------------------------------------------------------------------------
+PART = BLOCK  # the part length patched small: a segment of 2 * BLOCK + 1 rows is sorted in three parts
+CELL = (23 * M + 200) << 6  # one (group, register) cell of capacity 40, rank 0
+
+
+def _quiet(rng, rows, capacity=40):
+    """Keys whose ranks stay under 32, so that a planted rank above it is its cell's largest."""
+    packed = keys(rng, capacity, rows)
+    return np.where(packed == SENTINEL, packed, packed & ~np.int32(32))
+
+
+def _planted(rng, rows, ranks_by_part):
+    packed = _quiet(rng, rows)
+    packed[packed >> 6 == CELL >> 6] = SENTINEL  # the cell's rows are the planted ones alone
+    length = BLOCK * -(-rows // (BLOCK * -(-rows // PART)))  # rows a part, as the kernel cuts them
+    for part, rank in ranks_by_part.items():
+        packed[part * length + int(rng.integers(0, min(length, rows - part * length)))] = CELL | rank
+    return 40, packed
+
+
+def a_part_wholly_filtered(rng):
+    capacity, packed = 40, keys(rng, 40, 3 * PART)
+    packed[PART:2 * PART] = SENTINEL  # the middle part sorts sentinels alone and gives registers of zero
+    return capacity, packed
+
+
+PARTED = {
+    "the_largest_rank_in_the_first_part": (3, lambda rng: _planted(rng, 3 * PART, {0: 61, 1: 40, 2: 33})),
+    "the_largest_rank_in_the_last_part": (3, lambda rng: _planted(rng, 3 * PART, {0: 33, 1: 40, 2: 61})),
+    "a_cell_in_every_part_with_equal_ranks": (3, lambda rng: _planted(rng, 3 * PART, {0: 47, 1: 47, 2: 47})),
+    "a_ragged_count": (3, lambda rng: (300, keys(rng, 300, 2 * PART + 4_097))),
+    "one_row_past_a_part": (2, lambda rng: (40, keys(rng, 40, PART + 1))),
+    "a_part_wholly_filtered": (3, a_part_wholly_filtered),
+    "every_row_filtered": (3, lambda rng: (40, np.full(3 * PART, SENTINEL, np.int32))),
+    "five_parts": (5, lambda rng: (17, keys(rng, 17, 4 * PART + 1))),
+    "five_parts_the_last_of_one_row": (5, lambda rng: _planted(rng, 4 * PART + 1, {4: 61, 0: 9})),
+}
+
+
+def _sorts(text):
+    """(operands, dimension, operand type) of every sort in a lowered text."""
+    return re.findall(r'stablehlo\.sort"?\(([^)]*)\) <\{dimension = (\d+) : i64, is_stable = false\}>.*?\}\) : \((tensor<[^>]*>)\)', text, re.S)
+
+
+def _parted_registers(monkeypatch, packed, capacity):
+    """The registers with the part length patched small, what the one
+    sort was handed and the rows of each part summed."""
+    monkeypatch.setattr(kernel_mod, "_HLL_SORT_PART", PART)
+    summed, inner = [], kernel_mod._hll_run_end_sums
+    monkeypatch.setattr(kernel_mod, "_hll_run_end_sums", lambda part, cap: summed.append(part.shape) or inner(part, cap))
+    run = jax.jit(lambda p: kernel_mod._hll_sorted_registers(p, capacity))
+    sorts = _sorts(run.lower(jax.ShapeDtypeStruct(packed.shape, jnp.int32)).as_text())
+    traced = list(summed)  # one trace's worth: the call below may or may not trace again
+    return np.asarray(run(packed)), sorts, traced
+
+
+@pytest.mark.parametrize("case", sorted(PARTED))
+def test_a_segment_of_several_parts_gives_the_scatters_registers(monkeypatch, case):
+    """Each part sorted alone gives registers of its own and the parts
+    fold by ``max``, as the segments do: the scatter-max's bit for bit in
+    any cut, wherever a cell's largest rank lies."""
+    parts, make = PARTED[case]
+    capacity, packed = make(np.random.default_rng(45))
+    got, sorts, summed = _parted_registers(monkeypatch, packed, capacity)
+    assert kernel_mod.hll_sort_parts(packed.size) == parts
+    # ONE sort of one operand, along a part's rows; the parts equal in length, whole blocks, and none of padding alone
+    length = summed[0][0]
+    assert summed == [(length,)] * parts and length % BLOCK == 0 and length <= PART
+    assert (parts - 1) * length < packed.size <= parts * length
+    assert len(sorts) == 1 and "," not in sorts[0][0] and sorts[0][1:] == ("1", f"tensor<{parts}x{length}xi32>"), sorts
+    assert got.dtype == np.uint8 and got.shape == (capacity, M)
+    assert np.array_equal(got, scatter_max(packed, capacity))
+    if case.startswith(("the_largest", "a_cell", "five_parts_the")):
+        assert got.reshape(-1)[CELL >> 6] == (47 if case.startswith("a_cell") else 61)  # the planted rank, from whichever part
+
+
+@pytest.mark.parametrize("capacity,parts", [(RANGE_GROUPS + 1, 3), (3 * RANGE_GROUPS, 2)])
+def test_cells_in_ranges_and_rows_in_parts(monkeypatch, capacity, parts):
+    """Over one call's accumulator AND over one part's rows: every part
+    runs every range's call over its own sorted rows."""
+    monkeypatch.setattr(kernel_mod, "_SORTED_ACC_BYTES", (kernel_mod._SORTED_WINDOW + 48) * 128 * 4)
+    packed = keys(np.random.default_rng(capacity), capacity, (parts - 1) * PART + 1_000)
+    calls, inner = [], kernel_mod._sorted_window_sums
+    monkeypatch.setattr(kernel_mod, "_sorted_window_sums", lambda idx, cols, cells, *a: calls.append(cells) or inner(idx, cols, cells, *a))
+    got, sorts, summed = _parted_registers(monkeypatch, packed, capacity)
+    assert len(summed) == parts and len(sorts) == 1
+    assert len(calls) == parts * -(-capacity // RANGE_GROUPS) and sum(calls) == parts * capacity * M
+    assert np.array_equal(got, scatter_max(packed, capacity))
+
+
+@pytest.mark.parametrize("segments,rows,queries", [(3, 2 * PART + 1, 0), (12, PART + 5, 0), (5, 4 * PART + 77, 0), (3, 2 * PART + 9, 2)])
+def test_under_the_segments_vmap_a_row_of_the_sort_is_a_part_of_a_segment(monkeypatch, segments, rows, queries):
+    """``vmap`` of the plain form would sort [segments, parts, rows a
+    part]; the batched form written out (``_sort_in_parts``) sorts
+    [parts x segments, rows a part], which fills the chip's sublanes, and
+    hands back what ``vmap`` would: every segment's registers are the
+    scatter-max's, also with the queries' ``vmap`` around the segments'."""
+    monkeypatch.setattr(kernel_mod, "_HLL_SORT_PART", PART)
+    rng = np.random.default_rng(segments * rows)
+    packed = np.stack([keys(rng, 40, rows) for _ in range(segments * max(queries, 1))]).reshape((queries,) * bool(queries) + (segments, rows))
+    run = jax.vmap(lambda p: kernel_mod._hll_sorted_registers(p, 40))
+    run = jax.jit(jax.vmap(run) if queries else run)
+    parts = kernel_mod.hll_sort_parts(rows)
+    length = BLOCK * -(-rows // (parts * BLOCK))
+    leading = f"{queries}x" * bool(queries)
+    assert [(dim, shape) for _, dim, shape in _sorts(run.lower(jax.ShapeDtypeStruct(packed.shape, jnp.int32)).as_text())] == \
+        [(str(1 + bool(queries)), f"tensor<{leading}{parts * segments}x{length}xi32>")]
+    assert np.array_equal(np.asarray(run(packed)).reshape(-1, 40, M), np.stack([scatter_max(p, 40) for p in packed.reshape(-1, rows)]))
+
+
+@pytest.mark.parametrize("rows,parts", [(1, 1), (1 << 22, 1), ((1 << 22) + 1, 2), (1 << 23, 2), ((1 << 23) + 1, 3), (5 << 22, 5)])
+def test_as_few_parts_as_leave_a_part_the_length_the_sweep_chose(rows, parts):
+    assert kernel_mod._HLL_SORT_PART == 1 << 22 and kernel_mod._HLL_SORT_PART % BLOCK == 0
+    assert kernel_mod.hll_sort_parts(rows) == parts
+
+
+def test_a_segment_of_one_part_is_the_program_it_was(monkeypatch):
+    """Up to ``_HLL_SORT_PART`` rows nothing is cut: the lowered text is
+    the one a part length no segment reaches gives, the sort's operand the
+    segment's padded keys as one row, ``is_stable = false`` once."""
+    lowered = lambda: jax.jit(lambda p: kernel_mod._hll_sorted_registers(p, 9_040)).lower(jax.ShapeDtypeStruct((3 * BLOCK + 5,), jnp.int32)).as_text()
+    text = lowered()
+    monkeypatch.setattr(kernel_mod, "_HLL_SORT_PART", 1 << 40)
+    assert text == lowered()
+    monkeypatch.setattr(kernel_mod, "_HLL_SORT_PART", 4 * BLOCK)  # the segment's padded rows, to the row
+    assert text == lowered()
+    assert [(dim, shape) for _, dim, shape in _sorts(text)] == [("0", f"tensor<{4 * BLOCK}xi32>")]
+    assert text.count("is_stable = false") == 1 and "is_stable = true" not in text
+    monkeypatch.setattr(kernel_mod, "_HLL_SORT_PART", 2 * BLOCK)
+    assert text != lowered()
+
+
+# ---------------------------------------------------------------------------
 # through the kernel builder: a segment's state and the fold
 # ---------------------------------------------------------------------------
 QUERIES = {
@@ -254,6 +388,61 @@ def test_the_zone_tier_hands_a_sorted_hll_its_gathered_view(monkeypatch):
             c.cache_clear()
     assert launched == [("scatter", "inplace", True), ("sort", "gathered", True)]
     assert replies[0] == replies[1] and '"value": "0"' not in replies[0]
+
+
+PARTED_QUERIES = {k: v for k, v in QUERIES.items() if k != "under_a_filter"}  # its filter leaves a view of under one part
+PARTED_QUERIES["through_the_zone_tier"] = "SELECT distinctcounthll(dimLong) FROM testTable WHERE metInt < {cut} GROUP BY dimStr TOP 10"
+
+
+@pytest.fixture(scope="module")
+def parted_segments():
+    """Two segments of 18,000 rows clustered on metInt, and the value under
+    which 47.5% of the rows lie: a filter on it keeps 67 of a segment's 141
+    blocks of 128 rows, a gathered view of 128 blocks, two parts of ``PART``."""
+    schema = make_test_schema(with_mv=True)
+    rows = sorted(random_rows(schema, 2 * 18_000, seed=45, cardinality=40), key=lambda r: r["metInt"])
+    return [build_segment(schema, rows[i::2], "testTable", f"parted{i}") for i in range(2)], rows[int(0.475 * len(rows))]["metInt"]
+
+
+@pytest.mark.parametrize("query", sorted(PARTED_QUERIES))
+def test_the_launch_marks_the_parts_the_kernel_cuts(monkeypatch, parted_segments, query):
+    """``hll.sort.parts`` is ``hll_sort_parts`` of the keys a segment hands
+    the lowering, which the launch works out from the staged table (a key
+    a row of the view, times the multi-value widths) and the kernel reads
+    off its operand: the two must agree, and the reply is the scatter's."""
+    import json
+
+    from pinot_tpu.engine.reduce import reduce_to_response
+
+    for name, value in (("PINOT_TPU_HLL_PRESENCE", "0"), ("PINOT_TPU_INVINDEX", "0"), ("PINOT_TPU_ZONE_BLOCK", "128")):
+        monkeypatch.setenv(name, value)
+    monkeypatch.setattr(kernel_mod, "_HLL_SORT_PART", PART)
+    segs, cut = parted_segments
+    request = optimize_request(parse_pql(PARTED_QUERIES[query].format(cut=cut)))
+    handed, inner = [], kernel_mod._hll_sorted_registers
+    monkeypatch.setattr(kernel_mod, "_hll_sorted_registers", lambda packed, cap: handed.append(packed.shape[0]) or inner(packed, cap))
+    cached = (kernel_mod.make_table_kernel, kernel_mod.make_packed_table_kernel, kernel_mod.make_block_table_kernel,
+              kernel_mod.make_packed_block_table_kernel)
+    replies, marked = [], []
+    try:
+        for forced in ("0", "1"):
+            monkeypatch.setenv("PINOT_TPU_GROUPBY_MATMUL", forced)
+            for c in cached:
+                c.cache_clear()
+            executor = QueryExecutor()
+            reply = reduce_to_response(request, [executor.execute(segs, request)])
+            assert not reply.exceptions, reply.exceptions
+            replies.append(json.dumps(reply.to_json()["aggregationResults"], sort_keys=True))
+            marked.append((executor.metrics.meter("hll.lowering.sort").count, executor.metrics.meter("hll.sort.parts").count))
+    finally:
+        for c in cached:
+            c.cache_clear()
+    (keys_a_segment,) = set(handed)  # one aggregate, traced once a program
+    parts = kernel_mod.hll_sort_parts(keys_a_segment)
+    assert parts > 1 and marked == [(0, 0), (1, parts)]
+    assert replies[0] == replies[1] and '"value": "0"' not in replies[0]
+    if query == "through_the_zone_tier":
+        assert keys_a_segment == 128 * 128  # the gathered view's rows (67 blocks padded to 128), not the segment's 18,000
 
 
 def test_no_reducer_names_the_lowering():

@@ -70,7 +70,8 @@ def test_radix_contraction_compiles_for_v5e(one_chip, monkeypatch, shape):
 
 
 # (groups, segments, packed keys a segment): ClickBench's regions as hits_distinct_users_closed holds them
-# (one call: 9.3 MB of accumulator), the lowering's cap (67 MB of cells: four ranges), a ragged row count
+# (a segment in two parts, each one call: 9.3 MB of accumulator), the lowering's cap (67 MB of cells: six ranges a part),
+# a ragged row count of one part
 HLL_SHAPES = {
     "hits_regions_9040": (9_040, 12, 1 << 23),
     "cap_65536_in_ranges": (1 << 16, 2, 1 << 23),
@@ -80,9 +81,10 @@ HLL_SHAPES = {
 
 @pytest.mark.parametrize("shape", sorted(HLL_SHAPES))
 def test_hll_run_ends_compile_for_v5e(one_chip, monkeypatch, shape):
-    """The 'sort' lowering of a grouped distinctcounthll: a sort a
-    segment and the windowed contraction over each (group, register)
-    run's last rank, folded by maximum."""
+    """The 'sort' lowering of a grouped distinctcounthll: ONE sort of a
+    segment's keys in parts of ``_HLL_SORT_PART`` rows or fewer, and the
+    windowed contraction over each (group, register) run's last rank a
+    part, the parts and the segments folded by maximum."""
     from pinot_tpu.engine import config, kernel as kernel_mod
 
     K, S, n = HLL_SHAPES[shape]
@@ -90,20 +92,27 @@ def test_hll_run_ends_compile_for_v5e(one_chip, monkeypatch, shape):
     table = lambda packed: jnp.max(jax.vmap(lambda p: kernel_mod._hll_sorted_registers(p, K))(packed), axis=0)
     with jax.enable_x64(False):
         compiled = jax.jit(table).lower(jax.ShapeDtypeStruct((S, n), jnp.int32, sharding=one_chip)).compile()
-    calls = -(-K * config.HLL_M * 4 // kernel_mod._SORTED_ACC_BYTES)
-    assert (calls > 1) == shape.endswith("in_ranges")
-    # the keys are the sort's one operand, sorted in place: a stable sort would carry `sort(%keys, %iota)`, a row of row numbers
+    ranges = -(-K * config.HLL_M * 4 // kernel_mod._SORTED_ACC_BYTES)
+    parts = kernel_mod.hll_sort_parts(n)
+    assert (ranges > 1) == shape.endswith("in_ranges") and (parts > 1) == (n > kernel_mod._HLL_SORT_PART) == (shape != "k17_ragged_rows")
+    # the keys are the sort's one operand, sorted in place along a part's rows: a stable sort would carry
+    # `sort(%keys, %iota)`, a row of row numbers.  A row of the operand is a part of a segment ([parts x segments, rows a
+    # part]: the batched form _sort_in_parts writes out), so 24 rows fill three tiles of eight sublanes where
+    # [12, 2, 2^22] would pad each part's twelve to sixteen (PR 45: 110.0 ms for 146.7 on the chip)
     text = compiled.as_text()
-    sorts = re.findall(r"\bsort\(([^)]*)\)", text)
-    assert len(sorts) == 1 and "," not in sorts[0] and "iota" not in sorts[0], sorts
+    sorts = re.findall(r"(\S+) sort\(([^)]*)\), dimensions=\{(\d)\}", text)
+    assert len(sorts) == 1 and "," not in sorts[0][1] and "iota" not in sorts[0][1], sorts
+    padded = -(-n // (parts * kernel_mod._SORTED_BLOCK)) * kernel_mod._SORTED_BLOCK
+    assert sorts[0][0].startswith(f"s32[{parts * S},{padded}]{{1,0:") and sorts[0][2] == "1", sorts
     assert "is_stable=true" not in text
-    # sorted keys, cells and ranks (a range its own) and every segment's accumulator before the fold.  Without the row
-    # numbers the bound falls by half a row of int32, which is what the three compiled programs allow, not by the whole
-    # one: in rows beside the accumulators, 3.78 to 3.45 at 9,040 groups (1,744.9 to 1,610.7 MB in all: the peak is not
-    # the sort's), 13.01 on both sides at 65,536 in ranges (the peak is a range's), 1.01 to 0 at 17
-    rows = S * -(-n // kernel_mod._SORTED_BLOCK) * kernel_mod._SORTED_BLOCK * 4
-    assert compiled.memory_analysis().temp_size_in_bytes <= (1.5 + 2 * calls) * rows + 2 * S * K * config.HLL_M * 4 + (64 << 20)
-    assert text.count("tpu_custom_call") >= calls and "while" in text
+    # sorted keys, a part's copy of its rows, cells and ranks (a range its own) and the accumulators before the fold.  PR
+    # 44's bound was 1.5 rows of int32 and two a range beside the accumulators; the parts' slices of the sorted rows are
+    # copies, so 2: in rows beside the accumulators the three compiled programs hold 3.95 at 9,040 groups (1,812.1 MB in
+    # all; as one part 3.45 and 1,610.7: the peak is not the sort's), 12.07 at 65,536 in ranges (1,078.4 MB; as one part
+    # 13.01 and 1,141.5: a range's cells and ranks span a part, not a segment), 0 at 17 (one part: unchanged)
+    rows = S * parts * padded * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= (2 + 2 * ranges) * rows + 2 * S * K * config.HLL_M * 4 + (64 << 20)
+    assert text.count("tpu_custom_call") >= ranges * parts and "while" in text
 
 
 # the open cell's two group-bys (benchmark/traffic/suite_open.json: k6, q6) and TPC-H Q1 as the
