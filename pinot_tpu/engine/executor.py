@@ -26,10 +26,10 @@ import numpy as np
 from pinot_tpu.common.request import BrokerRequest, group_sort_ascending
 from pinot_tpu.common.schema import DataType
 from pinot_tpu.common.values import render_value
-from pinot_tpu.engine import config
+from pinot_tpu.engine import config, ladder
 from pinot_tpu.engine.context import TableContext, get_table_context
 from pinot_tpu.engine.device import StagedTable, get_staged
-from pinot_tpu.engine.plan import StaticPlan, build_query_inputs, build_static_plan
+from pinot_tpu.engine.plan import StaticPlan
 from pinot_tpu.engine.pruner import prune_segments
 from pinot_tpu.engine.results import (
     AggPartial,
@@ -311,7 +311,6 @@ class QueryExecutor:
         # launch + fetch run inline (the serial path, byte-identical
         # results — the differential suite holds the two together)
         self.lane = lane
-        self._sharded_kernels: Dict[Any, Any] = {}
         self._mesh_shardings: Dict[Any, Any] = {}  # mesh id -> (NamedSharding, placement key)
         self._qinput_cache: "OrderedDict[Any, Any]" = OrderedDict()
         self._qinput_cache_bytes = 0
@@ -516,7 +515,7 @@ class QueryExecutor:
             res = self._empty_result(request, total_docs)
         else:
             sel_columns = (
-                self._resolve_selection_columns(request, live[0])
+                ladder.selection_columns(request, live[0])
                 if request.is_selection
                 else None
             )
@@ -560,13 +559,6 @@ class QueryExecutor:
             sh = NamedSharding(mesh, P(tuple(mesh.axis_names)))
             placed = self._mesh_shardings[key] = (sh, placement_key(sh))
         return placed
-
-    def _mesh_key(self, mesh) -> Any:
-        """Hashable kernel-cache component for a mesh (per-lane meshes
-        must not share compiled sharded kernels)."""
-        if mesh is None:
-            return None
-        return tuple(getattr(d, "id", i) for i, d in enumerate(mesh.devices.flat))
 
     def _phase(self, name: str, **tags) -> boundary:
         """One executor boundary, unstarted (utils/trace.py): the
@@ -734,26 +726,11 @@ class QueryExecutor:
         mesh,
         use: _Use,
     ) -> IntermediateResult:
-        prep = use.prepared
-
         def scope():
             _check_expression_columns(request, live[0])
-            needed = set(request.referenced_columns())
-            sel_columns: Optional[List[str]] = None
-            if request.is_selection:
-                sel_columns = self._resolve_selection_columns(request, live[0])
-                needed.update(sel_columns)
-            pad_to = 0
-            if mesh is not None:
-                n = int(mesh.devices.size)
-                pad_to = -(-len(live) // n) * n
-            # columns used ONLY by doc-range predicates on sorted columns
-            # never reach the device (the kernel compares row ids against
-            # host-computed doc bounds) — skip staging them entirely
-            needed -= self._docrange_only_columns(request, live, sel_columns)
-            return sum(s.num_docs for s in live), tuple(sorted(needed)), sel_columns, pad_to
+            return ladder.scope(request, live, mesh)
 
-        total_docs, needed, sel_columns, pad_to = prep.once("scope", scope)
+        use.prepared.once("scope", scope)
         # looked up on every query (its own cache, by the segments'
         # tokens): an entry that held it would hold the segments
         ctx = get_table_context(live, build_timer=self.metrics.timer("phase.globalDictBuild"))
@@ -763,81 +740,106 @@ class QueryExecutor:
         # only while some audit quarantine is live
         audit_digest = shape if self._has_audit_poison else None
 
-        # selective predicates answer from host postings in O(matches)
-        # (engine/invindex_path.py — BitmapBasedFilterOperator analog);
-        # unselective ones fall through to the device scan below
-        if not self._audit_blocked(audit_digest, "postings"):
-            from pinot_tpu.engine import invindex_path
+        # the ladder (engine/ladder.py): the first tier that accepts
+        # answers; a blocked or declining one falls through to the next
+        for tier in ladder.TIERS:
+            if mesh is not None and not tier.on_mesh:
+                continue
+            if self._audit_blocked(audit_digest, tier.name):
+                if tier.name != "device":
+                    continue
+                # wrong-answer quarantine: unlike a device FAILURE (which
+                # retries), a tier caught lying never gets another attempt
+                # inside the TTL — straight to the host oracle path
+                return self._host_failover("auditQuarantine", live, request, ctx, ph, use)
+            res = self._SERVE[tier.name](self, tier, live, request, deadline, ctx, ph, sel, mesh, use)
+            if res is not None:
+                return res
+        raise AssertionError("the device tier answers or raises")
 
-            kept = prep.values.get("postings")  # None: not decided yet; (): declined
-            state = invindex_path.held_state(kept) if kept else None
-            if state is None and kept != ():  # not decided, or the postings it took were released since
-                state = invindex_path.index_path_decision(request, live, ctx, total_docs)[1]
-                prep.values["postings"] = invindex_path.hold_state(state) if state is not None else ()
-                self._prepared_count(use)
+    def _host_failover(self, reason: str, live, request, ctx, ph: phases, use: _Use) -> IntermediateResult:
+        from pinot_tpu.engine.host_fallback import execute_host
+
+        total_docs, _needed, sel_columns, _pad_to = use.prepared.values["scope"]
+        self._heal_mark("hostFailovers", reason=reason)
+        ph.enter("hostFailover")
+        res = execute_host(live, ctx, request, total_docs, sel_columns)
+        return self._finish_tier(res, request, "host")
+
+    def _serve_postings(self, tier, live, request, deadline, ctx, ph, sel, mesh, use) -> Optional[IntermediateResult]:
+        from pinot_tpu.engine import invindex_path
+
+        prep = use.prepared
+        total_docs, _needed, sel_columns, _pad_to = prep.values["scope"]
+        kept = prep.values.get(tier.prepared)  # None: not decided yet; (): declined
+        state = invindex_path.held_state(kept) if kept else None
+        if state is None and kept != ():  # not decided, or the postings it took were released since
+            state = tier.decide(request, live, ctx, total_docs, mesh)[1]
+            prep.values[tier.prepared] = invindex_path.hold_state(state) if state is not None else ()
+            self._prepared_count(use)
+        if state is None:
+            return None
+        ires = invindex_path.run_index_path(state, request, live, ctx, total_docs, sel_columns)
+        ph.relabel(tier.phase)
+        return self._finish_tier(ires, request, tier.name)
+
+    def _serve_bitsliced(self, tier, live, request, deadline, ctx, ph, sel, mesh, use) -> Optional[IntermediateResult]:
+        # A device fault here falls through to the scan tier's healing
+        # loop instead of failing the query on an optimization tier.
+        from pinot_tpu.engine.bitsliced import run_bitsliced_path
+
+        total_docs = use.prepared.values["scope"][0]
+        try:
+            state = use.prepared.once(
+                tier.prepared, lambda: tier.decide(request, live, ctx, total_docs, mesh)[1]
+            )
+            bres = None
             if state is not None:
-                ires = invindex_path.run_index_path(state, request, live, ctx, total_docs, sel_columns)
-                ph.relabel("indexPath")
-                return self._finish_tier(ires, request, "postings")
-
-        # mid-selectivity scalar aggregations the postings tier just
-        # declined evaluate as O(bit-width) bulk-bitwise passes over
-        # bit-sliced planes (engine/bitsliced.py) — single-device only;
-        # mesh placements keep the sharded scan path.  A device fault
-        # here falls through to the scan section's healing loop below
-        # instead of failing the query on an optimization tier.
-        if mesh is None and not self._audit_blocked(audit_digest, "bitsliced"):
-            from pinot_tpu.engine.bitsliced import bitsliced_decision, run_bitsliced_path
-
-            try:
-                state = prep.once(
-                    "bitsliced", lambda: bitsliced_decision(request, live, ctx, total_docs)[1]
+                bres = run_bitsliced_path(
+                    self, state, request, live, ctx, total_docs, deadline,
+                    lane=sel.lane if sel is not None else None,
+                    lane_index=sel.index if sel is not None else 0,
                 )
-                bres = None
-                if state is not None:
-                    bres = run_bitsliced_path(
-                        self, state, request, live, ctx, total_docs, deadline,
-                        lane=sel.lane if sel is not None else None,
-                        lane_index=sel.index if sel is not None else 0,
-                    )
-            except Exception as e:
-                from pinot_tpu.engine.dispatch import (
-                    LaneClosedError,
-                    QueryAbandonedError,
-                )
+        except Exception as e:
+            from pinot_tpu.engine.dispatch import (
+                LaneClosedError,
+                QueryAbandonedError,
+            )
 
-                if isinstance(
-                    e, (QueryAbandonedError, LaneClosedError, TimeoutError)
-                ):
-                    raise
-                # answered anyway, by the scan below — say so, or the
-                # only trace of a tier that never works is a meter
-                logger.warning(
-                    "bit-sliced tier failed; falling through to the scan",
-                    exc_info=True,
-                )
-                self._heal_mark("bitslicedFallbacks", error=str(e)[:200])
-                bres = None
-            if bres is not None:
-                ph.relabel("bitslicedPath")
-                return self._finish_tier(bres, request, "bitsliced")
+            if isinstance(
+                e, (QueryAbandonedError, LaneClosedError, TimeoutError)
+            ):
+                raise
+            # answered anyway, by the scan below — say so, or the
+            # only trace of a tier that never works is a meter
+            logger.warning(
+                "bit-sliced tier failed; falling through to the scan",
+                exc_info=True,
+            )
+            self._heal_mark("bitslicedFallbacks", error=str(e)[:200])
+            bres = None
+        if bres is None:
+            return None
+        ph.relabel(tier.phase)
+        return self._finish_tier(bres, request, tier.name)
 
-        # queries the planner can only send to the host (group space or
-        # guaranteed pair overflow) skip device staging entirely
-        from pinot_tpu.engine.plan import group_by_host_reason, plan_forced_host
+    def _serve_host(self, tier, live, request, deadline, ctx, ph, sel, mesh, use) -> Optional[IntermediateResult]:
+        total_docs, _needed, sel_columns, _pad_to = use.prepared.values["scope"]
+        forced = use.prepared.once(tier.prepared, lambda: tier.decide(request, live, ctx, total_docs, mesh)[1])
+        if forced is None:
+            return None
+        from pinot_tpu.engine.host_fallback import execute_host
 
-        if prep.once("forcedHost", lambda: plan_forced_host(request, ctx, mesh=mesh is not None)):
-            from pinot_tpu.engine.host_fallback import execute_host
+        # a group-by the device declines says why, by name (the
+        # reason EXPLAIN gives the segment's host record)
+        (reason,) = forced
+        if reason is not None:
+            self.metrics.meter(f"groupby.forcedHost.{reason.split(':')[0]}").mark()
+        res = execute_host(live, ctx, request, total_docs, sel_columns)
+        ph.relabel(tier.phase)
+        return self._finish_tier(res, request, tier.name)
 
-            # a group-by the device declines says why, by name (the
-            # reason EXPLAIN gives the segment's host record)
-            reason = group_by_host_reason(request, ctx, mesh=mesh is not None) if request.is_group_by else None
-            if reason is not None:
-                self.metrics.meter(f"groupby.forcedHost.{reason.split(':')[0]}").mark()
-            res = execute_host(live, ctx, request, total_docs, sel_columns)
-            ph.relabel("hostPath")
-            return self._finish_tier(res, request, "host")
-
+    def _serve_device(self, tier, live, request, deadline, ctx, ph, sel, mesh, use) -> IntermediateResult:
         # -- device section under the self-healing contract -----------
         # The WHOLE device path (staging, H2D uploads, kernel dispatch,
         # D2H fetch, finalize) is covered: classify the failure
@@ -851,17 +853,6 @@ class QueryExecutor:
             QueryAbandonedError,
             classify_device_error,
         )
-
-        if self._audit_blocked(audit_digest, "device"):
-            # wrong-answer quarantine: unlike a device FAILURE (which
-            # retries), a tier caught lying never gets another attempt
-            # inside the TTL — straight to the host oracle path
-            from pinot_tpu.engine.host_fallback import execute_host
-
-            self._heal_mark("hostFailovers", reason="auditQuarantine")
-            ph.enter("hostFailover")
-            res = execute_host(live, ctx, request, total_docs, sel_columns)
-            return self._finish_tier(res, request, "host")
 
         poison_ref: Dict[str, Any] = {}  # device section records the key
         last: Optional[DeviceExecutionError] = None
@@ -896,7 +887,7 @@ class QueryExecutor:
                         live, request, deadline, ctx, use, ph, poison_ref, sel=sel, mesh=mesh,
                     ),
                     request,
-                    "device",
+                    tier.name,
                 )
             except (QueryAbandonedError, LaneClosedError, TimeoutError):
                 raise
@@ -924,8 +915,6 @@ class QueryExecutor:
         # device exhausted: quarantine (when the section got far enough
         # to know its plan) and transparently fail over.  Coalesced
         # waiters each land here and each finalize from the host.
-        from pinot_tpu.engine.host_fallback import execute_host
-
         if poison_ref.get("key") is not None and not getattr(
             last, "resource_exhausted", False
         ):
@@ -933,10 +922,14 @@ class QueryExecutor:
             # full — quarantining it would strand a good plan on the
             # slow host path after pressure subsides
             self._poison(poison_ref["key"], str(last))
-        self._heal_mark("hostFailovers", reason=str(last)[:200])
-        ph.enter("hostFailover")
-        res = execute_host(live, ctx, request, total_docs, sel_columns)
-        return self._finish_tier(res, request, "host")
+        return self._host_failover(str(last)[:200], live, request, ctx, ph, use)
+
+    _SERVE = {
+        "postings": _serve_postings,
+        "bitsliced": _serve_bitsliced,
+        "host": _serve_host,
+        "device": _serve_device,
+    }
 
     def _device_section(
         self,
@@ -955,13 +948,7 @@ class QueryExecutor:
         lane = sel.lane if sel is not None else self.lane
         sharding, placement = self._mesh_placement(mesh)
 
-        def roles():
-            raw_cols, gfwd_cols, hll_cols = self._role_columns(request, live, ctx)
-            return raw_cols, gfwd_cols, hll_cols, self._skip_base_columns(
-                request, live, raw_cols, gfwd_cols, hll_cols
-            )
-
-        raw_cols, gfwd_cols, hll_cols, skip_base = prep.once("roles", roles)
+        raw_cols, gfwd_cols, hll_cols, skip_base = prep.once("roles", lambda: ladder.roles(request, live, ctx))
         # what a kept entry cannot spare a query, because it is the
         # table's state and not a function of the key: the staged table
         # of the moment, pinned.  pin=True: its token is refcounted for
@@ -1021,16 +1008,7 @@ class QueryExecutor:
             dev = prep.device = _PreparedDevice(staged.token)
         scratch: Dict[Any, Any] = {}  # plan->inputs table cache (regex)
 
-        def plan_and_digest():
-            # the digest is computed ONCE here and shared with the
-            # lane's injector hook and the failover wrapper's quarantine
-            from pinot_tpu.engine.dispatch import plan_digest
-
-            plan = build_static_plan(request, ctx, staged, scratch=scratch)
-            pdigest = plan_digest(plan) if plan.on_device else None
-            return plan, pdigest, (pdigest, staged.segment_names)
-
-        plan, pdigest, poison_key = dev.once("plan", plan_and_digest)
+        plan, pdigest, poison_key = dev.once("plan", lambda: ladder.plan(request, ctx, staged, scratch))
 
         if not plan.on_device:
             from pinot_tpu.engine.host_fallback import execute_host
@@ -1055,17 +1033,7 @@ class QueryExecutor:
         from pinot_tpu.engine.device import segment_arrays
 
         def inputs():
-            q_np = build_query_inputs(request, plan, ctx, staged, scratch=scratch)
-            block_ids, scanned_rows = self._block_skip_ids(plan, q_np, live, staged)
-            from pinot_tpu.engine.kernel import chunk_rows_limit
-
-            _limit = chunk_rows_limit()
-            if block_ids is not None and _limit and staged.num_segments * staged.n_pad > _limit:
-                # the block kernel has no segment-chunked variant: beyond the
-                # per-dispatch row budget its single dispatch would exhaust
-                # HBM at compile time — fall through to the chunked full
-                # kernel instead (correctness over the block-skip win)
-                block_ids = None
+            q_np, block_ids, scanned_rows = ladder.inputs(request, plan, ctx, live, staged, scratch)
             return q_np, self._inputs_digest(q_np), block_ids, scanned_rows
 
         derived = "inputs" not in dev.values
@@ -1089,19 +1057,8 @@ class QueryExecutor:
                 placement=placement,
             )
 
-        # the kernel's handle is looked up on every query, where its
-        # builders keep it (engine/kernel.py, _cached_sharded): a
-        # program forgotten there is built again by the next launch
+        kernel = ladder.program(plan, staged, block_ids, mesh)
         if block_ids is not None:
-            from pinot_tpu.engine.zonemap import zone_block_rows
-
-            block = zone_block_rows()
-            if mesh is None:
-                from pinot_tpu.engine.kernel import make_packed_block_table_kernel
-
-                kernel = make_packed_block_table_kernel(plan, block)
-            else:
-                kernel = self._block_kernel(plan, block, mesh)
             # block ids shard over the segment axis with everything else
             ids_dev = (
                 jax.device_put(np.asarray(block_ids), sharding)
@@ -1110,18 +1067,10 @@ class QueryExecutor:
             )
             args = (seg_arrays, upload_inputs(), ids_dev)
         else:
-            kernel = self._kernel(plan, staged, mesh)
-            from pinot_tpu.engine.kernel import groupby_lowering
-
-            if lane is not None and mesh is None and sharding is None and groupby_lowering(plan) != "runs":
-                # (a 'runs' group-by sorts the table's rows in its merge:
-                # a member more is a sort more, nothing shared)
-                # cross-query micro-batching eligibility: the plain
-                # packed single-device kernel only (no mesh collectives,
-                # no per-query block-id gathers, no chunked dispatch
-                # sequence) — exactly the path _kernel chose above when
-                # the table fits the per-dispatch row budget
-                batch_shape = dev.once("batch", lambda: self._batch_shape(staged, q_np))
+            if lane is not None:
+                # cross-query micro-batching, where ladder.batch finds
+                # the launch eligible
+                batch_shape = dev.once("batch", lambda: ladder.batch(plan, staged, q_np, block_ids, mesh))
                 if batch_shape is not None:
                     batch_spec = self._batch_spec(plan, staged, q_np, seg_arrays, batch_shape)
             if batch_spec is not None:
@@ -1193,326 +1142,6 @@ class QueryExecutor:
         ph.stop()
         return result
 
-    def _docrange_only_columns(
-        self,
-        request: BrokerRequest,
-        live: List[ImmutableSegment],
-        sel_columns: Optional[List[str]],
-    ) -> set:
-        """Filter columns whose every use qualifies for the docrange
-        fast path (plan.py StaticLeaf) and which appear nowhere else in
-        the query."""
-        qualifying = self._docrange_qualifying_cols(request, live)
-        used_elsewhere = {c for a in request.aggregations for c in a.columns}
-        if request.is_group_by:
-            used_elsewhere.update(request.group_by.columns)
-        if request.is_selection:
-            used_elsewhere.update(sel_columns or [])
-            used_elsewhere.update(s.column for s in request.selection.sorts)
-        return qualifying - used_elsewhere
-
-    def _docrange_qualifying_cols(
-        self, request: BrokerRequest, live: List[ImmutableSegment]
-    ) -> set:
-        """Filter columns whose EVERY leaf use classifies docrange
-        (sorted in every segment, SV, RANGE or single-value EQ).  MUST
-        mirror build_static_plan's classification: a column dropped or
-        base-skipped on a wrong prediction would leave the kernel
-        without its arrays."""
-        if request.filter is None:
-            return set()
-        from pinot_tpu.common.request import FilterOperator
-
-        qualifies: Dict[str, bool] = {}
-        for node in request.filter.walk():
-            if not node.is_leaf:
-                continue
-            col = node.column
-            ok = False
-            if live and live[0].has_column(col):
-                meta0 = live[0].column(col).metadata
-                shape_ok = node.operator == FilterOperator.RANGE or (
-                    node.operator == FilterOperator.EQUALITY
-                    and len(node.values) == 1
-                )
-                ok = (
-                    meta0.single_value
-                    and shape_ok
-                    and all(s.column(col).metadata.is_sorted for s in live)
-                )
-            qualifies[col] = qualifies.get(col, True) and ok
-        return {c for c, ok in qualifies.items() if ok}
-
-    def _block_skip_ids(
-        self,
-        plan: StaticPlan,
-        q_np: Dict[str, Any],
-        live: List[ImmutableSegment],
-        staged: StagedTable,
-    ):
-        """Zone-map block pruning decision (engine/zonemap.py): returns
-        (block_ids [S, nb_pad] or None, candidate_rows or None).
-
-        Engages when the candidate blocks, padded to a power of two,
-        are at most half the table.  The gate dates from the gathered
-        view, whose copy made Q5 (47 blocks of 128) and TPC-H Q6 (22 of
-        128) dearer than the full scan they skip (chip runs, PR 28 and
-        PR 34); an 'inplace' plan (kernel.zone_blocks) no longer pays
-        that, so half is now known to be low for it and waits for a cell
-        with selective traffic to be moved (ROADMAP S6).  On a mesh, the
-        ids array shards over the segment axis like every other
-        per-segment input (nb_pad is a global bucket)."""
-        import os
-
-        if os.environ.get("PINOT_TPU_ZONEMAP") == "0":
-            return None, None
-        from pinot_tpu.engine import zonemap
-
-        cand = zonemap.candidate_blocks(plan, q_np, live, staged.n_pad)
-        if cand is None:
-            return None, None
-        block = zonemap.zone_block_rows()
-        nb_total = staged.num_segments * (staged.n_pad // block)
-        nb_max = int(cand.sum(axis=1).max()) if cand.size else 0
-        if plan.selection is not None:
-            # the gathered view exposes only nb_pad*block rows per
-            # segment; top_k(k) requires k <= operand length, so grow
-            # the candidate window to cover the selection k (falls back
-            # to full scan below when that defeats the pruning win)
-            nb_max = max(nb_max, -(-plan.selection.k // block))
-        nb_pad = 1
-        while nb_pad < nb_max:
-            nb_pad *= 2
-        if nb_pad * staged.num_segments > nb_total // 2:
-            return None, None
-        ids = zonemap.block_ids_input(cand, nb_pad)
-        if ids.shape[0] < staged.num_segments:  # mesh-padding segments
-            pad = np.full(
-                (staged.num_segments - ids.shape[0], nb_pad), -1, dtype=np.int32
-            )
-            ids = np.concatenate([ids, pad], axis=0)
-        return ids, int(cand.sum()) * block
-
-    def _cached_sharded(self, key, factory):
-        k = self._sharded_kernels.get(key)
-        if k is None:
-            k = factory()
-            if len(self._sharded_kernels) > 128:
-                self._sharded_kernels.clear()
-            self._sharded_kernels[key] = k
-        return k
-
-    def _block_kernel(self, plan: StaticPlan, block: int, mesh=None):
-        from pinot_tpu.engine.kernel import kernel_name
-        from pinot_tpu.engine.packing import make_packed_kernel
-        from pinot_tpu.parallel.multichip import make_sharded_block_table_kernel
-
-        if mesh is None:
-            mesh = self.mesh
-        return self._cached_sharded(
-            (plan, "block", block, self._mesh_key(mesh)),
-            lambda: make_packed_kernel(
-                make_sharded_block_table_kernel(plan, mesh, block),
-                kernel_name("meshzone", plan),
-            ),
-        )
-
-    def _kernel(self, plan: StaticPlan, staged, mesh=None):
-        if mesh is None and self.lanes is None:
-            mesh = self.mesh
-        if mesh is None:
-            from pinot_tpu.engine.kernel import (
-                chunk_rows_limit,
-                make_chunked_table_kernel,
-                make_packed_table_kernel,
-                plan_chunkable,
-            )
-
-            limit = chunk_rows_limit()
-            if (
-                limit
-                and staged.num_segments * staged.n_pad > limit
-                and plan_chunkable(plan)
-            ):
-                # beyond the per-dispatch row budget the kernel's
-                # per-row temporaries exceed HBM at compile time: run
-                # segment-axis chunks and combine the reduced outputs.
-                # Outputs are holder-sized (small), so the single-
-                # transfer packing wrapper isn't needed here.
-                return make_chunked_table_kernel(
-                    plan, staged.num_segments, staged.n_pad
-                )
-            return make_packed_table_kernel(plan)
-        from pinot_tpu.engine.kernel import chunk_rows_limit, make_chunked_sharded_kernel
-
-        # the per-DEVICE row budget binds on a mesh too; the factory
-        # falls back to the plain packed sharded kernel when chunking
-        # is off or unnecessary
-        return self._cached_sharded(
-            (
-                plan,
-                "mesh",
-                staged.num_segments,
-                staged.n_pad,
-                chunk_rows_limit(),
-                self._mesh_key(mesh),
-            ),
-            lambda: make_chunked_sharded_kernel(
-                plan, mesh, staged.num_segments, staged.n_pad
-            ),
-        )
-
-    def _skip_base_columns(
-        self,
-        request: BrokerRequest,
-        live: Sequence[ImmutableSegment],
-        raw_cols,
-        gfwd_cols,
-        hll_cols,
-    ) -> set:
-        """Columns the kernel reads ONLY through a role stream skip
-        their base fwd/dict arrays: at 1B rows the dictId stream is the
-        difference between fitting in HBM and not.  Filter leaves and
-        selection outputs read base arrays, so those columns keep them.
-        Shared by the staging path and the prewarm aval builder
-        (engine/explain.py) — the two must agree bit-for-bit or a
-        prewarmed executable never matches a serving launch."""
-        if request.is_selection:
-            return set()
-        # filter leaves need base arrays on device — EXCEPT leaves
-        # whose every use classifies docrange (the kernel compares
-        # row ids against host-computed bounds, reading no column)
-        filter_cols = (
-            {n.column for n in request.filter.walk() if n.is_leaf}
-            if request.filter is not None
-            else set()
-        ) - self._docrange_qualifying_cols(request, live)
-        from pinot_tpu.engine.plan import _agg_kind
-
-        # scalar/pair agg inputs OUTSIDE raw_cols (small dictionaries)
-        # read dict[fwd] on device — their base arrays must stay
-        # (an expression streams every leaf or gathers every leaf,
-        # plan.StaticAgg.use_raw)
-        gather_agg_cols = {
-            c
-            for a in request.aggregations
-            if _agg_kind(a.base_function) in ("scalar", "pair")
-            and not set(a.columns) <= set(raw_cols)
-            for c in a.columns
-        }
-        return (
-            set(raw_cols) | set(gfwd_cols) | set(hll_cols)
-        ) - filter_cols - gather_agg_cols
-
-    # ------------------------------------------------------------------
-    def _resolve_selection_columns(
-        self, request: BrokerRequest, seg: ImmutableSegment
-    ) -> List[str]:
-        cols = request.selection.columns
-        if not cols or cols == ["*"]:
-            return list(seg.columns.keys())
-        return list(cols)
-
-    def _role_columns(
-        self,
-        request: BrokerRequest,
-        live: Sequence[ImmutableSegment],
-        ctx: Optional[TableContext] = None,
-    ):
-        """Columns to stage with role-specific arrays: aggregation
-        inputs get raw value arrays, group-by/sort keys get global-id
-        forward arrays (both avoid slow big-table gathers on device)."""
-        seg = live[0]
-
-        def big_card(c: str) -> bool:
-            # raw_card_min() is 0 on accelerators (TPU gathers serialize
-            # — see engine/config.py measurement); on CPU the narrow
-            # fwd + dict-gather feed stands below the threshold.  The
-            # staged dtype is sized by the table-wide max cardinality,
-            # so the decision must be too.
-            card = max(s.column(c).metadata.cardinality for s in live)
-            return card > config.raw_card_min()
-
-        def sv(c: str) -> bool:
-            return c in seg.columns and seg.column(c).metadata.single_value
-
-        from pinot_tpu.engine.plan import _agg_kind
-
-        # only scalar/pair agg kernels read .raw (presence/hist/hll work
-        # in dictId space)
-        def numeric_any(c: str) -> bool:
-            if c == "*" or c not in seg.columns:
-                return False
-            return seg.column(c).metadata.data_type.stored_type != DataType.STRING
-
-        # (every leaf of a compound expression is one, whatever its
-        # cardinality: the kernel multiplies row values, not dictionaries)
-        raw_cols = {
-            c
-            for a in request.aggregations
-            if _agg_kind(a.base_function) in ("scalar", "pair")
-            for c in a.columns
-            if numeric_any(c) and (a.expr is not None or big_card(c))
-        }
-        gfwd_cols = set()
-        if request.is_group_by:
-            gfwd_cols.update(c for c in request.group_by.columns if sv(c))
-        if request.is_selection:
-            gfwd_cols.update(s.column for s in request.selection.sorts if sv(s.column))
-        # presence/hist aggs (distinctcount, percentile) read global
-        # value ids per row: stage them host-side (gfwd) so the kernel
-        # streams instead of gathering a remap table on device (slow at
-        # any cardinality on TPU, ROADMAP S5).  Both kinds
-        # stay on device at any cardinality (dense holders within the
-        # budget, the sort-pairs path beyond it).
-        gfwd_cols.update(
-            a.column
-            for a in request.aggregations
-            if _agg_kind(a.base_function) in ("presence", "hist") and sv(a.column)
-        )
-        # HLL aggs: modest-cardinality SV columns lower to a presence
-        # contraction over gfwd streams (plan.hll_lowers_to_presence —
-        # registers depend only on the distinct value set); the rest
-        # stream host-computed (register, rank) pairs
-        from pinot_tpu.engine.plan import hll_lowers_to_presence
-
-        hll_cols = set()
-        for a in request.aggregations:
-            if _agg_kind(a.base_function) == "hll" and sv(a.column):
-                if hll_lowers_to_presence(request, ctx, a.column):
-                    gfwd_cols.add(a.column)
-                else:
-                    hll_cols.add(a.column)
-        return tuple(sorted(raw_cols)), tuple(sorted(gfwd_cols)), tuple(sorted(hll_cols))
-
-    def _batch_shape(self, staged, q_np) -> Optional[Tuple[tuple, int]]:
-        """What of a ``BatchSpec`` is a function of the query and the
-        staged table's shape, and so is kept with the prepared query:
-        (the inputs' structural signature, ``max_members``), or None
-        where one member already fills the per-dispatch row budget.
-        ``max_members`` keeps batch x rows under that budget so batching
-        can never blow the compile-time working set the chunked path
-        exists to bound."""
-        from pinot_tpu.engine.kernel import chunk_rows_limit
-        from pinot_tpu.engine.packing import batch_input_signature
-
-        limit = chunk_rows_limit()
-        rows = max(1, staged.num_segments * staged.n_pad)
-        if limit:
-            # the launch pads member count UP to a power of two, so the
-            # cap must be the largest power of two whose padded batch
-            # still fits the row budget — a plain floor-divide cap of 5
-            # would pad to 8 and overshoot the budget by ~1.5x
-            cap = limit // rows
-            max_members = 1
-            while max_members * 2 <= cap:
-                max_members *= 2
-        else:
-            max_members = 0
-        if max_members == 1:
-            return None  # one batch member already fills the budget
-        return batch_input_signature(q_np), max_members
-
     def _batch_spec(self, plan: StaticPlan, staged, q_np, seg_arrays, batch_shape):
         """BatchSpec for the lane micro-batching tier (PIMDAL-style
         cross-query amortization — engine/dispatch.py module
@@ -1525,7 +1154,7 @@ class QueryExecutor:
         literal-bucketed program identity (``a>5`` and ``a>999`` build
         the SAME StaticPlan — only their match tables/bounds differ) x
         resident-table identity x structural input identity
-        (``_batch_shape``).  Built on every query: its launch closes
+        (``ladder.batch``).  Built on every query: its launch closes
         over the staged table's arrays of the moment."""
         from pinot_tpu.engine.dispatch import BatchSpec
 

@@ -1,21 +1,25 @@
 """EXPLAIN: the serving-tier decision records, computed WITHOUT serving.
 
-``build_explain_node`` walks the exact decision order the executor
-applies (``executor.execute`` -> ``_execute_engine``) — prune verdicts,
-star-tree routing, the postings/scan operator choice, planner
-host-forcing, poison quarantine, and the zone-map/full-scan split — and
-returns a JSON-safe per-server plan node instead of results.
+``build_explain_node`` applies what ``executor.execute`` applies ahead
+of the ladder, with the reasons (``prune_explain``'s verdicts, the
+star-tree routing), then reads the executor's ladder
+(``engine/ladder.py``): the first tier of ``ladder.TIERS`` that accepts
+and, where that is the device, the second level's derivations (the
+``StaticPlan``, the poison quarantine, the zone-map/full-scan split,
+the batch shape).  It walks no order of its own, and returns a JSON-safe
+per-server plan node instead of results.
 
-The device-path decisions (StaticPlan shape, its digest, the zone-map
-candidate fraction) normally require a staged table; EXPLAIN must never
-stage (a cold EXPLAIN of a 1B-row table must not trigger a multi-GB H2D
-transfer) and never launch kernels.  ``_phantom_staged`` therefore
-builds a metadata-only ``StagedTable`` twin: the same n_pad/card_pad
-bucketing, per-segment cards, and role-array PRESENCE (zero-length
-sentinels) that real staging would produce — ``build_static_plan`` and
-``build_query_inputs`` read only those, so the phantom yields the
-IDENTICAL ``StaticPlan`` (hence the identical plan digest and poison
-key) the executor would compile, with zero device bytes moved.
+The device tier's derivations normally read a staged table; EXPLAIN
+must never stage (a cold EXPLAIN of a 1B-row table must not trigger a
+multi-GB H2D transfer) and never launch kernels.  ``_phantom_staged``
+therefore builds a metadata-only ``StagedTable`` twin: the same
+n_pad/card_pad bucketing, per-segment cards, and role-array PRESENCE
+(zero-length sentinels) that real staging would produce — the
+derivations read only those, so the phantom yields the IDENTICAL
+``StaticPlan`` (hence the identical plan digest and poison key) the
+executor would compile, with zero device bytes moved.  A phantom's
+derivations are kept nowhere: the executor's prepared-query memo is
+neither filled nor counted.
 
 The safety contract (tier-1 guarded): plain EXPLAIN performs zero lane
 submissions and marks zero cost meters.
@@ -28,17 +32,10 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from pinot_tpu.common.request import BrokerRequest
-from pinot_tpu.engine import config
+from pinot_tpu.engine import config, ladder
 from pinot_tpu.engine.context import get_table_context
 from pinot_tpu.engine.device import LEDGER, StagedColumn, StagedTable
 from pinot_tpu.engine.dispatch import plan_digest
-from pinot_tpu.engine.invindex_path import index_path_decision
-from pinot_tpu.engine.plan import (
-    build_query_inputs,
-    build_static_plan,
-    group_by_host_reason,
-    plan_forced_host,
-)
 from pinot_tpu.engine.plandigest import plan_shape_digest, plan_shape_summary
 from pinot_tpu.engine.pruner import prune_explain
 from pinot_tpu.segment.immutable import ImmutableSegment
@@ -197,6 +194,80 @@ def _staged_snapshot(table: str, segment_names: Sequence[str]) -> Dict[str, Any]
     }
 
 
+def _route(executor, request: BrokerRequest):
+    """(lane selection or None, the mesh the query would run on, its
+    lane): the chip-group routing the serving path applies, so that the
+    phantom pads the segment axis for the mesh of the lane this shape
+    would execute on."""
+    selection = executor.lane_selection(request) if getattr(executor, "lanes", None) is not None else None
+    if selection is not None:
+        return selection, selection.group.mesh, selection.lane
+    return None, executor.mesh, getattr(executor, "lane", None)
+
+
+def _compile_state(lane, pdigest: str) -> Dict[str, Any]:
+    """The ``device.compile`` record of a plan digest on ``lane``."""
+    compile_entry = lane.compile_info(pdigest) if lane is not None else None
+    if compile_entry is None:
+        # never launched here: no analysis exists yet.  The plan ledger
+        # can still prove the on-disk cache holds the binary — the first
+        # launch would restore, not compile
+        from pinot_tpu.engine import compilecache
+
+        state = "persistent" if compilecache.known_plan(pdigest) else "cold"
+        return {"state": state, "costAnalysis": "unavailable"}
+    # launched here -> warm; a prewarmed/persistent entry that has NOT
+    # served yet reports how its executable arrived (the r16 warm-start
+    # states)
+    state = "warm" if compile_entry.get("launches", 0) > 0 else compile_entry.get("via", "warm")
+    compile_info = {"state": state, **compile_entry}
+    # static cost-analysis tri-state (utilization plane): a dict once
+    # the async analysis landed, explicit "unavailable" when the backend
+    # reported nothing, "pending" while it is still running
+    if "costAnalysis" not in compile_entry:
+        compile_info["costAnalysis"] = "pending"
+    elif compile_entry["costAnalysis"] is None:
+        compile_info["costAnalysis"] = "unavailable"
+    return compile_info
+
+
+def _device_record(executor, selection, lane, pdigest: str, group_size: int, sharded_plan=None, quarantined=False):
+    """The node's ``device`` record: the lane-registered plan's digest
+    and compile state, and the mesh decision: which chip-group lane
+    executes this shape, the mesh it shards over, and the XLA collectives
+    the cross-chip merge lowers to (``sharded_plan`` None, the
+    single-chip program: shardAxis/collective None — the per-segment
+    combine is fused in-program)."""
+    from pinot_tpu.engine.mesh import SEGMENT_AXIS, collective_names
+
+    lanes_obj = getattr(executor, "lanes", None)
+    n_lanes = lanes_obj.size if lanes_obj is not None else 1
+    return {
+        "planDigest": pdigest,
+        "compile": _compile_state(lane, pdigest),
+        "quarantined": quarantined,
+        "mesh": {
+            "shape": f"{n_lanes}x{group_size}",
+            "lanes": n_lanes,
+            "laneIndex": selection.index if selection is not None else 0,
+            "shardAxis": SEGMENT_AXIS if sharded_plan is not None else None,
+            "collective": collective_names(sharded_plan) if sharded_plan is not None else None,
+        },
+    }
+
+
+def _phantom_plan(request: BrokerRequest, normal, ctx, needed, pad_to: int):
+    """The device tier's second level against a phantom table: (the
+    roles, the phantom, the scratch ``ladder.inputs`` takes on, and
+    ``ladder.plan``'s plan, digest and poison key)."""
+    roles = ladder.roles(request, normal, ctx)
+    phantom = _phantom_staged(
+        normal, list(needed) + list(request.referenced_columns()), *roles[:3], pad_segments_to=pad_to
+    )
+    scratch: Dict[Any, Any] = {}
+    return (roles, phantom, scratch) + ladder.plan(request, ctx, phantom, scratch)
+
+
 def build_explain_node(
     executor,
     segments: Sequence[ImmutableSegment],
@@ -207,7 +278,7 @@ def build_explain_node(
     result_cache=None,
 ) -> Dict[str, Any]:
     """One server's EXPLAIN plan node (module docstring).  ``executor``
-    supplies the decision helpers AND the live poison-quarantine state;
+    supplies the lane routing AND the live poison-quarantine state;
     ``plan_stats`` (utils/planstats.py) supplies historical estimates;
     ``result_cache`` (engine/rescache.py) answers the device node's
     cacheHit probe without marking hit/miss meters."""
@@ -215,17 +286,18 @@ def build_explain_node(
     records: List[Dict[str, Any]] = []
     tier_counts: Dict[str, int] = {}
 
-    def record(seg: ImmutableSegment, tier: str, reason: str, **extra) -> None:
-        tier_counts[TIER_COST_KEYS[tier]] = tier_counts.get(TIER_COST_KEYS[tier], 0) + 1
-        records.append(
-            dict({"segment": seg.segment_name, "tier": tier, "reason": reason}, **extra)
-        )
+    def record(segs, tier: str, reason: str, **extra) -> None:
+        for seg in segs:
+            tier_counts[TIER_COST_KEYS[tier]] = tier_counts.get(TIER_COST_KEYS[tier], 0) + 1
+            records.append(
+                dict({"segment": seg.segment_name, "tier": tier, "reason": reason}, **extra)
+            )
 
     verdicts = prune_explain(segments, request)
     live = [seg for seg, reason in verdicts if reason is None]
     for seg, reason in verdicts:
         if reason is not None:
-            record(seg, "pruned", reason)
+            record([seg], "pruned", reason)
 
     device_info: Optional[Dict[str, Any]] = None
     est_bytes = 0
@@ -235,310 +307,133 @@ def build_explain_node(
 
         star = [s for s in live if is_fit_for_star_tree(request, s)]
         normal = [s for s in live if s not in star]
-        for seg in star:
-            record(
-                seg,
-                "starTree",
-                "conjunctive-EQ dims + aggregations covered by the "
-                "segment's star-tree cube",
-            )
+        record(
+            star,
+            "starTree",
+            "conjunctive-EQ dims + aggregations covered by the "
+            "segment's star-tree cube",
+        )
 
     if normal:
-        needed = set(request.referenced_columns())
-        sel_columns: Optional[List[str]] = None
-        if request.is_selection:
-            sel_columns = executor._resolve_selection_columns(request, normal[0])
-            needed.update(sel_columns)
-        # chip-group routing mirrors the executor EXACTLY: the phantom
-        # must pad the segment axis for the mesh of the lane this shape
-        # would execute on, or the StaticPlan digest would diverge from
-        # real sharded execution
-        selection = None
-        if getattr(executor, "lanes", None) is not None:
-            selection = executor.lane_selection(request)
-        exec_mesh = (
-            selection.group.mesh if selection is not None else executor.mesh
-        )
-        pad_to = 0
-        if exec_mesh is not None:
-            n = int(exec_mesh.devices.size)
-            pad_to = -(-len(normal) // n) * n
-        needed -= executor._docrange_only_columns(request, normal, sel_columns)
+        selection, exec_mesh, lane = _route(executor, request)
+        ladder_docs, needed, _sel_columns, pad_to = ladder.scope(request, normal, exec_mesh)
         ctx = get_table_context(normal)
-
-        decision, state = index_path_decision(request, normal, ctx, total_docs)
-        bsi_decision, bsi_state = (None, None)
-        if state is None and exec_mesh is None:
-            # same tier order as the executor: bit-sliced engages only
-            # after postings declines, and only off-mesh
-            from pinot_tpu.engine.bitsliced import bitsliced_decision
-
-            bsi_decision, bsi_state = bitsliced_decision(
-                request, normal, ctx, total_docs
-            )
-        if state is not None:
+        tier, decision, state = ladder.first_accepting(request, normal, ctx, ladder_docs, exec_mesh)
+        full_scan_bytes = lambda: _estimate_scan_bytes(normal, needed, 1.0)
+        if tier.name == "postings":
             est_bytes = int(decision.get("estMatches", 0)) * (
                 decision.get("residuals", 0) + 1
             ) * 8
-            for seg in normal:
-                record(
-                    seg, "postings", decision["reason"],
-                    drivingColumn=decision.get("column"),
-                )
-        elif bsi_state is not None:
-            _spec, _leaves, _aggs, planes_total, _fp = bsi_state
-            est_bytes = (total_docs * planes_total) // 8
-            for seg in normal:
-                record(
-                    seg,
-                    "bitsliced",
-                    bsi_decision["reason"],
-                    planes=bsi_decision.get("planes"),
-                    planeCounts=bsi_decision.get("planeCounts"),
-                    fusedAggs=bsi_decision.get("fusedAggs"),
-                )
+            record(normal, tier.name, decision["reason"], drivingColumn=decision.get("column"))
+        elif tier.name == "bitsliced":
+            spec, _leaves, _aggs, planes_total, _fp = state
+            est_bytes = (ladder_docs * planes_total) // 8
+            record(
+                normal,
+                tier.name,
+                decision["reason"],
+                planes=decision.get("planes"),
+                planeCounts=decision.get("planeCounts"),
+                fusedAggs=decision.get("fusedAggs"),
+            )
             # the bit-sliced kernel is a lane-registered device plan
             # like any scan: its digest must match what the real
             # execution hands the lane (run_bitsliced_path), so the
             # compile timeline and poison lookups stay digest-exact
-            pdigest = plan_digest(("bsi", _spec))
-            lane = (
-                selection.lane
-                if selection is not None
-                else getattr(executor, "lane", None)
-            )
-            compile_entry = (
-                lane.compile_info(pdigest) if lane is not None else None
-            )
-            if compile_entry is not None:
-                cstate = (
-                    "warm"
-                    if compile_entry.get("launches", 0) > 0
-                    else compile_entry.get("via", "warm")
-                )
-                compile_info = {"state": cstate, **compile_entry}
-                if "costAnalysis" not in compile_entry:
-                    compile_info["costAnalysis"] = "pending"
-                elif compile_entry["costAnalysis"] is None:
-                    compile_info["costAnalysis"] = "unavailable"
-            else:
-                from pinot_tpu.engine import compilecache
-
-                cstate = (
-                    "persistent"
-                    if compilecache.known_plan(pdigest)
-                    else "cold"
-                )
-                compile_info = {"state": cstate, "costAnalysis": "unavailable"}
-            lanes_obj = getattr(executor, "lanes", None)
-            n_lanes = lanes_obj.size if lanes_obj is not None else 1
-            device_info = {
-                "planDigest": pdigest,
-                "compile": compile_info,
-                "quarantined": False,
-                "mesh": {
-                    "shape": f"{n_lanes}x1",
-                    "lanes": n_lanes,
-                    "laneIndex": selection.index if selection is not None else 0,
-                    "shardAxis": None,
-                    "collective": None,
-                },
-            }
-        elif plan_forced_host(request, ctx, mesh=exec_mesh is not None):
-            est_bytes = _estimate_scan_bytes(normal, sorted(needed), 1.0)
-            # a group-by the device declines is named: the key space, or
-            # what the runs lowering above MAX_GROUP_CAPACITY keys does
-            # not take (plan.group_runs_host_reason)
-            why = group_by_host_reason(request, ctx, mesh=exec_mesh is not None) if request.is_group_by else None
-            for seg in normal:
-                record(
-                    seg,
-                    "host",
-                    "planner forces host before staging ("
-                    + (f"group-by: {why}" if why is not None else "guaranteed sort-pair overflow")
-                    + ")",
-                    **({"groupByHostReason": why} if why is not None else {}),
-                )
-        else:
-            raw_cols, gfwd_cols, hll_cols = executor._role_columns(
-                request, normal, ctx
-            )
-            phantom = _phantom_staged(
+            device_info = _device_record(executor, selection, lane, plan_digest(("bsi", spec)), 1)
+        elif tier.name == "host":
+            est_bytes = full_scan_bytes()
+            why = decision["groupByHostReason"]
+            record(
                 normal,
-                list(needed) + list(request.referenced_columns()),
-                raw_cols, gfwd_cols, hll_cols,
-                pad_segments_to=pad_to,
+                tier.name,
+                "planner forces host before staging ("
+                + (f"group-by: {why}" if why is not None else "guaranteed sort-pair overflow")
+                + ")",
+                **({"groupByHostReason": why} if why is not None else {}),
             )
-            scratch: Dict[Any, Any] = {}
-            plan = build_static_plan(request, ctx, phantom, scratch=scratch)
+        else:
+            _roles, phantom, scratch, plan, pdigest, poison_key = _phantom_plan(request, normal, ctx, needed, pad_to)
+            poison = executor.poisoned_entry(poison_key) if plan.on_device else None
             if not plan.on_device:
-                est_bytes = _estimate_scan_bytes(normal, sorted(needed), 1.0)
-                for seg in normal:
-                    record(
-                        seg,
-                        "host",
-                        "StaticPlan is device-ineligible (group capacity, "
-                        "MV expansion, or pair-overflow guard)",
-                    )
+                est_bytes = full_scan_bytes()
+                record(
+                    normal,
+                    "host",
+                    "StaticPlan is device-ineligible (group capacity, "
+                    "MV expansion, or pair-overflow guard)",
+                )
             else:
-                pdigest = plan_digest(plan)
-                poison = executor.poisoned_entry((pdigest, phantom.segment_names))
-                lane = (
-                    selection.lane
-                    if selection is not None
-                    else getattr(executor, "lane", None)
-                )
-                compile_entry = (
-                    lane.compile_info(pdigest) if lane is not None else None
-                )
-                if compile_entry is not None:
-                    # launched here -> warm; a prewarmed/persistent
-                    # entry that has NOT served yet reports how its
-                    # executable arrived (the r16 warm-start states)
-                    state = (
-                        "warm"
-                        if compile_entry.get("launches", 0) > 0
-                        else compile_entry.get("via", "warm")
-                    )
-                    compile_info = {"state": state, **compile_entry}
-                    # static cost-analysis tri-state (utilization
-                    # plane): a dict once the async analysis landed,
-                    # explicit "unavailable" when the backend reported
-                    # nothing, "pending" while it is still running
-                    if "costAnalysis" not in compile_entry:
-                        compile_info["costAnalysis"] = "pending"
-                    elif compile_entry["costAnalysis"] is None:
-                        compile_info["costAnalysis"] = "unavailable"
-                else:
-                    # never launched here: no analysis exists yet.  The
-                    # plan ledger can still prove the on-disk cache
-                    # holds the binary — the first launch would restore,
-                    # not compile
-                    from pinot_tpu.engine import compilecache
-
-                    state = (
-                        "persistent"
-                        if compilecache.known_plan(pdigest)
-                        else "cold"
-                    )
-                    compile_info = {"state": state, "costAnalysis": "unavailable"}
-                # mesh decision record: which chip-group lane executes
-                # this shape, the mesh it shards over, and the XLA
-                # collectives the cross-chip merge lowers to (the
-                # single-chip fallback reports shardAxis/collective
-                # None — the per-segment combine is fused in-program)
-                from pinot_tpu.engine.mesh import SEGMENT_AXIS, collective_names
-
-                lanes_obj = getattr(executor, "lanes", None)
-                n_lanes = lanes_obj.size if lanes_obj is not None else 1
                 group_size = (
                     selection.group.size
                     if selection is not None
                     else (int(exec_mesh.devices.size) if exec_mesh is not None else 1)
                 )
-                mesh_info = {
-                    "shape": f"{n_lanes}x{group_size}",
-                    "lanes": n_lanes,
-                    "laneIndex": selection.index if selection is not None else 0,
-                    "shardAxis": SEGMENT_AXIS if exec_mesh is not None else None,
-                    "collective": (
-                        collective_names(plan) if exec_mesh is not None else None
+                device_info = _device_record(
+                    executor, selection, lane, pdigest, group_size,
+                    sharded_plan=plan if exec_mesh is not None else None,
+                    quarantined=poison is not None,
+                )
+            if poison is not None:
+                # HONESTY: the device plan is quarantined, so this
+                # query will ACTUALLY serve from the host path — the
+                # explain must say so, not report the device tier
+                est_bytes = full_scan_bytes()
+                record(
+                    normal,
+                    "host",
+                    "device plan quarantined (poisoned): "
+                    f"{poison['reason']} — serving via host "
+                    f"fallback for {poison['ttlRemainingS']}s more",
+                )
+            elif plan.on_device:
+                q_np, block_ids, scanned_rows = ladder.inputs(request, plan, ctx, normal, phantom, scratch)
+                if block_ids is not None and scanned_rows is not None:
+                    frac = (
+                        min(1.0, scanned_rows / phantom.total_docs)
+                        if phantom.total_docs
+                        else 1.0
+                    )
+                    est_bytes = _estimate_scan_bytes(normal, needed, frac)
+                    record(
+                        normal,
+                        "zonemap",
+                        "zone-map block pruning engages: candidate "
+                        f"fraction {frac:.4f} of the table",
+                        candidateFraction=round(frac, 4),
+                    )
+                else:
+                    est_bytes = full_scan_bytes()
+                    record(
+                        normal,
+                        "fullScan",
+                        "no selective tier applies: full vmapped "
+                        "device scan",
+                    )
+                # batching decision record (lane micro-batching
+                # tier): whether this shape's dispatches would
+                # stack with same-plan peers (ladder.batch, the
+                # executor's own eligibility), the window/cap that
+                # governs formation, and whether the result cache
+                # holds this exact query's answer RIGHT NOW.
+                cap = lane.batch_max if lane is not None and getattr(lane, "batch_max", 0) > 1 else 0
+                batch_shape = ladder.batch(plan, phantom, q_np, block_ids, exec_mesh)
+                if batch_shape is not None and batch_shape[1]:
+                    cap = min(cap, batch_shape[1])  # the row budget's bound on members
+                device_info["batching"] = {
+                    "batched": batch_shape is not None and cap > 1,
+                    "batchMax": cap,
+                    "windowMs": (
+                        round(lane.batch_window_s * 1000, 3)
+                        if lane is not None
+                        else 0.0
+                    ),
+                    "cacheHit": (
+                        result_cache.contains(request, segments, table)
+                        if result_cache is not None
+                        else False
                     ),
                 }
-                device_info = {
-                    "planDigest": pdigest,
-                    "compile": compile_info,
-                    "quarantined": poison is not None,
-                    "mesh": mesh_info,
-                }
-                if poison is not None:
-                    # HONESTY: the device plan is quarantined, so this
-                    # query will ACTUALLY serve from the host path — the
-                    # explain must say so, not report the device tier
-                    est_bytes = _estimate_scan_bytes(normal, sorted(needed), 1.0)
-                    for seg in normal:
-                        record(
-                            seg,
-                            "host",
-                            "device plan quarantined (poisoned): "
-                            f"{poison['reason']} — serving via host "
-                            f"fallback for {poison['ttlRemainingS']}s more",
-                        )
-                else:
-                    q_np = build_query_inputs(
-                        request, plan, ctx, phantom, scratch=scratch
-                    )
-                    block_ids, scanned_rows = executor._block_skip_ids(
-                        plan, q_np, normal, phantom
-                    )
-                    from pinot_tpu.engine.kernel import chunk_rows_limit
-
-                    _limit = chunk_rows_limit()
-                    if (
-                        block_ids is not None
-                        and _limit
-                        and phantom.num_segments * phantom.n_pad > _limit
-                    ):
-                        block_ids = None  # mirrors the executor's guard
-                    if block_ids is not None and scanned_rows is not None:
-                        frac = (
-                            min(1.0, scanned_rows / phantom.total_docs)
-                            if phantom.total_docs
-                            else 1.0
-                        )
-                        est_bytes = _estimate_scan_bytes(
-                            normal, sorted(needed), frac
-                        )
-                        for seg in normal:
-                            record(
-                                seg,
-                                "zonemap",
-                                "zone-map block pruning engages: candidate "
-                                f"fraction {frac:.4f} of the table",
-                                candidateFraction=round(frac, 4),
-                            )
-                    else:
-                        est_bytes = _estimate_scan_bytes(normal, sorted(needed), 1.0)
-                        for seg in normal:
-                            record(
-                                seg,
-                                "fullScan",
-                                "no selective tier applies: full vmapped "
-                                "device scan",
-                            )
-                    # batching decision record (lane micro-batching
-                    # tier): whether this shape's dispatches would
-                    # stack with same-plan peers, the window/cap that
-                    # governs formation, and whether the result cache
-                    # holds this exact query's answer RIGHT NOW.
-                    # Mirrors the executor's eligibility exactly: the
-                    # plain packed single-device kernel only.
-                    rows_total = phantom.num_segments * phantom.n_pad
-                    cap = 0
-                    if lane is not None and getattr(lane, "batch_max", 0) > 1:
-                        cap = lane.batch_max
-                        if _limit:
-                            cap = min(cap, max(1, _limit // max(rows_total, 1)))
-                    batchable = (
-                        exec_mesh is None
-                        and block_ids is None
-                        and cap > 1
-                        and (not _limit or rows_total <= _limit)
-                    )
-                    device_info["batching"] = {
-                        "batched": batchable,
-                        "batchMax": cap,
-                        "windowMs": (
-                            round(lane.batch_window_s * 1000, 3)
-                            if lane is not None
-                            else 0.0
-                        ),
-                        "cacheHit": (
-                            result_cache.contains(request, segments, table)
-                            if result_cache is not None
-                            else False
-                        ),
-                    }
 
     digest = plan_shape_digest(request)
     estimated: Dict[str, Any] = {
@@ -654,98 +549,45 @@ def build_prewarm_spec(
     """AOT prewarm spec for one query shape, or None when the shape has
     nothing lowerable to prewarm.
 
-    Walks the EXACT executor decision order (as ``build_explain_node``
-    does) and returns ``{"planDigest", "lane", "compile"}`` where
-    ``compile()`` pays the XLA compile of the kernel the first serving
-    launch would otherwise pay cold.  None is a *skip*, not a failure:
+    Reads the executor's ladder (as ``build_explain_node`` does) and
+    returns ``{"planDigest", "lane", "compile"}`` where ``compile()``
+    pays the XLA compile of the program the first serving launch would
+    otherwise pay cold.  None is a *skip*, not a failure:
 
-    - host/postings/star-tree-only shapes compile no device kernel;
+    - host/postings/star-tree-only shapes compile no device kernel, and
+      the bit-sliced tier compiles its own (tiny) kernel per spec;
     - mesh-sharded shapes need device-placed lowering (not supported —
       sharded servers fall back to persistent-cache classification);
-    - chunked dispatch sequences are many programs, not one lowering;
+    - chunked dispatch sequences are many programs, not one lowering
+      (``kernel.plan_program`` hands back no ``.lower``);
     - shapes already in the lane's compile timeline are warm already.
     """
-    verdicts = prune_explain(segments, request)
-    live = [seg for seg, reason in verdicts if reason is None]
-    if not live:
-        return None
+    live = [seg for seg, reason in prune_explain(segments, request) if reason is None]
     from pinot_tpu.startree.operator import is_fit_for_star_tree
 
     normal = [s for s in live if not is_fit_for_star_tree(request, s)]
-    if not normal:
+    _selection, exec_mesh, lane = _route(executor, request)
+    if not normal or exec_mesh is not None or lane is None:
         return None
-    total_docs = sum(s.num_docs for s in segments)
-    needed = set(request.referenced_columns())
-    sel_columns: Optional[List[str]] = None
-    if request.is_selection:
-        sel_columns = executor._resolve_selection_columns(request, normal[0])
-        needed.update(sel_columns)
-    selection = None
-    if getattr(executor, "lanes", None) is not None:
-        selection = executor.lane_selection(request)
-    exec_mesh = selection.group.mesh if selection is not None else executor.mesh
-    if exec_mesh is not None:
-        return None
-    lane = selection.lane if selection is not None else getattr(executor, "lane", None)
-    if lane is None:
-        return None
-    needed -= executor._docrange_only_columns(request, normal, sel_columns)
+    total_docs, needed, _sel_columns, pad_to = ladder.scope(request, normal, exec_mesh)
     ctx = get_table_context(normal)
-    decision, state = index_path_decision(request, normal, ctx, total_docs)
-    if state is not None or plan_forced_host(request, ctx):
+    if ladder.first_accepting(request, normal, ctx, total_docs, exec_mesh)[0].name != "device":
         return None
-    from pinot_tpu.engine.bitsliced import bitsliced_decision
-
-    if bitsliced_decision(request, normal, ctx, total_docs)[1] is not None:
-        # the bit-sliced tier compiles its own (tiny) kernel per spec,
-        # not the standard StaticPlan kernel this prewarm would pay for
+    roles, phantom, scratch, plan, pdigest, _poison_key = _phantom_plan(request, normal, ctx, needed, pad_to)
+    if not plan.on_device or lane.compile_info(pdigest) is not None:
+        return None  # the host's, or already cold/warm/prewarmed here: nothing to pay
+    q_np, block_ids, _scanned = ladder.inputs(request, plan, ctx, normal, phantom, scratch)
+    # the builders keep a handle a plan: this is the SAME callable the
+    # serving launch will call, so an in-process AOT compile also seeds
+    # the persistent cache entry serving reads
+    kernel = ladder.program(plan, phantom, block_ids, exec_mesh)
+    if not hasattr(kernel, "lower"):
         return None
-    raw_cols, gfwd_cols, hll_cols = executor._role_columns(request, normal, ctx)
-    phantom = _phantom_staged(
-        normal,
-        list(needed) + list(request.referenced_columns()),
-        raw_cols, gfwd_cols, hll_cols,
-    )
-    scratch: Dict[Any, Any] = {}
-    plan = build_static_plan(request, ctx, phantom, scratch=scratch)
-    if not plan.on_device:
-        return None
-    pdigest = plan_digest(plan)
-    if lane.compile_info(pdigest) is not None:
-        return None  # already cold/warm/prewarmed here: nothing to pay
-    q_np = build_query_inputs(request, plan, ctx, phantom, scratch=scratch)
-    block_ids, _scanned = executor._block_skip_ids(plan, q_np, normal, phantom)
-    from pinot_tpu.engine.kernel import (
-        chunk_rows_limit,
-        make_packed_block_table_kernel,
-        make_packed_table_kernel,
-        plan_chunkable,
-    )
-
-    _limit = chunk_rows_limit()
-    rows_total = phantom.num_segments * phantom.n_pad
-    if block_ids is not None and _limit and rows_total > _limit:
-        block_ids = None  # mirrors the executor's guard
-    if block_ids is None and _limit and rows_total > _limit and plan_chunkable(plan):
-        return None  # chunked dispatch sequence: not one lowerable program
-    skip_base = executor._skip_base_columns(
-        request, normal, raw_cols, gfwd_cols, hll_cols
-    )
-    seg_avals = _phantom_segment_avals(phantom, needed, ctx, skip_base)
+    lower_args = (_phantom_segment_avals(phantom, needed, ctx, roles[3]), q_np)
     if block_ids is not None:
-        from pinot_tpu.engine.zonemap import zone_block_rows
-
         import jax
 
-        kernel = make_packed_block_table_kernel(plan, zone_block_rows())
-        ids = np.asarray(block_ids)
-        lower_args = (seg_avals, q_np, jax.ShapeDtypeStruct(ids.shape, ids.dtype))
-    else:
-        # the factories are lru_cached per plan: this is the SAME
-        # callable the serving launch will call, so an in-process AOT
-        # compile also seeds the persistent cache entry serving reads
-        kernel = make_packed_table_kernel(plan)
-        lower_args = (seg_avals, q_np)
+        lower_args += (jax.ShapeDtypeStruct(block_ids.shape, block_ids.dtype),)
 
     def compile_now() -> None:
         kernel.lower(*lower_args).compile()

@@ -742,80 +742,7 @@ def _segment_add_matmul_multi(flat_idx, W, capacity: int):
 _FACTORED_CHUNK = int(_os.environ.get("PINOT_TPU_FACTORED_CHUNK", str(1 << 15)))
 
 
-_PALLAS_HIST_BLOCK = 2048
-
-
-def _value_state_counts_pallas(flat_idx, K: int, interpret: bool = False):
-    """Pallas variant of the factored occupancy contraction: the two
-    thin one-hots are GENERATED in VMEM per block and contracted into a
-    VMEM-resident [K1, 128] accumulator, so HBM traffic is the index
-    stream alone (the XLA form streams both generated one-hots through
-    HBM, ~512 B/row at K=2^14).  Gated by PINOT_TPU_VALUE_STATE_PALLAS
-    and not judged on the chip (ROADMAP D4); semantics are
-    identical to _value_state_counts.  Compiled for the TPU unless a
-    test passes ``interpret=True``."""
-    from jax.experimental import pallas as pl
-
-    fdt = jnp.float32
-    n = flat_idx.shape[0]
-    if n == 0:
-        # grid (0,) would never run the i==0 init — return exact zeros
-        # like the XLA variant
-        return jnp.zeros(K, dtype=config.float_dtype())
-    blk = _PALLAS_HIST_BLOCK
-    pad = (-n) % blk
-    if pad:
-        flat_idx = jnp.concatenate([flat_idx, jnp.full(pad, K, flat_idx.dtype)])
-    nb = flat_idx.shape[0] // blk
-    K1 = -(-K // 128)
-    # [nb, 1, blk] with the leading axis squeezed out of the block: Mosaic
-    # wants a block's last two dims divisible by (8, 128) or equal to the
-    # array's, and a [1, blk] block of an [nb, blk] array is neither
-    blocks = flat_idx.reshape(nb, 1, blk)
-
-    def kernel(idx_ref, out_ref):
-        i = pl.program_id(0)
-
-        @pl.when(i == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        idx = idx_ref[0, :]  # [blk] int32
-        hi_iota = jax.lax.broadcasted_iota(jnp.int32, (blk, K1), 1)
-        lo_iota = jax.lax.broadcasted_iota(jnp.int32, (blk, 128), 1)
-        hi = ((idx[:, None] // 128) == hi_iota).astype(jnp.bfloat16)
-        lo = ((idx[:, None] % 128) == lo_iota).astype(jnp.bfloat16)
-        out_ref[...] += jax.lax.dot_general(
-            hi, lo, (((0,), (0,)), ((), ())), preferred_element_type=fdt
-        )
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((None, 1, blk), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((K1, 128), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((K1, 128), fdt),
-        # the sequential-grid accumulator idiom (i==0 init + +=) needs
-        # grid steps to run in order: the compiled TPU grid, or the
-        # interpreter
-        interpret=interpret,
-    )(blocks)
-    return out.reshape(-1)[:K].astype(config.float_dtype())
-
-
-def _use_pallas_value_state() -> bool:
-    return _os.environ.get("PINOT_TPU_VALUE_STATE_PALLAS") == "1"
-
-
 def _value_state_counts(flat_idx, K: int):
-    """Gated dispatch: the Pallas histogram when enabled and available,
-    else the XLA factored contraction."""
-    if _use_pallas_value_state():
-        return _value_state_counts_pallas(flat_idx, K)
-    return _value_state_counts_xla(flat_idx, K)
-
-
-def _value_state_counts_xla(flat_idx, K: int):
     """Occupancy counts over a combined value-state key space of size K
     with a FACTORED one-hot contraction: split the key into (hi, lo)
     radix-128 digits and contract two THIN one-hots as a real
@@ -848,8 +775,6 @@ def _value_state_counts_xla(flat_idx, K: int):
         hi, lo, (((1,), (1,)), ((0,), (0,))), preferred_element_type=fdt
     )
     return jnp.sum(out, axis=0).reshape(-1)[:K]
-
-
 
 
 def _row_shaped(key: str) -> bool:
@@ -1621,7 +1546,7 @@ def _hll_rows(agg: StaticAgg, seg, bucket, rho):
 
 def _value_gids(agg: StaticAgg, seg, remap):
     """Per-row GLOBAL value ids for an SV presence/hist agg: prefer
-    the host-staged global-id stream (``.gfwd``, executor._role_columns)
+    the host-staged global-id stream (``.gfwd``, ladder._role_columns)
     over an on-device remap-table gather — device gathers serialize on
     TPU at any cardinality (2026-07 chip measurement, ROADMAP S5)."""
     gf = seg.get(f"{agg.column}.gfwd")
@@ -2202,16 +2127,6 @@ def make_block_table_kernel(plan: StaticPlan, block: int) -> Callable:
     return jax.jit(named(table_fn, kernel_name("zone", plan)))
 
 
-def make_chunked_table_kernel(plan: StaticPlan, num_segments: int, n_pad: int) -> Callable:
-    """The table kernel, dispatched over segment-axis chunks when the
-    table exceeds the per-dispatch row budget.  Falls back to the plain
-    kernel when chunking is off, unnecessary, or the plan isn't
-    chunk-combinable."""
-    # the resolved limit is part of the cache key: a kernel built under
-    # one PINOT_TPU_CHUNK_ROWS value must not be reused after it changes
-    return _chunked_table_kernel(plan, num_segments, n_pad, chunk_rows_limit())
-
-
 def _pick_chunk(num_segments: int, n_pad: int, limit: int, granularity: int = 1) -> int:
     """Segments per dispatch under the row budget, in multiples of
     ``granularity`` (the mesh device count on sharded paths).  Prefers
@@ -2235,9 +2150,20 @@ def _pick_chunk(num_segments: int, n_pad: int, limit: int, granularity: int = 1)
     return chunk
 
 
-def _chunked_run(table: Callable, reducers: Dict[str, str], num_segments: int, chunk: int) -> Callable:
+@functools.lru_cache(maxsize=64)
+def _chunked_program(plan: StaticPlan, mesh, num_segments: int, chunk: int) -> Callable:
+    """The table program (``mesh``: the sharded one), dispatched over
+    the segment axis ``chunk`` segments at a time, the chunks' reduced
+    outputs combined as the program's own merge combines them."""
     from pinot_tpu.engine.packing import make_packed_kernel
 
+    if mesh is None:
+        table = make_table_kernel(plan)
+    else:
+        from pinot_tpu.parallel.multichip import make_sharded_table_kernel
+
+        table = make_sharded_table_kernel(plan, mesh)
+    reducers = output_reducers(plan)
     # the combined outputs still fetch via ONE packed D2H transfer —
     # per-leaf fetches pay a transfer each (engine/packing.py).  The
     # chunks run as ``table``'s program; the pack is named after it.
@@ -2269,40 +2195,19 @@ def _chunked_run(table: Callable, reducers: Dict[str, str], num_segments: int, c
     return run
 
 
-@functools.lru_cache(maxsize=64)
-def _chunked_table_kernel(
-    plan: StaticPlan, num_segments: int, n_pad: int, limit: int
-) -> Callable:
-    chunk = _pick_chunk(num_segments, n_pad, limit)
-    if not limit or num_segments <= chunk or not plan_chunkable(plan):
-        return make_table_kernel(plan)
-    return _chunked_run(make_table_kernel(plan), output_reducers(plan), num_segments, chunk)
-
-
-def make_chunked_sharded_kernel(plan: StaticPlan, mesh, num_segments: int, n_pad: int):
-    """Mesh analog of ``make_chunked_table_kernel``: chunks the GLOBAL
-    segment axis in device-count multiples when the per-device row
-    share exceeds the dispatch budget, so pod-scale tables hit the same
-    capacity path the single chip does.  Returns the plain packed
-    sharded kernel when chunking is off or unnecessary."""
+@functools.lru_cache(maxsize=256)
+def _packed_sharded_kernel(plan: StaticPlan, mesh, block: Optional[int]) -> Callable:
+    """The mesh's program (``block``: its zone form) behind the
+    single-transfer fetch.  ``Mesh`` hashes and compares by its devices
+    and axis names, which is a placement's identity: two chip groups
+    never share a program, two lanes over one group do."""
     from pinot_tpu.engine.packing import make_packed_kernel
     from pinot_tpu.parallel.multichip import make_sharded_table_kernel
 
-    limit = chunk_rows_limit()
-    n_dev = int(mesh.devices.size)
-    chunk = (
-        _pick_chunk(num_segments, n_pad, limit * n_dev, granularity=n_dev)
-        if limit
-        else num_segments
+    return make_packed_kernel(
+        make_sharded_table_kernel(plan, mesh, block), kernel_name("mesh" if block is None else "meshzone", plan)
     )
-    if not limit or num_segments <= chunk or not plan_chunkable(plan):
-        return make_packed_kernel(make_sharded_table_kernel(plan, mesh), kernel_name("mesh", plan))
-    return _chunked_run(
-        make_sharded_table_kernel(plan, mesh),
-        output_reducers(plan),
-        num_segments,
-        chunk,
-    )
+
 
 @functools.lru_cache(maxsize=256)
 def make_packed_table_kernel(plan: StaticPlan) -> Callable:
@@ -2320,6 +2225,40 @@ def make_packed_block_table_kernel(plan: StaticPlan, block: int) -> Callable:
     from pinot_tpu.engine.packing import make_packed_kernel
 
     return make_packed_kernel(make_block_table_kernel(plan, block), kernel_name("zone", plan))
+
+
+def plan_program(plan: StaticPlan, num_segments: int, n_pad: int, block: Optional[int] = None, mesh=None) -> Callable:
+    """The device program that answers ``plan`` over a staged table of
+    ``num_segments`` x ``n_pad`` rows: the one place that chooses among
+    the builders of this module, for the executor's launch, EXPLAIN and
+    the prewarm worker alike.  ``block``: the zone tier's block rows
+    where the launch carries candidate block ids, else None; ``mesh``:
+    the chip group the segment axis is sharded over, else None.
+
+    What comes back has ``.dispatch`` and ``.fetch`` (engine/packing.py)
+    and, where it is one program, ``.lower``; a table over the
+    per-dispatch row budget (``chunk_rows_limit``, per device on a mesh)
+    whose outputs combine elementwise is a sequence of launches of the
+    table program and has none.  Every handle is kept where its builder
+    keeps it (the ``lru_cache``s here): asked twice, the same callable,
+    so jit's executables are found again and a program the prewarm
+    worker compiled is the one the first launch calls.
+
+    ``kernel_name`` tiers: ``scan`` and ``zone`` on one device, ``mesh``
+    and ``meshzone`` sharded.  The zone program has no chunked form:
+    ``ladder.inputs`` hands a table over the budget no block ids."""
+    if block is not None:
+        return make_packed_block_table_kernel(plan, block) if mesh is None else _packed_sharded_kernel(plan, mesh, block)
+    limit = chunk_rows_limit()
+    n_dev = 1 if mesh is None else int(mesh.devices.size)
+    # beyond the budget the kernel's per-row temporaries exceed HBM at
+    # compile time: run segment-axis chunks (in multiples of the mesh's
+    # devices) and combine the reduced outputs
+    if limit and num_segments * n_pad > limit * n_dev and plan_chunkable(plan):
+        chunk = _pick_chunk(num_segments, n_pad, limit * n_dev, granularity=n_dev)
+        if num_segments > chunk:
+            return _chunked_program(plan, mesh, num_segments, chunk)
+    return make_packed_table_kernel(plan) if mesh is None else _packed_sharded_kernel(plan, mesh, None)
 
 
 @functools.lru_cache(maxsize=128)

@@ -489,7 +489,7 @@ def build_static_plan(
         if any(not staged.column(c).single_value for c in a.columns):
             is_mv = True
         # every leaf of the argument streams its staged raw values, or
-        # every leaf gathers dict[fwd] (executor._role_columns)
+        # every leaf gathers dict[fwd] (ladder._role_columns)
         use_raw = (
             a.column != "*"
             and not is_mv

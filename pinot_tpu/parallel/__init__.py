@@ -1,7 +1,3 @@
-from pinot_tpu.parallel.multichip import (
-    default_mesh,
-    make_sharded_table_kernel,
-    run_sharded_query,
-)
+from pinot_tpu.parallel.multichip import default_mesh, make_sharded_table_kernel
 
-__all__ = ["default_mesh", "make_sharded_table_kernel", "run_sharded_query"]
+__all__ = ["default_mesh", "make_sharded_table_kernel"]

@@ -149,7 +149,7 @@ def _make_sharded(plan: StaticPlan, mesh: Mesh, stacked: Callable, n_extra: int)
     return jax.jit(named(sharded, kernel_name("meshzone" if n_extra else "mesh", plan)))
 
 
-def make_sharded_table_kernel(plan: StaticPlan, mesh: Mesh) -> Callable:
+def make_sharded_table_kernel(plan: StaticPlan, mesh: Mesh, block: Optional[int] = None) -> Callable:
     """Compile the query kernel as an SPMD program over the mesh.
 
     Takes the same (seg_arrays, query_inputs) pytrees as the
@@ -160,19 +160,15 @@ def make_sharded_table_kernel(plan: StaticPlan, mesh: Mesh) -> Callable:
     axis shards over all mesh axes and the merge collectives name all
     of them, so XLA lowers the reduction hierarchically — ICI inside a
     host, DCN across hosts.
+
+    With ``block`` (the zone tier's block rows) it is the block-skipping
+    program: the block id array [S, nb_pad] is a third operand and
+    shards over the segment axis with everything else, so selective
+    queries stay O(candidate blocks) per chip (an 'inplace' plan's loop
+    runs over the union of its shard's ids).
     """
-    return _make_sharded(plan, mesh, jax.vmap(make_single_segment_kernel(plan)), 0)
-
-
-def make_sharded_block_table_kernel(plan: StaticPlan, mesh: Mesh, block: int) -> Callable:
-    """Zone-map block-skipping variant of the sharded kernel: the block
-    id array [S, nb_pad] shards over the segment axis with everything
-    else, so selective queries stay O(candidate blocks) per chip (an
-    'inplace' plan's loop runs over the union of its shard's ids)."""
+    if block is None:
+        return _make_sharded(plan, mesh, jax.vmap(make_single_segment_kernel(plan)), 0)
     from pinot_tpu.engine.kernel import make_stacked_block_kernel
 
     return _make_sharded(plan, mesh, make_stacked_block_kernel(plan, block), 1)
-
-
-def run_sharded_query(plan: StaticPlan, mesh: Mesh, seg_arrays, q_inputs):
-    return make_sharded_table_kernel(plan, mesh)(seg_arrays, q_inputs)

@@ -159,19 +159,40 @@ def test_explain_golden_schema_and_zero_device_work(explain_broker):
     assert server.metrics.meter("plan.explains").count == 1
 
 
-def test_explain_device_digest_matches_real_execution(explain_broker):
+# a filter no block's zone can hold, with the host tiers out of its way
+_ZONE_FILTER = {"PINOT_TPU_ZONE_BLOCK": "32", "PINOT_TPU_INVINDEX": "0", "PINOT_TPU_BITSLICED": "0"}
+
+
+@pytest.mark.parametrize(
+    "settings, where, tier",
+    [
+        ({}, "dimInt > 40", "segmentsBitsliced"),
+        (_ZONE_FILTER, "dimInt > 1000000", "segmentsZonemap"),
+        # the zone program has no chunked form: a table over the
+        # per-dispatch row budget (2 x 1,024 staged rows here) drops its
+        # block ids, in EXPLAIN as at execution (ladder.inputs)
+        (dict(_ZONE_FILTER, PINOT_TPU_CHUNK_ROWS="1024"), "dimInt > 1000000", "segmentsFullScan"),
+    ],
+    ids=["bitsliced", "zone_filter", "zone_filter_over_chunk_rows"],
+)
+def test_explain_device_digest_matches_real_execution(explain_broker, monkeypatch, settings, where, tier):
     """The phantom-staged StaticPlan digest must equal the digest the
     real execution hands the lane — else the compile registry and the
-    poison-honesty lookup would silently miss."""
+    poison-honesty lookup would silently miss — and the tier EXPLAIN
+    names is the tier that serves."""
+    for name, value in settings.items():
+        monkeypatch.setenv(name, value)
     broker = explain_broker
     server = broker.local_servers[0]
-    pql = "SELECT sum(metInt) FROM expTable WHERE dimInt > 40"
+    pql = "SELECT sum(metInt) FROM expTable WHERE " + where
     pre = broker.handle_pql("EXPLAIN " + pql)
+    assert pre.explain["servers"][0]["tierCounts"] == {tier: 2}
     dev = pre.explain["servers"][0]["device"]
     assert dev["compile"]["state"] == "cold"  # never launched here
 
     real = broker.handle_pql(pql)
     assert not real.exceptions
+    assert real.cost[tier] == 2
     assert server.lane.stats()["compiledPlans"] >= 1
     assert server.lane.compile_info(dev["planDigest"]) is not None, (
         "phantom plan digest diverged from the real staged plan"

@@ -17,7 +17,7 @@ import pytest
 
 from pinot_tpu.common.request import expr_text
 from pinot_tpu.common.schema import DataType, FieldSpec, FieldType, Schema
-from pinot_tpu.engine import kernel as kernel_mod
+from pinot_tpu.engine import kernel as kernel_mod, ladder
 from pinot_tpu.engine.executor import QueryExecutor
 from pinot_tpu.engine.mesh import build_topology
 from pinot_tpu.engine.plandigest import plan_shape_digest
@@ -283,7 +283,7 @@ def _static_plan(segments, pql):
 
     request = optimize_request(parse_pql(pql))
     ctx = get_table_context(segments)
-    raw, gfwd, hll = QueryExecutor()._role_columns(request, segments, ctx)
+    raw, gfwd, hll, _skip_base = ladder.roles(request, segments, ctx)
     staged = get_staged(segments, request.referenced_columns(), raw_columns=raw, gfwd_columns=gfwd, hll_columns=hll,
                         ctx=ctx)
     return build_static_plan(request, ctx, staged)

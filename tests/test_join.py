@@ -940,6 +940,19 @@ def test_join_strategies_over_networked_cluster(tmp_path):
         q = "SELECT count(*), sum(f.v) FROM fN f JOIN dN d ON f.k = d.k"
 
         def serving():
+            # the default strategy answers as soon as one replica of every
+            # segment is ONLINE, by whichever strategy is eligible then; the
+            # forced ``colocated`` below also needs both tables' partitioning
+            # and, on every server of the probe's cover, the build side's
+            # partition: every replica ONLINE in the broker's view (lost
+            # under load: "srv1 lacks local build partitions [1]")
+            known = cl.broker.joinplan.partitions
+            if known.get("fN") != ("k", 2) or known.get("dN") != ("k", 2):
+                return False
+            for phys in (fphys, dphys):
+                view = cl.broker.routing.view_of(phys) or {}
+                if len(view) != 2 or any(sorted(r.values()) != ["ONLINE", "ONLINE"] for r in view.values()):
+                    return False
             r = cl.query(q)
             return not r.exceptions and int(
                 r.aggregation_results[0].value

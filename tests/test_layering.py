@@ -9,8 +9,11 @@ debt ROADMAP names (D14); the list may only shrink: a case fails on an
 upward import that is not on it, and on a listed pair that no longer
 occurs.
 
-The last walk is over calls, not imports: every ``lax.sort`` of the
-package says whether it is stable.
+The last two walks are not over packages.  One is over calls: every
+``lax.sort`` of the package says whether it is stable.  The other holds
+the serving ladder to its one place (``engine/ladder.py``): EXPLAIN and
+the prewarm worker import no tier's decision, no plan builder and no
+kernel builder of their own, and the executor chooses no program.
 """
 import ast
 import os
@@ -124,3 +127,33 @@ def test_every_device_sort_says_whether_it_is_stable():
     keys (PR 44: 3.3 ns a row against 1.6 over 100.7M packed keys).  A
     call that leaves the choice to the default has not made it."""
     assert not sort_calls_without_is_stable()
+
+
+# what decides a tier, builds a plan or its inputs, or chooses a program
+LADDER_INGREDIENTS = {
+    "index_path_decision", "bitsliced_decision", "plan_forced_host", "group_by_host_reason",
+    "build_static_plan", "build_query_inputs", "chunk_rows_limit", "plan_chunkable",
+}
+
+
+def test_the_ladder_is_read_not_walked_again():
+    """``engine/explain.py`` holds ``build_explain_node`` and
+    ``build_prewarm_spec`` (all that ``server/prewarm.py`` asks of it):
+    both read ``ladder.TIERS`` and the device tier's derivations.  An
+    import of an ingredient there, or a call of one of the executor's
+    underscore methods, is a second walk of the order begun; a ``_kernel``
+    or ``_block_kernel`` on the executor is a second choice of a plan's
+    program beside ``kernel.plan_program``."""
+    trees = dict(parsed_modules(os.path.join(PACKAGE, "engine")))
+    explain = trees[os.path.join(PACKAGE, "engine", "explain.py")]
+    imported = {alias.name for node in ast.walk(explain) if isinstance(node, ast.ImportFrom) for alias in node.names}
+    assert not {name for name in imported if name in LADDER_INGREDIENTS or name.startswith("make_packed_")}
+    reached = {
+        node.attr
+        for node in ast.walk(explain)
+        if isinstance(node, ast.Attribute) and isinstance(node.value, ast.Name) and node.value.id == "executor"
+    }
+    assert not {name for name in reached if name.startswith("_")}
+    executor = trees[os.path.join(PACKAGE, "engine", "executor.py")]
+    defined = {node.name for node in ast.walk(executor) if isinstance(node, ast.FunctionDef)}
+    assert not defined & {"_kernel", "_block_kernel"}

@@ -263,29 +263,3 @@ def test_repeated_query_uses_input_cache_on_device(cluster):
     req3 = optimize_request(parse_pql(pql.replace("1998-09-02", "1994-01-01")))
     third = reduce_to_response(req3, [ex.execute(segs, req3)]).to_json()
     assert third["aggregationResults"] != first["aggregationResults"]
-
-
-# ---------------------------------------------------------------------------
-# The Pallas occupancy histogram, compiled by Mosaic for this chip (not
-# the interpreter the CPU suite uses) and held to the XLA lowering it
-# stands beside.
-# ---------------------------------------------------------------------------
-PALLAS_ROWS = 1 << 20
-
-
-def test_pallas_value_state_histogram_compiles_and_matches_xla():
-    import jax.numpy as jnp
-
-    from pinot_tpu.engine.kernel import (
-        _value_state_counts_pallas,
-        _value_state_counts_xla,
-    )
-
-    K = 1 << 14  # the HLL presence shape
-    rng = np.random.default_rng(5)
-    idx = jnp.asarray(rng.integers(0, K + 1, size=PALLAS_ROWS).astype(np.int32))
-    compiled = jax.jit(lambda i: _value_state_counts_pallas(i, K)).lower(idx).compile()
-    want = np.asarray(jax.jit(lambda i: _value_state_counts_xla(i, K))(idx))
-    assert np.array_equal(np.asarray(compiled(idx)), want)
-
-
