@@ -489,7 +489,6 @@ def _dispatch_bitsliced(
     res._lane_index = lane_index
     res._batch_size = int(exec_info.get("batchSize", 1) or 1)
     m = executor.metrics
-    m.meter("filter.bitsliced.queries").mark()
     m.meter("filter.bitsliced.planes").mark(planes_total)
     m.meter("filter.bitsliced.fusedAggs").mark(len(agg_descs))
     m.meter("filter.bitsliced.bytes").mark(dev_bytes)

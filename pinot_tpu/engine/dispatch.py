@@ -444,7 +444,7 @@ class DeviceLane:
     docstring).
 
     ``stall_timeout_s`` arms the watchdog (default from
-    ``PINOT_TPU_LANE_STALL_S``, 120s — above the worst observed cold
+    ``PINOT_TPU_LANE_STALL_S``, 300s — above the worst observed cold
     compile; <= 0 disables it).
     ``fault_injector`` is an optional ``common.faults``
     ``DeviceFaultInjector`` consulted before every launch."""
@@ -468,8 +468,11 @@ class DeviceLane:
         if stall_timeout_s is None:
             # default well ABOVE the worst first-call compile: a
             # watchdog that fires during a legitimate cold compile
-            # would poison a healthy plan
-            stall_timeout_s = float(os.environ.get("PINOT_TPU_LANE_STALL_S", "120"))
+            # poisons a healthy plan, and the host answers the shape from
+            # then on (chip run, PR 47: the sorted contraction over three
+            # string keys, SSB Q3.2 at [16, 2^23] rows, compiles for
+            # 126 to 135 s cold; 120 s failed a server's first Q3.2)
+            stall_timeout_s = float(os.environ.get("PINOT_TPU_LANE_STALL_S", "300"))
         self.stall_timeout_s = stall_timeout_s
         self.fault_injector = fault_injector
         # persistent compile cache (engine/compilecache.py): on by

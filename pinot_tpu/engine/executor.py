@@ -325,6 +325,8 @@ class QueryExecutor:
         self._prepared_lock = threading.Lock()
         for outcome in ("hit", "miss", "stale"):
             metrics.meter(f"plan.prepared.{outcome}")
+        # one mark a query by the rung that answered it (_finish_tier)
+        self._tier_answered = {t.name: metrics.meter(f"tier.answered.{t.name}") for t in ladder.TIERS}
         metrics.gauge("plan.prepared.entries").set_fn(lambda: len(self._prepared))
         for place in ("device", "host"):  # both from the start: a share needs the one that stays 0
             metrics.meter(f"agg.expr.{place}")
@@ -472,10 +474,13 @@ class QueryExecutor:
         self, result: IntermediateResult, request: BrokerRequest, tier: str
     ) -> IntermediateResult:
         """Every ``_execute_engine`` exit point: stamp which serving
-        tier produced the answer (the audit plane's quarantine key) and
-        consult the armed wrong-answer injection, if any (chaos tests
-        only — production lanes have no fault injector)."""
+        tier produced the answer (the audit plane's quarantine key),
+        mark it (``tier.answered.<tier>``, one mark a query that the
+        ladder answered; a failover's answer marks ``host``) and consult
+        the armed wrong-answer injection, if any (chaos tests only —
+        production lanes have no fault injector)."""
         result._served_tier = tier
+        self._tier_answered[tier].mark()
         inj = self._fault_injector()
         if inj is not None and getattr(inj, "corruption_armed", False):
             from pinot_tpu.engine.plandigest import plan_shape_digest
@@ -882,13 +887,12 @@ class QueryExecutor:
                 self._heal_mark("deviceRetries")
                 ph.enter("staging")
             try:
-                return self._finish_tier(
-                    self._device_section(
-                        live, request, deadline, ctx, use, ph, poison_ref, sel=sel, mesh=mesh,
-                    ),
-                    request,
-                    tier.name,
+                res = self._device_section(
+                    live, request, deadline, ctx, use, ph, poison_ref, sel=sel, mesh=mesh,
                 )
+                # the section answers from the host where the plan is not
+                # on the device, is quarantined, or overflowed its pairs
+                return self._finish_tier(res, request, "host" if poison_ref.get("host") else tier.name)
             except (QueryAbandonedError, LaneClosedError, TimeoutError):
                 raise
             except Exception as e:
@@ -1508,6 +1512,9 @@ class QueryExecutor:
             # held to the whole state and not to the n it returns
             self.metrics.meter("groupby.groups.live").mark(live_groups)
             self.metrics.meter("groupby.groups.kept").mark(len(res.groups))
+            # the cells the plan sized the group space at (the product of
+            # the keys' table cardinalities), beside what was found live
+            self.metrics.meter("groupby.keySpaceCells").mark(plan.group_by.capacity)
             res.add_cost(numGroupsLive=live_groups, numGroupsKept=len(res.groups), **digest)
         elif plan.aggs:
             res.aggregations = [

@@ -374,6 +374,8 @@ def build_explain_node(
                     sharded_plan=plan if exec_mesh is not None else None,
                     quarantined=poison is not None,
                 )
+                if plan.group_by is not None:
+                    device_info["groupBy"] = _group_by_record(request, ctx, plan)
             if poison is not None:
                 # HONESTY: the device plan is quarantined, so this
                 # query will ACTUALLY serve from the host path — the
@@ -471,6 +473,51 @@ def build_explain_node(
     if device_info is not None:
         node["device"] = device_info
     return _json_safe(node)
+
+
+def _group_by_record(request: BrokerRequest, ctx, plan) -> Dict[str, Any]:
+    """A device group-by as the planner sized and lowered it: the planned
+    key space (``plan.group_capacity``: the product of the keys' table
+    cardinalities, what the group state holds a cell for, or what sends
+    the plan to the 'runs' lowering), the space the filter's own leaves on
+    the keys leave where it is smaller, and the answers of
+    ``kernel.groupby_lowering`` and ``groupby_operands``, which the
+    launch's tags and marks repeat."""
+    from pinot_tpu.engine.kernel import groupby_lowering, groupby_operands
+
+    out = {
+        "keySpaceCells": int(plan.group_by.capacity),
+        "lowering": groupby_lowering(plan),
+        "operands": groupby_operands(plan),
+    }
+    left = _filtered_key_space(request, ctx)
+    if left != plan.group_by.capacity:
+        out["filteredKeySpaceCells"] = left
+    return out
+
+
+def _filtered_key_space(request: BrokerRequest, ctx) -> int:
+    """The product, over the group columns, of the column's table values
+    that pass the filter's EQ, IN and RANGE leaves on that column (the
+    leaves of a root-level AND, or a filter of one leaf: what
+    ``invindex_path._decompose`` takes as driving candidates).  Printed,
+    never planned by: ``c_city IN (a, b) ... GROUP BY c_city`` reads 2 of
+    250, and a predicate on a column that determines a key (``c_nation =
+    x`` leaves ten cities) is not followed, since the table keeps no such
+    dependency."""
+    from pinot_tpu.engine.invindex_path import _decompose
+    from pinot_tpu.engine.plan import match_table
+
+    leaves = (_decompose(request.filter) or ((), ()))[0] if request.filter is not None else ()
+    cells = 1
+    for c in request.group_by.columns:
+        gdict = ctx.column(c).global_dict
+        passing = np.ones(max(gdict.cardinality, 1), dtype=bool)
+        for leaf in leaves:
+            if leaf.column == c:
+                passing &= match_table(leaf, gdict, passing.size)
+        cells *= max(int(passing.sum()), 1)
+    return cells
 
 
 # ---------------------------------------------------------------------------

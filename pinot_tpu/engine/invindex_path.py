@@ -282,4 +282,22 @@ def run_index_path(
     res.cost.pop("segmentsHost", None)
     res.cost["segmentsPostings"] = len(live)
     res.cost["bytesScanned"] = est * max(1, len(residuals) + 1) * 8
+    if request.is_group_by:
+        res.add_cost(**group_state_digest(request, res.groups))
     return res
+
+
+def group_state_digest(request: BrokerRequest, groups) -> dict:
+    """What a device group-by's finalize puts on the cost vector of its
+    whole fetched state (``executor._finalize``: ``numGroupsLive``,
+    ``numGroupsKept``, ``groupStateSumSq``), of a postings answer's
+    groups, so that a reply is held to the same three whichever tier
+    made it.  The tier answers O(matches) rows and trims nothing: every
+    group found is live and kept.  The digest is the sum of squares, in
+    float64, of every group's value of each aggregate whose device state
+    is dense floats (count, sum, min, max, avg, minmaxrange)."""
+    from pinot_tpu.engine.plan import _agg_kind
+
+    dense = [i for i, a in enumerate(request.aggregations) if _agg_kind(a.base_function) in ("scalar", "pair")]
+    sum_sq = sum(float(partials[i].finalize()) ** 2 for partials in groups.values() for i in dense)
+    return {"numGroupsLive": len(groups), "numGroupsKept": len(groups), "groupStateSumSq": sum_sq}

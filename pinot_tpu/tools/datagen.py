@@ -168,6 +168,7 @@ def _synthetic_columnar_segment(
     time_column: Optional[str] = None,
     rng=None,
     drawn_last: Sequence[str] = (),
+    made: Optional[Dict[str, Any]] = None,
 ):
     """Shared fast-path builder behind every synthetic_*_segment:
     ColumnData built directly from per-column value pools (dictIds drawn
@@ -179,7 +180,10 @@ def _synthetic_columnar_segment(
     sequence (and thus seeded data) stays reproducible.  Rows are drawn
     column by column in the schema's order, the ``drawn_last`` columns
     after the others: a schema that adds a column to another's can keep
-    the other's data for a seed."""
+    the other's data for a seed.  ``made`` holds the dictIds of columns
+    the caller made itself (a column that follows from another, as a
+    city's nation does): those are not drawn, and index the column's
+    pool in its dictionary's order."""
     import numpy as np
 
     from pinot_tpu.common.schema import DataType
@@ -200,9 +204,12 @@ def _synthetic_columnar_segment(
         else:
             d = Dictionary(spec.stored_type, np.unique(np.asarray(vals)))
         card = d.cardinality
-        fwd = rng.integers(0, card, size=num_rows, dtype=np.int64).astype(np.int32)
-        if spec.name == clustered_column:
-            fwd.sort()
+        if made is not None and spec.name in made:
+            fwd = np.asarray(made[spec.name], dtype=np.int32)
+        else:
+            fwd = rng.integers(0, card, size=num_rows, dtype=np.int64).astype(np.int32)
+            if spec.name == clustered_column:
+                fwd.sort()
         columns[spec.name] = ColumnData(
             metadata=ColumnMetadata(
                 name=spec.name,
@@ -562,3 +569,139 @@ def synthetic_hits_users_segment(
 
     smeta.crc = zlib.crc32(f"{name}:{num_rows}:{seed}".encode())
     return seg
+
+
+# ---------------------------------------------------------------------------
+# The Star Schema Benchmark (O'Neil, O'Neil, Chen, Revilak, 2009) as the
+# denormalised table ``lineorder_flat`` (ClickHouse's SSB documentation):
+# lineorder with the attributes of its customer, supplier, part and date
+# folded in at ingestion, which is how a store without a join serves it.
+# dbgen's data files are not shipped; the domains are dbgen's, and the
+# functional dependencies hold row by row.
+# ---------------------------------------------------------------------------
+
+SSB_TABLE = "lineorder_flat"
+SSB_FIRST_DAY, SSB_DAYS = "1992-01-01", 2406  # SSB's date dimension: to 1998-08-02
+SSB_REGIONS = ("AFRICA", "AMERICA", "ASIA", "EUROPE", "MIDDLE EAST")
+# five nations a region, in the regions' order (TPC-H's 25)
+SSB_NATIONS = (
+    "ALGERIA", "ETHIOPIA", "KENYA", "MOROCCO", "MOZAMBIQUE",
+    "ARGENTINA", "BRAZIL", "CANADA", "PERU", "UNITED STATES",
+    "CHINA", "INDIA", "INDONESIA", "JAPAN", "VIETNAM",
+    "FRANCE", "GERMANY", "ROMANIA", "RUSSIA", "UNITED KINGDOM",
+    "EGYPT", "IRAN", "IRAQ", "JORDAN", "SAUDI ARABIA",
+)
+# ten cities a nation: the nation's first nine characters (padded) and a digit
+SSB_CITIES = tuple(f"{n[:9]:<9}{d}" for n in SSB_NATIONS for d in range(10))
+SSB_MFGRS = tuple(f"MFGR#{m}" for m in range(1, 6))
+SSB_CATEGORIES = tuple(f"{m}{c}" for m in SSB_MFGRS for c in range(1, 6))  # 'MFGR#14'
+SSB_BRANDS = tuple(f"{c}{b}" for c in SSB_CATEGORIES for b in range(1, 41))  # 'MFGR#2221'
+_SSB_MONTHS = ("Jan", "Feb", "Mar", "Apr", "May", "Jun", "Jul", "Aug", "Sep", "Oct", "Nov", "Dec")
+_SSB_UNIT_PRICES = 1024  # distinct part prices a segment
+
+
+def lineorder_flat_schema() -> Schema:
+    """Every column that SSB's thirteen queries read, under the flat
+    table's names in lower case: 19 of its some 40 (the keys
+    ``lo_custkey``, ``lo_suppkey``, ``lo_partkey`` are left out and
+    their attributes kept)."""
+    dims = [FieldSpec(c, DataType.STRING) for c in (
+        "c_city", "c_nation", "c_region", "s_city", "s_nation", "s_region",
+        "p_mfgr", "p_category", "p_brand", "d_yearmonth")]
+    dims += [FieldSpec(c, DataType.INT) for c in ("lo_orderdate", "d_year", "d_yearmonthnum", "d_weeknuminyear")]
+    return Schema(
+        SSB_TABLE,
+        dimensions=dims,
+        metrics=[FieldSpec(c, DataType.INT, FieldType.METRIC) for c in (
+            "lo_quantity", "lo_discount", "lo_extendedprice", "lo_revenue", "lo_supplycost")],
+    )
+
+
+def _sorted_position(values: Sequence[str]):
+    """Where each of ``values`` stands in their sorted dictionary."""
+    import numpy as np
+
+    order = np.argsort(np.asarray(values, dtype=object), kind="stable")
+    at = np.empty(len(values), dtype=np.int32)
+    at[order] = np.arange(len(values), dtype=np.int32)
+    return at
+
+
+def synthetic_lineorder_flat_segment(num_rows: int, seed: int = 7, name: str = "lof0", segments: int = 16):
+    """One segment of ``lineorder_flat``: a contiguous range of order
+    dates, the ``k``-th of ``segments`` equal parts of SSB's 2,406 days
+    (``k``: the digits that end ``name``, modulo ``segments``), with
+    ``lo_orderdate`` sorted inside it.
+
+    A row's customer, supplier and part are drawn uniformly, as dbgen
+    draws the foreign keys, and stand here as the finest attribute the
+    queries read (the city of 250, the brand of 1,000); the coarser ones
+    follow from it, so city -> nation -> region and brand -> category ->
+    manufacturer hold row by row, as ``d_year``, ``d_yearmonthnum``,
+    ``d_yearmonth`` and ``d_weeknuminyear`` follow from the date.
+    ``lo_quantity`` 1..50 and ``lo_discount`` 0..10 uniform; a part's
+    price one of 1,024 a segment, uniform over dbgen's 90,000..209,900;
+    ``lo_extendedprice`` = quantity x price, ``lo_revenue`` =
+    extendedprice x (100 - discount) / 100 (dbgen's integer division),
+    ``lo_supplycost`` = 6 x price / 10.  The rows of ``seed`` are the
+    same in every process."""
+    import datetime
+
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    digits = "".join(ch for ch in name if ch.isdigit())
+    k = (int(digits[-6:]) if digits else 0) % segments
+    first, last = k * SSB_DAYS // segments, (k + 1) * SSB_DAYS // segments
+    day0 = datetime.date.fromisoformat(SSB_FIRST_DAY)
+    days = [day0 + datetime.timedelta(d) for d in range(first, last)]
+
+    made: Dict[str, Any] = {}
+    pools: Dict[str, Any] = {}
+    day = np.sort(rng.integers(0, len(days), num_rows, dtype=np.int32))
+    # the date's attributes: a value a day, the rows' ids through the day
+    by_day = {
+        "lo_orderdate": [d.year * 10000 + d.month * 100 + d.day for d in days],
+        "d_year": [d.year for d in days],
+        "d_yearmonthnum": [d.year * 100 + d.month for d in days],
+        "d_weeknuminyear": [(d.timetuple().tm_yday - 1) // 7 + 1 for d in days],
+        "d_yearmonth": [f"{_SSB_MONTHS[d.month - 1]}{d.year}" for d in days],
+    }
+    for col, values in by_day.items():
+        pools[col] = sorted(set(values))
+        at = {v: i for i, v in enumerate(pools[col])}
+        made[col] = np.asarray([at[v] for v in values], dtype=np.int32)[day]
+
+    # customer, supplier, part: the finest attribute drawn, the coarser by division
+    city_at, nation_at, region_at = (_sorted_position(v) for v in (SSB_CITIES, SSB_NATIONS, SSB_REGIONS))
+    for who in ("c", "s"):
+        city = rng.integers(0, len(SSB_CITIES), num_rows, dtype=np.int32)
+        made[f"{who}_city"], made[f"{who}_nation"], made[f"{who}_region"] = (
+            city_at[city], nation_at[city // 10], region_at[city // 50])
+        pools[f"{who}_city"], pools[f"{who}_nation"], pools[f"{who}_region"] = SSB_CITIES, SSB_NATIONS, SSB_REGIONS
+    brand = rng.integers(0, len(SSB_BRANDS), num_rows, dtype=np.int32)
+    made["p_brand"], made["p_category"], made["p_mfgr"] = (
+        _sorted_position(SSB_BRANDS)[brand], _sorted_position(SSB_CATEGORIES)[brand // 40],
+        _sorted_position(SSB_MFGRS)[brand // 200])
+    pools["p_brand"], pools["p_category"], pools["p_mfgr"] = SSB_BRANDS, SSB_CATEGORIES, SSB_MFGRS
+
+    # the measures: small tables over (quantity, price) and (extendedprice, discount)
+    prices = np.unique(rng.integers(90_000, 209_901, _SSB_UNIT_PRICES, dtype=np.int64))
+    quantity = rng.integers(0, 50, num_rows, dtype=np.int32)  # dictId of 1..50
+    discount = rng.integers(0, 11, num_rows, dtype=np.int32)  # dictId of 0..10
+    price = rng.integers(0, prices.size, num_rows, dtype=np.int32)
+    pools["lo_quantity"], made["lo_quantity"] = np.arange(1, 51, dtype=np.int64), quantity
+    pools["lo_discount"], made["lo_discount"] = np.arange(0, 11, dtype=np.int64), discount
+    ext_table = np.arange(1, 51, dtype=np.int64)[:, None] * prices[None, :]
+    pools["lo_extendedprice"], ext_at = np.unique(ext_table, return_inverse=True)
+    made["lo_extendedprice"] = ext_at.reshape(ext_table.shape).astype(np.int32)[quantity, price]
+    rev_table = pools["lo_extendedprice"][:, None] * (100 - np.arange(0, 11, dtype=np.int64))[None, :] // 100
+    pools["lo_revenue"], rev_at = np.unique(rev_table, return_inverse=True)
+    made["lo_revenue"] = rev_at.reshape(rev_table.shape).astype(np.int32)[made["lo_extendedprice"], discount]
+    cost = 6 * prices // 10
+    pools["lo_supplycost"], cost_at = np.unique(cost, return_inverse=True)
+    made["lo_supplycost"] = cost_at.astype(np.int32)[price]
+
+    return _synthetic_columnar_segment(
+        lineorder_flat_schema(), SSB_TABLE, pools, num_rows, seed, name, rng=rng, made=made,
+    )

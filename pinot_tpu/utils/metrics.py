@@ -593,9 +593,12 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "cost.tier.*": "per-serving-tier segment counts from the cost vector "
     "(segmentsPruned/Postings/Bitsliced/Zonemap/FullScan/Host/StarTree) — "
     "the series /debug/plans tier mixes reconcile against",
+    # which rung of the ladder answered (engine/ladder.py TIERS; marked
+    # in executor._finish_tier, one mark a query the ladder answered)
+    "tier.answered.*": "queries answered by the serving tier of that name: "
+    "postings, bitsliced, host (a forced host answer, a failover's, or a "
+    "quarantined or off-device plan's from inside the device rung) or device",
     # bit-sliced bulk-bitwise filter tier (engine/bitsliced.py, r17)
-    "filter.bitsliced.queries": "queries answered by the bit-sliced "
-    "bulk-bitwise tier (O(bit-width) plane passes, no row materialization)",
     "filter.bitsliced.planes": "packed bit-planes evaluated by bit-sliced "
     "kernels (filter + fused-aggregate planes)",
     "filter.bitsliced.fusedAggs": "aggregates answered by popcount-fused "
@@ -661,6 +664,10 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "device group-by, before the per-server trim",
     "groupby.groups.kept": "groups left after the per-server trim "
     "(max(5 x TOP, 100) an aggregate, and boundary ties)",
+    "groupby.keySpaceCells": "cells a device group-by's plan sized its group "
+    "space at (the product of the group columns' table cardinalities, "
+    "whatever the filter leaves; engine/plan.py group_capacity), marked by "
+    "the count a reply beside groupby.groups.live",
     "groupby.stateFetchBytes": "bytes of group state a device group-by's "
     "finalize was handed from the chip, marked by the count a reply, every "
     "lowering: a dense holder's K cells an aggregate and the occupancy, or "
