@@ -298,7 +298,10 @@ class DeviceFaultInjector:
         from pinot_tpu.engine.dispatch import DeviceExecutionError
 
         with self._lock:
-            if digest is not None and digest in self._poisoned:
+            # a launch over L of a table's segments comes as
+            # ``<planDigest>.L<L>`` (ladder.launch_digest): poisoned by
+            # its plan, at every size
+            if digest is not None and digest.partition(".L")[0] in self._poisoned:
                 self.launches.append(LaunchRecord(digest, "poison"))
                 raise DeviceExecutionError(
                     f"injected: poisoned plan {digest}", retryable=False

@@ -30,7 +30,7 @@ as  sum = vmin_s * count_s + sum_b 2^b * popcount(value_plane_b & bitmap).
 from __future__ import annotations
 
 import os
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -111,12 +111,20 @@ def bitsliced_decision(
     live: List[ImmutableSegment],
     ctx: TableContext,
     total_docs: int,
+    scanned: Optional[Sequence[int]] = None,
 ):
     """The bit-sliced tier verdict, separated from execution so EXPLAIN
     can report it without serving the query (index_path_decision's
     contract).  Returns ``(decision, state)``: a JSON-safe record plus
     the execution handoff (kernel spec, leaf nodes, fused-agg
-    descriptors) present only when taken."""
+    descriptors) present only when taken.
+
+    ``scanned``: the positions in ``live`` of the segments the filter can
+    match (None: all).  This tier's pass stays whole, over the planes of
+    every live segment as they are staged (a plane is a 32nd of a row's
+    width a bit, its outputs are a segment's own and a dead segment's
+    count is zero), so its cost is the table's; the scan it is weighed
+    against reads the scanned segments alone."""
     from pinot_tpu.engine import tiercost
     from pinot_tpu.engine.device import bsi_filter_width, bsiv_value_spec
 
@@ -238,7 +246,8 @@ def bitsliced_decision(
                     )
                     return decision, None
         bsi_ns = tiercost.bitsliced_cost_ns(total_docs, planes_total)
-        scan_ns = tiercost.scan_cost_ns(total_docs)
+        scan_ns = tiercost.scan_cost_ns(
+            total_docs if scanned is None else sum(live[i].num_docs for i in scanned))
         decision["estCostNs"] = int(bsi_ns)
         decision["scanCostNs"] = int(scan_ns)
         if bsi_ns >= scan_ns:
@@ -348,6 +357,7 @@ def run_bitsliced_path(
     deadline: Optional[float] = None,
     lane=None,
     lane_index: int = 0,
+    scanned: Optional[Sequence[int]] = None,
 ) -> Optional[IntermediateResult]:
     """Serve the scalar aggregation a taken ``bitsliced_decision`` handed
     off in ``state`` (which the executor keeps for a repeated query), or
@@ -355,7 +365,9 @@ def run_bitsliced_path(
     the same lane dispatch plumbing as the scan kernels (coalescing,
     micro-timers, static cost analysis -> achievedBytesPerSec), with
     the kernel spec standing in for the StaticPlan in every cache key —
-    both are process-stable hashables."""
+    both are process-stable hashables.  The pass is whole
+    (``bitsliced_decision``); ``scanned`` is what it counts as queried,
+    by the verdict the other tiers count by."""
     spec, leaves, agg_descs, planes_total, filter_planes = state
     leaf_spec, _tree, sums, extremes = spec
 
@@ -389,6 +401,7 @@ def run_bitsliced_path(
             executor, request, live, total_docs, deadline, lane,
             lane_index, staged, spec, leaves, agg_descs, planes_total,
             filter_planes, bsi_cols, bsiv_cols,
+            len(live) if scanned is None else len(scanned),
         )
     finally:
         RESIDENCY.unpin(staged.token)
@@ -410,6 +423,7 @@ def _dispatch_bitsliced(
     filter_planes: int,
     bsi_cols,
     bsiv_cols,
+    queried: int,
 ) -> Optional[IntermediateResult]:
     from pinot_tpu.engine.dispatch import plan_digest
     from pinot_tpu.engine.kernel import make_packed_bitsliced_kernel
@@ -472,7 +486,7 @@ def _dispatch_bitsliced(
     res = IntermediateResult(
         num_docs_scanned=matched,
         total_docs=total_docs,
-        num_segments_queried=len(live),
+        num_segments_queried=queried,
         # the bitwise pass reads words, not rows: planes * n/32 words
         # of 32-bit filter work per leaf plane (the O(W * n/32) claim)
         num_entries_scanned_in_filter=(filter_planes * total_docs) // 32,
@@ -482,7 +496,7 @@ def _dispatch_bitsliced(
     res.add_cost(
         bytesScanned=dev_bytes,
         deviceBytes=dev_bytes,
-        segmentsBitsliced=len(live),
+        segmentsBitsliced=queried,
         **cost,
     )
     res._device_digest = pdigest

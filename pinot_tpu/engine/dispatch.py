@@ -388,7 +388,7 @@ class _Dispatch:
     __slots__ = (
         "key", "launch", "pending", "waiters", "completed", "value",
         "error", "plan_digest", "cost_provider", "batch", "batch_size",
-        "t_submit", "trace", "parent", "program", "groupby", "operands", "expr", "cells", "blocks", "hll", "hll_parts", "launch_id",
+        "t_submit", "trace", "parent", "program", "groupby", "operands", "expr", "cells", "blocks", "hll", "hll_parts", "segments", "launch_id",
     )
 
     def __init__(
@@ -410,6 +410,7 @@ class _Dispatch:
         blocks: str = "",
         hll: str = "",
         hll_parts: int = 0,
+        segments: str = "",
     ) -> None:
         self.t_submit = t_submit  # phase.laneQueue runs from here to the launch call
         # the submitting query's span tree and the span (its laneWait)
@@ -424,6 +425,7 @@ class _Dispatch:
         self.blocks = blocks  # how a zone-tier program reads its candidate blocks (kernel.zone_blocks)
         self.hll = hll  # the lowering of the program's HLL aggregates (kernel.hll_lowering)
         self.hll_parts = hll_parts  # under 'sort', the parts a segment's keys are sorted in (kernel.hll_sort_parts)
+        self.segments = segments  # "<L>/<S>": the segments the program runs over, of the staged table's (ladder.launch_segments)
         self.launch_id: Optional[int] = None  # the physical launch this rode (occupancy)
         self.key = key
         self.launch = launch
@@ -607,6 +609,7 @@ class DeviceLane:
         blocks: str = "",
         hll: str = "",
         hll_parts: int = 0,
+        segments: str = "",
     ) -> LaneTicket:
         """Enqueue a kernel launch, or coalesce onto an identical one
         that is queued, launching, or still executing on device.
@@ -635,7 +638,10 @@ class DeviceLane:
         tag and one ``hll.lowering.matmul|sort|scatter|pairs`` mark a
         launch ("" for a program without one); ``hll_parts``: under
         'sort', in how many parts a segment's keys are sorted
-        (``kernel.hll_sort_parts``), marked on ``hll.sort.parts`` a launch.
+        (``kernel.hll_sort_parts``), marked on ``hll.sort.parts`` a launch;
+        ``segments``: ``<L>/<S>``, the segments the program runs over of
+        those the staged table holds (``ladder.launch_segments``), the
+        ``segments=`` tag ("" for a program that is no table scan).
 
         ``cost_provider`` (optional, utilization plane): a zero-arg
         callable returning the plan's static XLA cost analysis (or
@@ -675,7 +681,7 @@ class DeviceLane:
                 self._hit()
             else:
                 d = _Dispatch(key, launch, pending, plan_digest, cost_provider, batch,
-                              trace, parent, program, t_submit, groupby, operands, expr, cells, blocks, hll, hll_parts)
+                              trace, parent, program, t_submit, groupby, operands, expr, cells, blocks, hll, hll_parts, segments)
                 d.waiters.append(ticket)
                 self._by_key[key] = d
                 self._depth_tick_locked()
@@ -1234,6 +1240,8 @@ class DeviceLane:
                 tags["blocks"] = d.blocks
             if d.hll:
                 tags["hll"] = d.hll
+            if d.segments:
+                tags["segments"] = d.segments
             launching = boundary(
                 "laneDispatch", d.trace, launch_timer, parent=d.parent, program=d.program,
                 via="warm" if d.plan_digest is None or d.plan_digest in self._compile else "first",

@@ -28,9 +28,9 @@ from pinot_tpu.common.schema import DataType
 from pinot_tpu.common.values import render_value
 from pinot_tpu.engine import config, ladder
 from pinot_tpu.engine.context import TableContext, get_table_context
-from pinot_tpu.engine.device import StagedTable, get_staged
+from pinot_tpu.engine.device import get_staged
 from pinot_tpu.engine.plan import StaticPlan
-from pinot_tpu.engine.pruner import prune_segments
+from pinot_tpu.engine.pruner import prune_segments, scanned_segments
 from pinot_tpu.engine.results import (
     AggPartial,
     AvgPartial,
@@ -121,6 +121,9 @@ class _Prepared(_Derived):
 
     ``scope``     total docs, the columns to stage, the selection's
                   columns, the segment padding
+    ``scanned``   the positions of the segments the filter can match
+                  (``pruner.scanned_segments``): what every tier works
+                  over
     ``postings``  ``index_path_decision``'s hand-off, its postings held
                   weakly (``invindex_path.hold_state``; (): declined)
     ``bitsliced`` ``bitsliced_decision``'s hand-off (None: declined)
@@ -515,6 +518,10 @@ class QueryExecutor:
 
         segments = list(segments)
         total_docs = sum(s.num_docs for s in segments)
+        # upstream's three verdicts alone: the oracle is the independent
+        # derivation, so it scans every schema-valid segment, the ones the
+        # value pruner leaves out of production's work among them, and
+        # this way audits the pruner too
         live = prune_segments(segments, request)
         if not live:
             res = self._empty_result(request, total_docs)
@@ -585,20 +592,21 @@ class QueryExecutor:
         # star-tree routing: eligible segments answer from their
         # pre-aggregated cube (startree/operator.py); the rest take the
         # normal device path, partials merge below
-        from pinot_tpu.startree.operator import execute_star_tree, is_fit_for_star_tree
+        from pinot_tpu.startree.operator import execute_star_tree
 
         with self._phase("prune"):  # segment pruning and star-tree routing
             total_docs = sum(s.num_docs for s in segments)
-            live = prune_segments(segments, request)
+            # upstream's three verdicts drop a segment from the query; the
+            # value verdict leaves one out of the work (_execute_tiers)
+            live, star, normal = ladder.routed(segments, request)
             pruned = len(segments) - len(live)
-            star = [s for s in live if is_fit_for_star_tree(request, s)]
+            self.metrics.meter("prune.segments.offered").mark(len(segments))
         if not live:
             res = self._empty_result(request, total_docs)
             res.add_cost(segmentsPruned=pruned)
             return res
 
         if star:
-            normal = [s for s in live if s not in star]
             parts = [execute_star_tree(s, request) for s in star]
             if normal:
                 parts.append(self._execute_engine(normal, request, deadline))
@@ -736,6 +744,30 @@ class QueryExecutor:
             return ladder.scope(request, live, mesh)
 
         use.prepared.once("scope", scope)
+
+        def scanned_of():
+            # a function of the literals and the segments' tokens, the
+            # memo's key: a repeated text pays this look-up.  Derived, it
+            # is a stretch of ``prune`` between two of ``staging``: the
+            # phases follow one another, so no leaf is timed twice
+            ph.enter("prune")
+            try:
+                return scanned_segments(live, request)
+            finally:
+                ph.enter("staging")
+
+        # ``live`` stays the table's identity (the memo's fence, the
+        # staged table's key, the table context, totalDocs); the work is
+        # over the segments the filter can match, and every tier is
+        # handed them.  The rest count as pruned, as the time pruner's do
+        scanned = use.prepared.once("scanned", scanned_of)
+        dead = len(live) - len(scanned)
+        if dead:
+            self.metrics.meter("prune.segments.value").mark(dead)
+        if not scanned:
+            res = self._empty_result(request, use.prepared.values["scope"][0])
+            res.add_cost(segmentsPruned=dead)
+            return res
         # looked up on every query (its own cache, by the segments'
         # tokens): an entry that held it would hold the segments
         ctx = get_table_context(live, build_timer=self.metrics.timer("phase.globalDictBuild"))
@@ -756,9 +788,12 @@ class QueryExecutor:
                 # wrong-answer quarantine: unlike a device FAILURE (which
                 # retries), a tier caught lying never gets another attempt
                 # inside the TTL — straight to the host oracle path
-                return self._host_failover("auditQuarantine", live, request, ctx, ph, use)
-            res = self._SERVE[tier.name](self, tier, live, request, deadline, ctx, ph, sel, mesh, use)
+                res = self._host_failover("auditQuarantine", live, request, ctx, ph, use)
+            else:
+                res = self._SERVE[tier.name](self, tier, live, request, deadline, ctx, ph, sel, mesh, use)
             if res is not None:
+                if dead:
+                    res.add_cost(segmentsPruned=dead)
                 return res
         raise AssertionError("the device tier answers or raises")
 
@@ -768,7 +803,7 @@ class QueryExecutor:
         total_docs, _needed, sel_columns, _pad_to = use.prepared.values["scope"]
         self._heal_mark("hostFailovers", reason=reason)
         ph.enter("hostFailover")
-        res = execute_host(live, ctx, request, total_docs, sel_columns)
+        res = execute_host(live, ctx, request, total_docs, sel_columns, scanned=use.prepared.values["scanned"])
         return self._finish_tier(res, request, "host")
 
     def _serve_postings(self, tier, live, request, deadline, ctx, ph, sel, mesh, use) -> Optional[IntermediateResult]:
@@ -776,15 +811,16 @@ class QueryExecutor:
 
         prep = use.prepared
         total_docs, _needed, sel_columns, _pad_to = prep.values["scope"]
+        scanned = prep.values["scanned"]
         kept = prep.values.get(tier.prepared)  # None: not decided yet; (): declined
         state = invindex_path.held_state(kept) if kept else None
         if state is None and kept != ():  # not decided, or the postings it took were released since
-            state = tier.decide(request, live, ctx, total_docs, mesh)[1]
+            state = tier.decide(request, live, ctx, total_docs, mesh, scanned)[1]
             prep.values[tier.prepared] = invindex_path.hold_state(state) if state is not None else ()
             self._prepared_count(use)
         if state is None:
             return None
-        ires = invindex_path.run_index_path(state, request, live, ctx, total_docs, sel_columns)
+        ires = invindex_path.run_index_path(state, request, live, ctx, total_docs, sel_columns, scanned)
         ph.relabel(tier.phase)
         return self._finish_tier(ires, request, tier.name)
 
@@ -794,9 +830,10 @@ class QueryExecutor:
         from pinot_tpu.engine.bitsliced import run_bitsliced_path
 
         total_docs = use.prepared.values["scope"][0]
+        scanned = use.prepared.values["scanned"]
         try:
             state = use.prepared.once(
-                tier.prepared, lambda: tier.decide(request, live, ctx, total_docs, mesh)[1]
+                tier.prepared, lambda: tier.decide(request, live, ctx, total_docs, mesh, scanned)[1]
             )
             bres = None
             if state is not None:
@@ -804,6 +841,7 @@ class QueryExecutor:
                     self, state, request, live, ctx, total_docs, deadline,
                     lane=sel.lane if sel is not None else None,
                     lane_index=sel.index if sel is not None else 0,
+                    scanned=scanned,
                 )
         except Exception as e:
             from pinot_tpu.engine.dispatch import (
@@ -830,7 +868,8 @@ class QueryExecutor:
 
     def _serve_host(self, tier, live, request, deadline, ctx, ph, sel, mesh, use) -> Optional[IntermediateResult]:
         total_docs, _needed, sel_columns, _pad_to = use.prepared.values["scope"]
-        forced = use.prepared.once(tier.prepared, lambda: tier.decide(request, live, ctx, total_docs, mesh)[1])
+        scanned = use.prepared.values["scanned"]
+        forced = use.prepared.once(tier.prepared, lambda: tier.decide(request, live, ctx, total_docs, mesh, scanned)[1])
         if forced is None:
             return None
         from pinot_tpu.engine.host_fallback import execute_host
@@ -840,7 +879,7 @@ class QueryExecutor:
         (reason,) = forced
         if reason is not None:
             self.metrics.meter(f"groupby.forcedHost.{reason.split(':')[0]}").mark()
-        res = execute_host(live, ctx, request, total_docs, sel_columns)
+        res = execute_host(live, ctx, request, total_docs, sel_columns, scanned=scanned)
         ph.relabel(tier.phase)
         return self._finish_tier(res, request, tier.name)
 
@@ -1003,6 +1042,7 @@ class QueryExecutor:
         ph.enter("planBuild")  # staging ends here
         prep = use.prepared
         total_docs, needed, sel_columns, _pad_to = prep.values["scope"]
+        scanned = prep.values["scanned"]
         dev = prep.device
         if dev is None or dev.token != staged.token:
             # derived against another staged table (demoted since, and
@@ -1019,7 +1059,7 @@ class QueryExecutor:
 
             poison_ref["host"] = True  # host path from here: not a device fault
             ph.stop()
-            return execute_host(live, ctx, request, total_docs, sel_columns)
+            return execute_host(live, ctx, request, total_docs, sel_columns, scanned=scanned)
 
         # poison quarantine: this (plan digest, segment set) keeps
         # failing on device — skip the device entirely and serve from
@@ -1032,12 +1072,16 @@ class QueryExecutor:
             self._heal_mark("poisonSkips")
             ph.enter("hostFailover")
             poison_ref["host"] = True  # host path from here: not a device fault
-            return execute_host(live, ctx, request, total_docs, sel_columns)
+            return execute_host(live, ctx, request, total_docs, sel_columns, scanned=scanned)
 
         from pinot_tpu.engine.device import segment_arrays
 
         def inputs():
-            q_np, block_ids, scanned_rows = ladder.inputs(request, plan, ctx, live, staged, scratch)
+            # made over the launch's segments (ladder.launch_segments):
+            # their positions are one of the inputs, so the digest (the
+            # lane's coalesce key, the uploaded inputs' key) tells two
+            # launches of one plan over different segments apart
+            q_np, block_ids, scanned_rows = ladder.inputs(request, plan, ctx, live, staged, scratch, scanned, mesh)
             return q_np, self._inputs_digest(q_np), block_ids, scanned_rows
 
         derived = "inputs" not in dev.values
@@ -1061,7 +1105,14 @@ class QueryExecutor:
                 placement=placement,
             )
 
-        kernel = ladder.program(plan, staged, block_ids, mesh)
+        kernel = ladder.program(plan, staged, q_np, block_ids, mesh)
+        # the segments the program runs over, in the order of its outputs'
+        # leading axis (None: a launch's empty slot), and L of the staged S
+        launched = ladder.launched_segments(live, q_np)
+        launch_count = ladder.launch_count(staged, q_np)
+        # the plan's digest with L: a launch size is a compile of its own,
+        # and the lane's compile timeline is kept by what compiles
+        ldigest = ladder.launch_digest(pdigest, staged, q_np)
         if block_ids is not None:
             # block ids shard over the segment axis with everything else
             ids_dev = (
@@ -1092,9 +1143,10 @@ class QueryExecutor:
         exec_info: Dict[str, Any] = {}
         ph.stop()  # laneWait/planExec are timed inside _run_kernel
         outs = self._run_kernel(
-            kernel, args, plan, staged, digest, block_ids, deadline, pdigest,
+            kernel, args, plan, staged, digest, block_ids, deadline, ldigest,
             cost=cost, lane=lane, batch_spec=batch_spec, exec_info=exec_info,
             analysis_args=analysis_args,
+            segments=f"{launch_count}/{staged.num_segments}",
         )
         ph.enter("finalize")
 
@@ -1112,9 +1164,17 @@ class QueryExecutor:
                     # device path, so host errors are not device faults
                     poison_ref["host"] = True
                     ph.stop()
-                    return execute_host(live, ctx, request, total_docs, sel_columns)
+                    return execute_host(live, ctx, request, total_docs, sel_columns, scanned=scanned)
 
-        result = self._finalize(request, plan, ctx, staged, live, outs, total_docs, sel_columns)
+        # the rows the program ran over: the staged table's for the whole
+        # launch, which is every query of a table without a dead segment
+        launched_docs = (staged.total_docs if launch_count == staged.num_segments
+                         else sum(s.num_docs for s in launched if s is not None))
+        result = self._finalize(request, plan, ctx, launched, launched_docs, outs, total_docs, sel_columns)
+        # a value-dead segment of a whole launch was read and its rows
+        # rejected; it is counted with the pruned all the same, so that
+        # the tiers' counts partition numSegmentsQueried by the verdict
+        result.num_segments_queried = len(scanned)
         if plan.group_by is not None:
             ph.current.tag(groups=int(result.cost.get("numGroupsLive", 0)))  # add_cost keeps no zero
         if scanned_rows is not None:
@@ -1125,20 +1185,21 @@ class QueryExecutor:
         # block path reads only the candidate fraction), the serving
         # tier, and the dispatch-side hits recorded into ``cost``
         dev_bytes = sum(getattr(a, "nbytes", 0) for a in seg_arrays.values())
-        if block_ids is not None and scanned_rows is not None and staged.total_docs:
+        dev_bytes = dev_bytes * launch_count // staged.num_segments
+        if block_ids is not None and scanned_rows is not None and launched_docs:
             dev_bytes = int(
-                dev_bytes * min(1.0, scanned_rows / staged.total_docs)
+                dev_bytes * min(1.0, scanned_rows / launched_docs)
             )
         result.add_cost(bytesScanned=dev_bytes, deviceBytes=dev_bytes, **cost)
         if block_ids is not None:
-            result.add_cost(segmentsZonemap=len(live))
+            result.add_cost(segmentsZonemap=len(scanned))
         else:
-            result.add_cost(segmentsFullScan=len(live))
+            result.add_cost(segmentsFullScan=len(scanned))
         # device-plan identity for the utilization plane: lets the
         # plan-stats recorder join this shape's measured wall time with
         # the lane's static cost analysis (roofline numerator); the
         # lane index attributes it to the chip group that executed
-        result._device_digest = pdigest
+        result._device_digest = ldigest
         result._lane_index = sel.index if sel is not None else 0
         # batching actuals for EXPLAIN ANALYZE's device node: how many
         # same-shape queries this member's launch actually carried
@@ -1208,7 +1269,7 @@ class QueryExecutor:
         self, kernel, args, plan, staged, digest, block_ids, deadline,
         pdigest=None, cost: Optional[Dict[str, float]] = None, lane=None,
         batch_spec=None, exec_info: Optional[Dict[str, Any]] = None,
-        analysis_args=None,
+        analysis_args=None, segments: str = "",
     ) -> Dict[str, Any]:
         """DISPATCH + output fetch.  Serial mode (no lane): launch and
         fetch inline, the pre-pipeline behavior.  Pipelined: the launch
@@ -1219,7 +1280,8 @@ class QueryExecutor:
         transfer).  ``args`` may be a zero-arg callable (batch-eligible
         dispatches defer their solo H2D upload into the launch itself);
         ``analysis_args`` is the host-shaped stand-in the cost-analysis
-        helper lowers with in that case."""
+        helper lowers with in that case.  ``segments``: ``<L>/<S>``, the
+        launch span's ``segments=`` tag (``ladder.launch_segments``)."""
         if lane is None:
             lane = self.lane
         cost_args = args if not callable(args) else analysis_args
@@ -1320,6 +1382,7 @@ class QueryExecutor:
                         blocks=blocks,
                         hll=hll,
                         hll_parts=hll_parts,
+                        segments=segments,
                     )
                     fetch, handle = ticket.result(deadline)
                     # the lane thread delivered -> this worker runs again
@@ -1481,18 +1544,21 @@ class QueryExecutor:
         request: BrokerRequest,
         plan: StaticPlan,
         ctx: TableContext,
-        staged: StagedTable,
         live: List[ImmutableSegment],
+        launched_docs: int,
         outs: Dict[str, Any],
         total_docs: int,
         sel_columns: Optional[List[str]],
     ) -> IntermediateResult:
+        """``live``: the segments the program ran over, in the order of
+        its outputs' leading axis (``ladder.launched_segments``), and
+        ``launched_docs`` their rows."""
         matched = int(outs["num_docs"])
         res = IntermediateResult(
             num_docs_scanned=matched,
             total_docs=total_docs,
             num_segments_queried=len(live),
-            num_entries_scanned_in_filter=len(plan.leaves) * staged.total_docs,
+            num_entries_scanned_in_filter=len(plan.leaves) * launched_docs,
             num_entries_scanned_post_filter=matched * max(1, len(plan.aggs)),
         )
 
@@ -1939,6 +2005,8 @@ class QueryExecutor:
         valid = np.asarray(outs["sel_valid"])  # [S, k]
         rows: List[Tuple[list, list]] = []
         for si, seg in enumerate(live):
+            if seg is None:  # a launch's empty slot
+                continue
             for j in range(docids.shape[1]):
                 if not valid[si, j]:
                     continue

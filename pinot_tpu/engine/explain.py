@@ -37,7 +37,7 @@ from pinot_tpu.engine.context import get_table_context
 from pinot_tpu.engine.device import LEDGER, StagedColumn, StagedTable
 from pinot_tpu.engine.dispatch import plan_digest
 from pinot_tpu.engine.plandigest import plan_shape_digest, plan_shape_summary
-from pinot_tpu.engine.pruner import prune_explain
+from pinot_tpu.engine.pruner import prune_explain, scanned_segments
 from pinot_tpu.segment.immutable import ImmutableSegment
 
 # serving-tier name (as it appears in per-segment records) -> cost-
@@ -293,32 +293,34 @@ def build_explain_node(
                 dict({"segment": seg.segment_name, "tier": tier, "reason": reason}, **extra)
             )
 
-    verdicts = prune_explain(segments, request)
-    live = [seg for seg, reason in verdicts if reason is None]
-    for seg, reason in verdicts:
-        if reason is not None:
+    # what stands ahead of the ladder, as the executor has it.  Upstream's
+    # three verdicts drop a segment from the query; the value verdict's
+    # (the leaf, the column and the segment's [min, max]) leaves it among
+    # the table's segments and out of every tier's work, and it counts
+    # with the pruned; the star-tree routing stands ahead of the value
+    # verdict
+    _live, star, normal = ladder.routed(segments, request)
+    scanned = scanned_segments(normal, request)
+    for seg, reason in prune_explain(segments, request):
+        if reason is not None and seg not in star:
             record([seg], "pruned", reason)
+    record(
+        star,
+        "starTree",
+        "conjunctive-EQ dims + aggregations covered by the "
+        "segment's star-tree cube",
+    )
 
     device_info: Optional[Dict[str, Any]] = None
     est_bytes = 0
-    normal: List[ImmutableSegment] = []
-    if live:
-        from pinot_tpu.startree.operator import is_fit_for_star_tree
-
-        star = [s for s in live if is_fit_for_star_tree(request, s)]
-        normal = [s for s in live if s not in star]
-        record(
-            star,
-            "starTree",
-            "conjunctive-EQ dims + aggregations covered by the "
-            "segment's star-tree cube",
-        )
-
-    if normal:
+    if scanned:
         selection, exec_mesh, lane = _route(executor, request)
         ladder_docs, needed, _sel_columns, pad_to = ladder.scope(request, normal, exec_mesh)
         ctx = get_table_context(normal)
-        tier, decision, state = ladder.first_accepting(request, normal, ctx, ladder_docs, exec_mesh)
+        tier, decision, state = ladder.first_accepting(request, normal, ctx, ladder_docs, exec_mesh, scanned)
+        # ``normal`` is the table (what is staged, the table context);
+        # the records below are of the segments the tier works over
+        staged_segs, normal = normal, [normal[i] for i in scanned]
         full_scan_bytes = lambda: _estimate_scan_bytes(normal, needed, 1.0)
         if tier.name == "postings":
             est_bytes = int(decision.get("estMatches", 0)) * (
@@ -353,7 +355,7 @@ def build_explain_node(
                 **({"groupByHostReason": why} if why is not None else {}),
             )
         else:
-            _roles, phantom, scratch, plan, pdigest, poison_key = _phantom_plan(request, normal, ctx, needed, pad_to)
+            _roles, phantom, scratch, plan, pdigest, poison_key = _phantom_plan(request, staged_segs, ctx, needed, pad_to)
             poison = executor.poisoned_entry(poison_key) if plan.on_device else None
             if not plan.on_device:
                 est_bytes = full_scan_bytes()
@@ -389,11 +391,25 @@ def build_explain_node(
                     f"fallback for {poison['ttlRemainingS']}s more",
                 )
             elif plan.on_device:
-                q_np, block_ids, scanned_rows = ladder.inputs(request, plan, ctx, normal, phantom, scratch)
+                q_np, block_ids, scanned_rows = ladder.inputs(
+                    request, plan, ctx, staged_segs, phantom, scratch, scanned, exec_mesh)
+                # the segments the program runs over, of those staged:
+                # L of S, and the scanned ones by name
+                count = ladder.launch_count(phantom, q_np)
+                device_info["launch"] = {
+                    "segments": count,
+                    "ofStaged": phantom.num_segments,
+                    "scanned": [s.segment_name for s in normal],
+                }
+                if count != phantom.num_segments:
+                    # a launch size is a compile of its own, kept in the
+                    # lane's timeline under the plan's digest with L
+                    device_info["compile"] = _compile_state(lane, ladder.launch_digest(pdigest, phantom, q_np))
+                launched_docs = sum(s.num_docs for s in ladder.launched_segments(staged_segs, q_np) if s is not None)
                 if block_ids is not None and scanned_rows is not None:
                     frac = (
-                        min(1.0, scanned_rows / phantom.total_docs)
-                        if phantom.total_docs
+                        min(1.0, scanned_rows / launched_docs)
+                        if launched_docs
                         else 1.0
                     )
                     est_bytes = _estimate_scan_bytes(normal, needed, frac)
@@ -401,7 +417,7 @@ def build_explain_node(
                         normal,
                         "zonemap",
                         "zone-map block pruning engages: candidate "
-                        f"fraction {frac:.4f} of the table",
+                        f"fraction {frac:.4f} of the launch's rows",
                         candidateFraction=round(frac, 4),
                     )
                 else:
@@ -609,25 +625,32 @@ def build_prewarm_spec(
       (``kernel.plan_program`` hands back no ``.lower``);
     - shapes already in the lane's compile timeline are warm already.
     """
-    live = [seg for seg, reason in prune_explain(segments, request) if reason is None]
-    from pinot_tpu.startree.operator import is_fit_for_star_tree
-
-    normal = [s for s in live if not is_fit_for_star_tree(request, s)]
+    # the engine's segments, and among them those the filter can match:
+    # the program compiled is the one the ladder derives for the launch
+    # over them, at its segment count
+    _live, _star, normal = ladder.routed(segments, request)
+    scanned = scanned_segments(normal, request)
     _selection, exec_mesh, lane = _route(executor, request)
-    if not normal or exec_mesh is not None or lane is None:
+    if not scanned or exec_mesh is not None or lane is None:
         return None
     total_docs, needed, _sel_columns, pad_to = ladder.scope(request, normal, exec_mesh)
     ctx = get_table_context(normal)
-    if ladder.first_accepting(request, normal, ctx, total_docs, exec_mesh)[0].name != "device":
+    if ladder.first_accepting(request, normal, ctx, total_docs, exec_mesh, scanned)[0].name != "device":
         return None
     roles, phantom, scratch, plan, pdigest, _poison_key = _phantom_plan(request, normal, ctx, needed, pad_to)
-    if not plan.on_device or lane.compile_info(pdigest) is not None:
-        return None  # the host's, or already cold/warm/prewarmed here: nothing to pay
-    q_np, block_ids, _scanned = ladder.inputs(request, plan, ctx, normal, phantom, scratch)
+    if not plan.on_device:
+        return None
+    q_np, block_ids, _rows = ladder.inputs(request, plan, ctx, normal, phantom, scratch, scanned, exec_mesh)
+    # the plan's digest with the launch's L: what the lane's timeline
+    # keeps a compile under, so a text whose literals give a new launch
+    # size is prewarmed though its plan has launched at another
+    ldigest = ladder.launch_digest(pdigest, phantom, q_np)
+    if lane.compile_info(ldigest) is not None:
+        return None  # already cold/warm/prewarmed here: nothing to pay
     # the builders keep a handle a plan: this is the SAME callable the
     # serving launch will call, so an in-process AOT compile also seeds
     # the persistent cache entry serving reads
-    kernel = ladder.program(plan, phantom, block_ids, exec_mesh)
+    kernel = ladder.program(plan, phantom, q_np, block_ids, exec_mesh)
     if not hasattr(kernel, "lower"):
         return None
     lower_args = (_phantom_segment_avals(phantom, needed, ctx, roles[3]), q_np)
@@ -639,4 +662,4 @@ def build_prewarm_spec(
     def compile_now() -> None:
         kernel.lower(*lower_args).compile()
 
-    return {"planDigest": pdigest, "lane": lane, "compile": compile_now}
+    return {"planDigest": ldigest, "lane": lane, "compile": compile_now}

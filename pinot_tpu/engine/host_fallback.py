@@ -123,16 +123,20 @@ class _Block(NamedTuple):
 
 
 def _matched_blocks(
-    segments: List[ImmutableSegment], request: BrokerRequest, matched_rows=None
+    segments: List[ImmutableSegment], request: BrokerRequest, matched_rows=None, scanned=None
 ) -> Iterator[_Block]:
     """Every segment's matched rows, ``config.HOST_BLOCK_ROWS`` rows a
     block: ``ceil(rows / block)`` blocks a segment.  By default the
     filter is a vectorized mask over the block's rows (O(n) host scan);
     ``matched_rows(si, seg)`` substitutes a row-id resolver (the
     inverted-index path's O(matches) postings, engine/invindex_path.py),
-    whose ids are cut into blocks of the same size."""
+    whose ids are cut into blocks of the same size.  ``scanned``: the
+    positions of the segments to answer from (None: all): ``si`` stays a
+    segment's position in ``segments``, which is its position in the
+    table context's remaps."""
     step = config.HOST_BLOCK_ROWS
-    for si, seg in enumerate(segments):
+    for si in range(len(segments)) if scanned is None else scanned:
+        seg = segments[si]
         if matched_rows is not None:
             rows = matched_rows(si, seg)
             for lo in range(0, rows.size, step):
@@ -606,10 +610,11 @@ def execute_host(
     total_docs: int,
     sel_columns: Optional[List[str]],
     matched_rows=None,
+    scanned=None,
 ) -> IntermediateResult:
     """The host's answer: ``execute_host_steps`` run to its end."""
     return run_steps(
-        execute_host_steps(segments, ctx, request, total_docs, sel_columns, matched_rows)
+        execute_host_steps(segments, ctx, request, total_docs, sel_columns, matched_rows, scanned)
     )
 
 
@@ -629,23 +634,27 @@ def execute_host_steps(
     total_docs: int,
     sel_columns: Optional[List[str]],
     matched_rows=None,
+    scanned=None,
 ):
     """The pass as a generator: one ``yield`` after each block of
     ``config.HOST_BLOCK_ROWS`` rows, the result as its return value.
     Cost-accounted: every host-served query reports hostMs (wall time,
     the caller's pauses between steps included), bytesScanned, and the
     host serving tier on its result's cost vector (engine/results.py
-    COST_KEYS)."""
+    COST_KEYS).  ``segments`` with ``ctx`` are the table; ``scanned``
+    (None: all) the positions of those the pass reads and counts, the
+    ones the query's filter can match (``pruner.scanned_segments``)."""
     import time as _time
 
     t0 = _time.perf_counter()
     res = yield from _execute_host_impl(
-        segments, ctx, request, total_docs, sel_columns, matched_rows
+        segments, ctx, request, total_docs, sel_columns, matched_rows, scanned
     )
+    read = segments if scanned is None else [segments[i] for i in scanned]
     res.add_cost(
         hostMs=round((_time.perf_counter() - t0) * 1000, 3),
-        bytesScanned=_referenced_column_bytes(segments, request),
-        segmentsHost=len(segments),
+        bytesScanned=_referenced_column_bytes(read, request),
+        segmentsHost=len(read),
     )
     return res
 
@@ -657,12 +666,13 @@ def _execute_host_impl(
     total_docs: int,
     sel_columns: Optional[List[str]],
     matched_rows=None,
+    scanned=None,
 ):
     res = IntermediateResult(
         total_docs=total_docs,
-        num_segments_queried=len(segments),
+        num_segments_queried=len(segments) if scanned is None else len(scanned),
     )
-    blocks = _matched_blocks(segments, request, matched_rows)
+    blocks = _matched_blocks(segments, request, matched_rows, scanned)
     if request.is_group_by:
         res.groups = {}
         if _vectorizable_groupby(request, segments, ctx):

@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import os
 import weakref
-from typing import List, Optional
+from typing import List, Optional, Sequence
 
 import numpy as np
 
@@ -117,6 +117,7 @@ def index_path_decision(
     live: List[ImmutableSegment],
     ctx: TableContext,
     total_docs: int,
+    scanned: Optional[Sequence[int]] = None,
 ):
     """The operator-choice verdict, separated from execution so the
     EXPLAIN plane can report it without serving the query.
@@ -124,7 +125,15 @@ def index_path_decision(
     Returns ``(decision, state)``: ``decision`` is a JSON-safe record
     (``taken`` plus the reason/estimates that justify it); ``state`` is
     the resolved ``(best leaf, indexes, residuals, est)`` execution
-    handoff, present only when ``taken`` is True."""
+    handoff, present only when ``taken`` is True.
+
+    ``scanned``: the positions in ``live`` of the segments the filter can
+    match (``pruner.scanned_segments``; None: all).  The estimates, the
+    crossover and the postings are those segments' alone: the scan this
+    tier is weighed against reads no other, and ``indexes`` has an entry
+    a scanned segment, in their order."""
+    if scanned is not None:
+        live = [live[i] for i in scanned]
     if os.environ.get("PINOT_TPU_INVINDEX") == "0":
         return {"taken": False, "reason": "postings path disabled (PINOT_TPU_INVINDEX=0)"}, None
     tree = request.filter
@@ -254,13 +263,16 @@ def run_index_path(
     ctx: TableContext,
     total_docs: int,
     sel_columns: Optional[List[str]],
+    scanned: Optional[Sequence[int]] = None,
 ) -> IntermediateResult:
     """Answer from the postings a taken ``index_path_decision`` handed
-    off in ``state`` (which the executor keeps for a repeated query)."""
+    off in ``state`` (which the executor keeps for a repeated query),
+    over the ``scanned`` segments the decision was made for."""
     best, indexes, residuals, est = state
+    slot = {si: j for j, si in enumerate(range(len(live)) if scanned is None else scanned)}
 
     def matched_rows(si: int, seg: ImmutableSegment) -> np.ndarray:
-        idx, t = indexes[si]
+        idx, t = indexes[slot[si]]
         rows = idx.resolve_table(t)
         if rows.size and residuals:
             keep = np.ones(rows.size, dtype=bool)
@@ -272,7 +284,7 @@ def run_index_path(
     from pinot_tpu.engine.host_fallback import execute_host
 
     res = execute_host(
-        live, ctx, request, total_docs, sel_columns, matched_rows=matched_rows
+        live, ctx, request, total_docs, sel_columns, matched_rows=matched_rows, scanned=scanned
     )
     # filter work was O(postings), not O(n): report candidate rows like
     # the zone-map path does (num_entries_scanned contract)
@@ -280,7 +292,7 @@ def run_index_path(
     # cost re-attribution: this is the postings tier, and its bytes are
     # O(matches) — the wrapper's full-column upper bound does not apply
     res.cost.pop("segmentsHost", None)
-    res.cost["segmentsPostings"] = len(live)
+    res.cost["segmentsPostings"] = res.num_segments_queried
     res.cost["bytesScanned"] = est * max(1, len(residuals) + 1) * 8
     if request.is_group_by:
         res.add_cost(**group_state_digest(request, res.groups))

@@ -1916,6 +1916,37 @@ def named(fn: Callable, name: str) -> Callable:
     return fn
 
 
+def launch_view(segs: Dict[str, Any], q: Dict[str, Any]):
+    """(the staged arrays the launch works on, the query's per-segment
+    inputs): what a table program does first with its arguments.
+
+    A launch over the whole staged table carries no ``q["segments"]``
+    and gets both back as they came: the program traced is the one
+    traced before there was a choice, by the same name and fingerprint.
+    A launch over L of the table's S segments carries what
+    ``ladder.launch_segments`` made (the key's presence is the inputs'
+    structure, so jit keeps the two apart) and every other input of ``q``
+    with L rows.  The view is the L rows from ``first`` on of the
+    resident ``[S, ...]`` arrays, one ``dynamic_slice``: a transient of
+    this program and never a staged table.  ``slots`` int32[L] holds a
+    slot's segment, -1 for a slot with no valid row.
+    On the chip (PR 48, SSB's q4_2 and q4_3 at 4 of 16 segments of 2^23
+    rows): the slice 14.2 and 112.3 ms of device time a query, a take at
+    the slots 21.0 and 119.0, four single-segment slices concatenated
+    22.4 and 129.9, the whole launch 52.0 and 505.2."""
+    launch = q.get("segments")
+    if launch is None:
+        return segs, q
+    q = {k: v for k, v in q.items() if k != "segments"}
+    slots = launch["slots"]
+    view = {k: jax.lax.dynamic_slice_in_dim(v, launch["first"], slots.shape[0], axis=0) for k, v in segs.items()}
+    if "num_docs" in view:
+        view["num_docs"] = jnp.where(slots >= 0, view["num_docs"], 0)
+    else:
+        view["valid"] = view["valid"] & (slots >= 0)[:, None]
+    return view, q
+
+
 @functools.lru_cache(maxsize=256)
 def make_table_kernel(plan: StaticPlan) -> Callable:
     """vmap the single-segment kernel over the stacked segment axis and
@@ -1928,7 +1959,7 @@ def make_table_kernel(plan: StaticPlan) -> Callable:
     single = make_single_segment_kernel(plan)
 
     def table_fn(segs: Dict[str, Any], q: Dict[str, Any]) -> Dict[str, Any]:
-        outs = jax.vmap(single)(segs, q)
+        outs = jax.vmap(single)(*launch_view(segs, q))
         return reduce_outputs(plan, outs)
 
     return jax.jit(named(table_fn, kernel_name("scan", plan)))
@@ -2121,7 +2152,7 @@ def make_block_table_kernel(plan: StaticPlan, block: int) -> Callable:
     stacked = make_stacked_block_kernel(plan, block)
 
     def table_fn(segs, q, ids):
-        outs = stacked(segs, q, ids)
+        outs = stacked(*launch_view(segs, q), ids)
         return reduce_outputs(plan, outs)
 
     return jax.jit(named(table_fn, kernel_name("zone", plan)))
@@ -2174,9 +2205,18 @@ def _chunked_program(plan: StaticPlan, mesh, num_segments: int, chunk: int) -> C
 
     def dispatch(segs: Dict[str, Any], q: Dict[str, Any]):
         outs = None
+        # a launch over a window of the table's segments (launch_view)
+        # chunks its slots with its inputs, and each chunk's program
+        # takes its own part of the window from the whole resident arrays
+        launch = q.get("segments")
+        rest = q if launch is None else {k: v for k, v in q.items() if k != "segments"}
         for s in range(0, num_segments, chunk):
             e = min(s + chunk, num_segments)
-            o = table(sliced(segs, s, e), sliced(q, s, e))
+            if launch is None:
+                o = table(sliced(segs, s, e), sliced(q, s, e))
+            else:
+                part = {"slots": launch["slots"][s:e], "first": launch["first"] + s}
+                o = table(segs, dict(sliced(rest, s, e), segments=part))
             outs = (
                 o
                 if outs is None

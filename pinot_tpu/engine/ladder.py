@@ -6,11 +6,15 @@ host before it launches.
 runs the first tier that accepts; ``explain.build_explain_node`` loops
 over it and reports that tier; ``explain.build_prewarm_spec`` loops over
 it and compiles only where it ends on the device.  Pruning and the
-star-tree routing stand ahead of it (``executor.execute``).
+star-tree routing stand ahead of it, written once too (``routed``).
 
 Below the list, the device tier's second level, one function a
 derivation, in the order asked: ``scope``, ``roles``, ``plan``,
-``inputs``, ``batch``, ``program``.  Those that read a staged table take
+``launch_segments``, ``inputs``, ``batch``, ``program``.  Every rung is
+handed ``scanned`` beside ``live``: the positions of the segments the
+filter can match (``pruner.scanned_segments``).  ``live`` is the table's
+identity (the staged table, the table context, ``totalDocs``); the work,
+and what a rung decides by, is the scanned segments'.  Those that read a staged table take
 it as an argument and read its metadata alone (shape bucketing, the
 segments' cards, which role arrays are present), so EXPLAIN's phantom
 table (``explain._phantom_staged``) yields what the staged one would:
@@ -42,7 +46,7 @@ class Tier(NamedTuple):
     """One rung.  ``name`` is the tier's one name: the audit plane's
     quarantine key, what ``_finish_tier`` stamps on the reply, the cost
     vector's ``segments<Name>`` count and EXPLAIN's per-segment record.
-    ``decide(request, live, ctx, total_docs, mesh)`` gives (a JSON-safe
+    ``decide(request, live, ctx, total_docs, mesh, scanned)`` gives (a JSON-safe
     record of the verdict, the hand-off to the tier's execution or None
     where it declines); ``prepared`` is the name the executor keeps the
     hand-off under; ``phase`` is what the reply's first stretch is
@@ -56,26 +60,26 @@ class Tier(NamedTuple):
     on_mesh: bool = True
 
 
-def _postings(request, live, ctx, total_docs, mesh):
+def _postings(request, live, ctx, total_docs, mesh, scanned):
     # selective predicates answer from host postings in O(matches)
     # (engine/invindex_path.py — BitmapBasedFilterOperator analog);
     # unselective ones fall through
     from pinot_tpu.engine.invindex_path import index_path_decision
 
-    return index_path_decision(request, live, ctx, total_docs)
+    return index_path_decision(request, live, ctx, total_docs, scanned)
 
 
-def _bitsliced(request, live, ctx, total_docs, mesh):
+def _bitsliced(request, live, ctx, total_docs, mesh, scanned):
     # mid-selectivity scalar aggregations the postings tier just
     # declined evaluate as O(bit-width) bulk-bitwise passes over
     # bit-sliced planes (engine/bitsliced.py) — single-device only;
     # mesh placements keep the sharded scan path
     from pinot_tpu.engine.bitsliced import bitsliced_decision
 
-    return bitsliced_decision(request, live, ctx, total_docs)
+    return bitsliced_decision(request, live, ctx, total_docs, scanned)
 
 
-def _forced_host(request, live, ctx, total_docs, mesh):
+def _forced_host(request, live, ctx, total_docs, mesh, scanned):
     # queries the planner can only send to the host (group space or
     # guaranteed pair overflow) skip device staging entirely.  A
     # group-by the device declines says why, by name: the key space, or
@@ -90,7 +94,7 @@ def _forced_host(request, live, ctx, total_docs, mesh):
     return {"groupByHostReason": why}, (why,)
 
 
-def _device(request, live, ctx, total_docs, mesh):
+def _device(request, live, ctx, total_docs, mesh, scanned):
     return None, True  # the last rung takes what is left; its second level is below
 
 
@@ -102,7 +106,23 @@ TIERS: Tuple[Tier, ...] = (
 )
 
 
-def first_accepting(request, live, ctx, total_docs, mesh):
+def routed(segments: Sequence[ImmutableSegment], request: BrokerRequest):
+    """What stands ahead of the list, for ``executor.execute``, EXPLAIN
+    and the prewarm worker alike: (the segments upstream's three pruners
+    keep, those of them the star-tree answers from its cube, the rest:
+    the engine's, which the list is asked about).  The value verdict is
+    not here: it drops no segment from the query, it is a function of
+    the literals the prepared memo keeps (``pruner.scanned_segments``
+    over the engine's segments), and every rung is handed its answer."""
+    from pinot_tpu.engine.pruner import prune_segments
+    from pinot_tpu.startree.operator import is_fit_for_star_tree
+
+    live = prune_segments(segments, request)
+    star = [s for s in live if is_fit_for_star_tree(request, s)]
+    return live, star, [s for s in live if s not in star] if star else live
+
+
+def first_accepting(request, live, ctx, total_docs, mesh, scanned):
     """(tier, record, hand-off) of the first rung that accepts, for a
     reader that runs nothing: EXPLAIN and the prewarm worker.  The
     executor walks ``TIERS`` itself, through its memo and past the rungs
@@ -110,7 +130,7 @@ def first_accepting(request, live, ctx, total_docs, mesh):
     for tier in TIERS:
         if mesh is not None and not tier.on_mesh:
             continue
-        record, state = tier.decide(request, live, ctx, total_docs, mesh)
+        record, state = tier.decide(request, live, ctx, total_docs, mesh, scanned)
         if state is not None:
             return tier, record, state
     raise AssertionError("the device rung declines nothing")
@@ -318,6 +338,55 @@ def plan(request: BrokerRequest, ctx: TableContext, staged: StagedTable, scratch
     return static, pdigest, (pdigest, staged.segment_names)
 
 
+def launch_segments(scanned: Sequence[int], staged: StagedTable, mesh) -> Optional[Dict[str, np.ndarray]]:
+    """The segments the device program runs over, where they are not the
+    whole staged table (None: the whole launch): a window of L neighbours
+    among the staged segments, from ``first`` on, and ``slots`` int32[L],
+    a slot's position among the staged segments, -1 for a slot whose
+    segment the filter cannot match (or that stands before the scanned
+    ones, where the window ends with the table).  It rides with the
+    query's inputs as ``q["segments"]`` (``plan.build_query_inputs``) and
+    ``kernel.launch_view`` reads it.
+
+    ONE rule, read from the input: L is the scanned segments' span (first
+    to last, the dead between them included) padded to the next power of
+    two, so a plan compiles at most log2(S) launch sizes besides the
+    whole one, and L = S is the whole launch, today's program.  The
+    program takes that window of the columns that are already resident,
+    one slice: nothing is staged, so a query over a few segments costs no
+    second copy of its columns.  A segment is a range of time and a
+    filter on a column derived from the time leaves neighbours; a filter
+    whose segments lie apart by half the table or more launches whole,
+    and its dead segments' rows are rejected as they were before there
+    was a verdict.
+
+    A sharded placement (``mesh``) launches whole: each chip holds its
+    own shard of the segment axis, a window across shards is a collective
+    (an all-gather of the columns a scan exists to leave where they
+    lie), and no deployment has a dead segment there yet.  The filter
+    rejects the dead segments' rows there too."""
+    if mesh is not None:
+        return None
+    count = 1
+    while count < scanned[-1] - scanned[0] + 1:
+        count *= 2
+    if count >= staged.num_segments:
+        return None
+    first = min(scanned[0], staged.num_segments - count)
+    window = np.arange(first, first + count, dtype=np.int32)
+    return {"slots": np.where(np.isin(window, scanned), window, -1).astype(np.int32), "first": np.asarray(first, dtype=np.int32)}
+
+
+def launched_segments(live: Sequence[ImmutableSegment], q_np: Dict[str, Any]) -> List[Optional[ImmutableSegment]]:
+    """The segments a launch's program runs over, in the order of its
+    inputs' and outputs' leading axis: ``live`` for the whole launch,
+    else a slot's segment, None for an empty slot."""
+    launch = q_np.get("segments")
+    if launch is None:
+        return list(live)
+    return [live[i] if i >= 0 else None for i in launch["slots"]]
+
+
 def inputs(
     request: BrokerRequest,
     static: StaticPlan,
@@ -325,16 +394,22 @@ def inputs(
     live: Sequence[ImmutableSegment],
     staged: StagedTable,
     scratch: Dict[Any, Any],
+    scanned: Sequence[int],
+    mesh,
 ):
     """(the query's input tables ``q_np``, the zone tier's block ids or
-    None for the full scan, the rows those blocks hold)."""
+    None for the full scan, the rows those blocks hold), all three over
+    the launch's segments (``launch_segments``): a table has a row a
+    segment of the launch, and the zone tier's gate weighs the candidate
+    blocks against the launch's rows, not the table's."""
     from pinot_tpu.engine.kernel import chunk_rows_limit
     from pinot_tpu.engine.plan import build_query_inputs
 
-    q_np = build_query_inputs(request, static, ctx, staged, scratch=scratch)
-    block_ids, scanned_rows = _block_skip_ids(static, q_np, live, staged)
+    q_np = build_query_inputs(request, static, ctx, staged, scratch=scratch, launch=launch_segments(scanned, staged, mesh))
+    count = launch_count(staged, q_np)
+    block_ids, scanned_rows = _block_skip_ids(static, q_np, launched_segments(live, q_np), staged.n_pad, count)
     limit = chunk_rows_limit()
-    if block_ids is not None and limit and staged.num_segments * staged.n_pad > limit:
+    if block_ids is not None and limit and count * staged.n_pad > limit:
         # the block kernel has no segment-chunked variant: beyond the
         # per-dispatch row budget its single dispatch would exhaust
         # HBM at compile time — fall through to the chunked full
@@ -343,9 +418,11 @@ def inputs(
     return q_np, block_ids, scanned_rows
 
 
-def _block_skip_ids(static: StaticPlan, q_np: Dict[str, Any], live: Sequence[ImmutableSegment], staged: StagedTable):
-    """Zone-map block pruning decision (engine/zonemap.py): returns
-    (block_ids [S, nb_pad] or None, candidate_rows or None).
+def _block_skip_ids(static: StaticPlan, q_np: Dict[str, Any], live: Sequence[ImmutableSegment], n_pad: int, num_segments: int):
+    """Zone-map block pruning decision (engine/zonemap.py) over the
+    launch's segments ``live`` (``launched_segments``), ``num_segments`` slots of ``n_pad`` rows:
+    returns (block_ids [num_segments, nb_pad] or None, candidate_rows or
+    None).
 
     Engages when the candidate blocks, padded to a power of two,
     are at most half the table.  The gate dates from the gathered
@@ -360,11 +437,11 @@ def _block_skip_ids(static: StaticPlan, q_np: Dict[str, Any], live: Sequence[Imm
         return None, None
     from pinot_tpu.engine import zonemap
 
-    cand = zonemap.candidate_blocks(static, q_np, live, staged.n_pad)
+    cand = zonemap.candidate_blocks(static, q_np, live, n_pad)
     if cand is None:
         return None, None
     block = zonemap.zone_block_rows()
-    nb_total = staged.num_segments * (staged.n_pad // block)
+    nb_total = num_segments * (n_pad // block)
     nb_max = int(cand.sum(axis=1).max()) if cand.size else 0
     if static.selection is not None:
         # the gathered view exposes only nb_pad*block rows per
@@ -375,12 +452,12 @@ def _block_skip_ids(static: StaticPlan, q_np: Dict[str, Any], live: Sequence[Imm
     nb_pad = 1
     while nb_pad < nb_max:
         nb_pad *= 2
-    if nb_pad * staged.num_segments > nb_total // 2:
+    if nb_pad * num_segments > nb_total // 2:
         return None, None
     ids = zonemap.block_ids_input(cand, nb_pad)
-    if ids.shape[0] < staged.num_segments:  # mesh-padding segments
+    if ids.shape[0] < num_segments:  # mesh-padding segments, a launch's empty slots
         pad = np.full(
-            (staged.num_segments - ids.shape[0], nb_pad), -1, dtype=np.int32
+            (num_segments - ids.shape[0], nb_pad), -1, dtype=np.int32
         )
         ids = np.concatenate([ids, pad], axis=0)
     return ids, int(cand.sum()) * block
@@ -397,11 +474,14 @@ def batch(static: StaticPlan, staged: StagedTable, q_np: Dict[str, Any], block_i
     sorts the table's rows in its merge: a member more is a sort more,
     nothing shared.  ``max_members`` keeps batch x rows under that budget
     so batching can never blow the compile-time working set the chunked
-    path exists to bound."""
+    path exists to bound.  A launch over some of the table's segments
+    (``launch_segments``) stacks with no other either: each member would
+    take its own copy of its segments' columns, where the batch exists to
+    read the resident ones once."""
     from pinot_tpu.engine.kernel import chunk_rows_limit, groupby_lowering
     from pinot_tpu.engine.packing import batch_input_signature
 
-    if mesh is not None or block_ids is not None or groupby_lowering(static) == "runs":
+    if mesh is not None or block_ids is not None or groupby_lowering(static) == "runs" or "segments" in q_np:
         return None
     limit = chunk_rows_limit()
     rows = max(1, staged.num_segments * staged.n_pad)
@@ -421,12 +501,32 @@ def batch(static: StaticPlan, staged: StagedTable, q_np: Dict[str, Any], block_i
     return batch_input_signature(q_np), max_members
 
 
-def program(static: StaticPlan, staged: StagedTable, block_ids, mesh):
-    """The plan's device program, as ``kernel.plan_program`` chooses it:
-    looked up on every query where its builders keep it, so a program
-    forgotten there is built again by the next launch."""
+def program(static: StaticPlan, staged: StagedTable, q_np: Dict[str, Any], block_ids, mesh):
+    """The plan's device program, as ``kernel.plan_program`` chooses it
+    for the launch's segment count (``q_np``'s, where it carries the
+    launch's segments): looked up on every query where its builders keep
+    it, so a program forgotten there is built again by the next launch."""
     from pinot_tpu.engine.kernel import plan_program
     from pinot_tpu.engine.zonemap import zone_block_rows
 
     block = zone_block_rows() if block_ids is not None else None
-    return plan_program(static, staged.num_segments, staged.n_pad, block, mesh)
+    return plan_program(static, launch_count(staged, q_np), staged.n_pad, block, mesh)
+
+
+def launch_count(staged: StagedTable, q_np: Dict[str, Any]) -> int:
+    """L: the segments a launch's program runs over, the staged table's S
+    for the whole launch."""
+    launch = q_np.get("segments")
+    return staged.num_segments if launch is None else int(launch["slots"].shape[0])
+
+
+def launch_digest(pdigest: Optional[str], staged: StagedTable, q_np: Dict[str, Any]) -> Optional[str]:
+    """What names the launch's compiled program: the plan's digest for
+    the whole launch, and the digest with L for a launch over L of the
+    table's segments, which is a compile of its own.  The lane's compile
+    timeline (``via=first|warm``, ``compile.cold``, the cost analysis),
+    EXPLAIN's ``compile.state``, the prewarm worker's skip and the
+    utilization plane's join read it; the poison quarantine keeps the
+    plan's, a plan that fails failing at every size."""
+    count = launch_count(staged, q_np)
+    return pdigest if pdigest is None or count == staged.num_segments else f"{pdigest}.L{count}"

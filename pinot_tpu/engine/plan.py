@@ -719,9 +719,25 @@ def build_query_inputs(
     ctx: TableContext,
     staged: StagedTable,
     scratch: Optional[Dict[Any, Any]] = None,
+    launch: Optional[Dict[str, np.ndarray]] = None,
 ) -> Dict[str, Any]:
-    S = staged.num_segments
+    """``launch``: the launch's segments where it is not the whole staged
+    table (``ladder.launch_segments``; its ``slots`` int32[L] are positions
+    in ``ctx.segments``, -1 for a slot without a segment).  Every
+    per-segment table then has L rows, row j made for segment
+    ``slots[j]`` (zeros in an empty slot, which has no valid row), and
+    ``launch`` itself rides along as ``inputs["segments"]``: the program
+    takes those segments of the resident columns (``kernel.launch_view``),
+    and being an input it is under the inputs digest, so two launches of
+    one plan over different segments neither coalesce nor share uploaded
+    inputs."""
+    S = staged.num_segments if launch is None else int(launch["slots"].shape[0])
+    # (row of the tables, position in ctx.segments) of every segment made for
+    rows = list(enumerate(range(len(ctx.segments)))) if launch is None else [
+        (j, int(i)) for j, i in enumerate(launch["slots"]) if i >= 0]
     inputs: Dict[str, Any] = {}
+    if launch is not None:
+        inputs["segments"] = launch
 
     # filter leaf match tables
     if plan.filter_tree is not None:
@@ -750,7 +766,8 @@ def build_query_inputs(
                 (S, max(leaf_static.k_pad, 1) if kind == "runs" else 1, 2),
                 dtype=np.int32,
             )
-            for i, seg in enumerate(ctx.segments):
+            for j, i in rows:
+                seg = ctx.segments[i]
                 scol = seg.column(leaf_static.column)
                 d = scol.dictionary
                 if kind == "runs":
@@ -762,21 +779,21 @@ def build_query_inputs(
                             cache_key=(seg.segment_name, seg.metadata.crc, leaf_static.column),
                         )
                     for ri, (lo, hi) in enumerate(_table_runs(t)):
-                        runs_e[i, ri] = (lo, hi)
+                        runs_e[j, ri] = (lo, hi)
                 elif kind == "interval":
-                    bound_e[i] = leaf_interval(leaf_node, d)
+                    bound_e[j] = leaf_interval(leaf_node, d)
                 elif kind == "docrange":
                     if leaf_node.operator == FilterOperator.EQUALITY:
                         did = d.index_of(d.stored_type.convert(leaf_node.values[0]))
                         lo, hi = (did, did + 1) if did >= 0 else (0, 0)
                     else:
                         lo, hi = leaf_interval(leaf_node, d)
-                    bound_e[i] = (
+                    bound_e[j] = (
                         _doc_bound(scol.fwd, lo),
                         _doc_bound(scol.fwd, hi),
                     )
                 elif kind in ("points", "points_none"):
-                    point_e[i] = leaf_points(leaf_node, d, leaf_static.k_pad)
+                    point_e[j] = leaf_points(leaf_node, d, leaf_static.k_pad)
                 else:
                     col = staged.column(leaf_static.column)
                     if table_e.shape[1] == 1:
@@ -787,7 +804,7 @@ def build_query_inputs(
                             leaf_node, leaf_static.mode, d, col.card_pad, col.cards[i],
                             cache_key=(seg.segment_name, seg.metadata.crc, leaf_static.column),
                         )
-                    table_e[i] = t
+                    table_e[j] = t
             tables.append(table_e)
             bounds.append(bound_e)
             points.append(point_e)
@@ -808,14 +825,14 @@ def build_query_inputs(
             if not a.is_mv and staged.column(a.column).gfwd is not None:
                 aux["remap"] = np.zeros((S, 1), dtype=np.int32)
             else:
-                aux["remap"] = _stacked_remap(ctx, staged, a.column)
+                aux["remap"] = _stacked_remap(ctx, staged, a.column, S, rows)
         elif a.kind == "hll":
             if not a.is_mv and staged.column(a.column).hll_bucket is not None:
                 # staged per-row streams: the tables would be dead H2D
                 aux["bucket"] = np.zeros((S, 1), dtype=np.int32)
                 aux["rho"] = np.zeros((S, 1), dtype=np.int32)
             else:
-                bucket, rho = _hll_tables(ctx, staged, a.column)
+                bucket, rho = _hll_tables(ctx, staged, a.column, S, rows)
                 aux["bucket"] = bucket
                 aux["rho"] = rho
         agg_aux.append(aux)
@@ -826,7 +843,7 @@ def build_query_inputs(
         inputs["group_remap"] = [
             np.zeros((S, 1), dtype=np.int32)
             if use_g
-            else _stacked_remap(ctx, staged, c)
+            else _stacked_remap(ctx, staged, c, S, rows)
             for c, use_g in zip(plan.group_by.columns, plan.group_by.use_gfwd)
         ]
 
@@ -835,7 +852,7 @@ def build_query_inputs(
         inputs["sel_remap"] = [
             np.zeros((S, 1), dtype=np.int32)
             if use_g
-            else _stacked_remap(ctx, staged, c)
+            else _stacked_remap(ctx, staged, c, S, rows)
             for c, use_g in zip(
                 plan.selection.sort_columns, plan.selection.use_gfwd
             )
@@ -844,25 +861,26 @@ def build_query_inputs(
     return inputs
 
 
-def _stacked_remap(ctx: TableContext, staged: StagedTable, column: str) -> np.ndarray:
+def _stacked_remap(ctx: TableContext, staged: StagedTable, column: str, S: int, rows) -> np.ndarray:
+    """``S``, ``rows``: the launch's, as ``build_query_inputs`` has them."""
     col = staged.column(column)
     gcol = ctx.column(column)
-    out = np.zeros((staged.num_segments, col.card_pad), dtype=np.int32)
-    for i, remap in enumerate(gcol.remaps):
-        out[i, : remap.size] = remap
+    out = np.zeros((S, col.card_pad), dtype=np.int32)
+    for j, i in rows:
+        remap = gcol.remaps[i]
+        out[j, : remap.size] = remap
     return out
 
 
-def _hll_tables(ctx: TableContext, staged: StagedTable, column: str):
+def _hll_tables(ctx: TableContext, staged: StagedTable, column: str, S: int, rows):
     """Per-dictId (bucket, rho) tables: the HLL hash work happens once
     per dictionary entry on host; the device only scatter-maxes."""
     col = staged.column(column)
-    S = staged.num_segments
     bucket = np.zeros((S, col.card_pad), dtype=np.int32)
     rho = np.zeros((S, col.card_pad), dtype=np.int32)
-    for i, seg in enumerate(ctx.segments):
-        d = seg.column(column).dictionary
+    for j, i in rows:
+        d = ctx.segments[i].column(column).dictionary
         bt, rt = hll_mod.dictionary_tables(d)
-        bucket[i, : bt.size] = bt
-        rho[i, : rt.size] = rt
+        bucket[j, : bt.size] = bt
+        rho[j, : rt.size] = rt
     return bucket, rho

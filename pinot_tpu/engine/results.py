@@ -265,7 +265,9 @@ GroupKey = Tuple[str, ...]
 #   rescacheHits       queries answered from the ingest-aware result
 #                      cache (engine/rescache.py) — a hit marks ZERO
 #                      device/host work by construction
-#   segmentsPruned     segments dropped by metadata pruning (pruner.py)
+#   segmentsPruned     segments dropped by metadata pruning, and those
+#                      whose own dictionaries the filter empties, which
+#                      no tier scans (pruner.py)
 #   segmentsPostings   segments answered from host postings (invindex)
 #   segmentsBitsliced  segments answered by the bit-sliced bulk-bitwise
 #                      tier (engine/bitsliced.py — popcount-fused aggs)

@@ -180,7 +180,9 @@ def candidate_blocks(
 ) -> Optional[np.ndarray]:
     """bool [len(live), n_pad//block] candidate map, or None when block
     pruning does not apply (no filter, or segments smaller than one
-    block)."""
+    block).  ``live`` is in the order of ``q_np``'s rows; a None among
+    them is a launch's empty slot (``ladder.launched_segments``), which
+    has no candidate."""
     if plan.filter_tree is None:
         return None
     block = block or zone_block_rows()
@@ -189,6 +191,8 @@ def candidate_blocks(
     nb = n_pad // block
     out = np.zeros((len(live), nb), dtype=bool)
     for si, seg in enumerate(live):
+        if seg is None:
+            continue
         cand = _tree_candidates(plan, plan.filter_tree, q_np, seg, si, nb, block)
         # blocks fully past the segment's rows stay dead
         nb_live = -(-seg.num_docs // block)

@@ -593,6 +593,17 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "cost.tier.*": "per-serving-tier segment counts from the cost vector "
     "(segmentsPruned/Postings/Bitsliced/Zonemap/FullScan/Host/StarTree) — "
     "the series /debug/plans tier mixes reconcile against",
+    # the value pruner (engine/pruner.py): segments a query was handed,
+    # and those its filter can match no row of, left out of every tier's
+    # work and counted with the pruned
+    "phase.prune": "segment pruning: upstream's three verdicts and the "
+    "star-tree routing of every query (a span), and the value verdict where "
+    "a query derives it, a repeated text finding it with its prepared entry "
+    "(a second stretch of prune, between two of phase.staging)",
+    "prune.segments.offered": "segments queries were handed, before any pruner "
+    "(marked by the count, one query at a time)",
+    "prune.segments.value": "segments whose own dictionaries the filter empties "
+    "(pruner.value_dead): kept among the table's segments, scanned by no tier",
     # which rung of the ladder answered (engine/ladder.py TIERS; marked
     # in executor._finish_tier, one mark a query the ladder answered)
     "tier.answered.*": "queries answered by the serving tier of that name: "

@@ -217,11 +217,14 @@ def test_empty_match_and_disable(lineitem, monkeypatch):
     ex, segs = lineitem
     monkeypatch.setenv("PINOT_TPU_BITSLICED", "force")
     # a 0-match filter is legitimately postings turf; pin it off so the
-    # empty-bitmap edge (garbage extreme ids, zero psum) is exercised
+    # empty-bitmap edge (garbage extreme ids, zero psum) is exercised.
+    # Two leaves that each pass some value of every dictionary and no
+    # row together: a leaf no value passes leaves the segment out of the
+    # work altogether (the value pruner, PR 48)
     monkeypatch.setenv("PINOT_TPU_INVINDEX", "0")
     pql = (
         "SELECT count(*), sum(l_quantity), min(l_quantity) FROM lineitem "
-        "WHERE l_extendedprice < 0"
+        "WHERE l_quantity < 5 AND l_quantity > 45"
     )
     res, resp = _run(ex, segs, pql)
     assert res.cost.get("segmentsBitsliced") == len(segs)

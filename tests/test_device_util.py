@@ -504,16 +504,22 @@ def test_roofline_fractions_against_declared_peaks(monkeypatch, util_broker):
     assert recent["rooflineFraction"] is not None
 
 
-def test_host_path_latency_attributed_per_digest(util_broker):
+def test_host_path_latency_attributed_per_digest(util_broker, monkeypatch):
     """The host tier records per-digest execution time too — a mixed
     workload's /debug/plans carries comparable latency on BOTH tiers."""
     broker = util_broker
     server = broker.local_servers[0]
     # postings path serves host-side; the range scan serves on device
-    host_pql = "SELECT avg(metFloat) FROM utilTable WHERE dimStr = 'a'"
+    # (a value the table holds: a literal in no segment's dictionary leaves
+    # every segment out of the work, and no tier serves: PR 48; one value of
+    # twenty is over the postings tier's crossover, which is lifted here)
+    held = random_rows(make_test_schema(with_mv=False), 1200, seed=23)[0]["dimStr"]
+    host_pql = f"SELECT avg(metFloat) FROM utilTable WHERE dimStr = '{held}'"
     dev_pql = "SELECT sum(metInt) FROM utilTable WHERE dimInt > 40"
     for _ in range(2):
+        monkeypatch.setenv("PINOT_TPU_INDEX_MAX_MATCHES", "100000")
         assert not broker.handle_pql(host_pql).exceptions
+        monkeypatch.delenv("PINOT_TPU_INDEX_MAX_MATCHES")
         assert not broker.handle_pql(dev_pql).exceptions
     by_summary = {
         p["summary"]: p for p in server.plan_stats.snapshot(top=10)["plans"]

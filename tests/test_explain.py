@@ -182,6 +182,11 @@ def test_explain_device_digest_matches_real_execution(explain_broker, monkeypatc
     names is the tier that serves."""
     for name, value in settings.items():
         monkeypatch.setenv(name, value)
+    if settings:
+        # such a filter empties every dictionary too: with the value
+        # verdict (PR 48) in force no tier would be asked at all, and the
+        # zone program is what this case is about
+        monkeypatch.setattr("pinot_tpu.engine.pruner.value_dead", lambda seg, tree: None)
     broker = explain_broker
     server = broker.local_servers[0]
     pql = "SELECT sum(metInt) FROM expTable WHERE " + where
@@ -467,3 +472,90 @@ def test_explain_dump_renders_plan_and_analyze(explain_broker):
 
     # graceful on a non-explain response
     assert render_explain({"numDocsScanned": 5}).startswith("(no explain tree")
+
+
+def test_explain_names_the_value_dead_segments_and_the_launch_over_the_rest(monkeypatch):
+    """The value pruner (PR 48) in EXPLAIN: a segment the filter's
+    literals are not in the dictionaries of is recorded as pruned with the
+    leaf, the column and the segment's [min, max]; the device record
+    prints the segments the program runs over and L of S; what is served
+    counts the same; and the prewarm worker's spec compiles the program
+    the ladder derives for that launch, at its L."""
+    import numpy as np
+
+    from pinot_tpu.common.schema import DataType, FieldSpec, FieldType, Schema
+    from pinot_tpu.engine import explain as explain_mod
+    from pinot_tpu.segment.columnar import build_segment_from_columns
+
+    monkeypatch.setenv("PINOT_TPU_INVINDEX", "0")
+    schema = Schema("dated", dimensions=[FieldSpec("yr", DataType.INT), FieldSpec("g", DataType.INT)],
+                    metrics=[FieldSpec("v", DataType.INT, FieldType.METRIC)])
+    n, rng = 512, np.random.default_rng(48)
+    segs = [  # segment i holds the years 1990 + i and 1991 + i
+        build_segment_from_columns(
+            schema, {"yr": (1990 + i + rng.integers(0, 2, size=n)).astype(np.int32),
+                     "g": rng.integers(0, 5, size=n).astype(np.int32), "v": rng.integers(1, 9, size=n).astype(np.int32)},
+            n, "dated", f"vp{next(_FIXTURE_SEQ)}_{i}")
+        for i in range(8)
+    ]
+    broker = single_server_broker("dated", segs, pipeline=True)
+    server = broker.local_servers[0]
+    try:
+        pql = "SELECT sum(v) FROM dated WHERE yr = 1996 OR yr = 1998 GROUP BY g TOP 5"  # segments 5, 6 and 7: L = 4 of 8
+        node = broker.handle_pql("EXPLAIN " + pql).explain["servers"][0]
+        assert node["tierCounts"] == {"segmentsPruned": 5, "segmentsFullScan": 3} and node["totalDocs"] == 8 * n
+        by_name = {r["segment"]: r for r in node["segments"]}
+        dead = by_name[segs[0].segment_name]
+        assert dead["tier"] == "pruned" and dead["reason"].endswith("(ValueSegmentPruner)")
+        assert "yr" in dead["reason"] and "[1990,1991]" in dead["reason"] and "1996" in dead["reason"] and "1998" in dead["reason"]
+        assert [by_name[s.segment_name]["tier"] for s in segs[5:]] == ["fullScan"] * 3
+        launch = node["device"]["launch"]
+        assert launch == {"segments": 4, "ofStaged": 8, "scanned": [s.segment_name for s in segs[5:]]}
+        whole = broker.handle_pql("EXPLAIN SELECT sum(v) FROM dated WHERE yr > 1990 GROUP BY g TOP 5").explain["servers"][0]
+        assert whole["device"]["launch"]["segments"] == whole["device"]["launch"]["ofStaged"] == 8
+
+        # the prewarm worker's spec: the same program, lowered with the launch's four positions
+        lowered = []
+        real_program = explain_mod.ladder.program
+
+        def spy(plan, staged, q_np, block_ids, mesh):
+            kernel = real_program(plan, staged, q_np, block_ids, mesh)
+            lowered.append((kernel, q_np["segments"]["slots"].tolist(), int(q_np["segments"]["first"])))
+            return kernel
+
+        monkeypatch.setattr(explain_mod.ladder, "program", spy)
+        spec = explain_mod.build_prewarm_spec(server.executor, segs, optimize_request(parse_pql(pql)))
+        # a launch size is a compile of its own: the lane's timeline keeps it under the plan's digest with L
+        assert spec is not None and spec["planDigest"] == node["device"]["planDigest"] + ".L4"
+        assert node["device"]["compile"]["state"] == "cold"
+        spec["compile"]()
+        assert lowered[-1][1:] == ([-1, 5, 6, 7], 4)  # the four last neighbours of the eight, one slice
+
+        reply = broker.handle_pql(pql).to_json()
+        assert lowered[-1][0] is lowered[0][0] and len(lowered) == 2  # the serving launch asks for the program prewarmed
+        assert reply["totalDocs"] == 8 * n and reply["numSegmentsQueried"] == 3
+        assert reply["cost"]["segmentsPruned"] == 5 and reply["cost"]["segmentsFullScan"] == 3
+        want = {}
+        for s in segs[5:]:
+            yr, g, v = (np.asarray(s.column(c).dictionary.values)[s.column(c).fwd] for c in ("yr", "g", "v"))
+            for key in range(5):
+                want[key] = want.get(key, 0) + int(v[(g == key) & np.isin(yr, (1996, 1998))].sum())
+        got = {int(r["group"][0]): float(r["value"]) for r in reply["aggregationResults"][0]["groupByResult"]}
+        assert got == {k: float(x) for k, x in want.items()}
+
+        # the same plan with literals that leave two neighbours: L = 2 has not compiled, and nothing says it has
+        lane = spec["lane"]
+        assert lane.compile_info(spec["planDigest"])["launches"] == 1 and lane.compile_info(node["device"]["planDigest"]) is None
+        assert broker.handle_pql("EXPLAIN " + pql).explain["servers"][0]["device"]["compile"]["state"] == "warm"
+        two = "SELECT sum(v) FROM dated WHERE yr = 1997 OR yr = 1998 GROUP BY g TOP 5"  # segments 6 and 7
+        node2 = broker.handle_pql("EXPLAIN " + two).explain["servers"][0]
+        assert node2["device"]["planDigest"] == node["device"]["planDigest"] and node2["device"]["launch"]["segments"] == 2
+        assert node2["device"]["compile"]["state"] == "cold"
+        spec2 = explain_mod.build_prewarm_spec(server.executor, segs, optimize_request(parse_pql(two)))
+        assert spec2 is not None and spec2["planDigest"] == node["device"]["planDigest"] + ".L2"
+        cold = server.metrics.meter("compile.cold").count
+        assert broker.handle_pql(two).to_json()["numSegmentsQueried"] == 2
+        assert server.metrics.meter("compile.cold").count == cold + 1 and lane.compile_info(spec2["planDigest"])["via"] == "cold"
+        assert explain_mod.build_prewarm_spec(server.executor, segs, optimize_request(parse_pql(two))) is None  # launched: warm
+    finally:
+        server.shutdown()

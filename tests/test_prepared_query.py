@@ -48,6 +48,14 @@ def _segments(n: int = 4, rows: int = 2500, seed: int = 320, prefix: str = "prep
     return [synthetic_lineitem_segment(rows, seed=seed + i, name=f"{prefix}{i}") for i in range(n)]
 
 
+def _a_price_of_every_segment(segs) -> float:
+    """A needle every segment's dictionary holds: one that a segment lacks
+    leaves that segment out of the work (the value pruner, PR 48), and the
+    tier's count of segments with it."""
+    common = set.intersection(*(set(map(float, s.column("l_extendedprice").dictionary.values)) for s in segs))
+    return sorted(common)[len(common) // 2]
+
+
 def _marks(metrics) -> dict:
     return {o: metrics.meter(f"plan.prepared.{o}").count for o in OUTCOMES}
 
@@ -206,8 +214,7 @@ def test_a_consuming_segment_never_answers_with_an_older_count():
 def test_a_setting_flipped_between_two_identical_queries_is_followed(monkeypatch, setting, pql, tier_with, tier_without):
     segs = _segments(2, rows=70_000, seed=41, prefix="flip")
     if pql == "needle":
-        price = segs[0].column("l_extendedprice").dictionary
-        pql = f"SELECT sum(l_quantity), count(*) FROM lineitem WHERE l_extendedprice = {price.get(price.cardinality // 2)!r}"
+        pql = f"SELECT sum(l_quantity), count(*) FROM lineitem WHERE l_extendedprice = {_a_price_of_every_segment(segs)!r}"
     monkeypatch.delenv(setting, raising=False)
     monkeypatch.setenv("PINOT_TPU_BITSLICED", "0")  # between postings and the scan stands a third tier: not this test's
     ex = QueryExecutor()
@@ -286,8 +293,7 @@ def test_an_entry_keeps_neither_a_device_array_nor_a_segment_alive():
     from pinot_tpu.engine.device import clear_staging_cache
 
     segs = _segments(2, rows=70_000, seed=41, prefix="held")
-    price = segs[0].column("l_extendedprice").dictionary
-    needle = f"SELECT sum(l_quantity), count(*) FROM lineitem WHERE l_extendedprice = {price.get(price.cardinality // 2)!r}"
+    needle = f"SELECT sum(l_quantity), count(*) FROM lineitem WHERE l_extendedprice = {_a_price_of_every_segment(segs)!r}"
     ex = QueryExecutor()
     for pql in list(SHAPES.values()) + [needle]:
         ex.execute(segs, parse_pql(pql))
@@ -305,7 +311,7 @@ def test_an_entry_keeps_neither_a_device_array_nor_a_segment_alive():
             for leaf in jax.tree_util.tree_leaves(list(part.values.values()))]
     assert held and not [type(x) for x in held if isinstance(x, jax.Array)]
     gone = [weakref.ref(s) for s in segs]
-    del segs, price
+    del segs
     clear_staging_cache()
     context._context_cache.clear()
     gc.collect()

@@ -144,6 +144,31 @@ def test_the_cells_shapes_take_the_tier_and_lowering_perf_md_states(served, monk
     assert record.get("filteredKeySpaceCells") == left
 
 
+# what the value pruner (PR 48) leaves of the three date ranges: the third holds 1996-05 to 1998-08
+SEGMENTS_DEAD = {"q3_1": 0, "q3_2": 0, "q3_3": 0, "q3_4": 2, "q4_1": 0, "q4_2": 2, "q4_3": 2}
+
+
+@pytest.mark.parametrize("name", sorted(SEGMENTS_DEAD))
+def test_a_shape_works_over_the_date_ranges_its_filter_can_match(served, name):
+    """A segment is a range of order dates, so ``d_year = 1997 OR d_year =
+    1998`` and ``d_yearmonth = 'Dec1997'`` can match in the last range
+    alone: the postings tier answers q3_4 from one segment's postings, the
+    device q4_2 and q4_3 from one segment of the three that stay staged;
+    ``totalDocs`` stays the table's, and the reply stays the reference's."""
+    cluster, ref, _oracle = served
+    server = cluster.servers[0]
+    marks = lambda: {k: server.metrics.snapshot()["meters"].get(f"prune.segments.{k}", {"count": 0})["count"] for k in ("offered", "value")}
+    before = marks()
+    reply = cluster.query(PQL[name]).to_json()
+    dead = SEGMENTS_DEAD[name]
+    tier = "segmentsPostings" if AS_PERF_MD_STATES[name][0] == "postings" else "segmentsFullScan"
+    assert reply["cost"].get("segmentsPruned", 0) == dead and reply["cost"][tier] == SEGMENTS - dead, reply["cost"]
+    assert reply["numSegmentsQueried"] == SEGMENTS - dead and reply["totalDocs"] == SEGMENTS * ROWS
+    assert {k: v - before[k] for k, v in marks().items()} == {"offered": SEGMENTS, "value": dead}
+    got = ref_mod.compare(reply, SHAPES[name], ref.answers[name], ref.rows)
+    assert got == dict(CLEAN, sum_gap=got["sum_gap"]) and got["sum_gap"] < 1e-5, (name, got)
+
+
 def test_a_postings_reply_is_the_devices_reply(served, monkeypatch):
     """q3_3 through the postings tier, then with the tier switched off in
     this test alone through the device: the same groups, sums, counts,

@@ -387,3 +387,85 @@ def test_runs_groupby_compiles_for_v5e_at_the_cells_size(one_chip, runs_launches
     columns = 1 + 2 * (shape != "line_16")  # the key, and the two measures the sort carries
     assert memory.temp_size_in_bytes <= (8 + 6 * columns) * S * n * 4
     assert compiled.as_text().count(" sort(") == 1  # one sort of the table's rows: it is the merge across segments too
+
+
+# a launch over a window of the staged table's segments (PR 48): the neighbours of a date range, and a dead one among them
+LAUNCH_SHAPES = {
+    "window_4_of_16": "SELECT SUM(v), COUNT(*) FROM dated WHERE wk BETWEEN 120 AND 159 GROUP BY g100, g50 TOP 5000",
+    "window_3_of_16_a_dead_one_between": "SELECT SUM(v), COUNT(*) FROM dated WHERE (wk BETWEEN 80 AND 99 OR wk = 115) GROUP BY g100, g50 TOP 5000",
+}
+
+
+@pytest.fixture(scope="module")
+def part_launches():
+    """(plan, segment arrays, query inputs) of each launch of
+    LAUNCH_SHAPES as the executor makes it on the chip (float32, int32),
+    over sixteen tiny segments of ten weeks each."""
+    import numpy as np
+
+    from pinot_tpu.common.schema import DataType, FieldSpec, FieldType, Schema
+    from pinot_tpu.engine import kernel as kernel_mod
+    from pinot_tpu.engine.executor import QueryExecutor
+    from pinot_tpu.pql import optimize_request, parse_pql
+    from pinot_tpu.segment.columnar import build_segment_from_columns
+
+    schema = Schema("dated", dimensions=[FieldSpec("wk", DataType.INT), FieldSpec("g100", DataType.INT), FieldSpec("g50", DataType.INT)],
+                    metrics=[FieldSpec("v", DataType.INT, FieldType.METRIC)])
+    rng = np.random.default_rng(48)
+    n = 1024
+    segs = [build_segment_from_columns(schema, {
+        "wk": (10 * i + rng.integers(0, 10, size=n)).astype(np.int32), "g100": rng.integers(0, 100, size=n).astype(np.int32),
+        "g50": rng.integers(0, 50, size=n).astype(np.int32), "v": rng.integers(1, 1000, size=n).astype(np.int32),
+    }, n, "dated", f"part{i:02d}") for i in range(16)]
+    launches = {}
+    with pytest.MonkeyPatch.context() as mp, jax.enable_x64(False):
+        mp.setenv("PINOT_TPU_RAW_CARD_MIN", "0")
+        mp.setenv("PINOT_TPU_INVINDEX", "0")
+        run_kernel = QueryExecutor._run_kernel
+
+        def spy(self, kernel, args, plan, *rest, **kw):
+            launches[name] = (plan, args[0], args[1])
+            return run_kernel(self, kernel, args, plan, *rest, **kw)
+
+        mp.setattr(QueryExecutor, "_run_kernel", spy)
+        try:
+            for name, pql in LAUNCH_SHAPES.items():
+                QueryExecutor().execute(segs, optimize_request(parse_pql(pql)))
+        finally:
+            kernel_mod.make_table_kernel.cache_clear()
+            kernel_mod.make_packed_table_kernel.cache_clear()
+    return launches
+
+
+@pytest.mark.parametrize("shape", sorted(LAUNCH_SHAPES))
+def test_a_launch_over_four_of_sixteen_segments_keeps_four_segments_of_temporaries_on_v5e(one_chip, part_launches, monkeypatch, shape):
+    """The table program of a 5,000-cell group-by (the radix contraction,
+    as SSB's q4_2 takes) over four of sixteen resident segments of
+    8,388,608 rows: the arguments are the whole staged columns, and what
+    the program keeps in HBM beside them is four segments' worth, the
+    view (one ``dynamic-slice`` a column, a dead segment inside the
+    window an empty slot) and the kernel's own, never sixteen."""
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    plan, segs, q = part_launches[shape]
+    assert kernel_mod.groupby_lowering(plan) == "radix"
+    assert q["segments"]["slots"].shape == (4,) and int((q["segments"]["slots"] < 0).sum()) == (shape != "window_4_of_16")
+    S, L, n = 16, 4, 1 << 23
+
+    def at_scale(key, v):
+        rows = (n,) + v.shape[2:] if kernel_mod._row_key(key) else v.shape[1:]
+        return jax.ShapeDtypeStruct((S,) + rows, v.dtype, sharding=one_chip)
+
+    segs = {key: at_scale(key, v) for key, v in segs.items()}
+    q = jax.tree_util.tree_map(lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip), q)
+    try:
+        with jax.enable_x64(False):
+            compiled = kernel_mod.make_table_kernel(plan).lower(segs, q).compile()
+    finally:
+        kernel_mod.make_table_kernel.cache_clear()
+    memory = compiled.memory_analysis()
+    staged = sum(v.dtype.itemsize * S * n for key, v in segs.items() if kernel_mod._row_key(key))
+    assert memory.argument_size_in_bytes >= staged  # the whole resident columns go in
+    assert memory.temp_size_in_bytes < staged  # and under a quarter of their rows is worked on
+    assert "dynamic-slice" in compiled.as_text() and "gather" not in compiled.as_text()  # one slice a column
