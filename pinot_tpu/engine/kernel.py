@@ -271,10 +271,16 @@ def groupby_lowering(plan: StaticPlan) -> Optional[str]:
                config.MAX_GROUP_CAPACITY, on every backend): no state of
                K cells at all.  The table's rows are sorted by group id
                once, a run of equal ids is a group, its count is its
-               length and its sums a scan over it, and the program hands
-               back the per-server trim's candidates, the count of runs
-               and the digest of their values (_reduce_group_runs).  The
-               planner lets through only what it takes
+               length and its sums a scan over it.  The sorted ids are
+               read once, in blocks (_run_lengths): the position where
+               the open run began is carried from block to block, and a
+               block hands on how many runs end in it, their squared
+               lengths' sum and the largest of them, so that the cut and
+               the candidates' places are searched over blocks and read
+               rows in the few blocks a summary points at.  The program
+               hands back the per-server trim's candidates, the count of
+               runs and the digest of their values (_reduce_group_runs).
+               The planner lets through only what it takes
                (plan.group_runs_host_reason).
     None for a plan without a group-by.  min, max, minmaxrange,
     presence, hist and HLL aggregates keep _group_state on every
@@ -1653,9 +1659,25 @@ def reduce_outputs(plan: StaticPlan, outs: Dict[str, Any]) -> Dict[str, Any]:
     return merged
 
 
-# rows a level of a long cumulative pass: the vector as [n / block, block],
-# the pass along the minor axis, the rows' totals in a pass of their own
-_CUMULATIVE_BLOCK = 1024
+# rows a step of the 'runs' lowering's pass over the sorted ids
+# (_run_lengths: whole tiles of [8, 128], a step's ids as [rows / 128,
+# 128]), which is also the block whose largest length bounds the cut
+# (_kth_largest).  Chip run, PR 49, seed 49001, 12 x 2^23 ids in order
+# (4.3M runs, the longest 674,568), ms a pass: 16.67 at 8,192 rows a
+# step, 9.22 at 16,384, 5.48 at 32,768, 3.96 at 65,536, 3.71 at 131,072
+# (a grid step costs 1.3 us before it reads a row; the ids are read and
+# the lengths written in 0.49 each), and the cut reads 100 such blocks
+# whole (2.6 ms at 131,072): 65,536 is the least of the two together.
+# The same pass as jnp steps (log2(block) shifted maxima a block) read
+# 33.5 at blocks of 1,024 and 42.1 at 8,192, and a step that walks a
+# longer block in chunks of 8,192 with its carry in scalars 14.1
+_RUNS_BLOCK = 65536
+# rows of a block the candidates' places are counted and searched by
+# (_first_set: a place reads one such block, 10,100 places a query): one
+# tile of [8, 128].  In the cell (PR 49, call 1, ms a query): the two
+# flags' counts 0.54, the search of 10,000 places over 98,304 blocks
+# 0.92, the gather of their blocks 0.26
+_RUNS_PLACE_BLOCK = 1024
 # partial sums the digest of a 'runs' group-by comes back in (the host
 # adds them in float64)
 _RUNS_DIGEST_PARTS = 1024
@@ -1666,16 +1688,109 @@ def _shifted(x, step: int, fill):
     return jnp.concatenate([jnp.full((step,), fill, x.dtype), x[:-step]])
 
 
-def _long_cumulative(x, op, join, identity):
-    """An inclusive cumulative pass (``op``: jax.lax.cumsum or cummax)
-    over a long vector in two levels; ``join`` folds the rows before into
-    a row's own pass, ``identity`` stands before the first."""
-    n = x.shape[0]
-    if n <= _CUMULATIVE_BLOCK or n % _CUMULATIVE_BLOCK:
-        return op(x, axis=0)
-    inner = op(x.reshape(-1, _CUMULATIVE_BLOCK), axis=1)
-    before = _shifted(op(inner[:, -1], axis=0), 1, identity)
-    return join(inner, before[:, None]).reshape(-1)
+def _run_lengths(ids, capacity: int, with_dist: bool):
+    """ONE pass over ids in ascending order, whole blocks of
+    ``_RUNS_BLOCK`` (Pallas, a sequential grid, a block a step): a run of
+    equal ids is a group, and a run of ids under ``capacity`` is live.
+
+    Carried from block to block (SMEM): the position where the run that
+    is open at the block's end began.  A step is also handed the tile of
+    [8, 128] ids before its block and the one after it, for the id before
+    its first row and the id after its last (ascending: a tile's largest
+    and smallest), so no step waits for another's rows.  In a block, a
+    row's run began at the largest start position at or before it: 7
+    shifted maxima along the lanes, log2(sublanes) over the rows' last
+    lanes, the carry under both.
+
+    Returned, a row: ``order`` int32 [n], a run's length at its last
+    live row and the dtype's minimum everywhere else; with ``with_dist``
+    also a row's distance from its run's first (what _run_sums reads),
+    else None.  A block: how many live runs end in it, the sum of their
+    squared lengths (config.float_dtype()) and the largest of them (the
+    minimum where none ends), each set down in a tile of [8, 128] that
+    stays in VMEM for the 1,024 steps it has places for."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    blk, tile = _RUNS_BLOCK, 8 * 128
+    R = blk // 128
+    fdt = config.float_dtype()
+    low = int(jnp.iinfo(jnp.int32).min)
+    nb = ids.shape[0] // blk
+
+    def kernel(ids_ref, before_ref, after_ref, *refs):
+        order_ref = refs[0]
+        dist_ref = refs[1] if with_dist else None
+        ends_ref, squares_ref, longest_ref, open_ref = refs[-4:]
+        i = pl.program_id(0)
+
+        @pl.when(i == 0)
+        def _first_block():
+            open_ref[0] = 0
+
+        @pl.when(i % tile == 0)
+        def _new_tile():
+            for ref in (ends_ref, squares_ref, longest_ref):
+                ref[...] = jnp.zeros_like(ref)
+
+        x = ids_ref[...]  # [R, 128]: row r, lane l is the block's row 128 r + l
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, 128), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (R, 128), 1)
+        pos = i * blk + row * 128 + lane
+        before = jnp.where(i == 0, -1, jnp.max(before_ref[...]))
+        after = jnp.where(i == nb - 1, capacity, jnp.min(after_ref[...]))
+
+        def neighbour(step, edge_lane, edge_row, outside):
+            by_lane = pltpu.roll(x, step % 128, axis=1)
+            at_edge = jnp.where(row == edge_row, outside, pltpu.roll(by_lane, step % R, axis=0))
+            return jnp.where(lane == edge_lane, at_edge, by_lane)
+
+        start = x != neighbour(1, 0, 0, before)
+        end = (x != neighbour(-1, 127, R - 1, after)) & (x < capacity)
+        # where a row's run began: the largest start position at or before it
+        first = jnp.where(start, pos, open_ref[0])
+        step = 1
+        while step < 128:
+            first = jnp.maximum(first, jnp.where(lane >= step, pltpu.roll(first, step, axis=1), low))
+            step *= 2
+        above = pltpu.roll(first, 1, axis=1)[:, :1]  # [R, 1]: a row's last lane, its largest
+        rows = row[:, :1]
+        step = 1
+        while step < R:
+            above = jnp.maximum(above, jnp.where(rows >= step, pltpu.roll(above, step, axis=0), low))
+            step *= 2
+        first = jnp.maximum(first, jnp.where(rows >= 1, pltpu.roll(above, 1, axis=0), low))
+        open_ref[0] = jnp.max(first)
+
+        dist = pos - first
+        order = jnp.where(end, dist + 1, low)
+        order_ref[...] = order
+        if with_dist:
+            dist_ref[...] = dist
+        mine = (row[:8] * 128 + lane[:8]) == i % tile  # the block's place in its tile of summaries
+        ends_ref[...] = jnp.where(mine, jnp.sum(end.astype(jnp.int32)), ends_ref[...])
+        squares_ref[...] = jnp.where(mine, jnp.sum(jnp.where(end, jnp.square((dist + 1).astype(fdt)), 0)), squares_ref[...])
+        longest_ref[...] = jnp.where(mine, jnp.max(order), longest_ref[...])
+
+    by_row = pl.BlockSpec((R, 128), lambda i: (i, 0))
+    tile_before = pl.BlockSpec((8, 128), lambda i: (jnp.maximum(i * (R // 8) - 1, 0), 0))
+    tile_after = pl.BlockSpec((8, 128), lambda i: (jnp.minimum((i + 1) * (R // 8), nb * (R // 8) - 1), 0))
+    by_block = pl.BlockSpec((8, 128), lambda i: (i // tile, 0))
+    rows_out = jax.ShapeDtypeStruct((nb * R, 128), jnp.int32)
+    tiles = -(-nb // tile) * 8
+    ids = ids.reshape(-1, 128)
+    outs = pl.pallas_call(
+        kernel,
+        grid=(nb,),
+        in_specs=[by_row, tile_before, tile_after],
+        out_specs=[by_row] * (1 + with_dist) + [by_block] * 3,
+        out_shape=[rows_out] * (1 + with_dist) + [jax.ShapeDtypeStruct((tiles, 128), dt) for dt in (jnp.int32, fdt, jnp.int32)],
+        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        # the carry needs the grid in order: the compiled TPU grid, or the interpreter
+        interpret=jax.default_backend() == "cpu",
+    )(ids, ids, ids)
+    order, dist = outs[0].reshape(-1), (outs[1].reshape(-1) if with_dist else None)
+    return (order, dist, *(out.reshape(-1)[:nb] for out in outs[-3:]))
 
 
 def _run_sums(values, dist, longest):
@@ -1708,29 +1823,62 @@ def _order_keys(values):
     return jnp.where(bits < 0, bits ^ jnp.iinfo(idt).max, bits)
 
 
-def _kth_largest(keys, k: int):
-    """The ``k``-th largest of integer ``keys`` (the dtype's minimum where
-    they are fewer), by bisection on the value: one counting pass over the
-    rows a bit of the key, no sort and no scatter."""
-    info = jnp.iinfo(keys.dtype)
-
+def _largest_with(enough, lo, hi):
+    """The largest integer in [lo, hi] that ``enough`` holds for, by
+    bisection; ``enough`` holds for ``lo`` and for everything under a
+    value it holds for."""
     def halve(bounds):
         lo, hi = bounds
         mid = (lo >> 1) + (hi >> 1) + (((lo & 1) + (hi & 1) + 1) >> 1)  # ceil((lo + hi) / 2), no overflow
-        enough = jnp.sum(keys >= mid, dtype=jnp.int32) >= k
-        return jnp.where(enough, mid, lo), jnp.where(enough, hi, mid - 1)
+        ok = enough(mid)
+        return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)
 
-    lo, _ = jax.lax.while_loop(lambda b: b[0] < b[1], halve, (jnp.array(info.min, keys.dtype), jnp.max(keys)))
-    return lo
+    return jax.lax.while_loop(lambda b: b[0] < b[1], halve, (lo, hi))[0]
 
 
-def _first_set(flags, size: int):
-    """(positions of the first ``size`` set flags, ascending, the vector's
-    length where they run out; how many are set): a cumulative count and
-    one binary search a position, ``size`` gathers a step."""
-    counted = _long_cumulative(flags.astype(jnp.int32), jax.lax.cumsum, jnp.add, 0)
+def _first_set(keys, flag, size: int):
+    """(positions of the first ``size`` rows of integer ``keys`` [blocks,
+    ... a block's rows] that ``flag`` holds for, ascending in the flat
+    order, the rows' number where they run out; how many rows it holds
+    for).  The blocks' counts, a cumulative count over the BLOCKS, one
+    search a place over that, and a place's row from the one block it
+    lands in (a cumulative count along the block's lanes by shifted adds:
+    ``cumsum`` along 1,024 lanes is a window the chip walks, 2.3 ms for
+    10,000 blocks): no pass along the rows but the count."""
+    nb, blk = keys.shape[0], int(np.prod(keys.shape[1:]))
+    counts = jnp.sum(flag(keys).reshape(nb, -1), axis=1, dtype=jnp.int32)
+    upto = jnp.cumsum(counts)
     wanted = jnp.arange(1, size + 1, dtype=jnp.int32)
-    return jnp.searchsorted(counted, wanted, side="left").astype(jnp.int32), counted[-1]
+    block = jnp.minimum(jnp.searchsorted(upto, wanted, side="left", method="compare_all"), nb - 1).astype(jnp.int32)
+    inside = wanted - (upto[block] - counts[block])  # which of its block's set rows a place is
+    lanes = np.gcd(blk, 128)
+    upto_row = flag(keys[block]).reshape(size, -1, lanes).astype(jnp.int32)
+    step = 1
+    while step < lanes:
+        upto_row = upto_row + jnp.pad(upto_row[..., :-step], ((0, 0), (0, 0), (step, 0)))
+        step *= 2
+    rows_before = jnp.cumsum(upto_row[..., -1], axis=1) - upto_row[..., -1]
+    within = jnp.sum(upto_row + rows_before[..., None] < inside[:, None, None], axis=(1, 2), dtype=jnp.int32)
+    return jnp.where(wanted <= upto[-1], block * blk + within, nb * blk), upto[-1]
+
+
+def _kth_largest(keys, largest, k: int):
+    """The ``k``-th largest of integer ``keys`` [blocks, ... a block's
+    rows] (the dtype's minimum where they are fewer), ``largest`` each block's
+    largest.  The ``k``-th largest of the blocks' largest is at most the
+    answer, and fewer than ``k`` blocks hold anything over it: the
+    bisection counts over those blocks' rows alone, from that floor to
+    the largest key, and no pass runs along the rows."""
+    nb = keys.shape[0]
+    low = jnp.array(jnp.iinfo(keys.dtype).min, keys.dtype)
+    top = jnp.max(largest)
+    floor = low
+    if nb >= k:
+        floor = _largest_with(lambda v: jnp.sum(largest >= v, dtype=jnp.int32) >= k, low, top)
+    over, found = _first_set(largest[None, :], lambda v: v > floor, min(k, nb))
+    found = (jnp.arange(over.shape[0]) < found).reshape(-1, *[1] * (keys.ndim - 1))
+    rows = jnp.where(found, keys[jnp.minimum(over, nb - 1)], low)
+    return _largest_with(lambda v: jnp.sum(rows >= v, dtype=jnp.int32) >= k, floor, top)
 
 
 def _reduce_group_runs(plan: StaticPlan, rows):
@@ -1739,25 +1887,32 @@ def _reduce_group_runs(plan: StaticPlan, rows):
     finalize needs of a group-by, with no array of ``capacity`` cells.
 
     ONE sort of the table's keys carrying the weight columns (a filtered
-    row's key is ``capacity`` and sorts last): a run of equal keys is a
+    row's key is ``capacity`` and sorts last; so do the rows that pad the
+    table to whole blocks of ``_RUNS_BLOCK``): a run of equal keys is a
     group, whichever segments its rows came from, so the sort is the
-    merge across segments as well.  A run's count is its length, taken
-    from row positions in int32 (exact at any size: no count crosses the
-    segment axis as a float); its sums are _run_sums over the carried
-    columns.  Every aggregate's value stands at its run's last row.
+    merge across segments as well.  The sorted ids are then read ONCE
+    (_run_lengths): a run's count is its length, taken from row positions
+    in int32 (exact at any size: no count crosses the segment axis as a
+    float) and set down at the run's last row; a block hands on how many
+    live runs end in it (their sum is the live count), the sum of their
+    squared lengths (the digest of a count) and the largest of them.  A
+    plan that carries columns also takes a row's distance from its run's
+    first, and its sums are _run_sums over the carried columns.  Every
+    aggregate's value stands at its run's last row.
 
     Out of those, per aggregate in its own (descending) order, the
     per-server trim's candidates as results.trim_group_candidates keeps
     them: with more than ``max(5 x TOP, 100)`` live groups, the groups
     strictly beyond the value at that cut and the groups tied with it in
     ascending key, ``max(MAX_TRIM_TIES, what is left of the trim)`` of
-    them; else every live group.  The cut is _kth_largest; the positions
-    come from _first_set.  Returned: ``gb_runs_keys`` (the candidates'
+    them; else every live group.  The cut is _kth_largest over the blocks
+    the blocks' largest values leave; the positions come from _first_set,
+    by block.  Returned: ``gb_runs_keys`` (the candidates'
     keys an aggregate, -1 where a place is empty; an aggregate's list may
     repeat another's), ``gb_runs_state`` (the m state rows of
     _contraction_slots at those places, the occupancy's as a row count),
     ``gb_runs_live`` (live groups, exact) and ``gb_runs_sumsq`` (each
-    aggregate's sum of squared values over every live group, in
+    aggregate's sum of squared values over every live group, in at most
     _RUNS_DIGEST_PARTS partial sums: the cost vector's
     ``groupStateSumSq``).  A few hundred kilobytes at the most, whatever
     ``capacity`` is."""
@@ -1765,8 +1920,18 @@ def _reduce_group_runs(plan: StaticPlan, rows):
 
     gb = plan.group_by
     fdt = config.float_dtype()
+    # the segments' rows as they were made, then laid flat: without the
+    # barrier the compiler lays flat every operand of the filter's select
+    # (the ids, an iota, the segments' row counts), a copy of the table each
+    if rows[0].shape[-1] % 1024 == 0:  # a segment's rows as tiles of [8, 128]: the flat order, and a copy the chip makes at twice the rate
+        rows = [r.reshape(*r.shape[:-1], -1, 8, 128) for r in rows]
+    rows = jax.lax.optimization_barrier(tuple(rows))
     ids = rows[0].reshape(-1)
     cols = [c.reshape(-1) for c in rows[1:]]
+    pad = (-ids.shape[0]) % _RUNS_BLOCK
+    if pad:  # rows that count nowhere, as a filtered row does
+        ids = jnp.concatenate([ids, jnp.full(pad, gb.capacity, ids.dtype)])
+        cols = [jnp.concatenate([c, jnp.zeros(pad, c.dtype)]) for c in cols]
     n = ids.shape[0]
     trim = max(gb.top_n * 5, 100)
     most_ties = max(MAX_TRIM_TIES, trim)
@@ -1775,41 +1940,48 @@ def _reduce_group_runs(plan: StaticPlan, rows):
         ids, *cols = jax.lax.sort((ids, *cols), num_keys=1, is_stable=False)
 
     with jax.named_scope("groupby_runs_pass"):
-        live_row = ids < gb.capacity
-        end = live_row & (ids != jnp.concatenate([ids[1:], jnp.full((1,), gb.capacity, ids.dtype)]))
-        start = ids != _shifted(ids, 1, -1)
-        pos = jax.lax.iota(jnp.int32, n)
-        first = _long_cumulative(jnp.where(start, pos, 0), jax.lax.cummax, jnp.maximum, 0)
-        dist = pos - first
-        state = [dist + 1]  # the occupancy: a run's length at its last row
+        length, dist, ends, squares, longest = _run_lengths(ids, gb.capacity, bool(cols))
+        live = jnp.sum(ends)
+        state = [length]  # the occupancy: a run's length at its last row
         if cols:
-            longest = jnp.max(jnp.where(live_row, dist, 0))
-            state.extend(_run_sums(c, dist, longest) for c in cols)
-        live = jnp.sum(end, dtype=jnp.int32)
+            most = jnp.maximum(jnp.max(longest), 1) - 1
+            state.extend(_run_sums(c, dist, most) for c in cols)
+        end = length > 0
 
     def value_of(agg: StaticAgg, slots):
         if agg.base == "avg":
             return state[slots[0]] / jnp.maximum(state[slots[1]], 1).astype(fdt)
         return state[slots[0]]
 
+    def in_parts(sums):  # a block's or a row's, to partial sums of whole blocks
+        return jnp.sum(sums.reshape(np.gcd(n // _RUNS_BLOCK, _RUNS_DIGEST_PARTS), -1), axis=1)
+
     keys_out, at_out, sumsq = [], [], []
     chosen: Dict[tuple, tuple] = {}  # aggregates of one order share one selection
-    parts = np.gcd(n, _RUNS_DIGEST_PARTS)
     for i, slots in _contraction_slots(plan)[0].items():
         agg = plan.aggs[i]
         which = (agg.base == "avg", tuple(slots))
         if which not in chosen:
-            value = value_of(agg, slots)
-            with jax.named_scope("groupby_runs_digest"):
-                squares = jnp.where(end, jnp.square(value.astype(fdt)), 0)
-                digest = jnp.sum(squares.reshape(parts, -1), axis=1)
+            if which == (False, (0,)):  # a count: the pass made its order, its blocks' largest and its digest's parts
+                order, largest = length, longest
+                with jax.named_scope("groupby_runs_digest"):
+                    digest = in_parts(squares)
+            else:
+                value = value_of(agg, slots)
+                with jax.named_scope("groupby_runs_digest"):
+                    digest = in_parts(jnp.where(end, jnp.square(value.astype(fdt)), 0))
+                with jax.named_scope("groupby_runs_select"):
+                    order = _order_keys(value)
+                    order = jnp.where(end, order, jnp.iinfo(order.dtype).min)
+                    largest = jnp.max(order.reshape(-1, _RUNS_BLOCK), axis=1)
             with jax.named_scope("groupby_runs_select"):
-                order = _order_keys(value)
-                order = jnp.where(end, order, jnp.iinfo(order.dtype).min)
-                cut = _kth_largest(order, trim)
+                # blocks as whole tiles of [8, 128]: views of the flat rows, where [blocks, rows a block] is a copy
+                cut = _kth_largest(order.reshape(-1, _RUNS_BLOCK // 128, 128), largest, trim)
                 few = live <= trim
-                beyond_at, beyond_n = _first_set(end & (few | (order > cut)), min(trim, n))
-                tied_at, tied_n = _first_set(end & ~few & (order == cut), min(most_ties, n))
+                low = jnp.iinfo(order.dtype).min
+                by_block = order.reshape(-1, _RUNS_PLACE_BLOCK // 128, 128)
+                beyond_at, beyond_n = _first_set(by_block, lambda v: (v > low) & (few | (v > cut)), min(trim, n))
+                tied_at, tied_n = _first_set(by_block, lambda v: (v > low) & ~few & (v == cut), min(most_ties, n))
                 tied_n = jnp.minimum(tied_n, jnp.maximum(MAX_TRIM_TIES, trim - beyond_n))
                 at = jnp.concatenate([beyond_at, tied_at])
                 kept = jnp.concatenate([

@@ -240,22 +240,66 @@ def _edge_tables():
     }
 
 
-EDGES = _edge_tables()
+def _block_edge_tables():
+    """What a blocked pass over the sorted ids can get wrong (PR 49), the
+    block read from the module: ``(users a segment, TOP, filter)``.  The
+    table's rows in id order are the users' runs in ascending id, so a
+    user's count places its run; a filter is ``(PQL, rows it keeps)`` over
+    ``users_table``'s columns (RegionID = user % 97, AdvEngineID = the
+    row's number in its segment % 5), ranges, which the scan answers."""
+    block = kernel_mod._RUNS_BLOCK
+    ids = np.arange(1, 6001, dtype=np.int64) * 7919  # 6,000 users: over the patched bound
+    rng = np.random.default_rng(49)
+
+    def dealt(users):  # the table's rows in any order, over three segments of unequal length
+        users = rng.permutation(users)
+        return [users[: block // 3], *np.array_split(users[block // 3:], 2)]
+
+    def with_counts(counts):  # ids[i] has counts[i] rows, every other user one
+        return dealt(np.concatenate([ids, *[np.full(c - 1, ids[i]) for i, c in counts.items()]]))
+
+    def placed(users, slots):  # ``slots`` users of a region under 49 at rows whose AdvEngineID is 4, the others of them elsewhere
+        low = users[users % 97 < 49]
+        out = np.empty(users.size, dtype=np.int64)
+        row = np.arange(users.size)
+        here = np.concatenate([row[row % 5 == 4][:slots], row[row % 5 != 4][: low.size - slots]])
+        out[here] = low
+        out[np.setdiff1d(row, here)] = users[users % 97 >= 49]
+        return out
+
+    low_region_engine_4 = ("WHERE RegionID < 49 AND AdvEngineID > 3", lambda users, row: (users % 97 < 49) & (row % 5 == 4))
+    return {
+        "a_run_over_several_whole_blocks": (with_counts({2999: 3 * block + 17, 3000: 2}), 10, None),
+        # run 0 fills block 0, runs 1 and 2 half a block each: run 2 ends on a block's last row, run 3 starts on a block's first
+        "runs_that_end_and_start_on_a_blocks_edge": (with_counts({0: block, 1: block // 2, 2: block // 2, 5999: 3}), 10, None),
+        "rows_that_are_no_whole_number_of_blocks": ([ids[:2000], ids[2000:4000], np.concatenate([ids[4000:], ids[:7]])], 10, None),
+        "the_longest_run_a_power_of_two": (with_counts({41: 4096, 5000: 1024}), 10, None),
+        "the_longest_run_a_power_of_two_and_one": (with_counts({41: 4097, 5000: 1024}), 10, None),
+        # run 0 fills block 0, runs 1 and 2 half a block each, then a run over three blocks, a power of two and one more
+        "every_boundary_in_one_table": (with_counts({0: block, 1: block // 2, 2: block // 2, 2999: 3 * block + 17, 41: 4096, 5000: 4097}), 10, None),
+        "every_row_filtered": ([placed(ids, 0)] * 2, 10, low_region_engine_4),
+        "one_live_row": ([placed(ids, 1), placed(ids, 0)], 10, low_region_engine_4),
+    }
+
+
+BLOCK_EDGES = _block_edge_tables()
+EDGES = {**{case: (*table, None) for case, table in _edge_tables().items()}, **BLOCK_EDGES}
 
 
 @pytest.mark.parametrize("case", sorted(EDGES))
 def test_the_trim_the_live_count_and_the_digest_are_numpys(monkeypatch, case):
-    per_segment, top = EDGES[case]
+    per_segment, top, where = EDGES[case]
     segments = users_table(per_segment)
-    pql = f"SELECT COUNT(*) FROM hits GROUP BY UserID TOP {top}"
+    pql = f"SELECT COUNT(*) FROM hits {where[0] if where else ''} GROUP BY UserID TOP {top}"
     plans, launches, reply, _, server = _served_once(monkeypatch, segments, pql)
     assert launches[0]["tags"]["groupby"] == "runs" and not reply.get("exceptions"), reply
-    users, counts = np.unique(np.concatenate(per_segment), return_counts=True)
+    kept = [users[where[1](users, np.arange(users.size))] if where else users for users in map(np.asarray, per_segment)]
+    users, counts = np.unique(np.concatenate(kept), return_counts=True)
     cost = reply["cost"]
-    assert cost["numGroupsLive"] == users.size and cost.get("segmentsHost", 0) == 0
-    assert cost["groupStateSumSq"] == float(np.sum(counts.astype(np.int64) ** 2))
+    assert cost.get("numGroupsLive", 0) == users.size and cost.get("segmentsHost", 0) == 0
+    assert cost.get("groupStateSumSq", 0.0) == float(np.sum(counts.astype(np.int64) ** 2))
     # the per-server trim over a dense state of the same counts, key order the dictionary's (ascending value)
-    assert cost["numGroupsKept"] == trim_group_candidates([counts.astype(np.float64)], [False], top, users.size).size
+    assert cost.get("numGroupsKept", 0) == trim_group_candidates([counts.astype(np.float64)], [False], top, users.size).size
     groups = reply["aggregationResults"][0]["groupByResult"]
     assert [int(float(g["value"])) for g in groups] == sorted(counts.tolist(), reverse=True)[:top]
     count_of = dict(zip(users.tolist(), counts.tolist()))
@@ -263,6 +307,54 @@ def test_the_trim_the_live_count_and_the_digest_are_numpys(monkeypatch, case):
     assert len({g["group"][0] for g in groups}) == min(top, users.size)
     if case == "every_row_its_own_user":
         assert cost["numGroupsKept"] == MAX_TRIM_TIES  # the cap on boundary ties, as the dense trim has it
+
+
+# the same boundaries with columns carried: every unfiltered one in ONE table (a compile a table is what a case costs)
+CARRIED_EDGES = ("every_boundary_in_one_table", "every_row_filtered", "one_live_row")
+
+
+@pytest.mark.parametrize("case", CARRIED_EDGES)
+def test_a_blocks_edges_with_a_sum_and_an_average_carried_equal_the_host_paths(monkeypatch, case):
+    per_segment, top, where = BLOCK_EDGES[case]
+    segments = users_table(per_segment)
+    pql = f"SELECT COUNT(*), sum(AdvEngineID), avg(ResolutionWidth) FROM hits {where[0] if where else ''} GROUP BY UserID TOP {top}"
+    plans, launches, reply, _, server = _served_once(monkeypatch, segments, pql)
+    assert launches[0]["tags"]["groupby"] == "runs" and not reply.get("exceptions"), reply
+    assert reply["cost"].get("segmentsHost", 0) == 0 and server.executor.healing_stats()["hostFailovers"] == 0
+    host = host_answer(segments, pql)
+    held_to_the_host(reply, host, top)
+    assert reply["numDocsScanned"] == host.num_docs_scanned
+    assert reply["cost"].get("numGroupsLive", 0) == len(host.groups) or len(host.groups) > 100  # the host trims too
+
+
+@pytest.mark.parametrize("with_dist", [False, True])
+def test_the_pass_hands_on_its_carry_and_its_summaries_over_many_blocks_and_tiles(monkeypatch, with_dist):
+    """``kernel._run_lengths`` alone against numpy, its block cut to one
+    tile so that 1,100 blocks fill two tiles of summaries: a run over
+    several blocks, runs on both sides of every kind of edge, filtered
+    rows last."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(kernel_mod, "_RUNS_BLOCK", 1024)
+    rng = np.random.default_rng(49)
+    n, capacity = 1100 * 1024, 100_000
+    lengths = np.concatenate([[1024, 512, 512, 3000, 1], rng.integers(1, 40, size=60_000)])
+    lengths = lengths[np.cumsum(lengths) <= n - 5000]
+    ids = np.concatenate([np.repeat(np.sort(rng.choice(capacity, lengths.size, replace=False)), lengths),
+                          np.full(n - lengths.sum(), capacity)]).astype(np.int32)
+    order, dist, ends, squares, longest = kernel_mod._run_lengths(jnp.asarray(ids), capacity, with_dist)
+    last = np.cumsum(lengths) - 1
+    want = np.full(n, np.iinfo(np.int32).min, dtype=np.int32)
+    want[last] = lengths
+    assert np.array_equal(np.asarray(order), want)
+    by_block = want.reshape(-1, 1024)
+    assert np.array_equal(np.asarray(ends), np.sum(by_block > 0, axis=1)) and np.array_equal(np.asarray(longest), by_block.max(axis=1))
+    assert np.array_equal(np.asarray(squares), np.sum(np.where(by_block > 0, by_block.astype(np.float64) ** 2, 0), axis=1))
+    if with_dist:
+        first = np.repeat(np.concatenate([[0], last + 1]), np.concatenate([lengths, [n - lengths.sum()]]))
+        assert np.array_equal(np.asarray(dist), np.arange(n) - first)
+    else:
+        assert dist is None
 
 
 # ---------------------------------------------------------------------------

@@ -354,14 +354,19 @@ def test_runs_groupby_compiles_for_v5e_at_the_cells_size(one_chip, runs_launches
     """The whole table program of ClickBench's line 16 at the cell's own
     size, 12 segments of 8,388,608 rows and 17.6M keys (and, smaller, of
     the same with two carried columns): one sort of the
-    table's ids, the run pass, the cut, the candidates.  What comes back
+    table's ids, the blocked pass over them (Pallas, compiled and not
+    interpreted), the cut, the candidates.  What comes back
     is kilobytes, and what the program keeps in HBM beside the staged
-    columns is rows, never keys: a few vectors of 100.7M elements."""
+    columns is rows, never keys: a few vectors of 100.7M elements, and
+    the candidates' blocks."""
     import dataclasses
+    import math
 
     from pinot_tpu.engine import config, kernel as kernel_mod
+    from pinot_tpu.engine.results import MAX_TRIM_TIES
 
     monkeypatch.setattr(config, "MAX_GROUP_CAPACITY", 1 << 20)  # the program's own bound: 17.6M keys are over it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the pass compiled for the chip, not interpreted
     plan, segs, q = runs_launches[shape]
     plan = dataclasses.replace(plan, group_by=dataclasses.replace(
         plan.group_by, gcards=(HITS_USERS_KEYS,), capacity=HITS_USERS_KEYS))
@@ -385,8 +390,21 @@ def test_runs_groupby_compiles_for_v5e_at_the_cells_size(one_chip, runs_launches
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes < 1 << 20  # candidates, a count and a digest: never 17.6M of anything
     columns = 1 + 2 * (shape != "line_16")  # the key, and the two measures the sort carries
-    assert memory.temp_size_in_bytes <= (8 + 6 * columns) * S * n * 4
-    assert compiled.as_text().count(" sort(") == 1  # one sort of the table's rows: it is the merge across segments too
+    # (3 + 3 x columns) vectors of S x n x 4 B: the rows laid flat, the sort's result, the pass's lengths
+    # (and distances), a carried column's scan; and the candidates' blocks, twice (PR 48's program kept 8 + 6 x columns)
+    places = 100 + MAX_TRIM_TIES
+    assert memory.temp_size_in_bytes <= (3 + 3 * columns) * S * n * 4 + 2 * places * kernel_mod._RUNS_PLACE_BLOCK * 4
+    text = compiled.as_text()
+    assert text.count(" sort(") == 1  # one sort of the table's rows: it is the merge across segments too
+    assert text.count("tpu_custom_call") == 1  # the pass over the sorted ids
+    if shape == "line_16":
+        # nothing cumulative and no search runs along the table's rows: a window at most over the candidates' blocks,
+        # and no loop that carries a vector of the rows (PR 48's program had six: three copies of the segments' rows
+        # into one vector, the cut's counting passes and the two searches)
+        windows = [math.prod([int(d) for d in re.search(r"= \w+\[([\d,]+)\]", line).group(1).split(",")])
+                   for line in text.splitlines() if " reduce-window(" in line]
+        assert max(windows) <= places * kernel_mod._RUNS_PLACE_BLOCK < S * n // 8
+        assert not [line for line in text.splitlines() if " while(" in line and f"[{S * n}]" in line]
 
 
 # a launch over a window of the staged table's segments (PR 48): the neighbours of a date range, and a dead one among them
