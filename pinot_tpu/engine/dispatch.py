@@ -388,7 +388,7 @@ class _Dispatch:
     __slots__ = (
         "key", "launch", "pending", "waiters", "completed", "value",
         "error", "plan_digest", "cost_provider", "batch", "batch_size",
-        "t_submit", "trace", "parent", "program", "groupby", "operands", "expr", "cells", "blocks", "hll", "hll_parts", "segments", "launch_id",
+        "t_submit", "trace", "parent", "program", "groupby", "operands", "expr", "cells", "blocks", "hll", "hll_parts", "segments", "selection", "launch_id",
     )
 
     def __init__(
@@ -411,6 +411,7 @@ class _Dispatch:
         hll: str = "",
         hll_parts: int = 0,
         segments: str = "",
+        selection: str = "",
     ) -> None:
         self.t_submit = t_submit  # phase.laneQueue runs from here to the launch call
         # the submitting query's span tree and the span (its laneWait)
@@ -426,6 +427,7 @@ class _Dispatch:
         self.hll = hll  # the lowering of the program's HLL aggregates (kernel.hll_lowering)
         self.hll_parts = hll_parts  # under 'sort', the parts a segment's keys are sorted in (kernel.hll_sort_parts)
         self.segments = segments  # "<L>/<S>": the segments the program runs over, of the staged table's (ladder.launch_segments)
+        self.selection = selection  # the form a selection's candidates are found in (kernel.selection_lowering)
         self.launch_id: Optional[int] = None  # the physical launch this rode (occupancy)
         self.key = key
         self.launch = launch
@@ -610,6 +612,7 @@ class DeviceLane:
         hll: str = "",
         hll_parts: int = 0,
         segments: str = "",
+        selection: str = "",
     ) -> LaneTicket:
         """Enqueue a kernel launch, or coalesce onto an identical one
         that is queued, launching, or still executing on device.
@@ -681,7 +684,7 @@ class DeviceLane:
                 self._hit()
             else:
                 d = _Dispatch(key, launch, pending, plan_digest, cost_provider, batch,
-                              trace, parent, program, t_submit, groupby, operands, expr, cells, blocks, hll, hll_parts, segments)
+                              trace, parent, program, t_submit, groupby, operands, expr, cells, blocks, hll, hll_parts, segments, selection)
                 d.waiters.append(ticket)
                 self._by_key[key] = d
                 self._depth_tick_locked()
@@ -1242,6 +1245,8 @@ class DeviceLane:
                 tags["hll"] = d.hll
             if d.segments:
                 tags["segments"] = d.segments
+            if d.selection:
+                tags["selection"] = d.selection
             launching = boundary(
                 "laneDispatch", d.trace, launch_timer, parent=d.parent, program=d.program,
                 via="warm" if d.plan_digest is None or d.plan_digest in self._compile else "first",
@@ -1259,6 +1264,8 @@ class DeviceLane:
                 self.metrics.meter(f"hll.lowering.{d.hll}").mark()
                 if d.hll_parts:
                     self.metrics.meter("hll.sort.parts").mark(d.hll_parts)
+            if d.selection and self.metrics is not None:
+                self.metrics.meter(f"selection.lowering.{d.selection}").mark()
             self._set_inflight(1)
             error: Optional[BaseException] = None
             value: Any = None

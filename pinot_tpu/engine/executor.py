@@ -1305,7 +1305,7 @@ class QueryExecutor:
         # program); and how a zone-tier program
         # reads its candidate blocks: the ``blocks=`` tag and the
         # ``zone.blocks.*`` mark
-        from pinot_tpu.engine.kernel import groupby_cells, groupby_lowering, groupby_operands, hll_lowering, hll_sort_parts, zone_blocks
+        from pinot_tpu.engine.kernel import groupby_cells, groupby_lowering, groupby_operands, hll_lowering, hll_sort_parts, selection_lowering, zone_blocks
 
         groupby = groupby_lowering(plan) or ""
         operands = groupby_operands(plan) or ""
@@ -1316,6 +1316,9 @@ class QueryExecutor:
         # under 'sort', in how many parts a segment's packed keys are
         # sorted: the ``hll.sort.parts`` mark (0 under any other lowering)
         hll_parts = hll_sort_parts(self._hll_keys_a_segment(plan, staged, block_ids)) if hll == "sort" else 0
+        # the form a selection's candidates are found in: the
+        # ``selection=`` tag and the ``selection.lowering.*`` mark
+        selection = selection_lowering(plan) or ""
         # its K x m cells and the rows sharing saved (the ``cells=`` tag,
         # ``groupby.slots.shared``), and how many aggregates take a
         # compound expression (the ``expr=`` tag)
@@ -1343,6 +1346,8 @@ class QueryExecutor:
                     self.metrics.meter(f"hll.lowering.{hll}").mark()
                 if hll_parts:
                     self.metrics.meter("hll.sort.parts").mark(hll_parts)
+                if selection:
+                    self.metrics.meter(f"selection.lowering.{selection}").mark()
                 fetch, handle = launch()
             else:
                 # coalesce key: identical (plan, staged-table token, inputs
@@ -1383,6 +1388,7 @@ class QueryExecutor:
                         hll=hll,
                         hll_parts=hll_parts,
                         segments=segments,
+                        selection=selection,
                     )
                     fetch, handle = ticket.result(deadline)
                     # the lane thread delivered -> this worker runs again
@@ -1588,9 +1594,15 @@ class QueryExecutor:
                 for i, agg in enumerate(plan.aggs)
             ]
         if plan.selection is not None:
-            res.selection_rows = self._finalize_selection(
-                request, plan, live, outs, sel_columns
-            )
+            # the candidates' rows gathered and decoded: a timer and a
+            # ``pinot:selectionRows`` annotation inside ``finalize`` (no
+            # span of its own), and the valid candidates the device handed
+            # the host, a query
+            with boundary("selectionRows", None, self.metrics.timer("phase.selectionRows")):
+                res.selection_rows = self._finalize_selection(
+                    request, plan, live, outs, sel_columns
+                )
+            self.metrics.meter("selection.candidates").mark(len(res.selection_rows))
             res.selection_columns = sel_columns
         return res
 

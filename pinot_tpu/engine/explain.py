@@ -26,6 +26,7 @@ submissions and marks zero cost meters.
 """
 from __future__ import annotations
 
+import math
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -378,6 +379,8 @@ def build_explain_node(
                 )
                 if plan.group_by is not None:
                     device_info["groupBy"] = _group_by_record(request, ctx, plan)
+                if plan.selection is not None:
+                    device_info["selection"] = _selection_record(plan)
             if poison is not None:
                 # HONESTY: the device plan is quarantined, so this
                 # query will ACTUALLY serve from the host path — the
@@ -510,6 +513,26 @@ def _group_by_record(request: BrokerRequest, ctx, plan) -> Dict[str, Any]:
     if left != plan.group_by.capacity:
         out["filteredKeySpaceCells"] = left
     return out
+
+
+def _selection_record(plan) -> Dict[str, Any]:
+    """A device selection as the planner sized and lowered it: the
+    candidates a segment (k = offset + size), the sort columns' table
+    cardinalities (the key's radices: a STRING column orders by the table
+    dictionary's ordinals), whether their product packs into one key, and
+    the answer of ``kernel.selection_lowering``, which the launch's tag
+    and mark repeat."""
+    from pinot_tpu.engine.kernel import selection_lowering
+
+    sel = plan.selection
+    return {
+        "lowering": selection_lowering(plan),
+        "k": int(sel.k),
+        "sortColumns": list(sel.sort_columns),
+        "sortCardinalities": [int(g) for g in sel.sort_gcards],
+        "keySpace": math.prod(int(g) for g in sel.sort_gcards),
+        "packed": bool(sel.packed),
+    }
 
 
 def _filtered_key_space(request: BrokerRequest, ctx) -> int:

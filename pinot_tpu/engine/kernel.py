@@ -1454,14 +1454,36 @@ def _sort_ordinals(sel, seg, q, dtype):
         yield g, gcard
 
 
+def selection_lowering(plan) -> Optional[str]:
+    """The form a selection's k candidates a segment are found in, from
+    what the plan states (None for a plan without a selection): consulted
+    by _selection_outputs and by the launch's ``selection=`` tag and
+    ``selection.lowering.*`` mark, which must agree.
+
+    'first': no sort column: the first k matching rows, in doc order.
+    'topk':  the sort columns' global ordinals pack into one key
+             (StaticSelection.packed: the product of their table
+             cardinalities at most config.max_key_space()): one
+             ``lax.top_k`` a segment over all its rows.
+    'sort':  a wider key: a stable multi-operand ``lax.sort`` of every
+             row, one int32 operand a sort column."""
+    sel = getattr(plan, "selection", None)
+    if sel is None:
+        return None
+    if not sel.sort_columns:
+        return "first"
+    return "topk" if sel.packed else "sort"
+
+
 def _selection_outputs(plan: StaticPlan, seg, q, mask) -> Dict[str, Any]:
     sel = plan.selection
     n = mask.shape[0]
     kdt = config.key_dtype()
-    if not sel.sort_columns:
+    lowering = selection_lowering(plan)
+    if lowering == "first":
         # first-k matching docIds, in doc order
         score = jnp.where(mask, jnp.arange(n, dtype=kdt), n)
-    elif not sel.packed:
+    elif lowering == "sort":
         # Wide key space: radix product overflows the key dtype, so sort
         # lexicographically with one int32 operand per sort column instead
         # of packing (XLA sorts multi-operand natively; reference handles

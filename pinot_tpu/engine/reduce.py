@@ -170,16 +170,23 @@ def _having_ok(value: Any, op: str, target: float) -> bool:
     return True
 
 
+def ordered_selection_rows(sel, rows: List[Tuple[list, list]]) -> List[Tuple[list, list]]:
+    """Candidate ``(sort values, row)`` entries in the ORDER BY's order, by
+    value (a stable sort: rows tied on the key keep the order they came
+    in); as they came where the selection states no order."""
+    if not sel.sorts:
+        return list(rows)
+    descs = [not s.ascending for s in sel.sorts]
+
+    def key(entry: Tuple[list, list]):
+        return [_SortKey(v, d) for v, d in zip(entry[0], descs)]
+
+    return sorted(rows, key=key)
+
+
 def _reduce_selection(request: BrokerRequest, merged: IntermediateResult) -> SelectionResults:
     sel = request.selection
-    rows = merged.selection_rows or []
-    if sel.sorts:
-        descs = [not s.ascending for s in sel.sorts]
-
-        def key(entry: Tuple[list, list]):
-            return [_SortKey(v, d) for v, d in zip(entry[0], descs)]
-
-        rows = sorted(rows, key=key)
+    rows = ordered_selection_rows(sel, merged.selection_rows or [])
     window = rows[sel.offset : sel.offset + sel.size]
     columns = getattr(merged, "selection_columns", None) or _selection_columns(request, window)
     return SelectionResults(columns=columns, rows=[r for _, r in window])

@@ -469,6 +469,36 @@ def hits_expected_distinct(rows: int, n: int, exponent: float) -> float:
     return float(np.sum(-np.expm1(rows * np.log1p(-p))))
 
 
+def _hits_first_day(name: str) -> int:
+    """The first of a segment's three days: later by the segment's number,
+    the digits that end ``name``."""
+    digits = "".join(ch for ch in name if ch.isdigit())
+    return _HITS_FIRST_DAY + _HITS_DAYS_A_SEGMENT * (int(digits[-6:]) if digits else 0)
+
+
+def _hits_user_column(rng, num_rows: int, users: int):
+    """``UserID`` of a ``hits`` segment, the first draws of ``rng``: the
+    dictionary's values (ascending), the rows' ids, and what the columns
+    that follow a user need (the distinct users' hashes by rank, which
+    distinct user a row by rank is, and the rows' shuffle).  The ranks
+    are drawn in ascending order, so that the distinct ones are the runs'
+    heads; their values sorted into the dictionary; the rows shuffled at
+    the end (no table by rank, no sort of the rows)."""
+    import numpy as np
+
+    user_rank = _zipf_ranks_sorted(rng, num_rows, users, HITS_USER_EXPONENT)
+    head = np.ones(num_rows, dtype=bool)
+    head[1:] = user_rank[1:] != user_rank[:-1]
+    run = np.cumsum(head, dtype=np.int32) - 1  # which distinct user a row is, rows by rank
+    hashes = _mix64(user_rank[head].astype(np.uint64))
+    values = (hashes >> np.uint64(1)).astype(np.int64)  # opaque, positive, one an id (a collision is a 2^-63 event)
+    order = np.argsort(values)
+    position = np.empty(order.size, dtype=np.int32)
+    position[order] = np.arange(order.size, dtype=np.int32)
+    shuffle = rng.permutation(num_rows)
+    return values[order], position[run][shuffle], hashes, run, shuffle
+
+
 def synthetic_hits_users_segment(
     num_rows: int,
     seed: int = 7,
@@ -488,24 +518,8 @@ def synthetic_hits_users_segment(
     ``seed`` are the same in every process."""
     import numpy as np
 
-    from pinot_tpu.segment.dictionary import Dictionary
-    from pinot_tpu.segment.immutable import ColumnData, ColumnMetadata, ImmutableSegment, SegmentMetadata
-
     rng = np.random.default_rng(seed)
-    # UserID: the ranks drawn in ascending order, so that the distinct
-    # ones are the runs' heads; their values sorted into the dictionary;
-    # the rows shuffled at the end (no table by rank, no sort of the rows)
-    user_rank = _zipf_ranks_sorted(rng, num_rows, users, HITS_USER_EXPONENT)
-    head = np.ones(num_rows, dtype=bool)
-    head[1:] = user_rank[1:] != user_rank[:-1]
-    run = np.cumsum(head, dtype=np.int32) - 1  # which distinct user a row is, rows by rank
-    hashes = _mix64(user_rank[head].astype(np.uint64))
-    values = (hashes >> np.uint64(1)).astype(np.int64)  # opaque, positive, one an id (a collision is a 2^-63 event)
-    order = np.argsort(values)
-    position = np.empty(order.size, dtype=np.int32)
-    position[order] = np.arange(order.size, dtype=np.int32)
-    shuffle = rng.permutation(num_rows)
-    user_fwd = position[run][shuffle]
+    values, user_fwd, hashes, run, shuffle = _hits_user_column(rng, num_rows, users)
     # RegionID: the home region of each distinct user, then a fresh draw
     # for a tenth of the rows.  Rank r has the value r * 5 mod 9,041 (a
     # bijection on 1..9,040, so that size does not follow the key's order)
@@ -525,20 +539,30 @@ def synthetic_hits_users_segment(
     by_width = sorted(HITS_WIDTHS)
     width_cdf = np.cumsum([k for _, k in by_width]) / float(sum(k for _, k in by_width))
     width_fwd = np.minimum(np.searchsorted(width_cdf, rng.random(num_rows), side="right"), len(by_width) - 1).astype(np.int32)
-    digits = "".join(ch for ch in name if ch.isdigit())
-    first_day = _HITS_FIRST_DAY + _HITS_DAYS_A_SEGMENT * (int(digits[-6:]) if digits else 0)
+    first_day = _hits_first_day(name)
     day_fwd = np.sort(rng.integers(0, _HITS_DAYS_A_SEGMENT, num_rows, dtype=np.int32))
 
     # every dictionary holds its whole pool (UserID's: the ids drawn)
     pools = {
-        "UserID": (values[order], user_fwd),
+        "UserID": (values, user_fwd),
         "RegionID": (region_values, region_fwd),
         "AdvEngineID": (np.arange(HITS_ADV_ENGINES + 1, dtype=np.int64), adv_fwd),
         "ResolutionWidth": (np.array([w for w, _ in by_width], dtype=np.int64), width_fwd),
         "EventDate": (first_day + np.arange(_HITS_DAYS_A_SEGMENT, dtype=np.int64), day_fwd),
     }
+    return _hits_segment(hits_users_schema(), pools, num_rows, seed, name)
+
+
+def _hits_segment(schema: Schema, pools: Dict[str, Any], num_rows: int, seed: int, name: str):
+    """A ``hits`` segment from ``pools``: a column's dictionary values
+    (ascending; a STRING column's as a list of ``str``) and its rows' ids."""
+    import zlib
+
+    from pinot_tpu.segment.dictionary import Dictionary
+    from pinot_tpu.segment.immutable import ColumnData, ColumnMetadata, ImmutableSegment, SegmentMetadata
+
     columns = {}
-    for spec in hits_users_schema().all_fields():
+    for spec in schema.all_fields():
         pool, fwd = pools[spec.name]
         d = Dictionary(spec.stored_type, pool)
         columns[spec.name] = ColumnData(
@@ -565,10 +589,202 @@ def synthetic_hits_users_segment(
         time_column="EventDate",
     )
     seg = ImmutableSegment(metadata=smeta, columns=columns)
-    import zlib
-
     smeta.crc = zlib.crc32(f"{name}:{num_rows}:{seed}".encode())
     return seg
+
+
+# ---------------------------------------------------------------------------
+# ClickBench ``hits``, the columns its search-phrase queries read
+# (queries.sql lines 13 to 15, 20 and 25 to 27: counts by SearchPhrase,
+# a look-up by UserID, and SearchPhrase WHERE SearchPhrase <> '' ORDER BY
+# EventTime | SearchPhrase | both LIMIT 10).  The users' table above with
+# its UserID and EventDate, a STRING column of millions of distinct
+# values, most rows empty, and the second of each hit.
+# ---------------------------------------------------------------------------
+
+HITS_PHRASE_EMPTY_SHARE = 0.869  # SearchPhrase = '' in the source's rows, about
+HITS_PHRASES = 22_672_621  # phrases drawn from; 100.7M rows (13.19M with a phrase) then hold about 6,019,103 distinct
+HITS_PHRASE_EXPONENT = 0.8
+HITS_SEARCH_ENGINES = 90
+_PHRASE_BYTES = 60  # the longest phrase: six words of nine bytes and five spaces are 59
+_PHRASE_WORDS = 4096  # the vocabulary; two words hold a rank of up to 2^24 ...
+_PHRASE_RANK_BITS = 25  # ... and a third of 2 values the 25th bit: a phrase spells its rank, so no two ranks share one
+_vocabulary: Dict[str, Any] = {}
+
+
+def hits_search_schema() -> Schema:
+    """``hits`` with the columns ClickBench's search-phrase queries read.
+    ``EventTime`` is the hit's second since the epoch (the source's
+    DateTime), ``EventDate`` its day, the column a deployment partitions
+    by.  PQL has no SMALLINT: ``SearchEngineID`` is INT."""
+    return Schema(
+        HITS_TABLE,
+        dimensions=[
+            FieldSpec("SearchPhrase", DataType.STRING),
+            FieldSpec("EventTime", DataType.LONG),
+            FieldSpec("SearchEngineID", DataType.INT),
+            FieldSpec("UserID", DataType.LONG),
+        ],
+        time_field=TimeFieldSpec("EventDate", DataType.INT, time_unit="DAYS"),
+    )
+
+
+def _phrase_vocabulary():
+    """``_PHRASE_WORDS`` distinct words of 1 to 9 bytes of UTF-8, half of
+    them Latin and half Cyrillic (two bytes a letter, as most of the
+    source's phrases are), from a seed of its own: the same in every
+    segment and process.  Returns the words' bytes [words, 9], zero
+    padded, and their lengths."""
+    import numpy as np
+
+    if "words" not in _vocabulary:  # one assignment at the end: run.py's generator threads may all arrive here first
+        rng = random.Random(0x5EA2C4)
+        latin, cyrillic = string.ascii_lowercase, "".join(chr(c) for c in range(0x430, 0x450))
+        words: List[str] = []
+        seen = set()
+        while len(words) < _PHRASE_WORDS:
+            if len(words) % 2:
+                word = "".join(rng.choice(cyrillic) for _ in range(rng.randint(1, 4)))
+            else:
+                word = "".join(rng.choice(latin) for _ in range(rng.randint(1, 9)))
+            if word not in seen:
+                seen.add(word)
+                words.append(word)
+        table = np.zeros((_PHRASE_WORDS, 9), dtype=np.uint8)
+        for i, word in enumerate(words):
+            raw = word.encode("utf-8")
+            table[i, : len(raw)] = np.frombuffer(raw, dtype=np.uint8)
+        _vocabulary["words"] = (table, np.count_nonzero(table, axis=1).astype(np.int64))
+    return _vocabulary["words"]
+
+
+def hits_phrase_bytes(ranks):
+    """The phrases of ``ranks`` (0-based, under 2^25) as rows of UTF-8
+    bytes [n, 60], zero padded: 2 to 6 words of the vocabulary with a
+    space between, 3 to 59 bytes.  A fixed function of the rank alone.
+    The first two words, and which half of the vocabulary the third or
+    the missing third comes from, spell the rank after a bijection of its
+    25 bits, so that two ranks never share a phrase and a phrase's place
+    in the dictionary says nothing of its popularity; the other words
+    are the rank's hash."""
+    import numpy as np
+
+    table, lengths = _phrase_vocabulary()
+    ranks = np.asarray(ranks, dtype=np.uint64)
+    mask = np.uint64((1 << _PHRASE_RANK_BITS) - 1)
+    spelled = (ranks * np.uint64(0x9E3779B1)) & mask  # an odd multiplier: a bijection on 25 bits
+    h = _mix64(ranks)
+    top = (spelled >> np.uint64(24)).astype(np.int64)  # the 25th bit
+    # 2 to 6 words; a phrase of two words has the 25th bit 0 (its twin with the bit set takes three)
+    count = np.where(top == 1, 3 + (h % np.uint64(4)).astype(np.int64), 2 + (h % np.uint64(5)).astype(np.int64))
+    half = _PHRASE_WORDS // 2
+    words = [
+        (spelled & np.uint64(_PHRASE_WORDS - 1)).astype(np.int64),
+        ((spelled >> np.uint64(12)) & np.uint64(_PHRASE_WORDS - 1)).astype(np.int64),
+        top * half + ((h >> np.uint64(8)) % np.uint64(half)).astype(np.int64),
+    ] + [((h >> np.uint64(20 + 12 * j)) % np.uint64(_PHRASE_WORDS)).astype(np.int64) for j in range(3)]
+    out = np.zeros((ranks.size, _PHRASE_BYTES), dtype=np.uint8)
+    start = np.zeros(ranks.size, dtype=np.int64)
+    for j, word in enumerate(words):
+        rows = np.nonzero(count > j)[0]
+        at, word = start[rows], word[rows]
+        if j:
+            out[rows, at] = 32
+            at = at + 1
+        length = lengths[word]
+        for c in range(table.shape[1]):
+            has = length > c
+            out[rows[has], at[has] + c] = table[word[has], c]
+        start[rows] = at + length
+    return out
+
+
+def _phrase_strings(rows_of_bytes) -> List[str]:
+    """Rows of zero-padded UTF-8 bytes as a list of ``str``: one decode of
+    the rows joined by NUL, one split."""
+    import numpy as np
+
+    n = rows_of_bytes.shape[0]
+    if n == 0:
+        return []
+    ended = np.concatenate([rows_of_bytes, np.zeros((n, 1), dtype=np.uint8)], axis=1)
+    keep = ended != 0
+    keep[:, -1] = True
+    return ended[keep].tobytes()[:-1].decode("utf-8").split("\x00")
+
+
+def _present(pool, ids, size: int):
+    """A dictionary of the values of ``pool`` (ascending) that ``ids``
+    hold, and the rows' ids in it: a count and a running sum, no sort."""
+    import numpy as np
+
+    seen = np.bincount(ids, minlength=size) > 0
+    place = np.cumsum(seen, dtype=np.int32) - 1
+    return pool[seen], place[ids]
+
+
+def synthetic_hits_search_segment(
+    num_rows: int,
+    seed: int = 7,
+    name: str = "hits0",
+    users: int = HITS_USERS,
+    phrases: int = HITS_PHRASES,
+):
+    """One segment of ``hits`` for ClickBench's search-phrase queries.
+    ``UserID`` and ``EventDate`` as ``synthetic_hits_users_segment`` makes
+    them (for a seed the same users row for row; three consecutive days,
+    sorted).  ``SearchPhrase`` is empty in 86.9% of the rows; the others
+    draw a phrase by Zipf's law (exponent 0.8) over ``phrases`` ranks,
+    each rank's phrase ``hits_phrase_bytes``'s: the same string in every
+    segment that holds the rank.  ``EventTime`` is a second of the row's
+    ``EventDate``, uniform over its 86,400 and not sorted inside the day.
+    ``SearchEngineID`` is 0 where the phrase is empty and drawn by Zipf's
+    law (exponent 1) over 1..90 elsewhere.  A dictionary holds the values
+    its rows hold."""
+    import numpy as np
+
+    if phrases > 1 << _PHRASE_RANK_BITS:
+        raise ValueError(f"phrases {phrases}: a phrase spells a rank of {_PHRASE_RANK_BITS} bits")
+    rng = np.random.default_rng(seed)
+    user_values, user_fwd, _, _, _ = _hits_user_column(rng, num_rows, users)
+    first_day = _hits_first_day(name)
+    day = np.sort(rng.integers(0, _HITS_DAYS_A_SEGMENT, num_rows, dtype=np.int32))
+    second = day.astype(np.int64) * 86_400 + rng.integers(0, 86_400, num_rows, dtype=np.int64)
+    seconds = _HITS_DAYS_A_SEGMENT * 86_400
+    time_values, time_fwd = _present(first_day * 86_400 + np.arange(seconds, dtype=np.int64), second, seconds)
+    day_values, day_fwd = _present(first_day + np.arange(_HITS_DAYS_A_SEGMENT, dtype=np.int64), day, _HITS_DAYS_A_SEGMENT)
+
+    # SearchPhrase: as UserID, the ranks drawn in ascending order, the
+    # distinct ones the runs' heads; their phrases sorted as bytes (the
+    # order of UTF-8 is the order of the code points, and of ``str``)
+    asked = np.nonzero(rng.random(num_rows) >= HITS_PHRASE_EMPTY_SHARE)[0]
+    rank = _zipf_ranks_sorted(rng, asked.size, phrases, HITS_PHRASE_EXPONENT)
+    head = np.ones(asked.size, dtype=bool)
+    head[1:] = rank[1:] != rank[:-1]
+    run = np.cumsum(head, dtype=np.int32) - 1
+    spelled = hits_phrase_bytes(rank[head])
+    order = np.argsort(spelled.view(f"S{_PHRASE_BYTES}").ravel(), kind="stable")
+    position = np.empty(order.size, dtype=np.int32)
+    position[order] = np.arange(1, order.size + 1, dtype=np.int32)  # 0 is the empty phrase's
+    phrase_fwd = np.zeros(num_rows, dtype=np.int32)
+    phrase_fwd[asked] = position[run][rng.permutation(asked.size)]
+    phrase_values = _phrase_strings(spelled[order])
+    if asked.size < num_rows:
+        phrase_values.insert(0, "")
+    else:
+        phrase_fwd -= 1
+    engine = np.zeros(num_rows, dtype=np.int64)
+    engine[asked] = 1 + _zipf_ranks_sorted(rng, asked.size, HITS_SEARCH_ENGINES, 1.0)[rng.permutation(asked.size)]
+    engine_values, engine_fwd = _present(np.arange(HITS_SEARCH_ENGINES + 1, dtype=np.int64), engine, HITS_SEARCH_ENGINES + 1)
+
+    pools = {
+        "SearchPhrase": (phrase_values, phrase_fwd),
+        "EventTime": (time_values, time_fwd),
+        "SearchEngineID": (engine_values, engine_fwd),
+        "UserID": (user_values, user_fwd),
+        "EventDate": (day_values, day_fwd),
+    }
+    return _hits_segment(hits_search_schema(), pools, num_rows, seed, name)
 
 
 # ---------------------------------------------------------------------------

@@ -279,6 +279,56 @@ def payloads_equivalent(
     return math.isclose(na, nb, rel_tol=rel_tol, abs_tol=abs_tol)
 
 
+def selection_equivalent(request, produced, oracle) -> bool:
+    """A sorted selection's reply held to the host oracle's rows by the
+    rule SQL states, where ties leave more than one right answer:
+    ``produced`` and ``oracle`` are the two ``IntermediateResult``s, the
+    oracle's holding EVERY matching row with its sort values
+    (``host_fallback``).  The reply's window (offset, size) has the
+    oracle's window's length and its key at every rank (so every row
+    under the cut's key is there as often as the table holds it), and the
+    rows it shows at a key are drawn from the table's rows at that key,
+    none oftener than the table holds it.  A different choice among rows
+    tied at the cut is then no divergence; a row from above the cut, a row
+    the table lacks and a row repeated are."""
+    from collections import Counter
+
+    from pinot_tpu.engine.reduce import ordered_selection_rows
+
+    sel = request.selection
+    start, end = sel.offset, sel.offset + sel.size
+    have = ordered_selection_rows(sel, produced.selection_rows or [])[start:end]
+    every = ordered_selection_rows(sel, oracle.selection_rows or [])
+    if [keys for keys, _ in have] != [keys for keys, _ in every[start:end]]:
+        return False
+    if not have:
+        return True
+    # the table's rows at the window's keys: the window, widened over the ties at both its ends
+    end = start + len(have)
+    while start > 0 and every[start - 1][0] == have[0][0]:
+        start -= 1
+    while end < len(every) and every[end][0] == have[-1][0]:
+        end += 1
+    held = Counter((repr(keys), repr(row)) for keys, row in every[start:end])
+    shown = Counter((repr(keys), repr(row)) for keys, row in have)
+    return all(n <= held[entry] for entry, n in shown.items())
+
+
+def results_equivalent(request, produced, oracle) -> bool:
+    """What the shadow auditor holds a production result to: the
+    canonical payloads equivalent (``payloads_equivalent``), or, for a
+    selection under an ORDER BY whose rows differ, everything but the
+    rows equivalent and the rows another right answer
+    (``selection_equivalent``)."""
+    a, b = canonical_payload(request, produced), canonical_payload(request, oracle)
+    if payloads_equivalent(a, b):
+        return True
+    if not (request.is_selection and request.selection.sorts):
+        return False
+    rest = lambda payload: dict(payload, selectionResults=dict(payload.get("selectionResults") or {}, results=None))
+    return payloads_equivalent(rest(a), rest(b)) and selection_equivalent(request, produced, oracle)
+
+
 # ---------------------------------------------------------------------------
 # Server-side shadow differential auditor
 # ---------------------------------------------------------------------------
@@ -462,10 +512,10 @@ class ShadowAuditor:
         if oracle is None:
             return
         self.metrics.meter("audit.samples").mark()
+        if results_equivalent(request, job["result"], oracle):
+            return
         produced = canonical_payload(request, job["result"])
         expected = canonical_payload(request, oracle)
-        if payloads_equivalent(produced, expected):
-            return
         # -- divergence: the device (or an optimization tier) lied -----
         tier = getattr(job["result"], "_served_tier", "unknown")
         detect_ms = (time.monotonic() - job["enqueuedAt"]) * 1000.0

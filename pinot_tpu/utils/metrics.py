@@ -707,6 +707,25 @@ SERVER_METRIC_CATALOG: Dict[str, str] = {
     "order values and their sum of squares, and trim_group_candidates' "
     "selection around the cut: no sort of the state); timer and "
     "annotation, no span",
+    # the form a launch's selection found its k candidates a segment in,
+    # one mark a launch that carries one (engine/kernel.py
+    # selection_lowering, which _selection_outputs asks too; the launch's
+    # ``selection=`` tag), and what its finalize was handed
+    "selection.lowering.first": "selection launches without an ORDER BY: "
+    "the first k matching rows a segment, in doc order",
+    "selection.lowering.topk": "selection launches whose sort columns' "
+    "table ordinals pack into one key (their cardinalities' product at "
+    "most config.max_key_space()): one lax.top_k a segment over all its rows",
+    "selection.lowering.sort": "selection launches over a wider key: a "
+    "stable multi-operand lax.sort of every row of every segment, one "
+    "int32 operand a sort column, to keep k rows a segment",
+    "selection.candidates": "valid candidate rows the device handed the "
+    "host for a selection, marked by the count (segments x k as the "
+    "program stands)",
+    "phase.selectionRows": "inside phase.finalize of a device selection: "
+    "each valid candidate's row gathered from its segment and decoded, "
+    "the sort values and the selected columns "
+    "(executor._finalize_selection); timer and annotation, no span",
     # which lowering a launch's HLL aggregates took, grouped or not, one
     # mark a launch that carries one (engine/kernel.py hll_lowering,
     # which the kernel builder and zone_blocks ask too; the launch's

@@ -487,3 +487,78 @@ def test_a_launch_over_four_of_sixteen_segments_keeps_four_segments_of_temporari
     assert memory.argument_size_in_bytes >= staged  # the whole resident columns go in
     assert memory.temp_size_in_bytes < staged  # and under a quarter of their rows is worked on
     assert "dynamic-slice" in compiled.as_text() and "gather" not in compiled.as_text()  # one slice a column
+
+
+# ClickBench lines 25 and 26 (benchmark/traffic/hits_search_selection_closed.json): a selection under an ORDER BY
+# over a time and over a STRING column of 6M values (PR 50).  Line 27's key, the pair, does not pack into 2^30 and takes
+# the stable sort of four operands, whose program compiles for over a minute at ANY size (69 s here at [2, 2^20], 79 s
+# at the cell's [12, 2^23], where it keeps 2.28 GB beside its arguments): too long for tier-1, so it is not compiled here
+SEARCH = "SELECT SearchPhrase FROM hits WHERE SearchPhrase <> '' ORDER BY {} LIMIT 10"
+SELECTION_SHAPES = {"by_time": "EventTime", "by_phrase": "SearchPhrase"}
+HITS_SECONDS, HITS_PHRASE_KEYS = 3_110_400, 6_019_104
+
+
+@pytest.fixture(scope="module")
+def selection_launches():
+    """(plan, segment arrays, query inputs) of each launch of
+    SELECTION_SHAPES as the executor makes it on the chip (float32,
+    int32), over a tiny table of the cell's generator."""
+    from pinot_tpu.engine import kernel as kernel_mod
+    from pinot_tpu.engine.executor import QueryExecutor
+    from pinot_tpu.pql import optimize_request, parse_pql
+    from pinot_tpu.tools.datagen import synthetic_hits_search_segment
+
+    launches = {}
+    with pytest.MonkeyPatch.context() as mp, jax.enable_x64(False):
+        mp.setenv("PINOT_TPU_RAW_CARD_MIN", "0")
+        run_kernel = QueryExecutor._run_kernel
+
+        def spy(self, kernel, args, plan, *rest, **kw):
+            launches[name] = (plan, args[0], args[1])
+            return run_kernel(self, kernel, args, plan, *rest, **kw)
+
+        mp.setattr(QueryExecutor, "_run_kernel", spy)
+        segs = [synthetic_hits_search_segment(4096, seed=500 + i, name=f"search{i}", users=5_000, phrases=9_000) for i in range(2)]
+        try:
+            for name, order in SELECTION_SHAPES.items():
+                QueryExecutor().execute(segs, optimize_request(parse_pql(SEARCH.format(order))))
+        finally:
+            kernel_mod.make_table_kernel.cache_clear()
+            kernel_mod.make_packed_table_kernel.cache_clear()
+    return launches
+
+
+@pytest.mark.parametrize("shape", sorted(SELECTION_SHAPES))
+def test_selection_compiles_for_v5e_at_the_cells_size(one_chip, selection_launches, monkeypatch, shape):
+    """The table programs of ClickBench's lines 25 and 26 at the cell's
+    own size and key spaces (12 segments of 8,388,608 rows; 3.1M seconds,
+    6M phrases): one ``lax.top_k`` a segment over a packed key.  What
+    comes back is ten row numbers a segment, and what the program keeps
+    in HBM beside the staged ids is a few vectors of its rows."""
+    import dataclasses
+
+    from pinot_tpu.engine import kernel as kernel_mod
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    plan, segs, q = selection_launches[shape]
+    gcards = ({"EventTime": HITS_SECONDS, "SearchPhrase": HITS_PHRASE_KEYS}[plan.selection.sort_columns[0]],)
+    plan = dataclasses.replace(plan, selection=dataclasses.replace(plan.selection, sort_gcards=gcards))
+    assert kernel_mod.selection_lowering(plan) == "topk" and plan.selection.k == 10 and plan.selection.use_gfwd == (True,)
+    S, n = 12, 1 << 23
+
+    def at_scale(key, v):
+        rows = (n,) + v.shape[2:] if kernel_mod._row_key(key) else v.shape[1:]
+        dtype = jnp.int32 if key.endswith((".gfwd", ".fwd")) else v.dtype  # ids of 259,200 to 6M values take four bytes
+        return jax.ShapeDtypeStruct((S,) + rows, dtype, sharding=one_chip)
+
+    segs = {key: at_scale(key, v) for key, v in segs.items()}
+    q = jax.tree_util.tree_map(lambda v: at_scale("", v), q)
+    try:
+        with jax.enable_x64(False):
+            compiled = kernel_mod.make_table_kernel(plan).lower(segs, q).compile()
+    finally:
+        kernel_mod.make_table_kernel.cache_clear()
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes < 1 << 16  # ten row numbers and their validity a segment, and a count
+    # the key and the row numbers, in and out, the segment axis padded to its tile of 16
+    assert memory.temp_size_in_bytes <= 4 * 16 * n * 4 + (64 << 20), f"temporaries {memory.temp_size_in_bytes / (1 << 30):.3f} GiB"
